@@ -93,10 +93,10 @@ class ModelConfig:
     # FLOPs (the right trade at small batch or remat="none"; won the 124M
     # race post CE-scatter fix). "fused" is an EXPERIMENT, not a product
     # path: the Pallas online-logsumexp kernel (ops/pallas_ce.py) is
-    # interpret-mode correct but hung the v5e chip three times across two
-    # remat configs (2026-07/08, multi-hour backend wedges) and measured
+    # interpret-mode correct but hung a v5e chip three times across two
+    # remat configs (2026-07/08, an earlier installation) and measured
     # SLOWER everywhere it completed (29.9-31.5% vs 40+% MFU at 124M);
-    # it is excluded from every capture campaign as a wedge class. Keep
+    # it stays out of chip_smoke.py and of every benchmark cell. Keep
     # chunked/dense for real runs; degrades loudly to chunked for biased
     # or tensor-sharded heads.
     ce_impl: str = "chunked"  # chunked | fused | dense
@@ -115,9 +115,7 @@ class ModelConfig:
     # b8/320 slots — ~140 MB/step of pure copy traffic — plus ~110 MB temp;
     # unrolling removes the inner loop and ALL cache copies, letting the
     # token scan update the cache in place). Decode-only: prefill (Tq>1)
-    # and training keep scan_unroll. Default off until measured on-chip —
-    # scan-unroll is an unproven kernel-config class on this backend
-    # (tpu_capture RISKY_STAGES).
+    # and training keep scan_unroll. Default off until measured on-chip.
     decode_unroll_layers: bool = False
     # Decode KV-cache container layout. 'unstacked' (default): a tuple of
     # per-layer (B, T, G, Dh) caches with a trace-time python loop over

@@ -1,6 +1,6 @@
 """Shared loader for the C++ runtime libraries under native/.
 
-One code path for auto-building (`make <target>.so`) and ctypes-loading every
+One code path for building (`make <target>.so`) and ctypes-loading every
 native extension, used by native_batcher.py and native_bpe.py. Build is
 serialized across *processes* with an fcntl file lock — preprocess fans out
 a multiprocessing Pool, and without the lock every fresh worker would race
@@ -14,6 +14,7 @@ import fcntl
 import os
 import subprocess
 import threading
+import warnings
 from typing import Callable, Dict, Optional
 
 NATIVE_DIR = os.path.join(
@@ -30,12 +31,14 @@ def load_native_lib(
     *,
     auto_build: bool = True,
 ) -> Optional[ctypes.CDLL]:
-    """Load native/<so_name>, building it first if absent.
+    """Load native/<so_name>, (re)building it first if absent or stale.
 
-    `configure(lib)` sets restype/argtypes; an AttributeError there (stale
-    .so missing a symbol) makes the load fail soft. Returns None when no
-    toolchain/library is available — callers fall back to their pure-Python
-    paths. The result (including failure) is cached per process.
+    The libraries are build products, never committed: a binary built on
+    another CPU can die with an illegal instruction that no `except` sees.
+    `configure(lib)` sets restype/argtypes. Returns None when the library
+    cannot be built or loaded — after a warning that says why, so a caller's
+    pure-Python path is never taken silently. The result (including failure)
+    is cached per process.
     """
     with _cache_lock:
         if so_name in _cache:
@@ -45,19 +48,26 @@ def load_native_lib(
         return lib
 
 
+def _needs_build(path: str) -> bool:
+    """Missing, or older than its source (lib<name>.so <- <name>.cpp)."""
+    if not os.path.exists(path):
+        return True
+    stem = os.path.basename(path).removeprefix("lib").removesuffix(".so")
+    src = os.path.join(NATIVE_DIR, stem + ".cpp")
+    return os.path.exists(src) and os.path.getmtime(src) > os.path.getmtime(path)
+
+
 def _load(
     so_name: str, configure: Callable[[ctypes.CDLL], None], auto_build: bool
 ) -> Optional[ctypes.CDLL]:
     path = os.path.join(NATIVE_DIR, so_name)
-    if not os.path.exists(path):
-        if not auto_build:
-            return None
+    if auto_build and _needs_build(path):
         lock_path = os.path.join(NATIVE_DIR, ".build.lock")
         try:
             with open(lock_path, "w") as lock_file:
                 fcntl.flock(lock_file, fcntl.LOCK_EX)
                 try:
-                    if not os.path.exists(path):  # a peer may have built it
+                    if _needs_build(path):  # a peer may have built it
                         subprocess.run(
                             ["make", "-s", so_name],
                             cwd=NATIVE_DIR,
@@ -67,11 +77,21 @@ def _load(
                         )
                 finally:
                     fcntl.flock(lock_file, fcntl.LOCK_UN)
-        except (subprocess.SubprocessError, FileNotFoundError, OSError):
+        except subprocess.CalledProcessError as e:
+            warnings.warn(
+                f"building native/{so_name} failed (rc={e.returncode}): "
+                f"{e.stderr.decode(errors='replace')[-500:]}"
+            )
             return None
+        except (subprocess.TimeoutExpired, OSError) as e:
+            warnings.warn(f"building native/{so_name} failed: {e!r}")
+            return None
+    if not os.path.exists(path):
+        return None
     try:
         lib = ctypes.CDLL(path)
         configure(lib)
-    except (OSError, AttributeError):
+    except (OSError, AttributeError) as e:
+        warnings.warn(f"loading native/{so_name} failed: {e!r}")
         return None
     return lib

@@ -997,6 +997,9 @@ def main(argv=None) -> int:
     if args.role:
         spec["role"] = args.role
 
+    from ..utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     server = WorkerServer(spec)
     server.announce()
     signal.signal(
@@ -1016,7 +1019,14 @@ def main(argv=None) -> int:
         server.start_orphan_watch()
     server.start_replica()
     server.serve_forever()
-    return 0
+    # serve_forever returns only once an exit path (shutdown op, SIGTERM,
+    # orphaned) is under way on its own thread, which stops the replica and
+    # ends the process with os._exit. Returning now would finalize the
+    # interpreter under that thread and the device runtime's — on TPU that
+    # aborts the process (SIGABRT) instead of releasing the chip cleanly.
+    time.sleep(_ORPHAN_DRAIN_S + 20.0)
+    sys.stderr.write(f"[worker {server.index}] exit path stalled; leaving\n")
+    os._exit(1)
 
 
 if __name__ == "__main__":

@@ -330,6 +330,27 @@ def shard_params_for_inference(params: Any, mesh: Any) -> Any:
 # ---------------------------------------------------------------------------
 
 
+def _checkpoint_step_dir(model_path: str) -> str:
+    """A step-N dir as given, else the newest one under `model_path`."""
+    from pretraining_llm_tpu.training import checkpoint as ckpt
+
+    if model_path.rstrip("/").split("/")[-1].startswith("step-"):
+        return model_path
+    latest = ckpt.latest_checkpoint(model_path)
+    if latest is None:
+        raise FileNotFoundError(f"no checkpoints under {model_path}")
+    return latest
+
+
+def load_config_for_inference(model_path: str) -> Config:
+    """The Config a checkpoint was trained with — metadata only: no params
+    are read and no device backend is touched (a fleet parent whose workers
+    own the chips needs the config and tokenizer, not the weights)."""
+    with open(f"{_checkpoint_step_dir(model_path)}/metadata.json") as f:
+        meta = json.load(f)
+    return Config.from_json(json.dumps(meta["extra"]["config"]))
+
+
 def load_model_for_inference(
     model_path: str, *, use_ema: bool = False
 ) -> Tuple[Any, Config]:
@@ -340,15 +361,8 @@ def load_model_for_inference(
     `train.ema_decay > 0`; fails loudly otherwise)."""
     from pretraining_llm_tpu.training import checkpoint as ckpt
 
-    path = model_path
-    if not path.rstrip("/").split("/")[-1].startswith("step-"):
-        latest = ckpt.latest_checkpoint(path)
-        if latest is None:
-            raise FileNotFoundError(f"no checkpoints under {path}")
-        path = latest
-    with open(f"{path}/metadata.json") as f:
-        meta = json.load(f)
-    cfg = Config.from_json(json.dumps(meta["extra"]["config"]))
+    path = _checkpoint_step_dir(model_path)
+    cfg = load_config_for_inference(path)
     key = "ema" if use_ema else "params"
     # Shape-only template: no throwaway init of the full model.
     template = jax.eval_shape(

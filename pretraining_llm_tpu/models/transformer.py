@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from pretraining_llm_tpu.utils import jax_compat
 
 from pretraining_llm_tpu.config import ModelConfig
 from pretraining_llm_tpu.models import layers, moe
@@ -1037,7 +1036,7 @@ def _chunked_ce(
                         h_l.reshape(bl * tl, dl), w_l, t_l.reshape(bl * tl)
                     ).reshape(bl, tl)
 
-                losses = jax_compat.shard_map(
+                losses = jax.shard_map(
                     local_ce,
                     mesh=mesh,
                     in_specs=(P(batch_axes, None, None), P(None, None), P(batch_axes, None)),

@@ -22,8 +22,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from pretraining_llm_tpu.utils import jax_compat
-
 
 def _pick_block(t: int, requested: int, default: int) -> int:
     if requested > 0:
@@ -153,12 +151,8 @@ def blockwise_attention(
     return out.transpose(1, 0, 2, 3, 4, 5).reshape(b, tq_len, h, dh).astype(q.dtype)
 
 
-@functools.lru_cache(maxsize=1)
 def _pallas_available() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def shard_mapped_kernel(kernel, q, k, v, mesh, *, batch_axes=("data", "fsdp"),
@@ -191,12 +185,12 @@ def shard_mapped_kernel(kernel, q, k, v, mesh, *, batch_axes=("data", "fsdp"),
     spec = P(batch_axes, None, head_ax, None)
     if segments is not None:
         seg_spec = P(batch_axes, None)
-        return jax_compat.shard_map(
+        return jax.shard_map(
             lambda q_, k_, v_, s_: kernel(q_, k_, v_, segments=s_),
             mesh=mesh, in_specs=(spec, spec, spec, seg_spec), out_specs=spec,
             check_vma=False,
         )(q, k, v, segments)
-    return jax_compat.shard_map(
+    return jax.shard_map(
         kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)
@@ -247,62 +241,65 @@ def flash_attention(
         return o.transpose(0, 2, 1, 3) if heads_major else o
 
     if _pallas_available():
-        try:
-            from pretraining_llm_tpu.ops.pallas_flash import pallas_flash_attention
-            from pretraining_llm_tpu.parallel.sharding import current_mesh
+        from pretraining_llm_tpu.ops.pallas_flash import pallas_flash_attention
+        from pretraining_llm_tpu.parallel.sharding import current_mesh
 
-            kernel = functools.partial(
-                pallas_flash_attention, causal=causal, block_q=block_q,
-                block_kv=block_kv, window=window,
+        kernel = functools.partial(
+            pallas_flash_attention, causal=causal, block_q=block_q,
+            block_kv=block_kv, window=window,
+        )
+        mesh = current_mesh()
+        if mesh is None or all(s == 1 for s in mesh.shape.values()):
+            return kernel(q, k, v, segments=segments,
+                          heads_major=heads_major)
+        # Manual-region classification (ADVICE r2): the direct kernel
+        # call is only correct when EVERY nontrivial mesh axis is manual
+        # (ulysses' all-to-all body — operands are per-device local
+        # arrays). In a PARTIAL-manual region (the pipeline: manual over
+        # 'pipe' only) activations are still auto-sharded over
+        # data/fsdp, so a direct pallas_call would be replicated by
+        # GSPMD, all-gathering the global batch — and a nested shard_map
+        # over the auto axes is not expressible either; use the
+        # blockwise fallback there (GSPMD partitions plain JAX ops).
+        abstract_mesh = jax.sharding.get_abstract_mesh()
+        manual_axes = {
+            name
+            for name, kind in zip(
+                abstract_mesh.axis_names, abstract_mesh.axis_types
             )
-            mesh = current_mesh()
-            if mesh is None or all(s == 1 for s in mesh.shape.values()):
-                return kernel(q, k, v, segments=segments,
-                              heads_major=heads_major)
-            # Manual-region classification (ADVICE r2): the direct kernel
-            # call is only correct when EVERY nontrivial mesh axis is manual
-            # (ulysses' all-to-all body — operands are per-device local
-            # arrays). In a PARTIAL-manual region (the pipeline: manual over
-            # 'pipe' only) activations are still auto-sharded over
-            # data/fsdp, so a direct pallas_call would be replicated by
-            # GSPMD, all-gathering the global batch — and a nested shard_map
-            # over the auto axes is not expressible either; use the
-            # blockwise fallback there (GSPMD partitions plain JAX ops).
-            abstract_mesh = jax_compat.get_abstract_mesh()
-            manual_axes = jax_compat.manual_axis_names(abstract_mesh)
-            nontrivial = {name for name, size in mesh.shape.items() if size > 1}
-            if nontrivial <= manual_axes:
-                return kernel(q, k, v, segments=segments,
-                              heads_major=heads_major)  # fully manual region
-            if not manual_axes:
-                out = shard_mapped_kernel(
-                    kernel, _to_btHD(q), _to_btHD(k), _to_btHD(v), mesh,
-                    segments=segments,
-                )
-                if out is not None:
-                    return _from_btHD(out)
-            # Partial-manual region, or unexpressible per-shard layout
-            # (seq/pipe-sharded activations, indivisible batch or heads):
-            # blockwise fallback below. Loud (VERDICT r2 #9) — the user
-            # configured the Pallas kernel and is getting the slower JAX
-            # path; fires once per trace (warnings dedupe).
-            import warnings
+            if kind == jax.sharding.AxisType.Manual
+        }
+        nontrivial = {name for name, size in mesh.shape.items() if size > 1}
+        if nontrivial <= manual_axes:
+            return kernel(q, k, v, segments=segments,
+                          heads_major=heads_major)  # fully manual region
+        if not manual_axes:
+            out = shard_mapped_kernel(
+                kernel, _to_btHD(q), _to_btHD(k), _to_btHD(v), mesh,
+                segments=segments,
+            )
+            if out is not None:
+                return _from_btHD(out)
+        # Partial-manual region, or unexpressible per-shard layout
+        # (seq/pipe-sharded activations, indivisible batch or heads):
+        # blockwise fallback below. Loud (VERDICT r2 #9) — the user
+        # configured the Pallas kernel and is getting the slower JAX
+        # path; fires once per trace (warnings dedupe).
+        import warnings
 
-            why = (
-                "inside a partial-manual shard_map region (e.g. the "
-                "pipeline's pipe-only region)"
-                if manual_axes
-                else "the mesh/shape layout is not expressible per-shard "
-                "(seq/pipe-sharded activations, or batch/head counts not "
-                "divisible by the mesh axes)"
-            )
-            warnings.warn(
-                f"flash attention falling back to blockwise JAX (no Pallas "
-                f"kernel): {why}.",
-                stacklevel=2,
-            )
-        except ImportError:
-            pass  # kernel module not built yet; blockwise path is correct
+        why = (
+            "inside a partial-manual shard_map region (e.g. the "
+            "pipeline's pipe-only region)"
+            if manual_axes
+            else "the mesh/shape layout is not expressible per-shard "
+            "(seq/pipe-sharded activations, or batch/head counts not "
+            "divisible by the mesh axes)"
+        )
+        warnings.warn(
+            f"flash attention falling back to blockwise JAX (no Pallas "
+            f"kernel): {why}.",
+            stacklevel=2,
+        )
     # blockwise_attention is GQA-native (grouped einsums) — no K/V expansion.
     return _from_btHD(blockwise_attention(
         _to_btHD(q), _to_btHD(k), _to_btHD(v), causal=causal,

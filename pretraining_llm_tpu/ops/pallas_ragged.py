@@ -392,13 +392,8 @@ def _ragged_call(q, k_pool, v_pool, block_tables, seq_lens, q_lens, t,
 
     def _params(dims):
         # dimension_semantics lets Mosaic parallelize the batch/partition
-        # dims; guarded so interpret mode (and older shims) keep working.
-        if interpret:
-            return None
-        try:
-            return pltpu.TPUCompilerParams(dimension_semantics=dims)
-        except Exception:  # pragma: no cover - compiler-param shim gaps
-            return None
+        # dims.
+        return pltpu.CompilerParams(dimension_semantics=dims)
 
     if kv_splits <= 1:
         kernel = functools.partial(

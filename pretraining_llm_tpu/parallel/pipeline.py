@@ -53,7 +53,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from pretraining_llm_tpu.utils import jax_compat
 
 BlockFn = Callable[[Any, jax.Array], Tuple[jax.Array, jax.Array]]
 
@@ -310,7 +309,7 @@ def pipeline_apply(
         return out, aux_total
 
     blocks_spec = jax.tree.map(lambda _: P(pipe_axis), blocks)
-    return jax_compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(blocks_spec, P()),

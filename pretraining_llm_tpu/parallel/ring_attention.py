@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from pretraining_llm_tpu.utils import jax_compat
 
 NEG_INF = -1e30
 
@@ -286,6 +285,6 @@ def ring_attention(
         layout=layout,
         block_kv=block_kv,
     )
-    return jax_compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(spec, kv_spec, kv_spec), out_specs=spec, check_vma=False
     )(q, k, v)

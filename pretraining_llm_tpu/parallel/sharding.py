@@ -26,8 +26,6 @@ from typing import Any, Iterator, Optional, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pretraining_llm_tpu.utils import jax_compat
-
 
 def _path_names(path: Tuple[Any, ...]) -> Tuple[str, ...]:
     names = []
@@ -250,6 +248,6 @@ def constrain(x: jax.Array, *spec: Any) -> jax.Array:
     mesh = _CURRENT_MESH
     if mesh is None:
         return x
-    context = jax_compat.get_abstract_mesh()
-    target = context if context is not None else mesh
+    context = jax.sharding.get_abstract_mesh()
+    target = mesh if context.empty else context
     return jax.lax.with_sharding_constraint(x, NamedSharding(target, P(*spec)))

@@ -22,7 +22,6 @@ from typing import Optional, Tuple
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from pretraining_llm_tpu.utils import jax_compat
 
 from pretraining_llm_tpu.ops.attention import naive_attention
 
@@ -127,6 +126,6 @@ def ulysses_attention(
         block_q=block_q,
         block_kv=block_kv,
     )
-    return jax_compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
     )(q, k, v)

@@ -229,7 +229,8 @@ class Trainer:
         self._completed_step = self.start_step
 
     def _make_iterator(self, path: str, seed: int):
-        """File iterator: native C++ gatherer when built, numpy otherwise.
+        """File iterator: native C++ gatherer when it builds, numpy otherwise
+        (a `batcher` log event says which).
 
         Samples this process's rows only (batch_size / process_count) from
         this process's contiguous token-stream shard.
@@ -239,10 +240,10 @@ class Trainer:
         # Mixture specs ("a.bin:3,b.bin:1") route through the numpy
         # MixtureIterator; the native batcher reads exactly one memmap.
         if dcfg.use_native_batcher and not data_loader.is_mixture(path):
-            try:
-                from pretraining_llm_tpu.data.native_batcher import NativeBatchIterator
+            from pretraining_llm_tpu.data.native_batcher import NativeBatchIterator
 
-                return NativeBatchIterator(
+            try:
+                it = NativeBatchIterator(
                     path,
                     local_batch,
                     mcfg.context_length,
@@ -250,8 +251,19 @@ class Trainer:
                     shard_index=jax.process_index(),
                     shard_count=jax.process_count(),
                 )
-            except (RuntimeError, ValueError):
-                pass  # no toolchain / unreadable: numpy path below
+            except (RuntimeError, ValueError) as e:
+                # No toolchain / unreadable file: the numpy loader below
+                # serves (or reports the file error itself) — said loudly,
+                # since the two back ends sample different batches.
+                self.logger.log({
+                    "event": "batcher", "backend": "numpy", "path": path,
+                    "native_error": repr(e)[:200],
+                })
+            else:
+                self.logger.log(
+                    {"event": "batcher", "backend": "native", "path": path}
+                )
+                return it
         return data_loader.get_batch_iterator(
             path,
             local_batch,

@@ -23,15 +23,24 @@ _PEAK_BF16_FLOPS = {
     "v2": 46e12,
 }
 
-_DEFAULT_CPU_FLOPS = 1e11  # nominal, so MFU math never divides by zero
+_NOMINAL_CPU_FLOPS = 1e11  # tests only: keeps MFU math finite off-chip
 
 
 def device_peak_flops(device: jax.Device | None = None) -> float:
-    """Peak bf16 FLOP/s for one chip; a nominal constant on CPU."""
+    """Peak bf16 FLOP/s for one chip; a nominal constant on CPU.
+
+    An accelerator that is not in the table raises: an MFU against a
+    made-up peak is worse than none."""
     if device is None:
         device = jax.devices()[0]
     kind = device.device_kind.lower()
     for key, flops in _PEAK_BF16_FLOPS.items():
         if key in kind:
             return flops
-    return _DEFAULT_CPU_FLOPS
+    if device.platform == "cpu":
+        return _NOMINAL_CPU_FLOPS
+    raise ValueError(
+        f"no peak FLOP/s on record for device_kind {device.device_kind!r} "
+        f"(platform {device.platform!r}); add it to utils/hardware.py with "
+        "its source"
+    )

@@ -34,9 +34,9 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from pretraining_llm_tpu.utils.platform import apply_platform_env
+from pretraining_llm_tpu.utils.compile_cache import use_compile_cache
 
-apply_platform_env()  # PLLM_PLATFORM=cpu runs the jax side off-TPU
+use_compile_cache()
 
 PARITY_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "data", "parity")
 
@@ -544,9 +544,8 @@ def main():
         print(f"torch (cpu fp32 baseline):          eval loss {to:.4f}")
         print(f"delta {delta:.4f}  ({'PASS' if passed else 'FAIL'} at +-0.01)")
         # Structured last line + nonzero exit on FAIL (ADVICE r3 medium):
-        # tpu_capture banks rc and the raw tail; bank_results classifies
-        # rc==0 records without an "error" key as success, so a FAIL that
-        # exits 0 is silently laundered into an "ok" row.
+        # a caller that keeps only rc and the last line would otherwise
+        # record a FAIL that exits 0 as a pass.
         print(json.dumps({
             "delta": round(delta, 6),
             "pass": passed,

@@ -22,9 +22,9 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from pretraining_llm_tpu.utils.platform import apply_platform_env
+from pretraining_llm_tpu.utils.compile_cache import use_compile_cache
 
-apply_platform_env()
+use_compile_cache()
 
 
 def main() -> None:
@@ -150,8 +150,7 @@ def main() -> None:
     rows = _extract_rows(data, args.tool)
 
     # Persist the FULL table and end stdout with one JSON summary line:
-    # campaign stages keep only the last stdout line (tpu_capture.run_cmd),
-    # and round 4's first-ever banked profile record was one truncated
+    # a caller that keeps only the end of stdout once recorded a truncated
     # HTML fragment — the whole table must live on disk, not in a pipe.
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     table_dir = os.path.join(repo, "data", "captures")

@@ -33,9 +33,9 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from pretraining_llm_tpu.utils.platform import apply_platform_env
+from pretraining_llm_tpu.utils.compile_cache import use_compile_cache
 
-apply_platform_env()
+use_compile_cache()
 
 
 def main() -> None:
@@ -239,7 +239,8 @@ def main() -> None:
 
     from pretraining_llm_tpu.data.tokenizer import get_tokenizer
     from pretraining_llm_tpu.generation.generate import (
-        cast_params_for_inference, load_model_for_inference,
+        cast_params_for_inference, load_config_for_inference,
+        load_model_for_inference,
     )
     from pretraining_llm_tpu.generation.serving import ServingEngine
 
@@ -250,9 +251,17 @@ def main() -> None:
         if not texts:
             raise SystemExit(f"no prompts in {args.input_file}")
 
-    params, cfg = load_model_for_inference(args.model_path, use_ema=args.ema)
-    params = cast_params_for_inference(params, cfg.model)
+    cfg = load_config_for_inference(args.model_path)
     enc = get_tokenizer(args.tokenizer or cfg.data.tokenizer_name)
+    if args.http and (args.replica_mode or cfg.frontend.replica_mode) == "process":
+        # One process for each chip: the workers load the checkpoint
+        # themselves, and this parent must never initialise a device
+        # backend — a parent that holds the chip leaves none for them.
+        _serve_http(args, cfg, None, enc)
+        return
+
+    params, _ = load_model_for_inference(args.model_path, use_ema=args.ema)
+    params = cast_params_for_inference(params, cfg.model)
 
     spec = {}
     if args.draft_model_path:
@@ -327,6 +336,7 @@ def main() -> None:
                 "index": rids[rid],
                 "prompt": texts[rids[rid]],
                 "output": enc.decode(toks),
+                "tokens": [int(t) for t in toks],
                 "n_tokens": len(toks),
             }
             # Per-request lifecycle latencies: how long the request sat in

@@ -21,9 +21,9 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from pretraining_llm_tpu.utils.platform import apply_platform_env
+from pretraining_llm_tpu.utils.compile_cache import use_compile_cache
 
-apply_platform_env()
+use_compile_cache()
 
 from pretraining_llm_tpu.parallel.mesh import initialize_distributed
 

@@ -7,7 +7,6 @@ import pytest
 
 from pretraining_llm_tpu.ops.attention import multihead_attention, naive_attention
 from pretraining_llm_tpu.ops.flash_attention import blockwise_attention
-from pretraining_llm_tpu.utils import jax_compat
 
 
 def _qkv(key, b=2, t=64, h=4, dh=16, dtype=jnp.float32):
@@ -167,11 +166,6 @@ def test_shard_mapped_flash_kernel_matches_dense(mesh8):
     assert shard_mapped_kernel(kernel, q3, k[:, :, :3], v[:, :, :3], mesh8) is None
 
 
-@pytest.mark.skipif(
-    not jax_compat._HAS_MODERN_SHARD_MAP,
-    reason="partial-manual shard_map regions need jax.shard_map (>=0.6); the "
-    "legacy fallback lowers them through PartitionId, which XLA aborts on",
-)
 def test_flash_dispatch_manual_region_classification(monkeypatch):
     """Dispatch must distinguish FULLY-manual from PARTIAL-manual regions.
 
@@ -211,7 +205,7 @@ def test_flash_dispatch_manual_region_classification(monkeypatch):
     # called directly — blockwise fallback handles the auto axes via GSPMD.
     with activation_mesh(mesh):
         got = jax.jit(
-            jax_compat.shard_map(
+            jax.shard_map(
                 body, mesh=mesh, in_specs=(P(), P(), P()), out_specs=P(),
                 axis_names={"pipe"}, check_vma=False,
             )
@@ -223,7 +217,7 @@ def test_flash_dispatch_manual_region_classification(monkeypatch):
     # local arrays — the direct kernel call is the correct path.
     with activation_mesh(mesh):
         got2 = jax.jit(
-            jax_compat.shard_map(
+            jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P("data"), P("data"), P("data")), out_specs=P("data"),
                 axis_names={"data", "pipe"}, check_vma=False,

@@ -343,3 +343,47 @@ def test_async_checkpoint_write_failure_surfaces(tmp_path, monkeypatch):
     t.save(2)
     with pytest.raises(RuntimeError, match="async checkpoint write failed"):
         t.join_pending_save()
+
+
+def test_compile_cache_dir_fixed_or_placed_from_outside(tmp_path, monkeypatch):
+    """Entry points call use_compile_cache() before their first jit. With
+    JAX_COMPILATION_CACHE_DIR set, JAX reads the variable itself and the
+    helper must leave the config alone; unset, the directory sits inside the
+    checkout whatever the cwd (a cache that moves never hits)."""
+    from pretraining_llm_tpu.utils import compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", "untouched-sentinel")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "placed"))
+        assert compile_cache.use_compile_cache() == str(tmp_path / "placed")
+        assert jax.config.jax_compilation_cache_dir == "untouched-sentinel"
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        dirs = []
+        for cwd in (tmp_path, repo):
+            monkeypatch.chdir(cwd)
+            dirs.append(compile_cache.use_compile_cache())
+            assert jax.config.jax_compilation_cache_dir == dirs[-1]
+        assert dirs[0] == dirs[1] == os.path.join(repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_device_peak_flops_refuses_unknown_accelerator():
+    """An MFU against a made-up peak is worse than none: only a CPU device
+    (tests) gets the nominal constant; an accelerator missing from the table
+    raises."""
+    from types import SimpleNamespace
+
+    from pretraining_llm_tpu.utils.hardware import device_peak_flops
+
+    assert device_peak_flops(
+        SimpleNamespace(device_kind="TPU v5 lite", platform="tpu")
+    ) == 197e12
+    assert device_peak_flops(SimpleNamespace(device_kind="cpu", platform="cpu")) > 0
+    assert device_peak_flops() > 0  # the test backend itself
+    for kind, platform in (("TPU v9z", "tpu"), ("NVIDIA H100", "gpu")):
+        with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+            device_peak_flops(SimpleNamespace(device_kind=kind, platform=platform))

@@ -340,89 +340,6 @@ def test_oom_is_deterministic_not_transient(monkeypatch, capsys):
         "save_attn_res", "save_attn", "save_attn", "none", "none"]
 
 
-def test_environment_error_carries_last_banked(monkeypatch, capsys):
-    # VERDICT r3 #8: when the backend is dead the driver's JSON must point
-    # at the banked evidence, not leave a bare 0.0.
-    banked = {"metric": "mfu_gpt2-124m_train", "value": 0.416,
-              "unit": "fraction_of_peak_bf16", "stage": "bsweep:batch/16",
-              "capture_path": "data/captures/tpu_capture_r03.jsonl",
-              "commit": "abc1234 2026-07-31T00:00:00+00:00"}
-    monkeypatch.setattr(bench, "_last_banked", lambda metric: dict(banked))
-    rc, rec, calls = _run(
-        monkeypatch, capsys,
-        attempts_script=[HUNG],
-        canary_script=[(False, "canary hung past 150s (backend unreachable)")],
-    )
-    assert rc == 1
-    assert rec.get("environment_error") is True
-    assert rec["last_banked"]["value"] == 0.416
-    assert rec["last_banked"]["capture_path"].startswith("data/captures/")
-
-
-def test_last_banked_scans_capture_jsonl(tmp_path, monkeypatch):
-    # The scanner must pick the best rc==0 record for the metric, skipping
-    # error records, other metrics, and the known-bogus rc==0-with-error
-    # shape (ADVICE r3 medium: a FAIL record now carries an error marker).
-    cap = tmp_path / "data" / "captures"
-    cap.mkdir(parents=True)
-    recs = [
-        {"stage": "mfu", "rc": 0, "metric": "mfu_gpt2-124m_train",
-         "value": 0.406, "unit": "fraction_of_peak_bf16"},
-        {"stage": "bsweep:batch/16", "rc": 0, "metric": "mfu_gpt2-124m_train",
-         "value": 0.416, "unit": "fraction_of_peak_bf16", "batch": 16},
-        {"stage": "mfu", "rc": 1, "metric": "mfu_gpt2-124m_train",
-         "value": 0.9},  # failed stage: ignored
-        {"stage": "decode", "rc": 0,
-         "metric": "decode_tokens_per_sec_gpt2-124m", "value": 3841.0},
-        {"stage": "mfu", "rc": 0, "metric": "mfu_gpt2-124m_train",
-         "value": 0.0, "error": "environment: dead"},  # error: ignored
-    ]
-    with open(cap / "tpu_capture_r99.jsonl", "w") as f:
-        for r in recs:
-            f.write(json.dumps(r) + "\n")
-    best = bench._last_banked("mfu_gpt2-124m_train", repo=str(tmp_path))
-    assert best is not None
-    assert best["value"] == 0.416
-    assert best["stage"] == "bsweep:batch/16"
-    assert best["capture_path"].endswith("tpu_capture_r99.jsonl")
-    assert bench._last_banked("mfu_llama-1b_train", repo=str(tmp_path)) is None
-
-
-def test_last_banked_carries_latest_refresh(tmp_path):
-    # VERDICT r5 #8: the banked record must carry FRESHNESS — the most
-    # recent mfu-refresh value + timestamp — alongside the all-time best,
-    # so a dead-backend round end distinguishes "peak banked long ago"
-    # from "reproduced this session".
-    cap = tmp_path / "data" / "captures"
-    cap.mkdir(parents=True)
-    r03 = [
-        {"stage": "campaign-start", "rc": 0, "ts": "2026-07-28T09:00:00Z"},
-        {"stage": "mfu", "rc": 0, "metric": "mfu_gpt2-124m_train",
-         "value": 0.503, "unit": "fraction_of_peak_bf16"},
-    ]
-    r05 = [
-        {"stage": "campaign-start", "rc": 0, "ts": "2026-08-01T10:00:00Z"},
-        # Refresh records carry no "ts" of their own: the file's
-        # campaign-start stamp is the session they ran in.
-        {"stage": "mfu-refresh-mid", "rc": 0,
-         "metric": "mfu_gpt2-124m_train", "value": 0.374},
-        {"stage": "mfu-refresh", "rc": 0, "metric": "mfu_gpt2-124m_train",
-         "value": 0.359},
-    ]
-    for name, recs in (("tpu_capture_r03.jsonl", r03),
-                       ("tpu_capture_r05.jsonl", r05)):
-        with open(cap / name, "w") as f:
-            for r in recs:
-                f.write(json.dumps(r) + "\n")
-    best = bench._last_banked("mfu_gpt2-124m_train", repo=str(tmp_path))
-    assert best["value"] == 0.503  # the all-time best stays the headline
-    fresh = best["latest_refresh"]
-    assert fresh["value"] == 0.359  # the LAST refresh, not the best one
-    assert fresh["stage"] == "mfu-refresh"
-    assert fresh["ts"] == "2026-08-01T10:00:00Z"
-    assert fresh["capture_path"].endswith("tpu_capture_r05.jsonl")
-
-
 def test_mode_flag_guards_reject_foreign_knobs():
     """Every mode rejects the other modes' knobs (a silently-ignored flag
     would bank a record indistinguishable from the baseline while the

@@ -260,7 +260,7 @@ def test_evaluate_cli(tmp_path):
     data = tmp_path / "val.bin"
     tokens.tofile(data)
 
-    env = dict(os.environ, PLLM_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, os.path.join(repo, "scripts", "train.py"),
          "--preset", "tiny", "--no-resume",

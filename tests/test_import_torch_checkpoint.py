@@ -132,7 +132,7 @@ def test_import_cli_roundtrip_generates(tmp_path):
     torch.save({"model_state_dict": sd}, pt)
     out_dir = tmp_path / "imported"
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PLLM_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, os.path.join(repo_root, "scripts", "import_torch_checkpoint.py"),
          str(pt), "--out_dir", str(out_dir), "--tokenizer", "byte"],

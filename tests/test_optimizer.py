@@ -4,11 +4,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-import pytest
 
 from pretraining_llm_tpu.config import TrainConfig
 from pretraining_llm_tpu.training import optimizer as opt
-from pretraining_llm_tpu.utils import jax_compat
 
 
 def _params(key):
@@ -224,11 +222,6 @@ def test_adafactor_learns():
     assert last < first - 0.5, (first, last)
 
 
-@pytest.mark.skipif(
-    not jax_compat._HAS_MODERN_SHARD_MAP,
-    reason="interleaved pipeline step needs jax.shard_map (>=0.6); the "
-    "legacy fallback lowers axis_index to PartitionId, rejected by XLA",
-)
 def test_adafactor_sharded_interleaved_pipeline_step():
     """Adafactor composes with the sharded state machinery: PP x TP x DP
     mesh, baked interleaved layout (the v tree's blocks arrays all carry

@@ -17,17 +17,6 @@ from pretraining_llm_tpu.config import ModelConfig, get_preset
 from pretraining_llm_tpu.models import transformer
 from pretraining_llm_tpu.parallel.sharding import activation_mesh
 from pretraining_llm_tpu.training import train_step as ts
-from pretraining_llm_tpu.utils import jax_compat
-
-# Running a pipelined computation needs jax.shard_map: the legacy
-# jax.experimental fallback lowers axis_index in a partial-manual region to
-# PartitionId, which XLA's SPMD partitioner rejects as UNIMPLEMENTED.
-# Validation/schedule tests don't execute the pipeline and still run.
-requires_modern_shard_map = pytest.mark.skipif(
-    not jax_compat._HAS_MODERN_SHARD_MAP,
-    reason="pipelined execution needs jax.shard_map (>=0.6); legacy fallback "
-    "lowers axis_index to PartitionId, rejected by SPMD partitioning",
-)
 
 
 @pytest.fixture(scope="module")
@@ -71,7 +60,6 @@ def test_pipeline_rejects_indivisible_local_batch(mesh_pipe4):
             transformer.forward(params, tokens, cfg)
 
 
-@requires_modern_shard_map
 def test_pipeline_forward_matches_scan(mesh_pipe4):
     """Pipelined forward == plain scanned forward (same params, same batch)."""
     cfg = _cfg()
@@ -92,7 +80,6 @@ def test_pipeline_forward_matches_scan(mesh_pipe4):
     )
 
 
-@requires_modern_shard_map
 def test_pipeline_grads_match_scan(mesh_pipe4):
     cfg = _cfg()
     params = transformer.init_params(cfg, jax.random.key(0))
@@ -118,7 +105,6 @@ def test_pipeline_grads_match_scan(mesh_pipe4):
         )
 
 
-@requires_modern_shard_map
 def test_pipeline_train_step_runs_and_matches(mesh_pipe4):
     """Full sharded train step under 2-data x 4-pipe == single-device step."""
     tiny = get_preset("tiny")
@@ -150,7 +136,6 @@ def test_pipeline_train_step_runs_and_matches(mesh_pipe4):
     assert int(jax.device_get(sharded["step"])) == 1
 
 
-@requires_modern_shard_map
 def test_pipeline_with_moe_aux(mesh_pipe4):
     """PP composes with MoE: aux loss flows out of the manual region."""
     cfg = _cfg(n_experts=2, experts_per_token=1, expert_capacity_factor=4.0)
@@ -186,7 +171,6 @@ def test_schedule_is_minimal_gpipe_and_bubble_shrinks_with_microbatches():
 
 
 @pytest.mark.parametrize("interleave,n_layers", [(2, 8), (2, 16), (4, 16)])
-@requires_modern_shard_map
 def test_interleaved_pipeline_matches_scan(mesh_pipe4, interleave, n_layers):
     """Interleaved virtual stages are a schedule, not a different computation:
     forward and gradients must match the plain scanned model. 4 stages x V
@@ -261,7 +245,6 @@ def mesh_pp_tp() -> Mesh:
     return Mesh(devs, ("data", "fsdp", "tensor", "seq", "expert", "pipe"))
 
 
-@requires_modern_shard_map
 def test_pipeline_composes_with_tensor_parallel(mesh_pp_tp):
     """PP x TP x DP: the pipe region is manual over 'pipe' only, so stage
     weights keep their tensor specs (GSPMD inserts the TP collectives inside
@@ -305,7 +288,6 @@ def test_pipeline_composes_with_tensor_parallel(mesh_pp_tp):
 
 
 @pytest.mark.parametrize("axis", ["fsdp", "expert"])
-@requires_modern_shard_map
 def test_pipeline_composes_with_fsdp_and_ep(axis):
     """PP x FSDP and PP x EP: stage weights keep their fsdp/expert specs
     under the partial-manual pipe region and match single-device."""
@@ -355,7 +337,6 @@ def test_pipeline_composes_with_fsdp_and_ep(axis):
     )
 
 
-@requires_modern_shard_map
 def test_baked_layout_roundtrip_and_step_equivalence(mesh_pipe4):
     """VERDICT r2 #5: the interleaved layout is baked into the train state
     (no per-step cross-rank reshard). bake -> unbake is the identity, the
