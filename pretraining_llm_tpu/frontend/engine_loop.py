@@ -26,10 +26,12 @@ Terminal statuses mirror the HTTP story: ``done`` (200), ``cancelled``
 from __future__ import annotations
 
 import dataclasses
+import logging
 import queue
 import threading
 import time
 import warnings
+from collections import deque
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 from pretraining_llm_tpu.frontend.admission import (
@@ -42,6 +44,9 @@ from pretraining_llm_tpu.observability.capacity import (
     CapacitySampler,
     DecisionLog,
 )
+from pretraining_llm_tpu.observability import spans as _spans
+
+_log = logging.getLogger("pretraining_llm_tpu.serving")
 
 TERMINAL_STATUSES = ("done", "cancelled", "expired", "error")
 
@@ -307,6 +312,10 @@ class EngineLoop:
         self.counters: Dict[str, int] = {
             "submitted": 0, "completed": 0, "cancelled": 0, "expired": 0,
             "errors": 0, "tokens_streamed": 0,
+            # Turns whose time outside the engine's tick and the idle wait
+            # (inbox, cancels and deadlines, the loop itself) the slow-tick
+            # rule logged: a pause between two scheduler turns.
+            "slow_turns": 0,
         }
 
     # -- public API (any thread) -------------------------------------------
@@ -714,26 +723,55 @@ class EngineLoop:
             from pretraining_llm_tpu.resilience.integrity import weight_fingerprint
             self.weight_fingerprint0 = weight_fingerprint(eng.params)
             self.weight_fingerprint = self.weight_fingerprint0
+        # The turn's own account: seconds by phase, and against the last turns'
+        # time outside the engine's tick and the idle wait, a pause is judged.
+        clock = _spans.PhaseClock(
+            {p: 0.0 for p in ("inbox", "deadlines", "tick", "idle_wait", "other")}
+        )
+        overheads: deque = deque(maxlen=_spans.SLOW_HISTORY)
+        turn = 0
         try:
             while True:
-                self._wake.clear()
-                self._drain_control()
-                self._drain_inbox()
-                self._apply_cancels_and_deadlines()
-                if self._stop.is_set():
-                    break
-                busy = False
-                if eng.has_work() or eng._inflight:
-                    busy = eng.pipeline_tick()
-                    # A long window may have carried requests past their
-                    # deadlines; apply before the next dispatch extends them.
-                    self._apply_cancels_and_deadlines()
-                self._last_turn = self._clock()
-                if fp_interval > 0 and self._clock() - last_fp >= fp_interval:
-                    self.weight_fingerprint = weight_fingerprint(eng.params)
-                    last_fp = self._clock()
-                if not busy and self._inbox.empty() and not self._stop.is_set():
-                    self._wake.wait(self.idle_wait_s)
+                turn += 1
+                before = dict(clock.acc)
+                with clock.span("other", "loop.turn"):
+                    self._wake.clear()
+                    with clock.span("inbox", "loop.inbox"):
+                        self._drain_control()
+                        self._drain_inbox()
+                    with clock.span("deadlines", "loop.deadlines"):
+                        self._apply_cancels_and_deadlines()
+                    if self._stop.is_set():
+                        break
+                    busy = False
+                    if eng.has_work() or eng._inflight:
+                        with clock.span("tick"):
+                            busy = eng.pipeline_tick()
+                        # A long window may have carried requests past their
+                        # deadlines; apply before the next dispatch extends them.
+                        with clock.span("deadlines", "loop.deadlines"):
+                            self._apply_cancels_and_deadlines()
+                    self._last_turn = self._clock()
+                    if fp_interval > 0 and self._clock() - last_fp >= fp_interval:
+                        self.weight_fingerprint = weight_fingerprint(eng.params)
+                        last_fp = self._clock()
+                    if not busy and self._inbox.empty() and not self._stop.is_set():
+                        with clock.span("idle_wait", "loop.idle_wait"):
+                            self._wake.wait(self.idle_wait_s)
+                split = {
+                    k: clock.acc[k] - before[k] for k in ("inbox", "deadlines", "other")
+                }
+                overhead = sum(split.values())
+                slow = _spans.slow_factor(overhead, overheads)
+                if slow:
+                    with self._lock:
+                        self.counters["slow_turns"] += 1
+                    _log.warning(
+                        "slow turn %d of the engine loop: %.3f s outside the engine's tick "
+                        "and the idle wait, the last %d turns' median times %.0f: %s",
+                        turn, overhead, len(overheads), slow, _spans.format_split(split),
+                    )
+                overheads.append(overhead)
         except BaseException as e:
             failure = e
             self.failure = e

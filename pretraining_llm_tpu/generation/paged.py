@@ -161,14 +161,15 @@ def _scatter_staged_pages(
             )
         return out
 
-    if "layers" in pools:
-        return {
-            "layers": tuple(
-                _fields(pools["layers"][layer], lambda buf, _l=layer: buf[_l])
-                for layer in range(len(pools["layers"]))
-            )
-        }
-    return _fields(pools, lambda buf: buf)
+    with jax.named_scope("attn.kv_write"):
+        if "layers" in pools:
+            return {
+                "layers": tuple(
+                    _fields(pools["layers"][layer], lambda buf, _l=layer: buf[_l])
+                    for layer in range(len(pools["layers"]))
+                )
+            }
+        return _fields(pools, lambda buf: buf)
 
 
 @functools.partial(jax.jit, static_argnames=("n_pages",), donate_argnums=(0,))

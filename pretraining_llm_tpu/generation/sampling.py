@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("sample")
 def sample_logits(
     logits: jax.Array,
     key: jax.Array,
@@ -109,6 +110,7 @@ def sample_logits_fused(
     )
     if logprobs_k <= 0:
         return tokens, None
-    lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    vals, ids = jax.lax.top_k(lp, logprobs_k)
-    return tokens, (vals, ids.astype(jnp.int32))
+    with jax.named_scope("sample"):
+        lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        vals, ids = jax.lax.top_k(lp, logprobs_k)
+        return tokens, (vals, ids.astype(jnp.int32))

@@ -39,6 +39,7 @@ surplus tokens are discarded at reap by the snapshot identity check.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import time
 import warnings
 from collections import deque
@@ -53,6 +54,15 @@ from pretraining_llm_tpu.generation import paged, speculative
 from pretraining_llm_tpu.generation import prefix_cache as prefix_cache_mod
 from pretraining_llm_tpu.models import transformer
 from pretraining_llm_tpu.observability import spans as _spans
+
+_log = logging.getLogger("pretraining_llm_tpu.serving")
+
+# Where a scheduler turn's host time goes; stats["phase_s"] keeps one running
+# total per phase. "other" is the turn's own bookkeeping between the rest.
+PHASES = (
+    "admit", "prefill_dispatch", "ensure_pages", "dispatch", "host_blocked",
+    "commit", "other",
+)
 
 
 @dataclasses.dataclass
@@ -475,7 +485,18 @@ class ServingEngine:
             # to the host per decode step. Stays 0 with fused sampling
             # (the default) — the transfer the fused path deletes.
             "logits_bytes_host": 0,
+            # Always-on account of the scheduler's host time (PHASES), so an
+            # untraced run can say which call stood still: running seconds
+            # per phase ("host_blocked" is host_blocked_s), pipeline ticks
+            # run, ticks the slow-tick rule logged, and the longest tick
+            # with its own phase split (a reader that wants a fresh account
+            # sets its "seconds" back to 0).
+            "phase_s": {p: 0.0 for p in PHASES},
+            "ticks": 0, "slow_ticks": 0,
+            "longest_tick": {"tick": 0, "seconds": 0.0, "phase_s": {}},
         }
+        self._clock = _spans.PhaseClock(self.stats["phase_s"])
+        self._tick_hist: deque = deque(maxlen=_spans.SLOW_HISTORY)
         # Cross-request prefix cache: content-addressed page reuse over
         # the allocator (generation/prefix_cache.py). Off by default —
         # when on, greedy outputs stay bit-identical to cache-off runs
@@ -938,56 +959,81 @@ class ServingEngine:
         BETWEEN scheduler turns of a long-lived engine. Returns True
         while device work remains dispatched or runnable (False = the
         engine is fully idle)."""
-        depth = self.pipeline_depth
-        self._admit(defer=True)
-        # Chunked prefill rides BEFORE the decode dispatch: its writes
-        # are committed prompt data (earlier in device program order than
-        # this tick's window), and the token budget bounds the prefill
-        # work a decode window ever waits behind — the TPOT protection.
-        chunked = self._dispatch_prefill_chunks(defer=True)
-        decoded = False
-        if self._n_decode_rows():
-            if self.spec_k:
-                # Worst case every queued round and the new one
-                # advance the device frontier by k+1 past the
-                # committed seq_lens — pre-ensure the whole horizon
-                # so no flush can land between dispatch and reap.
-                k = self.spec_k
-                self._ensure_write_pages(
-                    horizon=(k + 1) * (len(self._inflight) + 1)
+        before = dict(self.stats["phase_s"])
+        with self._clock.span("other", "serving.tick") as tick:
+            depth = self.pipeline_depth
+            self._admit(defer=True)
+            # Chunked prefill rides BEFORE the decode dispatch: its writes
+            # are committed prompt data (earlier in device program order than
+            # this tick's window), and the token budget bounds the prefill
+            # work a decode window ever waits behind — the TPOT protection.
+            chunked = self._dispatch_prefill_chunks(defer=True)
+            decoded = False
+            if self._n_decode_rows():
+                if self.spec_k:
+                    # Worst case every queued round and the new one
+                    # advance the device frontier by k+1 past the
+                    # committed seq_lens — pre-ensure the whole horizon
+                    # so no flush can land between dispatch and reap.
+                    k = self.spec_k
+                    self._ensure_write_pages(
+                        horizon=(k + 1) * (len(self._inflight) + 1)
+                    )
+                    if self._n_decode_rows():
+                        self._dispatch_spec_round()
+                        decoded = True
+                else:
+                    n = self._window_len()
+                    # ONE window length for both the page horizon and the
+                    # dispatch: ensure_write_pages may flush/preempt
+                    # (which only shrinks the remaining budget), and a
+                    # dispatch longer than the ensured horizon would
+                    # scratch-redirect live writes — computing n once
+                    # makes that impossible by construction. ``prealloc``
+                    # opportunistically extends rows toward the full
+                    # in-flight horizon (n * depth slots) from the free
+                    # list, so later dispatches rarely need new pages at
+                    # all — a page flush between an already-dispatched
+                    # window and its reap becomes the exception.
+                    self._ensure_write_pages(
+                        horizon=n, prealloc=n * (depth - 1)
+                    )
+                    if self._n_decode_rows():
+                        self._dispatch_window(n)
+                        decoded = True
+            if chunked:
+                self._note_chunk_window(decoded)
+            # Reap the oldest window once the queue exceeds its depth —
+            # by then it has had `depth` windows of device time to finish,
+            # so the readback rarely blocks — and drain outright when
+            # nothing is running (end of stream, or everyone preempted).
+            while (len(self._inflight) > depth
+                   or (self._inflight and not self.n_active)):
+                self._reap_window(self._inflight.popleft())
+            busy = bool(self._inflight) or self.has_work()
+        self._account_tick(tick.t1 - tick.t0, before)
+        return busy
+
+    def _account_tick(self, seconds: float, before: Dict[str, float]) -> None:
+        """Keep the longest tick's phase split; log a tick that stood still."""
+        st = self.stats
+        st["ticks"] += 1
+        longest = seconds > st["longest_tick"]["seconds"]
+        slow = _spans.slow_factor(seconds, self._tick_hist)
+        if longest or slow:
+            split = {k: v - before[k] for k, v in st["phase_s"].items()}
+            if longest:
+                st["longest_tick"] = {
+                    "tick": st["ticks"], "seconds": seconds, "phase_s": split,
+                }
+            if slow:
+                st["slow_ticks"] += 1
+                _log.warning(
+                    "slow tick %d: %.3f s, the last %d ticks' median times %.0f: %s",
+                    st["ticks"], seconds, len(self._tick_hist), slow,
+                    _spans.format_split(split),
                 )
-                if self._n_decode_rows():
-                    self._dispatch_spec_round()
-                    decoded = True
-            else:
-                n = self._window_len()
-                # ONE window length for both the page horizon and the
-                # dispatch: ensure_write_pages may flush/preempt
-                # (which only shrinks the remaining budget), and a
-                # dispatch longer than the ensured horizon would
-                # scratch-redirect live writes — computing n once
-                # makes that impossible by construction. ``prealloc``
-                # opportunistically extends rows toward the full
-                # in-flight horizon (n * depth slots) from the free
-                # list, so later dispatches rarely need new pages at
-                # all — a page flush between an already-dispatched
-                # window and its reap becomes the exception.
-                self._ensure_write_pages(
-                    horizon=n, prealloc=n * (depth - 1)
-                )
-                if self._n_decode_rows():
-                    self._dispatch_window(n)
-                    decoded = True
-        if chunked:
-            self._note_chunk_window(decoded)
-        # Reap the oldest window once the queue exceeds its depth —
-        # by then it has had `depth` windows of device time to finish,
-        # so the readback rarely blocks — and drain outright when
-        # nothing is running (end of stream, or everyone preempted).
-        while (len(self._inflight) > depth
-               or (self._inflight and not self.n_active)):
-            self._reap_window(self._inflight.popleft())
-        return bool(self._inflight) or self.has_work()
+        self._tick_hist.append(seconds)
 
     def _dispatch_window(self, n: int) -> None:
         """Enqueue one ``steps_per_sched``-step decode window WITHOUT
@@ -1016,7 +1062,10 @@ class ServingEngine:
         paged.check_paged_bounds(
             self.tables[active], seq_dispatch[active], self.block_size
         )
-        with _spans.span("serving.dispatch_window", steps=n):
+        with self._clock.span(
+            "dispatch", "serving.dispatch_window",
+            steps=n, window=self.stats["windows"],
+        ):
             if self._inflight:
                 base = self._inflight[-1].toks[:, -1]  # (B,) device, no sync
             else:
@@ -1061,7 +1110,10 @@ class ServingEngine:
         paged.check_paged_bounds(
             self.tables[active], seq_committed[active], self.block_size
         )
-        with _spans.span("serving.dispatch_window", steps=k + 1):
+        with self._clock.span(
+            "dispatch", "serving.dispatch_window",
+            steps=k + 1, window=self.stats["windows"],
+        ):
             if self._inflight:
                 prev = self._inflight[-1]
                 base, seq_dev = speculative.spec_next_inputs(
@@ -1112,9 +1164,8 @@ class ServingEngine:
         host-blocked time deep pipelining exists to hide — measured per
         window into stats and the span's trace args."""
         widx = self.stats["windows_reaped"]
-        with _spans.span("serving.reap_window", window=widx) as meta:
-            t0 = time.perf_counter()
-            with _spans.span("serving.host_blocked"):
+        with _spans.span("serving.reap_window", window=widx) as reap:
+            with self._clock.span("host_blocked", "serving.host_blocked") as wait:
                 if w.kind == "spec":
                     emit = np.asarray(w.emit)      # (B, k+1) — THE sync point
                     n_emit = np.asarray(w.n_emit)  # (B,)
@@ -1123,9 +1174,9 @@ class ServingEngine:
                     lp_host = None
                     if w.lp is not None:
                         lp_host = (np.asarray(w.lp[0]), np.asarray(w.lp[1]))
-            t_reaped = time.perf_counter()
+            t0, t_reaped = wait.t0, wait.t1
             blocked = t_reaped - t0
-            meta["host_blocked_s"] = round(blocked, 6)
+            reap.set(host_blocked_s=round(blocked, 6))
             self.stats["host_blocked_s"] += blocked
             self.stats["windows_reaped"] += 1
             if self.window_hist is not None and w.t_dispatch:
@@ -1134,56 +1185,57 @@ class ServingEngine:
                 self.host_blocked_hist.observe(blocked)
             capacity = self.max_blocks * self.block_size
             toks_before = self.stats["tokens"]
-            for row, req in w.snapshot:
-                if req.row != row or self.rows[row] is not req:
-                    # The row finished in an earlier reap and may have
-                    # been re-admitted since; this window's tokens for it
-                    # are surplus garbage by the lag contract. (Preemption
-                    # can't land here: it flushes the queue first.)
-                    continue
-                if self.traces:
-                    tr = self.traces.get(req.rid)
-                    if tr is not None and not tr.finished:
-                        # One span per (request, window) it rode: dispatch
-                        # -> reap. Under deep pipelining these intervals
-                        # OVERLAP across windows; the SLO decomposition
-                        # unions them into decode time. host_blocked_s is
-                        # the whole window's readback wait — per request
-                        # it reads as "this much of my window was the
-                        # host, not the device".
-                        tr.span(
-                            "req.window",
-                            w.t_dispatch or t0, t_reaped,
-                            kind=w.kind, steps=w.n, window=widx,
-                            host_blocked_s=round(blocked, 6),
+            with self._clock.span("commit", "serving.commit", rows=len(w.snapshot)):
+                for row, req in w.snapshot:
+                    if req.row != row or self.rows[row] is not req:
+                        # The row finished in an earlier reap and may have
+                        # been re-admitted since; this window's tokens for it
+                        # are surplus garbage by the lag contract. (Preemption
+                        # can't land here: it flushes the queue first.)
+                        continue
+                    if self.traces:
+                        tr = self.traces.get(req.rid)
+                        if tr is not None and not tr.finished:
+                            # One span per (request, window) it rode: dispatch
+                            # -> reap. Under deep pipelining these intervals
+                            # OVERLAP across windows; the SLO decomposition
+                            # unions them into decode time. host_blocked_s is
+                            # the whole window's readback wait — per request
+                            # it reads as "this much of my window was the
+                            # host, not the device".
+                            tr.span(
+                                "req.window",
+                                w.t_dispatch or t0, t_reaped,
+                                kind=w.kind, steps=w.n, window=widx,
+                                host_blocked_s=round(blocked, 6),
+                            )
+                    self._resolve_first(req)
+                    if req.row is None:  # first token alone finished it
+                        continue
+                    if w.kind == "spec":
+                        # Commit the round's data-dependent advance. Proposal/
+                        # acceptance telemetry counts here (not at dispatch)
+                        # so surplus rounds for finished rows skew neither
+                        # side of the hit rate.
+                        ne = int(n_emit[row])
+                        self.seq_lens[row] = min(
+                            int(self.seq_lens[row]) + ne, capacity
                         )
-                self._resolve_first(req)
-                if req.row is None:  # first token alone finished it
-                    continue
-                if w.kind == "spec":
-                    # Commit the round's data-dependent advance. Proposal/
-                    # acceptance telemetry counts here (not at dispatch)
-                    # so surplus rounds for finished rows skew neither
-                    # side of the hit rate.
-                    ne = int(n_emit[row])
-                    self.seq_lens[row] = min(
-                        int(self.seq_lens[row]) + ne, capacity
-                    )
-                    self.stats["spec_proposed"] = (
-                        self.stats.get("spec_proposed", 0) + self.spec_k
-                    )
-                    self.stats["spec_accepted"] = (
-                        self.stats.get("spec_accepted", 0) + ne - 1
-                    )
-                    self._consume_tokens(
-                        req, row, emit[row, :ne], advance_seq=False
-                    )
-                else:
-                    self._consume_tokens(
-                        req, row, window[row], advance_seq=False,
-                        lp=None if lp_host is None
-                        else (lp_host[0][row], lp_host[1][row]),
-                    )
+                        self.stats["spec_proposed"] = (
+                            self.stats.get("spec_proposed", 0) + self.spec_k
+                        )
+                        self.stats["spec_accepted"] = (
+                            self.stats.get("spec_accepted", 0) + ne - 1
+                        )
+                        self._consume_tokens(
+                            req, row, emit[row, :ne], advance_seq=False
+                        )
+                    else:
+                        self._consume_tokens(
+                            req, row, window[row], advance_seq=False,
+                            lp=None if lp_host is None
+                            else (lp_host[0][row], lp_host[1][row]),
+                        )
             if self.capacity is not None:
                 # Occupancy sample AT the reap sync point: every value is
                 # host state this method already touched (row snapshot,
@@ -1454,227 +1506,237 @@ class ServingEngine:
         each) into one larger prefill at the boundary where rows/pages
         free up. Greedy outputs are unaffected: a request's tokens depend
         only on its own prompt, never on when it was admitted."""
-        if defer and self.admit_batch > 1 and self.waiting and self.n_active:
-            goal = min(self.admit_batch, len(self.waiting), self.max_batch)
-            if self._admission_capacity() < goal:
-                self.stats["admit_deferrals"] = (
-                    self.stats.get("admit_deferrals", 0) + 1
+        if not self.waiting:
+            return
+        # The span covers every turn in which somebody waits: one that admits,
+        # one that stalls at the watermark, one that defers.
+        with self._clock.span("admit", "serving.admit") as span:
+            if defer and self.admit_batch > 1 and self.waiting and self.n_active:
+                goal = min(self.admit_batch, len(self.waiting), self.max_batch)
+                if self._admission_capacity() < goal:
+                    self.stats["admit_deferrals"] = (
+                        self.stats.get("admit_deferrals", 0) + 1
+                    )
+                    return
+                self.stats["admit_batches"] = (
+                    self.stats.get("admit_batches", 0) + 1
                 )
-                return
-            self.stats["admit_batches"] = (
-                self.stats.get("admit_batches", 0) + 1
-            )
-        admits: List[_Request] = []
-        while self.waiting:
-            free_rows = [i for i, r in enumerate(self.rows) if r is None]
-            if not free_rows:
-                break
-            req: _Request = self.waiting[0]
-            p = len(req.prompt)
-            # +1: the first decode step writes slot p — its page must exist.
-            need = paged.required_blocks(p + 1, self.block_size)
-            # Prefix-cache lookup: retain the longest cached block-aligned
-            # prefix and charge admission only for the uncached remainder.
-            cached_len = 0
-            shared: List[int] = []
-            t_lookup = t_hit = 0.0
-            if self.prefix_cache is not None:
-                t_lookup = time.perf_counter()
-                cached_len, shared = self.prefix_cache.acquire(req.prompt)
-                if self.kv_checksum and shared:
-                    cached_len, shared = self._verify_shared(
-                        req, cached_len, shared
-                    )
-                t_hit = time.perf_counter()
-            need_new = need - len(shared)
-            # Admission watermark — where head-of-line admission stalls:
-            # keep one growth block of headroom per already-running row,
-            # else a nearly-dry pool admits + pays a full prefill only for
-            # the newcomer to be preempted at the next older-row block
-            # boundary (prefill thrash). The stalled head waits for active
-            # rows to finish and free blocks; preemption happens on growth.
-            # Cold cached blocks count as available — the LRU hands them
-            # back before any live request is preempted.
-            if self._cache_available() - need_new < self.n_active:
-                if shared:
-                    self.prefix_cache.release_shared(shared)
-                break
-            blocks = self._cache_alloc(need_new)
-            assert blocks is not None, "watermark guarantees coverage"
-            self.waiting.popleft()
-            row = free_rows[0]
-            req.blocks = shared + blocks
-            req.n_shared = len(shared)
-            req.row = row
-            if self.prefix_cache is not None:
-                # Counted only for COMMITTED admissions, so a stalled head
-                # retried at every boundary cannot inflate the hit rate.
-                if cached_len:
-                    self.prefix_cache.note_hit(cached_len)
-                else:
-                    self.prefix_cache.note_miss()
-            req.admit_order = self._admit_counter
-            self._admit_counter += 1
-            self.stats["admissions"] += 1
-            if not self.prefill_chunk_tokens:
-                # Chunked mode counts prefill (and recompute rework) at
-                # chunk DISPATCH — where the tokens are actually paid —
-                # so a mid-prefill cancellation never inflates either.
-                self.stats["prefill_tokens"] += p - cached_len
-                if req.preemptions > 0:
-                    # Recompute-on-resume rework, counted where it is
-                    # actually PAID: the re-admission's prefill (a cache
-                    # hit on the victim's own published pages shrinks it).
-                    self.stats["preempted_tokens_recomputed"] = (
-                        self.stats.get("preempted_tokens_recomputed", 0)
-                        + p - cached_len
-                    )
-                    if self.preempt_tokens_counter is not None:
-                        self.preempt_tokens_counter.inc(p - cached_len)
-            t = self.req_timing.get(req.rid)
-            if t is not None:
-                # setdefault: a preempted request's re-admission must not
-                # move its queue-wait mark.
-                t.setdefault("admit_s", self._now())
+            admits: List[_Request] = []
+            while self.waiting:
+                free_rows = [i for i, r in enumerate(self.rows) if r is None]
+                if not free_rows:
+                    break
+                req: _Request = self.waiting[0]
+                p = len(req.prompt)
+                # +1: the first decode step writes slot p — its page must exist.
+                need = paged.required_blocks(p + 1, self.block_size)
+                # Prefix-cache lookup: retain the longest cached block-aligned
+                # prefix and charge admission only for the uncached remainder.
+                cached_len = 0
+                shared: List[int] = []
+                t_lookup = t_hit = 0.0
                 if self.prefix_cache is not None:
-                    # Accumulates: a preemption-resume hit on just-published
-                    # pages adds its savings on top of the first admission's.
-                    # Cache off -> key absent, so timing summaries (and the
-                    # JSONL/body schemas built from them) are unchanged.
-                    t["cached_tokens"] = t.get("cached_tokens", 0) + cached_len
-            if self.traces:
-                tr = self.traces.get(req.rid)
-                if tr is not None:
+                    t_lookup = time.perf_counter()
+                    cached_len, shared = self.prefix_cache.acquire(req.prompt)
+                    if self.kv_checksum and shared:
+                        cached_len, shared = self._verify_shared(
+                            req, cached_len, shared
+                        )
+                    t_hit = time.perf_counter()
+                need_new = need - len(shared)
+                # Admission watermark — where head-of-line admission stalls:
+                # keep one growth block of headroom per already-running row,
+                # else a nearly-dry pool admits + pays a full prefill only for
+                # the newcomer to be preempted at the next older-row block
+                # boundary (prefill thrash). The stalled head waits for active
+                # rows to finish and free blocks; preemption happens on growth.
+                # Cold cached blocks count as available — the LRU hands them
+                # back before any live request is preempted.
+                if self._cache_available() - need_new < self.n_active:
+                    if shared:
+                        self.prefix_cache.release_shared(shared)
+                    break
+                blocks = self._cache_alloc(need_new)
+                assert blocks is not None, "watermark guarantees coverage"
+                self.waiting.popleft()
+                row = free_rows[0]
+                req.blocks = shared + blocks
+                req.n_shared = len(shared)
+                req.row = row
+                if self.prefix_cache is not None:
+                    # Counted only for COMMITTED admissions, so a stalled head
+                    # retried at every boundary cannot inflate the hit rate.
+                    if cached_len:
+                        self.prefix_cache.note_hit(cached_len)
+                    else:
+                        self.prefix_cache.note_miss()
+                req.admit_order = self._admit_counter
+                self._admit_counter += 1
+                self.stats["admissions"] += 1
+                if not self.prefill_chunk_tokens:
+                    # Chunked mode counts prefill (and recompute rework) at
+                    # chunk DISPATCH — where the tokens are actually paid —
+                    # so a mid-prefill cancellation never inflates either.
+                    self.stats["prefill_tokens"] += p - cached_len
+                    if req.preemptions > 0:
+                        # Recompute-on-resume rework, counted where it is
+                        # actually PAID: the re-admission's prefill (a cache
+                        # hit on the victim's own published pages shrinks it).
+                        self.stats["preempted_tokens_recomputed"] = (
+                            self.stats.get("preempted_tokens_recomputed", 0)
+                            + p - cached_len
+                        )
+                        if self.preempt_tokens_counter is not None:
+                            self.preempt_tokens_counter.inc(p - cached_len)
+                t = self.req_timing.get(req.rid)
+                if t is not None:
+                    # setdefault: a preempted request's re-admission must not
+                    # move its queue-wait mark.
+                    t.setdefault("admit_s", self._now())
                     if self.prefix_cache is not None:
-                        # Recorded only for COMMITTED admissions (stalled
-                        # heads would otherwise stack duplicate spans).
-                        tr.span(
-                            "prefix_cache.lookup", t_lookup, t_hit,
-                            cached_tokens=cached_len, blocks=len(shared),
-                        )
-                    if "admit" not in tr.marks:
-                        # Same setdefault rule: the queue span is submit ->
-                        # FIRST row claim; preemption re-admissions keep it.
-                        now_p = time.perf_counter()
-                        tr.span(
-                            "req.queue", tr.marks.get("submit", tr.t0), now_p,
-                            n_prompt=p,
-                        )
-                        tr.marks["admit"] = now_p
-            self.rows[row] = req  # claim now: n_active sees earlier admits
-            self.tables[row, :] = 0
-            self.tables[row, : len(req.blocks)] = req.blocks
+                        # Accumulates: a preemption-resume hit on just-published
+                        # pages adds its savings on top of the first admission's.
+                        # Cache off -> key absent, so timing summaries (and the
+                        # JSONL/body schemas built from them) are unchanged.
+                        t["cached_tokens"] = t.get("cached_tokens", 0) + cached_len
+                if self.traces:
+                    tr = self.traces.get(req.rid)
+                    if tr is not None:
+                        if self.prefix_cache is not None:
+                            # Recorded only for COMMITTED admissions (stalled
+                            # heads would otherwise stack duplicate spans).
+                            tr.span(
+                                "prefix_cache.lookup", t_lookup, t_hit,
+                                cached_tokens=cached_len, blocks=len(shared),
+                            )
+                        if "admit" not in tr.marks:
+                            # Same setdefault rule: the queue span is submit ->
+                            # FIRST row claim; preemption re-admissions keep it.
+                            now_p = time.perf_counter()
+                            tr.span(
+                                "req.queue", tr.marks.get("submit", tr.t0), now_p,
+                                n_prompt=p,
+                            )
+                            tr.marks["admit"] = now_p
+                self.rows[row] = req  # claim now: n_active sees earlier admits
+                self.tables[row, :] = 0
+                self.tables[row, : len(req.blocks)] = req.blocks
+                if self.prefill_chunk_tokens:
+                    # Chunked admission: claim the row and ALL its blocks
+                    # (same watermark math — the allocation is identical),
+                    # but run NO prefill here. The committed frontier starts
+                    # at the cached prefix; _dispatch_prefill_chunks streams
+                    # the rest in budgeted chunks, cache hits riding the
+                    # same lane with a head start.
+                    req.prefill_pos = cached_len
+                    self.seq_lens[row] = cached_len
+                else:
+                    self.seq_lens[row] = p
+                admits.append(req)
+            span.set(rows=len(admits), prompt_tokens=sum(len(r.prompt) for r in admits))
+            if not admits:
+                return
             if self.prefill_chunk_tokens:
-                # Chunked admission: claim the row and ALL its blocks
-                # (same watermark math — the allocation is identical),
-                # but run NO prefill here. The committed frontier starts
-                # at the cached prefix; _dispatch_prefill_chunks streams
-                # the rest in budgeted chunks, cache hits riding the
-                # same lane with a head start.
-                req.prefill_pos = cached_len
-                self.seq_lens[row] = cached_len
-            else:
-                self.seq_lens[row] = p
-            admits.append(req)
-        if not admits:
-            return
-        if self.prefill_chunk_tokens:
-            return  # prompts stream in via _dispatch_prefill_chunks
-        # Cache hits prefill ONLY their uncached suffix (shared pages are
-        # already in the table; PagedInfo seq = cached length), misses run
-        # the full prefill — one batched program per non-empty group.
-        miss = [r for r in admits if r.n_shared == 0]
-        hits = [r for r in admits if r.n_shared > 0]
-        if miss and self.quantize == "int8-kv":
-            # Quantized-pool bit-identity: the monolithic lane's dense
-            # flash-prefill shortcut attends the UNQUANTIZED local k/v,
-            # while the suffix lane attends dequantized pool pages — the
-            # two would commit DIFFERENT quantized bytes for the same
-            # prompt, breaking identity across prefix-cache/chunked
-            # configurations. Route every admission through the suffix
-            # lane (cached_len 0 = full prompt) so page bytes are always
-            # the same pure function of the token's prompt prefix.
-            hits = miss + hits
-            miss = []
-        t_prefill = time.perf_counter()
-        groups: List[Tuple[List[_Request], jax.Array]] = []
-        if miss:
-            self._key, sub = jax.random.split(self._key)
-            prompts = [r.prompt for r in miss]
-            prefill_ids = [
-                r.blocks[: paged.required_blocks(len(r.prompt), self.block_size)]
-                for r in miss
-            ]
-            toks_dev, self.pools = paged.prefill_into_pool_batched(
-                self.params, self.cfg, self.pools, prompts, prefill_ids,
-                sub, temperature=self.temperature, top_k=self.top_k,
-                top_p=self.top_p, min_p=self.min_p, mesh=self.mesh,
-            )
-            if self.spec_k:
-                # The draft cache must cover the same pages (its sampled
-                # tokens are discarded — the target's first token above is
-                # the round seed either way).
-                _, self.d_pools = paged.prefill_into_pool_batched(
-                    self.draft_params, self.draft_cfg, self.d_pools, prompts,
-                    prefill_ids, sub, temperature=self.temperature,
-                    mesh=self.mesh,
-                )
-            groups.append((miss, toks_dev))
-        if hits:
-            self._key, sub = jax.random.split(self._key)
-            bs = self.block_size
-            suffixes = [r.prompt[r.n_shared * bs:] for r in hits]
-            tables_rows = self.tables[np.asarray([r.row for r in hits])]
-            cached_lens = [r.n_shared * bs for r in hits]
-            toks_dev, self.pools = paged.prefill_suffix_into_pool_batched(
-                self.params, self.cfg, self.pools, suffixes, tables_rows,
-                cached_lens, sub, temperature=self.temperature,
-                top_k=self.top_k, top_p=self.top_p, min_p=self.min_p,
-                mesh=self.mesh,
-            )
-            if self.spec_k:
-                # Shared block ids index BOTH pools, so the draft's prefix
-                # KV is already resident too — suffix-only there as well.
-                _, self.d_pools = paged.prefill_suffix_into_pool_batched(
-                    self.draft_params, self.draft_cfg, self.d_pools,
-                    suffixes, tables_rows, cached_lens, sub,
-                    temperature=self.temperature, mesh=self.mesh,
-                )
-            groups.append((hits, toks_dev))
-        if self.traces:
-            # Host-side prefill span (dispatch + any compile; the async
-            # device compute itself overlaps the next windows). Batched
-            # admissions share one interval — the per-request cost of a
-            # shared program IS the shared wall time.
-            t_prefill_end = time.perf_counter()
-            for req in admits:
-                tr = self.traces.get(req.rid)
-                if tr is not None:
-                    tr.span(
-                        "req.prefill", t_prefill, t_prefill_end,
-                        n_prompt=len(req.prompt), batch=len(admits),
+                return  # prompts stream in via _dispatch_prefill_chunks
+            # Cache hits prefill ONLY their uncached suffix (shared pages are
+            # already in the table; PagedInfo seq = cached length), misses run
+            # the full prefill — one batched program per non-empty group.
+            miss = [r for r in admits if r.n_shared == 0]
+            hits = [r for r in admits if r.n_shared > 0]
+            if miss and self.quantize == "int8-kv":
+                # Quantized-pool bit-identity: the monolithic lane's dense
+                # flash-prefill shortcut attends the UNQUANTIZED local k/v,
+                # while the suffix lane attends dequantized pool pages — the
+                # two would commit DIFFERENT quantized bytes for the same
+                # prompt, breaking identity across prefix-cache/chunked
+                # configurations. Route every admission through the suffix
+                # lane (cached_len 0 = full prompt) so page bytes are always
+                # the same pure function of the token's prompt prefix.
+                hits = miss + hits
+                miss = []
+            t_prefill = time.perf_counter()
+            groups: List[Tuple[List[_Request], jax.Array]] = []
+            with self._clock.span(
+                "prefill_dispatch", "serving.prefill_dispatch",
+                miss=len(miss), hits=len(hits),
+            ):
+                if miss:
+                    self._key, sub = jax.random.split(self._key)
+                    prompts = [r.prompt for r in miss]
+                    prefill_ids = [
+                        r.blocks[: paged.required_blocks(len(r.prompt), self.block_size)]
+                        for r in miss
+                    ]
+                    toks_dev, self.pools = paged.prefill_into_pool_batched(
+                        self.params, self.cfg, self.pools, prompts, prefill_ids,
+                        sub, temperature=self.temperature, top_k=self.top_k,
+                        top_p=self.top_p, min_p=self.min_p, mesh=self.mesh,
                     )
-        self.stats["tokens"] += len(admits)  # the prefill-sampled firsts
-        if defer:
+                    if self.spec_k:
+                        # The draft cache must cover the same pages (its sampled
+                        # tokens are discarded — the target's first token above is
+                        # the round seed either way).
+                        _, self.d_pools = paged.prefill_into_pool_batched(
+                            self.draft_params, self.draft_cfg, self.d_pools, prompts,
+                            prefill_ids, sub, temperature=self.temperature,
+                            mesh=self.mesh,
+                        )
+                    groups.append((miss, toks_dev))
+                if hits:
+                    self._key, sub = jax.random.split(self._key)
+                    bs = self.block_size
+                    suffixes = [r.prompt[r.n_shared * bs:] for r in hits]
+                    tables_rows = self.tables[np.asarray([r.row for r in hits])]
+                    cached_lens = [r.n_shared * bs for r in hits]
+                    toks_dev, self.pools = paged.prefill_suffix_into_pool_batched(
+                        self.params, self.cfg, self.pools, suffixes, tables_rows,
+                        cached_lens, sub, temperature=self.temperature,
+                        top_k=self.top_k, top_p=self.top_p, min_p=self.min_p,
+                        mesh=self.mesh,
+                    )
+                    if self.spec_k:
+                        # Shared block ids index BOTH pools, so the draft's prefix
+                        # KV is already resident too — suffix-only there as well.
+                        _, self.d_pools = paged.prefill_suffix_into_pool_batched(
+                            self.draft_params, self.draft_cfg, self.d_pools,
+                            suffixes, tables_rows, cached_lens, sub,
+                            temperature=self.temperature, mesh=self.mesh,
+                        )
+                    groups.append((hits, toks_dev))
+            if self.traces:
+                # Host-side prefill span (dispatch + any compile; the async
+                # device compute itself overlaps the next windows). Batched
+                # admissions share one interval — the per-request cost of a
+                # shared program IS the shared wall time.
+                t_prefill_end = time.perf_counter()
+                for req in admits:
+                    tr = self.traces.get(req.rid)
+                    if tr is not None:
+                        tr.span(
+                            "req.prefill", t_prefill, t_prefill_end,
+                            n_prompt=len(req.prompt), batch=len(admits),
+                        )
+            self.stats["tokens"] += len(admits)  # the prefill-sampled firsts
+            if defer:
+                for group, toks_dev in groups:
+                    for i, req in enumerate(group):
+                        req.pending_first = (toks_dev, i)
+                    # Next dispatch merges these device scalars into its input
+                    # tokens without a host round trip.
+                    self._pending_admit_merges.append(
+                        (toks_dev, list(range(len(group))), [r.row for r in group])
+                    )
+                return
             for group, toks_dev in groups:
+                toks = np.asarray(toks_dev)
                 for i, req in enumerate(group):
-                    req.pending_first = (toks_dev, i)
-                # Next dispatch merges these device scalars into its input
-                # tokens without a host round trip.
-                self._pending_admit_merges.append(
-                    (toks_dev, list(range(len(group))), [r.row for r in group])
-                )
-            return
-        for group, toks_dev in groups:
-            toks = np.asarray(toks_dev)
-            for i, req in enumerate(group):
-                tok = int(toks[i])
-                req.generated.append(tok)
-                self._lp_append(req, None)  # prefill-sampled: no sliver
-                self._emit_token(req, tok)
-                self.tokens[req.row] = tok
-                if tok == self.stop_token or len(req.generated) >= req.max_new:
-                    self._finish(req)
+                    tok = int(toks[i])
+                    req.generated.append(tok)
+                    self._lp_append(req, None)  # prefill-sampled: no sliver
+                    self._emit_token(req, tok)
+                    self.tokens[req.row] = tok
+                    if tok == self.stop_token or len(req.generated) >= req.max_new:
+                        self._finish(req)
 
     def _dispatch_prefill_chunks(self, defer: bool) -> bool:
         """Stream mid-prefill rows' next prompt chunks in ONE multi-token
@@ -1734,8 +1796,8 @@ class ServingEngine:
             finals.append(start + take == len(req.prompt))
             budget -= take
         t_chunk = time.perf_counter()
-        with _spans.span(
-            "serving.dispatch_chunks",
+        with self._clock.span(
+            "prefill_dispatch", "serving.dispatch_chunks",
             rows=len(group), tokens=sum(len(c) for c in chunks),
         ):
             self._key, sub = jax.random.split(self._key)
@@ -1845,59 +1907,60 @@ class ServingEngine:
         (window * depth), making a mid-queue page flush the exception;
         over-grants are speculative and rolled back at release,
         preemption, or by _reclaim_spec_pages under pressure."""
-        capacity = self.max_blocks * self.block_size
-        for row in range(self.max_batch):
-            req = self.rows[row]
-            if req is None:
-                continue
-            # n_generated may lag the device by the in-flight queue
-            # (pipelined mode): remaining is then an OVERestimate, so the
-            # horizon only ever covers extra slots — writes stay inside
-            # allocated (or scratch-redirected) pages either way.
-            remaining = req.max_new - req.n_generated
-            last_write = min(
-                int(self.seq_lens[row]) + min(horizon, remaining) - 1,
-                capacity - 1,
-            )
-            need_pages = last_write // self.block_size + 1
-            while len(req.blocks) < need_pages:
-                got = self.alloc.alloc(1)
-                if got is not None:
-                    req.blocks.extend(got)
-                    self.tables[row, len(req.blocks) - 1] = got[0]
+        with self._clock.span("ensure_pages", "serving.ensure_pages"):
+            capacity = self.max_blocks * self.block_size
+            for row in range(self.max_batch):
+                req = self.rows[row]
+                if req is None:
                     continue
-                if self._inflight:
-                    # Pool dry with windows in flight: drain them first —
-                    # their finished rows may free blocks, and preemption
-                    # bookkeeping (prompt+generated) must be exact.
-                    self._flush_inflight()
-                    if self.rows[row] is not req:
-                        break  # this row finished in the flush
-                    continue  # retry allocation against the fresh state
-                if self._reclaim_spec_pages(horizon):
-                    continue  # speculative grants rolled back; retry
-                if (
-                    self.prefix_cache is not None
-                    and self.prefix_cache.evict(1)
-                ):
-                    if self.decisions is not None:
-                        self.decisions.record(
-                            "evict_cold", blocks=1, reason="growth",
-                            rid=req.rid,
-                            trace_id=getattr(
-                                self.traces.get(req.rid), "trace_id", None
-                            ),
-                        )
-                    continue  # cold cache evicted BEFORE any preemption
-                victim = max(
-                    (r for r in self.rows if r is not None),
-                    key=lambda r: r.admit_order,
+                # n_generated may lag the device by the in-flight queue
+                # (pipelined mode): remaining is then an OVERestimate, so the
+                # horizon only ever covers extra slots — writes stay inside
+                # allocated (or scratch-redirected) pages either way.
+                remaining = req.max_new - req.n_generated
+                last_write = min(
+                    int(self.seq_lens[row]) + min(horizon, remaining) - 1,
+                    capacity - 1,
                 )
-                self._preempt(victim)
-                if victim is req or self.rows[row] is not req:
-                    break  # this row is gone; nothing more to grow
-        if prealloc > 0:
-            self._prealloc_write_pages(horizon + prealloc)
+                need_pages = last_write // self.block_size + 1
+                while len(req.blocks) < need_pages:
+                    got = self.alloc.alloc(1)
+                    if got is not None:
+                        req.blocks.extend(got)
+                        self.tables[row, len(req.blocks) - 1] = got[0]
+                        continue
+                    if self._inflight:
+                        # Pool dry with windows in flight: drain them first —
+                        # their finished rows may free blocks, and preemption
+                        # bookkeeping (prompt+generated) must be exact.
+                        self._flush_inflight()
+                        if self.rows[row] is not req:
+                            break  # this row finished in the flush
+                        continue  # retry allocation against the fresh state
+                    if self._reclaim_spec_pages(horizon):
+                        continue  # speculative grants rolled back; retry
+                    if (
+                        self.prefix_cache is not None
+                        and self.prefix_cache.evict(1)
+                    ):
+                        if self.decisions is not None:
+                            self.decisions.record(
+                                "evict_cold", blocks=1, reason="growth",
+                                rid=req.rid,
+                                trace_id=getattr(
+                                    self.traces.get(req.rid), "trace_id", None
+                                ),
+                            )
+                        continue  # cold cache evicted BEFORE any preemption
+                    victim = max(
+                        (r for r in self.rows if r is not None),
+                        key=lambda r: r.admit_order,
+                    )
+                    self._preempt(victim)
+                    if victim is req or self.rows[row] is not req:
+                        break  # this row is gone; nothing more to grow
+            if prealloc > 0:
+                self._prealloc_write_pages(horizon + prealloc)
 
     def _prealloc_write_pages(self, horizon: int) -> None:
         """Best-effort page growth toward ``horizon`` write slots per live

@@ -42,6 +42,13 @@ from pretraining_llm_tpu.parallel.sharding import constrain, current_mesh
 Params = Dict[str, Any]
 KVCache = Dict[str, jax.Array]  # {'k','v'}: (L, B, Tmax, kv_heads, Dh)
 
+# The ``jax.named_scope`` names below (``attn.core``, ``mlp``, ...) and each
+# op's source line are read back from profiler traces. JAX's persistent
+# compilation cache leaves such metadata out of its key unless told otherwise,
+# and then hands out an executable compiled from an earlier tree, which carries
+# that tree's scopes and lines (or none): keep the metadata in the key.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
 
 class PagedInfo(NamedTuple):
     """Batch-level paged-decode state, shared by every layer.
@@ -207,7 +214,8 @@ def _attention_block(
     pad slots.
     """
     cdt = jnp.dtype(cfg.compute_dtype)
-    h = layers.apply_norm(cfg.norm, blk["ln1"], x, cfg.norm_eps)
+    with jax.named_scope("blk.norm"):
+        h = layers.apply_norm(cfg.norm, blk["ln1"], x, cfg.norm_eps)
     # HEADS-MAJOR training layout for the flash kernel (opt-in probe knob,
     # measured ~1% slower on v5e despite removing the per-call relayout
     # copies — see ModelConfig.flash_heads_major for the numbers): q/k/v
@@ -219,38 +227,39 @@ def _attention_block(
         and cfg.attention_impl == "flash"
         and cfg.flash_heads_major
     )
-    if "wqkv" in blk["attn"]:
-        qkv = jnp.einsum(
-            "btd,dchn->bchtn" if hm else "btd,dchn->bcthn",
-            h.astype(cdt), _weight(blk["attn"], "wqkv", cdt),
-            preferred_element_type=jnp.float32,
-        ).astype(cdt)
-        if "bqkv" in blk["attn"]:
-            bqkv = blk["attn"]["bqkv"].astype(cdt)  # (3, H, Dh)
-            qkv = qkv + (
-                bqkv[None, :, :, None, :] if hm else bqkv[None, :, None, :, :]
-            )
-        q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # hm: (B, H, T, Dh)
-    else:
-        # GQA: H query heads, kv_heads <= H key/value heads.
-        q = jnp.einsum(
-            "btd,dhn->bhtn" if hm else "btd,dhn->bthn",
-            h.astype(cdt), _weight(blk["attn"], "wq", cdt),
-            preferred_element_type=jnp.float32,
-        ).astype(cdt)
-        kvp = jnp.einsum(
-            "btd,dcgn->bcgtn" if hm else "btd,dcgn->bctgn",
-            h.astype(cdt), _weight(blk["attn"], "wkv", cdt),
-            preferred_element_type=jnp.float32,
-        ).astype(cdt)
-        if "bq" in blk["attn"]:
-            bq = blk["attn"]["bq"].astype(cdt)  # (H, Dh)
-            bkv = blk["attn"]["bkv"].astype(cdt)  # (2, G, Dh)
-            q = q + (bq[None, :, None, :] if hm else bq[None, None])
-            kvp = kvp + (
-                bkv[None, :, :, None, :] if hm else bkv[None, :, None]
-            )
-        k, v = kvp[:, 0], kvp[:, 1]  # hm: (B, G, T, Dh)
+    with jax.named_scope("attn.qkv"):
+        if "wqkv" in blk["attn"]:
+            qkv = jnp.einsum(
+                "btd,dchn->bchtn" if hm else "btd,dchn->bcthn",
+                h.astype(cdt), _weight(blk["attn"], "wqkv", cdt),
+                preferred_element_type=jnp.float32,
+            ).astype(cdt)
+            if "bqkv" in blk["attn"]:
+                bqkv = blk["attn"]["bqkv"].astype(cdt)  # (3, H, Dh)
+                qkv = qkv + (
+                    bqkv[None, :, :, None, :] if hm else bqkv[None, :, None, :, :]
+                )
+            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # hm: (B, H, T, Dh)
+        else:
+            # GQA: H query heads, kv_heads <= H key/value heads.
+            q = jnp.einsum(
+                "btd,dhn->bhtn" if hm else "btd,dhn->bthn",
+                h.astype(cdt), _weight(blk["attn"], "wq", cdt),
+                preferred_element_type=jnp.float32,
+            ).astype(cdt)
+            kvp = jnp.einsum(
+                "btd,dcgn->bcgtn" if hm else "btd,dcgn->bctgn",
+                h.astype(cdt), _weight(blk["attn"], "wkv", cdt),
+                preferred_element_type=jnp.float32,
+            ).astype(cdt)
+            if "bq" in blk["attn"]:
+                bq = blk["attn"]["bq"].astype(cdt)  # (H, Dh)
+                bkv = blk["attn"]["bkv"].astype(cdt)  # (2, G, Dh)
+                q = q + (bq[None, :, None, :] if hm else bq[None, None])
+                kvp = kvp + (
+                    bkv[None, :, :, None, :] if hm else bkv[None, :, None]
+                )
+            k, v = kvp[:, 0], kvp[:, 1]  # hm: (B, G, T, Dh)
 
     if rope is not None:
         cos, sin = rope
@@ -267,8 +276,9 @@ def _attention_block(
             rope_pos = jnp.clip(positions[None, :] - pad_offsets[:, None], 0)
         else:
             rope_pos = positions
-        q = layers.apply_rope(q, cos, sin, rope_pos, seq_axis=2 if hm else 1)
-        k = layers.apply_rope(k, cos, sin, rope_pos, seq_axis=2 if hm else 1)
+        with jax.named_scope("attn.rope"):
+            q = layers.apply_rope(q, cos, sin, rope_pos, seq_axis=2 if hm else 1)
+            k = layers.apply_rope(k, cos, sin, rope_pos, seq_axis=2 if hm else 1)
 
     # Remat tags for the 'save_qkv_attn'/'save_big' policies: with post-RoPE
     # q/k/v saved, the attention backward starts directly from its VJP inputs
@@ -319,13 +329,14 @@ def _attention_block(
         # block and corrupt a live slot. Single-step schedulers never hit
         # this (check_paged_bounds), multi-step ones hit it by design.
         capacity = tables.shape[1] * block_size
-        pos = seq[:, None] + jnp.arange(tq, dtype=seq.dtype)[None, :]  # (B,T)
-        in_range = pos < capacity
-        pos_c = jnp.minimum(pos, capacity - 1)
-        blk_ids = jnp.where(
-            in_range, tables[jnp.arange(bsz)[:, None], pos_c // block_size], 0
-        )  # (B, T)
-        slots = jnp.where(in_range, pos_c % block_size, 0)  # (B, T)
+        with jax.named_scope("attn.kv_write"):
+            pos = seq[:, None] + jnp.arange(tq, dtype=seq.dtype)[None, :]  # (B,T)
+            in_range = pos < capacity
+            pos_c = jnp.minimum(pos, capacity - 1)
+            blk_ids = jnp.where(
+                in_range, tables[jnp.arange(bsz)[:, None], pos_c // block_size], 0
+            )  # (B, T)
+            slots = jnp.where(in_range, pos_c % block_size, 0)  # (B, T)
         quantized = "k_scale_pool" in kv
 
         def scatter(pool, val):
@@ -335,20 +346,21 @@ def _attention_block(
             # redirects) — whose content is never unmasked.
             return pool.at[blk_ids, slots].set(val.astype(pool.dtype))
 
-        if quantized:
-            k_q, k_sc = _kv_quantize(k)
-            v_q, v_sc = _kv_quantize(v)
-            new_kv = {
-                "k_pool": scatter(kv["k_pool"], k_q),
-                "v_pool": scatter(kv["v_pool"], v_q),
-                "k_scale_pool": scatter(kv["k_scale_pool"], k_sc),
-                "v_scale_pool": scatter(kv["v_scale_pool"], v_sc),
-            }
-        else:
-            new_kv = {
-                "k_pool": scatter(kv["k_pool"], k),
-                "v_pool": scatter(kv["v_pool"], v),
-            }
+        with jax.named_scope("attn.kv_write"):
+            if quantized:
+                k_q, k_sc = _kv_quantize(k)
+                v_q, v_sc = _kv_quantize(v)
+                new_kv = {
+                    "k_pool": scatter(kv["k_pool"], k_q),
+                    "v_pool": scatter(kv["v_pool"], v_q),
+                    "k_scale_pool": scatter(kv["k_scale_pool"], k_sc),
+                    "v_scale_pool": scatter(kv["v_scale_pool"], v_sc),
+                }
+            else:
+                new_kv = {
+                    "k_pool": scatter(kv["k_pool"], k),
+                    "v_pool": scatter(kv["v_pool"], v),
+                }
 
         if cfg.paged_attention_impl == "kernel" and quantized:
             # int8 pools through the kernel path: the ragged kernel fuses
@@ -367,17 +379,18 @@ def _attention_block(
                 q_lens = paged.q_lens
             else:
                 q_lens = jnp.full((bsz,), tq, dtype=seq.dtype)
-            out = ragged_paged_attention(
-                q.astype(cdt),
-                new_kv["k_pool"],
-                new_kv["v_pool"],
-                tables, seq, q_lens,
-                window=cfg.sliding_window,
-                k_scale=new_kv["k_scale_pool"],
-                v_scale=new_kv["v_scale_pool"],
-                kv_splits=cfg.ragged_kv_splits or None,
-                amla=cfg.ragged_amla,
-            )
+            with jax.named_scope("attn.core"):
+                out = ragged_paged_attention(
+                    q.astype(cdt),
+                    new_kv["k_pool"],
+                    new_kv["v_pool"],
+                    tables, seq, q_lens,
+                    window=cfg.sliding_window,
+                    k_scale=new_kv["k_scale_pool"],
+                    v_scale=new_kv["v_scale_pool"],
+                    kv_splits=cfg.ragged_kv_splits or None,
+                    amla=cfg.ragged_amla,
+                )
         elif cfg.paged_attention_impl == "kernel":
             # Gather-free: the Pallas kernel DMAs each row's pages straight
             # off the pool via the block table (ops/pallas_paged.py) — the
@@ -394,27 +407,29 @@ def _attention_block(
                     ragged_paged_attention,
                 )
 
-                out = ragged_paged_attention(
-                    q.astype(cdt),
-                    new_kv["k_pool"].astype(cdt),
-                    new_kv["v_pool"].astype(cdt),
-                    tables, seq, paged.q_lens,
-                    window=cfg.sliding_window,
-                    kv_splits=cfg.ragged_kv_splits or None,
-                    amla=cfg.ragged_amla,
-                )
+                with jax.named_scope("attn.core"):
+                    out = ragged_paged_attention(
+                        q.astype(cdt),
+                        new_kv["k_pool"].astype(cdt),
+                        new_kv["v_pool"].astype(cdt),
+                        tables, seq, paged.q_lens,
+                        window=cfg.sliding_window,
+                        kv_splits=cfg.ragged_kv_splits or None,
+                        amla=cfg.ragged_amla,
+                    )
             else:
                 from pretraining_llm_tpu.ops.pallas_paged import (
                     paged_decode_attention,
                 )
 
                 qin = q[:, 0] if tq == 1 else q
-                out = paged_decode_attention(
-                    qin.astype(cdt),
-                    new_kv["k_pool"].astype(cdt),
-                    new_kv["v_pool"].astype(cdt),
-                    tables, seq, window=cfg.sliding_window,
-                )
+                with jax.named_scope("attn.core"):
+                    out = paged_decode_attention(
+                        qin.astype(cdt),
+                        new_kv["k_pool"].astype(cdt),
+                        new_kv["v_pool"].astype(cdt),
+                        tables, seq, window=cfg.sliding_window,
+                    )
                 if tq == 1:
                     out = out[:, None]
         else:
@@ -426,30 +441,32 @@ def _attention_block(
                 # row's logical KV sequence, assembled from its pool blocks.
                 return pool[tables].reshape((bsz, kv_len) + pool.shape[2:])
 
-            if quantized:
-                ck = _kv_dequantize(
-                    gather(new_kv["k_pool"]), gather(new_kv["k_scale_pool"]), cdt
+            with jax.named_scope("attn.paged_gather"):
+                if quantized:
+                    ck = _kv_dequantize(
+                        gather(new_kv["k_pool"]), gather(new_kv["k_scale_pool"]), cdt
+                    )
+                    cv = _kv_dequantize(
+                        gather(new_kv["v_pool"]), gather(new_kv["v_scale_pool"]), cdt
+                    )
+                else:
+                    ck = gather(new_kv["k_pool"]).astype(cdt)
+                    cv = gather(new_kv["v_pool"]).astype(cdt)
+            with jax.named_scope("attn.core"):
+                lin = jnp.arange(kv_len)
+                # Causality is the length mask, per query token: token i (at
+                # logical slot seq+i) sees slots <= seq+i — its own just-
+                # written K/V and everything before it. Unallocated table tail
+                # entries point at arbitrary blocks but sit at linear indices
+                # beyond the frontier — always masked.
+                kv_mask = lin[None, None, :] <= pos[:, :, None]  # (B, T, kv_len)
+                if cfg.sliding_window:
+                    kv_mask = kv_mask & (
+                        lin[None, None, :] > pos[:, :, None] - cfg.sliding_window
+                    )
+                out = multihead_attention(
+                    q, ck, cv, impl="naive", causal=False, kv_mask=kv_mask
                 )
-                cv = _kv_dequantize(
-                    gather(new_kv["v_pool"]), gather(new_kv["v_scale_pool"]), cdt
-                )
-            else:
-                ck = gather(new_kv["k_pool"]).astype(cdt)
-                cv = gather(new_kv["v_pool"]).astype(cdt)
-            lin = jnp.arange(kv_len)
-            # Causality is the length mask, per query token: token i (at
-            # logical slot seq+i) sees slots <= seq+i — its own just-
-            # written K/V and everything before it. Unallocated table tail
-            # entries point at arbitrary blocks but sit at linear indices
-            # beyond the frontier — always masked.
-            kv_mask = lin[None, None, :] <= pos[:, :, None]  # (B, T, kv_len)
-            if cfg.sliding_window:
-                kv_mask = kv_mask & (
-                    lin[None, None, :] > pos[:, :, None] - cfg.sliding_window
-                )
-            out = multihead_attention(
-                q, ck, cv, impl="naive", causal=False, kv_mask=kv_mask
-            )
     elif kv is not None:
         # Decode: write this step's K/V into the cache at cache_index, attend
         # over the whole (masked) cache. The cache is a per-layer dict
@@ -462,17 +479,18 @@ def _attention_block(
                 buf, val.astype(buf.dtype), cache_index, axis=1
             )
 
-        if quantized:
-            k_q, k_sc = _kv_quantize(k)
-            v_q, v_sc = _kv_quantize(v)
-            new_kv = {
-                "k": write(kv["k"], k_q),
-                "v": write(kv["v"], v_q),
-                "k_scale": write(kv["k_scale"], k_sc),
-                "v_scale": write(kv["v_scale"], v_sc),
-            }
-        else:
-            new_kv = {"k": write(kv["k"], k), "v": write(kv["v"], v)}
+        with jax.named_scope("attn.kv_write"):
+            if quantized:
+                k_q, k_sc = _kv_quantize(k)
+                v_q, v_sc = _kv_quantize(v)
+                new_kv = {
+                    "k": write(kv["k"], k_q),
+                    "v": write(kv["v"], v_q),
+                    "k_scale": write(kv["k_scale"], k_sc),
+                    "v_scale": write(kv["v_scale"], v_sc),
+                }
+            else:
+                new_kv = {"k": write(kv["k"], k), "v": write(kv["v"], v)}
         tmax = new_kv["k"].shape[1]
         # The flash-prefill shortcut is only valid when the write offset is
         # PROVABLY zero at trace time (a concrete 0, as the generate prefill
@@ -498,11 +516,12 @@ def _attention_block(
             # masked einsum below (per-step shapes are tiny). Ring/ulysses
             # are training-time layouts; their decode prefill uses flash
             # (the dispatch inside falls back safely under exotic meshes).
-            out = multihead_attention(
-                q, k, v, impl="flash",
-                block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv,
-                window=cfg.sliding_window,
-            )
+            with jax.named_scope("attn.core"):
+                out = multihead_attention(
+                    q, k, v, impl="flash",
+                    block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv,
+                    window=cfg.sliding_window,
+                )
         elif (
             tq > 1
             and pad_offsets is None
@@ -543,31 +562,33 @@ def _attention_block(
                 kv_view = {
                     name: buf[:, k_lo:hi] for name, buf in new_kv.items()
                 }
-            ck, cv = _materialize_cache(kv_view, quantized, cdt)
-            out = blockwise_attention(
-                q, ck, cv, causal=True,
-                block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv,
-                q_offset=cache_index, k_offset=k_lo,
-                window=cfg.sliding_window,
-            )
+            with jax.named_scope("attn.core"):
+                ck, cv = _materialize_cache(kv_view, quantized, cdt)
+                out = blockwise_attention(
+                    q, ck, cv, causal=True,
+                    block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv,
+                    q_offset=cache_index, k_offset=k_lo,
+                    window=cfg.sliding_window,
+                )
         else:
-            kv_positions = jnp.arange(tmax)
-            kv_mask = (kv_positions < cache_index + tq)[None, :]
-            if pad_offsets is not None:
-                # Ragged rows: slots below each row's left-pad offset are
-                # dead (never written with real tokens) — mask them out.
-                kv_mask = kv_mask & (kv_positions[None, :] >= pad_offsets[:, None])
-            cache_k, cache_v = _materialize_cache(new_kv, quantized, cdt)
-            out = multihead_attention(
-                q,
-                cache_k,
-                cache_v,
-                impl="naive",
-                q_positions=positions,
-                kv_positions=kv_positions,
-                kv_mask=kv_mask,
-                window=cfg.sliding_window,
-            )
+            with jax.named_scope("attn.core"):
+                kv_positions = jnp.arange(tmax)
+                kv_mask = (kv_positions < cache_index + tq)[None, :]
+                if pad_offsets is not None:
+                    # Ragged rows: slots below each row's left-pad offset are
+                    # dead (never written with real tokens) — mask them out.
+                    kv_mask = kv_mask & (kv_positions[None, :] >= pad_offsets[:, None])
+                cache_k, cache_v = _materialize_cache(new_kv, quantized, cdt)
+                out = multihead_attention(
+                    q,
+                    cache_k,
+                    cache_v,
+                    impl="naive",
+                    q_positions=positions,
+                    kv_positions=kv_positions,
+                    kv_mask=kv_mask,
+                    window=cfg.sliding_window,
+                )
     else:
         grouped_ok = cfg.attention_impl in ("naive", "flash")
         if cfg.attention_impl == "ring":
@@ -582,37 +603,39 @@ def _attention_block(
             grouped_ok = ulysses_supports_grouped(
                 current_mesh(), cfg.n_heads, cfg.kv_heads
             )
-        out = multihead_attention(
-            q,
-            k if grouped_ok else rep(k),
-            v if grouped_ok else rep(v),
-            impl=cfg.attention_impl,
-            block_q=cfg.flash_block_q,
-            block_kv=cfg.flash_block_kv,
-            ring_layout="zigzag" if zigzag else "contiguous",
-            segments=segments,
-            window=cfg.sliding_window,
-            heads_major=hm,
-        )
+        with jax.named_scope("attn.core"):
+            out = multihead_attention(
+                q,
+                k if grouped_ok else rep(k),
+                v if grouped_ok else rep(v),
+                impl=cfg.attention_impl,
+                block_q=cfg.flash_block_q,
+                block_kv=cfg.flash_block_kv,
+                ring_layout="zigzag" if zigzag else "contiguous",
+                segments=segments,
+                window=cfg.sliding_window,
+                heads_major=hm,
+            )
 
     # Tag for the 'save_attn' remat policy: keep the (cheap-to-store,
     # expensive-to-recompute) attention output, recompute everything else.
     # (Heads-major path saves (B, H, T, Dh) — consumers below match.)
     out = checkpoint_name(out, "attn_out")
 
-    if cfg.use_output_proj:
-        out = jnp.einsum(
-            "bhtn,hnd->btd" if hm else "bthn,hnd->btd",
-            out, _weight(blk["attn"], "wo", cdt),
-            preferred_element_type=jnp.float32,
-        ).astype(cdt) + blk["attn"]["bo"].astype(cdt)
-    else:
-        # Reference shape (attention.py:95): concat heads is the output.
-        if hm:
-            out = out.transpose(0, 2, 1, 3)
-        b, t = out.shape[:2]
-        out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)
-    return x + out.astype(x.dtype), new_kv
+    with jax.named_scope("attn.out"):
+        if cfg.use_output_proj:
+            out = jnp.einsum(
+                "bhtn,hnd->btd" if hm else "bthn,hnd->btd",
+                out, _weight(blk["attn"], "wo", cdt),
+                preferred_element_type=jnp.float32,
+            ).astype(cdt) + blk["attn"]["bo"].astype(cdt)
+        else:
+            # Reference shape (attention.py:95): concat heads is the output.
+            if hm:
+                out = out.transpose(0, 2, 1, 3)
+            b, t = out.shape[:2]
+            out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)
+        return x + out.astype(x.dtype), new_kv
 
 
 def _mlp_block(
@@ -620,32 +643,34 @@ def _mlp_block(
 ) -> Tuple[jax.Array, jax.Array]:
     """Pre-LN MLP sub-block: x + mlp(ln2(x)). Returns (x, router aux loss)."""
     cdt = jnp.dtype(cfg.compute_dtype)
-    h = layers.apply_norm(cfg.norm, blk["ln2"], x, cfg.norm_eps).astype(cdt)
+    with jax.named_scope("blk.norm"):
+        h = layers.apply_norm(cfg.norm, blk["ln2"], x, cfg.norm_eps).astype(cdt)
     mlp = blk["mlp"]
-    if cfg.n_experts:
-        out, aux = moe.moe_mlp(mlp, h, cfg, decode=decode)
-        return x + out.astype(x.dtype), aux
-    if cfg.activation == "swiglu":
-        gates = jnp.einsum(
-            "btd,dcf->bctf", h, _weight(mlp, "w1", cdt), preferred_element_type=jnp.float32
+    with jax.named_scope("mlp"):
+        if cfg.n_experts:
+            out, aux = moe.moe_mlp(mlp, h, cfg, decode=decode)
+            return x + out.astype(x.dtype), aux
+        if cfg.activation == "swiglu":
+            gates = jnp.einsum(
+                "btd,dcf->bctf", h, _weight(mlp, "w1", cdt), preferred_element_type=jnp.float32
+            ).astype(cdt)
+            if "b1" in mlp:
+                gates = gates + mlp["b1"].astype(cdt)[None, :, None, :]
+            hidden = jax.nn.silu(gates[:, 0]) * gates[:, 1]
+        else:
+            hidden = jnp.einsum(
+                "btd,df->btf", h, _weight(mlp, "w1", cdt), preferred_element_type=jnp.float32
+            ).astype(cdt)
+            if "b1" in mlp:
+                hidden = hidden + mlp["b1"].astype(cdt)
+            hidden = layers.activation_fn(cfg.activation, hidden)
+        hidden = checkpoint_name(hidden, "mlp_hidden")
+        out = jnp.einsum(
+            "btf,fd->btd", hidden, _weight(mlp, "w2", cdt), preferred_element_type=jnp.float32
         ).astype(cdt)
-        if "b1" in mlp:
-            gates = gates + mlp["b1"].astype(cdt)[None, :, None, :]
-        hidden = jax.nn.silu(gates[:, 0]) * gates[:, 1]
-    else:
-        hidden = jnp.einsum(
-            "btd,df->btf", h, _weight(mlp, "w1", cdt), preferred_element_type=jnp.float32
-        ).astype(cdt)
-        if "b1" in mlp:
-            hidden = hidden + mlp["b1"].astype(cdt)
-        hidden = layers.activation_fn(cfg.activation, hidden)
-    hidden = checkpoint_name(hidden, "mlp_hidden")
-    out = jnp.einsum(
-        "btf,fd->btd", hidden, _weight(mlp, "w2", cdt), preferred_element_type=jnp.float32
-    ).astype(cdt)
-    if "b2" in mlp:
-        out = out + mlp["b2"].astype(cdt)
-    return x + out.astype(x.dtype), jnp.zeros((), jnp.float32)
+        if "b2" in mlp:
+            out = out + mlp["b2"].astype(cdt)
+        return x + out.astype(x.dtype), jnp.zeros((), jnp.float32)
 
 
 def _block(
@@ -798,27 +823,28 @@ def forward(
     # batch-sharded constraint efficiently — the "[SPMD] involuntary full
     # rematerialization" replicate-then-reshard of the activations seen in
     # the multichip dryrun (XLA all-gathers the table either way).
-    emb_table = constrain(params["tok_embed"]["embedding"], None, None)
-    x = emb_table[tokens].astype(cdt)
-    if cfg.pos_embed == "learned":
-        pos_table = constrain(params["pos_embed"]["embedding"], None, None)
-        if paged is not None:
-            # Each row's query tokens sit at their own logical positions
-            # (seq + i); clip keeps overshoot rows (scratch-redirected
-            # garbage by contract) inside the table.
-            ppos = jnp.clip(
-                paged.seq_lens[:, None]
-                + jnp.arange(t, dtype=paged.seq_lens.dtype)[None, :],
-                0, cfg.context_length - 1,
-            )
-            x = x + pos_table[ppos].astype(cdt)
-        elif pad_offsets is not None:
-            logical = jnp.clip(positions[None, :] - pad_offsets[:, None], 0)
-            x = x + pos_table[logical].astype(cdt)  # (B, T, D) per-row gather
-        else:
-            x = x + pos_table[positions].astype(cdt)[None]
-        rope = None
-    else:
+    with jax.named_scope("embed"):
+        emb_table = constrain(params["tok_embed"]["embedding"], None, None)
+        x = emb_table[tokens].astype(cdt)
+        if cfg.pos_embed == "learned":
+            pos_table = constrain(params["pos_embed"]["embedding"], None, None)
+            if paged is not None:
+                # Each row's query tokens sit at their own logical positions
+                # (seq + i); clip keeps overshoot rows (scratch-redirected
+                # garbage by contract) inside the table.
+                ppos = jnp.clip(
+                    paged.seq_lens[:, None]
+                    + jnp.arange(t, dtype=paged.seq_lens.dtype)[None, :],
+                    0, cfg.context_length - 1,
+                )
+                x = x + pos_table[ppos].astype(cdt)
+            elif pad_offsets is not None:
+                logical = jnp.clip(positions[None, :] - pad_offsets[:, None], 0)
+                x = x + pos_table[logical].astype(cdt)  # (B, T, D) per-row gather
+            else:
+                x = x + pos_table[positions].astype(cdt)[None]
+    rope = None
+    if cfg.pos_embed != "learned":
         rope = layers.rope_table(cfg.context_length, cfg.head_dim, cfg.rope_theta)
     x = constrain(x, ("data", "fsdp"), "seq" if cfg.sequence_parallel else None, None)
 
@@ -946,18 +972,20 @@ def forward(
             unroll=unroll,
         )
 
-    x = layers.apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
+    with jax.named_scope("final_norm"):
+        x = layers.apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
     if return_pre_logits:
         # Loss path: the chunked-CE head computes logits itself (see
         # _chunked_ce); hand back the final-norm hidden states.
         logits = x
     else:
         w_out, head_bias = _lm_head_weights(params, cfg)
-        logits = jnp.einsum(
-            "btd,dv->btv", x.astype(cdt), w_out.astype(cdt), preferred_element_type=jnp.float32
-        )
-        if head_bias is not None:
-            logits = logits + head_bias.astype(jnp.float32)
+        with jax.named_scope("lm_head"):
+            logits = jnp.einsum(
+                "btd,dv->btv", x.astype(cdt), w_out.astype(cdt), preferred_element_type=jnp.float32
+            )
+            if head_bias is not None:
+                logits = logits + head_bias.astype(jnp.float32)
     extras: Tuple[Any, ...] = ()
     if return_hidden:
         extras += ({"block_outputs": block_outputs, "final_hidden": x},)
@@ -1126,12 +1154,13 @@ def _head_logits32(xc, wc, bias, cdt):
     compute-dtype operands, f32 accumulation, f32 bias add. The chunked and
     dense backward paths must stay numerically in lockstep — any change to
     this formula applies to both."""
-    logits = jnp.einsum(
-        "sd,dv->sv", xc.astype(cdt), wc, preferred_element_type=jnp.float32
-    )
-    if bias is not None:
-        logits = logits + bias.astype(jnp.float32)
-    return logits
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum(
+            "sd,dv->sv", xc.astype(cdt), wc, preferred_element_type=jnp.float32
+        )
+        if bias is not None:
+            logits = logits + bias.astype(jnp.float32)
+        return logits
 
 
 def _lse_saved_ce(xs, w_out, bias, ts_, cdt, z=0.0):
@@ -1324,10 +1353,11 @@ def loss_fn(
     # z-loss is part of the TRAINING objective only — include_aux=False
     # (eval) keeps reported val_loss pure cross-entropy, exactly like the
     # MoE router aux term.
-    loss = _chunked_ce(
-        hidden, w_out, bias, targets, cfg,
-        z=cfg.z_loss_coef if include_aux else 0.0,
-    )
+    with jax.named_scope("loss.ce"):
+        loss = _chunked_ce(
+            hidden, w_out, bias, targets, cfg,
+            z=cfg.z_loss_coef if include_aux else 0.0,
+        )
     if cfg.n_experts and include_aux:
         loss = loss + cfg.router_aux_coef * aux
     return loss

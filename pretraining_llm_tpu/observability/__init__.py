@@ -9,10 +9,12 @@ spent 20% of wall-clock replaying a poison window look identical. The pieces:
   - events.py  — structured, monotonic-timestamped run events (an EventBus
                  with in-process subscribers and an optional JSONL sink);
                  everything else in this package is a fold over the stream.
-  - spans.py   — nested host-side context-manager timers exporting Chrome
-                 trace-event JSON (open in Perfetto next to the XLA xplane
-                 dumps from ``--profile``). Recording is an append to a
-                 list — no device syncs, safe anywhere on the host.
+  - spans.py   — nested host-side spans. Each is a ``jax.profiler
+                 .TraceAnnotation``, so a profiler trace holds it on the
+                 device ops' own clock; a ``SpanRecorder`` keeps them in
+                 memory too and exports Chrome trace-event JSON, once
+                 somebody asked for one (``get_recorder``/``set_recorder``):
+                 recording is opt-in, and costs an append to a list.
   - goodput.py — folds the event stream into a wall-clock decomposition
                  (productive / replay / eval / checkpoint / restore / idle /
                  other) and a single ``goodput`` fraction. Replay detection

@@ -235,21 +235,24 @@ def _make_step_fn(cfg: Config, mesh: Optional[Mesh] = None):
                 ),
                 state["params"],
             )
-            (loss_sum, grad_sum), _ = jax.lax.scan(
-                micro_step, (jnp.zeros((), jnp.float32), zero_grads), (xm, ym)
+            with jax.named_scope("microbatch"):
+                (loss_sum, grad_sum), _ = jax.lax.scan(
+                    micro_step, (jnp.zeros((), jnp.float32), zero_grads), (xm, ym)
+                )
+                loss = loss_sum / n_micro
+                grads = jax.tree.map(lambda g: g / n_micro, grad_sum)
+
+        with jax.named_scope("grad_clip"):
+            if tcfg.grad_clip > 0:
+                grads, grad_norm = opt.clip_by_global_norm(grads, tcfg.grad_clip)
+            else:
+                grad_norm = opt.global_norm(grads)
+
+        with jax.named_scope("optimizer"):
+            lr = opt.learning_rate(state["step"], tcfg)
+            new_params, new_opt = opt.optimizer_update(
+                grads, state["opt"], state["params"], lr, tcfg
             )
-            loss = loss_sum / n_micro
-            grads = jax.tree.map(lambda g: g / n_micro, grad_sum)
-
-        if tcfg.grad_clip > 0:
-            grads, grad_norm = opt.clip_by_global_norm(grads, tcfg.grad_clip)
-        else:
-            grad_norm = opt.global_norm(grads)
-
-        lr = opt.learning_rate(state["step"], tcfg)
-        new_params, new_opt = opt.optimizer_update(
-            grads, state["opt"], state["params"], lr, tcfg
-        )
         new_state = {"params": new_params, "opt": new_opt, "step": state["step"] + 1}
         if "ema" in state:
             d = tcfg.ema_decay

@@ -3,8 +3,9 @@
 The reference's only observability is wall-clock deltas printed at eval
 boundaries (`/root/reference/scripts/train_transformer.py:75,98-101`). Here
 (SURVEY §5): on-demand XLA trace capture (TensorBoard/Perfetto-readable
-xplane dumps) scoped to a step window, plus `annotate` for named_scope
-regions that show up in the trace timeline.
+xplane dumps) scoped to a step window. The regions that show up in such a
+trace are named where the work is: `jax.named_scope` in the model and the
+train step, `observability.spans.span` on the host.
 """
 
 from __future__ import annotations
@@ -23,11 +24,6 @@ def trace(logdir: str) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named scope that appears on the profiler timeline (and in HLO names)."""
-    return jax.named_scope(name)
 
 
 class StepProfiler:

@@ -1,0 +1,290 @@
+"""The program's own instrumentation: host spans (observability/spans.py) in
+the serving engine and the engine loop, the always-on phase account of the
+scheduler tick, and the ``jax.named_scope`` names in the compiled programs.
+
+The names are a contract: the benchmark's readers (benchmark/harness/
+program_trace.py) find spans and scopes by them, PERF.md lists them.
+"""
+
+import contextlib
+import dataclasses
+import logging
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from pretraining_llm_tpu.config import get_preset
+from pretraining_llm_tpu.frontend.engine_loop import EngineLoop
+from pretraining_llm_tpu.generation import paged, serving
+from pretraining_llm_tpu.generation.serving import PHASES, ServingEngine
+from pretraining_llm_tpu.models import transformer
+from pretraining_llm_tpu.observability import spans
+from pretraining_llm_tpu.training import train_step as ts
+
+TINY = get_preset("tiny")
+# RoPE and grouped KV heads, so every scope of the attention block is on the path.
+CFG = dataclasses.replace(
+    TINY.model, compute_dtype="float32", pos_embed="rope", n_kv_heads=2, remat="full"
+)
+TRAIN_CFG = dataclasses.replace(
+    TINY, model=CFG, train=dataclasses.replace(TINY.train, batch_size=4, microbatches=2)
+)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return transformer.init_params(CFG, jax.random.key(0))
+
+
+def _prompts(n, lengths=(5, 9, 14, 7, 11, 3)):
+    rng = np.random.default_rng(42)
+    return [rng.integers(0, CFG.vocab_size, size=lengths[i % len(lengths)]).tolist() for i in range(n)]
+
+
+def _engine(params, **kw):
+    kw = {"max_batch": 2, "n_blocks": 32, "block_size": 8, "temperature": 0.0,
+          "steps_per_sched": 4, "pipeline_depth": 2, **kw}
+    return ServingEngine(params, CFG, **kw)
+
+
+# -- (a) spans: off by default, names and nesting when a recorder exists ------------
+
+
+def test_no_recorder_means_nothing_is_recorded(params, monkeypatch):
+    monkeypatch.setattr(spans, "_default", None)
+    eng = _engine(params)
+    for p in _prompts(3):
+        eng.submit(p, 6)
+    out = eng.run(pipeline=True)
+    assert len(out) == 3
+    # module-level span() never made a recorder, so there is nowhere a span could be
+    assert spans._default is None
+    rec = spans.get_recorder()  # asking for it is what turns recording on
+    assert spans._default is rec and rec.summary() == {}
+
+
+@pytest.fixture()
+def recorded_run(params, monkeypatch):
+    rec = spans.SpanRecorder()
+    monkeypatch.setattr(spans, "_default", rec)
+    eng = _engine(params)
+    for p in _prompts(3):
+        eng.submit(p, 6)
+    eng.run(pipeline=True)
+    events, dropped = rec.drain()
+    assert dropped == 0
+    return eng, events
+
+
+def test_span_names_and_counts(recorded_run):
+    eng, events = recorded_run
+    count = {}
+    for name, *_ in events:
+        count[name] = count.get(name, 0) + 1
+    assert count["serving.tick"] == eng.stats["ticks"]
+    assert count["serving.dispatch_window"] == eng.stats["windows"]
+    assert count["serving.reap_window"] == eng.stats["windows_reaped"]
+    assert count["serving.host_blocked"] == count["serving.commit"] == eng.stats["windows_reaped"]
+    # three requests over two rows: the third waits, so admission runs in more than one turn
+    assert count["serving.prefill_dispatch"] >= 2
+    assert count["serving.admit"] >= count["serving.prefill_dispatch"]
+    assert count["serving.ensure_pages"] == count["serving.dispatch_window"]
+    admitted = [m for name, *_, m in events if name == "serving.admit"]
+    assert sum(m["rows"] for m in admitted) == eng.stats["admissions"] == 3
+    reaps = [m for name, *_, m in events if name == "serving.reap_window"]
+    assert all(m["host_blocked_s"] >= 0 for m in reaps)
+
+
+@pytest.mark.parametrize("child,parent", [
+    ("serving.admit", "serving.tick"),
+    ("serving.prefill_dispatch", "serving.admit"),
+    ("serving.ensure_pages", "serving.tick"),
+    ("serving.dispatch_window", "serving.tick"),
+    ("serving.reap_window", "serving.tick"),
+    ("serving.host_blocked", "serving.reap_window"),
+    ("serving.commit", "serving.reap_window"),
+])
+def test_span_nesting(recorded_run, child, parent):
+    _, events = recorded_run
+    parents = [(t0, t0 + dur, depth) for name, t0, dur, _, depth, _ in events if name == parent]
+    children = [(t0, t0 + dur, depth) for name, t0, dur, _, depth, _ in events if name == child]
+    assert children
+    for a, b, depth in children:
+        assert any(pa <= a and b <= pb and pdepth < depth for pa, pb, pdepth in parents), (child, a, b)
+
+
+def test_engine_loop_spans(params, monkeypatch):
+    rec = spans.SpanRecorder()
+    monkeypatch.setattr(spans, "_default", rec)
+    eng = _engine(params)
+    with EngineLoop(eng, idle_wait_s=0.005) as loop:
+        status, tokens, _ = loop.submit(_prompts(1)[0], 6).result(timeout=60)
+        time.sleep(0.05)  # a few idle turns
+    assert status == "done" and len(tokens) == 6
+    by_thread = {}
+    for name, t0, dur, tid, depth, _ in rec.drain()[0]:
+        by_thread.setdefault(tid, []).append((name, t0, t0 + dur, depth))
+    (loop_events,) = [ev for ev in by_thread.values() if any(e[0] == "loop.turn" for e in ev)]
+    names = {e[0] for e in loop_events}
+    assert {"loop.turn", "loop.inbox", "loop.deadlines", "loop.idle_wait", "serving.tick"} <= names
+    turns = [e for e in loop_events if e[0] == "loop.turn"]
+    for name, a, b, depth in loop_events:
+        if name in ("loop.inbox", "loop.deadlines", "loop.idle_wait", "serving.tick"):
+            assert any(ta <= a and b <= tb and tdepth < depth for _, ta, tb, tdepth in turns), name
+    assert loop.counters["slow_turns"] == 0
+
+
+# -- (b) the always-on phase account --------------------------------------------------
+
+
+def test_phase_account_sums_to_the_ticks(recorded_run):
+    eng, events = recorded_run
+    phase_s = eng.stats["phase_s"]
+    assert set(phase_s) == set(PHASES)
+    ticks_s = sum(dur for name, _, dur, *_ in events if name == "serving.tick")
+    assert sum(phase_s.values()) == pytest.approx(ticks_s, rel=0.01)
+    assert phase_s["host_blocked"] == pytest.approx(eng.stats["host_blocked_s"], rel=1e-9)
+    for phase in ("admit", "prefill_dispatch", "ensure_pages", "dispatch", "host_blocked", "commit", "other"):
+        assert phase_s[phase] > 0, phase
+    longest = eng.stats["longest_tick"]
+    assert 1 <= longest["tick"] <= eng.stats["ticks"]
+    assert sum(longest["phase_s"].values()) == pytest.approx(longest["seconds"], rel=1e-6)
+    assert eng.stats["slow_ticks"] == 0
+
+
+class _SlowReadback:
+    """``numpy`` for the serving module, whose next ``asarray`` takes ``delay`` seconds."""
+
+    def __init__(self):
+        self.delay = 0.0
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def asarray(self, *args, **kw):
+        delay, self.delay = self.delay, 0.0
+        time.sleep(delay)
+        return np.asarray(*args, **kw)
+
+
+def test_stalled_readback_logs_one_slow_tick_naming_the_phase(params, monkeypatch, caplog):
+    slow_np = _SlowReadback()
+    monkeypatch.setattr(serving, "np", slow_np)
+    eng = _engine(params, steps_per_sched=1)
+    for p in _prompts(2):
+        eng.submit(p, 40)
+    with caplog.at_level(logging.WARNING, logger="pretraining_llm_tpu.serving"):
+        for _ in range(20):
+            eng.pipeline_tick()
+        assert eng.stats["slow_ticks"] == 0 and not caplog.records
+        # nobody waits and both rows decode: the tick's first host read is the window's readback
+        slow_np.delay = 0.4
+        eng.pipeline_tick()
+        for _ in range(5):
+            eng.pipeline_tick()
+    assert eng.stats["slow_ticks"] == 1
+    (record,) = [r for r in caplog.records if "slow tick" in r.getMessage()]
+    message = record.getMessage()
+    assert "slow tick 21:" in message
+    # the split lists phases largest first
+    assert message.split(": ", 2)[2].startswith("host_blocked=")
+    longest = eng.stats["longest_tick"]
+    assert longest["tick"] == 21 and longest["phase_s"]["host_blocked"] >= 0.4
+    assert max(longest["phase_s"], key=longest["phase_s"].get) == "host_blocked"
+
+
+# -- (c) scope names in the lowered programs ------------------------------------------
+
+MODEL_SCOPES = ("embed", "blk.norm", "attn.qkv", "attn.rope", "attn.kv_write", "attn.core",
+                "attn.out", "mlp", "final_norm", "lm_head")
+PROGRAM_SCOPES = {
+    "decode": MODEL_SCOPES + ("attn.paged_gather", "sample"),
+    "prefill": MODEL_SCOPES + ("sample",),
+    "train": tuple(s for s in MODEL_SCOPES if s != "attn.kv_write")
+    + ("loss.ce", "optimizer", "grad_clip", "microbatch"),
+}
+
+
+def _decode_args(params, rows=2):
+    pools = transformer.make_paged_kv_pool(CFG, 16, 8)
+    tables = jnp.asarray(np.arange(1, 1 + rows * 4).reshape(rows, 4), jnp.int32)
+    return (params, pools, jnp.asarray([3, 5][:rows], jnp.int32), tables,
+            jnp.asarray([4, 9][:rows], jnp.int32), jax.random.key(1))
+
+
+def _prefill_args(params):
+    pools = transformer.make_paged_kv_pool(CFG, 16, 8)
+    rng = np.random.default_rng(7)
+    prompts = jnp.asarray(rng.integers(0, CFG.vocab_size, (2, 16)), jnp.int32)
+    return (params, pools, prompts, jnp.asarray([16, 11], jnp.int32),
+            jnp.asarray([[1, 2], [3, 4]], jnp.int32), jax.random.key(2))
+
+
+def _train_args():
+    state = ts.init_train_state(TRAIN_CFG, jax.random.key(3))
+    rng = np.random.default_rng(11)
+    b, t = TRAIN_CFG.train.batch_size, CFG.context_length
+    x = jnp.asarray(rng.integers(0, CFG.vocab_size, (b, t)), jnp.int32)
+    return state, (x, jnp.roll(x, -1, axis=1))
+
+
+def _run_programs(params):
+    """The three programs on fixed inputs: every output leaf, as numpy."""
+    toks, pools = paged.paged_decode_steps(*_decode_args(params), CFG, n_steps=3)
+    first, pools2 = paged._prefill_scatter_sample(*_prefill_args(params), CFG, 16, 2)
+    state, metrics = ts.build_train_step(TRAIN_CFG)(*_train_args())
+    return [np.asarray(leaf) for leaf in jax.tree.leaves((toks, pools, first, pools2, state, metrics))]
+
+
+@pytest.fixture(scope="module")
+def scope_tokens(params):
+    """Per program, every word of every op's name path in the lowered text."""
+    lowered = {
+        "decode": paged.paged_decode_steps.lower(*_decode_args(params), CFG, n_steps=3),
+        "prefill": paged._prefill_scatter_sample.lower(*_prefill_args(params), CFG, 16, 2),
+        "train": ts.lower_train_step(TRAIN_CFG),
+    }
+    out = {}
+    for program, low in lowered.items():
+        paths = set(re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True)))
+        out[program] = {"paths": paths, "words": {w for p in paths for w in re.split(r"[/()]", p)}}
+    return out
+
+
+@pytest.mark.parametrize("program,scope", [(p, s) for p, ss in PROGRAM_SCOPES.items() for s in ss])
+def test_scope_is_in_the_lowered_program(scope_tokens, program, scope):
+    assert scope in scope_tokens[program]["words"]
+
+
+def test_backward_and_recompute_carry_the_scopes(scope_tokens):
+    paths = scope_tokens["train"]["paths"]
+    assert any("transpose(jvp(loss.ce))" in p for p in paths)
+    assert any("rematted_computation/mlp" in p for p in paths)
+    assert any("rematted_computation/attn.core" in p for p in paths)
+
+
+# -- (d) the scopes are metadata: they change no output bit ---------------------------
+
+
+def test_outputs_are_bit_identical_without_the_scopes(params, monkeypatch):
+    with_scopes = _run_programs(params)
+
+    def no_scope(name):
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(jax, "named_scope", no_scope)
+    jax.clear_caches()
+    try:
+        low = paged.paged_decode_steps.lower(*_decode_args(params), CFG, n_steps=3)
+        assert "attn.core" not in low.as_text(debug_info=True)  # the scopes are really gone
+        without = _run_programs(params)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert len(with_scopes) == len(without)
+    for a, b in zip(with_scopes, without):
+        assert a.dtype == b.dtype and np.array_equal(a, b)

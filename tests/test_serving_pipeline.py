@@ -295,7 +295,7 @@ def test_reap_window_records_spans(params):
             for e in reaps
         )
     finally:
-        spans.set_recorder(spans.SpanRecorder())
+        spans.set_recorder(None)
 
 
 # -- engine knob validation ------------------------------------------------
