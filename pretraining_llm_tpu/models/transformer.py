@@ -26,11 +26,13 @@ the blocks.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import PartitionSpec as P
 
 
 from pretraining_llm_tpu.config import ModelConfig
@@ -996,6 +998,44 @@ def forward(
     return logits, new_cache
 
 
+# The chunk rule's two numbers: bytes of f32 logits a chunk may hold, and the
+# fewest tokens worth a chunk. Module-level so a test reaches several chunks
+# at a toy size.
+_CE_CHUNK_BYTES = 512 * 1024 * 1024
+_CE_MIN_CHUNK_TOKENS = 512
+
+
+def _ce_n_chunks(s: int, vocab_size: int) -> int:
+    """Chunks for ``s`` tokens of one device's f32 logits."""
+    # Chunk only when the fp32 logits buffer is big enough to matter (XLA
+    # already fuses the small-head case well — measured neutral-to-slower to
+    # chunk at GPT-2 batch sizes). Target <= ~512 MB per chunk.
+    logits_bytes = s * vocab_size * 4
+    want = max(1, -(-logits_bytes // _CE_CHUNK_BYTES))
+    n_chunks = 1
+    if want > 1:
+        # Any divisor of S with chunk >= 512 keeps the memory bound; prefer
+        # the smallest chunk count >= want, else the largest available (an
+        # awkward S loses granularity, not the whole saving).
+        divisors = [c for c in range(2, s // _CE_MIN_CHUNK_TOKENS + 1) if s % c == 0]
+        at_least = [c for c in divisors if c >= want]
+        if at_least:
+            n_chunks = min(at_least)
+        elif divisors:
+            n_chunks = max(divisors)
+        if n_chunks < want:
+            import warnings
+
+            warnings.warn(
+                f"chunked CE head: batch*seq={s} has no divisor >= {want} with "
+                f"chunk >= {_CE_MIN_CHUNK_TOKENS}; using {n_chunks} chunks — logits memory "
+                f"{logits_bytes / n_chunks / 2**20:.0f} MB/chunk exceeds the "
+                f"{_CE_CHUNK_BYTES / 2**20:.0f} MB target. Prefer power-of-two batch*context products.",
+                stacklevel=3,
+            )
+    return n_chunks
+
+
 def _chunked_ce(
     hidden: jax.Array,
     w_out: jax.Array,
@@ -1012,7 +1052,13 @@ def _chunked_ce(
     re-reading them is pure HBM traffic. Instead scan over token chunks:
     each chunk's logits live only transiently, and the backward recomputes
     them chunk-by-chunk (one extra small matmul per chunk for a ~3x cut in
-    head memory traffic). fused: Pallas kernel (see ops/pallas_ce).
+    head memory traffic). Under a mesh whose batch axes (data, fsdp) hold
+    more than one device, the chunks are cut from each device's OWN tokens
+    and the scans run per device (see _lse_saved_ce): left to the
+    partitioner, the batch sharding lands on the chunk axis the scan walks,
+    and every chunk's full-vocabulary f32 logits are all-reduced over the
+    d-sharded head, forward and backward (18% of gpt2-xl's fsdp=4 step).
+    fused: Pallas kernel (see ops/pallas_ce).
     dense: the OPPOSITE trade — deliberately materializes and SAVES the
     compute-dtype (S, V) logits so backward recomputes nothing (see
     _dense_lse_ce); head memory is S*V*2 bytes.
@@ -1054,8 +1100,6 @@ def _chunked_ce(
             hidden_c = hidden.astype(cdt)
             w_c = w_out.astype(cdt)
             if mesh is not None and (nontrivial("data") or nontrivial("fsdp")):
-                from jax.sharding import PartitionSpec as P
-
                 batch_axes = ("data", "fsdp")
 
                 def local_ce(h_l, w_l, t_l):
@@ -1093,35 +1137,19 @@ def _chunked_ce(
         return _dense_lse_ce(
             hidden.reshape(s, d), w_out, bias, targets.reshape(s), cdt, z=z
         ) / s
-    # Chunk only when the fp32 logits buffer is big enough to matter (XLA
-    # already fuses the small-head case well — measured neutral-to-slower to
-    # chunk at GPT-2 batch sizes). Target <= ~512 MB per chunk.
-    logits_bytes = s * cfg.vocab_size * 4
-    want = max(1, -(-logits_bytes // (512 * 1024 * 1024)))
-    n_chunks = 1
-    if want > 1:
-        # Any divisor of S with chunk >= 512 keeps the memory bound; prefer
-        # the smallest chunk count >= want, else the largest available (an
-        # awkward S loses granularity, not the whole saving).
-        divisors = [c for c in range(2, s // 512 + 1) if s % c == 0]
-        at_least = [c for c in divisors if c >= want]
-        if at_least:
-            n_chunks = min(at_least)
-        elif divisors:
-            n_chunks = max(divisors)
-        if n_chunks < want:
-            import warnings
-
-            warnings.warn(
-                f"chunked CE head: batch*seq={s} has no divisor >= {want} with "
-                f"chunk >= 512; using {n_chunks} chunks — logits memory "
-                f"{logits_bytes / n_chunks / 2**20:.0f} MB/chunk exceeds the "
-                "512 MB target. Prefer power-of-two batch*context products.",
-                stacklevel=2,
-            )
-    xs = hidden.reshape(n_chunks, s // n_chunks, d)
-    ts_ = targets.reshape(n_chunks, s // n_chunks)
-    return _lse_saved_ce(xs, w_out, bias, ts_, cdt, z=z) / s
+    # The devices the batch is spread over each chunk their own tokens; a
+    # batch they do not divide stays with the partitioner.
+    mesh = current_mesh()
+    batch_axes = tuple(
+        ax for ax in ("data", "fsdp") if mesh is not None and mesh.shape.get(ax, 1) > 1
+    )
+    shards = math.prod(mesh.shape[ax] for ax in batch_axes)
+    if b % shards:
+        batch_axes, shards = (), 1
+    n_chunks = _ce_n_chunks(s // shards, cfg.vocab_size)
+    xs = hidden.reshape(shards * n_chunks, s // (shards * n_chunks), d)
+    ts_ = targets.reshape(xs.shape[:2])
+    return _lse_saved_ce(xs, w_out, bias, ts_, cdt, z=z, mesh=mesh, batch_axes=batch_axes) / s
 
 
 def _subtract_onehot(p: jax.Array, targets: jax.Array) -> jax.Array:
@@ -1163,7 +1191,7 @@ def _head_logits32(xc, wc, bias, cdt):
         return logits
 
 
-def _lse_saved_ce(xs, w_out, bias, ts_, cdt, z=0.0):
+def _lse_saved_ce(xs, w_out, bias, ts_, cdt, z=0.0, mesh=None, batch_axes=()):
     """Sum of per-token CE over chunked logits, custom VJP.
 
     vs `lax.scan(jax.checkpoint(chunk))`: the checkpointed backward re-runs
@@ -1177,7 +1205,28 @@ def _lse_saved_ce(xs, w_out, bias, ts_, cdt, z=0.0):
 
     Gradients match the checkpointed path to float-associativity: dlogits
     stays fp32 into the dX/dW matmuls exactly as autodiff would keep it.
+
+    ``batch_axes`` (the mesh's data/fsdp axes of extent > 1, else empty):
+    the leading dim of xs/ts_ is then sharded over them, each device's
+    chunks contiguous, and both scans run inside a shard_map that is manual
+    over those axes only (tensor/seq/pipe stay with the partitioner). The
+    compute-dtype head weight enters replicated, so it is gathered over
+    fsdp once a pass, and the devices' f32 partial dW (and db) are summed
+    once after the backward scan; the partitioner then keeps each device's
+    slice of the sum. A scan carry in global view cannot hold an unreduced
+    partial sum, hence the manual region. With no batch axes this is the
+    plain single-device code.
     """
+    rows, whole = P(batch_axes), P()
+
+    def per_device(f, in_specs, out_specs):
+        if not batch_axes:
+            return f
+        return jax.shard_map(
+            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            axis_names=set(batch_axes), check_vma=False,
+        )
+
     def logits_of(xc, wc, bias):
         return _head_logits32(xc, wc, bias, cdt)
 
@@ -1186,51 +1235,65 @@ def _lse_saved_ce(xs, w_out, bias, ts_, cdt, z=0.0):
         return _fwd(xs, w_out, bias)[0]
 
     def _fwd(xs, w_out, bias):
-        wc = w_out.astype(cdt)
+        def scan_chunks(xs, ts_, wc, bias):
+            def chunk(carry, inp):
+                xc, tc = inp
+                logits = logits_of(xc, wc, bias)
+                lse = jax.nn.logsumexp(logits, axis=-1)
+                label_logit = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
+                total = jnp.sum(lse - label_logit)
+                if z:
+                    # z-loss (PaLM/ST-MoE): z * lse^2 keeps softmax logits from
+                    # drifting (lse ~ 0 means calibrated normalizers; also
+                    # guards bf16 logit overflow at scale).
+                    total = total + z * jnp.sum(jnp.square(lse))
+                return carry + total, lse
 
-        def chunk(carry, inp):
-            xc, tc = inp
-            logits = logits_of(xc, wc, bias)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            label_logit = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
-            total = jnp.sum(lse - label_logit)
-            if z:
-                # z-loss (PaLM/ST-MoE): z * lse^2 keeps softmax logits from
-                # drifting (lse ~ 0 means calibrated normalizers; also
-                # guards bf16 logit overflow at scale).
-                total = total + z * jnp.sum(jnp.square(lse))
-            return carry + total, lse
+            total, lses = jax.lax.scan(chunk, jnp.zeros((), jnp.float32), (xs, ts_))
+            if batch_axes:
+                total = jax.lax.psum(total, batch_axes)
+            return total, lses
 
-        total, lses = jax.lax.scan(chunk, jnp.zeros((), jnp.float32), (xs, ts_))
+        total, lses = per_device(
+            scan_chunks, (rows, rows, whole, whole), (whole, rows)
+        )(xs, ts_, w_out.astype(cdt), bias)
         return total, (xs, w_out, bias, lses)
 
     def _bwd(res, g):
         xs, w_out, bias, lses = res
-        wc = w_out.astype(cdt)
-        dw0 = jnp.zeros(w_out.shape, jnp.float32)
-        db0 = None if bias is None else jnp.zeros(bias.shape, jnp.float32)
 
-        def chunk(carry, inp):
-            dw_acc, db_acc = carry
-            xc, tc, lse = inp
-            logits = logits_of(xc, wc, bias)
-            p = jnp.exp(logits - lse[:, None])  # softmax, one pass
-            if z:
-                # d(lse^2)/dlogits = 2*lse*softmax -> fold into p's scale.
-                p = p * (1.0 + 2.0 * z * lse[:, None])
-            dlogits = _subtract_onehot(p, tc) * g  # fp32
-            dx = jnp.einsum(
-                "sv,dv->sd", dlogits, wc, preferred_element_type=jnp.float32
-            )
-            dw_acc = dw_acc + jnp.einsum(
-                "sd,sv->dv", xc.astype(cdt), dlogits,
-                preferred_element_type=jnp.float32,
-            )
-            if db_acc is not None:
-                db_acc = db_acc + jnp.sum(dlogits, axis=0)
-            return (dw_acc, db_acc), dx.astype(xs.dtype)
+        def scan_chunks(xs, ts_, lses, wc, bias, g):
+            dw0 = jnp.zeros(wc.shape, jnp.float32)
+            db0 = None if bias is None else jnp.zeros(bias.shape, jnp.float32)
 
-        (dw, db), dxs = jax.lax.scan(chunk, (dw0, db0), (xs, ts_, lses))
+            def chunk(carry, inp):
+                dw_acc, db_acc = carry
+                xc, tc, lse = inp
+                logits = logits_of(xc, wc, bias)
+                p = jnp.exp(logits - lse[:, None])  # softmax, one pass
+                if z:
+                    # d(lse^2)/dlogits = 2*lse*softmax -> fold into p's scale.
+                    p = p * (1.0 + 2.0 * z * lse[:, None])
+                dlogits = _subtract_onehot(p, tc) * g  # fp32
+                dx = jnp.einsum(
+                    "sv,dv->sd", dlogits, wc, preferred_element_type=jnp.float32
+                )
+                dw_acc = dw_acc + jnp.einsum(
+                    "sd,sv->dv", xc.astype(cdt), dlogits,
+                    preferred_element_type=jnp.float32,
+                )
+                if db_acc is not None:
+                    db_acc = db_acc + jnp.sum(dlogits, axis=0)
+                return (dw_acc, db_acc), dx.astype(xs.dtype)
+
+            (dw, db), dxs = jax.lax.scan(chunk, (dw0, db0), (xs, ts_, lses))
+            if batch_axes:  # the devices' partial sums meet once, after the scan
+                dw, db = jax.lax.psum((dw, db), batch_axes)
+            return dxs, dw, db
+
+        dxs, dw, db = per_device(
+            scan_chunks, (rows, rows, rows, whole, whole, whole), (rows, whole, whole)
+        )(xs, ts_, lses, w_out.astype(cdt), bias, g)
         return (
             dxs,
             dw.astype(w_out.dtype),

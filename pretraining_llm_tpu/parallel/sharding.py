@@ -16,6 +16,13 @@ The rules implement:
 Because the train step is a single global-view `pjit` program, any spec is
 *correct* — the rules only decide layout/performance. Sharding-invariance is
 enforced by tests (same loss on a 1-device and an 8-device mesh).
+
+One place is not left to the partitioner: the chunked CE head
+(`models/transformer.py::_lse_saved_ce`) runs its chunk scans in a shard_map
+that is manual over the batch axes (data, fsdp). In global view the batch
+sharding lands on the chunk axis the scan walks, and XLA then sums every
+chunk's full-vocabulary f32 logits over the fsdp-sharded head; per device,
+the head weight is gathered once a pass and dW summed once after the scan.
 """
 
 from __future__ import annotations
