@@ -29,6 +29,13 @@ _REMAT_POLICIES = ("none", "full", "dots_saveable", "save_attn",
                    "save_attn_res", "save_qkv_attn", "save_big")
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-magnitude correction, 0.1 * mscale * ln(factor) + 1."""
+    import math
+
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     """Architecture of a decoder-only transformer.
@@ -165,6 +172,53 @@ class ModelConfig:
     # derives from the token count only (mesh-independent routing). 0 = one
     # global group (tiny-shape/testing escape hatch).
     moe_group_size: int = 2048
+    # Routing. "capacity" (above) drops what overflows an expert's slots and
+    # stays the training path. "dropless" sorts the (token, choice) pairs by
+    # expert and runs one grouped matmul per projection: no slot, no drop, and
+    # a token's output never depends on what shares its batch or its padded
+    # bucket, so the paged engine and ragged generate accept it.
+    moe_routing: str = "capacity"  # capacity | dropless
+    # Dropless scoring: softmax, or sigmoid scores with a per-expert bias that
+    # enters the SELECTION only ("noaux_tc"); the gates are the unbiased
+    # scores of the selected experts, renormalised, times moe_routed_scale.
+    moe_score: str = "softmax"  # softmax | sigmoid
+    moe_score_bias: bool = False
+    moe_norm_topk: bool = True
+    moe_routed_scale: float = 1.0
+    # Shared experts: one always-on SwiGLU of width n_shared_experts * d_expert
+    # beside the routed ones. d_expert 0 = d_ff.
+    n_shared_experts: int = 0
+    d_expert: int = 0
+    # Leading dense layers (width d_ff) before the expert layers: stored as
+    # params["dense_blocks"], run as a group of their own ahead of
+    # params["blocks"]. Caches and pools cover all n_layers.
+    n_dense_layers: int = 0
+    # Latent (MLA) attention, kv_lora_rank > 0: low-rank queries, one latent
+    # of kv_lora_rank + qk_rope_head_dim values a token shared by all heads
+    # (the only thing cached), keys and values expanded from it for prefill
+    # and absorbed into the query and output for decode. d_head must be
+    # qk_nope_head_dim + qk_rope_head_dim.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN: frequencies interpolated between the original context and
+    # rope_factor times it; mscale_all_dim scales the softmax by its square.
+    rope_scaling: str = "none"  # none | yarn
+    rope_factor: float = 1.0
+    rope_original_context: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # Manifold-constrained hyper-connections (arXiv:2512.24880): hc_mult > 1
+    # residual streams a token, read, written and mixed per sublayer by
+    # coefficients computed in float32 (models/hyper.py). 1 = plain residual.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
     # Pipeline parallelism: split the layer stack into stages over the 'pipe'
     # mesh axis, GPipe microbatch schedule via ppermute. 1 = off.
     pipeline_stages: int = 1
@@ -285,6 +339,55 @@ class ModelConfig:
                 raise ValueError("expert_capacity_factor must be positive")
             if self.moe_group_size < 0:
                 raise ValueError("moe_group_size must be >= 0 (0 = one global group)")
+            if self.moe_routing not in ("capacity", "dropless"):
+                raise ValueError(
+                    f"moe_routing must be 'capacity' or 'dropless', got {self.moe_routing!r}"
+                )
+            if self.moe_score not in ("softmax", "sigmoid"):
+                raise ValueError(
+                    f"moe_score must be 'softmax' or 'sigmoid', got {self.moe_score!r}"
+                )
+            if self.moe_routing == "dropless" and self.activation != "swiglu":
+                raise ValueError("dropless experts are SwiGLU (activation='swiglu')")
+            if self.moe_routing == "capacity" and (
+                self.moe_score != "softmax" or self.moe_score_bias
+                or self.n_shared_experts or self.d_expert or self.moe_routed_scale != 1.0
+            ):
+                raise ValueError(
+                    "sigmoid scores, a score bias, shared experts, d_expert and "
+                    "moe_routed_scale need moe_routing='dropless'"
+                )
+        if not 0 <= self.n_dense_layers < max(self.n_layers, 1) or (
+            self.n_dense_layers and not self.n_experts
+        ):
+            raise ValueError(
+                f"n_dense_layers={self.n_dense_layers} needs an expert model with "
+                f"more layers than that (n_layers={self.n_layers})"
+            )
+        if self.kv_lora_rank:
+            if self.d_head != self.qk_nope_head_dim + self.qk_rope_head_dim or (
+                min(self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim) <= 0
+                or self.qk_rope_head_dim % 2
+            ):
+                raise ValueError(
+                    "latent attention needs qk_nope_head_dim, an even qk_rope_head_dim "
+                    "and v_head_dim, and d_head equal to the sum of the first two"
+                )
+            if self.pos_embed != "rope" or self.sliding_window or self.kv_cache_dtype != "compute":
+                raise ValueError(
+                    "latent attention runs with RoPE, no sliding window and an "
+                    "unquantized cache (int8 latent pages: ROADMAP)"
+                )
+            if self.n_kv_heads not in (None, self.n_heads) or self.qkv_bias:
+                raise ValueError("latent attention has no grouped KV heads and no QKV bias")
+            if self.paged_attention_impl != "gather":
+                raise ValueError("the paged kernels read per-head K/V; a latent pool takes 'gather'")
+        if self.rope_scaling not in ("none", "yarn"):
+            raise ValueError(f"rope_scaling must be 'none' or 'yarn', got {self.rope_scaling!r}")
+        if self.rope_scaling == "yarn" and (self.rope_factor < 1 or self.rope_original_context < 1):
+            raise ValueError("yarn needs rope_factor >= 1 and rope_original_context")
+        if self.hc_mult < 1 or (self.hc_mult > 1 and self.pipeline_stages > 1):
+            raise ValueError("hc_mult must be >= 1, and residual streams do not pipeline yet")
         if self.pipeline_stages < 1 or self.n_layers % self.pipeline_stages != 0:
             raise ValueError(
                 f"pipeline_stages={self.pipeline_stages} must divide "
@@ -364,34 +467,55 @@ class ModelConfig:
     def d_ff(self) -> int:
         return int(self.mlp_ratio * self.d_model)
 
+    @property
+    def moe_dropless(self) -> bool:
+        return bool(self.n_experts) and self.moe_routing == "dropless"
+
+    @property
+    def moe_capacity(self) -> bool:
+        """Experts under a capacity bound: pad slots would compete with real
+        tokens for it, so ragged and bucketed prefills refuse these."""
+        return bool(self.n_experts) and self.moe_routing == "capacity"
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_expert or self.d_ff
+
+    @property
+    def latent_dim(self) -> int:
+        """Values cached a token a layer by latent attention (0 = per-head K/V)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim if self.kv_lora_rank else 0
+
+    @property
+    def softmax_scale(self) -> float:
+        """1/sqrt(head_dim), times YaRN's mscale(factor, mscale_all_dim) squared."""
+        scale = self.head_dim ** -0.5
+        if self.rope_scaling == "yarn" and self.rope_mscale_all_dim:
+            scale *= yarn_mscale(self.rope_factor, self.rope_mscale_all_dim) ** 2
+        return scale
+
+    @property
+    def rope_yarn(self) -> Optional[Tuple[float, int, float, float, float]]:
+        """What ``layers.rope_table`` needs for YaRN: (factor, original
+        context, beta_fast, beta_slow, cos/sin scale), or None."""
+        if self.rope_scaling != "yarn":
+            return None
+        scale = yarn_mscale(self.rope_factor, self.rope_mscale) / yarn_mscale(
+            self.rope_factor, self.rope_mscale_all_dim
+        )
+        return (self.rope_factor, self.rope_original_context, self.rope_beta_fast,
+                self.rope_beta_slow, scale)
+
     def num_params(self) -> int:
         """Analytic parameter count (matches init_params exactly; tested)."""
-        d, h, dh, f, v, t = (
-            self.d_model,
-            self.n_heads,
-            self.head_dim,
-            self.d_ff,
-            self.vocab_size,
-            self.context_length,
-        )
+        d, v, t = self.d_model, self.vocab_size, self.context_length
         n = v * d  # token embedding
         if self.pos_embed == "learned":
             n += t * d
-        g = self.kv_heads
-        per_block = 0
-        per_block += 2 * self._norm_params()  # ln1, ln2
-        per_block += d * h * dh + 2 * d * g * dh  # wqkv (or wq + wkv for GQA)
-        if self.qkv_bias:
-            per_block += h * dh + 2 * g * dh
-        if self.use_output_proj:
-            per_block += h * dh * d + d  # wo + bias
-        per_expert = self._per_expert_params()
-        if self.n_experts:
-            per_block += d * self.n_experts  # router
-            per_block += self.n_experts * per_expert
-        else:
-            per_block += per_expert
-        n += self.n_layers * per_block
+        shared = self._attn_params() + 2 * self._norm_params() + 2 * self._hc_params()
+        moe_layers = self.n_layers - self.n_dense_layers if self.n_experts else 0
+        n += (self.n_layers - moe_layers) * (shared + self._ffn_params(self.d_ff))
+        n += moe_layers * (shared + self._moe_params(self.n_experts))
         n += self._norm_params()  # final norm
         if not self.tie_embeddings:
             n += d * v
@@ -399,27 +523,59 @@ class ModelConfig:
                 n += v
         return n
 
+    def _attn_params(self) -> int:
+        d, h, dh, g = self.d_model, self.n_heads, self.head_dim, self.kv_heads
+        if self.kv_lora_rank:
+            r, c = self.q_lora_rank, self.kv_lora_rank
+            q = d * r + r + r * h * dh if r else d * h * dh  # down, its norm, up
+            kv = d * self.latent_dim + c + c * h * (self.qk_nope_head_dim + self.v_head_dim)
+            return q + kv + h * self.v_head_dim * d
+        n = d * h * dh + 2 * d * g * dh  # wqkv (or wq + wkv for GQA)
+        if self.qkv_bias:
+            n += h * dh + 2 * g * dh
+        if self.use_output_proj:
+            n += h * dh * d + d  # wo + bias
+        return n
+
+    def _hc_params(self) -> int:
+        """One sublayer's hyper-connection: phi, b and the three alphas."""
+        n = self.hc_mult
+        return (n * self.d_model + 1) * (n * n + 2 * n) + 3 if n > 1 else 0
+
     def _norm_params(self) -> int:
         return 2 * self.d_model if self.norm == "layernorm" else self.d_model
 
-    def _per_expert_params(self) -> int:
-        """One FFN's parameter count (the dense MLP, or one MoE expert)."""
-        d, f = self.d_model, self.d_ff
+    def _ffn_params(self, f: int) -> int:
+        """One FFN of width ``f``: the dense MLP, an expert, the shared expert."""
+        d = self.d_model
         if self.activation == "swiglu":
             return d * 2 * f + f * d + ((2 * f + d) if self.mlp_bias else 0)
         return d * f + f * d + ((f + d) if self.mlp_bias else 0)
+
+    def _per_expert_params(self) -> int:
+        return self._ffn_params(self.expert_width)
+
+    def _moe_params(self, n_routed: int) -> int:
+        """An expert layer's FFN side with ``n_routed`` of its experts counted."""
+        n = self.d_model * self.n_experts + n_routed * self._per_expert_params()
+        if self.moe_score_bias:
+            n += self.n_experts
+        if self.n_shared_experts:
+            n += self._ffn_params(self.n_shared_experts * self.expert_width)
+        return n
 
     def num_active_params(self) -> int:
         """Params a single token's forward actually touches.
 
         Equal to num_params for dense models; for MoE only experts_per_token
-        of the n_experts FFNs execute per token, so MFU/throughput math must
-        not count the inactive experts' weights.
+        of the n_experts FFNs execute per token (the shared expert always
+        does), so MFU/throughput math must not count the inactive experts'
+        weights.
         """
         n = self.num_params()
         if self.n_experts:
             inactive = self.n_experts - self.experts_per_token
-            n -= self.n_layers * inactive * self._per_expert_params()
+            n -= (self.n_layers - self.n_dense_layers) * inactive * self._per_expert_params()
         return n
 
     def flops_per_token(self) -> int:
@@ -436,8 +592,13 @@ class ModelConfig:
         does skip masked blocks), so the O(T^2) term carries a 1/2 factor —
         counting the full square would overstate MFU on long contexts.
         MoE counts only the experts_per_token experts a token executes.
+        Latent attention's scores run over qk_nope + qk_rope and its values
+        over v_head_dim, so d_attn is the mean of the two widths there (the
+        expanded form; the absorbed decode form is not a training path).
         """
         d_attn = self.n_heads * self.head_dim
+        if self.kv_lora_rank:
+            d_attn = self.n_heads * (self.head_dim + self.v_head_dim) // 2
         return (
             6 * self.num_active_params()
             + 12 * self.n_layers * d_attn * self.context_length // 2
@@ -1387,6 +1548,29 @@ _register(
         ),
         mesh=MeshConfig(data=-1, expert=4),
         train=TrainConfig(batch_size=32, lr=3e-4),
+    ),
+)
+
+# Every mechanism of the Xing4.0 family at a width a CPU smoke run holds:
+# latent attention under YaRN, a leading dense layer, dropless sigmoid-routed
+# experts with a shared one, four residual streams. The published widths are
+# benchmark/configs/xing4.0-29b-a4b.json; this is for tests and serve.py.
+_register(
+    "xing-mini",
+    Config(
+        model=ModelConfig(
+            vocab_size=256, context_length=256, d_model=64, n_heads=4, n_layers=3, d_head=24,
+            mlp_ratio=2.5, activation="swiglu", norm="rmsnorm", pos_embed="rope",
+            tie_embeddings=False, mlp_bias=False, norm_eps=1e-6,
+            kv_lora_rank=32, q_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            rope_scaling="yarn", rope_factor=64.0, rope_original_context=64, rope_mscale_all_dim=1.0,
+            n_experts=8, experts_per_token=2, moe_routing="dropless", moe_score="sigmoid",
+            moe_score_bias=True, moe_routed_scale=2.0, n_shared_experts=1, d_expert=32,
+            n_dense_layers=1, hc_mult=4,
+        ),
+        mesh=MeshConfig(),
+        data=DataConfig(tokenizer_name="byte"),
+        train=TrainConfig(batch_size=8, train_steps=50, eval_interval=20, eval_iters=2, lr=1e-3),
     ),
 )
 

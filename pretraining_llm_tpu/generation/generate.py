@@ -275,10 +275,11 @@ def generate(
             f"context_length={cfg.context_length}"
         )
     if prompt_lengths is not None:
-        if cfg.n_experts:
+        if cfg.moe_capacity:
             raise ValueError(
-                "ragged prompt_lengths is unsupported for MoE models: left-"
-                "pad slots would compete for expert capacity during prefill"
+                "ragged prompt_lengths is unsupported for capacity-routed MoE "
+                "models: left-pad slots would compete for expert capacity "
+                "during prefill (dropless routing has none)"
             )
         lengths = jnp.asarray(prompt_lengths, jnp.int32).reshape(-1)
         if lengths.shape[0] != prompt.shape[0]:
@@ -297,7 +298,7 @@ def generate(
     # hidden states — bucketing is for dense models only.
     bucket = (
         prompt_len
-        if cfg.n_experts
+        if cfg.moe_capacity
         else _bucket_len(prompt_len, cfg.context_length, max_new_tokens)
     )
     # Ragged rows occupy slots up to bucket+max_new (dead left-pads
@@ -466,11 +467,11 @@ def generate_text_batch(
     # capacity); a uniform-length batch — incl. every single-prompt call —
     # needs no ragged machinery, which keeps generate_text working for MoE.
     uniform = bool((lengths == lengths[0]).all())
-    if cfg.model.n_experts and not uniform:
+    if cfg.model.moe_capacity and not uniform:
         raise ValueError(
-            "MoE models require equal-length prompts per batch (ragged "
-            "left-pad slots would compete for expert capacity); generate "
-            "each prompt separately or group by length"
+            "capacity-routed MoE models require equal-length prompts per "
+            "batch (ragged left-pad slots would compete for expert capacity); "
+            "generate each prompt separately or group by length"
         )
     use_lengths = None if uniform else lengths
     out = np.asarray(

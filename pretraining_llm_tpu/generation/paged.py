@@ -47,7 +47,16 @@ _POOL_OF_DENSE = {
     "v": "v_pool",
     "k_scale": "k_scale_pool",
     "v_scale": "v_scale_pool",
+    "latent": "latent_pool",
+    "rope": "rope_pool",
 }
+
+
+def pool_block_size(pools: transformer.KVCache) -> int:
+    """Tokens a page of ``pools`` holds (either container layout, per-head or latent)."""
+    fields = pools["layers"][0] if "layers" in pools else pools
+    pool = fields["k_pool"] if "k_pool" in fields else fields["latent_pool"]
+    return int(pool.shape[1 if "layers" in pools else 2])
 
 
 def required_blocks(n_tokens: int, block_size: int) -> int:
@@ -137,7 +146,7 @@ def _scatter_staged_pages(
     ``n_chunks`` pages and scattered at ``flat_ids`` (pad pages point at
     the reserved scratch block 0 — duplicate indices there are benign)."""
 
-    def _fields(layer_pool, dense_layer):
+    def _fields(layer_pool, dense_layer, stacked):
         out = dict(layer_pool)
         scattered = 0
         for dense_key, pool_key in _POOL_OF_DENSE.items():
@@ -145,13 +154,13 @@ def _scatter_staged_pages(
                 continue
             scattered += 1
             buf = dense_layer(dense_cache[dense_key])  # (N, P, ...) or (L, N, P, ...)
-            lead = buf.shape[: buf.ndim - 4]  # () per-layer, (L,) stacked
-            tail = buf.shape[-2:]
-            pages = buf.reshape(lead + (n_chunks, -1) + tail)
+            pool = layer_pool[pool_key]
+            lead = buf.shape[:1] if stacked else ()  # (L,) stacked, () per-layer
+            # a page is whatever one block of this pool holds: (bs, G, Dh) per
+            # head, (bs, c) of latents, (bs * r,) of rotated key slices
+            pages = buf.reshape(lead + (n_chunks,) + pool.shape[len(lead) + 1 :])
             sel = (flat_ids,) if not lead else (slice(None), flat_ids)
-            out[pool_key] = layer_pool[pool_key].at[sel].set(
-                pages.astype(layer_pool[pool_key].dtype)
-            )
+            out[pool_key] = pool.at[sel].set(pages.astype(pool.dtype))
         if not scattered:
             # A container-layout mismatch (e.g. an unstacked staging
             # cache) would otherwise silently prefill NOTHING.
@@ -165,11 +174,45 @@ def _scatter_staged_pages(
         if "layers" in pools:
             return {
                 "layers": tuple(
-                    _fields(pools["layers"][layer], lambda buf, _l=layer: buf[_l])
+                    _fields(pools["layers"][layer], lambda buf, _l=layer: buf[_l], False)
                     for layer in range(len(pools["layers"]))
                 )
             }
-        return _fields(pools, lambda buf: buf)
+        return _fields(pools, lambda buf: buf, True)
+
+
+# A prefill computes the f32 logits of every position and keeps the last real
+# one of each row. Past this many bytes of logits (a long prompt under a large
+# vocabulary: 8,192 positions of 131,072 are 4.3 GB a row) the head runs on the
+# last positions' hidden states alone; the same numbers, computed for fewer rows.
+_ALL_POSITION_LOGITS_BYTES = 2 << 30
+
+
+def _prefill_last_logits(
+    params: Any, prompts: jax.Array, last_idx: jax.Array, cfg: ModelConfig,
+    cache: transformer.KVCache,
+) -> Tuple[jax.Array, transformer.KVCache]:
+    """Causal forward over (N, P) padded prompts into ``cache`` -> (logits
+    (N, V) f32 at each row's ``last_idx``, the filled cache)."""
+    n_rows, p_bucket = prompts.shape
+    if 4 * n_rows * p_bucket * cfg.vocab_size <= _ALL_POSITION_LOGITS_BYTES:
+        logits, cache = transformer.forward(
+            params, prompts, cfg, kv_cache=cache, cache_index=jnp.int32(0)
+        )
+        last = jnp.take_along_axis(
+            logits,
+            jnp.broadcast_to(last_idx[:, None, None], (n_rows, 1, logits.shape[-1])),
+            axis=1,
+        )[:, 0]
+        return last, cache
+    hidden, cache = transformer.forward(
+        params, prompts, cfg, kv_cache=cache, cache_index=jnp.int32(0),
+        return_pre_logits=True,
+    )
+    last_h = jnp.take_along_axis(
+        hidden, jnp.broadcast_to(last_idx[:, None, None], (n_rows, 1, hidden.shape[-1])), axis=1
+    )
+    return transformer.lm_head(params, last_h, cfg)[:, 0], cache
 
 
 @functools.partial(jax.jit, static_argnames=("n_pages",), donate_argnums=(0,))
@@ -214,6 +257,11 @@ def _prefill_dense(
         cache = transformer.make_kv_cache(
             _dc.replace(cfg, decode_cache_layout="stacked"), 1, p_bucket
         )
+        if 4 * p_bucket * cfg.vocab_size > _ALL_POSITION_LOGITS_BYTES:
+            last, cache = _prefill_last_logits(
+                params, prompt, (prompt_len - 1).astype(jnp.int32)[None], cfg, cache
+            )
+            return last[0], cache
         logits, cache = transformer.forward(
             params, prompt, cfg, kv_cache=cache, cache_index=jnp.int32(0)
         )
@@ -239,10 +287,7 @@ def prefill_into_pool(
     (allocator output). Returns (last-token logits (V,) fp32, updated
     pools). Compiles once per page count, not per prompt length.
     """
-    if "layers" in pools:
-        block_size = int(pools["layers"][0]["k_pool"].shape[1])
-    else:
-        block_size = int(pools["k_pool"].shape[2])
+    block_size = pool_block_size(pools)
     p = len(prompt_ids)
     if p == 0:
         raise ValueError("empty prompt")
@@ -314,15 +359,8 @@ def _prefill_scatter_sample(
         cache = transformer.make_kv_cache(
             _dc.replace(cfg, decode_cache_layout="stacked"), n_rows, p_bucket
         )
-        logits, cache = transformer.forward(
-            params, prompts, cfg, kv_cache=cache, cache_index=jnp.int32(0)
-        )
         idx = jnp.clip(prompt_lens - 1, 0, p_bucket - 1).astype(jnp.int32)
-        last = jnp.take_along_axis(
-            logits,
-            jnp.broadcast_to(idx[:, None, None], (n_rows, 1, logits.shape[-1])),
-            axis=1,
-        )[:, 0]
+        last, cache = _prefill_last_logits(params, prompts, idx, cfg, cache)
         toks = sample_logits(
             last, key, temperature=temperature, top_k=top_k, top_p=top_p,
             min_p=min_p,
@@ -356,10 +394,7 @@ def prefill_into_pool_batched(
     pages. Rows and pages are bucketed to powers of two so the jit cache
     stays at O(log(max_batch) * log(max_pages)) program variants.
     """
-    if "layers" in pools:
-        block_size = int(pools["layers"][0]["k_pool"].shape[1])
-    else:
-        block_size = int(pools["k_pool"].shape[2])
+    block_size = pool_block_size(pools)
     n = len(prompts)
     if n == 0:
         raise ValueError("no prompts")
@@ -534,6 +569,7 @@ def prefill_suffix_into_pool_batched(
 def _forward_sample_one(
     params, pools, tokens, block_tables, seq_lens, key, cfg,
     temperature, top_k, top_p, min_p, mesh=None, logprobs_k=0,
+    with_moe_counts=False,
 ):
     """The single decode step both jitted entry points trace: forward one
     token per row through the paged cache, sample the next. Kept as ONE
@@ -542,21 +578,31 @@ def _forward_sample_one(
     Returns ``(next_token (B,), logprobs, pools)`` — ``logprobs`` is
     ``None`` unless ``logprobs_k > 0``, in which case it is the
     ``(values (B, k), ids (B, k))`` top-k log-softmax of the raw logits
-    (the decode-fused host payload; see `sample_logits_fused`)."""
+    (the decode-fused host payload; see `sample_logits_fused`).
+    ``with_moe_counts`` (dropless expert models) appends the step's tokens
+    per expert, (expert layers, E) int32."""
     from pretraining_llm_tpu.parallel.sharding import activation_mesh
 
     with activation_mesh(mesh):
-        logits, pools = transformer.forward(
-            params,
-            tokens[:, None],
-            cfg,
-            kv_cache=pools,
-            paged=PagedInfo(block_tables, seq_lens),
-        )
+        if with_moe_counts:
+            logits, pools, counts = transformer.forward(
+                params, tokens[:, None], cfg, kv_cache=pools,
+                paged=PagedInfo(block_tables, seq_lens), return_moe_counts=True,
+            )
+        else:
+            logits, pools = transformer.forward(
+                params,
+                tokens[:, None],
+                cfg,
+                kv_cache=pools,
+                paged=PagedInfo(block_tables, seq_lens),
+            )
         nxt, lp = sample_logits_fused(
             logits[:, 0], key, temperature=temperature, top_k=top_k,
             top_p=top_p, min_p=min_p, logprobs_k=logprobs_k,
         )
+        if with_moe_counts:
+            return nxt.astype(jnp.int32), lp, pools, counts
         return nxt.astype(jnp.int32), lp, pools
 
 
@@ -764,11 +810,23 @@ def paged_decode_steps(
     pages covering seq_len + n_steps writes per surviving row
     (ServingEngine._ensure_write_pages horizon).
 
-    Returns ((B, n_steps) sampled tokens in order, updated pools).
+    Returns ((B, n_steps) sampled tokens in order, updated pools). For a
+    dropless expert model the first value is a pair: the tokens, and the
+    window's routing counters ``{"expert_tokens": (expert layers, E) tokens
+    routed to each expert over the window, "experts_touched": (expert layers,)
+    experts that got a token, summed over the steps}`` — read back with the
+    tokens, in the same transfer.
     """
+    counted = cfg.moe_dropless
 
     def one(carry, sub):
         pools, tok, seq = carry
+        if counted:
+            nxt, _, pools, counts = _forward_sample_one(
+                params, pools, tok, block_tables, seq, sub, cfg,
+                temperature, top_k, top_p, min_p, mesh, with_moe_counts=True,
+            )
+            return (pools, nxt, seq + 1), (nxt, counts)
         nxt, _, pools = _forward_sample_one(
             params, pools, tok, block_tables, seq, sub, cfg,
             temperature, top_k, top_p, min_p, mesh,
@@ -779,6 +837,13 @@ def paged_decode_steps(
     (pools, _, _), toks = jax.lax.scan(
         one, (pools, tokens, seq_lens), subs
     )
+    if counted:
+        toks, counts = toks  # counts: (n_steps, expert layers, E)
+        moe = {
+            "expert_tokens": jnp.sum(counts, axis=0),
+            "experts_touched": jnp.sum((counts > 0).astype(jnp.int32), axis=(0, 2)),
+        }
+        return (toks.T, moe), pools
     return toks.T, pools  # (B, n_steps)
 
 

@@ -118,6 +118,7 @@ class _Window:
     emit: Any = None                # spec: (B, k+1) device emissions
     n_emit: Any = None              # spec: (B,) device per-row emit counts
     seq_dev: Any = None             # spec: (B,) device frontier at dispatch
+    moe: Any = None                 # decode, dropless experts: routing counters (device)
     t_dispatch: float = 0.0         # perf_counter at dispatch (trace spans)
 
 
@@ -166,10 +167,28 @@ class ServingEngine:
         fused_sampling: bool = True,
         logprobs_k: int = 0,
     ):
-        if cfg.n_experts:
+        if cfg.moe_capacity:
             # Same restriction as ragged generate: pad slots inside a
-            # prefill bucket would compete for expert capacity.
-            raise ValueError("paged serving does not support MoE models yet")
+            # prefill bucket would compete for expert capacity. Dropless
+            # routing has no capacity, and is served.
+            raise ValueError(
+                "paged serving does not support capacity-routed MoE models "
+                "(moe_routing='dropless' is served)"
+            )
+        if cfg.kv_lora_rank:
+            # A latent (MLA) page pool: what is not built on it yet (ROADMAP).
+            refused = {
+                "quantize": quantize != "none", "prefix_cache": prefix_cache,
+                "kv_checksum": kv_checksum, "spec_k": bool(spec_k),
+            }
+            if any(refused.values()):
+                raise ValueError(
+                    "a latent-attention model is served without "
+                    + ", ".join(k for k, v in refused.items() if v)
+                    + ": int8 pages, the prefix cache (and kv_transfer, which "
+                    "publishes into it) and speculative decoding are not built "
+                    "on the latent pool yet"
+                )
         if cfg.doc_mask_token >= 0:
             # Decode sessions are single documents; forward() rejects the
             # combination with a cache (same sanitization as generate()).
@@ -360,6 +379,14 @@ class ServingEngine:
             from jax.sharding import NamedSharding, PartitionSpec
 
             tp = mesh.shape.get("tensor", 1)
+            if pool_cfg.kv_lora_rank:
+                # one latent for all heads: nothing to split, every shard holds it
+                from jax.sharding import NamedSharding, PartitionSpec
+
+                return jax.tree.map(
+                    lambda leaf: jax.device_put(leaf, NamedSharding(mesh, PartitionSpec())),
+                    pools,
+                )
             head_ax = (
                 "tensor" if (tp > 1 and pool_cfg.kv_heads % tp == 0) else None
             )
@@ -523,7 +550,7 @@ class ServingEngine:
         )
         info = {
             "quantize": self.quantize,
-            "kv_dtype": str(layer0["k_pool"].dtype),
+            "kv_dtype": str(next(iter(layer0.values())).dtype),
             "kv_scale_dtype": (
                 str(layer0["k_scale_pool"].dtype)
                 if "k_scale_pool" in layer0 else None
@@ -531,6 +558,9 @@ class ServingEngine:
             "n_blocks": self.n_blocks,
             "block_size": self.block_size,
             "bytes_per_block": total // self.n_blocks,
+            # over all layers: 2 * kv_heads * Dh elements a layer per head,
+            # latent_dim elements a layer for a latent pool
+            "bytes_per_token": total // (self.n_blocks * self.block_size),
             "pool_bytes": total,
         }
         if self.d_pools is not None:
@@ -789,7 +819,7 @@ class ServingEngine:
         # here is on the WINDOW-START state only.
         paged.check_paged_bounds(self.tables, self.seq_lens, self.block_size)
         self._key, sub = jax.random.split(self._key)
-        toks, lp = self._decode_window(
+        toks, lp, moe = self._decode_window(
             jnp.asarray(self.tokens), jnp.asarray(self.tables),
             jnp.asarray(self.seq_lens), sub, n, raw_key_single=True,
         )
@@ -797,6 +827,8 @@ class ServingEngine:
         lp_host = None
         if lp is not None:
             lp_host = (np.asarray(lp[0]), np.asarray(lp[1]))
+        if moe is not None:
+            self._count_moe(moe, n)
         self.stats["steps"] += n
         for row, req in enumerate(self.rows):
             if req is None or req.prefill_pos is not None:
@@ -811,10 +843,12 @@ class ServingEngine:
     def _decode_window(self, base, tables_dev, seq_dev, key, n,
                        raw_key_single=False):
         """ONE definition of the decode-window device dispatch for the
-        synchronous and pipelined schedulers. Returns ``(toks, lp)``:
+        synchronous and pipelined schedulers. Returns ``(toks, lp, moe)``:
         ``toks`` a (B, n) DEVICE token array (the pipelined path chains
         its last column without a sync), ``lp`` None or the device
-        ``((B, n, k) values, (B, n, k) ids)`` logprob sliver.
+        ``((B, n, k) values, (B, n, k) ids)`` logprob sliver, ``moe`` None
+        or a dropless expert model's device routing counters of the window
+        (``paged.paged_decode_steps``; the other lanes do not count).
 
         Fused (default): sampling runs inside the jitted step program —
         the host payload per window is token ids (+ the optional
@@ -852,25 +886,53 @@ class ServingEngine:
                 )
                 cols.append(tok)
                 seq = seq + 1
-            return jnp.stack(cols, axis=1), None
+            return jnp.stack(cols, axis=1), None, None
         dev_args = (self.params, self.pools, base, tables_dev, seq_dev, key)
         if self.logprobs_k:
             if single:
                 nxt, lpv, lpi, self.pools = paged.paged_decode_step_lp(
                     *dev_args, logprobs_k=self.logprobs_k, **common
                 )
-                return nxt[:, None], (lpv[:, None], lpi[:, None])
+                return nxt[:, None], (lpv[:, None], lpi[:, None]), None
             toks, lpv, lpi, self.pools = paged.paged_decode_steps_lp(
                 *dev_args, n_steps=n, logprobs_k=self.logprobs_k, **common
             )
-            return toks, (lpv, lpi)
+            return toks, (lpv, lpi), None
         if single:
             nxt, self.pools = paged.paged_decode_step(*dev_args, **common)
-            return nxt[:, None], None
+            return nxt[:, None], None, None
         toks, self.pools = paged.paged_decode_steps(
             *dev_args, n_steps=n, **common
         )
-        return toks, None
+        if self.cfg.moe_dropless:
+            # the window's routing counters ride beside its tokens
+            return toks[0], None, toks[1]
+        return toks, None, None
+
+    def _count_moe(self, moe: Any, n_steps: int) -> Dict[str, int]:
+        """Add a reaped window's routing counters into the stats:
+        ``moe_expert_tokens`` (expert layers, E) tokens routed to each expert,
+        ``moe_experts_touched`` (expert layers,) experts that got a token,
+        summed over steps, and ``moe_steps``. Idle rows route too (their
+        tokens are discarded, their experts are read all the same). Returns
+        the window's own totals, the ``serving.commit`` span's metadata:
+        steps, expert layers, experts a layer, experts touched, pairs routed,
+        and the busiest expert's pairs summed over layers."""
+        tokens = np.asarray(moe["expert_tokens"], np.int64)
+        touched = np.asarray(moe["experts_touched"], np.int64)
+        st = self.stats
+        if "moe_steps" not in st:
+            st["moe_expert_tokens"] = np.zeros_like(tokens)
+            st["moe_experts_touched"] = np.zeros_like(touched)
+            st["moe_steps"] = 0
+        st["moe_expert_tokens"] += tokens
+        st["moe_experts_touched"] += touched
+        st["moe_steps"] += n_steps
+        return dict(
+            moe_steps=n_steps, moe_layers=tokens.shape[0], moe_experts=tokens.shape[1],
+            moe_touched=int(touched.sum()), moe_routed=int(tokens.sum()),
+            moe_busiest=int(tokens.max(axis=-1).sum()),
+        )
 
     def _spec_step(self) -> bool:
         """One speculative round for every active row: k draft proposals,
@@ -1072,7 +1134,7 @@ class ServingEngine:
                 base = jnp.asarray(self.tokens)
             base = self._merge_admitted(base)
             self._key, sub = jax.random.split(self._key)
-            toks, lp = self._decode_window(
+            toks, lp, moe = self._decode_window(
                 base, jnp.asarray(self.tables), jnp.asarray(seq_dispatch),
                 sub, n,
             )
@@ -1083,7 +1145,7 @@ class ServingEngine:
             self.seq_lens[i] = min(int(self.seq_lens[i]) + n, capacity)
         self._inflight.append(
             _Window(kind="decode", snapshot=snapshot, n=n, toks=toks,
-                    lp=lp, t_dispatch=time.perf_counter())
+                    lp=lp, t_dispatch=time.perf_counter(), moe=moe)
         )
 
     def _dispatch_spec_round(self) -> None:
@@ -1164,6 +1226,7 @@ class ServingEngine:
         host-blocked time deep pipelining exists to hide — measured per
         window into stats and the span's trace args."""
         widx = self.stats["windows_reaped"]
+        moe_meta: Dict[str, int] = {}
         with _spans.span("serving.reap_window", window=widx) as reap:
             with self._clock.span("host_blocked", "serving.host_blocked") as wait:
                 if w.kind == "spec":
@@ -1174,6 +1237,8 @@ class ServingEngine:
                     lp_host = None
                     if w.lp is not None:
                         lp_host = (np.asarray(w.lp[0]), np.asarray(w.lp[1]))
+                    if w.moe is not None:
+                        moe_meta = self._count_moe(w.moe, w.n)
             t0, t_reaped = wait.t0, wait.t1
             blocked = t_reaped - t0
             reap.set(host_blocked_s=round(blocked, 6))
@@ -1185,7 +1250,9 @@ class ServingEngine:
                 self.host_blocked_hist.observe(blocked)
             capacity = self.max_blocks * self.block_size
             toks_before = self.stats["tokens"]
-            with self._clock.span("commit", "serving.commit", rows=len(w.snapshot)):
+            with self._clock.span(
+                "commit", "serving.commit", rows=len(w.snapshot), **moe_meta
+            ):
                 for row, req in w.snapshot:
                     if req.row != row or self.rows[row] is not req:
                         # The row finished in an earlier reap and may have

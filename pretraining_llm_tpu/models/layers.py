@@ -7,12 +7,25 @@ with bf16 matmul inputs (TPU MXU native), and pluggable position encodings.
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+import math
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 Params = Dict[str, jax.Array]
+
+
+def weight(sub: Params, name: str, cdt) -> jax.Array:
+    """Matmul weight read in compute dtype — the single dequant point for
+    int8 serving params (models/quantize.py). A quantized projection is an
+    int8 leaf plus a sibling ``{name}_scale`` fp32 leaf (per-output-channel
+    symmetric); dequant is one fp32 multiply, then the SAME compute-dtype
+    cast the bf16 path takes, so the matmul accumulates identically."""
+    w = sub[name]
+    if w.dtype == jnp.int8:
+        return (w.astype(jnp.float32) * sub[name + "_scale"]).astype(cdt)
+    return w.astype(cdt)
 
 
 # ---------------------------------------------------------------------------
@@ -65,9 +78,39 @@ def activation_fn(kind: str, x: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def rope_table(context_length: int, head_dim: int, theta: float) -> Tuple[jax.Array, jax.Array]:
-    """(cos, sin) tables of shape (T, head_dim // 2), fp32."""
+def yarn_inv_freq(
+    head_dim: int, theta: float, factor: float, original_context: int,
+    beta_fast: float, beta_slow: float,
+) -> jax.Array:
+    """YaRN's frequencies (head_dim // 2,): pairs that turn more than
+    ``beta_fast`` times inside the original context keep their frequency,
+    pairs that turn fewer than ``beta_slow`` times are slowed by ``factor``,
+    and a linear ramp over the pair index lies between."""
     half = head_dim // 2
+
+    def pair_of(turns: float) -> float:
+        return head_dim * math.log(original_context / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), head_dim - 1)
+    ramp = jnp.clip(
+        (jnp.arange(half, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0.0, 1.0
+    )
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    return freqs / factor * ramp + freqs * (1.0 - ramp)
+
+
+def rope_table(
+    context_length: int, head_dim: int, theta: float,
+    yarn: Optional[Tuple[float, int, float, float, float]] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """(cos, sin) tables of shape (T, head_dim // 2), fp32. ``yarn`` =
+    (factor, original context, beta_fast, beta_slow, cos/sin scale)."""
+    half = head_dim // 2
+    if yarn is not None:
+        freqs = yarn_inv_freq(head_dim, theta, *yarn[:4])
+        angles = jnp.arange(context_length, dtype=jnp.float32)[:, None] * freqs[None, :]
+        return jnp.cos(angles) * yarn[4], jnp.sin(angles) * yarn[4]
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = jnp.arange(context_length, dtype=jnp.float32)[:, None] * freqs[None, :]
     return jnp.cos(angles), jnp.sin(angles)

@@ -23,6 +23,12 @@ parallelism strategy left open. This is the TPU-native design:
     dropped tokens fall back to the residual stream (their MoE output is 0).
   - Switch-style load-balance auxiliary loss in fp32, threaded through the
     block scan and added to the task loss as `router_aux_coef * aux`.
+
+That capacity path is the training path. Serving has a second one at the end
+of this module, ``cfg.moe_routing == "dropless"``: no capacity and no drop
+(sort the (token, choice) pairs by expert, one grouped matmul a projection),
+sigmoid or softmax scores, a selection-only bias, a shared expert; a token's
+output there never depends on what shares its batch or its padded bucket.
 """
 
 from __future__ import annotations
@@ -34,6 +40,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from pretraining_llm_tpu.config import ModelConfig
+from pretraining_llm_tpu.models.layers import weight as _weight
 from pretraining_llm_tpu.parallel.sharding import constrain
 
 Params = Dict[str, Any]
@@ -199,3 +206,113 @@ def moe_mlp(
     # accumulation is exact enough; same CPU batched-dot dtype caveat as xin.
     y = jnp.einsum("gsec,gecd->gsd", combine.astype(ddt), out.astype(ddt))
     return y.astype(h.dtype).reshape(b, t, d), aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless routing (serving; cfg.moe_routing == "dropless")
+# ---------------------------------------------------------------------------
+
+
+def init_dropless_params(
+    cfg: ModelConfig, key: jax.Array, resid_std: float, dtype: jnp.dtype
+) -> Params:
+    """Router (D, E) [+ selection bias (E,)], experts stored as the grouped
+    matmul reads them — w1 (E, D, 2F) with the gate columns before the up
+    columns, w2 (E, F, D) — and the shared expert as a dense SwiGLU."""
+    d, f, e = cfg.d_model, cfg.expert_width, cfg.n_experts
+    ks = jax.random.split(key, 5)
+
+    def normal(k: jax.Array, shape: Tuple[int, ...], s: float = 0.02) -> jax.Array:
+        return (jax.random.normal(k, shape, jnp.float32) * s).astype(dtype)
+
+    out: Params = {
+        "router": normal(ks[0], (d, e)),
+        "experts": {"w1": normal(ks[1], (e, d, 2 * f)), "w2": normal(ks[2], (e, f, d), resid_std)},
+    }
+    if cfg.moe_score_bias:
+        out["router_bias"] = jnp.zeros((e,), dtype)
+    if cfg.n_shared_experts:
+        fs = cfg.n_shared_experts * f
+        out["shared"] = {"w1": normal(ks[3], (d, 2, fs)), "w2": normal(ks[4], (fs, d), resid_std)}
+    return out
+
+
+def route_dropless(mlp: Params, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
+    """x (S, D) -> (expert ids (S, K) int32, gates (S, K) float32). Scores in
+    float32; the bias enters the selection only; the gates are the unbiased
+    scores of the selected experts, renormalised and scaled."""
+    logits = jnp.einsum(
+        "sd,de->se", x.astype(jnp.float32), mlp["router"].astype(jnp.float32),
+        preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+    )
+    scores = jax.nn.sigmoid(logits) if cfg.moe_score == "sigmoid" else jax.nn.softmax(logits, -1)
+    select = scores + mlp["router_bias"].astype(jnp.float32) if "router_bias" in mlp else scores
+    _, idx = jax.lax.top_k(select, cfg.experts_per_token)
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    if cfg.moe_norm_topk:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), gates * cfg.moe_routed_scale
+
+
+def moe_mlp_dropless(
+    mlp: Params, h: jax.Array, cfg: ModelConfig, dense_mlp: Any
+) -> Tuple[jax.Array, jax.Array]:
+    """Dropless expert FFN on normed input h (B, T, D) -> (output, tokens
+    routed to each expert (E,) int32).
+
+    The (token, choice) pairs are sorted by expert, each projection is one
+    grouped matmul over the experts held (``jax.lax.ragged_dot``: on TPU a
+    native grouped-matmul kernel that reads only the experts some token chose),
+    the result is un-sorted and the K weighted parts of a token summed. The
+    same program shape serves an 8 k-token prefill and a 32-row decode step,
+    and a token's output is a function of that token alone.
+
+    The layer holds the experts its weights carry, ``w1.shape[0]`` of the
+    ``cfg.n_experts`` the router scores, from ``cfg``'s first expert on: a
+    pair routed to an expert that lives elsewhere adds nothing here (expert
+    parallelism's share of the result, without the exchange).
+    ``dense_mlp(params, h)`` is the model's dense SwiGLU, for the shared expert.
+
+    Inside a layer stack ``mlp["experts"]`` is the stack's weights, (L, E, ...),
+    and ``mlp["expert_layer"]`` says which layer this is: the grouped matmul
+    then runs over all L * E groups with every other layer's group empty. A
+    per-layer slice of the stack would be copied for the kernel each call
+    (0.94 + 0.47 GB a layer at 64 experts of 3584 x 1024); an empty group costs
+    nothing and the stack is read where it lies.
+    """
+    cdt = jnp.dtype(cfg.compute_dtype)
+    b, t, d = h.shape
+    s, k = b * t, cfg.experts_per_token
+    x = h.reshape(s, d)
+    ex = mlp["experts"]
+    w1, w2 = _weight(ex, "w1", cdt), _weight(ex, "w2", cdt)
+    held, f = w1.shape[-3], w2.shape[-2]
+    with jax.named_scope("moe.router"):
+        idx, gates = route_dropless(mlp, x, cfg)
+    with jax.named_scope("moe.dispatch"):
+        flat = idx.reshape(s * k)
+        flat = jnp.where(flat < held, flat, held)  # experts held elsewhere sort last
+        order = jnp.argsort(flat, stable=True)
+        counts = jnp.bincount(flat, length=held + 1).astype(jnp.int32)
+        xs = x[order // k].astype(cdt)  # (S*K, D), rows grouped by expert
+        sizes = counts[:held]
+        if "expert_layer" in mlp:
+            n_stack = w1.shape[0]
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((n_stack * held,), jnp.int32), sizes, (mlp["expert_layer"] * held,)
+            )
+            w1, w2 = w1.reshape((n_stack * held,) + w1.shape[2:]), w2.reshape((n_stack * held,) + w2.shape[2:])
+    with jax.named_scope("moe.experts"):
+        up = jax.lax.ragged_dot(xs, w1, sizes, preferred_element_type=cdt)
+        hidden = jax.nn.silu(up[:, :f]) * up[:, f:]
+        ys = jax.lax.ragged_dot(hidden, w2, sizes, preferred_element_type=cdt)
+    with jax.named_scope("moe.combine"):
+        g_sorted = jnp.where(flat[order] < held, gates.reshape(s * k)[order], 0.0)
+        ys = (ys.astype(jnp.float32) * g_sorted[:, None]).astype(cdt)
+        y = jnp.sum(
+            ys[jnp.argsort(order)].reshape(s, k, d).astype(jnp.float32), axis=1
+        ).astype(cdt).reshape(b, t, d)
+    if "shared" in mlp:
+        with jax.named_scope("moe.shared"):
+            y = y + dense_mlp(mlp["shared"], h)
+    return y.astype(h.dtype), counts[:held]

@@ -36,7 +36,7 @@ from jax.sharding import PartitionSpec as P
 
 
 from pretraining_llm_tpu.config import ModelConfig
-from pretraining_llm_tpu.models import layers, moe
+from pretraining_llm_tpu.models import hyper, layers, mla, moe
 from pretraining_llm_tpu.ops import remat
 from pretraining_llm_tpu.ops.attention import multihead_attention
 from pretraining_llm_tpu.parallel.sharding import constrain, current_mesh
@@ -122,9 +122,11 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 
     g = cfg.kv_heads
 
-    def init_block(k: jax.Array) -> Params:
+    def init_block(k: jax.Array, dense_ffn: bool = False) -> Params:
         ks = jax.random.split(k, 5)
-        if g == h:
+        if cfg.kv_lora_rank:
+            attn: Params = mla.init_attn_params(cfg, ks[0], resid_std, dtype)
+        elif g == h:
             attn: Params = {"wqkv": normal(ks[0], (d, 3, h, dh))}
             if cfg.qkv_bias:
                 attn["bqkv"] = jnp.zeros((3, h, dh), dtype)
@@ -137,10 +139,12 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             if cfg.qkv_bias:
                 attn["bq"] = jnp.zeros((h, dh), dtype)
                 attn["bkv"] = jnp.zeros((2, g, dh), dtype)
-        if cfg.use_output_proj:
+        if cfg.use_output_proj and not cfg.kv_lora_rank:
             attn["wo"] = normal(ks[1], (h, dh, d), resid_std)
             attn["bo"] = jnp.zeros((d,), dtype)
-        if cfg.n_experts:
+        if cfg.moe_dropless and not dense_ffn:
+            mlp: Params = moe.init_dropless_params(cfg, ks[2], resid_std, dtype)
+        elif cfg.n_experts and not dense_ffn:
             mlp: Params = moe.init_moe_params(cfg, ks[2], resid_std, dtype)
         elif cfg.activation == "swiglu":
             mlp: Params = {"w1": normal(ks[2], (d, 2, f)), "w2": normal(ks[3], (f, d), resid_std)}
@@ -152,21 +156,32 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             if cfg.mlp_bias:
                 mlp["b1"] = jnp.zeros((f,), dtype)
                 mlp["b2"] = jnp.zeros((d,), dtype)
-        return {
+        block = {
             "ln1": layers.init_norm(cfg.norm, d, dtype),
             "attn": attn,
             "ln2": layers.init_norm(cfg.norm, d, dtype),
             "mlp": mlp,
         }
+        if cfg.hc_mult > 1:
+            k_hc = jax.random.split(jax.random.fold_in(k, 7))
+            block["hc_attn"] = hyper.init_hc_params(cfg, k_hc[0], dtype)
+            block["hc_mlp"] = hyper.init_hc_params(cfg, k_hc[1], dtype)
+        return block
 
     # vmap over per-layer keys -> every block param gets a leading (n_layers,) dim
-    blocks = jax.vmap(init_block)(jax.random.split(k_blocks, nl))
+    layer_keys = jax.random.split(k_blocks, nl)
+    blocks = jax.vmap(init_block)(layer_keys[cfg.n_dense_layers:])
 
     params: Params = {
         "tok_embed": {"embedding": normal(k_tok, (v, d))},
         "blocks": blocks,
         "final_norm": layers.init_norm(cfg.norm, d, dtype),
     }
+    if cfg.n_dense_layers:
+        # the leading dense layers, a group of their own ahead of "blocks"
+        params["dense_blocks"] = jax.vmap(lambda k: init_block(k, dense_ffn=True))(
+            layer_keys[: cfg.n_dense_layers]
+        )
     if cfg.pos_embed == "learned":
         params["pos_embed"] = {"embedding": normal(k_pos, (t, d))}
     if not cfg.tie_embeddings:
@@ -181,16 +196,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def _weight(sub: Params, name: str, cdt: Any) -> jax.Array:
-    """Matmul weight read in compute dtype — the single dequant point for
-    int8 serving params (models/quantize.py). A quantized projection is an
-    int8 leaf plus a sibling ``{name}_scale`` fp32 leaf (per-output-channel
-    symmetric); dequant is one fp32 multiply, then the SAME compute-dtype
-    cast the bf16 path takes, so the matmul accumulates identically."""
-    w = sub[name]
-    if w.dtype == jnp.int8:
-        return (w.astype(jnp.float32) * sub[name + "_scale"]).astype(cdt)
-    return w.astype(cdt)
+_weight = layers.weight
 
 
 def _attention_block(
@@ -205,8 +211,11 @@ def _attention_block(
     pad_offsets: Optional[jax.Array] = None,
     segments: Optional[jax.Array] = None,
     paged: Optional[PagedInfo] = None,
+    residual: bool = True,
 ) -> Tuple[jax.Array, Optional[Params]]:
     """Pre-LN attention sub-block: x + attn(ln1(x)). Returns (x, new_kv).
+    ``residual=False`` returns attn(ln1(x)) alone: hyper-connections write
+    it into the streams themselves.
 
     ``pad_offsets`` (B,) enables RAGGED cached decode: row i is left-padded
     by pad_offsets[i] slots, so its token at cache slot s has logical
@@ -215,6 +224,12 @@ def _attention_block(
     per-row logical positions, and the kv mask excludes each row's dead
     pad slots.
     """
+    if cfg.kv_lora_rank:
+        if zigzag or segments is not None:
+            raise ValueError("latent attention has no ring layout and no document mask")
+        return mla.attention_block(
+            blk, x, cfg, rope, positions, kv, cache_index, pad_offsets, paged, residual
+        )
     cdt = jnp.dtype(cfg.compute_dtype)
     with jax.named_scope("blk.norm"):
         h = layers.apply_norm(cfg.norm, blk["ln1"], x, cfg.norm_eps)
@@ -637,42 +652,56 @@ def _attention_block(
                 out = out.transpose(0, 2, 1, 3)
             b, t = out.shape[:2]
             out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)
-        return x + out.astype(x.dtype), new_kv
+        return (x + out.astype(x.dtype) if residual else out.astype(x.dtype)), new_kv
+
+
+def _dense_mlp(mlp: Params, h: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The dense FFN on normed input: w2 . act(w1 . h), in compute dtype."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    if cfg.activation == "swiglu":
+        gates = jnp.einsum(
+            "btd,dcf->bctf", h, _weight(mlp, "w1", cdt), preferred_element_type=jnp.float32
+        ).astype(cdt)
+        if "b1" in mlp:
+            gates = gates + mlp["b1"].astype(cdt)[None, :, None, :]
+        hidden = jax.nn.silu(gates[:, 0]) * gates[:, 1]
+    else:
+        hidden = jnp.einsum(
+            "btd,df->btf", h, _weight(mlp, "w1", cdt), preferred_element_type=jnp.float32
+        ).astype(cdt)
+        if "b1" in mlp:
+            hidden = hidden + mlp["b1"].astype(cdt)
+        hidden = layers.activation_fn(cfg.activation, hidden)
+    hidden = checkpoint_name(hidden, "mlp_hidden")
+    out = jnp.einsum(
+        "btf,fd->btd", hidden, _weight(mlp, "w2", cdt), preferred_element_type=jnp.float32
+    ).astype(cdt)
+    if "b2" in mlp:
+        out = out + mlp["b2"].astype(cdt)
+    return out
 
 
 def _mlp_block(
-    blk: Params, x: jax.Array, cfg: ModelConfig, decode: bool = False
+    blk: Params, x: jax.Array, cfg: ModelConfig, decode: bool = False,
+    residual: bool = True,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Pre-LN MLP sub-block: x + mlp(ln2(x)). Returns (x, router aux loss)."""
+    """Pre-LN MLP sub-block: x + mlp(ln2(x)). Returns (x, router aux loss);
+    for a dropless expert layer the second value is the tokens routed to each
+    expert, (E,) int32, instead."""
     cdt = jnp.dtype(cfg.compute_dtype)
     with jax.named_scope("blk.norm"):
         h = layers.apply_norm(cfg.norm, blk["ln2"], x, cfg.norm_eps).astype(cdt)
     mlp = blk["mlp"]
     with jax.named_scope("mlp"):
-        if cfg.n_experts:
+        if "router" in mlp and cfg.moe_dropless:
+            out, aux = moe.moe_mlp_dropless(
+                mlp, h, cfg, lambda shared, hh: _dense_mlp(shared, hh, cfg)
+            )
+        elif "router" in mlp:
             out, aux = moe.moe_mlp(mlp, h, cfg, decode=decode)
-            return x + out.astype(x.dtype), aux
-        if cfg.activation == "swiglu":
-            gates = jnp.einsum(
-                "btd,dcf->bctf", h, _weight(mlp, "w1", cdt), preferred_element_type=jnp.float32
-            ).astype(cdt)
-            if "b1" in mlp:
-                gates = gates + mlp["b1"].astype(cdt)[None, :, None, :]
-            hidden = jax.nn.silu(gates[:, 0]) * gates[:, 1]
         else:
-            hidden = jnp.einsum(
-                "btd,df->btf", h, _weight(mlp, "w1", cdt), preferred_element_type=jnp.float32
-            ).astype(cdt)
-            if "b1" in mlp:
-                hidden = hidden + mlp["b1"].astype(cdt)
-            hidden = layers.activation_fn(cfg.activation, hidden)
-        hidden = checkpoint_name(hidden, "mlp_hidden")
-        out = jnp.einsum(
-            "btf,fd->btd", hidden, _weight(mlp, "w2", cdt), preferred_element_type=jnp.float32
-        ).astype(cdt)
-        if "b2" in mlp:
-            out = out + mlp["b2"].astype(cdt)
-        return x + out.astype(x.dtype), jnp.zeros((), jnp.float32)
+            out, aux = _dense_mlp(mlp, h, cfg), jnp.zeros((), jnp.float32)
+        return (x + out.astype(x.dtype) if residual else out.astype(x.dtype)), aux
 
 
 def _block(
@@ -688,6 +717,21 @@ def _block(
     segments: Optional[jax.Array] = None,
     paged: Optional[PagedInfo] = None,
 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
+    if cfg.hc_mult > 1:
+        # x is the token's (B, T, n, d) residual streams: each sublayer reads
+        # one vector from them and writes its output back (models/hyper.py).
+        coef = hyper.coefficients(blk["hc_attn"], x, cfg)
+        y, new_kv = _attention_block(
+            blk, hyper.read(coef, x), cfg, rope, positions, kv, cache_index, zigzag,
+            pad_offsets, segments=segments, paged=paged, residual=False,
+        )
+        x = hyper.write(coef, x, y)
+        coef = hyper.coefficients(blk["hc_mlp"], x, cfg)
+        y, aux = _mlp_block(
+            blk, hyper.read(coef, x), cfg, decode=kv is not None and x.shape[1] == 1,
+            residual=False,
+        )
+        return hyper.write(coef, x, y), new_kv, aux
     x, new_kv = _attention_block(
         blk, x, cfg, rope, positions, kv, cache_index, zigzag, pad_offsets,
         segments=segments, paged=paged,
@@ -725,8 +769,13 @@ def forward(
     blocks_baked: bool = False,
     pad_offsets: Optional[jax.Array] = None,
     paged: Optional[PagedInfo] = None,
+    return_moe_counts: bool = False,
 ) -> Tuple[jax.Array, Optional[KVCache]]:
     """Compute logits. tokens: (B, T) int32 -> logits (B, T, V) fp32.
+
+    ``return_moe_counts=True`` (dropless expert models) additionally returns
+    the tokens this call routed to each expert, per expert layer:
+    (n_layers - n_dense_layers, E) int32.
 
     ``paged`` + a pool-layout ``kv_cache`` (make_paged_kv_pool) selects
     PAGED single-token decode for continuous-batching serving: block
@@ -847,8 +896,19 @@ def forward(
                 x = x + pos_table[positions].astype(cdt)[None]
     rope = None
     if cfg.pos_embed != "learned":
-        rope = layers.rope_table(cfg.context_length, cfg.head_dim, cfg.rope_theta)
+        rope = layers.rope_table(
+            cfg.context_length, cfg.qk_rope_head_dim or cfg.head_dim, cfg.rope_theta, cfg.rope_yarn
+        )
     x = constrain(x, ("data", "fsdp"), "seq" if cfg.sequence_parallel else None, None)
+    if cfg.hc_mult > 1:
+        x = hyper.copy_in(x, cfg)
+
+    def in_stack(blk, experts, layer):
+        """``blk`` with its group's expert stack and its own place in it (see
+        moe.moe_mlp_dropless): the stack is closed over, never sliced."""
+        if experts is None:
+            return blk
+        return {**blk, "mlp": {**blk["mlp"], "experts": experts, "expert_layer": layer}}
 
     def scan_body(carry, layer_inputs):
         x, aux_sum = carry
@@ -858,15 +918,26 @@ def forward(
                 blk, x, cfg, rope, positions, None, None, zigzag,
                 segments=segments,
             )
+            if aux.ndim:  # a dropless layer's tokens per expert ride the outputs
+                return (x, aux_sum), ((x if return_hidden else None), aux)
             return (x, aux_sum + aux), (x if return_hidden else None)
         blk, cache_layer = layer_inputs
         x, new_kv, aux = _block(
             blk, x, cfg, rope, positions, cache_layer, cache_index,
             pad_offsets=pad_offsets, paged=paged,
         )
+        if aux.ndim:
+            return (x, aux_sum), (new_kv, aux)
         return (x, aux_sum + aux), new_kv
 
     body = remat.checkpoint_wrap(scan_body, cfg.remat)
+
+    def without_experts(blocks):
+        """(blocks less a dropless group's expert stack, that stack or None)."""
+        if not (cfg.moe_dropless and "experts" in blocks["mlp"]):
+            return blocks, None
+        mlp = {k: v for k, v in blocks["mlp"].items() if k != "experts"}
+        return {**blocks, "mlp": mlp}, blocks["mlp"]["experts"]
 
     mesh = current_mesh()
     use_pipeline = (
@@ -875,6 +946,48 @@ def forward(
         and mesh is not None
         and mesh.shape.get("pipe", 1) > 1
     )
+
+    # The stack as groups of like layers, each one scan: the leading dense
+    # layers of an expert model ("dense_blocks"), then "blocks". A homogeneous
+    # model is one group and traces exactly as it did before groups existed.
+    # Each group: (the layers it holds, its stacked blocks).
+    k = cfg.n_dense_layers if "dense_blocks" in params else 0
+    groups = [(range(k, cfg.n_layers), params["blocks"])]
+    if k:
+        groups.insert(0, (range(k), params["dense_blocks"]))
+    moe_counts = None
+
+    def scan_group(blocks, x, aux, cache=None):
+        """One group's depth scan -> (x, aux, per-layer outputs)."""
+        nonlocal moe_counts
+        blocks, experts = without_experts(blocks)
+        xs = blocks if cache is None else (blocks, cache)
+        step = body
+        if experts is not None:
+            # the expert stack is closed over; each layer gets its index in it
+            def with_experts(carry, inputs):
+                inputs, layer = inputs
+                if cache is None:
+                    return scan_body(carry, in_stack(inputs, experts, layer))
+                return scan_body(carry, (in_stack(inputs[0], experts, layer), inputs[1]))
+
+            n = jax.tree.leaves(blocks)[0].shape[0]
+            xs = (xs, jnp.arange(n, dtype=jnp.int32))
+            step = remat.checkpoint_wrap(with_experts, cfg.remat)
+        (x, aux), out = jax.lax.scan(step, (x, aux), xs, unroll=(
+            cfg.n_layers
+            if cache is not None and "layers" not in kv_cache
+            and cfg.decode_unroll_layers and x.shape[1] == 1
+            else cfg.scan_unroll
+        ))
+        if cfg.moe_dropless and "router" in blocks["mlp"]:
+            out, moe_counts = out  # an expert group yields (outputs, tokens per expert)
+        return x, aux, out
+
+    def concat_groups(outs):
+        return outs[0] if len(outs) == 1 else jax.tree.map(
+            lambda *a: jnp.concatenate(a, axis=0), *outs
+        )
 
     block_outputs = None
     aux0 = jnp.zeros((), jnp.float32)
@@ -888,6 +1001,8 @@ def forward(
     if use_pipeline:
         if return_hidden:
             raise ValueError("return_hidden is not supported with pipeline parallelism")
+        if len(groups) > 1:
+            raise ValueError("pipeline parallelism takes a homogeneous layer stack")
         from pretraining_llm_tpu.parallel import pipeline
 
         def pipe_block(blk, h):
@@ -901,9 +1016,11 @@ def forward(
         )
         new_cache = None
     elif kv_cache is None:
-        (x, aux_total), block_outputs = jax.lax.scan(
-            body, (x, aux0), params["blocks"], unroll=cfg.scan_unroll
-        )
+        aux_total, outs = aux0, []
+        for _, blocks in groups:
+            x, aux_total, out = scan_group(blocks, x, aux_total)
+            outs.append(out)
+        block_outputs = concat_groups(outs) if return_hidden else None
         new_cache = None
     elif "layers" in kv_cache:
         # UNSTACKED decode cache (decode_cache_layout='unstacked'):
@@ -924,36 +1041,42 @@ def forward(
             # keep the in-place layer loop below: they repeat every few
             # tokens, so per-round re-stack copies would claw back the
             # unstacked layout's win (boundary: decode_loop_max_tokens).
-            stacked_cache = {
-                name: jnp.stack([lyr[name] for lyr in kv_cache["layers"]])
-                for name in kv_cache["layers"][0]
-            }
-            (x, aux_total), new_stacked = jax.lax.scan(
-                body, (x, aux0), (params["blocks"], stacked_cache),
-                unroll=cfg.scan_unroll,
-            )
-            new_cache = {
-                "layers": tuple(
-                    {name: buf[layer] for name, buf in new_stacked.items()}
-                    for layer in range(cfg.n_layers)
-                )
-            }
+            aux_total, new_layers = aux0, []
+            for layers_of, blocks in groups:
+                lyrs = [kv_cache["layers"][i] for i in layers_of]
+                stacked_cache = {
+                    name: jnp.stack([lyr[name] for lyr in lyrs]) for name in lyrs[0]
+                }
+                x, aux_total, new_stacked = scan_group(blocks, x, aux_total, stacked_cache)
+                new_layers += [
+                    {name: buf[i] for name, buf in new_stacked.items()}
+                    for i in range(len(lyrs))
+                ]
+            new_cache = {"layers": tuple(new_layers)}
         else:
             aux_total = aux0
-            new_layers = []
-            for layer in range(cfg.n_layers):
-                blk = jax.tree.map(
-                    lambda a, _l=layer: jax.lax.index_in_dim(
-                        a, _l, 0, keepdims=False
-                    ),
-                    params["blocks"],
-                )
-                x, new_kv, aux = _block(
-                    blk, x, cfg, rope, positions, kv_cache["layers"][layer],
-                    cache_index, pad_offsets=pad_offsets, paged=paged,
-                )
-                aux_total = aux_total + aux
-                new_layers.append(new_kv)
+            new_layers, counts = [], []
+            for layers_of, blocks in groups:
+                blocks, experts = without_experts(blocks)
+                for i, layer in enumerate(layers_of):
+                    blk = jax.tree.map(
+                        lambda a, _l=i: jax.lax.index_in_dim(
+                            a, _l, 0, keepdims=False
+                        ),
+                        blocks,
+                    )
+                    blk = in_stack(blk, experts, i)
+                    x, new_kv, aux = _block(
+                        blk, x, cfg, rope, positions, kv_cache["layers"][layer],
+                        cache_index, pad_offsets=pad_offsets, paged=paged,
+                    )
+                    if aux.ndim:
+                        counts.append(aux)
+                    else:
+                        aux_total = aux_total + aux
+                    new_layers.append(new_kv)
+            if counts:
+                moe_counts = jnp.stack(counts)
             new_cache = {"layers": tuple(new_layers)}
     else:
         # Single-token decode steps may fully unroll the depth scan: the
@@ -964,16 +1087,17 @@ def forward(
         # (On-chip 2026-08-01: unroll measured SLOWER than the rolled scan
         # — the unstacked cache layout above is the measured fix for the
         # carry-copy problem instead.)
-        unroll = (
-            cfg.n_layers
-            if cfg.decode_unroll_layers and x.shape[1] == 1
-            else cfg.scan_unroll
-        )
-        (x, aux_total), new_cache = jax.lax.scan(
-            body, (x, aux0), (params["blocks"], kv_cache),
-            unroll=unroll,
-        )
+        aux_total, outs = aux0, []
+        for layers_of, blocks in groups:
+            cache = kv_cache if len(groups) == 1 else jax.tree.map(
+                lambda a: a[layers_of.start : layers_of.stop], kv_cache
+            )
+            x, aux_total, out = scan_group(blocks, x, aux_total, cache)
+            outs.append(out)
+        new_cache = concat_groups(outs)
 
+    if cfg.hc_mult > 1:
+        x = hyper.sum_out(x)
     with jax.named_scope("final_norm"):
         x = layers.apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
     if return_pre_logits:
@@ -981,21 +1105,30 @@ def forward(
         # _chunked_ce); hand back the final-norm hidden states.
         logits = x
     else:
-        w_out, head_bias = _lm_head_weights(params, cfg)
-        with jax.named_scope("lm_head"):
-            logits = jnp.einsum(
-                "btd,dv->btv", x.astype(cdt), w_out.astype(cdt), preferred_element_type=jnp.float32
-            )
-            if head_bias is not None:
-                logits = logits + head_bias.astype(jnp.float32)
+        logits = lm_head(params, x, cfg)
     extras: Tuple[Any, ...] = ()
     if return_hidden:
         extras += ({"block_outputs": block_outputs, "final_hidden": x},)
     if return_aux:
         extras += (aux_total,)
+    if return_moe_counts:
+        extras += (moe_counts,)
     if extras:
         return (logits, new_cache) + extras
     return logits, new_cache
+
+
+def lm_head(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """f32 logits (B, T, V) of final-norm hidden states (B, T, D)."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    w_out, head_bias = _lm_head_weights(params, cfg)
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum(
+            "btd,dv->btv", x.astype(cdt), w_out.astype(cdt), preferred_element_type=jnp.float32
+        )
+        if head_bias is not None:
+            logits = logits + head_bias.astype(jnp.float32)
+        return logits
 
 
 # The chunk rule's two numbers: bytes of f32 logits a chunk may hold, and the
@@ -1428,10 +1561,10 @@ def loss_fn(
 
 def _is_pool_cache(kv_cache: Optional[KVCache]) -> bool:
     """True for a paged POOL container (stacked or unstacked layout)."""
-    return kv_cache is not None and (
-        "k_pool" in kv_cache
-        or ("layers" in kv_cache and "k_pool" in kv_cache["layers"][0])
-    )
+    if kv_cache is None:
+        return False
+    fields = kv_cache["layers"][0] if "layers" in kv_cache else kv_cache
+    return "k_pool" in fields or "latent_pool" in fields
 
 
 def _unstack_fields(n_layers: int, fields: Dict[str, Tuple[Tuple[int, ...], Any]]) -> KVCache:
@@ -1467,7 +1600,13 @@ def make_kv_cache(
         )
     # GQA caches only kv_heads heads — the memory win that motivates GQA.
     shape = (cfg.n_layers, batch_size, max_length, cfg.kv_heads, cfg.head_dim)
-    if cfg.kv_cache_dtype == "int8":
+    if cfg.kv_lora_rank:
+        # Latent attention caches one latent and one rotated key slice a
+        # token, shared by all heads (two fields: see models/mla.py).
+        dt = jnp.dtype(dtype or cfg.compute_dtype)
+        lead = (cfg.n_layers, batch_size, max_length)
+        fields = {"latent": (lead + (cfg.kv_lora_rank,), dt), "rope": (lead + (cfg.qk_rope_head_dim,), dt)}
+    elif cfg.kv_cache_dtype == "int8":
         if dtype is not None:
             # An explicit element dtype contradicts the quantized layout;
             # dropping it silently would hand back an int8 cache to a
@@ -1503,7 +1642,11 @@ def make_paged_kv_pool(
 
     Pools are stacked over layers like the contiguous cache and ride the
     same depth-scan carry: {'k_pool','v_pool'}: (L, n_blocks, block_size,
-    kv_heads, Dh), plus scale pools when ``kv_cache_dtype='int8'``.
+    kv_heads, Dh), plus scale pools when ``kv_cache_dtype='int8'``. A latent
+    (MLA) model pools ``latent_dim`` values a token, the same for every head:
+    {'latent_pool': (L, n_blocks, block_size, kv_lora_rank), 'rope_pool':
+    (L, n_blocks, block_size * qk_rope_head_dim)} (two fields whose device
+    layouts stay the natural ones: see models/mla.py).
     Block 0 is reserved by convention as the idle-row scratch target (the
     serving engine parks inactive batch rows on it); allocators hand out
     ids from 1.
@@ -1524,7 +1667,15 @@ def make_paged_kv_pool(
         # TPU sublane granularity; also keeps page gathers tile-aligned.
         raise ValueError(f"block_size must be a multiple of 8, got {block_size}")
     shape = (cfg.n_layers, n_blocks, block_size, cfg.kv_heads, cfg.head_dim)
-    if cfg.kv_cache_dtype == "int8":
+    if cfg.kv_lora_rank:
+        if scale_dtype is not None:
+            raise ValueError("a latent pool has no int8 pages yet (ROADMAP)")
+        dt = jnp.dtype(dtype or cfg.compute_dtype)
+        fields = {
+            "latent_pool": ((cfg.n_layers, n_blocks, block_size, cfg.kv_lora_rank), dt),
+            "rope_pool": ((cfg.n_layers, n_blocks, block_size * cfg.qk_rope_head_dim), dt),
+        }
+    elif cfg.kv_cache_dtype == "int8":
         if dtype is not None:
             raise ValueError(
                 f"make_paged_kv_pool(dtype={dtype!r}) conflicts with "

@@ -31,7 +31,9 @@ OptState = Dict[str, Any]
 # decayed deliberately: it is a plain d×e dense projection, and the reference
 # decays every Linear weight.
 _DECAY_LEAVES = frozenset(
-    {"wqkv", "wq", "wkv", "wo", "w1", "w2", "kernel", "embedding", "router"}
+    {"wqkv", "wq", "wkv", "wo", "w1", "w2", "kernel", "embedding", "router",
+     # latent attention's low-rank projections (models/mla.py)
+     "wq_a", "wq_b", "wkv_a", "wkv_b"}
 )
 
 # Leaves that deliberately receive NO decay: norm parameters and biases.
@@ -39,7 +41,11 @@ _DECAY_LEAVES = frozenset(
 # so classification is by name, never by rank. tests/test_optimizer.py asserts
 # every leaf of every preset lands in exactly one of these two sets.
 _NO_DECAY_LEAVES = frozenset(
-    {"scale", "bias", "bqkv", "bq", "bkv", "bo", "b1", "b2"}
+    {"scale", "bias", "bqkv", "bq", "bkv", "bo", "b1", "b2",
+     # the router's selection bias; the hyper-connections' coefficient
+     # projection, bias and gains (models/hyper.py): small, and the streams'
+     # mixing should not be pulled toward uniform
+     "router_bias", "phi", "b", "alpha"}
 )
 
 

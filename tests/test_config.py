@@ -120,6 +120,8 @@ _PERF_INTENT = {
     "reference-3b":    ("flash",        "dots_saveable",  "chunked"),
     "llama3-1b-gqa":   ("flash",        "dots_saveable",  "chunked"),
     "moe-8x350m":      ("flash",        "dots_saveable",  "chunked"),
+    # a smoke preset like "tiny", with every Xing4.0 mechanism: naive on purpose
+    "xing-mini":       ("naive",        "none",           "chunked"),
 }
 
 

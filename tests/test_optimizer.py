@@ -89,6 +89,9 @@ def test_decay_mask_covers_every_leaf_of_every_preset():
             d_head=4,
             n_kv_heads=(2 if (model.n_kv_heads or model.n_heads) != model.n_heads else None),
             n_experts=min(model.n_experts, 4),
+            # a latent-attention preset keeps its head split consistent
+            **(dict(qk_nope_head_dim=2, qk_rope_head_dim=2, v_head_dim=4, kv_lora_rank=8, q_lora_rank=8)
+               if model.kv_lora_rank else {}),
         )
         params = transformer.init_params(tiny, jax.random.key(0))
         mask = opt.decay_mask(params)
@@ -110,6 +113,8 @@ def test_decay_mask_covers_every_leaf_of_every_preset():
     # The GQA leaves must actually appear in the sweep (llama3-1b-gqa preset),
     # otherwise this test silently lost its teeth.
     assert {"wq", "wkv"} <= seen_names
+    # and so must the latent projections and the stream wrappers (xing-mini)
+    assert {"wq_a", "wkv_b", "phi", "alpha", "router_bias"} <= seen_names
 
 
 def test_clip_by_global_norm():

@@ -260,6 +260,75 @@ def test_scope_is_in_the_lowered_program(scope_tokens, program, scope):
     assert scope in scope_tokens[program]["words"]
 
 
+# Latent attention, dropless experts and residual streams bring their own scopes, nested so
+# that the tables above still add up: moe.* inside mlp, the low-rank projections inside
+# attn.qkv, mla.absorb beside attn.core, hc.* at block level.
+MECHANISM_CFG = transformer.ModelConfig(
+    vocab_size=64, context_length=64, d_model=32, n_heads=2, n_layers=2, d_head=24, mlp_ratio=2.0,
+    activation="swiglu", norm="rmsnorm", pos_embed="rope", tie_embeddings=False, mlp_bias=False,
+    compute_dtype="float32", n_experts=4, experts_per_token=2, moe_routing="dropless",
+    moe_score="sigmoid", moe_score_bias=True, n_shared_experts=1, d_expert=16, n_dense_layers=1,
+    kv_lora_rank=16, q_lora_rank=12, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    rope_scaling="yarn", rope_factor=4.0, rope_original_context=16, rope_mscale_all_dim=1.0, hc_mult=2,
+)
+MOE_SCOPES = ("moe.router", "moe.dispatch", "moe.experts", "moe.shared", "moe.combine")
+MECHANISM_SCOPES = {
+    "decode": MOE_SCOPES + ("mla.absorb", "hc.coef", "hc.mix", "attn.kv_write", "attn.paged_gather",
+                            "attn.core", "attn.qkv", "attn.rope", "attn.out", "mlp"),
+    "prefill": MOE_SCOPES + ("hc.coef", "hc.mix", "attn.kv_write", "attn.core", "attn.qkv", "mlp"),
+}
+
+
+@pytest.fixture(scope="module")
+def mechanism_paths():
+    p = transformer.init_params(MECHANISM_CFG, jax.random.key(0))
+    pools = lambda: transformer.make_paged_kv_pool(MECHANISM_CFG, 16, 8)
+    tables = jnp.asarray(np.arange(1, 9).reshape(2, 4), jnp.int32)
+    lowered = {
+        "decode": paged.paged_decode_steps.lower(
+            p, pools(), jnp.asarray([3, 5], jnp.int32), tables, jnp.asarray([4, 9], jnp.int32),
+            jax.random.key(1), MECHANISM_CFG, n_steps=2),
+        "prefill": paged._prefill_scatter_sample.lower(
+            p, pools(), jnp.zeros((2, 16), jnp.int32), jnp.asarray([16, 11], jnp.int32),
+            jnp.asarray([[1, 2], [3, 4]], jnp.int32), jax.random.key(2), MECHANISM_CFG, 16, 2),
+    }
+    return {k: set(re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True))) for k, low in lowered.items()}
+
+
+@pytest.mark.parametrize("program,scope", [(p, s) for p, ss in MECHANISM_SCOPES.items() for s in ss])
+def test_mechanism_scope_is_in_the_lowered_program(mechanism_paths, program, scope):
+    paths = [p for p in mechanism_paths[program] if scope in re.split(r"[/()]", p)]
+    assert paths
+    if scope.startswith("moe."):  # nested inside mlp, so mlp_time_share still holds the expert layer
+        assert all("mlp" in re.split(r"[/()]", p) for p in paths)
+
+
+def test_routing_counters_ride_the_window_and_reach_the_stats_and_the_commit_span(monkeypatch):
+    p = transformer.init_params(MECHANISM_CFG, jax.random.key(0))
+    rec = spans.SpanRecorder()
+    monkeypatch.setattr(spans, "_default", rec)
+    eng = ServingEngine(p, MECHANISM_CFG, max_batch=2, n_blocks=16, block_size=8)
+    eng.submit([1, 2, 3, 4, 5], 6)
+    eng.run()
+    events, _ = rec.drain()
+    st = eng.stats
+    assert set(st) >= {"moe_expert_tokens", "moe_experts_touched", "moe_steps"}
+    assert st["moe_expert_tokens"].shape == (1, 4) and st["moe_experts_touched"].shape == (1,)
+    # every row of the batch routes, the idle one too: 2 rows x 2 choices a step
+    assert st["moe_expert_tokens"].sum() == st["moe_steps"] * 2 * 2
+    commits = [meta for name, *_, meta in events if name == "serving.commit"]
+    keys = {"moe_steps", "moe_layers", "moe_experts", "moe_touched", "moe_routed", "moe_busiest"}
+    assert commits and all(keys <= set(meta) for meta in commits)
+    assert sum(meta["moe_routed"] for meta in commits) == st["moe_expert_tokens"].sum()
+
+
+def test_a_dense_model_has_no_routing_counters(params):
+    eng = _engine(params)
+    eng.submit(_prompts(1)[0], 4)
+    eng.run()
+    assert not [k for k in eng.stats if k.startswith("moe_")]
+
+
 def test_backward_and_recompute_carry_the_scopes(scope_tokens):
     paths = scope_tokens["train"]["paths"]
     assert any("transpose(jvp(loss.ce))" in p for p in paths)
