@@ -381,7 +381,12 @@ class ModelConfig:
             if self.n_kv_heads not in (None, self.n_heads) or self.qkv_bias:
                 raise ValueError("latent attention has no grouped KV heads and no QKV bias")
             if self.paged_attention_impl != "gather":
-                raise ValueError("the paged kernels read per-head K/V; a latent pool takes 'gather'")
+                raise ValueError(
+                    "paged_attention_impl picks among the per-head K/V forms; a latent pool "
+                    "picks its own decode form from its input (models/mla.py::decode_form: "
+                    "ops/pallas_latent.py for one query a row on a TPU, the gather form "
+                    "otherwise), so leave it at 'gather'"
+                )
         if self.rope_scaling not in ("none", "yarn"):
             raise ValueError(f"rope_scaling must be 'none' or 'yarn', got {self.rope_scaling!r}")
         if self.rope_scaling == "yarn" and (self.rope_factor < 1 or self.rope_original_context < 1):

@@ -52,11 +52,14 @@ _POOL_OF_DENSE = {
 }
 
 
-def pool_block_size(pools: transformer.KVCache) -> int:
+def pool_block_size(pools: transformer.KVCache, cfg: ModelConfig) -> int:
     """Tokens a page of ``pools`` holds (either container layout, per-head or latent)."""
     fields = pools["layers"][0] if "layers" in pools else pools
-    pool = fields["k_pool"] if "k_pool" in fields else fields["latent_pool"]
-    return int(pool.shape[1 if "layers" in pools else 2])
+    if "k_pool" in fields:
+        return int(fields["k_pool"].shape[1 if "layers" in pools else 2])
+    # a latent page is folded (models/mla.py::page_fold): rows x (slots a row x width)
+    rows, lanes = fields["latent_pool"].shape[-2:]
+    return int(rows * lanes // cfg.kv_lora_rank)
 
 
 def required_blocks(n_tokens: int, block_size: int) -> int:
@@ -157,7 +160,8 @@ def _scatter_staged_pages(
             pool = layer_pool[pool_key]
             lead = buf.shape[:1] if stacked else ()  # (L,) stacked, () per-layer
             # a page is whatever one block of this pool holds: (bs, G, Dh) per
-            # head, (bs, c) of latents, (bs * r,) of rotated key slices
+            # head; (bs / fold, fold * c) of latents and (bs / fold, fold * r) of
+            # rotated key slices, the same values row-major as (bs, c) and (bs, r)
             pages = buf.reshape(lead + (n_chunks,) + pool.shape[len(lead) + 1 :])
             sel = (flat_ids,) if not lead else (slice(None), flat_ids)
             out[pool_key] = pool.at[sel].set(pages.astype(pool.dtype))
@@ -287,7 +291,7 @@ def prefill_into_pool(
     (allocator output). Returns (last-token logits (V,) fp32, updated
     pools). Compiles once per page count, not per prompt length.
     """
-    block_size = pool_block_size(pools)
+    block_size = pool_block_size(pools, cfg)
     p = len(prompt_ids)
     if p == 0:
         raise ValueError("empty prompt")
@@ -394,7 +398,7 @@ def prefill_into_pool_batched(
     pages. Rows and pages are bucketed to powers of two so the jit cache
     stays at O(log(max_batch) * log(max_pages)) program variants.
     """
-    block_size = pool_block_size(pools)
+    block_size = pool_block_size(pools, cfg)
     n = len(prompts)
     if n == 0:
         raise ValueError("no prompts")

@@ -52,7 +52,7 @@ import numpy as np
 from pretraining_llm_tpu.config import ModelConfig
 from pretraining_llm_tpu.generation import paged, speculative
 from pretraining_llm_tpu.generation import prefix_cache as prefix_cache_mod
-from pretraining_llm_tpu.models import transformer
+from pretraining_llm_tpu.models import mla, transformer
 from pretraining_llm_tpu.observability import spans as _spans
 
 _log = logging.getLogger("pretraining_llm_tpu.serving")
@@ -293,6 +293,12 @@ class ServingEngine:
                 )
         self.params = params
         self.cfg = cfg
+        # How a decode step reads the pool, fixed for the engine's programs: a
+        # per-head pool as configured, a latent pool by what its input and the
+        # backend allow (models/mla.py::decode_form).
+        self.decode_attention = (
+            mla.decode_form(1) if cfg.kv_lora_rank else cfg.paged_attention_impl
+        )
         self.max_batch = int(max_batch)
         self.block_size = int(block_size)
         # Clamp max_seq so EVERY reachable prefill bucket fits the model
@@ -562,6 +568,8 @@ class ServingEngine:
             # latent_dim elements a layer for a latent pool
             "bytes_per_token": total // (self.n_blocks * self.block_size),
             "pool_bytes": total,
+            # "gather" | "kernel" (per head), "gather" | "latent_kernel" (latent)
+            "decode_attention": self.decode_attention,
         }
         if self.d_pools is not None:
             info["draft_pool_bytes"] = int(

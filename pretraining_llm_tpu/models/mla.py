@@ -7,9 +7,14 @@ of the cache, ``latent`` and ``rope``, because of how the TPU lays arrays out:
 one (n_blocks, block, 576) pool gets a device layout with the block index
 minor-most (576 is no multiple of 128 lanes), and every decode step then
 copies the whole pool into the scatter's layout and back, 0.74 ms each way
-a layer at 4,097 blocks (PERF.md section 6, PR 27). A (n_blocks, block, 512)
-pool and a (n_blocks, block * 64) one keep their natural layouts. Two
-forms of one function:
+a layer at 4,097 blocks (PERF.md section 6, PR 27). In the page pool both
+fields are **folded** (``page_fold``): ``fold`` consecutive slots lie side by
+side in one row of a page, (n_blocks, block / fold, fold * 512) and
+(n_blocks, block / fold, fold * 64), the same bytes as (block, 512) and
+(block, 64) row-major, so that a rope row fills whole 128-lane tiles. Both
+keep their natural layouts, a page of either is one contiguous piece of HBM
+that a kernel can copy by itself, and the gather form's ``pool[tables]``
+reshapes to (rows, slots, width) as before. Two forms of one function:
 
 - **expanded** (training forward, prefill): keys and values of every head are
   expanded from the latent, ``[k_nope | v] = c_kv W_kvb``, and ordinary causal
@@ -18,7 +23,11 @@ forms of one function:
   moves into the query, ``q~ = q_nope W_kvb^K``, scores and the weighted sum
   run in the latent space against the cached latents, and the value half maps
   the result back, ``o = (softmax . c_kv) W_kvb^V``. Nothing per head is ever
-  read from the cache.
+  read from the cache. Over a page pool the middle of it has two forms and the
+  input picks one (``decode_form``): one query a row on a TPU reads the latent
+  pool once, in place, through ``ops/pallas_latent.py``; anything else gathers
+  ``pool[tables]`` first and reads the copy twice (about five passes over the
+  row's latents; PERF.md section 6, PR 28), which is also the tests' oracle.
 
 The softmax scale is ``cfg.softmax_scale`` (YaRN's mscale squared included).
 Scopes: the low-rank projections in ``attn.qkv``, ``mla.absorb`` around both
@@ -28,6 +37,7 @@ absorbed matmuls, ``attn.kv_write`` / ``attn.paged_gather`` / ``attn.core`` /
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -118,34 +128,109 @@ def _expanded(attn: Params, q, c_kv, k_rope, cfg: ModelConfig, cdt: Any, impl: s
     return out[..., :dv]
 
 
-def _absorbed(attn: Params, q, latents, ropes, mask, cfg: ModelConfig, cdt: Any) -> jax.Array:
-    """q (B,T,H,nope+rope) against cached ``c_kv`` (B,K,kv_lora_rank) and
-    ``k_rope`` (B,K,rope) under ``mask`` (B,T,K): (B, T, H, v_head_dim)."""
+def decode_form(t: int, backend: Optional[str] = None) -> str:
+    """The form attention over a latent page pool takes for ``t`` queries a
+    row: ``"latent_kernel"`` (``ops/pallas_latent.py``: the pool read once, in
+    place) for the single-token decode step where Mosaic compiles, ``"gather"``
+    (``pool[tables]``, then ``_absorbed``) for several queries a row (the chunk
+    lane) and for every other backend. Read from the input, never from an
+    option; the engine reports ``decode_form(1)`` in ``pool_info()``."""
+    backend = backend or jax.default_backend()
+    return "latent_kernel" if t == 1 and backend == "tpu" else "gather"
+
+
+def _dot_dtype(cdt: Any) -> Any:
+    # The CPU backend has no batched-bf16 DotThunk (see models/moe.py): the
+    # absorbed dots are batched over heads or rows, so they run in float32 there.
+    return jnp.float32 if jax.default_backend() == "cpu" else cdt
+
+
+def _absorb(attn: Params, q, core, cfg: ModelConfig, cdt: Any) -> jax.Array:
+    """``W_kvb``'s key half into the query, ``core(q_lat (B,T,H,c), q_rope
+    (B,T,H,rope)) -> (B,T,H,c)`` in the latent space (under ``attn.core``),
+    its value half out of the result: (B, T, H, v_head_dim)."""
     nope = cfg.qk_nope_head_dim
     f32 = jnp.float32
-    # The CPU backend has no batched-bf16 DotThunk (see models/moe.py): these
-    # four dots are batched over heads or rows, so they run in float32 there.
-    ddt = f32 if jax.default_backend() == "cpu" else cdt
+    ddt = _dot_dtype(cdt)
     wkv_b = _w(attn, "wkv_b", cdt).astype(ddt)
-    q, latents, ropes = q.astype(ddt), latents.astype(ddt), ropes.astype(ddt)
+    q = q.astype(ddt)
     with jax.named_scope("mla.absorb"):
         q_lat = jnp.einsum(
             "bthn,chn->bthc", q[..., :nope], wkv_b[..., :nope], preferred_element_type=f32
         ).astype(ddt)
     with jax.named_scope("attn.core"):
-        s = jnp.einsum("bthc,bkc->bhtk", q_lat, latents, preferred_element_type=f32)
-        s = s + jnp.einsum("bthr,bkr->bhtk", q[..., nope:], ropes, preferred_element_type=f32)
-        s = jnp.where(mask[:, None], s * cfg.softmax_scale, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        # a row with no visible slot (a dead left-pad query) gives zeros, not NaN
-        p = jnp.where(jnp.any(mask, axis=-1)[:, None, :, None], p, 0.0)
-        o_lat = jnp.einsum(
-            "bhtk,bkc->bthc", p.astype(ddt), latents, preferred_element_type=f32
-        ).astype(ddt)
+        o_lat = core(q_lat, q[..., nope:]).astype(ddt)
     with jax.named_scope("mla.absorb"):
         return jnp.einsum(
             "bthc,chn->bthn", o_lat, wkv_b[..., nope:], preferred_element_type=f32
         ).astype(cdt)
+
+
+def _absorbed(attn: Params, q, latents, ropes, mask, cfg: ModelConfig, cdt: Any) -> jax.Array:
+    """q (B,T,H,nope+rope) against cached ``c_kv`` (B,K,kv_lora_rank) and
+    ``k_rope`` (B,K,rope) under ``mask`` (B,T,K): (B, T, H, v_head_dim)."""
+    f32 = jnp.float32
+    ddt = _dot_dtype(cdt)
+    latents, ropes = latents.astype(ddt), ropes.astype(ddt)
+
+    def core(q_lat, q_rope):
+        s = jnp.einsum("bthc,bkc->bhtk", q_lat, latents, preferred_element_type=f32)
+        s = s + jnp.einsum("bthr,bkr->bhtk", q_rope, ropes, preferred_element_type=f32)
+        s = jnp.where(mask[:, None], s * cfg.softmax_scale, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        # a row with no visible slot (a dead left-pad query) gives zeros, not NaN
+        p = jnp.where(jnp.any(mask, axis=-1)[:, None, :, None], p, 0.0)
+        return jnp.einsum("bhtk,bkc->bthc", p.astype(ddt), latents, preferred_element_type=f32)
+
+    return _absorb(attn, q, core, cfg, cdt)
+
+
+def page_fold(block_size: int, rope_dim: int) -> int:
+    """Slots that lie side by side in one row of a pool page: as many as make
+    a rope row a whole number of 128-lane tiles (2 at a rope width of 64), or
+    the largest power of two under that which divides the block (toy blocks)."""
+    fold = 128 // math.gcd(128, rope_dim)
+    while block_size % fold:
+        fold //= 2
+    return fold
+
+
+def _write_slots(pool: jax.Array, blk_ids, slots, values, fold: int) -> jax.Array:
+    """``values`` (B, T, w) into slots (B, T) of blocks (B, T) of a folded pool
+    (n_blocks, block / fold, fold * w): slot s is lanes (s % fold) * w .. + w of
+    row s // fold."""
+    w = values.shape[-1]
+    values = values.astype(pool.dtype)
+    if values.shape[1] == 1:
+        # One token a row of the batch: no two of them share a row of a page
+        # (requests own their blocks; idle rows share the scratch block), so
+        # read each row, put the token in its lanes and write the row back. A
+        # scatter of whole rows keeps its natural form on the TPU; windows at
+        # lane offsets become B sequential dynamic-update-slices a pool, 0.30
+        # ms a layer where this takes 0.05 (PERF.md section 6, PR 28).
+        blk, row = blk_ids[:, 0], slots[:, 0] // fold
+        mine = (jnp.arange(fold * w) // w)[None, :] == (slots % fold)
+        return pool.at[blk, row].set(jnp.where(mine, jnp.tile(values[:, 0], (1, fold)), pool[blk, row]))
+    idx = jnp.stack([blk_ids, slots // fold, (slots % fold) * w], axis=-1)
+    dims = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(2,), inserted_window_dims=(0, 1), scatter_dims_to_operand_dims=(0, 1, 2)
+    )
+    return jax.lax.scatter(pool, idx, values, dims, mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+
+
+def _absorbed_in_place(attn: Params, q, pool, rpool, tables, seq, cfg: ModelConfig, cdt: Any):
+    """The single-token decode step over the page pool without a gathered
+    copy: q (B,1,H,nope+rope) against the pages ``tables`` names, slots
+    0..``seq`` of each row visible."""
+    from pretraining_llm_tpu.ops.pallas_latent import latent_decode_attention
+
+    def core(q_lat, q_rope):
+        return latent_decode_attention(
+            q_lat[:, 0].astype(pool.dtype), q_rope[:, 0].astype(pool.dtype), pool, rpool,
+            tables, seq, scale=cfg.softmax_scale,
+        )[:, None]
+
+    return _absorb(attn, q, core, cfg, cdt)
 
 
 def attention_block(
@@ -155,8 +240,9 @@ def attention_block(
 ) -> Tuple[jax.Array, Optional[Params]]:
     """The latent counterpart of ``transformer._attention_block``: same
     arguments, same cache discipline. ``kv`` is ``{'latent': (B, Tmax, c),
-    'rope': (B, Tmax, r)}`` (contiguous) or ``{'latent_pool': (n_blocks, block,
-    c), 'rope_pool': (n_blocks, block * r)}`` (paged)."""
+    'rope': (B, Tmax, r)}`` (contiguous) or ``{'latent_pool': (n_blocks, block
+    / fold, fold * c), 'rope_pool': (n_blocks, block / fold, fold * r)}`` (paged,
+    ``page_fold``)."""
     cdt = jnp.dtype(cfg.compute_dtype)
     attn = blk["attn"]
     with jax.named_scope("blk.norm"):
@@ -183,7 +269,8 @@ def attention_block(
         if paged is None:
             raise ValueError("a paged kv pool requires forward(..., paged=PagedInfo)")
         pool, rpool = kv["latent_pool"], kv["rope_pool"]
-        block_size = pool.shape[1]
+        fold = pool.shape[2] // c_kv.shape[-1]
+        block_size = pool.shape[1] * fold
         tables, seq = paged.block_tables, paged.seq_lens
         capacity = tables.shape[1] * block_size
         with jax.named_scope("attn.kv_write"):
@@ -194,17 +281,18 @@ def attention_block(
             pos_c = jnp.minimum(pos, capacity - 1)
             blk_ids = jnp.where(in_range, tables[jnp.arange(b)[:, None], pos_c // block_size], 0)
             slots = jnp.where(in_range, pos_c % block_size, 0)
-            pool = pool.at[blk_ids, slots].set(c_kv.astype(pool.dtype))
-            rpool = rpool.at[
-                blk_ids[..., None], slots[..., None] * rdim + jnp.arange(rdim, dtype=slots.dtype)
-            ].set(k_rope.astype(rpool.dtype))
+            pool = _write_slots(pool, blk_ids, slots, c_kv, fold)
+            rpool = _write_slots(rpool, blk_ids, slots, k_rope, fold)
             new_kv = {"latent_pool": pool, "rope_pool": rpool}
-        with jax.named_scope("attn.paged_gather"):
-            kv_len = tables.shape[1] * block_size
-            cached = pool[tables].reshape(b, kv_len, pool.shape[-1]).astype(cdt)
-            cached_r = rpool[tables].reshape(b, kv_len, rdim).astype(cdt)
-        mask = jnp.arange(kv_len)[None, None, :] <= pos[:, :, None]
-        out = _absorbed(attn, q, cached, cached_r, mask, cfg, cdt)
+        if decode_form(t) == "latent_kernel":
+            out = _absorbed_in_place(attn, q, pool, rpool, tables, seq, cfg, cdt)
+        else:
+            with jax.named_scope("attn.paged_gather"):
+                kv_len = tables.shape[1] * block_size
+                cached = pool[tables].reshape(b, kv_len, c_kv.shape[-1]).astype(cdt)
+                cached_r = rpool[tables].reshape(b, kv_len, rdim).astype(cdt)
+            mask = jnp.arange(kv_len)[None, None, :] <= pos[:, :, None]
+            out = _absorbed(attn, q, cached, cached_r, mask, cfg, cdt)
     elif kv is not None:
         with jax.named_scope("attn.kv_write"):
             write = lambda buf, val: jax.lax.dynamic_update_slice_in_dim(

@@ -1644,9 +1644,10 @@ def make_paged_kv_pool(
     same depth-scan carry: {'k_pool','v_pool'}: (L, n_blocks, block_size,
     kv_heads, Dh), plus scale pools when ``kv_cache_dtype='int8'``. A latent
     (MLA) model pools ``latent_dim`` values a token, the same for every head:
-    {'latent_pool': (L, n_blocks, block_size, kv_lora_rank), 'rope_pool':
-    (L, n_blocks, block_size * qk_rope_head_dim)} (two fields whose device
-    layouts stay the natural ones: see models/mla.py).
+    {'latent_pool': (L, n_blocks, block_size / fold, fold * kv_lora_rank),
+    'rope_pool': (L, n_blocks, block_size / fold, fold * qk_rope_head_dim)}
+    (two fields, ``fold`` slots side by side in a row of a page, so that both
+    keep the TPU's natural layout: see models/mla.py).
     Block 0 is reserved by convention as the idle-row scratch target (the
     serving engine parks inactive batch rows on it); allocators hand out
     ids from 1.
@@ -1671,9 +1672,11 @@ def make_paged_kv_pool(
         if scale_dtype is not None:
             raise ValueError("a latent pool has no int8 pages yet (ROADMAP)")
         dt = jnp.dtype(dtype or cfg.compute_dtype)
+        fold = mla.page_fold(block_size, cfg.qk_rope_head_dim)
+        page = (cfg.n_layers, n_blocks, block_size // fold)
         fields = {
-            "latent_pool": ((cfg.n_layers, n_blocks, block_size, cfg.kv_lora_rank), dt),
-            "rope_pool": ((cfg.n_layers, n_blocks, block_size * cfg.qk_rope_head_dim), dt),
+            "latent_pool": (page + (fold * cfg.kv_lora_rank,), dt),
+            "rope_pool": (page + (fold * cfg.qk_rope_head_dim,), dt),
         }
     elif cfg.kv_cache_dtype == "int8":
         if dtype is not None:

@@ -3,7 +3,9 @@
 The XLA paged-decode path (models/transformer.py paged branch) assembles
 each row's logical KV sequence with a `pool[tables]` gather before a
 masked einsum — three full passes over the row's KV bytes per layer step
-(read pool, write gathered copy, read it again in attention). This kernel
+(read pool, write gathered copy, read it again in attention; measured on
+the latent pool, where `ops/pallas_latent.py` now makes the one pass with
+several pages a step: PERF.md section 6, PR 28). This kernel
 reads the pool blocks DIRECTLY: the block table is a scalar-prefetch
 operand, and the K/V BlockSpec index maps use it to DMA exactly the
 row's pages into VMEM — vLLM's PagedAttention memory model expressed as

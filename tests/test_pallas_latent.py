@@ -1,0 +1,220 @@
+"""The latent-pool decode kernel (ops/pallas_latent.py) against the gather form.
+
+The oracle is ``models/mla.py::_absorbed``'s middle on hand-built tables:
+``pool[tables]``, masked scores in float32, softmax, ``p . latents``. The
+kernel reads the same pages of both pools through the block table, several a
+step of its in-row loop, and must agree to accumulation-order tolerance
+whatever the rows' lengths, the fill of a last page, the division of the page
+count by the step's pages, the fold of a page's slots, and whatever a table's
+dead tail points at. Interpret mode on CPU (same convention as
+test_pallas_paged); one at-size compile for a described v5e says what Mosaic
+would refuse.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from pretraining_llm_tpu.models import mla
+from pretraining_llm_tpu.ops import pallas_latent as pk
+
+H, C, R, BS, N_BLOCKS = 4, 32, 8, 8, 48
+SCALE = 0.23
+
+
+def gather_form(q, q_rope, pool, rpool, tables, seq):
+    """softmax(([q | q_rope] . [latents | ropes]) * SCALE) . latents over slots
+    0..seq of each row, from gathered copies of (n_blocks, BS, width) pools, in
+    float32."""
+    b = q.shape[0]
+    kv = tables.shape[1] * BS
+    f32 = jnp.float32
+    lat = pool[tables].reshape(b, kv, C).astype(f32)
+    rope = rpool[tables].reshape(b, kv, R).astype(f32)
+    s = jnp.einsum("bhc,bkc->bhk", q.astype(f32), lat) + jnp.einsum("bhr,bkr->bhk", q_rope.astype(f32), rope)
+    mask = jnp.arange(kv)[None, None, :] <= jnp.asarray(seq)[:, None, None]
+    p = jax.nn.softmax(jnp.where(mask, s * SCALE, -jnp.inf), axis=-1)
+    return jnp.einsum("bhk,bkc->bhc", p, lat)
+
+
+def through_the_kernel(q, q_rope, pool, rpool, tables, seq, pages, fold=2):
+    """The same pools with ``fold`` slots side by side in a row of a page."""
+    return pk.latent_decode_attention(
+        q, q_rope, pool.reshape(N_BLOCKS, BS // fold, fold * C), rpool.reshape(N_BLOCKS, BS // fold, fold * R),
+        jnp.asarray(tables), jnp.asarray(seq), scale=SCALE, pages_per_step=pages,
+    )
+
+
+def state(seed, seq, max_blocks, dtype=jnp.float32):
+    """Rows of the given lengths on disjoint, shuffled pages; a table's dead
+    tail is 0 (the scratch block)."""
+    rng = np.random.default_rng(seed)
+    b = len(seq)
+    perm = rng.permutation(np.arange(1, N_BLOCKS)).tolist()
+    tables = np.zeros((b, max_blocks), np.int32)
+    for i, n in enumerate(seq):
+        own = min(max_blocks, n // BS + 1)
+        tables[i, :own] = [perm.pop() for _ in range(own)]
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), dtype)
+    return (normal(b, H, C), normal(b, H, R), normal(N_BLOCKS, BS, C), normal(N_BLOCKS, BS, R),
+            tables, np.asarray(seq, np.int32))
+
+
+CASES = {
+    # seq_lens, max_blocks, pages a step
+    "rows-of-unlike-lengths": ((3, 17, 30, 44), 6, 2),
+    "length-0-on-the-scratch-block": ((0, 21), 4, 2),
+    "last-page-holds-one-slot": ((BS, 2 * BS), 4, 2),  # slot seq is the page's first
+    "last-page-one-slot-short-of-full": ((BS - 2, 3 * BS - 2), 4, 2),  # one short of full
+    "last-page-full": ((BS - 1, 3 * BS - 1), 4, 2),
+    "pages-not-divisible-by-the-step": ((5, 37, 20), 5, 3),
+    "one-page-a-step": ((5, 37, 20), 5, 1),
+    "a-step-wider-than-the-table": ((5, 37, 20), 5, 8),
+    "row-at-capacity": ((4 * BS - 1, 4 * BS, 4 * BS + 5), 4, 2),  # the last two wrote to scratch
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_matches_the_gather_form(case):
+    seq, max_blocks, pages = CASES[case]
+    q, q_rope, pool, rpool, tables, seq = state(len(case), seq, max_blocks)
+    if case == "length-0-on-the-scratch-block":
+        tables[0] = 0  # an idle engine row: every entry the scratch block
+    got = through_the_kernel(q, q_rope, pool, rpool, tables, seq, pages)
+    want = gather_form(q, q_rope, pool, rpool, tables, seq)
+    assert got.shape == want.shape and got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+@pytest.mark.parametrize("tail", ["live-blocks-of-another-row", "poisoned-blocks"])
+def test_a_dead_tail_is_never_read_into_the_result(tail):
+    seq = (11, 42, 3)
+    q, q_rope, pool, rpool, tables, seq = state(5, seq, 6)
+    want = gather_form(q, q_rope, pool, rpool, tables, seq)
+    if tail == "live-blocks-of-another-row":
+        tables[0, 2:] = tables[1, :4]
+        tables[2, 1:] = tables[1, 1:]
+    else:
+        # blocks nobody owns, holding values that would swamp any sum they entered
+        free = [i for i in range(1, N_BLOCKS) if i not in set(tables.ravel().tolist())][:5]
+        pool = pool.at[jnp.asarray(free)].set(1e4)
+        rpool = rpool.at[jnp.asarray(free)].set(1e4)
+        tables[0, 2:] = free[:4]
+        tables[2, 1:] = free
+    got = through_the_kernel(q, q_rope, pool, rpool, tables, seq, 2)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    # and the gather form itself does not care either: the oracle is sound
+    np.testing.assert_allclose(
+        np.asarray(gather_form(q, q_rope, pool, rpool, tables, seq)), np.asarray(want), atol=1e-5
+    )
+
+
+def test_kernel_bf16():
+    q, q_rope, pool, rpool, tables, seq = state(7, (13, 40, 0, 29), 6, jnp.bfloat16)
+    got = through_the_kernel(q, q_rope, pool, rpool, tables, seq, 2)
+    assert got.dtype == jnp.bfloat16
+    want = gather_form(q, q_rope, pool, rpool, tables, seq)
+    # the tolerance of tests/test_pallas_paged.py::test_kernel_bf16
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=3e-2)
+
+
+@pytest.mark.parametrize("fold", [1, 4, 8])
+def test_any_fold_of_a_page_gives_the_same_numbers(fold):
+    """Slots side by side in a row change the order of a class's columns, not
+    what a softmax over them sums to (fold 2 is every other test's)."""
+    q, q_rope, pool, rpool, tables, seq = state(3, (19, 33, 0, 39), 5)
+    got = through_the_kernel(q, q_rope, pool, rpool, tables, seq, 2, fold)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(gather_form(q, q_rope, pool, rpool, tables, seq)), atol=1e-5
+    )
+
+
+@pytest.mark.parametrize("block,rope,fold", [(64, 64, 2), (16, 64, 2), (8, 8, 8), (64, 32, 4), (64, 128, 1), (64, 96, 4)])
+def test_page_fold_fills_whole_lane_tiles_where_the_block_allows(block, rope, fold):
+    assert mla.page_fold(block, rope) == fold
+    assert block % fold == 0 and (fold * rope % 128 == 0 or fold == block)
+
+
+@pytest.mark.parametrize("t", [1, 3], ids=["one-token-a-row", "a-chunk-a-row"])
+def test_a_written_slot_is_the_slot_the_gather_form_reads(t):
+    """``mla._write_slots`` into a folded pool (whole rows rewritten for one
+    token a row, windows for a chunk), then the pool read as (slots, width):
+    every token lies at its slot and nothing else moved."""
+    fold = 4
+    before = np.random.default_rng(t).normal(size=(N_BLOCKS, BS, C)).astype(np.float32)
+    pool = jnp.asarray(before).reshape(N_BLOCKS, BS // fold, fold * C)
+    blk = np.asarray([[5, 5, 9], [7, 0, 0], [2, 2, 2]])[:, :t]
+    slots = np.asarray([[6, 7, 0], [3, 0, 0], [1, 2, 3]])[:, :t]  # row 1's last two went to scratch
+    vals = np.arange(3 * t * C, dtype=np.float32).reshape(3, t, C) + 1
+    after = np.asarray(mla._write_slots(pool, jnp.asarray(blk), jnp.asarray(slots), jnp.asarray(vals), fold))
+    want = before.copy()
+    for r in range(3):
+        for i in range(t):
+            want[blk[r, i], slots[r, i]] = vals[r, i]
+    # block 0 is scratch: rows past their capacity all write there, in no promised order
+    np.testing.assert_array_equal(after.reshape(N_BLOCKS, BS, C)[1:], want[1:])
+
+
+@pytest.mark.parametrize("wrong", ["width", "dtype", "batch", "rope-pool"])
+def test_kernel_validation(wrong):
+    q, q_rope, pool, rpool, tables, seq = state(1, (5, 9), 4)
+    lat, rope = pool.reshape(N_BLOCKS, BS // 2, 2 * C), rpool.reshape(N_BLOCKS, BS // 2, 2 * R)
+    args = [q, q_rope, lat, rope, jnp.asarray(tables), jnp.asarray(seq)]
+    match = "batch" if wrong == "batch" else "do not match the pools"
+    if wrong == "width":
+        args[0] = q[..., :24]
+    elif wrong == "dtype":
+        args[0] = q.astype(jnp.bfloat16)
+    elif wrong == "batch":
+        args[5] = jnp.zeros((3,), jnp.int32)
+    else:
+        args[3] = rpool  # pages of another fold than the latents'
+    with pytest.raises(ValueError, match=match):
+        pk.latent_decode_attention(*args, scale=SCALE, pages_per_step=3)
+
+
+# -- what Mosaic says, at the serving cell's size, without a chip ----------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_kernel_compiles_for_the_chip_at_the_cells_size(one_chip):
+    """32 rows x 32 heads over 129 pages of the folded (4097, 32, 1024) and
+    (4097, 32, 128) bfloat16 pools (``serve_xing_decode_7k``): Mosaic takes it,
+    both pools enter the custom call as they lie (no copy, slice or relayout of
+    either), and nothing of the size of a gathered copy is made."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    b, h, c, r, bs, n_blocks, nb = 32, 32, 512, 64, 64, 4097, 129
+    fold = mla.page_fold(bs, r)
+    page = (n_blocks, bs // fold)
+    shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    fn = lambda q, qr, lat, rope, t, n: pk._latent_call(q, qr, lat, rope, t, n, 0.1, pk.PAGES_PER_STEP, False)
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable without a chip
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(fn).lower(
+            shape((b, h, c)), shape((b, h, r)), shape(page + (fold * c,)), shape(page + (fold * r,)),
+            shape((b, nb), jnp.int32), shape((b,), jnp.int32),
+        ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    ops = [line.split(" = ", 1)[1] for line in text.splitlines() if " = " in line]
+    pool_sized = [op for op in ops if op.startswith(f"bf16[{n_blocks},") and " parameter(" not in op]
+    assert not pool_sized, pool_sized
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20  # block-diagonal queries, a padded table

@@ -13,6 +13,7 @@ Weights are seeded with every scale, bias, alpha and ``b_corr`` non-trivial
 """
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -93,6 +94,22 @@ def test_parameter_count_is_the_tree_and_the_familys(params):
 # -- 2. prefill into the latent pool, then decode through the page tables -------------
 
 
+@pytest.fixture
+def kernel_forced(monkeypatch):
+    """``mla.decode_form`` answers as on a TPU, so one query a row through the
+    latent pool takes ``ops/pallas_latent.py`` (interpreted here) and several
+    still take the gather form. A jitted program keeps the form it was traced
+    with, so the caches go before and after."""
+    monkeypatch.setattr(mla, "decode_form", functools.partial(mla.decode_form, backend="tpu"))
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _traces_the_kernel(fn, *args):
+    return "pallas_call" in str(jax.make_jaxpr(fn)(*args))
+
+
 def _teacher_forced(p, seqs, prompt_lens, steps, readmit_row=None, readmit_at=None):
     """Logits after each row's prompt and after each forced token, through the
     engine's prefill and decode lanes on hand-built tables. ``readmit_row`` is
@@ -134,9 +151,15 @@ def _teacher_forced(p, seqs, prompt_lens, steps, readmit_row=None, readmit_at=No
     return [np.stack(o) for o in out]
 
 
-@pytest.mark.parametrize("readmit", [False, True], ids=["steady", "preempted-and-readmitted"])
-def test_paged_decode_matches_the_reference(params, readmit):
+@pytest.mark.parametrize("readmit,form", [(False, "gather"), (True, "gather"), (True, "latent_kernel")],
+                         ids=["steady", "preempted-and-readmitted", "readmitted-through-the-kernel"])
+def test_paged_decode_matches_the_reference(params, readmit, form, request):
+    if form == "latent_kernel":
+        request.getfixturevalue("kernel_forced")
     seed = SEEDS[0]
+    pools = tr.make_paged_kv_pool(CFG, 8, 8)
+    step = (params[seed], pools, jnp.zeros((2,), jnp.int32), jnp.zeros((2, 4), jnp.int32), jnp.zeros((2,), jnp.int32))
+    assert _traces_the_kernel(functools.partial(paged.paged_decode_logits, cfg=CFG), *step) == (form == "latent_kernel")
     prompt_lens, steps = (21, 9, 33), 6
     seqs = [tokens(seed + r, n + steps) for r, n in enumerate(prompt_lens)]
     got = _teacher_forced(params[seed], seqs, prompt_lens, steps,
@@ -144,6 +167,31 @@ def test_paged_decode_matches_the_reference(params, readmit):
     for toks, n, rows in zip(seqs, prompt_lens, got):
         want = reference_logits(seed, toks)[n : n + steps]  # row t scores token t + 1
         assert rel_err(rows, want) < LOGITS_TOL
+
+
+def test_several_queries_a_row_keep_the_gather_form(params, kernel_forced):
+    """t > 1 through the pool (the chunk lane): the gather form whatever the
+    backend, and the same numbers as t = 1 steps through the kernel."""
+    p, bs = params[SEEDS[0]], 8
+    toks = tokens(5, 19)
+    pools = tr.make_paged_kv_pool(CFG, 16, bs)
+    ids = [3, 9, 4]
+    _, pools = paged.prefill_into_pool(p, CFG, pools, toks[:16].tolist(), ids[:2])
+    tables = jnp.zeros((1, 4), jnp.int32).at[0, :3].set(jnp.asarray(ids))
+
+    def chunk(pools):  # three queries at slots 16..18 in one call
+        return tr.forward(p, jnp.asarray(toks[None, 16:19]), CFG, kv_cache=pools,
+                          paged=tr.PagedInfo(tables, jnp.asarray([16], jnp.int32)))[0]
+
+    assert not _traces_the_kernel(chunk, pools)
+    together = np.asarray(chunk(pools)[0], np.float32)
+    one_by_one = []
+    for i in range(3):
+        logits, pools = paged.paged_decode_logits(
+            p, pools, jnp.asarray(toks[16 + i : 17 + i]), tables, jnp.asarray([16 + i], jnp.int32), cfg=CFG)
+        one_by_one.append(np.asarray(logits[0]))
+    assert rel_err(together, np.stack(one_by_one)) < LOGITS_TOL
+    assert rel_err(together, reference_logits(SEEDS[0], toks)[16:19]) < LOGITS_TOL
 
 
 # -- 3. the absorbed form is the expanded form ----------------------------------------
@@ -292,8 +340,12 @@ ROOMY = ((24, 16), (9, 12), (33, 8), (17, 16), (12, 10))
 GROWING = ((9, 30), (12, 30), (7, 30), (10, 30), (5, 20))  # four rows outgrow eleven pages
 
 
-@pytest.mark.parametrize("pool_blocks,load", [(64, ROOMY), (12, GROWING)], ids=["roomy", "preempting"])
-def test_engine_serves_the_configuration(params, pool_blocks, load):
+@pytest.mark.parametrize("pool_blocks,load,form", [(64, ROOMY, "gather"), (12, GROWING, "gather"),
+                                                   (12, GROWING, "latent_kernel")],
+                         ids=["roomy", "preempting", "preempting-through-the-kernel"])
+def test_engine_serves_the_configuration(params, pool_blocks, load, form, request):
+    if form == "latent_kernel":
+        request.getfixturevalue("kernel_forced")
     seed = SEEDS[1]
     eng = ServingEngine(params[seed], CFG, max_batch=4, n_blocks=pool_blocks, block_size=8, max_seq=64)
     rng = np.random.default_rng(seed % 2 ** 31)
@@ -311,6 +363,12 @@ def test_engine_serves_the_configuration(params, pool_blocks, load):
     assert own.max() < 0.05 < 0.5 < off.max()
     info = eng.pool_info()
     assert info["bytes_per_token"] == CFG.latent_dim * 4 * CFG.n_layers == family.latent_bytes_per_token(ARCH, 4)
+    # the form of the engine's decode windows, as its capacity snapshot carries it
+    assert info["decode_attention"] == form
+    window = (eng.params, eng.pools, jnp.zeros((4,), jnp.int32), jnp.asarray(eng.tables),
+              jnp.zeros((4,), jnp.int32), jax.random.key(0))
+    assert _traces_the_kernel(functools.partial(paged.paged_decode_steps, cfg=CFG, n_steps=1), *window) == (
+        form == "latent_kernel")
     st = eng.stats
     assert st["moe_steps"] > 0 and st["moe_expert_tokens"].shape == (CFG.n_layers - 1, CFG.n_experts)
     assert st["moe_expert_tokens"].sum() == st["moe_steps"] * 4 * CFG.experts_per_token * (CFG.n_layers - 1)
@@ -322,6 +380,18 @@ def test_engine_serves_the_configuration(params, pool_blocks, load):
 def test_engine_refuses_what_the_latent_pool_lacks(params, kw):
     with pytest.raises(ValueError, match="latent"):
         ServingEngine(params[SEEDS[0]], CFG, max_batch=2, n_blocks=8, block_size=8, max_seq=32, **kw)
+
+
+@pytest.mark.parametrize("impl", ["gather", "kernel"])
+def test_a_per_head_engine_reports_the_form_it_was_configured_with(impl):
+    cfg = ModelConfig(vocab_size=64, context_length=32, d_model=16, n_heads=2, n_layers=1, paged_attention_impl=impl)
+    eng = ServingEngine(tr.init_params(cfg, jax.random.key(0)), cfg, max_batch=2, n_blocks=8, block_size=8)
+    assert eng.pool_info()["decode_attention"] == impl
+
+
+def test_a_latent_model_leaves_the_per_head_option_alone():
+    with pytest.raises(ValueError, match="picks its own decode form"):
+        dataclasses.replace(CFG, paged_attention_impl="kernel")
 
 
 def test_capacity_routed_experts_are_still_refused():
