@@ -25,8 +25,40 @@ _ACTIVATIONS = ("relu", "gelu", "swiglu")
 _NORMS = ("layernorm", "rmsnorm")
 _POS_EMBEDS = ("learned", "rope")
 _ATTN_IMPLS = ("naive", "flash", "ring", "ulysses")
-_REMAT_POLICIES = ("none", "full", "dots_saveable", "save_attn",
-                   "save_attn_res", "save_qkv_attn", "save_big")
+_REMAT_POLICIES = ("none", "full", "dots_saveable", "save_attn", "save_attn_res")
+
+# Options and values taken out of the program, for configurations that arrive
+# from outside it: a checkpoint's saved config (Config.from_json) and dotted
+# overrides from a command line (Config.with_overrides). A retired field at
+# the value the remaining path implements is dropped; anything else listed
+# here raises and names what to use. The removed behaviour is not emulated.
+_RETIRED_KEYS: Dict[str, Tuple[Optional[Tuple[Any, ...]], Any, str]] = {
+    # section.key: (removed values, or None where the whole field went;
+    #               the value the remaining path implements; removed in)
+    "model.decode_cache_layout": (None, "unstacked", "PR 29"),
+    "model.decode_unroll_layers": (None, False, "PR 29"),
+    "model.scan_unroll": (None, 1, "PR 29"),
+    "model.ce_impl": (("fused",), "chunked", "PR 29"),
+    "model.remat": (("save_qkv_attn", "save_big"), "save_attn_res", "PR 29"),
+}
+
+
+def _without_retired_keys(section: str, kw: Dict[str, Any]) -> Dict[str, Any]:
+    """``kw`` for one config section without its retired fields; ValueError
+    for a field value or an enumeration value whose path was removed."""
+    out = {}
+    for key, value in kw.items():
+        removed, use, pr = _RETIRED_KEYS.get(f"{section}.{key}", ((), None, ""))
+        if removed is None and value == use:
+            continue
+        if removed is None or value in removed:
+            advice = "drop the key" if removed is None else f"use {key}={use!r}"
+            raise ValueError(
+                f"{section}.{key}={value!r} selects a path that was removed in {pr}; "
+                f"the program implements {key}={use!r}: {advice}"
+            )
+        out[key] = value
+    return out
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -83,66 +115,29 @@ class ModelConfig:
     flash_block_kv: int = 0
     # Heads-major (B, H, T, Dh) q/k/v for the flash TRAINING path: produced
     # straight from the projection einsum so the kernel fold is a reshape,
-    # not a transpose. Default OFF: the op-level profile showed ~6% of the
-    # step in relayout copies around the pallas calls, but the heads-major
-    # program measured consistently ~1% SLOWER on v5e (2026-08-01:
-    # 124m 43.1 vs 43.8, 1B 46.6 vs 47.0, 350M 42.6 vs 43.0) — XLA moves
-    # the layout pressure into the out-projection/residual side. Kept as a
-    # probe knob for other hardware/shapes.
+    # not a transpose. No cell selects it; it waits for the race of the
+    # folds around the flash calls (ROADMAP S5(b)).
     flash_heads_major: bool = False
     # Rematerialization policy applied to each scanned block — see
     # ops/remat.py for what each saves.
-    remat: str = "none"  # none | full | dots_saveable | save_attn | save_attn_res | save_qkv_attn | save_big
-    # CE head implementation: "chunked" scans token chunks, backward
-    # recomputes each chunk's logits (default; handles bias + vocab-sharded
-    # TP heads); "dense" SAVES the compute-dtype logits so backward
-    # recomputes nothing — S*V*2 bytes of head memory for zero recompute
-    # FLOPs (the right trade at small batch or remat="none"; won the 124M
-    # race post CE-scatter fix). "fused" is an EXPERIMENT, not a product
-    # path: the Pallas online-logsumexp kernel (ops/pallas_ce.py) is
-    # interpret-mode correct but hung a v5e chip three times across two
-    # remat configs (2026-07/08, an earlier installation) and measured
-    # SLOWER everywhere it completed (29.9-31.5% vs 40+% MFU at 124M);
-    # it stays out of chip_smoke.py and of every benchmark cell. Keep
-    # chunked/dense for real runs; degrades loudly to chunked for biased
-    # or tensor-sharded heads.
-    ce_impl: str = "chunked"  # chunked | fused | dense
+    remat: str = "none"  # none | full | dots_saveable | save_attn | save_attn_res
+    # CE head implementation: "chunked" scans token chunks and its backward
+    # recomputes each chunk's logits (every preset and cell; handles a bias
+    # and a vocab-sharded head); "dense" SAVES the compute-dtype logits so
+    # backward recomputes nothing — S*V*2 bytes of head memory for zero
+    # recompute FLOPs. No cell selects "dense"; tests use it as the
+    # reference for "chunked".
+    ce_impl: str = "chunked"  # chunked | dense
     # z-loss coefficient (PaLM/ST-MoE): adds z * mean(logsumexp(logits)^2)
     # to the training loss, pinning the softmax normalizer near 0 —
-    # stabilizes large-scale bf16 training. 0 = off. chunked/dense CE
-    # heads only (the fused Pallas kernel does not implement it).
+    # stabilizes large-scale bf16 training. 0 = off.
     z_loss_coef: float = 0.0
-    # Unroll factor for the depth scan (1 = fully rolled). Unrolling lets XLA
-    # fuse across layer boundaries at the cost of compile time.
-    scan_unroll: int = 1
-    # Fully unroll the depth scan for SINGLE-TOKEN cached decode steps.
-    # The rolled layer scan nests a while loop inside the token-decode scan,
-    # and XLA inserts full-cache copies at the loop boundary every decode
-    # step (measured via AOT HLO: 4 cache-shaped copies/step at gpt2-124m
-    # b8/320 slots — ~140 MB/step of pure copy traffic — plus ~110 MB temp;
-    # unrolling removes the inner loop and ALL cache copies, letting the
-    # token scan update the cache in place). Decode-only: prefill (Tq>1)
-    # and training keep scan_unroll. Default off until measured on-chip.
-    decode_unroll_layers: bool = False
-    # Decode KV-cache container layout. 'unstacked' (default): a tuple of
-    # per-layer (B, T, G, Dh) caches with a trace-time python loop over
-    # layers — each leaf is updated in place via one dynamic-update-slice
-    # on the token-scan carry (the aliasable pattern). 'stacked': one
-    # (L, B, T, G, Dh) array per field riding the depth scan — profiled on
-    # v5e at ~50% of the decode step in pure cache MOVEMENT (the scan's
-    # ys-stacking makes a fresh (L, ...) buffer every token step, so the
-    # token-scan carry cannot alias and XLA copies the whole cache back
-    # in, plus per-layer slice/update-slice relayouts). Measured
-    # 2026-08-01 at gpt2-124m b8: unstacked 6,856 tok/s vs stacked 4,129
-    # (+66%). Semantics identical (tested: greedy/ragged/int8).
-    decode_cache_layout: str = "unstacked"
-    # Unstacked-layout dispatch boundary: multi-token cached forwards with
-    # Tq <= this take the in-place per-layer loop (single-token decode
-    # steps and speculative-decoding verify rounds, where per-call
-    # re-stack copies would claw back the unstacked win); larger Tq
-    # (prefill buckets start at 16) re-stacks once and runs the rolled
-    # scan so the prefill program stays O(1) in depth. Raise it if you
-    # run speculative decoding with spec_k >= this value.
+    # A cached forward over a per-layer cache (see make_kv_cache) with
+    # Tq <= this runs a trace-time loop over layers, each cache leaf updated
+    # in place (single-token decode steps, speculative verify rounds); a
+    # larger Tq (prefill buckets start at 16) stacks the cache once and runs
+    # the rolled depth scan, so the prefill program stays O(1) in depth.
+    # Raise it if you run speculative decoding with spec_k >= this value.
     decode_loop_max_tokens: int = 8
     # Shard activations' sequence dim over the 'seq' mesh axis (Megatron-SP)
     sequence_parallel: bool = False
@@ -250,8 +245,9 @@ class ModelConfig:
     # (max_pages, B), >1 = forced count. `ragged_amla` switches the
     # online softmax to AMLA's exp2 MUL-by-ADD rescale (per-page
     # correction as an exponent-field add; int8 dequant scales absorbed
-    # into the same restructure). Defaults keep the proven numerics —
-    # flips are bench-gated (BASELINE.md re-race procedure).
+    # into the same restructure). Defaults keep the single-pass numerics;
+    # no cell selects either, they wait for the paged-attention race
+    # (ROADMAP S4).
     ragged_kv_splits: int = 1  # 0 = auto | 1 = off | >1 = forced
     ragged_amla: bool = False
 
@@ -287,28 +283,14 @@ class ModelConfig:
             )
         if self.remat not in _REMAT_POLICIES:
             raise ValueError(f"remat must be one of {_REMAT_POLICIES}, got {self.remat!r}")
-        if self.decode_cache_layout not in ("stacked", "unstacked"):
-            raise ValueError(
-                "decode_cache_layout must be 'stacked' or 'unstacked', got "
-                f"{self.decode_cache_layout!r}"
-            )
         if self.decode_loop_max_tokens < 1:
             raise ValueError(
                 f"decode_loop_max_tokens must be >= 1, got "
                 f"{self.decode_loop_max_tokens}"
             )
-        if self.decode_unroll_layers and self.decode_cache_layout != "stacked":
-            # The unroll knob only means something on the stacked depth
-            # scan; silently ignoring it under the unstacked layout would
-            # bank mislabeled measurements.
+        if self.ce_impl not in ("chunked", "dense"):
             raise ValueError(
-                "decode_unroll_layers requires decode_cache_layout="
-                "'stacked' (the unstacked layout has no depth scan to "
-                "unroll)"
-            )
-        if self.ce_impl not in ("chunked", "fused", "dense"):
-            raise ValueError(
-                f"ce_impl must be 'chunked', 'fused' or 'dense', got {self.ce_impl!r}"
+                f"ce_impl must be 'chunked' or 'dense', got {self.ce_impl!r}"
             )
         if self.ring_layout not in ("contiguous", "zigzag"):
             raise ValueError(
@@ -429,11 +411,6 @@ class ModelConfig:
             )
         if self.z_loss_coef < 0:
             raise ValueError("z_loss_coef must be >= 0")
-        if self.z_loss_coef > 0 and self.ce_impl == "fused":
-            raise ValueError(
-                "z_loss_coef requires ce_impl='chunked' or 'dense' (the "
-                "fused Pallas CE kernel does not implement the z term)"
-            )
         if self.sliding_window < 0:
             raise ValueError("sliding_window must be >= 0 (0 = full causal)")
         if self.sliding_window > 0 and self.attention_impl in ("ring", "ulysses"):
@@ -1303,6 +1280,7 @@ class Config:
                 top[key] = value
         new = self
         for section, kw in sections.items():
+            kw = _without_retired_keys(section, kw)
             old = getattr(new, section)
             valid = {f.name for f in dataclasses.fields(old)}
             for k in kw:
@@ -1319,6 +1297,8 @@ class Config:
     @staticmethod
     def from_json(text: str) -> "Config":
         raw = json.loads(text)
+        raw = {k: _without_retired_keys(k, v) if isinstance(v, dict) else v
+               for k, v in raw.items()}
         return Config(
             model=ModelConfig(**raw["model"]),
             mesh=MeshConfig(**{k: tuple(v) if k == "axis_names" else v for k, v in raw["mesh"].items()}),

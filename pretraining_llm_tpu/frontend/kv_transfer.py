@@ -6,7 +6,7 @@ module makes KV state itself migratable, page by page:
 
   snapshot    ``snapshot_chain`` pulls the longest cached block chain for
               a prompt out of a sender engine's pool: per pool leaf (K,
-              V, and quantization-scale leaves alike, layer-stacked) one
+              V, and quantization-scale leaves alike, every layer's) one
               contiguous byte string per page, plus a content digest
               computed with exactly the ``kv_block_digest`` algorithm —
               the same digest ``kv_checksum`` verifies at acquire, so a
@@ -64,12 +64,6 @@ KV_FRAME_BUDGET_BYTES = 8 * 1024 * 1024
 XFER_VERSION = 1
 
 
-def _block_axis(leaf: Any) -> int:
-    # Mirrors resilience.integrity._block_axis: stacked pools are
-    # (L, n_blocks, block_size, ...), per-layer leaves (n_blocks, ...).
-    return 1 if getattr(leaf, "ndim", 0) >= 5 else 0
-
-
 def _np_dtype(name: str) -> np.dtype:
     """Resolve a dtype name, including the ml_dtypes extensions
     (bfloat16 scale pools) plain numpy does not know."""
@@ -125,8 +119,7 @@ def snapshot_chain(
         for j, b in enumerate(blocks):
             arrays: List[np.ndarray] = []
             for leaf in leaves:
-                page = leaf[:, b] if _block_axis(leaf) == 1 else leaf[b]
-                arrays.append(np.ascontiguousarray(jax.device_get(page)))
+                arrays.append(np.ascontiguousarray(jax.device_get(leaf[b])))
             digest = _page_digest(arrays)
             expected = cache.checksum_of(b)
             if expected is not None and digest != expected:
@@ -308,13 +301,8 @@ def adopt_chain(engine: Any, xfer: Dict[str, Any]) -> Dict[str, Any]:
     if len(layout) != len(leaves):
         return _reject_all("layout_mismatch")
     for spec, leaf in zip(layout, leaves):
-        axis = _block_axis(leaf)
-        shape = (
-            (leaf.shape[0],) + tuple(leaf.shape[2:]) if axis == 1
-            else tuple(leaf.shape[1:])
-        )
         if (
-            tuple(spec["shape"]) != shape
+            tuple(spec["shape"]) != tuple(leaf.shape[1:])
             or str(spec["dtype"]) != str(leaf.dtype)
         ):
             return _reject_all("layout_mismatch")
@@ -360,10 +348,8 @@ def adopt_chain(engine: Any, xfer: Dict[str, Any]) -> Dict[str, Any]:
     # pins adopt_chain to the loop thread).
     pool_leaves, treedef = jax.tree_util.tree_flatten(engine.pools)
     for j, leaf in enumerate(pool_leaves):
-        axis = _block_axis(leaf)
         for i, b in enumerate(blocks):
-            idx = (slice(None), b) if axis == 1 else (b,)
-            pool_leaves[j] = pool_leaves[j].at[idx].set(
+            pool_leaves[j] = pool_leaves[j].at[b].set(
                 decoded[i][j].astype(leaf.dtype)
             )
     engine.pools = jax.tree_util.tree_unflatten(treedef, pool_leaves)

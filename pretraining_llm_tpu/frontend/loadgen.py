@@ -15,8 +15,8 @@ The whole workload is materialised up front by ``build_schedule`` from
 so a given spec is ONE reproducible workload: same seed -> byte-identical
 schedule, regardless of wall-clock, host, or which client runs it.
 
-Clients: ``run_engine_loop`` drives an in-process EngineLoop (bench.py's
-serving-SLO mode); ``run_http`` drives a live gateway over HTTP with
+Clients: ``run_engine_loop`` drives an in-process EngineLoop;
+``run_http`` drives a live gateway over HTTP with
 stdlib urllib (no deps). Both produce a ``LoadReport`` with
 TTFT/TPOT/e2e percentiles and goodput-under-SLO — completed requests
 that met BOTH SLO bounds, per second of wall time; a server that answers

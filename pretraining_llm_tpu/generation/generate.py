@@ -5,9 +5,9 @@ O(n * T^2) with no cache (`/root/reference/src/models/transformer.py:96-114`,
 SURVEY §3.2). TPU-native redesign:
 
   - prefill once over the prompt (one big MXU-friendly forward),
-  - then a `lax.scan` of single-token decode steps against a stacked KV cache
-    (L, B, T, H, Dh) — O(n * T) total, one compiled program for the whole
-    generation (no per-token Python dispatch),
+  - then a `lax.scan` of single-token decode steps against a per-layer KV
+    cache (`transformer.make_kv_cache`) — O(n * T) total, one compiled
+    program for the whole generation (no per-token Python dispatch),
   - sampling semantics match the reference by default (temperature-1
     categorical) with temperature/top-k/top-p extensions.
 
@@ -64,31 +64,6 @@ def cast_params_for_inference(params: Any, cfg: ModelConfig) -> Any:
         return x.astype(cdt)
 
     return jax.tree_util.tree_map_with_path(cast, params)
-
-
-def decode_bench_workload(cfg: ModelConfig, batch: int, *,
-                          quick: bool = False) -> Tuple[ModelConfig, Any, jax.Array, int]:
-    """The canonical decode measurement workload, shared by `bench.py
-    --mode decode` and `profile_capture.py --mode decode` so the profile
-    always traces exactly the shape the benchmark measures.
-
-    Returns (cfg, params, prompt, new_tokens): ring/ulysses fall back to
-    the cached naive path, params are inference-cast, prompt is (batch,
-    prompt_len) with prompt_len = min(64, ctx - new_tokens).
-    """
-    import dataclasses as _dc
-
-    if cfg.attention_impl in ("ring", "ulysses"):
-        cfg = _dc.replace(cfg, attention_impl="naive", sequence_parallel=False)
-    new_tokens = min(64 if quick else 256, cfg.context_length // 2)
-    prompt_len = min(64, cfg.context_length - new_tokens)
-    params = cast_params_for_inference(
-        transformer.init_params(cfg, jax.random.key(0)), cfg
-    )
-    prompt = jax.random.randint(
-        jax.random.key(1), (batch, prompt_len), 0, cfg.vocab_size
-    )
-    return cfg, params, prompt, new_tokens
 
 
 def _bucket_len(prompt_len: int, ctx: int, max_new_tokens: int) -> int:
@@ -167,21 +142,11 @@ def _generate_jit(
             src = jnp.clip(
                 jnp.arange(total)[None, :] - pad_off[:, None], 0, total - 1
             )  # (B, total)
-            if "layers" in cache:
-                # Unstacked layout: per-layer leaves are (B, T, ...).
-                cache = jax.tree.map(
-                    lambda c: jnp.take_along_axis(
-                        c, src[:, :, None, None], axis=1
-                    ),
-                    cache,
-                )
-            else:
-                cache = jax.tree.map(
-                    lambda c: jnp.take_along_axis(
-                        c, src[None, :, :, None, None], axis=2
-                    ),
-                    cache,
-                )
+            # per-layer leaves are (B, T, ...)
+            cache = jax.tree.map(
+                lambda c: jnp.take_along_axis(c, src[:, :, None, None], axis=1),
+                cache,
+            )
             start_index = jnp.int32(bucket)
         next_tok = sample_logits(
             last, sub, temperature=temperature, top_k=top_k, top_p=top_p,
@@ -263,7 +228,7 @@ def generate(
         # decode session is a single document, so the mask is vacuous — and
         # forward() rejects the combination with a KV cache. A checkpoint
         # trained with packing must still decode (the e2e contract), so
-        # sanitize here like decode_bench_workload does for ring/ulysses.
+        # drop it here.
         import dataclasses as _dc
 
         cfg = _dc.replace(cfg, doc_mask_token=-1)

@@ -53,10 +53,10 @@ _POOL_OF_DENSE = {
 
 
 def pool_block_size(pools: transformer.KVCache, cfg: ModelConfig) -> int:
-    """Tokens a page of ``pools`` holds (either container layout, per-head or latent)."""
-    fields = pools["layers"][0] if "layers" in pools else pools
+    """Tokens a page of ``pools`` holds (per-head or latent)."""
+    fields = pools["layers"][0]
     if "k_pool" in fields:
-        return int(fields["k_pool"].shape[1 if "layers" in pools else 2])
+        return int(fields["k_pool"].shape[1])
     # a latent page is folded (models/mla.py::page_fold): rows x (slots a row x width)
     rows, lanes = fields["latent_pool"].shape[-2:]
     return int(rows * lanes // cfg.kv_lora_rank)
@@ -145,44 +145,31 @@ def _scatter_staged_pages(
 ) -> transformer.KVCache:
     """ONE definition of the staged-cache -> pool page scatter, shared by
     the single-prompt and batched admission prefills. The staged cache is
-    STACKED ((L, N, n_pages*bs, ...) fields); each field is cut into
-    ``n_chunks`` pages and scattered at ``flat_ids`` (pad pages point at
-    the reserved scratch block 0 — duplicate indices there are benign)."""
+    STACKED ((L, N, n_pages*bs, ...) fields, make_kv_cache(stacked=True));
+    each layer of each field is cut into ``n_chunks`` pages and scattered
+    into that layer's pool at ``flat_ids`` (pad pages point at the reserved
+    scratch block 0 — duplicate indices there are benign)."""
+    staged = [(d, p) for d, p in _POOL_OF_DENSE.items() if d in dense_cache]
+    if not staged:
+        # A per-layer staging cache would otherwise silently prefill NOTHING.
+        raise ValueError(
+            f"no cache fields matched the pool mapping; staging cache "
+            f"keys = {sorted(dense_cache)} (need make_kv_cache(stacked=True))"
+        )
 
-    def _fields(layer_pool, dense_layer, stacked):
+    def _layer(layer, layer_pool):
         out = dict(layer_pool)
-        scattered = 0
-        for dense_key, pool_key in _POOL_OF_DENSE.items():
-            if dense_key not in dense_cache:
-                continue
-            scattered += 1
-            buf = dense_layer(dense_cache[dense_key])  # (N, P, ...) or (L, N, P, ...)
+        for dense_key, pool_key in staged:
             pool = layer_pool[pool_key]
-            lead = buf.shape[:1] if stacked else ()  # (L,) stacked, () per-layer
             # a page is whatever one block of this pool holds: (bs, G, Dh) per
             # head; (bs / fold, fold * c) of latents and (bs / fold, fold * r) of
             # rotated key slices, the same values row-major as (bs, c) and (bs, r)
-            pages = buf.reshape(lead + (n_chunks,) + pool.shape[len(lead) + 1 :])
-            sel = (flat_ids,) if not lead else (slice(None), flat_ids)
-            out[pool_key] = pool.at[sel].set(pages.astype(pool.dtype))
-        if not scattered:
-            # A container-layout mismatch (e.g. an unstacked staging
-            # cache) would otherwise silently prefill NOTHING.
-            raise ValueError(
-                f"no cache fields matched the pool mapping; staging cache "
-                f"keys = {sorted(dense_cache)} (need the stacked layout)"
-            )
+            pages = dense_cache[dense_key][layer].reshape((n_chunks,) + pool.shape[1:])
+            out[pool_key] = pool.at[flat_ids].set(pages.astype(pool.dtype))
         return out
 
     with jax.named_scope("attn.kv_write"):
-        if "layers" in pools:
-            return {
-                "layers": tuple(
-                    _fields(pools["layers"][layer], lambda buf, _l=layer: buf[_l], False)
-                    for layer in range(len(pools["layers"]))
-                )
-            }
-        return _fields(pools, lambda buf: buf, True)
+        return {"layers": tuple(_layer(i, lp) for i, lp in enumerate(pools["layers"]))}
 
 
 # A prefill computes the f32 logits of every position and keeps the last real
@@ -227,8 +214,8 @@ def _scatter_pages(
     n_pages: int,
 ) -> transformer.KVCache:
     """Scatter a (L, 1, n_pages*bs, ...) dense prefill cache into the pools
-    (stacked or unstacked container) at ``block_ids``. Donated pools: the
-    update is in-place on device. (The batch-1 form of
+    at ``block_ids``. Donated pools: the update is in-place on device. (The
+    batch-1 form of
     ``_scatter_staged_pages``.)"""
     return _scatter_staged_pages(pools, dense_cache, block_ids, n_pages)
 
@@ -250,17 +237,12 @@ def _prefill_dense(
     decode write to slot seq_len lands BEFORE the mask exposes it, exactly
     the dense-prefill overwrite discipline (`generate._generate_jit`).
     """
-    import dataclasses as _dc
-
     from pretraining_llm_tpu.parallel.sharding import activation_mesh
 
     with activation_mesh(mesh):
-        # The staging cache is consumed field-by-field by _scatter_pages
-        # (reshape (L, 1, pages*bs, ...) -> pool pages), which needs the
-        # STACKED container regardless of the model's decode default.
-        cache = transformer.make_kv_cache(
-            _dc.replace(cfg, decode_cache_layout="stacked"), 1, p_bucket
-        )
+        # One forward fills the staging cache and _scatter_pages consumes it
+        # field by field ((L, 1, pages*bs, ...) -> pool pages): stacked.
+        cache = transformer.make_kv_cache(cfg, 1, p_bucket, stacked=True)
         if 4 * p_bucket * cfg.vocab_size > _ALL_POSITION_LOGITS_BYTES:
             last, cache = _prefill_last_logits(
                 params, prompt, (prompt_len - 1).astype(jnp.int32)[None], cfg, cache
@@ -352,17 +334,13 @@ def _prefill_scatter_sample(
     pool's scratch discipline. Pad ROWS (N rounded up to a bucket) carry
     all-zero tables and garbage tokens the caller slices away.
     """
-    import dataclasses as _dc
-
     from pretraining_llm_tpu.parallel.sharding import activation_mesh
 
     n_rows = prompts.shape[0]
     with activation_mesh(mesh):
-        # Stacked staging cache regardless of the decode default — the
-        # scatter consumes (L, N, pages*bs, ...) field layouts.
-        cache = transformer.make_kv_cache(
-            _dc.replace(cfg, decode_cache_layout="stacked"), n_rows, p_bucket
-        )
+        # One forward fills the staging cache; the scatter consumes
+        # (L, N, pages*bs, ...) fields: stacked.
+        cache = transformer.make_kv_cache(cfg, n_rows, p_bucket, stacked=True)
         idx = jnp.clip(prompt_lens - 1, 0, p_bucket - 1).astype(jnp.int32)
         last, cache = _prefill_last_logits(params, prompts, idx, cfg, cache)
         toks = sample_logits(

@@ -105,7 +105,7 @@ class PrefixCache:
         # them to the allocator instead of re-coldlisting a known-bad page.
         self._doomed: set = set()
         # Tallies live in the caller's dict (the engine's ``stats``) so
-        # serve.py/bench.py records and EngineLoop.metrics() see them for
+        # serve.py records and EngineLoop.metrics() see them for
         # free; typed counters attach via bind().
         self.stats: Dict[str, Any] = stats if stats is not None else {}
         for k in STAT_KEYS:

@@ -408,7 +408,7 @@ class ServingEngine:
                     stacklevel=2,
                 )
             # Every pool leaf carries kv_heads at axis -2 (scale pools have
-            # a trailing 1); stacked leaves are 5-dim, unstacked 4-dim.
+            # a trailing 1).
             return jax.tree.map(
                 lambda leaf: jax.device_put(
                     leaf,
@@ -500,12 +500,12 @@ class ServingEngine:
             # Pipelined-scheduler telemetry: windows dispatched/reaped and
             # the host seconds spent blocked on a window's readback — the
             # quantity deep pipelining exists to shrink (host_blocked_s /
-            # windows_reaped is the per-window counter bench.py reports).
+            # windows_reaped is the blocked time per window).
             "windows": 0, "windows_reaped": 0, "host_blocked_s": 0.0,
             "flushes": 0,
             # Prompt tokens actually prefilled (suffix-only for cache
             # hits) — with prefix_cache_hit_tokens this yields the
-            # prefill-reduction ratio bench.py's serving record reports.
+            # share of prompt tokens the prefix cache spared.
             "prefill_tokens": 0,
             # Chunked-prefill telemetry: chunk programs dispatched, chunk
             # tokens prefilled through them, and scheduler ticks whose
@@ -550,7 +550,7 @@ class ServingEngine:
         pages included), host-side shape math only (no device sync).
         Draft pools (speculative serving) are reported separately."""
         pools = self.pools
-        layer0 = pools["layers"][0] if "layers" in pools else pools
+        layer0 = pools["layers"][0]
         total = int(
             sum(leaf.nbytes for leaf in jax.tree.leaves(pools))
         )

@@ -118,9 +118,6 @@ def _expanded(attn: Params, q, c_kv, k_rope, cfg: ModelConfig, cdt: Any, impl: s
         q = (q.astype(jnp.float32) * (cfg.softmax_scale * width ** 0.5)).astype(cdt)
         pad = lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, 0), (0, width - a.shape[-1])))
         q, k, v = pad(q), pad(k), pad(v)
-    q = checkpoint_name(q, "qkv")
-    k = checkpoint_name(k, "qkv")
-    v = checkpoint_name(v, "qkv")
     with jax.named_scope("attn.core"):
         out = multihead_attention(
             q, k, v, impl=impl, block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv,

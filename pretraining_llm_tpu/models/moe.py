@@ -37,7 +37,6 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
 from pretraining_llm_tpu.config import ModelConfig
 from pretraining_llm_tpu.models.layers import weight as _weight
@@ -191,9 +190,6 @@ def moe_mlp(
         hidden = jax.nn.relu(hidden) if cfg.activation == "relu" else jax.nn.gelu(
             hidden, approximate=True
         )
-    # 'save_big' saves the expert hidden too (mirrors the dense MLP tag) —
-    # without it the whole dispatch + expert FFN would recompute in backward.
-    hidden = checkpoint_name(hidden, "mlp_hidden")
     out = jnp.einsum(
         "ecf,efd->ecd", hidden, ex["w2"].astype(cdt), preferred_element_type=jnp.float32
     ).astype(cdt)

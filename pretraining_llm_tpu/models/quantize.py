@@ -123,8 +123,7 @@ def is_quantized(params: Any) -> bool:
 
 
 def param_bytes(params: Any) -> int:
-    """Total bytes across all leaves — the model-bytes estimate bench.py
-    reports so HBM-bandwidth wins are attributable."""
+    """Total bytes across all leaves: what a decode step streams from HBM."""
     return int(
         sum(
             leaf.size * jnp.dtype(leaf.dtype).itemsize

@@ -20,8 +20,8 @@ TPU-first instead of translated:
     `reference-3b` preset.
 
 The same forward serves training (kv_cache=None) and KV-cached decode
-(kv_cache + cache_index given): caches are stacked per layer and scanned with
-the blocks.
+(kv_cache + cache_index given): a cache is per-layer leaves walked by a
+trace-time loop, or one stacked array scanned with the blocks (make_kv_cache).
 """
 
 from __future__ import annotations
@@ -296,13 +296,6 @@ def _attention_block(
         with jax.named_scope("attn.rope"):
             q = layers.apply_rope(q, cos, sin, rope_pos, seq_axis=2 if hm else 1)
             k = layers.apply_rope(k, cos, sin, rope_pos, seq_axis=2 if hm else 1)
-
-    # Remat tags for the 'save_qkv_attn'/'save_big' policies: with post-RoPE
-    # q/k/v saved, the attention backward starts directly from its VJP inputs
-    # instead of recomputing LN1 + the QKV projection (+RoPE).
-    q = checkpoint_name(q, "qkv")
-    k = checkpoint_name(k, "qkv")
-    v = checkpoint_name(v, "qkv")
 
     # GQA: every attention path attends H query heads against G KV heads
     # directly when the layout allows it (no K/V expansion — the cache/HBM
@@ -672,7 +665,6 @@ def _dense_mlp(mlp: Params, h: jax.Array, cfg: ModelConfig) -> jax.Array:
         if "b1" in mlp:
             hidden = hidden + mlp["b1"].astype(cdt)
         hidden = layers.activation_fn(cfg.activation, hidden)
-    hidden = checkpoint_name(hidden, "mlp_hidden")
     out = jnp.einsum(
         "btf,fd->btd", hidden, _weight(mlp, "w2", cdt), preferred_element_type=jnp.float32
     ).astype(cdt)
@@ -974,12 +966,7 @@ def forward(
             n = jax.tree.leaves(blocks)[0].shape[0]
             xs = (xs, jnp.arange(n, dtype=jnp.int32))
             step = remat.checkpoint_wrap(with_experts, cfg.remat)
-        (x, aux), out = jax.lax.scan(step, (x, aux), xs, unroll=(
-            cfg.n_layers
-            if cache is not None and "layers" not in kv_cache
-            and cfg.decode_unroll_layers and x.shape[1] == 1
-            else cfg.scan_unroll
-        ))
+        (x, aux), out = jax.lax.scan(step, (x, aux), xs)
         if cfg.moe_dropless and "router" in blocks["mlp"]:
             out, moe_counts = out  # an expert group yields (outputs, tokens per expert)
         return x, aux, out
@@ -1023,24 +1010,18 @@ def forward(
         block_outputs = concat_groups(outs) if return_hidden else None
         new_cache = None
     elif "layers" in kv_cache:
-        # UNSTACKED decode cache (decode_cache_layout='unstacked'):
-        # trace-time python loop over layers, each layer's (B, T, G, Dh)
-        # cache leaves updated by ONE dynamic-update-slice directly on the
-        # token-scan carry — the aliasable pattern, eliminating both the
-        # stacked layout's whole-cache carry copies and its per-layer
-        # slice/update-slice relayouts (together ~50% of the profiled v5e
-        # decode step). Layer weights come from static slices of the
-        # stacked block params (fold into their consumers, no copies).
+        # Per-layer cache (and every page pool): a trace-time python loop
+        # over layers, each layer's cache leaves updated by ONE
+        # dynamic-update-slice directly on the caller's carry, which XLA can
+        # alias; a stacked cache riding the depth scan is a fresh (L, ...)
+        # buffer every step. Layer weights are static slices of the stacked
+        # block params and fold into their consumers.
         if t > cfg.decode_loop_max_tokens:
-            # PREFILL: the carry-copy pathology is per decode STEP; a
-            # python layer loop here would only scale the prefill program
-            # (and its compile time) by n_layers. Re-stack, run the rolled
-            # scan once, unstack the result — two whole-cache copies per
-            # prefill, amortized over the entire generation. Small multi-
-            # token calls (speculative-decoding verify rounds, Tq=k+1)
-            # keep the in-place layer loop below: they repeat every few
-            # tokens, so per-round re-stack copies would claw back the
-            # unstacked layout's win (boundary: decode_loop_max_tokens).
+            # A long call (prefill): the layer loop would scale the program
+            # and its compile time by n_layers. Stack, run the rolled scan
+            # once, unstack: two cache copies a call. Short multi-token calls
+            # (speculative verify rounds) repeat every few tokens and keep
+            # the in-place loop below.
             aux_total, new_layers = aux0, []
             for layers_of, blocks in groups:
                 lyrs = [kv_cache["layers"][i] for i in layers_of]
@@ -1079,14 +1060,9 @@ def forward(
                 moe_counts = jnp.stack(counts)
             new_cache = {"layers": tuple(new_layers)}
     else:
-        # Single-token decode steps may fully unroll the depth scan: the
-        # rolled inner while forces XLA to copy the whole cache at the
-        # token-scan loop boundary every step (see ModelConfig.
-        # decode_unroll_layers). Tq is a static shape, so this is a
-        # trace-time choice; prefill (Tq>1) keeps the rolled scan.
-        # (On-chip 2026-08-01: unroll measured SLOWER than the rolled scan
-        # — the unstacked cache layout above is the measured fix for the
-        # carry-copy problem instead.)
+        # Stacked dense cache (make_kv_cache(..., stacked=True)): the layers
+        # ride the depth scan. For a caller that makes the cache, runs one
+        # forward and hands the result on (prefill staging).
         aux_total, outs = aux0, []
         for layers_of, blocks in groups:
             cache = kv_cache if len(groups) == 1 else jax.tree.map(
@@ -1177,7 +1153,7 @@ def _chunked_ce(
     cfg: ModelConfig,
     z: float = 0.0,
 ) -> jax.Array:
-    """Mean cross-entropy head dispatcher (chunked | fused | dense).
+    """Mean cross-entropy head dispatcher (chunked | dense).
 
     chunked (default): no full (B*T, V) logits buffer. The fp32 logits for
     GPT-2-sized vocabs dwarf every other activation (B=12, T=1024,
@@ -1191,7 +1167,6 @@ def _chunked_ce(
     partitioner, the batch sharding lands on the chunk axis the scan walks,
     and every chunk's full-vocabulary f32 logits are all-reduced over the
     d-sharded head, forward and backward (18% of gpt2-xl's fsdp=4 step).
-    fused: Pallas kernel (see ops/pallas_ce).
     dense: the OPPOSITE trade — deliberately materializes and SAVES the
     compute-dtype (S, V) logits so backward recomputes nothing (see
     _dense_lse_ce); head memory is S*V*2 bytes.
@@ -1199,70 +1174,12 @@ def _chunked_ce(
     cdt = jnp.dtype(cfg.compute_dtype)
     b, t, d = hidden.shape
     s = b * t
-    if cfg.ce_impl == "fused":
-        from pretraining_llm_tpu.ops.pallas_ce import fused_cross_entropy
-
-        mesh = current_mesh()
-        # GSPMD can't partition a pallas_call: without handling it would
-        # REPLICATE the kernel (all-gathering the global batch onto every
-        # device). Batch-sharded meshes get an explicit shard_map over the
-        # batch axes (W replicated, per-shard kernel); vocab-sharded (tensor)
-        # and seq/pipe-sharded hidden layouts fall back to chunked CE.
-        nontrivial = lambda ax: mesh.shape.get(ax, 1) > 1 if mesh is not None else False
-        fused_ok = bias is None and not any(
-            nontrivial(ax) for ax in ("tensor", "seq", "pipe")
-        )
-        if not fused_ok:
-            # Loud degradation (VERDICT r2 #9): the user asked for the fused
-            # kernel; tell them they aren't getting it instead of silently
-            # training slower. Fires once per trace (warnings dedupe).
-            import warnings
-
-            why = (
-                "the lm_head has a bias"
-                if bias is not None
-                else "the mesh shards tensor/seq/pipe axes the kernel can't express"
-            )
-            warnings.warn(
-                f"ce_impl='fused' degraded to chunked CE: {why}. "
-                "Drop lm_head_bias / use a data+fsdp-only mesh to get the "
-                "fused kernel.",
-                stacklevel=3,
-            )
-        if fused_ok:
-            hidden_c = hidden.astype(cdt)
-            w_c = w_out.astype(cdt)
-            if mesh is not None and (nontrivial("data") or nontrivial("fsdp")):
-                batch_axes = ("data", "fsdp")
-
-                def local_ce(h_l, w_l, t_l):
-                    bl, tl, dl = h_l.shape
-                    return fused_cross_entropy(
-                        h_l.reshape(bl * tl, dl), w_l, t_l.reshape(bl * tl)
-                    ).reshape(bl, tl)
-
-                losses = jax.shard_map(
-                    local_ce,
-                    mesh=mesh,
-                    in_specs=(P(batch_axes, None, None), P(None, None), P(batch_axes, None)),
-                    out_specs=P(batch_axes, None),
-                    check_vma=False,
-                )(hidden_c, w_c, targets)
-            else:
-                losses = fused_cross_entropy(
-                    hidden_c.reshape(s, d), w_c, targets.reshape(s)
-                )
-            return jnp.mean(losses)
     if cfg.ce_impl == "dense":
         # ZERO-recompute head: the backward of the chunked path re-runs the
-        # (S, V) logits matmul (2*S*d*V FLOPs — ~10% of the whole step's
-        # analytic FLOPs at gpt2-124m/b16, pure unaccounted wall time),
-        # while this path SAVES compute-dtype logits (+ the f32 lse) and
-        # backward is just softmax + the two unavoidable grad matmuls.
-        # Cost: S*V*2 bytes of saved residual (824 MB at b8/T1024/V50304)
-        # — affordable exactly when remat pressure is low (small batch or
-        # remat=none), which is when the recompute charge dominates. Also
-        # removes the chunk scan's serialization. Numerics: backward's
+        # (S, V) logits matmul (2*S*d*V FLOPs), while this path SAVES
+        # compute-dtype logits (+ the f32 lse) and backward is just softmax +
+        # the two unavoidable grad matmuls, at S*V*2 bytes of saved
+        # residual and without the chunk scan's serialization. Numerics: backward's
         # softmax is exp(bf16-rounded logits - lse) vs the chunked path's
         # freshly recomputed f32-accum logits; grads agree to bf16 rounding
         # (tested) — the forward LOSS value is computed from f32-accum
@@ -1560,11 +1477,9 @@ def loss_fn(
 
 
 def _is_pool_cache(kv_cache: Optional[KVCache]) -> bool:
-    """True for a paged POOL container (stacked or unstacked layout)."""
-    if kv_cache is None:
-        return False
-    fields = kv_cache["layers"][0] if "layers" in kv_cache else kv_cache
-    return "k_pool" in fields or "latent_pool" in fields
+    """True for a page pool (make_paged_kv_pool), false for a dense cache."""
+    layers_ = (kv_cache or {}).get("layers")
+    return bool(layers_) and ("k_pool" in layers_[0] or "latent_pool" in layers_[0])
 
 
 def _unstack_fields(n_layers: int, fields: Dict[str, Tuple[Tuple[int, ...], Any]]) -> KVCache:
@@ -1586,12 +1501,15 @@ def _unstack_fields(n_layers: int, fields: Dict[str, Tuple[Tuple[int, ...], Any]
 
 
 def make_kv_cache(
-    cfg: ModelConfig, batch_size: int, max_length: int, dtype: Any = None
+    cfg: ModelConfig, batch_size: int, max_length: int, dtype: Any = None,
+    *, stacked: bool = False,
 ) -> KVCache:
-    """Decode cache in the layout ``cfg.decode_cache_layout`` selects:
-    stacked {(L, B, T, G, Dh)} fields, or {'layers': (per-layer dicts of
-    (B, T, G, Dh) fields,)} — see the config field for the v5e profile
-    evidence behind the unstacked option."""
+    """Dense decode cache: {'layers': (per-layer dicts of (B, T, G, Dh)
+    fields,)}, each leaf updated in place on the caller's token-scan carry —
+    the container for a decode loop. ``stacked=True`` gives {(L, B, T, G, Dh)}
+    fields that ride the depth scan instead: for a caller that fills the
+    cache with one forward and hands it on (prefill staging), where there is
+    no carry to alias and the rolled scan keeps the program O(1) in depth."""
     if max_length > cfg.context_length:
         # Position tables (learned or RoPE) are sized by context_length; JAX
         # gather would silently clamp out-of-range positions — fail fast here.
@@ -1629,9 +1547,9 @@ def make_kv_cache(
     else:
         dtype = jnp.dtype(dtype or cfg.compute_dtype)
         fields = {"k": (shape, dtype), "v": (shape, dtype)}
-    if cfg.decode_cache_layout == "unstacked":
-        return _unstack_fields(cfg.n_layers, fields)
-    return {name: jnp.zeros(s, dt) for name, (s, dt) in fields.items()}
+    if stacked:
+        return {name: jnp.zeros(s, dt) for name, (s, dt) in fields.items()}
+    return _unstack_fields(cfg.n_layers, fields)
 
 
 def make_paged_kv_pool(
@@ -1640,12 +1558,12 @@ def make_paged_kv_pool(
 ) -> KVCache:
     """Block POOL layout for paged serving decode (see PagedInfo).
 
-    Pools are stacked over layers like the contiguous cache and ride the
-    same depth-scan carry: {'k_pool','v_pool'}: (L, n_blocks, block_size,
-    kv_heads, Dh), plus scale pools when ``kv_cache_dtype='int8'``. A latent
-    (MLA) model pools ``latent_dim`` values a token, the same for every head:
-    {'latent_pool': (L, n_blocks, block_size / fold, fold * kv_lora_rank),
-    'rope_pool': (L, n_blocks, block_size / fold, fold * qk_rope_head_dim)}
+    One pool a layer, {'layers': (per-layer dicts,)}: {'k_pool','v_pool'}:
+    (n_blocks, block_size, kv_heads, Dh), plus scale pools when
+    ``kv_cache_dtype='int8'``. A latent (MLA) model pools ``latent_dim``
+    values a token, the same for every head:
+    {'latent_pool': (n_blocks, block_size / fold, fold * kv_lora_rank),
+    'rope_pool': (n_blocks, block_size / fold, fold * qk_rope_head_dim)}
     (two fields, ``fold`` slots side by side in a row of a page, so that both
     keep the TPU's natural layout: see models/mla.py).
     Block 0 is reserved by convention as the idle-row scratch target (the
@@ -1704,12 +1622,9 @@ def make_paged_kv_pool(
             )
         dtype = jnp.dtype(dtype or cfg.compute_dtype)
         fields = {"k_pool": (shape, dtype), "v_pool": (shape, dtype)}
-    if cfg.decode_cache_layout == "unstacked":
-        # Same carry-aliasing rationale as the dense unstacked cache
-        # (see decode_cache_layout): per-layer pools update in place on
-        # the serving window's token-scan carry.
-        return _unstack_fields(cfg.n_layers, fields)
-    return {name: jnp.zeros(s, dt) for name, (s, dt) in fields.items()}
+    # Per-layer pools update in place on the serving window's token-scan
+    # carry (see make_kv_cache).
+    return _unstack_fields(cfg.n_layers, fields)
 
 
 def _kv_quantize(x: jax.Array) -> Tuple[jax.Array, jax.Array]:

@@ -558,7 +558,7 @@ def _flash(q, k, v, h, g, causal, block_q, block_kv, interpret, window):
 def _flash_fwd(q, k, v, h, g, causal, block_q, block_kv, interpret, window):
     o, lse = _fwd(q, k, v, h, g, causal=causal, block_q=block_q, block_kv=block_kv,
                   interpret=interpret, window=window)
-    # Remat tags: under the 'save_qkv_attn'/'save_big' policies the VJP
+    # Remat tags: under the 'save_attn_res' policy the VJP
     # residuals themselves are saved, so the backward never re-runs this
     # kernel (plain 'save_attn' only tags the merged output downstream,
     # which cannot reconstruct lse — the fwd kernel reruns there).

@@ -6,25 +6,17 @@ by the scanned-layer path (models.transformer.forward) and the pipelined path
 same backward-pass schedule.
 
 Policies (cheapest memory -> cheapest recompute):
-  - "full":          save nothing; backward re-runs the whole block.
-  - "dots_saveable": save every matmul output (XLA default-ish middle ground).
+  - "full":          save nothing; backward re-runs the whole block (the
+                     training cells' setting).
+  - "dots_saveable": save every matmul output.
   - "save_attn":     save only the merged attention output ("attn_out" tag);
                      backward re-runs QKV projection + the flash forward.
   - "save_attn_res": save the flash kernel's OUTPUT residuals ("attn_o_res",
                      "attn_lse") instead: the attention VJP starts from its
-                     saved (o, lse) — the flash forward never reruns — while
-                     the QKV projection (plain matmuls the VJP needs as
-                     inputs anyway) still recomputes. Same memory class as
-                     save_attn (+lse, 4 bytes/token/head); kills the double
-                     flash-forward the 2026-08-01 profile showed under
-                     save_attn. (Distinct from the LOSING save_qkv_attn,
-                     which additionally saved the q/k/v INPUTS.)
-  - "save_qkv_attn": additionally save post-RoPE q/k/v ("qkv") and the flash
-                     VJP residuals ("attn_o_res", "attn_lse") — the attention
-                     backward starts directly from its residuals, so neither
-                     the QKV projection nor the flash forward kernel reruns.
-  - "save_big":      save_qkv_attn + the MLP hidden ("mlp_hidden"); recompute
-                     is just LN/residual elementwise math.
+                     saved (o, lse), so the flash forward never reruns, while
+                     the QKV projection still recomputes. Same memory class
+                     as save_attn (+lse, 4 bytes/token/head). No cell selects
+                     it yet (ROADMAP S5(a)).
   - "none":          no checkpointing (autodiff saves everything it needs).
 """
 
@@ -35,16 +27,11 @@ from typing import Callable
 import jax
 
 # Tag names referenced by checkpoint_name() calls in models/transformer.py,
-# models/moe.py and ops/pallas_flash.py. Keep these lists in sync with the
+# models/mla.py and ops/pallas_flash.py. Keep these lists in sync with the
 # tag sites — a policy naming a tag that no longer exists silently saves
 # nothing for it.
 _SAVE_ATTN = ("attn_out",)
 _SAVE_ATTN_RES = ("attn_o_res", "attn_lse")
-_SAVE_QKV_ATTN = ("qkv",) + _SAVE_ATTN_RES
-_SAVE_BIG = _SAVE_QKV_ATTN + ("mlp_hidden",)
-
-POLICIES = ("none", "full", "dots_saveable", "save_attn", "save_attn_res",
-            "save_qkv_attn", "save_big")
 
 
 def checkpoint_wrap(fn: Callable, remat: str) -> Callable:
@@ -63,13 +50,5 @@ def checkpoint_wrap(fn: Callable, remat: str) -> Callable:
         return jax.checkpoint(
             fn,
             policy=jax.checkpoint_policies.save_only_these_names(*_SAVE_ATTN_RES),
-        )
-    if remat == "save_qkv_attn":
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.save_only_these_names(*_SAVE_QKV_ATTN)
-        )
-    if remat == "save_big":
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.save_only_these_names(*_SAVE_BIG)
         )
     raise ValueError(f"unknown remat policy {remat!r}")

@@ -477,8 +477,7 @@ class ServingFaultInjector:
         block = cached[0]
 
         def _poison(leaf):
-            idx = (slice(None), block) if leaf.ndim >= 5 else (block,)
-            page = leaf[idx]
+            page = leaf[block]  # a pool leaf is (n_blocks, ...)
             if jnp.issubdtype(page.dtype, jnp.floating):
                 # Exact K/V bytes, or quantization SCALES: 100.0 blows the
                 # dequantized magnitudes far outside any trained range.
@@ -487,7 +486,7 @@ class ServingFaultInjector:
                 # int8 quantized codes: a constant nonzero page (sign-flip
                 # would leave an all-zero page — and its digest — intact).
                 bad = jnp.full_like(page, 101)
-            return leaf.at[idx].set(bad)
+            return leaf.at[block].set(bad)
 
         engine.pools = jax.tree_util.tree_map(_poison, engine.pools)
         return True

@@ -164,13 +164,6 @@ def _fingerprint_reduce(leaves: Sequence[Any]):
 # ---------------------------------------------------------------------------
 
 
-def _block_axis(leaf: Any) -> int:
-    # Stacked pools are (L, n_blocks, block_size, ...); the per-layer
-    # container's leaves are (n_blocks, block_size, ...). See
-    # make_paged_kv_pool — n_blocks is the only axis a block id indexes.
-    return 1 if getattr(leaf, "ndim", 0) >= 5 else 0
-
-
 def kv_block_digest(pools: Any, block: int) -> str:
     """Content digest of ONE pool block across every pool leaf (K, V, and
     quantization scales alike). This is a device pull per leaf, so callers
@@ -180,11 +173,8 @@ def kv_block_digest(pools: Any, block: int) -> str:
 
     h = hashlib.blake2b(digest_size=16)
     for leaf in jax.tree_util.tree_leaves(pools):
-        if _block_axis(leaf) == 1:
-            page = leaf[:, block]
-        else:
-            page = leaf[block]
-        arr = np.ascontiguousarray(jax.device_get(page))
+        # a pool leaf is (n_blocks, ...): see make_paged_kv_pool
+        arr = np.ascontiguousarray(jax.device_get(leaf[block]))
         h.update(str(arr.dtype).encode())
         h.update(arr.tobytes())
     return h.hexdigest()

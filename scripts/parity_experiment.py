@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Eval-loss parity: this framework vs an independent PyTorch twin.
 
-BASELINE.md's bar is "eval loss matching the GPU baseline +-0.01". This
+BASELINE.json's bar is "eval loss matching the GPU baseline +-0.01". This
 environment has no GPU and no network, so the baseline is produced the way
 the reference would have produced it: a from-scratch PyTorch training run
 (torch CPU, fp32) of the SAME architecture, from the SAME initial weights,
@@ -19,7 +19,7 @@ Usage:
   python scripts/parity_experiment.py --steps 1500 --eval-iters 50
 
 Writes data/parity/{corpus.txt,train.bin,val.bin,init.npz,results.json} and
-prints a BASELINE.md-ready table row.
+prints the result as a markdown table row.
 """
 
 from __future__ import annotations
@@ -499,7 +499,7 @@ def main():
             return 2
         # A rerun on a DIFFERENT backend must not destroy the banked
         # record: the TPU pinned-precision capture is round evidence
-        # (BASELINE.md parity table), and a casual CPU rerun would
+        # (data/parity/results.json), and a casual CPU rerun would
         # silently overwrite it. Archive the displaced record under a
         # backend-suffixed key (the pattern jax_tpu_fastmatmul/jax_cpu
         # already follow).

@@ -1,6 +1,7 @@
 """Config layer: presets, validation, overrides, analytic param counts."""
 
 import dataclasses
+import json
 
 import pytest
 
@@ -78,6 +79,44 @@ def test_json_roundtrip():
     cfg = get_preset("llama-1b")
     restored = Config.from_json(cfg.to_json())
     assert restored == cfg
+
+
+# What the parent of PR 29 wrote into every checkpoint's saved config, and
+# what a command line could still ask for: (key, value, the value named in
+# the error, or None where the key is dropped and the config loads).
+_RETIRED = [
+    ("decode_cache_layout", "unstacked", None),
+    ("decode_unroll_layers", False, None),
+    ("scan_unroll", 1, None),
+    ("decode_cache_layout", "stacked", "'unstacked'"),
+    ("decode_unroll_layers", True, "False"),
+    ("scan_unroll", 2, "=1"),
+    ("ce_impl", "fused", "'chunked'"),
+    ("remat", "save_big", "'save_attn_res'"),
+    ("remat", "save_qkv_attn", "'save_attn_res'"),
+]
+
+
+@pytest.mark.parametrize("key,value,use", _RETIRED, ids=[f"{k}={v}" for k, v, _ in _RETIRED])
+def test_retired_model_keys(key, value, use):
+    """A saved config and a dotted override meet one table: a retired field
+    at the value the remaining path implements is dropped, a removed path is
+    refused by name with the value to use."""
+    saved = json.loads(Config().to_json())
+    assert key not in saved["model"] or use is not None  # the field is gone
+    saved["model"].update(decode_cache_layout="unstacked", decode_unroll_layers=False, scan_unroll=1)
+    saved["model"][key] = value
+    if use is None:
+        assert Config.from_json(json.dumps(saved)) == Config()
+        assert Config().with_overrides({f"model.{key}": value}) == Config()
+        return
+    for load in (
+        lambda: Config.from_json(json.dumps(saved)),
+        lambda: Config().with_overrides({f"model.{key}": value}),
+    ):
+        with pytest.raises(ValueError, match=rf"model\.{key}={value!r}.*removed in PR 29") as e:
+            load()
+        assert use in str(e.value)
 
 
 def test_serving_config_wiring():

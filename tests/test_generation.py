@@ -21,42 +21,36 @@ def params():
     return transformer.init_params(CFG, jax.random.key(0))
 
 
-def test_unstacked_cache_layout_matches_stacked(params):
-    """decode_cache_layout='unstacked' (per-layer caches, python layer
-    loop, in-place carry updates) must generate EXACTLY the stacked
-    layout's tokens — greedy, ragged rows, and int8 quantized."""
-    cfg_u = dataclasses.replace(CFG, decode_cache_layout="unstacked")
-    prompt = jax.random.randint(jax.random.key(11), (2, 9), 0, CFG.vocab_size)
-    want = np.asarray(
-        generate(params, CFG, prompt, 12, jax.random.key(3), temperature=0.0)
-    )
-    got = np.asarray(
-        generate(params, cfg_u, prompt, 12, jax.random.key(3), temperature=0.0)
-    )
-    np.testing.assert_array_equal(got, want)
-
-    # Ragged rows exercise the per-layer cache roll after prefill.
-    lengths = np.asarray([5, 9], np.int32)
-    want_r = np.asarray(generate(
-        params, CFG, prompt, 8, jax.random.key(4), temperature=0.0,
-        prompt_lengths=lengths,
-    ))
-    got_r = np.asarray(generate(
-        params, cfg_u, prompt, 8, jax.random.key(4), temperature=0.0,
-        prompt_lengths=lengths,
-    ))
-    np.testing.assert_array_equal(got_r, want_r)
-
-    # int8 quantized cache leaves carry through the unstacked container.
-    cfg8 = dataclasses.replace(CFG, kv_cache_dtype="int8")
-    cfg8_u = dataclasses.replace(cfg8, decode_cache_layout="unstacked")
-    want_q = np.asarray(
-        generate(params, cfg8, prompt, 8, jax.random.key(5), temperature=0.0)
-    )
-    got_q = np.asarray(
-        generate(params, cfg8_u, prompt, 8, jax.random.key(5), temperature=0.0)
-    )
-    np.testing.assert_array_equal(got_q, want_q)
+@pytest.mark.parametrize("kind", ["exact", "int8", "latent"])
+def test_dense_cache_containers_agree(params, kind):
+    """make_kv_cache's two containers — per-layer leaves (python layer loop,
+    in-place updates; stacked once for a long call) and stacked fields
+    (riding the depth scan) — hold the same values and give the same logits
+    through a prefill and single-token steps."""
+    if kind == "latent":  # two layer groups, latent + rope fields
+        cfg = dataclasses.replace(get_preset("xing-mini").model, compute_dtype="float32")
+        p = transformer.init_params(cfg, jax.random.key(0))
+    else:
+        cfg = dataclasses.replace(CFG, kv_cache_dtype="int8" if kind == "int8" else "compute")
+        p = params
+    tokens = jax.random.randint(jax.random.key(11), (2, 15), 0, cfg.vocab_size)
+    per_layer = transformer.make_kv_cache(cfg, 2, 16)
+    stacked = transformer.make_kv_cache(cfg, 2, 16, stacked=True)
+    assert set(stacked) == set(per_layer["layers"][0]) and len(per_layer["layers"]) == cfg.n_layers
+    # a prefill longer than decode_loop_max_tokens, then steps through the loop
+    for start, stop in [(0, 12), (12, 13), (13, 14), (14, 15)]:
+        step = lambda cache: transformer.forward(
+            p, tokens[:, start:stop], cfg, kv_cache=cache, cache_index=jnp.int32(start)
+        )
+        (want, stacked), (got, per_layer) = step(stacked), step(per_layer)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+    for name, buf in stacked.items():
+        restacked = jnp.stack([lyr[name] for lyr in per_layer["layers"]])
+        assert restacked.dtype == buf.dtype
+        np.testing.assert_allclose(
+            np.asarray(restacked[:, :, :15], np.float32), np.asarray(buf[:, :, :15], np.float32),
+            rtol=1e-5, atol=1 if buf.dtype == jnp.int8 else 1e-5,
+        )
 
 
 def test_greedy_cached_matches_uncached(params):
@@ -323,26 +317,6 @@ def test_generate_flash_equals_naive_greedy(params):
         generate(params, cfg_flash, prompt, 8, jax.random.key(7), temperature=0.0)
     )
     np.testing.assert_array_equal(got_n, got_f)
-
-
-def test_generate_decode_unroll_equals_rolled_greedy(params):
-    """decode_unroll_layers only changes the compiled loop structure (no
-    inner while -> no per-step cache copies); greedy output must be
-    bit-identical to the rolled depth scan."""
-    # The unroll knob is stacked-only (the unstacked default has no depth
-    # scan to unroll — config validation rejects the combination).
-    cfg_stacked = dataclasses.replace(CFG, decode_cache_layout="stacked")
-    cfg_unroll = dataclasses.replace(cfg_stacked, decode_unroll_layers=True)
-    with pytest.raises(ValueError, match="decode_unroll_layers requires"):
-        dataclasses.replace(CFG, decode_unroll_layers=True)
-    prompt = jax.random.randint(jax.random.key(16), (2, 8), 0, CFG.vocab_size)
-    got_r = np.asarray(
-        generate(params, cfg_stacked, prompt, 8, jax.random.key(7), temperature=0.0)
-    )
-    got_u = np.asarray(
-        generate(params, cfg_unroll, prompt, 8, jax.random.key(7), temperature=0.0)
-    )
-    np.testing.assert_array_equal(got_r, got_u)
 
 
 @pytest.mark.parametrize(

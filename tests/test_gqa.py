@@ -90,15 +90,12 @@ def test_gqa_cache_shape_and_decode_matches_full_forward():
     tokens = jax.random.randint(jax.random.key(1), (b, t), 0, cfg.vocab_size)
 
     cache = transformer.make_kv_cache(cfg, b, cfg.context_length)
-    # Default container is the unstacked per-layer tuple; MQA caches ONE
+    # Default container is the per-layer tuple; MQA caches ONE
     # kv head per layer either way (the GQA memory win under test).
     assert cache["layers"][0]["k"].shape == (
         b, cfg.context_length, 1, cfg.head_dim
     )
-    stacked = transformer.make_kv_cache(
-        dataclasses.replace(cfg, decode_cache_layout="stacked"),
-        b, cfg.context_length,
-    )
+    stacked = transformer.make_kv_cache(cfg, b, cfg.context_length, stacked=True)
     assert stacked["k"].shape == (
         cfg.n_layers, b, cfg.context_length, 1, cfg.head_dim
     )

@@ -34,9 +34,8 @@ def test_quantize_roundtrip_error_bound():
 
 def test_int8_cache_structure_and_memory():
     # Structure assertions target the STACKED container explicitly (the
-    # model default is the unstacked per-layer tuple, same fields/leaves).
-    stacked_cfg = dataclasses.replace(CFG, decode_cache_layout="stacked")
-    cache = transformer.make_kv_cache(stacked_cfg, 2, 32)
+    # default is the per-layer tuple, same fields/leaves).
+    cache = transformer.make_kv_cache(CFG, 2, 32, stacked=True)
     assert set(cache) == {"k", "v", "k_scale", "v_scale"}
     un = transformer.make_kv_cache(CFG, 2, 32)
     assert set(un) == {"layers"} and len(un["layers"]) == CFG.n_layers

@@ -170,15 +170,14 @@ def test_swiglu_rmsnorm_rope_variant_runs():
 
 
 @pytest.mark.parametrize(
-    "policy", ["full", "dots_saveable", "save_attn", "save_attn_res",
-               "save_qkv_attn", "save_big"]
+    "policy", ["full", "dots_saveable", "save_attn", "save_attn_res"]
 )
 def test_remat_matches_no_remat(policy):
     """Every remat policy is a pure scheduling choice: identical gradients.
 
-    The named-saveable policies (save_attn / save_qkv_attn / save_big) rely
-    on checkpoint_name tags inside the attention and MLP blocks; this pins
-    the tags to the math staying equivalent.
+    The named-saveable policies (save_attn / save_attn_res) rely on
+    checkpoint_name tags inside the attention block and the flash kernel;
+    this pins the tags to the math staying equivalent.
     """
     cfg = _fp32(TINY)
     cfg_remat = dataclasses.replace(cfg, remat=policy)

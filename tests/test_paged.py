@@ -86,18 +86,22 @@ def test_forward_paged_validation(params):
 
 
 def test_pool_shape_and_reserved_block():
-    # Default container is unstacked (per-layer pools, carry-aliasable).
+    # One pool a layer (carry-aliasable); the block axis of a leaf is 0.
     pools = transformer.make_paged_kv_pool(CFG, 6, 8)
     assert set(pools) == {"layers"} and len(pools["layers"]) == CFG.n_layers
     assert pools["layers"][0]["k_pool"].shape == (
         6, 8, CFG.kv_heads, CFG.head_dim
     )
-    stacked = transformer.make_paged_kv_pool(
-        dataclasses.replace(CFG, decode_cache_layout="stacked"), 6, 8
-    )
-    assert stacked["k_pool"].shape == (
-        CFG.n_layers, 6, 8, CFG.kv_heads, CFG.head_dim
-    )
+    assert paged.pool_block_size(pools, CFG) == 8
+    # Prefill stages through the STACKED dense cache; a per-layer one is
+    # refused rather than scattering nothing.
+    staged = transformer.make_kv_cache(CFG, 1, 16, stacked=True)
+    assert staged["k"].shape == (CFG.n_layers, 1, 16, CFG.kv_heads, CFG.head_dim)
+    ids = jnp.asarray([1, 2], jnp.int32)
+    out = paged._scatter_staged_pages(pools, staged, ids, 2)
+    assert out["layers"][0]["k_pool"].shape == pools["layers"][0]["k_pool"].shape
+    with pytest.raises(ValueError, match="stacked=True"):
+        paged._scatter_staged_pages(pools, transformer.make_kv_cache(CFG, 1, 16), ids, 2)
     with pytest.raises(ValueError, match="multiple of 8"):
         transformer.make_paged_kv_pool(CFG, 6, 12)
     with pytest.raises(ValueError, match="n_blocks"):
@@ -349,36 +353,29 @@ def test_multitoken_paged_forward_matches_stepwise(params):
 
 def test_batched_prefill_matches_sequential(params):
     """One fused prefill program for N prompts == N sequential prefills:
-    same pool bytes on every real block (both layouts), same greedy
-    first tokens."""
+    same pool bytes on every real block, same greedy first tokens."""
     prompts = _prompts(3)
-    for layout in ("unstacked", "stacked"):
-        cfg = dataclasses.replace(CFG, decode_cache_layout=layout)
-        pools_a = transformer.make_paged_kv_pool(cfg, 16, 8, dtype="float32")
-        pools_b = jax.tree.map(jnp.copy, pools_a)
-        alloc = paged.BlockAllocator(16)
-        ids = [alloc.alloc(paged.required_blocks(len(p), 8)) for p in prompts]
-        lasts = []
-        for p, b in zip(prompts, ids):
-            last, pools_a = paged.prefill_into_pool(params, cfg, pools_a, p, b)
-            lasts.append(int(np.argmax(np.asarray(last))))
-        toks, pools_b = paged.prefill_into_pool_batched(
-            params, cfg, pools_b, prompts, ids, jax.random.key(3),
-            temperature=0.0,
+    pools_a = transformer.make_paged_kv_pool(CFG, 16, 8, dtype="float32")
+    pools_b = jax.tree.map(jnp.copy, pools_a)
+    alloc = paged.BlockAllocator(16)
+    ids = [alloc.alloc(paged.required_blocks(len(p), 8)) for p in prompts]
+    lasts = []
+    for p, b in zip(prompts, ids):
+        last, pools_a = paged.prefill_into_pool(params, CFG, pools_a, p, b)
+        lasts.append(int(np.argmax(np.asarray(last))))
+    toks, pools_b = paged.prefill_into_pool_batched(
+        params, CFG, pools_b, prompts, ids, jax.random.key(3),
+        temperature=0.0,
+    )
+    assert np.asarray(toks).tolist() == lasts
+
+    def k_block(pools, blk):
+        return np.stack([np.asarray(l["k_pool"][blk]) for l in pools["layers"]])
+
+    for blk in sorted(set(b for row in ids for b in row)):
+        np.testing.assert_allclose(
+            k_block(pools_a, blk), k_block(pools_b, blk), atol=1e-6
         )
-        assert np.asarray(toks).tolist() == lasts
-
-        def k_block(pools, blk):
-            if "layers" in pools:
-                return np.stack(
-                    [np.asarray(l["k_pool"][blk]) for l in pools["layers"]]
-                )
-            return np.asarray(pools["k_pool"][:, blk])
-
-        for blk in sorted(set(b for row in ids for b in row)):
-            np.testing.assert_allclose(
-                k_block(pools_a, blk), k_block(pools_b, blk), atol=1e-6
-            )
 
 
 def test_batched_prefill_validation(params):

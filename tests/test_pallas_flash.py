@@ -138,7 +138,7 @@ def test_fused_single_block_backward_matches_naive(g):
 
 @pytest.mark.parametrize("policy_names", [("attn_o_res", "attn_lse"), ()])
 def test_remat_saved_residuals_match_recompute(policy_names):
-    """The 'save_qkv_attn'/'save_big' policies save the kernel's VJP residuals
+    """The 'save_attn_res' policy saves the kernel's VJP residuals
     (o + squeezed lse, tagged in _flash_fwd) instead of re-running the forward
     in the backward. Gradients must be identical either way — this pins the
     tag names and the lse squeeze/re-expand pair in _flash_fwd/_bwd."""

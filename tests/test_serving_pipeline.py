@@ -244,7 +244,7 @@ def test_host_blocked_counter_monotonic(params, monkeypatch):
     """Per-reap telemetry invariants: windows_reaped increments by
     exactly one per reap and host_blocked_s is monotonically
     non-decreasing (a reap that SUBTRACTED blocked time would corrupt
-    the per-window average bench.py reports)."""
+    the blocked time per window)."""
     seen = []
     orig = ServingEngine._reap_window
 

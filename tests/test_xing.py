@@ -454,14 +454,16 @@ GPT2 = dict(vocab_size=256, context_length=64, n_layers=2, activation="gelu", no
             remat="full")
 DENSE = {"mistral-7b-v0.1": ModelConfig(**MISTRAL), "gpt2-large": ModelConfig(d_model=40, n_heads=4, **GPT2),
          "gpt2-xl": ModelConfig(d_model=50, n_heads=5, **GPT2)}
-# (equations, hash) of each program as the parent commit 3f5f5eb traces it: the benchmark's
-# three dense configurations' flags at toy widths (hc_mult 1, no latent, no experts)
+# (equations, hash) of each program as the commit 7c8a3aa (PR 28) traces it, less its `name`
+# equations for the remat tags "qkv" and "mlp_hidden", which went with the policies that read
+# them (PR 29; before that, 3f5f5eb): the benchmark's three dense configurations' flags at
+# toy widths (hc_mult 1, no latent, no experts)
 PARENTS = {
-    ("mistral-7b-v0.1", "decode"): (531, "4fbf49da8df1bfe2"),
-    ("mistral-7b-v0.1", "prefill"): (275, "b5356c9c5ef058b1"),
-    ("mistral-7b-v0.1", "forward"): (214, "9b10aafb8fc4ed06"),
-    ("gpt2-large", "train"): (816, "539b2656e304a05e"),
-    ("gpt2-xl", "train"): (816, "d06566e6d808adb8"),
+    ("mistral-7b-v0.1", "decode"): (523, "16f3f04aad6681aa"),
+    ("mistral-7b-v0.1", "prefill"): (271, "1e842ff3fbeb6ac0"),
+    ("mistral-7b-v0.1", "forward"): (210, "86a2e3bb3520ba25"),
+    ("gpt2-large", "train"): (808, "98fa975426632a7d"),
+    ("gpt2-xl", "train"): (808, "092d28b6a34b7b9b"),
 }
 
 

@@ -63,7 +63,10 @@ def test_z_loss_changes_the_objective():
 def test_z_loss_validation():
     with pytest.raises(ValueError, match=">= 0"):
         ModelConfig(z_loss_coef=-0.1)
-    with pytest.raises(ValueError, match="fused"):
+    # both heads that remain implement the z term
+    for ce_impl in ("chunked", "dense"):
+        assert ModelConfig(z_loss_coef=1e-3, ce_impl=ce_impl).z_loss_coef == 1e-3
+    with pytest.raises(ValueError, match="ce_impl must be"):
         ModelConfig(z_loss_coef=1e-3, ce_impl="fused")
 
 
