@@ -228,17 +228,25 @@ class ModelConfig:
     # scales with L*B*T). Prefill attention always runs on the unquantized
     # local block; only decode-step reads dequantize.
     kv_cache_dtype: str = "compute"  # compute | int8
-    # Paged (serving) decode attention: "gather" assembles each row's KV
-    # with pool[tables] before a masked einsum (proven path); "kernel"
-    # runs the Pallas block-table kernel (ops/pallas_paged.py) that reads
-    # pool pages directly — no gathered copy is ever written, cutting the
-    # per-layer decode KV traffic ~3x at large batch*context. int8 pools
-    # compose with both: "gather" dequantizes after the pool gather,
-    # "kernel" fuses the scale-page dequant into the ragged kernel's page
-    # loop (only int8 bytes + scales cross HBM).
+    # Paged (serving) attention over a per-head page pool. "gather", the
+    # default, means "what the input and the backend allow"
+    # (models/transformer.py::paged_attention_form): the single-token decode
+    # step over an unquantized, unsharded pool of lane-wide heads on a TPU
+    # runs the Pallas kernel that copies each row's LIVE pages from the pool
+    # in place (ops/pallas_paged.py); everything else (several queries a
+    # row, int8 pools, a serving mesh, every other backend) attends over
+    # pool[tables], every slot the table names, with a masked einsum. On
+    # a v5e XLA keeps that gathered copy in VMEM while it fits (one HBM
+    # pass over every tabled slot, not three), so the kernel's gain is
+    # first the slots it never reads (PERF.md section 6, PR 30).
+    # "kernel" forces the Pallas forms for every input and backend
+    # (interpreted off the TPU; what the tests select): int8 pools then go
+    # through the ragged kernel, which fuses the scale-page dequant into
+    # its page loop (only int8 bytes + scales cross HBM), where "gather"
+    # dequantizes after the pool gather.
     paged_attention_impl: str = "gather"  # gather | kernel
     # Ragged-kernel speed knobs (paged_attention_impl="kernel" only; the
-    # gather path ignores both). `ragged_kv_splits` partitions each row's
+    # gather form and the decode step's kernel ignore both). `ragged_kv_splits` partitions each row's
     # page range across that many parallel grid lanes (FA2 work
     # partitioning with a log-sum-exp combine): 1 = single-pass kernel
     # (the pre-split default, bit-compatible), 0 = auto-tune from

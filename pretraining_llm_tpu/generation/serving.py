@@ -293,11 +293,14 @@ class ServingEngine:
                 )
         self.params = params
         self.cfg = cfg
-        # How a decode step reads the pool, fixed for the engine's programs: a
-        # per-head pool as configured, a latent pool by what its input and the
-        # backend allow (models/mla.py::decode_form).
+        # How a decode step reads the pool, fixed for the engine's programs:
+        # what its input and the backend allow (models/mla.py::decode_form for
+        # a latent pool, transformer.paged_attention_form for a per-head one).
         self.decode_attention = (
-            mla.decode_form(1) if cfg.kv_lora_rank else cfg.paged_attention_impl
+            mla.decode_form(1) if cfg.kv_lora_rank
+            else transformer.paged_attention_form(
+                cfg, 1, cfg.kv_cache_dtype == "int8", mesh=mesh
+            )
         )
         self.max_batch = int(max_batch)
         self.block_size = int(block_size)
@@ -525,6 +528,13 @@ class ServingEngine:
             # with its own phase split (a reader that wants a fresh account
             # sets its "seconds" back to 0).
             "phase_s": {p: 0.0 for p in PHASES},
+            # Pages the decode windows' attention had to read (those holding
+            # a slot visible to an active row, summed over rows and steps)
+            # and pages their block tables named (max_batch x max_blocks a
+            # step): live / tabled is how much of the table's width the
+            # traffic fills, which is what the in-place kernel saves over
+            # the gather form. Logged whenever the engine runs empty.
+            "attn_pages_live": 0, "attn_pages_tabled": 0,
             "ticks": 0, "slow_ticks": 0,
             "longest_tick": {"tick": 0, "seconds": 0.0, "phase_s": {}},
         }
@@ -568,7 +578,7 @@ class ServingEngine:
             # latent_dim elements a layer for a latent pool
             "bytes_per_token": total // (self.n_blocks * self.block_size),
             "pool_bytes": total,
-            # "gather" | "kernel" (per head), "gather" | "latent_kernel" (latent)
+            # "gather" | "kernel" | "ragged" (per head), "gather" | "latent_kernel" (latent)
             "decode_attention": self.decode_attention,
         }
         if self.d_pools is not None:
@@ -811,6 +821,24 @@ class ServingEngine:
         if chunked:
             self._note_chunk_window(decoded)
 
+    def _count_attention_pages(self, n: int) -> None:
+        """``attn_pages_live`` / ``attn_pages_tabled`` of the ``n``-step
+        decode window about to go out, from the decoding rows' committed
+        lengths: step j of a row sees slots (seq + j - sliding_window,
+        seq + j]."""
+        bs = self.block_size
+        seq = self.seq_lens[[
+            i for i, r in enumerate(self.rows)
+            if r is not None and r.prefill_pos is None
+        ]]
+        last = np.minimum(
+            seq[:, None] + np.arange(n)[None, :], self.max_blocks * bs - 1
+        )
+        window = self.cfg.sliding_window
+        first = np.maximum(last - window + 1, 0) // bs if window else 0
+        self.stats["attn_pages_live"] += int(np.sum(last // bs - first + 1))
+        self.stats["attn_pages_tabled"] += n * self.max_batch * self.max_blocks
+
     def _step_decode(self) -> bool:
         """The synchronous decode arm of step(); True when a decode
         window (or spec round) actually ran."""
@@ -826,6 +854,7 @@ class ServingEngine:
         # handled by the model's scratch-redirect guard; the invariant
         # here is on the WINDOW-START state only.
         paged.check_paged_bounds(self.tables, self.seq_lens, self.block_size)
+        self._count_attention_pages(n)
         self._key, sub = jax.random.split(self._key)
         toks, lp, moe = self._decode_window(
             jnp.asarray(self.tokens), jnp.asarray(self.tables),
@@ -1132,6 +1161,7 @@ class ServingEngine:
         paged.check_paged_bounds(
             self.tables[active], seq_dispatch[active], self.block_size
         )
+        self._count_attention_pages(n)
         with self._clock.span(
             "dispatch", "serving.dispatch_window",
             steps=n, window=self.stats["windows"],
@@ -2208,3 +2238,11 @@ class ServingEngine:
         self.tables[row, :] = 0
         self.seq_lens[row] = 0
         self.tokens[row] = 0
+        if not self.has_work():
+            st = self.stats
+            _log.info(
+                "engine empty after %d ticks, %d decode steps: attention read %d "
+                "live pages of %d tabled (%.4f)",
+                st["ticks"], st["steps"], st["attn_pages_live"], st["attn_pages_tabled"],
+                st["attn_pages_live"] / max(1, st["attn_pages_tabled"]),
+            )

@@ -83,6 +83,42 @@ class PagedInfo(NamedTuple):
     q_lens: Optional[jax.Array] = None  # (B,) int32 or None
 
 
+def paged_attention_form(
+    cfg: ModelConfig,
+    tq: int,
+    quantized: bool,
+    ragged: bool = False,
+    backend: Optional[str] = None,
+    mesh: Any = None,
+) -> str:
+    """The form attention over a per-head page pool takes for ``tq`` queries
+    a row: ``"kernel"`` (``ops/pallas_paged.py``: the row's live pages read in
+    place), ``"ragged"`` (``ops/pallas_ragged.py``: int8 pools, per-row query
+    counts) or ``"gather"`` (``pool[tables]`` and a masked einsum over every
+    slot the table names). Under ``paged_attention_impl="gather"``, the
+    default, it is read from the input as ``mla.decode_form`` reads a latent
+    pool's: the single-token decode step over an unquantized pool that no
+    serving mesh shards and whose pages are copies of their own takes the
+    kernel where Mosaic compiles, everything else (several queries a row,
+    int8 pools, a sharded pool, narrow or odd heads, every other backend) the
+    gather form. ``"kernel"`` forces the Pallas forms whatever the input and
+    the backend (interpreted off the TPU). The engine reports the decode
+    step's form in ``pool_info()``."""
+    if cfg.paged_attention_impl == "kernel":
+        return "ragged" if quantized or (tq > 1 and ragged) else "kernel"
+    from pretraining_llm_tpu.ops.pallas_paged import pages_copy_in_place
+
+    if (
+        tq == 1
+        and not quantized
+        and mesh is None
+        and (backend or jax.default_backend()) == "tpu"
+        and pages_copy_in_place(cfg.kv_heads, cfg.head_dim)
+    ):
+        return "kernel"
+    return "gather"
+
+
 def _lm_head_weights(params: Params, cfg: ModelConfig):
     """(w_out (D, V), bias (V,)|None) — single source of truth for the output
     head, shared by forward (sampling logits) and loss_fn (chunked CE)."""
@@ -372,15 +408,20 @@ def _attention_block(
                     "v_pool": scatter(kv["v_pool"], v),
                 }
 
-        if cfg.paged_attention_impl == "kernel" and quantized:
-            # int8 pools through the kernel path: the ragged kernel fuses
-            # the per-(slot, head) dequant into its page loop — int8 bytes
-            # + scale pages are what crosses HBM, never a dequantized
-            # (B, kv_len) copy. EVERY query shape routes the ragged form
-            # (decode steps pass q_lens=1 per row, uniform multi-token
-            # verifies pass q_lens=tq): one kernel owns quantized decode,
-            # chunked prefill AND the speculative verify, so the quantized
-            # graph has a single attention numerics path.
+        form = paged_attention_form(
+            cfg, tq, quantized, ragged=paged.q_lens is not None, mesh=current_mesh()
+        )
+        if form == "ragged":
+            # The ragged kernel owns what the other forms cannot take. int8
+            # pools: it fuses the per-(slot, head) dequant into its page
+            # loop — int8 bytes + scale pages are what crosses HBM, never a
+            # dequantized (B, kv_len) copy — for EVERY query shape (decode
+            # steps pass q_lens=1 per row, uniform multi-token verifies pass
+            # q_lens=tq), so the quantized graph has a single attention
+            # numerics path. Unquantized: the chunk lane, whose rows carry
+            # heterogeneous true query counts; it elides DMA past each
+            # row's OWN chunk end instead of scanning every row to the
+            # longest row's frontier.
             from pretraining_llm_tpu.ops.pallas_ragged import (
                 ragged_paged_attention,
             )
@@ -389,59 +430,45 @@ def _attention_block(
                 q_lens = paged.q_lens
             else:
                 q_lens = jnp.full((bsz,), tq, dtype=seq.dtype)
+            if quantized:
+                k_in, v_in = new_kv["k_pool"], new_kv["v_pool"]
+                scales = {
+                    "k_scale": new_kv["k_scale_pool"],
+                    "v_scale": new_kv["v_scale_pool"],
+                }
+            else:
+                k_in, v_in = new_kv["k_pool"].astype(cdt), new_kv["v_pool"].astype(cdt)
+                scales = {}
             with jax.named_scope("attn.core"):
                 out = ragged_paged_attention(
-                    q.astype(cdt),
-                    new_kv["k_pool"],
-                    new_kv["v_pool"],
-                    tables, seq, q_lens,
+                    q.astype(cdt), k_in, v_in, tables, seq, q_lens,
                     window=cfg.sliding_window,
-                    k_scale=new_kv["k_scale_pool"],
-                    v_scale=new_kv["v_scale_pool"],
                     kv_splits=cfg.ragged_kv_splits or None,
                     amla=cfg.ragged_amla,
+                    **scales,
                 )
-        elif cfg.paged_attention_impl == "kernel":
-            # Gather-free: the Pallas kernel DMAs each row's pages straight
-            # off the pool via the block table (ops/pallas_paged.py) — the
-            # row's KV bytes are read once, no (B, kv_len) copy is ever
-            # materialized. tq > 1 routes the multi-token form (the
+        elif form == "kernel":
+            # Gather-free: the Pallas kernel copies each row's LIVE pages
+            # straight from the pool through the block table
+            # (ops/pallas_paged.py), several a step of an in-row loop for
+            # the decode step; the slots of the table that hold nothing are
+            # never read. tq > 1 routes the multi-token form (the
             # speculative verify's per-query frontiers live inside the
             # kernel mask).
-            if tq > 1 and paged.q_lens is not None:
-                # Ragged multi-token form (chunked prefill): rows carry
-                # heterogeneous true query counts; the ragged kernel
-                # elides DMA past each row's OWN chunk end instead of
-                # scanning every row to the longest row's frontier.
-                from pretraining_llm_tpu.ops.pallas_ragged import (
-                    ragged_paged_attention,
-                )
+            from pretraining_llm_tpu.ops.pallas_paged import (
+                paged_decode_attention,
+            )
 
-                with jax.named_scope("attn.core"):
-                    out = ragged_paged_attention(
-                        q.astype(cdt),
-                        new_kv["k_pool"].astype(cdt),
-                        new_kv["v_pool"].astype(cdt),
-                        tables, seq, paged.q_lens,
-                        window=cfg.sliding_window,
-                        kv_splits=cfg.ragged_kv_splits or None,
-                        amla=cfg.ragged_amla,
-                    )
-            else:
-                from pretraining_llm_tpu.ops.pallas_paged import (
-                    paged_decode_attention,
+            qin = q[:, 0] if tq == 1 else q
+            with jax.named_scope("attn.core"):
+                out = paged_decode_attention(
+                    qin.astype(cdt),
+                    new_kv["k_pool"].astype(cdt),
+                    new_kv["v_pool"].astype(cdt),
+                    tables, seq, window=cfg.sliding_window,
                 )
-
-                qin = q[:, 0] if tq == 1 else q
-                with jax.named_scope("attn.core"):
-                    out = paged_decode_attention(
-                        qin.astype(cdt),
-                        new_kv["k_pool"].astype(cdt),
-                        new_kv["v_pool"].astype(cdt),
-                        tables, seq, window=cfg.sliding_window,
-                    )
-                if tq == 1:
-                    out = out[:, None]
+            if tq == 1:
+                out = out[:, None]
         else:
             max_blocks = tables.shape[1]
             kv_len = max_blocks * block_size

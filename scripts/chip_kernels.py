@@ -1,6 +1,7 @@
 #!/usr/bin/env python
 """Compile and run every Pallas attention kernel on the TPU against its XLA
-reference, at the sizes serving and training use (Dh 64, 12 heads, bf16).
+reference, at the sizes serving and training use (Dh 64, 12 heads, bf16; the
+paged decode step also at 32 heads of 128).
 
 The CPU tests run these kernels in interpret mode at toy sizes; only the
 chip hears Mosaic's refusals (tiling, unaligned slices, VMEM) and only there
@@ -79,9 +80,9 @@ def flash_case(t: int, g: int, seg: bool):
     return errs, 3e-2
 
 
-def _pool(key, g: int, page: int, int8: bool):
+def _pool(key, g: int, page: int, int8: bool, dh: int = DH):
     ks = jax.random.split(key, 4)
-    shape = (N_BLOCKS, page, g, DH)
+    shape = (N_BLOCKS, page, g, dh)
     if not int8:
         return (
             jax.random.normal(ks[0], shape, jnp.bfloat16),
@@ -116,15 +117,18 @@ def _tables(b: int, page: int, t: int, rng: np.random.Generator):
     return jnp.asarray(tables), jnp.asarray(seq)
 
 
-def paged_case(g: int, page: int, t: int):
+def paged_case(g: int, page: int, t: int, h: int = H, dh: int = DH):
+    """Heads of 64 run the one-page-a-grid-step form whatever ``t``; one query
+    a row over heads of 128 (the Mistral cells' layout) the form that copies a
+    row's live pages in place (``pallas_paged.pages_copy_in_place``)."""
     from pretraining_llm_tpu.ops.pallas_paged import paged_decode_attention
     from pretraining_llm_tpu.ops.pallas_ragged import ragged_gather_attention
 
     b = 8
     rng = np.random.default_rng(page + t)
-    k_pool, v_pool, _, _ = _pool(jax.random.key(1), g, page, False)
+    k_pool, v_pool, _, _ = _pool(jax.random.key(1), g, page, False, dh)
     tables, seq = _tables(b, page, t, rng)
-    q = jax.random.normal(jax.random.key(2), (b, t, H, DH), jnp.bfloat16)
+    q = jax.random.normal(jax.random.key(2), (b, t, h, dh), jnp.bfloat16)
     got = paged_decode_attention(
         q[:, 0] if t == 1 else q, k_pool, v_pool, tables, seq
     )
@@ -168,6 +172,8 @@ def cases():
         for g in (12, 4):
             for t in (1, 4):  # decode / speculative verify
                 yield f"paged page{page} g{g} t{t}", paged_case, (g, page, t)
+        for g in (8, 32):  # grouped and ungrouped heads of 128, in place
+            yield f"paged page{page} g{g} t1 h32 dh128", paged_case, (g, page, 1, 32, 128)
     for page in (64, 16):
         for g in (12, 4):
             for t in (1, 128):  # decode-only launch / chunked-prefill launch

@@ -218,3 +218,47 @@ def test_kernel_compiles_for_the_chip_at_the_cells_size(one_chip):
     pool_sized = [op for op in ops if op.startswith(f"bf16[{n_blocks},") and " parameter(" not in op]
     assert not pool_sized, pool_sized
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20  # block-diagonal queries, a padded table
+
+
+@pytest.mark.parametrize("max_blocks,heads,kv_heads,ok", [(17, 32, 8, True), (22, 32, 8, True), (22, 32, 32, True),
+                                                          (17, 12, 12, False)],
+                         ids=["decode-cell", "chat-cell", "ungrouped-heads", "heads-of-64"])
+def test_per_head_decode_kernel_compiles_for_the_chip_at_the_cells_size(one_chip, max_blocks, heads, kv_heads, ok):
+    """``ops/pallas_paged.py``'s single-query form at the Mistral cells' size
+    (40 rows, 32 query heads over 8 kv heads of 128, 641 pages of 64 slots,
+    tables of 17 and 22 pages, window 4,096): Mosaic takes it, the flat
+    (641, 512, 128) view of each pool is a bitcast, and no pool-sized copy
+    stands beside the custom call. Ungrouped heads make a page four times as
+    wide, so fewer pages a step keep two groups inside VMEM. Heads of 64 are
+    what ``pages_copy_in_place`` exists to keep away: Mosaic refuses to copy
+    a page that is half a lane tile wide."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from pretraining_llm_tpu.ops import pallas_paged as pp
+
+    b, d, bs, n_blocks = 40, 128 if ok else 64, 64, 641
+    shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    pool = shape((n_blocks, bs, kv_heads, d))
+    pages = pp._pages_a_step(pool, pp.PAGES_PER_STEP)
+    fn = lambda q, k, v, t, n: pp._decode_call(q, k, v, t, n, 4096, pages, False)
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable without a chip
+    compilation_cache.reset_cache()
+    try:
+        lowered = jax.jit(fn).lower(shape((b, heads, d)), pool, pool, shape((b, max_blocks), jnp.int32),
+                                    shape((b,), jnp.int32))
+        assert pp.pages_copy_in_place(kv_heads, d) == ok
+        if not ok:
+            with pytest.raises(Exception, match="aligned to tiling"):
+                lowered.compile()
+            return
+        compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    ops = [line.split(" = ", 1)[1] for line in text.splitlines() if " = " in line]
+    pool_sized = [op for op in ops if op.startswith(f"bf16[{n_blocks},") and " parameter(" not in op]
+    assert len(pool_sized) == 2 and all(" bitcast(" in op for op in pool_sized), pool_sized
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

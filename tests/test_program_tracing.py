@@ -156,6 +156,46 @@ def test_phase_account_sums_to_the_ticks(recorded_run):
     assert eng.stats["slow_ticks"] == 0
 
 
+def _tick_pages(eng):
+    """(live, tabled) one tick added: a tick dispatches at most one window."""
+    before = (eng.stats["attn_pages_live"], eng.stats["attn_pages_tabled"])
+    eng.pipeline_tick()
+    return (eng.stats["attn_pages_live"] - before[0], eng.stats["attn_pages_tabled"] - before[1])
+
+
+@pytest.mark.parametrize("window", [0, 12], ids=["no-window", "sliding-window"])
+def test_attention_pages_live_and_tabled_grow_with_each_window(params, window, caplog):
+    """``attn_pages_live`` counts, for every step of a decode window and every
+    decoding row, the pages that hold a slot the step can see; ``attn_pages_tabled``
+    the pages its block tables name, ``max_batch x max_blocks`` a step."""
+    cfg = dataclasses.replace(CFG, sliding_window=window)
+    eng = ServingEngine(params, cfg, max_batch=3, n_blocks=32, block_size=8, max_seq=64,
+                        steps_per_sched=2, pipeline_depth=2)
+    assert (eng.stats["attn_pages_live"], eng.stats["attn_pages_tabled"]) == (0, 0)
+    assert _tick_pages(eng) == (0, 0)  # every row idle: no window, nothing read
+    prompts = _prompts(2, lengths=(21, 9))
+    for p in prompts:
+        eng.submit(p, 20)
+    lengths = np.asarray([len(p) for p in prompts])  # a row's first decode step writes slot len(prompt)
+
+    def expected(seq):
+        steps = seq[:, None] + np.arange(2)[None, :]
+        first = np.maximum(steps - window + 1, 0) // 8 if window else 0
+        return int(np.sum(steps // 8 - first + 1))
+
+    tabled = 2 * 3 * eng.max_blocks  # two steps, three rows' tables, one of them idle
+    assert _tick_pages(eng) == (expected(lengths), tabled)
+    assert _tick_pages(eng) == (expected(lengths + 2), tabled)
+    assert 0 < eng.stats["attn_pages_live"] <= eng.stats["attn_pages_tabled"]
+    with caplog.at_level(logging.INFO, logger="pretraining_llm_tpu.serving"):
+        while eng.pipeline_tick():
+            pass
+    st = eng.stats
+    assert st["attn_pages_live"] <= st["attn_pages_tabled"] == st["steps"] * 3 * eng.max_blocks
+    lines = [r.getMessage() for r in caplog.records if "engine empty" in r.getMessage()]
+    assert len(lines) == 1 and f"{st['attn_pages_live']} live pages of {st['attn_pages_tabled']} tabled" in lines[0]
+
+
 class _SlowReadback:
     """``numpy`` for the serving module, whose next ``asarray`` takes ``delay`` seconds."""
 
