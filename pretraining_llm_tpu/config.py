@@ -184,6 +184,20 @@ class ModelConfig:
     # beside the routed ones. d_expert 0 = d_ff.
     n_shared_experts: int = 0
     d_expert: int = 0
+    # Group-limited selection (DeepSeek-V3's noaux_tc): the experts are
+    # moe_n_group groups of consecutive experts, a group scores the sum of its
+    # two best biased scores, and the top-k is taken among the experts of the
+    # moe_topk_group best groups. 1 group = no limit.
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    # Experts whose weights this program holds, the router's first ones
+    # (expert parallelism's share: moe.moe_mlp_dropless). 0 = all n_experts.
+    n_experts_held: int = 0
+    # SwiGLU clamps, one a layer (empty = none, 0 = none in that layer):
+    # silu(min(gate, L)) * clip(up, -L, L) in the routed experts
+    # (moe_swiglu_limits) and in the shared expert (moe_shared_swiglu_limits).
+    moe_swiglu_limits: Tuple[float, ...] = ()
+    moe_shared_swiglu_limits: Tuple[float, ...] = ()
     # Leading dense layers (width d_ff) before the expert layers: stored as
     # params["dense_blocks"], run as a group of their own ahead of
     # params["blocks"]. Caches and pools cover all n_layers.
@@ -207,6 +221,21 @@ class ModelConfig:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    # A head-wise sigmoid gate on latent attention's output, from the
+    # sublayer's input: o_h * sigmoid((x W_gate)_h).
+    attn_output_gate: bool = False
+    # Hybrid stack, layer_group_size g > 0: layer i is a latent-attention
+    # layer when (i + 1) % g == 0 and a KDA linear-attention layer otherwise
+    # (models/kda.py; arXiv:2510.26692). A KDA layer has n_heads heads of
+    # kda_head_dim keys and values, a causal depthwise convolution of
+    # kda_conv_kernel taps on q, k and v, and a per-channel log-decay bounded
+    # below by kda_gate_lower_bound a token. Its cache is a fixed-size state a
+    # row, not pages (transformer.make_paged_kv_pool). The stack is stored and
+    # scanned as runs of like layers, params["groups"] (layer_runs).
+    layer_group_size: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
+    kda_gate_lower_bound: float = -5.0
     # Manifold-constrained hyper-connections (arXiv:2512.24880): hc_mult > 1
     # residual streams a token, read, written and mixed per sublayer by
     # coefficients computed in float32 (models/hyper.py). 1 = plain residual.
@@ -347,6 +376,41 @@ class ModelConfig:
                     "sigmoid scores, a score bias, shared experts, d_expert and "
                     "moe_routed_scale need moe_routing='dropless'"
                 )
+        if self.n_experts and (
+            self.n_experts % self.moe_n_group or not 1 <= self.moe_topk_group <= self.moe_n_group
+            or self.experts_per_token > self.moe_topk_group * (self.n_experts // self.moe_n_group)
+            or not 0 <= self.n_experts_held <= self.n_experts
+        ):
+            raise ValueError(
+                "moe_n_group must divide n_experts, moe_topk_group groups must hold "
+                "experts_per_token experts, and n_experts_held is at most n_experts"
+            )
+        if (self.moe_n_group > 1 or self.n_experts_held or self.moe_swiglu_limits
+                or self.moe_shared_swiglu_limits) and not self.moe_dropless:
+            raise ValueError(
+                "moe_n_group, n_experts_held and the SwiGLU clamps need moe_routing='dropless'"
+            )
+        for name in ("moe_swiglu_limits", "moe_shared_swiglu_limits"):
+            # a JSON round trip hands back a list; the config is a static (hashed) jit argument
+            object.__setattr__(self, name, tuple(float(v) for v in getattr(self, name)))
+        for limits in (self.moe_swiglu_limits, self.moe_shared_swiglu_limits):
+            if limits and (len(limits) != self.n_layers or min(limits) < 0):
+                raise ValueError("a SwiGLU clamp list has one limit >= 0 a layer")
+        if self.layer_group_size:
+            if self.layer_group_size < 2 or not self.kv_lora_rank or self.kda_head_dim < 1:
+                raise ValueError(
+                    "a hybrid stack (layer_group_size >= 2) needs latent attention "
+                    "(kv_lora_rank) for its attention layers and kda_head_dim"
+                )
+            if self.kda_conv_kernel < 2 or self.kda_gate_lower_bound >= 0:
+                raise ValueError("kda_conv_kernel >= 2 and kda_gate_lower_bound < 0")
+            if self.hc_mult > 1 or self.pipeline_stages > 1 or self.attention_impl in ("ring", "ulysses"):
+                raise ValueError(
+                    "a hybrid stack runs with plain residuals, no pipeline and no "
+                    "ring/ulysses attention"
+                )
+        if self.attn_output_gate and not self.kv_lora_rank:
+            raise ValueError("attn_output_gate is latent attention's (kv_lora_rank)")
         if not 0 <= self.n_dense_layers < max(self.n_layers, 1) or (
             self.n_dense_layers and not self.n_experts
         ):
@@ -472,6 +536,38 @@ class ModelConfig:
         return self.d_expert or self.d_ff
 
     @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
+        """(mixer, ffn) of every layer: mixer ``"kda"`` or ``"attn"`` (per-head
+        or latent attention, whichever the model has), ffn ``"dense"`` or
+        ``"moe"``."""
+        g = self.layer_group_size
+        return tuple(
+            ("kda" if g and (i + 1) % g else "attn",
+             "moe" if self.n_experts and i >= self.n_dense_layers else "dense")
+            for i in range(self.n_layers)
+        )
+
+    @property
+    def layer_runs(self) -> Tuple[Tuple[int, int], ...]:
+        """The stack as runs of like layers, (first, past the last): what
+        ``forward`` scans one at a time. A homogeneous model is one run, or two
+        with leading dense layers."""
+        kinds, runs, start = self.layer_kinds, [], 0
+        for i in range(1, self.n_layers + 1):
+            if i == self.n_layers or kinds[i] != kinds[start]:
+                runs.append((start, i))
+                start = i
+        return tuple(runs)
+
+    @property
+    def n_kda_layers(self) -> int:
+        return sum(mixer == "kda" for mixer, _ in self.layer_kinds)
+
+    @property
     def latent_dim(self) -> int:
         """Values cached a token a layer by latent attention (0 = per-head K/V)."""
         return self.kv_lora_rank + self.qk_rope_head_dim if self.kv_lora_rank else 0
@@ -505,7 +601,8 @@ class ModelConfig:
         shared = self._attn_params() + 2 * self._norm_params() + 2 * self._hc_params()
         moe_layers = self.n_layers - self.n_dense_layers if self.n_experts else 0
         n += (self.n_layers - moe_layers) * (shared + self._ffn_params(self.d_ff))
-        n += moe_layers * (shared + self._moe_params(self.n_experts))
+        n += moe_layers * (shared + self._moe_params(self.experts_held))
+        n += self.n_kda_layers * (self._kda_params() - self._attn_params())
         n += self._norm_params()  # final norm
         if not self.tie_embeddings:
             n += d * v
@@ -519,13 +616,21 @@ class ModelConfig:
             r, c = self.q_lora_rank, self.kv_lora_rank
             q = d * r + r + r * h * dh if r else d * h * dh  # down, its norm, up
             kv = d * self.latent_dim + c + c * h * (self.qk_nope_head_dim + self.v_head_dim)
-            return q + kv + h * self.v_head_dim * d
+            return q + kv + h * self.v_head_dim * d + (d * h if self.attn_output_gate else 0)
         n = d * h * dh + 2 * d * g * dh  # wqkv (or wq + wkv for GQA)
         if self.qkv_bias:
             n += h * dh + 2 * g * dh
         if self.use_output_proj:
             n += h * dh * d + d  # wo + bias
         return n
+
+    def _kda_params(self) -> int:
+        """A KDA mixer: q, k, v, decay, output gate and output projections, beta,
+        the three convolutions, A_log, dt_bias and the head norm's scale."""
+        d, w = self.d_model, self.n_heads * self.kda_head_dim
+        return 6 * d * w + d * self.n_heads + 3 * w * self.kda_conv_kernel + (
+            self.n_heads + w + self.kda_head_dim
+        )
 
     def _hc_params(self) -> int:
         """One sublayer's hyper-connection: phi, b and the three alphas."""
@@ -564,7 +669,7 @@ class ModelConfig:
         """
         n = self.num_params()
         if self.n_experts:
-            inactive = self.n_experts - self.experts_per_token
+            inactive = self.experts_held - self.experts_per_token
             n -= (self.n_layers - self.n_dense_layers) * inactive * self._per_expert_params()
         return n
 
@@ -1560,6 +1665,32 @@ _register(
             n_experts=8, experts_per_token=2, moe_routing="dropless", moe_score="sigmoid",
             moe_score_bias=True, moe_routed_scale=2.0, n_shared_experts=1, d_expert=32,
             n_dense_layers=1, hc_mult=4,
+        ),
+        mesh=MeshConfig(),
+        data=DataConfig(tokenizer_name="byte"),
+        train=TrainConfig(batch_size=8, train_steps=50, eval_interval=20, eval_iters=2, lr=1e-3),
+    ),
+)
+
+# Every mechanism of the Ling-3.0 family at a width a CPU smoke run holds: two
+# periods of 2 KDA linear-attention layers to 1 latent-attention layer with a
+# head-wise gate, a leading dense layer, group-limited sigmoid routing over
+# half of 16 experts with a shared one, a SwiGLU clamp in the last layers. The
+# published widths are benchmark/configs/ling-3.0-flash.json; this is for the
+# unit tests and serve.py.
+_register(
+    "ling-mini",
+    Config(
+        model=ModelConfig(
+            vocab_size=256, context_length=256, d_model=64, n_heads=4, n_layers=6, d_head=24,
+            mlp_ratio=2.5, activation="swiglu", norm="rmsnorm", pos_embed="rope",
+            tie_embeddings=False, mlp_bias=False, norm_eps=1e-6,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            attn_output_gate=True, layer_group_size=3, kda_head_dim=16,
+            n_experts=16, n_experts_held=8, experts_per_token=2, moe_routing="dropless",
+            moe_score="sigmoid", moe_score_bias=True, moe_routed_scale=2.5, n_shared_experts=1,
+            d_expert=32, n_dense_layers=1, moe_n_group=4, moe_topk_group=2,
+            moe_swiglu_limits=(0.0, 0.0, 0.0, 0.0, 4.0, 4.0),
         ),
         mesh=MeshConfig(),
         data=DataConfig(tokenizer_name="byte"),

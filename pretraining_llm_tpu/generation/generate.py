@@ -112,8 +112,12 @@ def _generate_jit(
             # keeps pad positions (>= prompt_len) invisible to real ones,
             # and each pad slot's garbage K/V is overwritten by the decoded
             # token that lands there before the kv_mask ever exposes it.
+            # (A recurrent layer's state has no slot to overwrite: it takes
+            # the true length and leaves the padding out, models/kda.py.)
             logits, cache = transformer.forward(
-                params, prompt, cfg, kv_cache=cache, cache_index=jnp.int32(0)
+                params, prompt, cfg, kv_cache=cache, cache_index=jnp.int32(0),
+                lengths=jnp.broadcast_to(prompt_len.astype(jnp.int32), (b,))
+                if cfg.layer_group_size else None,
             )
             idx = jnp.broadcast_to(
                 (prompt_len - 1).astype(jnp.int32), (b, 1, logits.shape[-1])
@@ -132,7 +136,8 @@ def _generate_jit(
             # copies that the decode kv mask never exposes.
             pad_off = (bucket - prompt_lengths).astype(jnp.int32)
             logits, cache = transformer.forward(
-                params, prompt, cfg, kv_cache=cache, cache_index=jnp.int32(0)
+                params, prompt, cfg, kv_cache=cache, cache_index=jnp.int32(0),
+                lengths=prompt_lengths.astype(jnp.int32) if cfg.layer_group_size else None,
             )
             idx = jnp.broadcast_to(
                 (prompt_lengths - 1).astype(jnp.int32)[:, None, None],
@@ -142,11 +147,16 @@ def _generate_jit(
             src = jnp.clip(
                 jnp.arange(total)[None, :] - pad_off[:, None], 0, total - 1
             )  # (B, total)
-            # per-layer leaves are (B, T, ...)
-            cache = jax.tree.map(
-                lambda c: jnp.take_along_axis(c, src[:, :, None, None], axis=1),
-                cache,
-            )
+            # per-layer leaves are (B, T, ...); a KDA layer keeps a state as
+            # of its row's last real token and no slots, so nothing to roll
+            cache = {"layers": tuple(
+                lyr if "state" in lyr else jax.tree.map(
+                    lambda c: jnp.take_along_axis(
+                        c, src.reshape(src.shape + (1,) * (c.ndim - 2)), axis=1),
+                    lyr,
+                )
+                for lyr in cache["layers"]
+            )}
             start_index = jnp.int32(bucket)
         next_tok = sample_logits(
             last, sub, temperature=temperature, top_k=top_k, top_p=top_p,

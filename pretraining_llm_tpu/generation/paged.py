@@ -54,7 +54,8 @@ _POOL_OF_DENSE = {
 
 def pool_block_size(pools: transformer.KVCache, cfg: ModelConfig) -> int:
     """Tokens a page of ``pools`` holds (per-head or latent)."""
-    fields = pools["layers"][0]
+    # the first layer that has pages (a hybrid stack's KDA layers keep state slots)
+    fields = next(f for f in pools["layers"] if "state_pool" not in f)
     if "k_pool" in fields:
         return int(fields["k_pool"].shape[1])
     # a latent page is folded (models/mla.py::page_fold): rows x (slots a row x width)
@@ -65,6 +66,23 @@ def pool_block_size(pools: transformer.KVCache, cfg: ModelConfig) -> int:
 def required_blocks(n_tokens: int, block_size: int) -> int:
     """Blocks needed to hold ``n_tokens`` cache slots."""
     return -(-n_tokens // block_size)
+
+
+def prefill_bucket(cfg: ModelConfig, n_rows: int, max_pages: int, block_size: int) -> Tuple[int, int]:
+    """(rows, pages) of the batched prefill program that takes ``n_rows``
+    prompts of at most ``max_pages`` pages: both bucketed to powers of two so
+    the jit cache stays at O(log(max_batch) * log(max_pages)) program variants,
+    the pages no further than the model's context (its whole pages), which
+    every prompt the engine accepts fits."""
+    pages = min(1 << (max_pages - 1).bit_length(), cfg.context_length // block_size)
+    return 1 << (n_rows - 1).bit_length(), max(max_pages, pages)
+
+
+def state_slots(pools: transformer.KVCache) -> int:
+    """State slots a hybrid stack's pools hold, the scratch slot (the last) not
+    counted; 0 for a model that keeps pages alone."""
+    state = next((f for f in pools["layers"] if "state_pool" in f), None)
+    return 0 if state is None else state["state_pool"].shape[0] - 1
 
 
 def check_paged_bounds(block_tables, seq_lens, block_size: int) -> None:
@@ -142,14 +160,23 @@ def _scatter_staged_pages(
     dense_cache: transformer.KVCache,
     flat_ids: jax.Array,  # (n_rows * n_pages,) int32 pool block ids
     n_chunks: int,  # n_rows * n_pages (static)
+    slots: Optional[jax.Array] = None,  # (n_rows,) int32 state slots
 ) -> transformer.KVCache:
     """ONE definition of the staged-cache -> pool page scatter, shared by
     the single-prompt and batched admission prefills. The staged cache is
-    STACKED ((L, N, n_pages*bs, ...) fields, make_kv_cache(stacked=True));
-    each layer of each field is cut into ``n_chunks`` pages and scattered
-    into that layer's pool at ``flat_ids`` (pad pages point at the reserved
-    scratch block 0 — duplicate indices there are benign)."""
-    staged = [(d, p) for d, p in _POOL_OF_DENSE.items() if d in dense_cache]
+    STACKED ((L, N, n_pages*bs, ...) fields, make_kv_cache(stacked=True)), or
+    per layer for a hybrid stack, whose layers keep unlike caches; each layer
+    of each field is cut into ``n_chunks`` pages and scattered into that
+    layer's pool at ``flat_ids`` (pad pages point at the reserved scratch
+    block 0 — duplicate indices there are benign). A KDA layer's staged state
+    and conv tail go whole into slot ``slots[row]`` of its state pools (pad
+    rows: the scratch slot), written with the pages."""
+    # per layer only where the layers keep unlike caches (_staging_cache)
+    per_layer = "layers" in dense_cache and any("state_pool" in lp for lp in pools["layers"])
+    field = (lambda layer, key: dense_cache["layers"][layer][key]) if per_layer else (
+        lambda layer, key: dense_cache[key][layer])
+    names = set().union(*dense_cache["layers"]) if per_layer else set(dense_cache)
+    staged = [(d, p) for d, p in _POOL_OF_DENSE.items() if d in names]
     if not staged:
         # A per-layer staging cache would otherwise silently prefill NOTHING.
         raise ValueError(
@@ -159,17 +186,30 @@ def _scatter_staged_pages(
 
     def _layer(layer, layer_pool):
         out = dict(layer_pool)
+        if "state_pool" in layer_pool:
+            if slots is None:
+                raise ValueError("a state-slot model's prefill names each row's slot")
+            for name in ("state", "conv"):
+                pool = layer_pool[name + "_pool"]
+                out[name + "_pool"] = pool.at[slots].set(field(layer, name).astype(pool.dtype))
+            return out
         for dense_key, pool_key in staged:
             pool = layer_pool[pool_key]
             # a page is whatever one block of this pool holds: (bs, G, Dh) per
             # head; (bs / fold, fold * c) of latents and (bs / fold, fold * r) of
             # rotated key slices, the same values row-major as (bs, c) and (bs, r)
-            pages = dense_cache[dense_key][layer].reshape((n_chunks,) + pool.shape[1:])
+            pages = field(layer, dense_key).reshape((n_chunks,) + pool.shape[1:])
             out[pool_key] = pool.at[flat_ids].set(pages.astype(pool.dtype))
         return out
 
     with jax.named_scope("attn.kv_write"):
-        return {"layers": tuple(_layer(i, lp) for i, lp in enumerate(pools["layers"]))}
+        return {**pools, "layers": tuple(_layer(i, lp) for i, lp in enumerate(pools["layers"]))}
+
+
+def _staging_cache(cfg: ModelConfig, n_rows: int, p_bucket: int) -> transformer.KVCache:
+    """The dense cache a prefill forward fills for ``_scatter_staged_pages``:
+    stacked (the rolled depth scan), per layer for a hybrid stack."""
+    return transformer.make_kv_cache(cfg, n_rows, p_bucket, stacked=not cfg.layer_group_size)
 
 
 # A prefill computes the f32 logits of every position and keeps the last real
@@ -186,9 +226,12 @@ def _prefill_last_logits(
     """Causal forward over (N, P) padded prompts into ``cache`` -> (logits
     (N, V) f32 at each row's ``last_idx``, the filled cache)."""
     n_rows, p_bucket = prompts.shape
+    # a recurrent layer leaves its state as of each row's last real token
+    lengths = last_idx + 1 if cfg.layer_group_size else None
     if 4 * n_rows * p_bucket * cfg.vocab_size <= _ALL_POSITION_LOGITS_BYTES:
         logits, cache = transformer.forward(
-            params, prompts, cfg, kv_cache=cache, cache_index=jnp.int32(0)
+            params, prompts, cfg, kv_cache=cache, cache_index=jnp.int32(0),
+            lengths=lengths,
         )
         last = jnp.take_along_axis(
             logits,
@@ -198,7 +241,7 @@ def _prefill_last_logits(
         return last, cache
     hidden, cache = transformer.forward(
         params, prompts, cfg, kv_cache=cache, cache_index=jnp.int32(0),
-        return_pre_logits=True,
+        return_pre_logits=True, lengths=lengths,
     )
     last_h = jnp.take_along_axis(
         hidden, jnp.broadcast_to(last_idx[:, None, None], (n_rows, 1, hidden.shape[-1])), axis=1
@@ -212,12 +255,22 @@ def _scatter_pages(
     dense_cache: transformer.KVCache,
     block_ids: jax.Array,  # (n_pages,) int32
     n_pages: int,
+    slot: Optional[jax.Array] = None,  # () int32
 ) -> transformer.KVCache:
     """Scatter a (L, 1, n_pages*bs, ...) dense prefill cache into the pools
     at ``block_ids``. Donated pools: the update is in-place on device. (The
-    batch-1 form of
-    ``_scatter_staged_pages``.)"""
-    return _scatter_staged_pages(pools, dense_cache, block_ids, n_pages)
+    batch-1 form of ``_scatter_staged_pages``.) A state-slot model's prompt
+    goes into ``slot``; with none named, into the slot under the pools' own
+    cursor, which then moves on: the n-th prompt so prefilled lands in slot n
+    (mod the batch rows), the row a caller that builds its tables in prefill
+    order decodes it at."""
+    if "state_cursor" not in pools:
+        return _scatter_staged_pages(pools, dense_cache, block_ids, n_pages)
+    cursor = pools["state_cursor"]
+    if slot is None:
+        slot, cursor = cursor % state_slots(pools), cursor + 1
+    pools = _scatter_staged_pages(pools, dense_cache, block_ids, n_pages, slot[None])
+    return {**pools, "state_cursor": cursor}
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "p_bucket", "mesh"))
@@ -242,8 +295,8 @@ def _prefill_dense(
     with activation_mesh(mesh):
         # One forward fills the staging cache and _scatter_pages consumes it
         # field by field ((L, 1, pages*bs, ...) -> pool pages): stacked.
-        cache = transformer.make_kv_cache(cfg, 1, p_bucket, stacked=True)
-        if 4 * p_bucket * cfg.vocab_size > _ALL_POSITION_LOGITS_BYTES:
+        cache = _staging_cache(cfg, 1, p_bucket)
+        if cfg.layer_group_size or 4 * p_bucket * cfg.vocab_size > _ALL_POSITION_LOGITS_BYTES:
             last, cache = _prefill_last_logits(
                 params, prompt, (prompt_len - 1).astype(jnp.int32)[None], cfg, cache
             )
@@ -266,12 +319,14 @@ def prefill_into_pool(
     block_ids: Sequence[int],
     *,
     mesh: Any = None,
+    slot: Optional[int] = None,
 ) -> Tuple[jax.Array, transformer.KVCache]:
     """Prefill one prompt and write its pages into the pool.
 
     ``block_ids`` must be exactly ceil(len(prompt)/block_size) pages
     (allocator output). Returns (last-token logits (V,) fp32, updated
-    pools). Compiles once per page count, not per prompt length.
+    pools). Compiles once per page count, not per prompt length. ``slot``: the
+    state slot of a state-slot model's row (``_scatter_pages`` for none).
     """
     block_size = pool_block_size(pools, cfg)
     p = len(prompt_ids)
@@ -290,7 +345,8 @@ def prefill_into_pool(
         params, prompt, jnp.int32(p), cfg, p_bucket, mesh
     )
     pools = _scatter_pages(
-        pools, dense, jnp.asarray(block_ids, jnp.int32), n_pages
+        pools, dense, jnp.asarray(block_ids, jnp.int32), n_pages,
+        None if slot is None else jnp.int32(slot),
     )
     return last, pools
 
@@ -318,6 +374,7 @@ def _prefill_scatter_sample(
     top_p: Optional[float] = None,
     min_p: Optional[float] = None,
     mesh: Any = None,
+    slots: Optional[jax.Array] = None,  # (N,) int32 state slots (pad rows: the scratch slot)
 ) -> Tuple[jax.Array, transformer.KVCache]:
     """Batched admission in ONE device program: causal prefill over N
     padded prompts -> scatter every row's pages into the pools -> sample
@@ -340,7 +397,7 @@ def _prefill_scatter_sample(
     with activation_mesh(mesh):
         # One forward fills the staging cache; the scatter consumes
         # (L, N, pages*bs, ...) fields: stacked.
-        cache = transformer.make_kv_cache(cfg, n_rows, p_bucket, stacked=True)
+        cache = _staging_cache(cfg, n_rows, p_bucket)
         idx = jnp.clip(prompt_lens - 1, 0, p_bucket - 1).astype(jnp.int32)
         last, cache = _prefill_last_logits(params, prompts, idx, cfg, cache)
         toks = sample_logits(
@@ -349,7 +406,7 @@ def _prefill_scatter_sample(
         ).astype(jnp.int32)
 
         pools = _scatter_staged_pages(
-            pools, cache, block_ids.reshape(-1), n_rows * n_pages
+            pools, cache, block_ids.reshape(-1), n_rows * n_pages, slots
         )
         return toks, pools
 
@@ -367,14 +424,15 @@ def prefill_into_pool_batched(
     top_p: Optional[float] = None,
     min_p: Optional[float] = None,
     mesh: Any = None,
+    slots: Optional[Sequence[int]] = None,
 ) -> Tuple[jax.Array, transformer.KVCache]:
     """Prefill N prompts and write all their pages into the pool in one
     device program; returns (first sampled token per prompt — a DEVICE
     (N,) int32 array, no host sync — and the updated pools).
 
     ``rows_block_ids[i]`` must be exactly ceil(len(prompts[i])/block_size)
-    pages. Rows and pages are bucketed to powers of two so the jit cache
-    stays at O(log(max_batch) * log(max_pages)) program variants.
+    pages. Rows and pages are bucketed (``prefill_bucket``). ``slots[i]`` is
+    prompt i's state slot (state-slot models).
     """
     block_size = pool_block_size(pools, cfg)
     n = len(prompts)
@@ -393,8 +451,7 @@ def prefill_into_pool_batched(
         pages.append(np_i)
     import numpy as np
 
-    bucket_rows = 1 << (n - 1).bit_length()
-    bucket_pages = 1 << (max(pages) - 1).bit_length()
+    bucket_rows, bucket_pages = prefill_bucket(cfg, n, max(pages), block_size)
     p_bucket = bucket_pages * block_size
     prompt_arr = np.zeros((bucket_rows, p_bucket), np.int32)
     lens = np.ones((bucket_rows,), np.int32)
@@ -406,9 +463,24 @@ def prefill_into_pool_batched(
     toks, pools = _prefill_scatter_sample(
         params, pools, jnp.asarray(prompt_arr), jnp.asarray(lens),
         jnp.asarray(ids_arr), key, cfg, p_bucket, bucket_pages,
-        temperature, top_k, top_p, min_p, mesh,
+        temperature, top_k, top_p, min_p, mesh, _slot_array(pools, slots, bucket_rows),
     )
     return toks[:n], pools
+
+
+def _slot_array(pools: transformer.KVCache, slots: Optional[Sequence[int]], rows: int):
+    """(rows,) int32 state slots for a prefill program's rows, the pad rows on
+    the scratch slot; None for a model without state slots."""
+    import numpy as np
+
+    scratch = state_slots(pools)
+    if not scratch:
+        return None
+    if slots is None:
+        raise ValueError("a state-slot model's prefill names each row's slot")
+    out = np.full((rows,), scratch, np.int32)
+    out[: len(slots)] = slots
+    return jnp.asarray(out)
 
 
 @functools.partial(
@@ -433,6 +505,7 @@ def _suffix_prefill_sample(
     top_p: Optional[float] = None,
     min_p: Optional[float] = None,
     mesh: Any = None,
+    slots: Optional[jax.Array] = None,  # (N,) int32 state slots (pad rows: the scratch slot)
 ) -> Tuple[jax.Array, transformer.KVCache]:
     """Prefix-cache hit admission: ONE multi-token paged forward over each
     row's uncached suffix. Token j of row i writes its K/V at slot
@@ -459,7 +532,7 @@ def _suffix_prefill_sample(
         # outputs are bit-identical with or without it.
         logits, pools = transformer.forward(
             params, suffix, cfg, kv_cache=pools,
-            paged=PagedInfo(block_tables, cached_lens, q_lens=suffix_lens),
+            paged=PagedInfo(block_tables, cached_lens, q_lens=suffix_lens, slots=slots),
         )
         idx = jnp.clip(suffix_lens - 1, 0, t_bucket - 1).astype(jnp.int32)
         last = jnp.take_along_axis(
@@ -489,6 +562,7 @@ def prefill_suffix_into_pool_batched(
     min_p: Optional[float] = None,
     mesh: Any = None,
     t_bucket: Optional[int] = None,
+    slots: Optional[Sequence[int]] = None,
 ) -> Tuple[jax.Array, transformer.KVCache]:
     """Prefill ONLY the uncached suffixes of N prefix-cache-hit prompts in
     one device program; returns (first sampled token per row — a DEVICE
@@ -543,7 +617,7 @@ def prefill_suffix_into_pool_batched(
     toks, pools = _suffix_prefill_sample(
         params, pools, jnp.asarray(suf_arr), jnp.asarray(lens),
         jnp.asarray(tab_arr), jnp.asarray(cl_arr), key, cfg, t_bucket,
-        temperature, top_k, top_p, min_p, mesh,
+        temperature, top_k, top_p, min_p, mesh, _slot_array(pools, slots, bucket_rows),
     )
     return toks[:n], pools
 

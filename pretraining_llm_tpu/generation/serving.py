@@ -57,6 +57,10 @@ from pretraining_llm_tpu.observability import spans as _spans
 
 _log = logging.getLogger("pretraining_llm_tpu.serving")
 
+# The most padded prompt tokens one batched admission prefill program takes
+# (ServingEngine._prefill_parts); a boundary that admits more runs several.
+PREFILL_PROGRAM_TOKENS = 32768
+
 # Where a scheduler turn's host time goes; stats["phase_s"] keeps one running
 # total per phase. "other" is the turn's own bookkeeping between the rest.
 PHASES = (
@@ -175,7 +179,22 @@ class ServingEngine:
                 "paged serving does not support capacity-routed MoE models "
                 "(moe_routing='dropless' is served)"
             )
-        if cfg.kv_lora_rank:
+        if cfg.layer_group_size:
+            # State slots beside the pages (models/kda.py): a row's recurrent
+            # state cannot be shared by prefix, shipped, digested or rolled back yet.
+            refused = {
+                "prefix_cache": prefix_cache, "kv_checksum": kv_checksum,
+                "quantize": quantize != "none", "spec_k": bool(spec_k),
+            }
+            if any(refused.values()):
+                raise ValueError(
+                    "a state-slot model (linear-attention layers) is served without "
+                    + ", ".join(k for k, v in refused.items() if v)
+                    + ": the prefix cache (and kv_transfer, which publishes into it) "
+                    "and kv_checksum know pages only, int8 pages are not built on "
+                    "its pools, and speculative decoding needs a state rollback"
+                )
+        elif cfg.kv_lora_rank:
             # A latent (MLA) page pool: what is not built on it yet (ROADMAP).
             refused = {
                 "quantize": quantize != "none", "prefix_cache": prefix_cache,
@@ -382,6 +401,8 @@ class ServingEngine:
                 # set directly, quantize='none') keep fp32 scales for
                 # bit-compatibility with the dense int8 cache.
                 scale_dtype="bfloat16" if self.quantize == "int8-kv" else None,
+                # a hybrid stack: one state slot a batch row beside the pages
+                state_slots=self.max_batch if pool_cfg.layer_group_size else 0,
             )
             if mesh is None:
                 return pools
@@ -425,6 +446,9 @@ class ServingEngine:
                 pools,
             )
 
+        # A hybrid stack keeps a recurrent state a row beside its pages: row b
+        # owns slot b of the state pools for as long as it owns the row.
+        self.state_slots = bool(cfg.layer_group_size)
         self.pools = _build_pool(cfg)
         # Draft pools mirror the block structure exactly: SAME table/ids,
         # draft-model dims per block (paged_spec_round's shared-frontier
@@ -560,10 +584,15 @@ class ServingEngine:
         pages included), host-side shape math only (no device sync).
         Draft pools (speculative serving) are reported separately."""
         pools = self.pools
-        layer0 = pools["layers"][0]
+        # the first layer that has pages; a hybrid stack's KDA layers keep state slots
+        layer0 = next(f for f in pools["layers"] if "state_pool" not in f)
+        state = int(sum(
+            leaf.nbytes for f in pools["layers"] if "state_pool" in f
+            for leaf in jax.tree.leaves(f)
+        ))
         total = int(
-            sum(leaf.nbytes for leaf in jax.tree.leaves(pools))
-        )
+            sum(leaf.nbytes for leaf in jax.tree.leaves(pools["layers"]))
+        ) - state
         info = {
             "quantize": self.quantize,
             "kv_dtype": str(next(iter(layer0.values())).dtype),
@@ -581,6 +610,12 @@ class ServingEngine:
             # "gather" | "kernel" | "ragged" (per head), "gather" | "latent_kernel" (latent)
             "decode_attention": self.decode_attention,
         }
+        if self.state_slots:
+            # the other kind of cache: a fixed-size state a row, whatever its length
+            info.update(
+                state_slots=self.max_batch, state_bytes=state,
+                bytes_per_slot=state // (self.max_batch + 1),
+            )
         if self.d_pools is not None:
             info["draft_pool_bytes"] = int(
                 sum(leaf.nbytes for leaf in jax.tree.leaves(self.d_pools))
@@ -839,6 +874,17 @@ class ServingEngine:
         self.stats["attn_pages_live"] += int(np.sum(last // bs - first + 1))
         self.stats["attn_pages_tabled"] += n * self.max_batch * self.max_blocks
 
+    def _decode_tables(self) -> np.ndarray:
+        """The block tables a decode window goes out with. A state-slot model's
+        decode step updates the slot of every row whose table names a page, so
+        a row that rides no window (mid-prefill: its chunks are building its
+        state) goes out with an empty table, like a free row."""
+        if not (self.state_slots and self.prefill_chunk_tokens):
+            return self.tables  # no row is ever mid-prefill without the chunk lane
+        tables = self.tables.copy()
+        tables[[i for i, r in enumerate(self.rows) if r is not None and r.prefill_pos is not None]] = 0
+        return tables
+
     def _step_decode(self) -> bool:
         """The synchronous decode arm of step(); True when a decode
         window (or spec round) actually ran."""
@@ -857,7 +903,7 @@ class ServingEngine:
         self._count_attention_pages(n)
         self._key, sub = jax.random.split(self._key)
         toks, lp, moe = self._decode_window(
-            jnp.asarray(self.tokens), jnp.asarray(self.tables),
+            jnp.asarray(self.tokens), jnp.asarray(self._decode_tables()),
             jnp.asarray(self.seq_lens), sub, n, raw_key_single=True,
         )
         window = np.asarray(toks)  # (B, n)
@@ -953,8 +999,10 @@ class ServingEngine:
         summed over steps, and ``moe_steps``. Idle rows route too (their
         tokens are discarded, their experts are read all the same). Returns
         the window's own totals, the ``serving.commit`` span's metadata:
-        steps, expert layers, experts a layer, experts touched, pairs routed,
-        and the busiest expert's pairs summed over layers."""
+        steps, expert layers, experts a layer (those held), experts touched,
+        pairs routed (every row's choices, wherever the expert lives), pairs
+        that met an expert held here, and the busiest expert's pairs summed
+        over layers."""
         tokens = np.asarray(moe["expert_tokens"], np.int64)
         touched = np.asarray(moe["experts_touched"], np.int64)
         st = self.stats
@@ -965,9 +1013,10 @@ class ServingEngine:
         st["moe_expert_tokens"] += tokens
         st["moe_experts_touched"] += touched
         st["moe_steps"] += n_steps
+        routed = n_steps * self.max_batch * self.cfg.experts_per_token * tokens.shape[0]
         return dict(
             moe_steps=n_steps, moe_layers=tokens.shape[0], moe_experts=tokens.shape[1],
-            moe_touched=int(touched.sum()), moe_routed=int(tokens.sum()),
+            moe_touched=int(touched.sum()), moe_routed=routed, moe_routed_here=int(tokens.sum()),
             moe_busiest=int(tokens.max(axis=-1).sum()),
         )
 
@@ -1173,7 +1222,7 @@ class ServingEngine:
             base = self._merge_admitted(base)
             self._key, sub = jax.random.split(self._key)
             toks, lp, moe = self._decode_window(
-                base, jnp.asarray(self.tables), jnp.asarray(seq_dispatch),
+                base, jnp.asarray(self._decode_tables()), jnp.asarray(seq_dispatch),
                 sub, n,
             )
         self.stats["steps"] += n
@@ -1288,6 +1337,9 @@ class ServingEngine:
                 self.host_blocked_hist.observe(blocked)
             capacity = self.max_blocks * self.block_size
             toks_before = self.stats["tokens"]
+            if self.state_slots:
+                # slots owned as the window commits: a row's slot is its row
+                moe_meta["state_slots"] = self.n_active
             with self._clock.span(
                 "commit", "serving.commit", rows=len(w.snapshot), **moe_meta
             ):
@@ -1570,6 +1622,28 @@ class ServingEngine:
             return None
         return self._cache_alloc(n)
 
+    def _prefill_parts(self, reqs: List[_Request]) -> List[List[_Request]]:
+        """One boundary's admissions as the batched prefill programs they run
+        in: in order, as many a program as keep its padded size (rows bucketed
+        to a power of two x the longest prompt's page bucket) within
+        ``PREFILL_PROGRAM_TOKENS``. A prefill's activations grow with that
+        product (an expert layer sorts ``experts_per_token`` copies of every
+        token), and eight 5,184-token rows of a 128-row engine do not fit a
+        v5e beside 10.7 GB of weights and caches where two programs of four do."""
+        parts: List[List[_Request]] = []
+        for req in reqs:
+            trial = (parts[-1] if parts else []) + [req]
+            rows, pages = paged.prefill_bucket(
+                self.cfg, len(trial),
+                max(paged.required_blocks(len(r.prompt), self.block_size) for r in trial),
+                self.block_size,
+            )
+            if parts and rows * pages * self.block_size <= PREFILL_PROGRAM_TOKENS:
+                parts[-1].append(req)
+            else:
+                parts.append([req])
+        return parts
+
     def _admission_capacity(self) -> int:
         """How many queue heads could be admitted RIGHT NOW under the
         free-row + watermark rules, without committing anything — the
@@ -1724,6 +1798,10 @@ class ServingEngine:
                             )
                             tr.marks["admit"] = now_p
                 self.rows[row] = req  # claim now: n_active sees earlier admits
+                if self.state_slots:
+                    self.stats["state_slots_peak"] = max(
+                        self.stats.get("state_slots_peak", 0), self.n_active
+                    )
                 self.tables[row, :] = 0
                 self.tables[row, : len(req.blocks)] = req.blocks
                 if self.prefill_chunk_tokens:
@@ -1765,17 +1843,19 @@ class ServingEngine:
                 "prefill_dispatch", "serving.prefill_dispatch",
                 miss=len(miss), hits=len(hits),
             ):
-                if miss:
+                for part in self._prefill_parts(miss):
                     self._key, sub = jax.random.split(self._key)
-                    prompts = [r.prompt for r in miss]
+                    prompts = [r.prompt for r in part]
                     prefill_ids = [
                         r.blocks[: paged.required_blocks(len(r.prompt), self.block_size)]
-                        for r in miss
+                        for r in part
                     ]
                     toks_dev, self.pools = paged.prefill_into_pool_batched(
                         self.params, self.cfg, self.pools, prompts, prefill_ids,
                         sub, temperature=self.temperature, top_k=self.top_k,
                         top_p=self.top_p, min_p=self.min_p, mesh=self.mesh,
+                        # the state is written with the pages, into the row's own slot
+                        slots=[r.row for r in part] if self.state_slots else None,
                     )
                     if self.spec_k:
                         # The draft cache must cover the same pages (its sampled
@@ -1786,7 +1866,7 @@ class ServingEngine:
                             prefill_ids, sub, temperature=self.temperature,
                             mesh=self.mesh,
                         )
-                    groups.append((miss, toks_dev))
+                    groups.append((part, toks_dev))
                 if hits:
                     self._key, sub = jax.random.split(self._key)
                     bs = self.block_size
@@ -1912,6 +1992,8 @@ class ServingEngine:
                 offsets, sub, temperature=self.temperature,
                 top_k=self.top_k, top_p=self.top_p, min_p=self.min_p,
                 mesh=self.mesh, t_bucket=self.prefill_chunk_tokens,
+                # each chunk starts from the state its row's slot holds
+                slots=[r.row for r in group] if self.state_slots else None,
             )
             if self.spec_k:
                 # The draft pool must hold the same chunk K/V (shared

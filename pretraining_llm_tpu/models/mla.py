@@ -71,6 +71,8 @@ def init_attn_params(cfg: ModelConfig, key: jax.Array, resid_std: float, dtype: 
         attn["wq_b"] = normal(ks[1], (r, h, cfg.head_dim))
     else:
         attn["wq"] = normal(ks[0], (d, h, cfg.head_dim))
+    if cfg.attn_output_gate:
+        attn["wgate"] = normal(jax.random.fold_in(key, 5), (d, h))
     return attn
 
 
@@ -319,6 +321,12 @@ def attention_block(
 
     out = checkpoint_name(out, "attn_out")
     with jax.named_scope("attn.out"):
+        if "wgate" in attn:
+            # one sigmoid gate a head, from the sublayer's input
+            gate = jnp.einsum(
+                "btd,dh->bth", h.astype(cdt), _w(attn, "wgate", cdt), preferred_element_type=jnp.float32
+            )
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate)[..., None]).astype(cdt)
         out = jnp.einsum(
             "bthn,hnd->btd", out, _w(attn, "wo", cdt), preferred_element_type=jnp.float32
         ).astype(cdt)

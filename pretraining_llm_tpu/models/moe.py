@@ -215,7 +215,7 @@ def init_dropless_params(
     """Router (D, E) [+ selection bias (E,)], experts stored as the grouped
     matmul reads them — w1 (E, D, 2F) with the gate columns before the up
     columns, w2 (E, F, D) — and the shared expert as a dense SwiGLU."""
-    d, f, e = cfg.d_model, cfg.expert_width, cfg.n_experts
+    d, f, e, held = cfg.d_model, cfg.expert_width, cfg.n_experts, cfg.experts_held
     ks = jax.random.split(key, 5)
 
     def normal(k: jax.Array, shape: Tuple[int, ...], s: float = 0.02) -> jax.Array:
@@ -223,7 +223,9 @@ def init_dropless_params(
 
     out: Params = {
         "router": normal(ks[0], (d, e)),
-        "experts": {"w1": normal(ks[1], (e, d, 2 * f)), "w2": normal(ks[2], (e, f, d), resid_std)},
+        "experts": {
+            "w1": normal(ks[1], (held, d, 2 * f)), "w2": normal(ks[2], (held, f, d), resid_std)
+        },
     }
     if cfg.moe_score_bias:
         out["router_bias"] = jnp.zeros((e,), dtype)
@@ -231,6 +233,15 @@ def init_dropless_params(
         fs = cfg.n_shared_experts * f
         out["shared"] = {"w1": normal(ks[3], (d, 2, fs)), "w2": normal(ks[4], (fs, d), resid_std)}
     return out
+
+
+def swiglu(gate: jax.Array, up: jax.Array, limit: Any = None) -> jax.Array:
+    """silu(gate) * up; under a clamp ``limit`` (a scalar, 0 = off)
+    silu(min(gate, L)) * clip(up, -L, L)."""
+    if limit is not None:
+        lim = jnp.where(limit > 0, limit, jnp.inf).astype(gate.dtype)
+        gate, up = jnp.minimum(gate, lim), jnp.clip(up, -lim, lim)
+    return jax.nn.silu(gate) * up
 
 
 def route_dropless(mlp: Params, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
@@ -243,6 +254,14 @@ def route_dropless(mlp: Params, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Arr
     )
     scores = jax.nn.sigmoid(logits) if cfg.moe_score == "sigmoid" else jax.nn.softmax(logits, -1)
     select = scores + mlp["router_bias"].astype(jnp.float32) if "router_bias" in mlp else scores
+    if cfg.moe_n_group > 1:
+        # group-limited: a group scores the sum of its two best, the experts of
+        # the groups that lose leave the selection
+        grouped = select.reshape(select.shape[0], cfg.moe_n_group, -1)
+        best2, _ = jax.lax.top_k(grouped, 2)
+        _, keep = jax.lax.top_k(jnp.sum(best2, axis=-1), cfg.moe_topk_group)
+        kept = jnp.zeros(grouped.shape[:2], bool).at[jnp.arange(keep.shape[0])[:, None], keep].set(True)
+        select = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(select.shape)
     _, idx = jax.lax.top_k(select, cfg.experts_per_token)
     gates = jnp.take_along_axis(scores, idx, axis=-1)
     if cfg.moe_norm_topk:
@@ -274,7 +293,8 @@ def moe_mlp_dropless(
     then runs over all L * E groups with every other layer's group empty. A
     per-layer slice of the stack would be copied for the kernel each call
     (0.94 + 0.47 GB a layer at 64 experts of 3584 x 1024); an empty group costs
-    nothing and the stack is read where it lies.
+    nothing and the stack is read where it lies. ``mlp["expert_limit"]``, if
+    there, is the layer's SwiGLU clamp (``swiglu``).
     """
     cdt = jnp.dtype(cfg.compute_dtype)
     b, t, d = h.shape
@@ -300,11 +320,17 @@ def moe_mlp_dropless(
             w1, w2 = w1.reshape((n_stack * held,) + w1.shape[2:]), w2.reshape((n_stack * held,) + w2.shape[2:])
     with jax.named_scope("moe.experts"):
         up = jax.lax.ragged_dot(xs, w1, sizes, preferred_element_type=cdt)
-        hidden = jax.nn.silu(up[:, :f]) * up[:, f:]
+        hidden = swiglu(up[:, :f], up[:, f:], mlp.get("expert_limit"))
         ys = jax.lax.ragged_dot(hidden, w2, sizes, preferred_element_type=cdt)
     with jax.named_scope("moe.combine"):
-        g_sorted = jnp.where(flat[order] < held, gates.reshape(s * k)[order], 0.0)
-        ys = (ys.astype(jnp.float32) * g_sorted[:, None]).astype(cdt)
+        here = flat[order] < held
+        g_sorted = jnp.where(here, gates.reshape(s * k)[order], 0.0)
+        ys = ys.astype(jnp.float32) * g_sorted[:, None]
+        if held < cfg.n_experts:
+            # rows past the last group belong to no expert held: the grouped
+            # matmul promises nothing for them, so select, do not multiply by 0
+            ys = jnp.where(here[:, None], ys, 0.0)
+        ys = ys.astype(cdt)
         y = jnp.sum(
             ys[jnp.argsort(order)].reshape(s, k, d).astype(jnp.float32), axis=1
         ).astype(cdt).reshape(b, t, d)

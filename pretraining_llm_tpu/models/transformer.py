@@ -36,7 +36,7 @@ from jax.sharding import PartitionSpec as P
 
 
 from pretraining_llm_tpu.config import ModelConfig
-from pretraining_llm_tpu.models import hyper, layers, mla, moe
+from pretraining_llm_tpu.models import hyper, kda, layers, mla, moe
 from pretraining_llm_tpu.ops import remat
 from pretraining_llm_tpu.ops.attention import multihead_attention
 from pretraining_llm_tpu.parallel.sharding import constrain, current_mesh
@@ -81,6 +81,11 @@ class PagedInfo(NamedTuple):
     # computes pad queries and lets the caller discard them, so outputs
     # for REAL queries are bit-identical whether or not q_lens is passed.
     q_lens: Optional[jax.Array] = None  # (B,) int32 or None
+    # State-slot models (models/kda.py): the slot of the state pools each row
+    # reads and writes. None = row b's own slot b, left alone while the row's
+    # table names no page (the decode step); a prefill program's rows are not
+    # the engine's, so it passes them (pad rows: the scratch slot, the last).
+    slots: Optional[jax.Array] = None  # (B,) int32 or None
 
 
 def paged_attention_form(
@@ -158,9 +163,11 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 
     g = cfg.kv_heads
 
-    def init_block(k: jax.Array, dense_ffn: bool = False) -> Params:
+    def init_block(k: jax.Array, dense_ffn: bool = False, mixer: str = "attn") -> Params:
         ks = jax.random.split(k, 5)
-        if cfg.kv_lora_rank:
+        if mixer == "kda":
+            attn: Params = kda.init_params(cfg, ks[0], resid_std, dtype)
+        elif cfg.kv_lora_rank:
             attn: Params = mla.init_attn_params(cfg, ks[0], resid_std, dtype)
         elif g == h:
             attn: Params = {"wqkv": normal(ks[0], (d, 3, h, dh))}
@@ -175,7 +182,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             if cfg.qkv_bias:
                 attn["bq"] = jnp.zeros((h, dh), dtype)
                 attn["bkv"] = jnp.zeros((2, g, dh), dtype)
-        if cfg.use_output_proj and not cfg.kv_lora_rank:
+        if cfg.use_output_proj and not cfg.kv_lora_rank and mixer != "kda":
             attn["wo"] = normal(ks[1], (h, dh, d), resid_std)
             attn["bo"] = jnp.zeros((d,), dtype)
         if cfg.moe_dropless and not dense_ffn:
@@ -206,18 +213,26 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 
     # vmap over per-layer keys -> every block param gets a leading (n_layers,) dim
     layer_keys = jax.random.split(k_blocks, nl)
-    blocks = jax.vmap(init_block)(layer_keys[cfg.n_dense_layers:])
-
     params: Params = {
         "tok_embed": {"embedding": normal(k_tok, (v, d))},
-        "blocks": blocks,
         "final_norm": layers.init_norm(cfg.norm, d, dtype),
     }
-    if cfg.n_dense_layers:
-        # the leading dense layers, a group of their own ahead of "blocks"
-        params["dense_blocks"] = jax.vmap(lambda k: init_block(k, dense_ffn=True))(
-            layer_keys[: cfg.n_dense_layers]
-        )
+    if cfg.layer_group_size:
+        # a hybrid stack: one stack a kind of layer (stack_key), whatever the
+        # order the kinds come in (layer_groups)
+        kinds = cfg.layer_kinds
+        for kind in sorted(set(kinds)):
+            of_kind = jnp.asarray([i for i, k in enumerate(kinds) if k == kind])
+            params[stack_key(cfg, *kind)] = jax.vmap(lambda k, _kind=kind: init_block(
+                k, dense_ffn=_kind[1] == "dense", mixer=_kind[0]
+            ))(layer_keys[of_kind])
+    else:
+        params["blocks"] = jax.vmap(init_block)(layer_keys[cfg.n_dense_layers:])
+        if cfg.n_dense_layers:
+            # the leading dense layers, a group of their own ahead of "blocks"
+            params["dense_blocks"] = jax.vmap(lambda k: init_block(k, dense_ffn=True))(
+                layer_keys[: cfg.n_dense_layers]
+            )
     if cfg.pos_embed == "learned":
         params["pos_embed"] = {"embedding": normal(k_pos, (t, d))}
     if not cfg.tie_embeddings:
@@ -225,6 +240,35 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         if cfg.lm_head_bias:
             params["lm_head"]["bias"] = jnp.zeros((v,), dtype)
     return params
+
+
+def stack_key(cfg: ModelConfig, mixer: str, ffn: str) -> str:
+    """Where the layers of one kind are stacked in the parameter tree:
+    "blocks", or "dense_blocks" for an expert model's leading dense layers; a
+    hybrid stack's attention layers (its linear-attention layers are the many)
+    in "attn_blocks" and "attn_dense_blocks"."""
+    key = "dense_blocks" if ffn == "dense" and cfg.n_experts else "blocks"
+    return "attn_" + key if cfg.layer_group_size and mixer == "attn" else key
+
+
+def layer_groups(params: Params, cfg: ModelConfig):
+    """The stack as runs of like layers, each one scan of ``forward``: [(the
+    layers it holds, the stacked blocks of their kind, the run's first layer's
+    place in that stack)]. A homogeneous model is one run of all of "blocks",
+    an expert model with leading dense layers two; a hybrid stack alternates
+    between its kinds' stacks (``cfg.layer_runs``)."""
+    if not cfg.layer_group_size:
+        k = cfg.n_dense_layers if "dense_blocks" in params else 0
+        groups = [(range(k, cfg.n_layers), params["blocks"], 0)]
+        if k:
+            groups.insert(0, (range(k), params["dense_blocks"], 0))
+        return groups
+    kinds, seen, groups = cfg.layer_kinds, {}, []
+    for a, b in cfg.layer_runs:
+        key = stack_key(cfg, *kinds[a])
+        groups.append((range(a, b), params[key], seen.get(key, 0)))
+        seen[key] = seen.get(key, 0) + b - a
+    return groups
 
 
 # ---------------------------------------------------------------------------
@@ -675,8 +719,9 @@ def _attention_block(
         return (x + out.astype(x.dtype) if residual else out.astype(x.dtype)), new_kv
 
 
-def _dense_mlp(mlp: Params, h: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """The dense FFN on normed input: w2 . act(w1 . h), in compute dtype."""
+def _dense_mlp(mlp: Params, h: jax.Array, cfg: ModelConfig, limit: Any = None) -> jax.Array:
+    """The dense FFN on normed input: w2 . act(w1 . h), in compute dtype.
+    ``limit`` clamps a SwiGLU (``moe.swiglu``)."""
     cdt = jnp.dtype(cfg.compute_dtype)
     if cfg.activation == "swiglu":
         gates = jnp.einsum(
@@ -684,7 +729,7 @@ def _dense_mlp(mlp: Params, h: jax.Array, cfg: ModelConfig) -> jax.Array:
         ).astype(cdt)
         if "b1" in mlp:
             gates = gates + mlp["b1"].astype(cdt)[None, :, None, :]
-        hidden = jax.nn.silu(gates[:, 0]) * gates[:, 1]
+        hidden = moe.swiglu(gates[:, 0], gates[:, 1], limit)
     else:
         hidden = jnp.einsum(
             "btd,df->btf", h, _weight(mlp, "w1", cdt), preferred_element_type=jnp.float32
@@ -714,7 +759,8 @@ def _mlp_block(
     with jax.named_scope("mlp"):
         if "router" in mlp and cfg.moe_dropless:
             out, aux = moe.moe_mlp_dropless(
-                mlp, h, cfg, lambda shared, hh: _dense_mlp(shared, hh, cfg)
+                mlp, h, cfg,
+                lambda shared, hh: _dense_mlp(shared, hh, cfg, mlp.get("shared_limit")),
             )
         elif "router" in mlp:
             out, aux = moe.moe_mlp(mlp, h, cfg, decode=decode)
@@ -735,7 +781,14 @@ def _block(
     pad_offsets: Optional[jax.Array] = None,
     segments: Optional[jax.Array] = None,
     paged: Optional[PagedInfo] = None,
+    lengths: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
+    if "wf" in blk["attn"]:  # a KDA mixer's decay projection
+        if zigzag or segments is not None:
+            raise ValueError("a KDA layer has no ring layout and no document mask")
+        x, new_kv = kda.mixer_block(blk, x, cfg, kv, pad_offsets, paged, lengths)
+        x, aux = _mlp_block(blk, x, cfg, decode=kv is not None and x.shape[1] == 1)
+        return x, new_kv, aux
     if cfg.hc_mult > 1:
         # x is the token's (B, T, n, d) residual streams: each sublayer reads
         # one vector from them and writes its output back (models/hyper.py).
@@ -789,6 +842,7 @@ def forward(
     pad_offsets: Optional[jax.Array] = None,
     paged: Optional[PagedInfo] = None,
     return_moe_counts: bool = False,
+    lengths: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[KVCache]]:
     """Compute logits. tokens: (B, T) int32 -> logits (B, T, V) fp32.
 
@@ -839,6 +893,11 @@ def forward(
     i's token at cache slot s has logical position s - pad_offsets[i]
     (RoPE / learned positions use logical; causality + cache writes use
     slots; the kv mask hides each row's pad slots).
+
+    ``lengths`` (B,) int32: each row's true token count in a right-padded
+    multi-token call (a bucketed prefill). Attention needs none (causality
+    keeps the padding out of every real position); a recurrent layer
+    (models/kda.py) does, to leave its state as of the last real token.
     """
     cdt = jnp.dtype(cfg.compute_dtype)
     b, t = tokens.shape
@@ -922,12 +981,25 @@ def forward(
     if cfg.hc_mult > 1:
         x = hyper.copy_in(x, cfg)
 
-    def in_stack(blk, experts, layer):
+    def in_stack(blk, experts, layer, limits=None):
         """``blk`` with its group's expert stack and its own place in it (see
-        moe.moe_mlp_dropless): the stack is closed over, never sliced."""
+        moe.moe_mlp_dropless): the stack is closed over, never sliced.
+        ``limits``: the layer's two SwiGLU clamps (routed, shared), if its
+        group has any."""
         if experts is None:
             return blk
-        return {**blk, "mlp": {**blk["mlp"], "experts": experts, "expert_layer": layer}}
+        mlp = {**blk["mlp"], "experts": experts, "expert_layer": layer}
+        if limits is not None:
+            mlp.update(expert_limit=limits[0], shared_limit=limits[1])
+        return {**blk, "mlp": mlp}
+
+    def clamps_of(layers_of):
+        """(n, 2) float32 clamps of a group's layers, or None if all are off."""
+        both = [
+            [lim[i] if lim else 0.0 for i in layers_of]
+            for lim in (cfg.moe_swiglu_limits, cfg.moe_shared_swiglu_limits)
+        ]
+        return jnp.asarray(both, jnp.float32).T if any(map(any, both)) else None
 
     def scan_body(carry, layer_inputs):
         x, aux_sum = carry
@@ -935,7 +1007,7 @@ def forward(
             blk = layer_inputs
             x, _, aux = _block(
                 blk, x, cfg, rope, positions, None, None, zigzag,
-                segments=segments,
+                segments=segments, lengths=lengths,
             )
             if aux.ndim:  # a dropless layer's tokens per expert ride the outputs
                 return (x, aux_sum), ((x if return_hidden else None), aux)
@@ -943,7 +1015,7 @@ def forward(
         blk, cache_layer = layer_inputs
         x, new_kv, aux = _block(
             blk, x, cfg, rope, positions, cache_layer, cache_index,
-            pad_offsets=pad_offsets, paged=paged,
+            pad_offsets=pad_offsets, paged=paged, lengths=lengths,
         )
         if aux.ndim:
             return (x, aux_sum), (new_kv, aux)
@@ -966,36 +1038,39 @@ def forward(
         and mesh.shape.get("pipe", 1) > 1
     )
 
-    # The stack as groups of like layers, each one scan: the leading dense
-    # layers of an expert model ("dense_blocks"), then "blocks". A homogeneous
-    # model is one group and traces exactly as it did before groups existed.
-    # Each group: (the layers it holds, its stacked blocks).
-    k = cfg.n_dense_layers if "dense_blocks" in params else 0
-    groups = [(range(k, cfg.n_layers), params["blocks"])]
-    if k:
-        groups.insert(0, (range(k), params["dense_blocks"]))
-    moe_counts = None
+    # The stack as groups of like layers, each one scan (layer_groups). A
+    # homogeneous model is one group and traces exactly as it did before
+    # groups existed. Each group: (the layers it holds, its stacked blocks).
+    groups = layer_groups(params, cfg)
+    counts_of: list = []  # each expert group's tokens per expert, (its layers, E)
 
-    def scan_group(blocks, x, aux, cache=None):
-        """One group's depth scan -> (x, aux, per-layer outputs)."""
-        nonlocal moe_counts
-        blocks, experts = without_experts(blocks)
+    def scan_group(layers_of, stack, first, x, aux, cache=None):
+        """One group's depth scan -> (x, aux, per-layer outputs). ``stack``
+        holds every layer of the group's kind, the group's from ``first`` on."""
+        blocks, experts = without_experts(stack)
+        n = len(layers_of)
+        if n != jax.tree.leaves(blocks)[0].shape[0]:
+            blocks = jax.tree.map(lambda a: a[first : first + n], blocks)
         xs = blocks if cache is None else (blocks, cache)
         step = body
         if experts is not None:
+            clamps = clamps_of(layers_of)
+
             # the expert stack is closed over; each layer gets its index in it
             def with_experts(carry, inputs):
                 inputs, layer = inputs
+                layer, limits = layer if clamps is not None else (layer, None)
                 if cache is None:
-                    return scan_body(carry, in_stack(inputs, experts, layer))
-                return scan_body(carry, (in_stack(inputs[0], experts, layer), inputs[1]))
+                    return scan_body(carry, in_stack(inputs, experts, layer, limits))
+                return scan_body(carry, (in_stack(inputs[0], experts, layer, limits), inputs[1]))
 
-            n = jax.tree.leaves(blocks)[0].shape[0]
-            xs = (xs, jnp.arange(n, dtype=jnp.int32))
+            idx = jnp.arange(first, first + n, dtype=jnp.int32)
+            xs = (xs, idx if clamps is None else (idx, clamps))
             step = remat.checkpoint_wrap(with_experts, cfg.remat)
         (x, aux), out = jax.lax.scan(step, (x, aux), xs)
         if cfg.moe_dropless and "router" in blocks["mlp"]:
-            out, moe_counts = out  # an expert group yields (outputs, tokens per expert)
+            out, counts = out  # an expert group yields (outputs, tokens per expert)
+            counts_of.append(counts)
         return x, aux, out
 
     def concat_groups(outs):
@@ -1031,8 +1106,8 @@ def forward(
         new_cache = None
     elif kv_cache is None:
         aux_total, outs = aux0, []
-        for _, blocks in groups:
-            x, aux_total, out = scan_group(blocks, x, aux_total)
+        for layers_of, stack, first in groups:
+            x, aux_total, out = scan_group(layers_of, stack, first, x, aux_total)
             outs.append(out)
         block_outputs = concat_groups(outs) if return_hidden else None
         new_cache = None
@@ -1050,33 +1125,35 @@ def forward(
             # (speculative verify rounds) repeat every few tokens and keep
             # the in-place loop below.
             aux_total, new_layers = aux0, []
-            for layers_of, blocks in groups:
+            for layers_of, stack, first in groups:
                 lyrs = [kv_cache["layers"][i] for i in layers_of]
                 stacked_cache = {
                     name: jnp.stack([lyr[name] for lyr in lyrs]) for name in lyrs[0]
                 }
-                x, aux_total, new_stacked = scan_group(blocks, x, aux_total, stacked_cache)
+                x, aux_total, new_stacked = scan_group(
+                    layers_of, stack, first, x, aux_total, stacked_cache)
                 new_layers += [
                     {name: buf[i] for name, buf in new_stacked.items()}
                     for i in range(len(lyrs))
                 ]
-            new_cache = {"layers": tuple(new_layers)}
+            new_cache = {**kv_cache, "layers": tuple(new_layers)}
         else:
             aux_total = aux0
             new_layers, counts = [], []
-            for layers_of, blocks in groups:
-                blocks, experts = without_experts(blocks)
+            for layers_of, stack, first in groups:
+                blocks, experts = without_experts(stack)
+                clamps = clamps_of(layers_of) if experts is not None else None
                 for i, layer in enumerate(layers_of):
                     blk = jax.tree.map(
-                        lambda a, _l=i: jax.lax.index_in_dim(
+                        lambda a, _l=first + i: jax.lax.index_in_dim(
                             a, _l, 0, keepdims=False
                         ),
                         blocks,
                     )
-                    blk = in_stack(blk, experts, i)
+                    blk = in_stack(blk, experts, first + i, None if clamps is None else clamps[i])
                     x, new_kv, aux = _block(
                         blk, x, cfg, rope, positions, kv_cache["layers"][layer],
-                        cache_index, pad_offsets=pad_offsets, paged=paged,
+                        cache_index, pad_offsets=pad_offsets, paged=paged, lengths=lengths,
                     )
                     if aux.ndim:
                         counts.append(aux)
@@ -1084,18 +1161,18 @@ def forward(
                         aux_total = aux_total + aux
                     new_layers.append(new_kv)
             if counts:
-                moe_counts = jnp.stack(counts)
-            new_cache = {"layers": tuple(new_layers)}
+                counts_of.append(jnp.stack(counts))
+            new_cache = {**kv_cache, "layers": tuple(new_layers)}
     else:
         # Stacked dense cache (make_kv_cache(..., stacked=True)): the layers
         # ride the depth scan. For a caller that makes the cache, runs one
         # forward and hands the result on (prefill staging).
         aux_total, outs = aux0, []
-        for layers_of, blocks in groups:
+        for layers_of, stack, first in groups:
             cache = kv_cache if len(groups) == 1 else jax.tree.map(
                 lambda a: a[layers_of.start : layers_of.stop], kv_cache
             )
-            x, aux_total, out = scan_group(blocks, x, aux_total, cache)
+            x, aux_total, out = scan_group(layers_of, stack, first, x, aux_total, cache)
             outs.append(out)
         new_cache = concat_groups(outs)
 
@@ -1115,7 +1192,7 @@ def forward(
     if return_aux:
         extras += (aux_total,)
     if return_moe_counts:
-        extras += (moe_counts,)
+        extras += (jnp.concatenate(counts_of) if len(counts_of) > 1 else (counts_of or [None])[0],)
     if extras:
         return (logits, new_cache) + extras
     return logits, new_cache
@@ -1506,23 +1583,28 @@ def loss_fn(
 def _is_pool_cache(kv_cache: Optional[KVCache]) -> bool:
     """True for a page pool (make_paged_kv_pool), false for a dense cache."""
     layers_ = (kv_cache or {}).get("layers")
-    return bool(layers_) and ("k_pool" in layers_[0] or "latent_pool" in layers_[0])
+    return bool(layers_) and any(
+        name in layers_[0] for name in ("k_pool", "latent_pool", "state_pool")
+    )
 
 
-def _unstack_fields(n_layers: int, fields: Dict[str, Tuple[Tuple[int, ...], Any]]) -> KVCache:
+def _unstack_fields(
+    cfg: ModelConfig, fields: Dict[str, Tuple[Tuple[int, ...], Any]],
+    kda_fields: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]] = None,
+) -> KVCache:
     """{'layers': per-layer dicts of fresh zero arrays} from {name:
     (stacked_shape, dtype)} specs — allocated per layer DIRECTLY (never
     materializing the stacked array first: pools are sized toward HBM
     capacity, and a transient 2x would OOM engines that otherwise fit).
     Each layer gets its own buffers (sharing one zeros across carry
-    leaves would alias donated updates)."""
+    leaves would alias donated updates). A KDA layer of a hybrid stack keeps
+    ``kda_fields`` ({name: (shape, dtype)}, no layer dimension) instead."""
     return {
         "layers": tuple(
-            {
-                name: jnp.zeros(shape[1:], dt)
-                for name, (shape, dt) in fields.items()
-            }
-            for _ in range(n_layers)
+            {name: jnp.zeros(shape, dt) for name, (shape, dt) in kda_fields.items()}
+            if mixer == "kda" else
+            {name: jnp.zeros(shape[1:], dt) for name, (shape, dt) in fields.items()}
+            for mixer, _ in cfg.layer_kinds
         )
     }
 
@@ -1575,13 +1657,17 @@ def make_kv_cache(
         dtype = jnp.dtype(dtype or cfg.compute_dtype)
         fields = {"k": (shape, dtype), "v": (shape, dtype)}
     if stacked:
+        if cfg.layer_group_size:
+            raise ValueError("a hybrid stack's layers keep unlike caches: no stacked form")
         return {name: jnp.zeros(s, dt) for name, (s, dt) in fields.items()}
-    return _unstack_fields(cfg.n_layers, fields)
+    return _unstack_fields(
+        cfg, fields, kda.state_shapes(cfg, batch_size) if cfg.layer_group_size else None
+    )
 
 
 def make_paged_kv_pool(
     cfg: ModelConfig, n_blocks: int, block_size: int, dtype: Any = None,
-    *, scale_dtype: Any = None,
+    *, scale_dtype: Any = None, state_slots: int = 0,
 ) -> KVCache:
     """Block POOL layout for paged serving decode (see PagedInfo).
 
@@ -1596,6 +1682,12 @@ def make_paged_kv_pool(
     Block 0 is reserved by convention as the idle-row scratch target (the
     serving engine parks inactive batch rows on it); allocators hand out
     ids from 1.
+
+    A hybrid stack (``cfg.layer_group_size``) gives pages to its attention
+    layers only; each KDA layer keeps {'state_pool': (state_slots + 1, H, K, V)
+    float32, 'conv_pool': (state_slots + 1, kernel - 1, 3 H K)}: a slot a batch
+    row (the engine's ``max_batch``), a row's slot its index, and the last slot
+    the scratch that a prefill's pad rows write (as block 0 is for pages).
 
     ``scale_dtype`` (int8 pools only) picks the per-(slot, head) scale
     element type: fp32 by default (historical layout, bit-compatible with
@@ -1649,9 +1741,20 @@ def make_paged_kv_pool(
             )
         dtype = jnp.dtype(dtype or cfg.compute_dtype)
         fields = {"k_pool": (shape, dtype), "v_pool": (shape, dtype)}
+    kda_fields = None
+    if cfg.layer_group_size:
+        if state_slots < 1:
+            raise ValueError("a hybrid stack's state pools need state_slots (the batch rows)")
+        kda_fields = {
+            name + "_pool": spec for name, spec in kda.state_shapes(cfg, state_slots + 1).items()
+        }
     # Per-layer pools update in place on the serving window's token-scan
     # carry (see make_kv_cache).
-    return _unstack_fields(cfg.n_layers, fields)
+    pools = _unstack_fields(cfg, fields, kda_fields)
+    if kda_fields:
+        # where generation.paged.prefill_into_pool puts a prompt it is given no slot for
+        pools["state_cursor"] = jnp.zeros((), jnp.int32)
+    return pools
 
 
 def _kv_quantize(x: jax.Array) -> Tuple[jax.Array, jax.Array]:

@@ -32,8 +32,10 @@ OptState = Dict[str, Any]
 # decays every Linear weight.
 _DECAY_LEAVES = frozenset(
     {"wqkv", "wq", "wkv", "wo", "w1", "w2", "kernel", "embedding", "router",
-     # latent attention's low-rank projections (models/mla.py)
-     "wq_a", "wq_b", "wkv_a", "wkv_b"}
+     # latent attention's low-rank projections and head-wise gate (models/mla.py)
+     "wq_a", "wq_b", "wkv_a", "wkv_b", "wgate",
+     # KDA's decay, beta and output-gate projections (models/kda.py)
+     "wf", "wbeta", "wg"}
 )
 
 # Leaves that deliberately receive NO decay: norm parameters and biases.
@@ -45,7 +47,10 @@ _NO_DECAY_LEAVES = frozenset(
      # the router's selection bias; the hyper-connections' coefficient
      # projection, bias and gains (models/hyper.py): small, and the streams'
      # mixing should not be pulled toward uniform
-     "router_bias", "phi", "b", "alpha"}
+     "router_bias", "phi", "b", "alpha",
+     # KDA's convolution taps, decay rate and decay bias (models/kda.py): a few
+     # values a channel that set time scales, not a projection
+     "conv", "A_log", "dt_bias"}
 )
 
 

@@ -161,6 +161,8 @@ _PERF_INTENT = {
     "moe-8x350m":      ("flash",        "dots_saveable",  "chunked"),
     # a smoke preset like "tiny", with every Xing4.0 mechanism: naive on purpose
     "xing-mini":       ("naive",        "none",           "chunked"),
+    # the same for every Ling-3.0 mechanism
+    "ling-mini":       ("naive",        "none",           "chunked"),
 }
 
 

@@ -92,6 +92,9 @@ def test_decay_mask_covers_every_leaf_of_every_preset():
             # a latent-attention preset keeps its head split consistent
             **(dict(qk_nope_head_dim=2, qk_rope_head_dim=2, v_head_dim=4, kv_lora_rank=8, q_lora_rank=8)
                if model.kv_lora_rank else {}),
+            # a hybrid preset keeps one layer of each mixer and a share of its experts
+            **(dict(layer_group_size=2, kda_head_dim=4, n_experts_held=2, moe_swiglu_limits=(0.0, 4.0))
+               if model.layer_group_size else {}),
         )
         params = transformer.init_params(tiny, jax.random.key(0))
         mask = opt.decay_mask(params)
@@ -115,6 +118,8 @@ def test_decay_mask_covers_every_leaf_of_every_preset():
     assert {"wq", "wkv"} <= seen_names
     # and so must the latent projections and the stream wrappers (xing-mini)
     assert {"wq_a", "wkv_b", "phi", "alpha", "router_bias"} <= seen_names
+    # and KDA's and the gated latent attention's (ling-mini)
+    assert {"wf", "wbeta", "wg", "wgate", "conv", "A_log", "dt_bias"} <= seen_names
 
 
 def test_clip_by_global_norm():

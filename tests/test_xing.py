@@ -34,7 +34,7 @@ from harness.families import xing as family  # noqa: E402
 from references import xing as ref  # noqa: E402
 from references.common import int8_fake_quant  # noqa: E402
 
-from pretraining_llm_tpu.config import ModelConfig  # noqa: E402
+from pretraining_llm_tpu.config import ModelConfig, get_preset  # noqa: E402
 from pretraining_llm_tpu.generation import paged  # noqa: E402
 from pretraining_llm_tpu.generation.serving import ServingEngine  # noqa: E402
 from pretraining_llm_tpu.models import hyper, layers, mla, moe, transformer as tr  # noqa: E402
@@ -453,7 +453,7 @@ GPT2 = dict(vocab_size=256, context_length=64, n_layers=2, activation="gelu", no
             pos_embed="learned", tie_embeddings=True, qkv_bias=True, mlp_bias=True, attention_impl="flash",
             remat="full")
 DENSE = {"mistral-7b-v0.1": ModelConfig(**MISTRAL), "gpt2-large": ModelConfig(d_model=40, n_heads=4, **GPT2),
-         "gpt2-xl": ModelConfig(d_model=50, n_heads=5, **GPT2)}
+         "gpt2-xl": ModelConfig(d_model=50, n_heads=5, **GPT2), "xing4.0-29b-a4b": get_preset("xing-mini").model}
 # (equations, hash) of each program as the commit 7c8a3aa (PR 28) traces it, less its `name`
 # equations for the remat tags "qkv" and "mlp_hidden", which went with the policies that read
 # them (PR 29; before that, 3f5f5eb): the benchmark's three dense configurations' flags at
@@ -464,6 +464,12 @@ PARENTS = {
     ("mistral-7b-v0.1", "forward"): (210, "86a2e3bb3520ba25"),
     ("gpt2-large", "train"): (808, "98fa975426632a7d"),
     ("gpt2-xl", "train"): (808, "092d28b6a34b7b9b"),
+    # the fourth configuration, at the `xing-mini` preset's widths (latent pool, dropless experts
+    # with no group limit, no clamp and every expert held, four streams), as f8a0c12 (PR 30)
+    # traces it: PR 31's per-layer type table, state slots, group limit and clamps leave it be
+    ("xing4.0-29b-a4b", "decode"): (2779, "81e950d7efcdced8"),
+    ("xing4.0-29b-a4b", "prefill"): (1445, "1fd884617569206c"),
+    ("xing4.0-29b-a4b", "forward"): (1354, "7c021ba348cc52d2"),
 }
 
 
