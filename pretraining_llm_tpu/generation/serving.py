@@ -52,7 +52,7 @@ import numpy as np
 from pretraining_llm_tpu.config import ModelConfig
 from pretraining_llm_tpu.generation import paged, speculative
 from pretraining_llm_tpu.generation import prefix_cache as prefix_cache_mod
-from pretraining_llm_tpu.models import mla, transformer
+from pretraining_llm_tpu.models import mla, moe, transformer
 from pretraining_llm_tpu.observability import spans as _spans
 
 _log = logging.getLogger("pretraining_llm_tpu.serving")
@@ -321,6 +321,17 @@ class ServingEngine:
                 cfg, 1, cfg.kv_cache_dtype == "int8", mesh=mesh
             )
         )
+        # And how a decode step (max_batch rows, K choices each) runs a dropless
+        # layer's experts (models/moe.py::experts_form); None without any.
+        self.decode_experts = None
+        if cfg.moe_dropless:
+            experts = next(
+                stack["mlp"]["experts"] for _, stack, _ in transformer.layer_groups(params, cfg)
+                if "experts" in stack["mlp"]
+            )
+            self.decode_experts = moe.experts_form(
+                int(max_batch) * cfg.experts_per_token, cfg, experts, mesh=mesh
+            )
         self.max_batch = int(max_batch)
         self.block_size = int(block_size)
         # Clamp max_seq so EVERY reachable prefill bucket fits the model
@@ -610,6 +621,8 @@ class ServingEngine:
             # "gather" | "kernel" | "ragged" (per head), "gather" | "latent_kernel" (latent)
             "decode_attention": self.decode_attention,
         }
+        if self.decode_experts:
+            info["decode_experts"] = self.decode_experts  # "kernel" | "grouped"
         if self.state_slots:
             # the other kind of cache: a fixed-size state a row, whatever its length
             info.update(
@@ -992,7 +1005,7 @@ class ServingEngine:
             return toks[0], None, toks[1]
         return toks, None, None
 
-    def _count_moe(self, moe: Any, n_steps: int) -> Dict[str, int]:
+    def _count_moe(self, counters: Any, n_steps: int) -> Dict[str, int]:
         """Add a reaped window's routing counters into the stats:
         ``moe_expert_tokens`` (expert layers, E) tokens routed to each expert,
         ``moe_experts_touched`` (expert layers,) experts that got a token,
@@ -1003,8 +1016,8 @@ class ServingEngine:
         pairs routed (every row's choices, wherever the expert lives), pairs
         that met an expert held here, and the busiest expert's pairs summed
         over layers."""
-        tokens = np.asarray(moe["expert_tokens"], np.int64)
-        touched = np.asarray(moe["experts_touched"], np.int64)
+        tokens = np.asarray(counters["expert_tokens"], np.int64)
+        touched = np.asarray(counters["experts_touched"], np.int64)
         st = self.stats
         if "moe_steps" not in st:
             st["moe_expert_tokens"] = np.zeros_like(tokens)
@@ -2322,9 +2335,15 @@ class ServingEngine:
         self.tokens[row] = 0
         if not self.has_work():
             st = self.stats
+            routing = ""
+            if "moe_steps" in st:
+                routing = "; experts (%s) took %d pairs, %d touched over %d steps" % (
+                    self.decode_experts, st["moe_expert_tokens"].sum(),
+                    st["moe_experts_touched"].sum(), st["moe_steps"],
+                )
             _log.info(
                 "engine empty after %d ticks, %d decode steps: attention read %d "
-                "live pages of %d tabled (%.4f)",
+                "live pages of %d tabled (%.4f)%s",
                 st["ticks"], st["steps"], st["attn_pages_live"], st["attn_pages_tabled"],
-                st["attn_pages_live"] / max(1, st["attn_pages_tabled"]),
+                st["attn_pages_live"] / max(1, st["attn_pages_tabled"]), routing,
             )

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 
 from pretraining_llm_tpu.config import ModelConfig
 from pretraining_llm_tpu.models.layers import weight as _weight
-from pretraining_llm_tpu.parallel.sharding import constrain
+from pretraining_llm_tpu.parallel.sharding import constrain, current_mesh
 
 Params = Dict[str, Any]
 
@@ -269,18 +269,104 @@ def route_dropless(mlp: Params, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Arr
     return idx.astype(jnp.int32), gates * cfg.moe_routed_scale
 
 
+# Sorted rows an expert (S * K / n_experts, what even routing gives each) up to
+# which the expert FFN runs as ops/pallas_moe.py's kernel. Timed on the v5e
+# against the ragged_dot pair at both serving cells' expert shapes (PERF.md
+# section 6, PR 32): the kernel wins 2.5x at 8, 1.7x at 16, the two cross
+# between 29 (three quarters of the sorted rows held elsewhere) and 52.
+KERNEL_ROWS_PER_EXPERT = 16
+
+
+def experts_form(
+    rows: int, cfg: ModelConfig, experts: Params, mesh: Any = None, backend: Any = None
+) -> str:
+    """The form a dropless layer's expert FFN takes for ``rows`` sorted (token,
+    choice) pairs over ``cfg.n_experts``: ``"kernel"`` (``ops/pallas_moe.py``: each
+    touched expert's weights streamed once, for a handful of rows an expert) or
+    ``"grouped"`` (two ``jax.lax.ragged_dot``s). Read from the input as
+    ``mla.decode_form`` reads a latent pool's: a decode step over unquantized
+    bfloat16 experts of whole 128-lane tiles that no mesh shards takes the
+    kernel where Mosaic compiles; a prefill, int8 or float32 experts, a mesh
+    and every other backend keep the grouped form. The engine reports the
+    decode step's form in ``pool_info()``."""
+    w1 = experts["w1"]
+    if (
+        rows <= KERNEL_ROWS_PER_EXPERT * cfg.n_experts
+        and w1.dtype == jnp.dtype(cfg.compute_dtype) == jnp.bfloat16
+        and mesh is None
+        and (backend or jax.default_backend()) == "tpu"
+        and w1.shape[-2] % 128 == 0
+        and w1.shape[-1] % 256 == 0
+    ):
+        return "kernel"
+    return "grouped"
+
+
+def _in_stack(w1: jax.Array, w2: jax.Array, sizes: jax.Array, layer: jax.Array):
+    """A stack's (L, E, ...) weights as L * E groups for the grouped matmul,
+    every group of another layer than ``layer`` empty."""
+    n_stack, held = w1.shape[:2]
+    sizes = jax.lax.dynamic_update_slice(
+        jnp.zeros((n_stack * held,), jnp.int32), sizes, (layer * held,)
+    )
+    return w1.reshape((n_stack * held,) + w1.shape[2:]), w2.reshape((n_stack * held,) + w2.shape[2:]), sizes
+
+
+def _grouped_pair(xs: jax.Array, w1: jax.Array, w2: jax.Array, sizes: jax.Array, limit: Any):
+    f, cdt = w2.shape[-2], xs.dtype
+    up = jax.lax.ragged_dot(xs, w1, sizes, preferred_element_type=cdt)
+    hidden = swiglu(up[:, :f], up[:, f:], limit)
+    return jax.lax.ragged_dot(hidden, w2, sizes, preferred_element_type=cdt)
+
+
+def experts_grouped(
+    xs: jax.Array, w1: jax.Array, w2: jax.Array, sizes: jax.Array, layer: Any = None, limit: Any = None
+) -> jax.Array:
+    """The expert FFN of sorted rows ``xs`` (N, D), ``sizes[e]`` of them for
+    held expert e, as two grouped matmuls; ``layer`` for a stack's weights."""
+    if layer is not None:
+        w1, w2, sizes = _in_stack(w1, w2, sizes, layer)
+    return _grouped_pair(xs, w1, w2, sizes, limit)
+
+
+@jax.custom_vjp
+def experts_kernel(
+    xs: jax.Array, w1: jax.Array, w2: jax.Array, sizes: jax.Array, layer: Any = None, limit: Any = None
+) -> jax.Array:
+    """``experts_grouped`` through ``ops/pallas_moe.py`` (rows past the last
+    group are promised by neither); its VJP is the grouped form's."""
+    from pretraining_llm_tpu.ops.pallas_moe import expert_ffn
+
+    return expert_ffn(xs, w1, w2, sizes, layer, limit)
+
+
+def _experts_kernel_fwd(xs, w1, w2, sizes, layer, limit):
+    return experts_kernel(xs, w1, w2, sizes, layer, limit), (xs, w1, w2, sizes, layer, limit)
+
+
+def _experts_kernel_bwd(res, g):
+    xs, w1, w2, sizes, layer, limit = res
+    _, vjp = jax.vjp(lambda x, a, b, lim: experts_grouped(x, a, b, sizes, layer, lim), xs, w1, w2, limit)
+    d_xs, d_w1, d_w2, d_limit = vjp(g)
+    return d_xs, d_w1, d_w2, None, None, d_limit
+
+
+experts_kernel.defvjp(_experts_kernel_fwd, _experts_kernel_bwd)
+
+
 def moe_mlp_dropless(
     mlp: Params, h: jax.Array, cfg: ModelConfig, dense_mlp: Any
 ) -> Tuple[jax.Array, jax.Array]:
     """Dropless expert FFN on normed input h (B, T, D) -> (output, tokens
     routed to each expert (E,) int32).
 
-    The (token, choice) pairs are sorted by expert, each projection is one
-    grouped matmul over the experts held (``jax.lax.ragged_dot``: on TPU a
-    native grouped-matmul kernel that reads only the experts some token chose),
-    the result is un-sorted and the K weighted parts of a token summed. The
-    same program shape serves an 8 k-token prefill and a 32-row decode step,
-    and a token's output is a function of that token alone.
+    The (token, choice) pairs are sorted by expert, the experts held run over
+    their rows in the form the input picks (``experts_form``: one grouped
+    matmul a projection, ``jax.lax.ragged_dot``, for a prefill's hundreds of
+    rows an expert; one Pallas kernel that streams each touched expert's
+    weights once for a decode step's handful), the result is un-sorted and the
+    K weighted parts of a token summed. A token's output is a function of that
+    token alone.
 
     The layer holds the experts its weights carry, ``w1.shape[0]`` of the
     ``cfg.n_experts`` the router scores, from ``cfg``'s first expert on: a
@@ -290,11 +376,11 @@ def moe_mlp_dropless(
 
     Inside a layer stack ``mlp["experts"]`` is the stack's weights, (L, E, ...),
     and ``mlp["expert_layer"]`` says which layer this is: the grouped matmul
-    then runs over all L * E groups with every other layer's group empty. A
-    per-layer slice of the stack would be copied for the kernel each call
-    (0.94 + 0.47 GB a layer at 64 experts of 3584 x 1024); an empty group costs
-    nothing and the stack is read where it lies. ``mlp["expert_limit"]``, if
-    there, is the layer's SwiGLU clamp (``swiglu``).
+    then runs over all L * E groups with every other layer's group empty, the
+    kernel addresses its tiles ``(layer * E + expert, ...)``. A per-layer slice
+    of the stack would be copied each call (0.94 + 0.47 GB a layer at 64
+    experts of 3584 x 1024); the stack is read where it lies.
+    ``mlp["expert_limit"]``, if there, is the layer's SwiGLU clamp (``swiglu``).
     """
     cdt = jnp.dtype(cfg.compute_dtype)
     b, t, d = h.shape
@@ -302,7 +388,9 @@ def moe_mlp_dropless(
     x = h.reshape(s, d)
     ex = mlp["experts"]
     w1, w2 = _weight(ex, "w1", cdt), _weight(ex, "w2", cdt)
-    held, f = w1.shape[-3], w2.shape[-2]
+    held = w1.shape[-3]
+    layer, limit = mlp.get("expert_layer"), mlp.get("expert_limit")
+    form = experts_form(s * k, cfg, ex, current_mesh())
     with jax.named_scope("moe.router"):
         idx, gates = route_dropless(mlp, x, cfg)
     with jax.named_scope("moe.dispatch"):
@@ -312,16 +400,13 @@ def moe_mlp_dropless(
         counts = jnp.bincount(flat, length=held + 1).astype(jnp.int32)
         xs = x[order // k].astype(cdt)  # (S*K, D), rows grouped by expert
         sizes = counts[:held]
-        if "expert_layer" in mlp:
-            n_stack = w1.shape[0]
-            sizes = jax.lax.dynamic_update_slice(
-                jnp.zeros((n_stack * held,), jnp.int32), sizes, (mlp["expert_layer"] * held,)
-            )
-            w1, w2 = w1.reshape((n_stack * held,) + w1.shape[2:]), w2.reshape((n_stack * held,) + w2.shape[2:])
+        if form == "grouped" and layer is not None:
+            w1, w2, sizes = _in_stack(w1, w2, sizes, layer)
     with jax.named_scope("moe.experts"):
-        up = jax.lax.ragged_dot(xs, w1, sizes, preferred_element_type=cdt)
-        hidden = swiglu(up[:, :f], up[:, f:], mlp.get("expert_limit"))
-        ys = jax.lax.ragged_dot(hidden, w2, sizes, preferred_element_type=cdt)
+        if form == "kernel":
+            ys = experts_kernel(xs, w1, w2, sizes, layer, limit)
+        else:
+            ys = _grouped_pair(xs, w1, w2, sizes, limit)
     with jax.named_scope("moe.combine"):
         here = flat[order] < held
         g_sorted = jnp.where(here, gates.reshape(s * k)[order], 0.0)

@@ -1,0 +1,219 @@
+"""Pallas TPU expert FFN for a handful of rows an expert: weights read once.
+
+A decode step hands an expert layer two rows an expert. XLA's TPU expansion
+of ``jax.lax.ragged_dot`` is built for prefill (row tiles of hundreds) and
+pays a fixed ~25 us a touched group whatever the group's bytes (PERF.md
+section 6, PR 32). This kernel is the other end of that trade: it is bound by
+the bytes of the experts some row chose, and does the two grouped matmuls and
+the SwiGLU between them in one pass over those bytes.
+
+  - The rows arrive sorted by expert (``models/moe.py::moe_mlp_dropless``),
+    ``sizes[e]`` of them for held expert ``e``; rows past the last group
+    belong to experts held elsewhere.
+  - A **visit** is one expert and ``2 * ROW_TILE`` consecutive sorted rows: the
+    two ``ROW_TILE``-aligned windows that hold the expert's first rows. A group
+    of up to ``ROW_TILE + 1`` rows is one visit wherever it starts; a larger
+    one takes a visit every ``2 * ROW_TILE`` rows, its weights read again for
+    each (the regime of a prefill, which keeps ``ragged_dot``).
+  - Grid ``(visits, F tiles)``. The list of visits (expert, first window) is
+    scalar prefetch, built from ``sizes`` outside; the weight stacks stay whole
+    in HBM and a step's tiles are addressed ``(layer * E + expert, ...)`` by
+    the block index maps: gate columns ``(D, tf)``, up columns ``(D, tf)``
+    (``w1`` is passed twice: gate columns come before up columns in ``2F``)
+    and the matching ``(tf, D)`` rows of ``w2``, double buffered by the
+    pipeline. ``silu(gate) * up`` stays in VMEM; the ``(rows, D)`` output
+    accumulates in float32 across the F tiles.
+  - The grid is as long as the visits of this call (a dynamic grid bound, at
+    least one step: with nothing routed here that step computes nothing). An
+    expert no row chose, another layer's expert and the rows of experts held
+    elsewhere cost no grid step, no copy and no MXU pass.
+  - Neighbouring groups share a sublane tile of the sorted rows, so a visit
+    cannot write its rows where they lie. It computes its two whole windows
+    (the neighbours' rows through its own weights are wasted MXU passes, of
+    which a byte-bound kernel has plenty) into an output block of its own, and
+    one row gather outside brings the sorted order back.
+
+Same arithmetic as the grouped form: operands in the compute dtype, float32
+accumulation, gate / up and the hidden each rounded to the compute dtype once.
+Forward only here; ``models/moe.py`` gives the call the grouped form's VJP.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# Rows of a window of the sorted rows: one packed sublane tile of bfloat16.
+ROW_TILE = 16
+# The pipeline's two buffers of (gate, up, down) tiles stay under this, inside
+# Mosaic's default 16 MB of scoped VMEM with room for rows and accumulator.
+WEIGHT_TILE_BYTES = 12 << 20
+
+
+def f_tile(d: int, f: int, itemsize: int) -> int:
+    """Columns of F a grid step takes: the largest whole number of 128-lane
+    tiles that divides F and keeps two buffers of the three weight tiles under
+    ``WEIGHT_TILE_BYTES`` (at least one lane tile)."""
+    fits = [
+        tf for tf in range(128, f + 1, 128)
+        if f % tf == 0 and 2 * 3 * d * tf * itemsize <= WEIGHT_TILE_BYTES
+    ]
+    return max(fits, default=128)
+
+
+def n_visits(n_rows: int, held: int) -> int:
+    """The most visits ``n_rows`` sorted rows (a multiple of ROW_TILE) over
+    ``held`` experts can take: one a touched expert, and one more for every
+    ROW_TILE rows a group holds (a group of n rows takes at most 1 + n //
+    ROW_TILE)."""
+    return min(held, n_rows) + n_rows // ROW_TILE
+
+
+def plan(sizes: jax.Array, n_rows: int):
+    """``sizes`` (E,) rows a held expert -> the visits and the way back.
+
+    (expert (V,), first window (V,), live visits (1,), position of each sorted
+    row in the visits' output (n_rows,)); V is ``n_visits``, what lies past the
+    live visits is never read."""
+    held = sizes.shape[0]
+    span = 2 * ROW_TILE
+    sizes = sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    base = (ends - sizes) // ROW_TILE * ROW_TILE  # the window a group starts in
+    visits = jnp.where(sizes > 0, (ends - base + span - 1) // span, 0)
+    v_ends = jnp.cumsum(visits)
+    first = v_ends - visits
+    live = v_ends[-1]
+    v = jnp.arange(n_visits(n_rows, held), dtype=jnp.int32)
+    expert = jnp.minimum(jnp.searchsorted(v_ends, v, side="right"), held - 1).astype(jnp.int32)
+    window = jnp.clip(base[expert] // ROW_TILE + 2 * (v - first[expert]), 0, n_rows // ROW_TILE - 1)
+    rows = jnp.arange(n_rows, dtype=jnp.int32)
+    of_row = jnp.minimum(jnp.searchsorted(ends, rows, side="right"), held - 1)
+    rel = rows - base[of_row]
+    position = (first[of_row] + rel // span) * span + rel % span
+    # a row past the last group gets some position inside the output: never used
+    position = jnp.clip(position, 0, v.shape[0] * span - 1)
+    return expert, window.astype(jnp.int32), live.reshape(1), position
+
+
+def _moe_kernel(
+    exp_ref,  # (V,) int32 scalar prefetch: the visit's group, layer * E + expert
+    win_ref,  # (V,) int32: its first window
+    live_ref,  # (1,) int32: visits that do anything
+    *refs,
+    clamp: bool,
+):
+    if clamp:
+        lim_ref, *refs = refs  # (1,) float32 in SMEM
+    xa_ref, xb_ref, wg_ref, wu_ref, wd_ref, o_ref, x_scr, acc_scr = refs
+    v, j = pl.program_id(0), pl.program_id(1)
+    cdt = x_scr.dtype
+
+    @pl.when(v < live_ref[0])
+    def _visit():
+        @pl.when(j == 0)
+        def _rows():
+            x_scr[:ROW_TILE] = xa_ref[...]
+            x_scr[ROW_TILE:] = xb_ref[...]
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        x = x_scr[...]
+        dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+        # rounded to the compute dtype as ragged_dot's preferred_element_type does
+        gate = dot(x, wg_ref[...]).astype(cdt).astype(jnp.float32)
+        up = dot(x, wu_ref[...]).astype(cdt).astype(jnp.float32)
+        if clamp:
+            lim = lim_ref[0]
+            gate, up = jnp.minimum(gate, lim), jnp.clip(up, -lim, lim)
+        hidden = (gate * jax.nn.sigmoid(gate)).astype(cdt).astype(jnp.float32) * up
+        acc_scr[...] += dot(hidden.astype(cdt), wd_ref[...])
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _out():
+            o_ref[...] = acc_scr[...].astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tf", "interpret"))
+def _moe_call(xs, w1, w2, sizes, layer, limit, tf, interpret):
+    n, d = xs.shape
+    held, f = w1.shape[-3], w2.shape[-2]
+    w1 = w1.reshape(-1, d, 2 * f)  # a stack's (L, E) as L * E groups: a bitcast
+    w2 = w2.reshape(-1, f, d)
+    nf, span = f // tf, 2 * ROW_TILE
+    n_rows = n + -n % ROW_TILE
+    xs = jnp.pad(xs, ((0, n_rows - n), (0, 0)))
+    expert, window, live, position = plan(sizes, n_rows)
+    n_windows, visits = n_rows // ROW_TILE, expert.shape[0]
+
+    clamp = limit is not None
+    lim = ()
+    if clamp:
+        lim = (jnp.where(limit > 0, limit, jnp.inf).astype(xs.dtype).astype(jnp.float32).reshape(1),)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(jnp.maximum(live[0], 1), nf),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM) for _ in lim] + [
+            pl.BlockSpec((ROW_TILE, d), lambda v, j, exp, win, live: (win[v], 0)),
+            pl.BlockSpec(
+                (ROW_TILE, d), lambda v, j, exp, win, live: (jnp.minimum(win[v] + 1, n_windows - 1), 0)
+            ),
+            pl.BlockSpec((None, d, tf), lambda v, j, exp, win, live: (exp[v], 0, j)),
+            pl.BlockSpec((None, d, tf), lambda v, j, exp, win, live: (exp[v], 0, nf + j)),
+            pl.BlockSpec((None, tf, d), lambda v, j, exp, win, live: (exp[v], j, 0)),
+        ],
+        out_specs=pl.BlockSpec((span, d), lambda v, j, exp, win, live: (v, 0)),
+        scratch_shapes=[pltpu.VMEM((span, d), xs.dtype), pltpu.VMEM((span, d), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_moe_kernel, clamp=clamp),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((visits * span, d), xs.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(expert + layer * held, window, live, *lim, xs, xs, w1, w1, w2)
+    return out[position[:n]]
+
+
+def expert_ffn(
+    xs: jax.Array,  # (N, D) rows sorted by expert, the compute dtype
+    w1: jax.Array,  # (E, D, 2F) gate columns then up columns, or a stack (L, E, D, 2F)
+    w2: jax.Array,  # (E, F, D) or (L, E, F, D)
+    sizes: jax.Array,  # (E,) int32 rows of each held expert, in order
+    layer: Optional[jax.Array] = None,  # the layer of a stack, a scalar
+    limit: Optional[jax.Array] = None,  # SwiGLU clamp, a scalar, 0 = off
+    *,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """(silu(x . gate_e) * (x . up_e)) . down_e for each sorted row x of held
+    expert e: (N, D) in ``xs``'s dtype. Rows past ``sum(sizes)`` (experts held
+    elsewhere) come back as something finite or not: the caller selects them
+    away, as after ``ragged_dot``. ``interpret=None``: compiled on TPU, the
+    interpreter elsewhere (tests)."""
+    if interpret is None:
+        interpret = jax.devices()[0].platform != "tpu"
+    n, d = xs.shape
+    held, f = w1.shape[-3], w2.shape[-2]
+    stacked = w1.ndim == 4
+    if (
+        w1.shape[-3:] != (held, d, 2 * f)
+        or w2.shape != w1.shape[:-2] + (f, d)
+        or w1.ndim != 3 + stacked
+        or not xs.dtype == w1.dtype == w2.dtype
+        or sizes.shape != (held,)
+        or stacked != (layer is not None)
+        or d % 128 or f % 128
+    ):
+        raise ValueError(
+            f"rows {xs.shape} {xs.dtype}, w1 {w1.shape} {w1.dtype}, w2 {w2.shape} {w2.dtype}, sizes "
+            f"{sizes.shape}, layer {layer}: want (N, D), ([L,] E, D, 2F), ([L,] E, F, D), (E,) in one "
+            f"dtype, D and F whole 128-lane tiles, and a layer exactly for a stack"
+        )
+    layer = jnp.zeros((), jnp.int32) if layer is None else jnp.asarray(layer, jnp.int32)
+    return _moe_call(
+        xs, w1, w2, sizes, layer, limit, f_tile(d, f, xs.dtype.itemsize), bool(interpret)
+    )

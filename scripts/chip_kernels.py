@@ -1,14 +1,16 @@
 #!/usr/bin/env python
-"""Compile and run every Pallas attention kernel on the TPU against its XLA
-reference, at the sizes serving and training use (Dh 64, 12 heads, bf16; the
-paged decode step also at 32 heads of 128).
+"""Compile and run every Pallas kernel on the TPU against its XLA reference,
+at the sizes serving and training use (attention: Dh 64, 12 heads, bf16; the
+paged decode step also at 32 heads of 128; the expert FFN at the two serving
+cells' expert shapes).
 
 The CPU tests run these kernels in interpret mode at toy sizes; only the
 chip hears Mosaic's refusals (tiling, unaligned slices, VMEM) and only there
 do the compiled numerics exist. One line per case, then one JSON summary
-line; exit 0 iff every case matched. A correctness run — it times nothing.
+line; exit 0 iff every case matched. A correctness run; ``--time-moe`` instead
+times the expert kernel against ``ragged_dot`` alone, 2 to 512 rows an expert.
 
-Usage (on the chip):  python scripts/chip_kernels.py [--only SUBSTR]
+Usage (on the chip):  python scripts/chip_kernels.py [--only SUBSTR] [--time-moe]
 """
 
 from __future__ import annotations
@@ -163,7 +165,104 @@ def ragged_case(g: int, page: int, t: int, splits, amla: bool, int8: bool):
     return {"o": _err(got, want)}, 3e-2
 
 
+# The two serving cells' expert layers: (layers of the stack, experts held, D, F,
+# sorted rows of a decode step, of which routed to experts held here).
+MOE_SHAPES = {"ling": (3, 128, 2560, 768, 1024, 256), "xing": (3, 64, 3584, 1024, 128, 128)}
+MOE_MIXES = ("uniform", "one-takes-all", "tile-edges", "all-elsewhere")
+
+
+def _moe_inputs(shape: str, mix: str, rows_an_expert: int = 0, seed: int = 0):
+    """(xs, w1, w2, sizes) at a cell's expert shapes. ``rows_an_expert`` > 0:
+    that many rows an expert on average, all of them routed here."""
+    from pretraining_llm_tpu.ops.pallas_moe import ROW_TILE
+
+    n_stack, held, d, f, n, here = MOE_SHAPES[shape]
+    if rows_an_expert:
+        n = here = rows_an_expert * held
+    rng = np.random.default_rng(seed)
+    if mix == "uniform":
+        sizes = np.bincount(rng.integers(0, held, here), minlength=held)
+    elif mix == "one-takes-all":
+        sizes = np.zeros(held, np.int64)
+        sizes[held // 3] = here
+    elif mix == "tile-edges":  # groups of 0, 1, 2, 7, a row tile and one more, then the rest
+        sizes = np.zeros(held, np.int64)
+        sizes[1:12:2] = (1, 2, 7, ROW_TILE, ROW_TILE + 1, 0)
+        sizes[-1] = here - sizes.sum()
+    else:
+        sizes = np.zeros(held, np.int64)
+    ks = jax.random.split(jax.random.key(seed), 3)
+    xs = jax.random.normal(ks[0], (n, d), jnp.bfloat16)
+
+    def stack(key, shape, fan_in):  # a layer at a time: the generator's temporaries are float32
+        one = jax.jit(lambda k: (jax.random.normal(k, shape, jnp.float32) * fan_in ** -0.5).astype(jnp.bfloat16))
+        return jnp.stack([one(k) for k in jax.random.split(key, n_stack)])
+
+    w1, w2 = stack(ks[1], (held, d, 2 * f), d), stack(ks[2], (held, f, d), f)
+    return xs, w1, w2, jnp.asarray(sizes, jnp.int32)
+
+
+def moe_case(shape: str, mix: str, layer: int, clamp: bool):
+    """``ops/pallas_moe.py`` against the ``ragged_dot`` pair it replaces, over
+    the rows routed here (what lies past them is promised by neither)."""
+    from pretraining_llm_tpu.models import moe
+
+    xs, w1, w2, sizes = _moe_inputs(shape, mix)
+    limit = jnp.float32(1.5) if clamp else None
+    got = jax.jit(moe.experts_kernel)(xs, w1, w2, sizes, jnp.int32(layer), limit)
+    want = jax.jit(moe.experts_grouped)(xs, w1, w2, sizes, jnp.int32(layer), limit)
+    here = int(sizes.sum())
+    if not here:
+        return {"o": 0.0 if got.shape == want.shape else float("inf")}, 3e-2
+    scale = max(1.0, float(jnp.max(jnp.abs(want[:here].astype(jnp.float32)))))
+    return {"o": _err(got[:here], want[:here]) / scale}, 3e-2
+
+
+def time_moe(reps: int = 20):
+    """Milliseconds a layer of the kernel and of the ``ragged_dot`` pair alone,
+    at both cells' expert shapes: the decode step's own mix, then 2 to 512 rows
+    an expert (PERF.md section 6, PR 32: where ``moe.KERNEL_ROWS_PER_EXPERT``
+    comes from). Each call runs every layer of the stack in turn."""
+    import time
+
+    from pretraining_llm_tpu.models import moe
+
+    def ms_a_layer(form, xs, w1, w2, sizes):
+        def every_layer(xs, w1, w2, sizes):
+            def body(acc, layer):
+                return acc + form(xs, w1, w2, sizes, layer, None)[0, 0].astype(jnp.float32), None
+            return jax.lax.scan(body, jnp.float32(0), jnp.arange(w1.shape[0], dtype=jnp.int32))[0]
+        fn = jax.jit(every_layer)
+        fn(xs, w1, w2, sizes).block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out = fn(xs, w1, w2, sizes)
+        out.block_until_ready()
+        return (time.perf_counter() - t0) / (reps * w1.shape[0]) * 1e3
+
+    for shape, (_, held, d, f, _, _) in MOE_SHAPES.items():
+        for rows in (0, 2, 8, 32, 64, 128, 512):
+            xs, w1, w2, sizes = _moe_inputs(shape, "uniform", rows)
+            touched = int((sizes > 0).sum())
+            line = {
+                "shape": shape, "rows_an_expert": rows or "decode step", "rows": xs.shape[0],
+                "touched": touched, "touched_mb": round(touched * 3 * d * f * 2 / 1e6, 1),
+            }
+            for name, form in (("kernel_ms", moe.experts_kernel), ("grouped_ms", moe.experts_grouped)):
+                try:
+                    line[name] = round(ms_a_layer(form, xs, w1, w2, sizes), 4)
+                except Exception as e:
+                    line[name] = f"{type(e).__name__}: {str(e).splitlines()[0][:200]}"
+            print(json.dumps(line), flush=True)
+            del xs, w1, w2
+
+
 def cases():
+    for shape in MOE_SHAPES:
+        for mix in MOE_MIXES:
+            for layer, clamp in ((0, False), (MOE_SHAPES[shape][0] - 1, True)):
+                name = f"moe {shape} {mix} layer{layer}" + (" clamp" if clamp else "")
+                yield name, moe_case, (shape, mix, layer, clamp)
     for t in (1024, 2048):  # one block (fused backward) / 2x2 blocks
         for g in (12, 4):
             for seg in (False, True):
@@ -195,6 +294,8 @@ def cases():
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--only", default="", help="run cases whose name contains this")
+    ap.add_argument("--time-moe", action="store_true",
+                    help="time the expert kernel against ragged_dot instead (one JSON line a size)")
     args = ap.parse_args()
     dev = jax.devices()[0]
     print(f"jax {jax.__version__} backend {jax.default_backend()} "
@@ -203,6 +304,9 @@ def main() -> int:
         print("chip_kernels: no TPU backend; the CPU tests cover interpret mode",
               file=sys.stderr)
         return 1
+    if args.time_moe:
+        time_moe()
+        return 0
     results = {}
     for name, fn, fn_args in cases():
         if args.only not in name:

@@ -8,7 +8,9 @@ whatever the rows' lengths, the fill of a last page, the division of the page
 count by the step's pages, the fold of a page's slots, and whatever a table's
 dead tail points at. Interpret mode on CPU (same convention as
 test_pallas_paged); one at-size compile for a described v5e says what Mosaic
-would refuse.
+would refuse. The other kernels of the serving path compile at size here too
+(``ops/pallas_paged.py``'s decode form, ``ops/pallas_moe.py``): one file, so
+that one test worker loads the TPU compiler.
 """
 
 import jax
@@ -262,3 +264,39 @@ def test_per_head_decode_kernel_compiles_for_the_chip_at_the_cells_size(one_chip
     pool_sized = [op for op in ops if op.startswith(f"bf16[{n_blocks},") and " parameter(" not in op]
     assert len(pool_sized) == 2 and all(" bitcast(" in op for op in pool_sized), pool_sized
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("stack,held,d,f,rows,clamp", [(4, 128, 2560, 768, 1024, False), (5, 64, 3584, 1024, 128, True)],
+                         ids=["ling-decode-step", "xing-decode-step"])
+def test_expert_kernel_compiles_for_the_chip_at_the_cells_size(one_chip, stack, held, d, f, rows, clamp):
+    """``ops/pallas_moe.py`` at the two serving cells' expert layers (a stack of
+    128 experts of 2560 x 768 under 1,024 sorted rows, of 64 experts of 3584 x
+    1024 under 128): Mosaic takes it with its tiles inside the default scoped
+    VMEM, both stacks enter the custom call as they lie (the flat (L * E, ...)
+    views are bitcasts; no copy, slice or relayout of either), and the
+    temporaries are the visits' output, not a stack's size."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from pretraining_llm_tpu.ops import pallas_moe as pm
+
+    shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    tf = pm.f_tile(d, f, 2)
+    fn = lambda xs, w1, w2, sizes, layer, *limit: pm._moe_call(xs, w1, w2, sizes, layer, *(limit or (None,)), tf, False)
+    args = [shape((rows, d)), shape((stack, held, d, 2 * f)), shape((stack, held, f, d)),
+            shape((held,), jnp.int32), shape((), jnp.int32)] + [shape((), jnp.float32)] * clamp
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable without a chip
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(fn).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "ragged-dot" not in text
+    ops = [line.split(" = ", 1)[1] for line in text.splitlines() if " = " in line]
+    stack_sized = [op for op in ops if op.startswith((f"bf16[{stack},{held},", f"bf16[{stack * held},"))
+                   and " parameter(" not in op]
+    assert len(stack_sized) == 2 and all(" bitcast(" in op for op in stack_sized), stack_sized
+    assert 2 * 3 * d * tf * 2 <= pm.WEIGHT_TILE_BYTES
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

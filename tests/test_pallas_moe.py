@@ -1,0 +1,305 @@
+"""ops/pallas_moe.py (interpreted here): the expert FFN of a handful of rows an
+expert against the ``ragged_dot`` form it replaces and a dense per-expert
+oracle, the rule that picks the form (``moe.experts_form``), and the programs
+and the engine that follow it. The compiled kernel is heard on the chip
+(``scripts/chip_kernels.py``) and, at the cells' sizes, by the at-size compile
+in ``tests/test_pallas_latent.py``."""
+
+import dataclasses
+import functools
+import logging
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from pretraining_llm_tpu.generation import paged
+from pretraining_llm_tpu.generation.serving import ServingEngine
+from pretraining_llm_tpu.models import moe, transformer
+from pretraining_llm_tpu.ops import pallas_moe as pk
+
+TILE = pk.ROW_TILE
+E, D, F, STACK = 6, 128, 256, 3
+
+
+def _weights(dtype, held=E, stack=None, seed=0):
+    k1, k2 = jax.random.split(jax.random.key(seed))
+    lead = (held,) if stack is None else (stack, held)
+    w1 = (jax.random.normal(k1, lead + (D, 2 * F), jnp.float32) * D ** -0.5).astype(dtype)
+    w2 = (jax.random.normal(k2, lead + (F, D), jnp.float32) * F ** -0.5).astype(dtype)
+    return w1, w2
+
+
+def _rows(n, dtype, seed=1):
+    return jax.random.normal(jax.random.key(seed), (n, D), jnp.float32).astype(dtype)
+
+
+def _oracle(xs, w1, w2, sizes, limit=None):
+    """Each row through its own expert's dense SwiGLU, in float32; rows past
+    the last group are zero."""
+    xs, w1, w2 = (np.asarray(a, np.float32) for a in (xs, w1, w2))
+    out, row = np.zeros_like(xs), 0
+    for e, n in enumerate(np.asarray(sizes)):
+        up = xs[row : row + n] @ w1[e]
+        gate, up = up[:, :F], up[:, F:]
+        if limit:
+            gate, up = np.minimum(gate, limit), np.clip(up, -limit, limit)
+        out[row : row + n] = (gate / (1 + np.exp(-gate)) * up) @ w2[e]
+        row += n
+    return out
+
+
+def _tol(dtype):
+    return 2e-5 if dtype == jnp.float32 else 6e-2
+
+
+GROUPS = {
+    "empty-one-two-seven": [0, 1, 2, 7, 0, 3],
+    "tile-and-one-more": [TILE, 0, TILE + 1, 1, 0, 2],
+    "straddles-a-window": [TILE - 1, 2, 0, TILE + 1, TILE + 2, 1],
+    "one-takes-every-row": [0, 0, 3 * TILE + 5, 0, 0, 0],
+    "all-alone": [1, 1, 1, 1, 1, 1],
+    "last-only": [0, 0, 0, 0, 0, 9],
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("sizes", GROUPS.values(), ids=GROUPS)
+def test_kernel_is_the_grouped_form_and_the_dense_oracle(sizes, dtype):
+    sizes = jnp.asarray(sizes, jnp.int32)
+    n = int(sizes.sum())
+    xs, (w1, w2) = _rows(n, dtype), _weights(dtype)
+    got = pk.expert_ffn(xs, w1, w2, sizes)
+    assert got.shape == xs.shape and got.dtype == dtype
+    want = moe.experts_grouped(xs, w1, w2, sizes)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), atol=_tol(dtype))
+    np.testing.assert_allclose(np.asarray(got, np.float32), _oracle(xs, w1, w2, sizes), atol=_tol(dtype))
+
+
+@pytest.mark.parametrize("sizes,elsewhere", [([2, 0, 5, 1], 9), ([0, 0, 0, 0], 13), ([TILE + 1, 0, 0, 3], TILE)],
+                         ids=["some-here", "all-elsewhere", "a-window-of-strangers"])
+def test_rows_of_experts_held_elsewhere_cost_nothing_and_change_nothing(sizes, elsewhere):
+    """``held < n_experts``: the rows past the last group belong to no expert
+    here. The rows that do come out as without them."""
+    sizes = jnp.asarray(sizes, jnp.int32)
+    here = int(sizes.sum())
+    xs, (w1, w2) = _rows(here + elsewhere, jnp.float32), _weights(jnp.float32, held=4)
+    got = pk.expert_ffn(xs, w1, w2, sizes)
+    assert got.shape == xs.shape
+    np.testing.assert_allclose(got[:here], _oracle(xs, w1, w2, sizes)[:here], atol=2e-5)
+    if here:
+        np.testing.assert_array_equal(got[:here], pk.expert_ffn(xs[:here], w1, w2, sizes))
+
+
+@pytest.mark.parametrize("clamp", [None, 0.0, 0.4], ids=["no-clamp", "clamp-off", "clamp-on"])
+@pytest.mark.parametrize("layer", [0, 1, STACK - 1], ids=["first", "middle", "last"])
+def test_a_stack_is_read_at_its_layer(layer, clamp):
+    sizes = jnp.asarray([3, 0, TILE + 2, 1, 0, 2], jnp.int32)
+    xs, (w1, w2) = _rows(int(sizes.sum()), jnp.float32), _weights(jnp.float32, stack=STACK)
+    limit = None if clamp is None else jnp.float32(clamp)
+    got = jax.jit(pk.expert_ffn)(xs, w1, w2, sizes, jnp.int32(layer), limit)
+    np.testing.assert_allclose(got, _oracle(xs, w1[layer], w2[layer], sizes, clamp), atol=2e-5)
+    np.testing.assert_allclose(got, moe.experts_grouped(xs, w1, w2, sizes, jnp.int32(layer), limit), atol=2e-5)
+    if clamp:  # the clamp bites at this size, or the case shows nothing
+        assert not np.allclose(got, _oracle(xs, w1[layer], w2[layer], sizes), atol=1e-3)
+
+
+def test_what_no_row_chose_is_never_read():
+    """NaN in every expert no row chose and in every other layer of the stack:
+    the same finite result (the kernel visits touched experts only, as PR 30's
+    kernel reads no dead page)."""
+    sizes = np.asarray([0, 4, 0, TILE + 3, 0, 1])
+    layer = 1
+    xs, (w1, w2) = _rows(int(sizes.sum()) + 7, jnp.float32), _weights(jnp.float32, stack=STACK)
+    clean = pk.expert_ffn(xs, w1, w2, jnp.asarray(sizes), jnp.int32(layer))
+    dead = np.ones((STACK, E), bool)
+    dead[layer, sizes > 0] = False
+    poison = lambda w: jnp.where(dead.reshape(STACK, E, 1, 1), jnp.nan, w)
+    got = pk.expert_ffn(xs, poison(w1), poison(w2), jnp.asarray(sizes), jnp.int32(layer))
+    here = int(sizes.sum())
+    assert np.isfinite(np.asarray(got[:here])).all()
+    np.testing.assert_array_equal(got[:here], clean[:here])
+
+
+@pytest.mark.parametrize("rows,held", [(16, 4), (256, 64), (1024, 128), (48, 300)])
+def test_the_grid_holds_every_plan(rows, held):
+    """``n_visits`` bounds the visits of the mixes that take most: every
+    touched expert alone in a visit, and groups that start on a window's last
+    row."""
+    span = 2 * TILE
+    first_on_a_last_row = np.bincount(np.arange(rows - TILE + 1) % min(held, 3), minlength=held)
+    first_on_a_last_row[0] += TILE - 1
+    for sizes in (
+        np.bincount(np.arange(rows) % held, minlength=held),
+        first_on_a_last_row,
+        np.r_[rows, np.zeros(held - 1, int)],
+    ):
+        ends = np.cumsum(sizes)
+        base = (ends - sizes) // TILE * TILE
+        visits = np.where(sizes > 0, -(-(ends - base) // span), 0).sum()
+        assert visits <= pk.n_visits(rows, held)
+        expert, window, live, position = pk.plan(jnp.asarray(sizes, jnp.int32), rows)
+        assert int(live[0]) == visits and expert.shape == window.shape == (pk.n_visits(rows, held),)
+        here = np.asarray(position)[: sizes.sum()]
+        assert len(set(here.tolist())) == len(here) and here.max(initial=0) < visits * span + span
+
+
+def test_shapes_the_kernel_cannot_take_are_refused_by_name():
+    xs, (w1, w2) = _rows(4, jnp.float32), _weights(jnp.float32)
+    sizes = jnp.asarray([4, 0, 0, 0, 0, 0], jnp.int32)
+    with pytest.raises(ValueError, match="128-lane"):
+        pk.expert_ffn(xs[:, :96], w1[:, :96], w2[:, :, :96], sizes)
+    with pytest.raises(ValueError, match="layer"):
+        pk.expert_ffn(xs, w1, w2, sizes, jnp.int32(0))
+    with pytest.raises(ValueError, match="one"):
+        pk.expert_ffn(xs.astype(jnp.bfloat16), w1, w2, sizes)
+
+
+# -- the form follows the input -------------------------------------------------------
+
+CFG = transformer.ModelConfig(
+    vocab_size=64, context_length=64, d_model=128, n_heads=2, n_layers=3, mlp_ratio=2.0,
+    activation="swiglu", norm="rmsnorm", pos_embed="rope", tie_embeddings=False, mlp_bias=False,
+    compute_dtype="bfloat16", param_dtype="bfloat16", n_experts=8, experts_per_token=2,
+    moe_routing="dropless", moe_score="sigmoid", n_shared_experts=1, d_expert=128, n_dense_layers=1,
+)
+
+
+def _experts(dtype=jnp.bfloat16, d=128, f=128):
+    return {"w1": jax.ShapeDtypeStruct((8, d, 2 * f), dtype), "w2": jax.ShapeDtypeStruct((8, f, d), dtype)}
+
+
+@pytest.mark.parametrize("rows,backend,experts,mesh,compute,form", [
+    (2 * 8, "tpu", _experts(), None, "bfloat16", "kernel"),
+    (moe.KERNEL_ROWS_PER_EXPERT * 8, "tpu", _experts(), None, "bfloat16", "kernel"),
+    (moe.KERNEL_ROWS_PER_EXPERT * 8 + 1, "tpu", _experts(), None, "bfloat16", "grouped"),  # a prefill
+    (512 * 8, "tpu", _experts(), None, "bfloat16", "grouped"),
+    (2 * 8, "cpu", _experts(), None, "bfloat16", "grouped"),
+    (2 * 8, "gpu", _experts(), None, "bfloat16", "grouped"),
+    (2 * 8, "tpu", _experts(jnp.int8), None, "bfloat16", "grouped"),  # quantized for serving
+    (2 * 8, "tpu", _experts(jnp.float32), None, "float32", "grouped"),
+    (2 * 8, "tpu", _experts(), None, "float32", "grouped"),  # a cast copy of the stack is no place to stream from
+    (2 * 8, "tpu", _experts(), "a mesh", "bfloat16", "grouped"),
+    (2 * 8, "tpu", _experts(d=192), None, "bfloat16", "grouped"),
+    (2 * 8, "tpu", _experts(f=64), None, "bfloat16", "grouped"),
+], ids=["decode", "at-the-constant", "past-it", "prefill", "cpu", "gpu", "int8", "float32", "float32-compute",
+        "mesh", "d-not-lanes", "f-not-lanes"])
+def test_form_is_read_from_rows_backend_dtype_mesh_and_lanes(rows, backend, experts, mesh, compute, form):
+    cfg = dataclasses.replace(CFG, compute_dtype=compute)
+    assert moe.experts_form(rows, cfg, experts, mesh=mesh, backend=backend) == form
+    if backend == "cpu":  # what this process runs on, unasked
+        assert moe.experts_form(rows, cfg, experts, mesh=mesh) == form
+
+
+@pytest.fixture
+def on_a_tpu(monkeypatch):
+    """``moe.experts_form`` answers as on a TPU (the kernel is interpreted
+    here). A jitted program keeps the form it was traced with, so the caches
+    go before and after."""
+    monkeypatch.setattr(moe, "experts_form", functools.partial(moe.experts_form, backend="tpu"))
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.fixture(scope="module")
+def params():
+    return transformer.init_params(CFG, jax.random.key(0))
+
+
+def _decode(p, n_steps=2):
+    pools = transformer.make_paged_kv_pool(CFG, 16, 8)
+    tables = jnp.asarray(np.arange(1, 9).reshape(2, 4), jnp.int32)
+    return functools.partial(
+        paged.paged_decode_steps, p, pools, jnp.asarray([3, 5], jnp.int32), tables,
+        jnp.asarray([4, 9], jnp.int32), jax.random.key(1), CFG, n_steps=n_steps,
+    )
+
+
+def _prefill(p, tokens=64):
+    pools = transformer.make_paged_kv_pool(CFG, 32, 8)
+    pages = jnp.arange(1, 1 + 2 * tokens // 8, dtype=jnp.int32).reshape(2, -1)
+    return functools.partial(
+        paged._prefill_scatter_sample, p, pools, jnp.zeros((2, tokens), jnp.int32),
+        jnp.asarray([tokens, 11], jnp.int32), pages, jax.random.key(2), CFG, tokens, tokens // 8,
+    )
+
+
+def _primitives(fn):
+    """{primitive name: [name stacks]} over the whole jaxpr of ``fn()``."""
+    found = {}
+
+    def walk(jaxpr, outer):
+        for eqn in jaxpr.eqns:
+            path = f"{outer}/{eqn.source_info.name_stack}"  # a call's body names its scopes from the call on
+            found.setdefault(eqn.primitive.name, []).append(path)
+            if eqn.primitive.name == "pallas_call":
+                continue  # the kernel's own body
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, path)
+
+    walk(jax.make_jaxpr(fn)().jaxpr, "")
+    return found
+
+
+def test_decode_traces_the_kernel_under_moe_experts_and_prefill_keeps_ragged_dot(params, on_a_tpu):
+    decode = _primitives(_decode(params))
+    assert "ragged_dot" not in decode and "ragged_dot_general" not in decode
+    assert decode["pallas_call"] and all("moe.experts" in path for path in decode["pallas_call"])
+    prefill = _primitives(_prefill(params))  # 2 x 64 tokens x 2 choices over 8 experts: 32 rows an expert
+    assert "pallas_call" not in prefill
+    assert any(name.startswith("ragged_dot") for name in prefill)
+
+
+def test_off_the_tpu_every_program_keeps_the_grouped_form(params):
+    assert "pallas_call" not in _primitives(_decode(params))
+
+
+def test_decode_through_the_kernel_is_decode_through_the_grouped_form(params, on_a_tpu, monkeypatch):
+    tokens, _ = _decode(params, n_steps=4)()
+    monkeypatch.setattr(moe, "experts_form", lambda *a, **k: "grouped")
+    jax.clear_caches()
+    want, _ = _decode(params, n_steps=4)()
+    for got_leaf, want_leaf in zip(jax.tree.leaves(tokens), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(got_leaf, want_leaf)
+
+
+def test_engine_reports_the_decode_steps_form(params, on_a_tpu, caplog):
+    eng = ServingEngine(params, CFG, max_batch=2, n_blocks=16, block_size=8)
+    assert eng.decode_experts == eng.pool_info()["decode_experts"] == "kernel"
+    eng.submit([1, 2, 3, 4, 5], 4)
+    with caplog.at_level(logging.INFO, logger="pretraining_llm_tpu.serving"):
+        eng.run()
+    lines = [r.getMessage() for r in caplog.records if "engine empty" in r.getMessage()]
+    assert len(lines) == 1 and "experts (kernel) took" in lines[0]
+    # a batch whose decode step brings a prefill's rows an expert keeps the grouped form
+    wide = ServingEngine(params, CFG, max_batch=8 * moe.KERNEL_ROWS_PER_EXPERT, n_blocks=16, block_size=8)
+    assert wide.pool_info()["decode_experts"] == "grouped"
+
+
+def test_engine_off_the_tpu_and_without_experts(params):
+    assert ServingEngine(params, CFG, max_batch=2, n_blocks=16, block_size=8).pool_info()["decode_experts"] == "grouped"
+    dense = dataclasses.replace(CFG, n_experts=0, n_shared_experts=0, n_dense_layers=0, moe_routing="capacity")
+    eng = ServingEngine(transformer.init_params(dense, jax.random.key(0)), dense, max_batch=2, n_blocks=16, block_size=8)
+    assert "decode_experts" not in eng.pool_info()
+
+
+def test_gradient_through_the_layer_is_the_grouped_forms(on_a_tpu, monkeypatch):
+    """``loss_fn`` differentiates ``moe_mlp_dropless``: at a size the rule
+    hands to the kernel the VJP is the grouped form's."""
+    mlp = moe.init_dropless_params(CFG, jax.random.key(3), 0.02, jnp.bfloat16)
+    h = jax.random.normal(jax.random.key(4), (2, 4, CFG.d_model), jnp.bfloat16)
+    dense = lambda shared, hh: transformer._dense_mlp(shared, hh, CFG)
+
+    def grads():
+        loss = lambda m, x: jnp.sum(moe.moe_mlp_dropless(m, x, CFG, dense)[0].astype(jnp.float32) ** 2)
+        assert ("pallas_call" in str(jax.make_jaxpr(loss)(mlp, h))) == (moe.experts_form(0, CFG, mlp["experts"]) == "kernel")
+        return jax.grad(loss, (0, 1))(mlp, h)
+
+    got = grads()
+    monkeypatch.setattr(moe, "experts_form", lambda *a, **k: "grouped")
+    want = grads()
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.isfinite(np.asarray(g, np.float32)).all()
+        np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(w, np.float32), atol=2e-2, rtol=5e-2)

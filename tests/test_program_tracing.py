@@ -343,6 +343,35 @@ def test_mechanism_scope_is_in_the_lowered_program(mechanism_paths, program, sco
         assert all("mlp" in re.split(r"[/()]", p) for p in paths)
 
 
+def test_the_expert_kernel_sits_under_moe_experts(monkeypatch):
+    """Where ``moe.experts_form`` picks the kernel (a TPU, bfloat16 experts of whole lane tiles, a
+    decode step's rows) the call's trace path holds ``mlp`` and ``moe.experts``, which is what
+    ``moe_time_share.*`` and ``moe_experts_hbm_roofline.*`` sum; no grouped matmul is left."""
+    import functools
+
+    from pretraining_llm_tpu.models import moe
+
+    cfg = dataclasses.replace(
+        MECHANISM_CFG, d_model=128, d_expert=128, compute_dtype="bfloat16", param_dtype="bfloat16",
+        n_experts=8, hc_mult=1,
+    )
+    monkeypatch.setattr(moe, "experts_form", functools.partial(moe.experts_form, backend="tpu"))
+    jax.clear_caches()
+    try:
+        p = jax.eval_shape(lambda k: transformer.init_params(cfg, k), jax.random.key(0))
+        pools = jax.eval_shape(lambda: transformer.make_paged_kv_pool(cfg, 16, 8))
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+        text = paged.paged_decode_steps.lower(
+            p, pools, i32(2), i32(2, 4), i32(2), jax.eval_shape(lambda: jax.random.key(1)), cfg, n_steps=2
+        ).as_text(debug_info=True)
+    finally:
+        jax.clear_caches()
+    paths = set(re.findall(r'loc\("([^"]+)"', text))
+    kernel = [path for path in paths if "jit(_moe_call)" in path]
+    assert kernel and all({"mlp", "moe.experts"} <= set(re.split(r"[/()]", path)) for path in kernel)
+    assert not [path for path in paths if "ragged_dot" in path]
+
+
 def test_routing_counters_ride_the_window_and_reach_the_stats_and_the_commit_span(monkeypatch):
     p = transformer.init_params(MECHANISM_CFG, jax.random.key(0))
     rec = spans.SpanRecorder()
