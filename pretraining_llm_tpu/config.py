@@ -236,6 +236,16 @@ class ModelConfig:
     kda_head_dim: int = 0
     kda_conv_kernel: int = 4
     kda_gate_lower_bound: float = -5.0
+    # Multi-token prediction (DeepSeek-V3, arXiv:2412.19437 section 2.2): the
+    # depth of the module kept beside the stack, params["mtp"]. 1 = one module:
+    # an embedding norm, a hidden-state norm, a (2D, D) projection of the two
+    # concatenated, one whole decoder block of the stack's last kind with a
+    # cache layer of its own (index n_layers of every cache and pool), a final
+    # norm; embedding and head are the stack's. Its logits at position p
+    # predict token p + 2, which the serving engine takes as the draft of a
+    # speculative round (models/mtp.py; spec_k without a draft model). The
+    # training loss does not read it. 0 = none; the sources publish one.
+    mtp_depth: int = 0
     # Manifold-constrained hyper-connections (arXiv:2512.24880): hc_mult > 1
     # residual streams a token, read, written and mixed per sublayer by
     # coefficients computed in float32 (models/hyper.py). 1 = plain residual.
@@ -411,6 +421,21 @@ class ModelConfig:
                 )
         if self.attn_output_gate and not self.kv_lora_rank:
             raise ValueError("attn_output_gate is latent attention's (kv_lora_rank)")
+        if self.mtp_depth not in (0, 1):
+            raise ValueError(
+                f"mtp_depth={self.mtp_depth}: one multi-token-prediction module is built "
+                "(several chained: ROADMAP)"
+            )
+        if self.mtp_depth and (
+            self.layer_group_size or self.hc_mult > 1 or self.pipeline_stages > 1
+            or self.moe_capacity or self.moe_swiglu_limits or self.moe_shared_swiglu_limits
+            or self.pos_embed != "rope"
+        ):
+            raise ValueError(
+                "a multi-token-prediction module (mtp_depth) is one more block of a "
+                "homogeneous stack under RoPE: no hybrid stack, residual streams, pipeline, "
+                "capacity-routed experts, per-layer SwiGLU clamps or learned positions"
+            )
         if not 0 <= self.n_dense_layers < max(self.n_layers, 1) or (
             self.n_dense_layers and not self.n_experts
         ):
@@ -564,6 +589,12 @@ class ModelConfig:
         return tuple(runs)
 
     @property
+    def n_cache_layers(self) -> int:
+        """Layers of a decode cache or page pool: the stack's, then the
+        multi-token-prediction module's block."""
+        return self.n_layers + self.mtp_depth
+
+    @property
     def n_kda_layers(self) -> int:
         return sum(mixer == "kda" for mixer, _ in self.layer_kinds)
 
@@ -604,6 +635,9 @@ class ModelConfig:
         n += moe_layers * (shared + self._moe_params(self.experts_held))
         n += self.n_kda_layers * (self._kda_params() - self._attn_params())
         n += self._norm_params()  # final norm
+        # the module: a block of the stack's last kind, the (2D, D) projection, three norms
+        ffn = self._moe_params(self.experts_held) if self.n_experts else self._ffn_params(self.d_ff)
+        n += self.mtp_depth * (shared + ffn + 2 * d * d + 3 * self._norm_params())
         if not self.tie_embeddings:
             n += d * v
             if self.lm_head_bias:
@@ -671,6 +705,9 @@ class ModelConfig:
         if self.n_experts:
             inactive = self.experts_held - self.experts_per_token
             n -= (self.n_layers - self.n_dense_layers) * inactive * self._per_expert_params()
+        if self.mtp_depth:
+            # the training forward does not run the module
+            n -= self.num_params() - dataclasses.replace(self, mtp_depth=0).num_params()
         return n
 
     def flops_per_token(self) -> int:
@@ -1691,6 +1728,30 @@ _register(
             moe_score="sigmoid", moe_score_bias=True, moe_routed_scale=2.5, n_shared_experts=1,
             d_expert=32, n_dense_layers=1, moe_n_group=4, moe_topk_group=2,
             moe_swiglu_limits=(0.0, 0.0, 0.0, 0.0, 4.0, 4.0),
+        ),
+        mesh=MeshConfig(),
+        data=DataConfig(tokenizer_name="byte"),
+        train=TrainConfig(batch_size=8, train_steps=50, eval_interval=20, eval_iters=2, lr=1e-3),
+    ),
+)
+
+# Every mechanism of the JoyAI-LLM-Flash family at a width a CPU smoke run
+# holds: latent attention, a leading dense layer, sigmoid-routed dropless
+# experts (half of them held) with a shared one, and the multi-token-prediction
+# module that drafts for its own stack. The published widths are
+# benchmark/configs/joyai-llm-flash.json; this is for the unit tests and
+# serve.py (--spec-k 1 with no draft model).
+_register(
+    "joyai-mini",
+    Config(
+        model=ModelConfig(
+            vocab_size=256, context_length=256, d_model=64, n_heads=4, n_layers=3, d_head=24,
+            mlp_ratio=2.5, activation="swiglu", norm="rmsnorm", pos_embed="rope",
+            tie_embeddings=False, mlp_bias=False, norm_eps=1e-6,
+            kv_lora_rank=32, q_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            n_experts=16, n_experts_held=8, experts_per_token=2, moe_routing="dropless",
+            moe_score="sigmoid", moe_score_bias=True, moe_routed_scale=2.5, n_shared_experts=1,
+            d_expert=32, n_dense_layers=1, mtp_depth=1,
         ),
         mesh=MeshConfig(),
         data=DataConfig(tokenizer_name="byte"),

@@ -37,7 +37,7 @@ from pretraining_llm_tpu.generation.sampling import (
     sample_logits,
     sample_logits_fused,
 )
-from pretraining_llm_tpu.models import transformer
+from pretraining_llm_tpu.models import mtp, transformer
 from pretraining_llm_tpu.models.transformer import PagedInfo
 
 # Pool-key names <- their contiguous-cache counterparts (prefill writes a
@@ -243,10 +243,13 @@ def _prefill_last_logits(
         params, prompts, cfg, kv_cache=cache, cache_index=jnp.int32(0),
         return_pre_logits=True, lengths=lengths,
     )
-    last_h = jnp.take_along_axis(
-        hidden, jnp.broadcast_to(last_idx[:, None, None], (n_rows, 1, hidden.shape[-1])), axis=1
-    )
-    return transformer.lm_head(params, last_h, cfg)[:, 0], cache
+    return transformer.lm_head(params, _rows_at(hidden, last_idx), cfg)[:, 0], cache
+
+
+def _rows_at(hidden: jax.Array, idx: jax.Array) -> jax.Array:
+    """(N, 1, D): row ``idx[i]`` of each sequence of ``hidden`` (N, T, D)."""
+    n, _, d = hidden.shape
+    return jnp.take_along_axis(hidden, jnp.broadcast_to(idx[:, None, None], (n, 1, d)), axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("n_pages",), donate_argnums=(0,))
@@ -355,7 +358,7 @@ def prefill_into_pool(
     jax.jit,
     static_argnames=(
         "cfg", "p_bucket", "n_pages", "temperature", "top_k", "top_p",
-        "min_p", "mesh",
+        "min_p", "mesh", "with_draft",
     ),
     donate_argnums=(1,),
 )
@@ -375,6 +378,7 @@ def _prefill_scatter_sample(
     min_p: Optional[float] = None,
     mesh: Any = None,
     slots: Optional[jax.Array] = None,  # (N,) int32 state slots (pad rows: the scratch slot)
+    with_draft: bool = False,
 ) -> Tuple[jax.Array, transformer.KVCache]:
     """Batched admission in ONE device program: causal prefill over N
     padded prompts -> scatter every row's pages into the pools -> sample
@@ -390,6 +394,13 @@ def _prefill_scatter_sample(
     scratch block 0; duplicate scatter indices there are benign by the
     pool's scratch discipline. Pad ROWS (N rounded up to a bucket) carry
     all-zero tables and garbage tokens the caller slices away.
+
+    ``with_draft`` (a model with an MTP module, self-drafting): the module is
+    prefilled too, over the stack's hidden state of every prompt position and
+    the token that followed it (the prompt shifted by one, the token just
+    sampled behind its end), into its own layer of the same staged pages; the
+    first value is then (N, 2): each row's first token and its first draft,
+    the argmax of the module's logits at the prompt's last position.
     """
     from pretraining_llm_tpu.parallel.sharding import activation_mesh
 
@@ -399,11 +410,29 @@ def _prefill_scatter_sample(
         # (L, N, pages*bs, ...) fields: stacked.
         cache = _staging_cache(cfg, n_rows, p_bucket)
         idx = jnp.clip(prompt_lens - 1, 0, p_bucket - 1).astype(jnp.int32)
-        last, cache = _prefill_last_logits(params, prompts, idx, cfg, cache)
+        if with_draft:
+            # the head on the last positions only, for the stack and the module
+            # alike: the module reads every position's hidden state anyway
+            hidden, cache = transformer.forward(
+                params, prompts, cfg, kv_cache=cache, cache_index=jnp.int32(0),
+                return_pre_logits=True,
+            )
+            last = transformer.lm_head(params, _rows_at(hidden, idx), cfg)[:, 0]
+        else:
+            last, cache = _prefill_last_logits(params, prompts, idx, cfg, cache)
         toks = sample_logits(
             last, key, temperature=temperature, top_k=top_k, top_p=top_p,
             min_p=min_p,
         ).astype(jnp.int32)
+        if with_draft:
+            following = jnp.roll(prompts, -1, axis=1).at[jnp.arange(n_rows), idx].set(toks)
+            m_hidden, cache, _ = mtp.mtp_forward(
+                params, hidden, following, cfg, kv_cache=cache, cache_index=jnp.int32(0),
+                return_pre_logits=True,
+            )
+            with jax.named_scope("mtp.head"):
+                m_last = transformer.lm_head(params, _rows_at(m_hidden, idx), cfg)[:, 0]
+                toks = jnp.stack([toks, jnp.argmax(m_last, axis=-1).astype(jnp.int32)], axis=1)
 
         pools = _scatter_staged_pages(
             pools, cache, block_ids.reshape(-1), n_rows * n_pages, slots
@@ -425,6 +454,7 @@ def prefill_into_pool_batched(
     min_p: Optional[float] = None,
     mesh: Any = None,
     slots: Optional[Sequence[int]] = None,
+    with_draft: bool = False,
 ) -> Tuple[jax.Array, transformer.KVCache]:
     """Prefill N prompts and write all their pages into the pool in one
     device program; returns (first sampled token per prompt — a DEVICE
@@ -432,7 +462,9 @@ def prefill_into_pool_batched(
 
     ``rows_block_ids[i]`` must be exactly ceil(len(prompts[i])/block_size)
     pages. Rows and pages are bucketed (``prefill_bucket``). ``slots[i]`` is
-    prompt i's state slot (state-slot models).
+    prompt i's state slot (state-slot models). ``with_draft``: the model's MTP
+    module is prefilled too and the first value is (N, 2), first token and
+    first draft (``_prefill_scatter_sample``).
     """
     block_size = pool_block_size(pools, cfg)
     n = len(prompts)
@@ -464,6 +496,7 @@ def prefill_into_pool_batched(
         params, pools, jnp.asarray(prompt_arr), jnp.asarray(lens),
         jnp.asarray(ids_arr), key, cfg, p_bucket, bucket_pages,
         temperature, top_k, top_p, min_p, mesh, _slot_array(pools, slots, bucket_rows),
+        with_draft,
     )
     return toks[:n], pools
 
@@ -698,6 +731,58 @@ def paged_decode_step(
     return nxt, pools
 
 
+def _accept_reject(
+    drafts: jax.Array,  # (B, k) int32 proposals
+    q_dists: Optional[jax.Array],  # (B, k, V) the draft's distributions; None: each proposal was its argmax
+    t_logits: jax.Array,  # (B, k+1, V) the target's logits after the seed token and after each proposal
+    key: jax.Array,
+    temperature: float,
+) -> Tuple[jax.Array, jax.Array]:
+    """ONE accept/reject rule for both draft sources (a separate draft model:
+    ``paged_spec_round``; the model's own MTP module: ``paged_mtp_round``),
+    vectorized over rows -> (emit (B, k+1), n_emit (B,)): row b's output is
+    ``emit[b, :n_emit[b]]``, the accepted proposals and then the target's
+    correction or bonus token.
+
+    Greedy: a proposal is accepted iff it is the target's argmax, and the
+    closing token is the target's argmax at the first rejected position, so
+    the output equals target-only greedy decoding whatever was proposed.
+    Sampling: Leviathan's rule, accept ``d`` with probability
+    ``min(1, p(d) / q(d))`` and resample a rejection from ``norm(max(p - q,
+    0))``; a proposal that was the draft's argmax is a point mass (``q(d)`` =
+    1: accepted with probability ``p(d)``, resampled from ``p`` without ``d``),
+    so the output is distributed as target-only sampling either way."""
+    from pretraining_llm_tpu.generation.speculative import _probs
+
+    b, k = drafts.shape
+    v = t_logits.shape[-1]
+    rows = jnp.arange(b)
+    with jax.named_scope("spec.accept"):
+        if temperature == 0.0:
+            best = jnp.argmax(t_logits, axis=-1).astype(jnp.int32)  # (B, k+1)
+            accepts = best[:, :k] == drafts
+            n_acc = jnp.sum(jnp.cumprod(accepts.astype(jnp.int32), axis=1), axis=1).astype(jnp.int32)
+            final = best[rows, n_acc]
+        else:
+            p_dists = jax.vmap(jax.vmap(lambda l: _probs(l, temperature)))(t_logits)  # (B, k+1, V)
+            if q_dists is None:
+                q_dists = jax.nn.one_hot(drafts, v, dtype=jnp.float32)
+            _, sub_u, sub_r = jax.random.split(key, 3)
+            cols = jnp.arange(k)[None, :]
+            p_at = p_dists[rows[:, None], cols, drafts]  # (B, k)
+            q_at = q_dists[rows[:, None], cols, drafts]
+            u = jax.random.uniform(sub_u, (b, k))
+            accepts = u < jnp.minimum(1.0, p_at / jnp.maximum(q_at, 1e-30))
+            n_acc = jnp.sum(jnp.cumprod(accepts.astype(jnp.int32), axis=1), axis=1).astype(jnp.int32)
+            # the bonus position: residual against q = 0 is p itself
+            q_pad = jnp.concatenate([q_dists, jnp.zeros((b, 1, v), jnp.float32)], axis=1)
+            resid = jnp.maximum(p_dists[rows, n_acc] - q_pad[rows, n_acc], 0.0)
+            resid = resid / jnp.maximum(jnp.sum(resid, axis=-1, keepdims=True), 1e-30)
+            final = jax.random.categorical(sub_r, jnp.log(resid + 1e-30)).astype(jnp.int32)
+        emit = jnp.concatenate([drafts, jnp.zeros((b, 1), jnp.int32)], axis=1)  # (B, k+1)
+        return emit.at[rows, n_acc].set(final), n_acc + 1
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("cfg_t", "cfg_d", "k", "temperature", "mesh"),
@@ -739,9 +824,6 @@ def paged_spec_round(
     """
     from pretraining_llm_tpu.generation.speculative import _probs
     from pretraining_llm_tpu.parallel.sharding import activation_mesh
-
-    b = tokens.shape[0]
-    v = cfg_t.vocab_size
 
     with activation_mesh(mesh):
         # --- draft: k proposal steps (no extra write-only step needed —
@@ -790,45 +872,120 @@ def paged_spec_round(
             params_t, seq_tokens, cfg_t, kv_cache=t_pools,
             paged=transformer.PagedInfo(block_tables, seq_lens),
         )  # (B, k+1, V)
-        p_dists = jax.vmap(
-            jax.vmap(lambda l: _probs(l, temperature))
-        )(t_logits)  # (B, k+1, V)
+        emit, n_emit = _accept_reject(drafts, q_dists, t_logits, key, temperature)
+        return emit, n_emit, t_pools, d_pools
 
-        # --- accept / reject (vectorized over rows) -------------------
-        key, sub_u, sub_r = jax.random.split(key, 3)
-        rows = jnp.arange(b)[:, None]
-        cols = jnp.arange(k)[None, :]
-        p_at = p_dists[rows, cols, drafts]  # (B, k)
-        q_at = q_dists[rows, cols, drafts]
-        if temperature == 0.0:
-            accepts = p_at > 0.0
-        else:
-            u = jax.random.uniform(sub_u, (b, k))
-            accepts = u < jnp.minimum(1.0, p_at / jnp.maximum(q_at, 1e-30))
-        n_acc = jnp.sum(
-            jnp.cumprod(accepts.astype(jnp.int32), axis=1), axis=1
-        ).astype(jnp.int32)  # (B,)
 
-        p_final = p_dists[jnp.arange(b), n_acc]  # (B, V)
-        if temperature == 0.0:
-            final = jnp.argmax(p_final, axis=-1).astype(jnp.int32)
-        else:
-            q_pad = jnp.concatenate(
-                [q_dists, jnp.zeros((b, 1, v), jnp.float32)], axis=1
+def _mtp_verify(params, pools, seq_tokens, block_tables, seq_lens, cfg):
+    """The verify half of a self-drafting round: the stack over ``seq_tokens``
+    (B, 2) at positions ``seq_lens``, ``seq_lens + 1`` through the pool ->
+    (logits (B, 2, V), pools, the hidden state that feeds the module (B, 2, D),
+    tokens per expert of the expert layers or None)."""
+    out = transformer.forward(
+        params, seq_tokens, cfg, kv_cache=pools, paged=PagedInfo(block_tables, seq_lens),
+        return_hidden=True, return_moe_counts=cfg.moe_dropless,
+    )
+    return out[0], out[1], out[2]["final_hidden"], out[3] if cfg.moe_dropless else None
+
+
+def _round_counters(cfg: ModelConfig, counts: Any, m_counts: Any) -> Any:
+    """A round's routing counters, the stack's expert layers and then the
+    module's block, as ``paged_decode_steps`` gives a window's; None for a
+    model without dropless experts."""
+    if not cfg.moe_dropless:
+        return None
+    counts = jnp.concatenate([counts, m_counts[None]], axis=0)
+    return {
+        "expert_tokens": counts,
+        "experts_touched": jnp.sum((counts > 0).astype(jnp.int32), axis=-1),
+    }
+
+
+@functools.partial(
+    jax.jit, static_argnames=("cfg", "temperature", "mesh"), donate_argnums=(1,)
+)
+def paged_mtp_round(
+    params: Any,
+    pools: transformer.KVCache,
+    tokens: jax.Array,  # (B,) int32: each row's newest committed token, x_s
+    drafts: jax.Array,  # (B,) int32: each row's pending draft of x_{s+1}
+    block_tables: jax.Array,  # (B, max_blocks) int32
+    seq_lens: jax.Array,  # (B,) int32: s, tokens before it are cached in every layer
+    key: jax.Array,
+    cfg: ModelConfig,
+    temperature: float = 0.0,
+    mesh: Any = None,
+) -> Tuple[jax.Array, jax.Array, jax.Array, Any, transformer.KVCache]:
+    """One self-drafting speculative round for every batch row: the model's
+    own multi-token-prediction module is the draft (``models/mtp.py``), its
+    cache one more layer of the same pool under the same block tables.
+
+    Verify, then draft (the module needs the stack's hidden state of the
+    positions just verified and the token that followed each):
+
+    - verify: the stack over ``[x_s, d]`` at positions ``s, s + 1`` in one
+      two-query forward through the pool; ``_accept_reject`` (the rule
+      ``paged_spec_round`` uses) emits ``[y]`` if ``d`` is rejected, ``[d, y']``
+      if accepted;
+    - draft: the module over ``(h_s, emit[0])`` and ``(h_{s+1}, emit[1])`` at
+      the same positions; the next draft is the argmax of its logits at the
+      last accepted position (a point-mass proposal, whatever the
+      temperature). After a rejection the second position holds garbage in
+      both caches, above the new frontier ``s + 1`` and overwritten by the
+      next round: ``paged_spec_round``'s slot-reuse discipline.
+
+    Returns (emit (B, 2), n_emit (B,), next draft (B,), routing counters or
+    None, pools). The module's cache covers the positions the stack's does
+    after every round, and no shape depends on what was accepted. Greedy
+    output equals target-only paged decoding row for row.
+
+    One device program, so that a round is one dispatch; its two halves carry
+    the device scopes ``spec.verify`` and ``mtp.draft`` (``spec.accept``
+    between them), and the engine spans the dispatch as ``serving.spec_round``."""
+    from pretraining_llm_tpu.parallel.sharding import activation_mesh
+
+    b = tokens.shape[0]
+    with activation_mesh(mesh):
+        with jax.named_scope("spec.verify"):
+            t_logits, pools, hidden, counts = _mtp_verify(
+                params, pools, jnp.stack([tokens, drafts], axis=1), block_tables, seq_lens, cfg
             )
-            resid = jnp.maximum(p_final - q_pad[jnp.arange(b), n_acc], 0.0)
-            resid = resid / jnp.maximum(
-                jnp.sum(resid, axis=-1, keepdims=True), 1e-30
+        emit, n_emit = _accept_reject(drafts[:, None], None, t_logits, key, temperature)
+        with jax.named_scope("mtp.draft"):
+            m_hidden, pools, m_counts = mtp.mtp_forward(
+                params, hidden, emit, cfg, kv_cache=pools,
+                paged=PagedInfo(block_tables, seq_lens), return_pre_logits=True,
             )
-            final = jax.random.categorical(
-                sub_r, jnp.log(resid + 1e-30)
-            ).astype(jnp.int32)
+            with jax.named_scope("mtp.head"):
+                m_logits = transformer.lm_head(params, m_hidden[jnp.arange(b), n_emit - 1][:, None], cfg)
+                nxt = jnp.argmax(m_logits[:, 0], axis=-1).astype(jnp.int32)
+        return emit, n_emit, nxt, _round_counters(cfg, counts, m_counts), pools
 
-        emit = jnp.concatenate(
-            [drafts, jnp.zeros((b, 1), jnp.int32)], axis=1
-        )  # (B, k+1)
-        emit = emit.at[jnp.arange(b), n_acc].set(final)
-        return emit, n_acc + 1, t_pools, d_pools
+
+@functools.partial(jax.jit, static_argnames=("cfg", "mesh"), donate_argnums=(1,))
+def paged_mtp_logits(
+    params: Any,
+    pools: transformer.KVCache,
+    seq_tokens: jax.Array,  # (B, 2) int32: the tokens at positions s, s + 1
+    following: jax.Array,  # (B, 2) int32: the tokens at s + 1, s + 2
+    block_tables: jax.Array,
+    seq_lens: jax.Array,
+    cfg: ModelConfig,
+    mesh: Any = None,
+) -> Tuple[jax.Array, jax.Array, transformer.KVCache]:
+    """The round's two forwards without its decisions, for a caller that
+    forces the tokens: the stack's logits (B, 2, V) over ``seq_tokens`` and
+    the module's (B, 2, V) over the stack's own hidden states and
+    ``following``, both through the pool (what ``paged_decode_logits`` is to
+    the decode step)."""
+    from pretraining_llm_tpu.parallel.sharding import activation_mesh
+
+    with activation_mesh(mesh):
+        t_logits, pools, hidden, _ = _mtp_verify(params, pools, seq_tokens, block_tables, seq_lens, cfg)
+        m_logits, pools, _ = mtp.mtp_forward(
+            params, hidden, following, cfg, kv_cache=pools, paged=PagedInfo(block_tables, seq_lens)
+        )
+        return t_logits, m_logits, pools
 
 
 @functools.partial(

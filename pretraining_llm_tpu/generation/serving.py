@@ -23,6 +23,14 @@ cheaper than a recompile, and XLA sees a static (max_batch,) program
 forever. The reference has no serving stack (batch-1 fixed-count
 generate, /root/reference/src/models/transformer.py:96-114).
 
+Speculative serving (``spec_k``): a round in place of a decode window, one to
+``spec_k + 1`` tokens a row. The draft is a separate model with a pool of its
+own (``draft_params`` + ``draft_cfg``: ``paged.paged_spec_round``, per-head
+pools), or, with neither, the model's own multi-token-prediction module
+(``cfg.mtp_depth``, ``spec_k=1``: ``paged.paged_mtp_round``), whose cache is
+one more layer of the same pool under the same block tables; a row then
+carries its pending draft (``drafts``) beside ``tokens`` and ``seq_lens``.
+
 Deep pipelining: the run() scheduler keeps a depth-``pipeline_depth``
 queue of dispatched-but-unreaped decode windows. Window k+1's input
 tokens chain from window k's last column ON DEVICE, host ``seq_lens``
@@ -122,7 +130,8 @@ class _Window:
     emit: Any = None                # spec: (B, k+1) device emissions
     n_emit: Any = None              # spec: (B,) device per-row emit counts
     seq_dev: Any = None             # spec: (B,) device frontier at dispatch
-    moe: Any = None                 # decode, dropless experts: routing counters (device)
+    draft: Any = None               # spec, self-drafting: (B,) device next drafts
+    moe: Any = None                 # dropless experts: the window's routing counters (device)
     t_dispatch: float = 0.0         # perf_counter at dispatch (trace spans)
 
 
@@ -198,32 +207,55 @@ class ServingEngine:
             # A latent (MLA) page pool: what is not built on it yet (ROADMAP).
             refused = {
                 "quantize": quantize != "none", "prefix_cache": prefix_cache,
-                "kv_checksum": kv_checksum, "spec_k": bool(spec_k),
+                "kv_checksum": kv_checksum,
+                "draft_params": draft_params is not None or draft_cfg is not None,
             }
             if any(refused.values()):
                 raise ValueError(
                     "a latent-attention model is served without "
                     + ", ".join(k for k, v in refused.items() if v)
                     + ": int8 pages, the prefix cache (and kv_transfer, which "
-                    "publishes into it) and speculative decoding are not built "
-                    "on the latent pool yet"
+                    "publishes into it) and a separate draft model's pool are not "
+                    "built on the latent pool yet (spec_k with the model's own "
+                    "multi-token-prediction module as the draft is)"
                 )
         if cfg.doc_mask_token >= 0:
             # Decode sessions are single documents; forward() rejects the
             # combination with a cache (same sanitization as generate()).
             cfg = dataclasses.replace(cfg, doc_mask_token=-1)
-        # Speculative serving: a draft model proposes spec_k tokens per
-        # round, the target verifies them in ONE multi-token paged
-        # forward (paged.paged_spec_round). Greedy output equals
-        # target-only serving; decode dispatches drop ~(k+1)x at the
-        # draft's acceptance rate.
+        # Speculative serving: a draft proposes spec_k tokens per round, the
+        # target verifies them in ONE multi-token paged forward. Greedy
+        # output equals target-only serving; decode dispatches drop ~(k+1)x
+        # at the draft's acceptance rate. Two draft sources: a separate draft
+        # model with a pool of its own (draft_params + draft_cfg:
+        # paged.paged_spec_round), or, with neither, the model's own
+        # multi-token-prediction module (cfg.mtp_depth), whose cache is one
+        # more layer of the SAME pool (self-drafting: paged.paged_mtp_round).
         if spec_k < 0:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
-        if (spec_k > 0) != (draft_params is not None and draft_cfg is not None):
+        self.self_draft = bool(spec_k and cfg.mtp_depth and draft_params is None and draft_cfg is None)
+        if self.self_draft:
+            if spec_k > cfg.mtp_depth:
+                raise ValueError(
+                    f"spec_k={spec_k} exceeds the model's multi-token-prediction depth "
+                    f"(mtp_depth={cfg.mtp_depth}): self-drafting proposes one token a module"
+                )
+            if prefill_chunk_tokens or prefix_cache:
+                raise ValueError(
+                    "self-drafting (spec_k with the model's own MTP module) is served "
+                    "without prefill_chunk_tokens and prefix_cache: the chunk and suffix "
+                    "lanes do not prefill the module"
+                )
+        elif (spec_k > 0) != (draft_params is not None and draft_cfg is not None):
             raise ValueError(
                 "speculative serving needs all three of draft_params, "
-                "draft_cfg and spec_k >= 1 (or none of them)"
+                "draft_cfg and spec_k >= 1 (or none of them); without a draft "
+                "model, spec_k needs a model with a multi-token-prediction "
+                "module (mtp_depth) to draft for itself"
             )
+        if cfg.mtp_depth and not self.self_draft:
+            # nobody reads the module: serve the stack alone, without its pages
+            cfg = dataclasses.replace(cfg, mtp_depth=0)
         # Decode-fused sampling (default): token selection runs INSIDE
         # the jitted decode window, so each window ships (B, n) token ids
         # (plus an optional (B, n, k) logprob sliver) back to the host
@@ -257,7 +289,13 @@ class ServingEngine:
         self.spec_k = int(spec_k)
         self.draft_params = draft_params
         self.draft_cfg: Optional[ModelConfig] = None
-        if spec_k:
+        if spec_k and (top_k or top_p or min_p):
+            raise ValueError(
+                "speculative serving supports temperature-only "
+                "sampling (the accept/reject rule needs the raw "
+                "draft/target distributions)"
+            )
+        if spec_k and not self.self_draft:
             if draft_cfg.vocab_size != cfg.vocab_size:
                 raise ValueError(
                     f"draft vocab ({draft_cfg.vocab_size}) must equal "
@@ -265,12 +303,6 @@ class ServingEngine:
                 )
             if draft_cfg.n_experts:
                 raise ValueError("draft model cannot be MoE (same rule)")
-            if top_k or top_p or min_p:
-                raise ValueError(
-                    "speculative serving supports temperature-only "
-                    "sampling (the accept/reject rule needs the raw "
-                    "draft/target distributions)"
-                )
             if draft_cfg.doc_mask_token >= 0:
                 draft_cfg = dataclasses.replace(draft_cfg, doc_mask_token=-1)
             self.draft_cfg = draft_cfg
@@ -306,7 +338,7 @@ class ServingEngine:
             # raw bf16/fp32 trees are quantized here for direct callers.
             if not quantize_mod.is_quantized(params):
                 params = quantize_mod.quantize_params_for_serving(params, cfg)
-            if self.spec_k and not quantize_mod.is_quantized(self.draft_params):
+            if self.draft_cfg is not None and not quantize_mod.is_quantized(self.draft_params):
                 self.draft_params = quantize_mod.quantize_params_for_serving(
                     self.draft_params, self.draft_cfg
                 )
@@ -315,10 +347,12 @@ class ServingEngine:
         # How a decode step reads the pool, fixed for the engine's programs:
         # what its input and the backend allow (models/mla.py::decode_form for
         # a latent pool, transformer.paged_attention_form for a per-head one).
+        # (A self-drafting round verifies spec_k + 1 queries a row.)
+        queries = self.spec_k + 1 if self.self_draft else 1
         self.decode_attention = (
-            mla.decode_form(1) if cfg.kv_lora_rank
+            mla.decode_form(queries) if cfg.kv_lora_rank
             else transformer.paged_attention_form(
-                cfg, 1, cfg.kv_cache_dtype == "int8", mesh=mesh
+                cfg, queries, cfg.kv_cache_dtype == "int8", mesh=mesh
             )
         )
         # And how a decode step (max_batch rows, K choices each) runs a dropless
@@ -330,7 +364,7 @@ class ServingEngine:
                 if "experts" in stack["mlp"]
             )
             self.decode_experts = moe.experts_form(
-                int(max_batch) * cfg.experts_per_token, cfg, experts, mesh=mesh
+                queries * int(max_batch) * cfg.experts_per_token, cfg, experts, mesh=mesh
             )
         self.max_batch = int(max_batch)
         self.block_size = int(block_size)
@@ -464,12 +498,17 @@ class ServingEngine:
         # Draft pools mirror the block structure exactly: SAME table/ids,
         # draft-model dims per block (paged_spec_round's shared-frontier
         # contract).
-        self.d_pools = _build_pool(self.draft_cfg) if self.spec_k else None
+        self.d_pools = _build_pool(self.draft_cfg) if self.draft_cfg is not None else None
         self.n_blocks = int(n_blocks)
         self.alloc = paged.BlockAllocator(n_blocks)
         self.tables = np.zeros((self.max_batch, self.max_blocks), np.int32)
         self.seq_lens = np.zeros((self.max_batch,), np.int32)
         self.tokens = np.zeros((self.max_batch,), np.int32)
+        # Self-drafting: each row's pending draft of the token after
+        # ``tokens[row]``, proposed by the module in the prefill or the round
+        # that committed ``tokens[row]``; recomputed with the prompt when a
+        # preempted request is admitted again.
+        self.drafts = np.zeros((self.max_batch,), np.int32)
         self.rows: List[Optional[_Request]] = [None] * self.max_batch
         self.waiting: deque = deque()
         self.finished: Dict[int, List[int]] = {}
@@ -629,7 +668,11 @@ class ServingEngine:
                 state_slots=self.max_batch, state_bytes=state,
                 bytes_per_slot=state // (self.max_batch + 1),
             )
+        if self.self_draft:
+            # the module's pages are one more layer of ``pools``, counted above
+            info["draft"] = "mtp"
         if self.d_pools is not None:
+            info["draft"] = "model"
             info["draft_pool_bytes"] = int(
                 sum(leaf.nbytes for leaf in jax.tree.leaves(self.d_pools))
             )
@@ -1005,7 +1048,7 @@ class ServingEngine:
             return toks[0], None, toks[1]
         return toks, None, None
 
-    def _count_moe(self, counters: Any, n_steps: int) -> Dict[str, int]:
+    def _count_moe(self, counters: Any, n_steps: int, queries: int = 1) -> Dict[str, int]:
         """Add a reaped window's routing counters into the stats:
         ``moe_expert_tokens`` (expert layers, E) tokens routed to each expert,
         ``moe_experts_touched`` (expert layers,) experts that got a token,
@@ -1015,7 +1058,9 @@ class ServingEngine:
         steps, expert layers, experts a layer (those held), experts touched,
         pairs routed (every row's choices, wherever the expert lives), pairs
         that met an expert held here, and the busiest expert's pairs summed
-        over layers."""
+        over layers. A self-drafting round is one step of ``queries`` tokens a
+        row, the module's block one more expert layer (its experts touched
+        also by themselves, ``mtp_touched``)."""
         tokens = np.asarray(counters["expert_tokens"], np.int64)
         touched = np.asarray(counters["experts_touched"], np.int64)
         st = self.stats
@@ -1026,12 +1071,16 @@ class ServingEngine:
         st["moe_expert_tokens"] += tokens
         st["moe_experts_touched"] += touched
         st["moe_steps"] += n_steps
-        routed = n_steps * self.max_batch * self.cfg.experts_per_token * tokens.shape[0]
-        return dict(
+        routed = n_steps * queries * self.max_batch * self.cfg.experts_per_token * tokens.shape[0]
+        meta = dict(
             moe_steps=n_steps, moe_layers=tokens.shape[0], moe_experts=tokens.shape[1],
             moe_touched=int(touched.sum()), moe_routed=routed, moe_routed_here=int(tokens.sum()),
             moe_busiest=int(tokens.max(axis=-1).sum()),
         )
+        if self.self_draft:
+            # the last expert layer counted is the module's block
+            meta["mtp_touched"] = int(touched[-1])
+        return meta
 
     def _spec_step(self) -> bool:
         """One speculative round for every active row: k draft proposals,
@@ -1045,16 +1094,16 @@ class ServingEngine:
         if self._n_decode_rows() == 0:  # everyone preempted (tiny pool)
             return False
         paged.check_paged_bounds(self.tables, self.seq_lens, self.block_size)
+        self._count_attention_pages(k + 1)  # the verify's k + 1 queries a row
         self._key, sub = jax.random.split(self._key)
-        emit, n_emit, self.pools, self.d_pools = paged.paged_spec_round(
-            self.params, self.pools, self.d_pools, self.draft_params,
-            jnp.asarray(self.tokens), jnp.asarray(self.tables),
-            jnp.asarray(self.seq_lens), sub, cfg_t=self.cfg,
-            cfg_d=self.draft_cfg, k=k, temperature=self.temperature,
-            mesh=self.mesh,
+        emit, n_emit, draft, moe = self._spec_round(
+            jnp.asarray(self.tokens), jnp.asarray(self.drafts), jnp.asarray(self.seq_lens), sub
         )
         emit = np.asarray(emit)  # (B, k+1)
         n_emit = np.asarray(n_emit)  # (B,)
+        draft = None if draft is None else np.asarray(draft)
+        if moe is not None:
+            self._count_moe(moe, 1, queries=k + 1)
         self.stats["steps"] += 1
         self.stats["spec_rounds"] = self.stats.get("spec_rounds", 0) + 1
         self.stats["spec_proposed"] = (
@@ -1066,10 +1115,37 @@ class ServingEngine:
             self.stats["spec_accepted"] = (
                 self.stats.get("spec_accepted", 0) + int(n_emit[row]) - 1
             )
+            if draft is not None:
+                self.drafts[row] = draft[row]
             self._consume_tokens(
                 req, row, emit[row, : int(n_emit[row])], advance_seq=True
             )
         return True
+
+    def _spec_round(self, base, draft_dev, seq_dev, key):
+        """ONE definition of a speculative round's device dispatch for the
+        synchronous and pipelined schedulers -> device ``(emit (B, k+1),
+        n_emit (B,), next drafts (B,) or None, routing counters or None)``.
+        Self-drafting runs ``paged.paged_mtp_round`` over the one pool (the
+        model's MTP module proposes; ``draft_dev`` is each row's pending
+        draft); a separate draft model runs ``paged.paged_spec_round`` over
+        both pools."""
+        with _spans.span(
+            "serving.spec_round", k=self.spec_k, draft="mtp" if self.self_draft else "model"
+        ):
+            tables = jnp.asarray(self.tables)
+            if self.self_draft:
+                emit, n_emit, draft, moe, self.pools = paged.paged_mtp_round(
+                    self.params, self.pools, base, draft_dev, tables, seq_dev, key,
+                    cfg=self.cfg, temperature=self.temperature, mesh=self.mesh,
+                )
+                return emit, n_emit, draft, moe
+            emit, n_emit, self.pools, self.d_pools = paged.paged_spec_round(
+                self.params, self.pools, self.d_pools, self.draft_params,
+                base, tables, seq_dev, key, cfg_t=self.cfg, cfg_d=self.draft_cfg,
+                k=self.spec_k, temperature=self.temperature, mesh=self.mesh,
+            )
+            return emit, n_emit, None, None
 
     def run(self, *, pipeline: bool = True) -> Dict[int, List[int]]:
         """Drive the engine until every submitted request has finished.
@@ -1272,51 +1348,54 @@ class ServingEngine:
         paged.check_paged_bounds(
             self.tables[active], seq_committed[active], self.block_size
         )
+        self._count_attention_pages(k + 1)  # from the committed lengths
         with self._clock.span(
             "dispatch", "serving.dispatch_window",
-            steps=k + 1, window=self.stats["windows"],
+            steps=k + 1, window=self.stats["windows"], kind="spec",
         ):
             if self._inflight:
                 prev = self._inflight[-1]
                 base, seq_dev = speculative.spec_next_inputs(
                     prev.emit, prev.n_emit, prev.seq_dev
                 )
+                draft_dev = prev.draft  # the module's proposal behind ``base``
             else:
                 base = jnp.asarray(self.tokens)
                 seq_dev = jnp.asarray(self.seq_lens)
-            base, seq_dev = self._merge_admitted(base, seq_dev)
+                draft_dev = jnp.asarray(self.drafts) if self.self_draft else None
+            base, seq_dev, draft_dev = self._merge_admitted(base, seq_dev, draft_dev)
             self._key, sub = jax.random.split(self._key)
-            emit, n_emit, self.pools, self.d_pools = paged.paged_spec_round(
-                self.params, self.pools, self.d_pools, self.draft_params,
-                base, jnp.asarray(self.tables), seq_dev, sub,
-                cfg_t=self.cfg, cfg_d=self.draft_cfg, k=k,
-                temperature=self.temperature, mesh=self.mesh,
-            )
+            emit, n_emit, draft, moe = self._spec_round(base, draft_dev, seq_dev, sub)
         self.stats["steps"] += 1
         self.stats["windows"] += 1
         self.stats["spec_rounds"] = self.stats.get("spec_rounds", 0) + 1
         snapshot = [(i, self.rows[i]) for i in active]
         self._inflight.append(
             _Window(kind="spec", snapshot=snapshot, n=k + 1,
-                    emit=emit, n_emit=n_emit, seq_dev=seq_dev,
-                    t_dispatch=time.perf_counter())
+                    emit=emit, n_emit=n_emit, seq_dev=seq_dev, draft=draft,
+                    moe=moe, t_dispatch=time.perf_counter())
         )
 
-    def _merge_admitted(self, base, seq_dev=None):
+    def _merge_admitted(self, base, seq_dev=None, draft_dev=None):
         """Splice rows admitted since the last dispatch into the chained
-        device inputs: their prefill-sampled first token, and (spec mode)
+        device inputs: their prefill-sampled first token, (spec mode)
         their committed frontier — a released row's stale chain values
         are otherwise garbage by design (zero tables scratch its writes),
-        but a RE-ADMITTED row must restart from committed host state."""
-        for toks_dev, idxs, rows in self._pending_admit_merges:
-            r = jnp.asarray(rows, jnp.int32)
-            base = base.at[r].set(toks_dev[jnp.asarray(idxs, jnp.int32)])
+        but a RE-ADMITTED row must restart from committed host state — and
+        (self-drafting) the first draft their prefill proposed. The decode
+        window takes ``base`` alone; a spec round all three (``draft_dev``
+        None without self-drafting)."""
+        for toks_dev, idxs, rows, drafts_dev in self._pending_admit_merges:
+            r, i = jnp.asarray(rows, jnp.int32), jnp.asarray(idxs, jnp.int32)
+            base = base.at[r].set(toks_dev[i])
             if seq_dev is not None:
                 seq_dev = seq_dev.at[r].set(
                     jnp.asarray(self.seq_lens[np.asarray(rows)], jnp.int32)
                 )
+            if draft_dev is not None:
+                draft_dev = draft_dev.at[r].set(drafts_dev[i])
         self._pending_admit_merges = []
-        return base if seq_dev is None else (base, seq_dev)
+        return base if seq_dev is None else (base, seq_dev, draft_dev)
 
     def _reap_window(self, w: _Window) -> None:
         """Materialize a window's tokens and do the lagged bookkeeping:
@@ -1332,6 +1411,9 @@ class ServingEngine:
                 if w.kind == "spec":
                     emit = np.asarray(w.emit)      # (B, k+1) — THE sync point
                     n_emit = np.asarray(w.n_emit)  # (B,)
+                    draft = None if w.draft is None else np.asarray(w.draft)
+                    if w.moe is not None:
+                        moe_meta = self._count_moe(w.moe, 1, queries=w.n)
                 else:
                     window = np.asarray(w.toks)    # (B, n) — THE sync point
                     lp_host = None
@@ -1353,9 +1435,10 @@ class ServingEngine:
             if self.state_slots:
                 # slots owned as the window commits: a row's slot is its row
                 moe_meta["state_slots"] = self.n_active
+            spec0 = (self.stats.get("spec_proposed", 0), self.stats.get("spec_accepted", 0))
             with self._clock.span(
                 "commit", "serving.commit", rows=len(w.snapshot), **moe_meta
-            ):
+            ) as commit:
                 for row, req in w.snapshot:
                     if req.row != row or self.rows[row] is not req:
                         # The row finished in an earlier reap and may have
@@ -1397,6 +1480,8 @@ class ServingEngine:
                         self.stats["spec_accepted"] = (
                             self.stats.get("spec_accepted", 0) + ne - 1
                         )
+                        if draft is not None:
+                            self.drafts[row] = draft[row]
                         self._consume_tokens(
                             req, row, emit[row, :ne], advance_seq=False
                         )
@@ -1406,6 +1491,14 @@ class ServingEngine:
                             lp=None if lp_host is None
                             else (lp_host[0][row], lp_host[1][row]),
                         )
+                if w.kind == "spec":
+                    # the round's own counts, for the rows that were still live:
+                    # drafts verified, drafts accepted, tokens committed
+                    commit.set(
+                        spec_proposed=self.stats.get("spec_proposed", 0) - spec0[0],
+                        spec_accepted=self.stats.get("spec_accepted", 0) - spec0[1],
+                        spec_emitted=self.stats["tokens"] - toks_before,
+                    )
             if self.capacity is not None:
                 # Occupancy sample AT the reap sync point: every value is
                 # host state this method already touched (row snapshot,
@@ -1869,8 +1962,13 @@ class ServingEngine:
                         top_p=self.top_p, min_p=self.min_p, mesh=self.mesh,
                         # the state is written with the pages, into the row's own slot
                         slots=[r.row for r in part] if self.state_slots else None,
+                        # self-drafting: the module's pages and each row's first draft too
+                        with_draft=self.self_draft,
                     )
-                    if self.spec_k:
+                    drafts_dev = None
+                    if self.self_draft:
+                        toks_dev, drafts_dev = toks_dev[:, 0], toks_dev[:, 1]
+                    if self.d_pools is not None:
                         # The draft cache must cover the same pages (its sampled
                         # tokens are discarded — the target's first token above is
                         # the round seed either way).
@@ -1879,7 +1977,7 @@ class ServingEngine:
                             prefill_ids, sub, temperature=self.temperature,
                             mesh=self.mesh,
                         )
-                    groups.append((part, toks_dev))
+                    groups.append((part, toks_dev, drafts_dev))
                 if hits:
                     self._key, sub = jax.random.split(self._key)
                     bs = self.block_size
@@ -1892,7 +1990,7 @@ class ServingEngine:
                         top_k=self.top_k, top_p=self.top_p, min_p=self.min_p,
                         mesh=self.mesh,
                     )
-                    if self.spec_k:
+                    if self.d_pools is not None:
                         # Shared block ids index BOTH pools, so the draft's prefix
                         # KV is already resident too — suffix-only there as well.
                         _, self.d_pools = paged.prefill_suffix_into_pool_batched(
@@ -1900,7 +1998,7 @@ class ServingEngine:
                             suffixes, tables_rows, cached_lens, sub,
                             temperature=self.temperature, mesh=self.mesh,
                         )
-                    groups.append((hits, toks_dev))
+                    groups.append((hits, toks_dev, None))
             if self.traces:
                 # Host-side prefill span (dispatch + any compile; the async
                 # device compute itself overlaps the next windows). Batched
@@ -1916,17 +2014,19 @@ class ServingEngine:
                         )
             self.stats["tokens"] += len(admits)  # the prefill-sampled firsts
             if defer:
-                for group, toks_dev in groups:
+                for group, toks_dev, drafts_dev in groups:
                     for i, req in enumerate(group):
                         req.pending_first = (toks_dev, i)
                     # Next dispatch merges these device scalars into its input
-                    # tokens without a host round trip.
+                    # tokens (and drafts) without a host round trip.
                     self._pending_admit_merges.append(
-                        (toks_dev, list(range(len(group))), [r.row for r in group])
+                        (toks_dev, list(range(len(group))), [r.row for r in group], drafts_dev)
                     )
                 return
-            for group, toks_dev in groups:
+            for group, toks_dev, drafts_dev in groups:
                 toks = np.asarray(toks_dev)
+                if drafts_dev is not None:
+                    self.drafts[[r.row for r in group]] = np.asarray(drafts_dev)
                 for i, req in enumerate(group):
                     tok = int(toks[i])
                     req.generated.append(tok)
@@ -2008,7 +2108,7 @@ class ServingEngine:
                 # each chunk starts from the state its row's slot holds
                 slots=[r.row for r in group] if self.state_slots else None,
             )
-            if self.spec_k:
+            if self.d_pools is not None:
                 # The draft pool must hold the same chunk K/V (shared
                 # block ids index both pools); its sampled tokens are
                 # discarded — the target's final-chunk token seeds the
@@ -2067,12 +2167,12 @@ class ServingEngine:
                 # never consumed — the row is outside every snapshot).
                 self._pending_admit_merges.append(
                     (toks_dev, list(range(len(group))),
-                     [r.row for r in group])
+                     [r.row for r in group], None)
                 )
             elif final_idxs:
                 self._pending_admit_merges.append(
                     (toks_dev, final_idxs,
-                     [group[i].row for i in final_idxs])
+                     [group[i].row for i in final_idxs], None)
                 )
         else:
             toks = np.asarray(toks_dev)
@@ -2333,6 +2433,7 @@ class ServingEngine:
         self.tables[row, :] = 0
         self.seq_lens[row] = 0
         self.tokens[row] = 0
+        self.drafts[row] = 0
         if not self.has_work():
             st = self.stats
             routing = ""
@@ -2340,6 +2441,11 @@ class ServingEngine:
                 routing = "; experts (%s) took %d pairs, %d touched over %d steps" % (
                     self.decode_experts, st["moe_expert_tokens"].sum(),
                     st["moe_experts_touched"].sum(), st["moe_steps"],
+                )
+            if self.spec_k:
+                routing += "; %d speculative rounds (draft: %s) proposed %d, accepted %d" % (
+                    st.get("spec_rounds", 0), "mtp" if self.self_draft else "model",
+                    st.get("spec_proposed", 0), st.get("spec_accepted", 0),
                 )
             _log.info(
                 "engine empty after %d ticks, %d decode steps: attention read %d "

@@ -21,6 +21,17 @@ Correctness contract (tested):
     sample the bonus token from the target's (k+1)-th distribution. The
     output distribution equals target-only sampling.
 
+This module is the contiguous, batch-1 path with a SEPARATE draft model, and
+the device-side chaining of rounds (``spec_next_inputs``) the serving engine
+uses. Over the paged pools the rounds live in ``generation/paged.py``:
+``paged_spec_round`` (a separate draft model with a pool of its own; per-head
+K/V pools only) and ``paged_mtp_round`` (the model's own multi-token-
+prediction module as the draft, ``models/mtp.py``: verify then draft, the
+module's cache one more layer of the same pool, latent pools included). Both
+decide acceptance by one rule, ``paged._accept_reject``. Not built: a separate
+draft over a latent pool, a rollback of state slots (linear-attention
+layers), several modules chained, top-k/top-p inside a round (ROADMAP R10).
+
 Design (one jitted program, batch 1 — the latency-bound serving shape):
   - Both models keep KV caches over the SAME slot layout: after a round,
     slots [0, P+k] are written in both; the accepted frontier advances by

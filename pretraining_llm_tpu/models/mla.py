@@ -194,6 +194,12 @@ def page_fold(block_size: int, rope_dim: int) -> int:
     return fold
 
 
+# The most tokens a row that ``_write_slots`` writes as so many single-token
+# writes of whole page rows (a speculative round's verify writes two); longer
+# calls (prefill chunks) take one scatter of windows.
+ROW_WRITE_TOKENS = 4
+
+
 def _write_slots(pool: jax.Array, blk_ids, slots, values, fold: int) -> jax.Array:
     """``values`` (B, T, w) into slots (B, T) of blocks (B, T) of a folded pool
     (n_blocks, block / fold, fold * w): slot s is lanes (s % fold) * w .. + w of
@@ -210,6 +216,12 @@ def _write_slots(pool: jax.Array, blk_ids, slots, values, fold: int) -> jax.Arra
         blk, row = blk_ids[:, 0], slots[:, 0] // fold
         mine = (jnp.arange(fold * w) // w)[None, :] == (slots % fold)
         return pool.at[blk, row].set(jnp.where(mine, jnp.tile(values[:, 0], (1, fold)), pool[blk, row]))
+    if values.shape[1] <= ROW_WRITE_TOKENS:
+        # a few tokens a row (the verify of a speculative round): one such write
+        # a token, in order, since a row's next token may share its page row
+        for i in range(values.shape[1]):
+            pool = _write_slots(pool, blk_ids[:, i : i + 1], slots[:, i : i + 1], values[:, i : i + 1], fold)
+        return pool
     idx = jnp.stack([blk_ids, slots // fold, (slots % fold) * w], axis=-1)
     dims = jax.lax.ScatterDimensionNumbers(
         update_window_dims=(2,), inserted_window_dims=(0, 1), scatter_dims_to_operand_dims=(0, 1, 2)

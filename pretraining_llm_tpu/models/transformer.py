@@ -239,6 +239,18 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         params["lm_head"] = {"kernel": normal(k_head, (d, v))}
         if cfg.lm_head_bias:
             params["lm_head"]["bias"] = jnp.zeros((v,), dtype)
+    if cfg.mtp_depth:
+        # The multi-token-prediction module (models/mtp.py): one block of the
+        # stack's last kind, never scanned with the stack (layer_groups does
+        # not see it); embedding and head are the stack's.
+        k_mtp = jax.random.split(jax.random.fold_in(key, 11))
+        params["mtp"] = {
+            "enorm": layers.init_norm(cfg.norm, d, dtype),
+            "hnorm": layers.init_norm(cfg.norm, d, dtype),
+            "eh_proj": normal(k_mtp[0], (2 * d, d)),
+            "block": init_block(k_mtp[1]),
+            "final_norm": layers.init_norm(cfg.norm, d, dtype),
+        }
     return params
 
 
@@ -1136,7 +1148,6 @@ def forward(
                     {name: buf[i] for name, buf in new_stacked.items()}
                     for i in range(len(lyrs))
                 ]
-            new_cache = {**kv_cache, "layers": tuple(new_layers)}
         else:
             aux_total = aux0
             new_layers, counts = [], []
@@ -1162,18 +1173,22 @@ def forward(
                     new_layers.append(new_kv)
             if counts:
                 counts_of.append(jnp.stack(counts))
-            new_cache = {**kv_cache, "layers": tuple(new_layers)}
+        # layers past the stack's (the MTP module's cache: models/mtp.py) stay as they are
+        new_cache = {**kv_cache, "layers": tuple(new_layers) + tuple(kv_cache["layers"][cfg.n_layers:])}
     else:
         # Stacked dense cache (make_kv_cache(..., stacked=True)): the layers
         # ride the depth scan. For a caller that makes the cache, runs one
         # forward and hands the result on (prefill staging).
         aux_total, outs = aux0, []
+        n_cached = jax.tree.leaves(kv_cache)[0].shape[0]
         for layers_of, stack, first in groups:
-            cache = kv_cache if len(groups) == 1 else jax.tree.map(
+            cache = kv_cache if len(groups) == 1 and n_cached == cfg.n_layers else jax.tree.map(
                 lambda a: a[layers_of.start : layers_of.stop], kv_cache
             )
             x, aux_total, out = scan_group(layers_of, stack, first, x, aux_total, cache)
             outs.append(out)
+        if n_cached > cfg.n_layers:  # the MTP module's layer stays as it is
+            outs.append(jax.tree.map(lambda a: a[cfg.n_layers :], kv_cache))
         new_cache = concat_groups(outs)
 
     if cfg.hc_mult > 1:
@@ -1604,7 +1619,8 @@ def _unstack_fields(
             {name: jnp.zeros(shape, dt) for name, (shape, dt) in kda_fields.items()}
             if mixer == "kda" else
             {name: jnp.zeros(shape[1:], dt) for name, (shape, dt) in fields.items()}
-            for mixer, _ in cfg.layer_kinds
+            # the stack's layers, then the MTP module's block (an attention layer)
+            for mixer, _ in cfg.layer_kinds + (("attn", ""),) * cfg.mtp_depth
         )
     }
 
@@ -1626,12 +1642,12 @@ def make_kv_cache(
             f"kv cache max_length={max_length} exceeds context_length={cfg.context_length}"
         )
     # GQA caches only kv_heads heads — the memory win that motivates GQA.
-    shape = (cfg.n_layers, batch_size, max_length, cfg.kv_heads, cfg.head_dim)
+    shape = (cfg.n_cache_layers, batch_size, max_length, cfg.kv_heads, cfg.head_dim)
     if cfg.kv_lora_rank:
         # Latent attention caches one latent and one rotated key slice a
         # token, shared by all heads (two fields: see models/mla.py).
         dt = jnp.dtype(dtype or cfg.compute_dtype)
-        lead = (cfg.n_layers, batch_size, max_length)
+        lead = (cfg.n_cache_layers, batch_size, max_length)
         fields = {"latent": (lead + (cfg.kv_lora_rank,), dt), "rope": (lead + (cfg.qk_rope_head_dim,), dt)}
     elif cfg.kv_cache_dtype == "int8":
         if dtype is not None:
@@ -1681,7 +1697,9 @@ def make_paged_kv_pool(
     keep the TPU's natural layout: see models/mla.py).
     Block 0 is reserved by convention as the idle-row scratch target (the
     serving engine parks inactive batch rows on it); allocators hand out
-    ids from 1.
+    ids from 1. A model with a multi-token-prediction module (``mtp_depth``)
+    gets one more layer of the same pages, index ``n_layers``, under the same
+    block tables: the module's block's own cache (models/mtp.py).
 
     A hybrid stack (``cfg.layer_group_size``) gives pages to its attention
     layers only; each KDA layer keeps {'state_pool': (state_slots + 1, H, K, V)
@@ -1704,13 +1722,13 @@ def make_paged_kv_pool(
     if block_size % 8:
         # TPU sublane granularity; also keeps page gathers tile-aligned.
         raise ValueError(f"block_size must be a multiple of 8, got {block_size}")
-    shape = (cfg.n_layers, n_blocks, block_size, cfg.kv_heads, cfg.head_dim)
+    shape = (cfg.n_cache_layers, n_blocks, block_size, cfg.kv_heads, cfg.head_dim)
     if cfg.kv_lora_rank:
         if scale_dtype is not None:
             raise ValueError("a latent pool has no int8 pages yet (ROADMAP)")
         dt = jnp.dtype(dtype or cfg.compute_dtype)
         fold = mla.page_fold(block_size, cfg.qk_rope_head_dim)
-        page = (cfg.n_layers, n_blocks, block_size // fold)
+        page = (cfg.n_cache_layers, n_blocks, block_size // fold)
         fields = {
             "latent_pool": (page + (fold * cfg.kv_lora_rank,), dt),
             "rope_pool": (page + (fold * cfg.qk_rope_head_dim,), dt),

@@ -35,7 +35,9 @@ _DECAY_LEAVES = frozenset(
      # latent attention's low-rank projections and head-wise gate (models/mla.py)
      "wq_a", "wq_b", "wkv_a", "wkv_b", "wgate",
      # KDA's decay, beta and output-gate projections (models/kda.py)
-     "wf", "wbeta", "wg"}
+     "wf", "wbeta", "wg",
+     # the multi-token-prediction module's (2D, D) projection (models/mtp.py)
+     "eh_proj"}
 )
 
 # Leaves that deliberately receive NO decay: norm parameters and biases.

@@ -66,8 +66,12 @@ def main() -> None:
                         help="draft checkpoint for SPECULATIVE serving "
                         "(k proposals per round verified in one target "
                         "forward; temperature-only sampling)")
-    parser.add_argument("--spec_k", type=int, default=4,
-                        help="draft proposals per speculative round")
+    parser.add_argument("--spec_k", type=int, default=None,
+                        help="draft proposals per speculative round (4 with "
+                        "--draft_model_path). Without a draft checkpoint, "
+                        "--spec_k 1 on a model with a multi-token-prediction "
+                        "module (model.mtp_depth) makes the module its own "
+                        "draft: no --draft_* option is needed")
     parser.add_argument("--no-pipeline", action="store_true",
                         help="disable the pipelined scheduler (fully "
                         "synchronous dispatch/reap baseline)")
@@ -268,8 +272,12 @@ def main() -> None:
         d_params, d_cfg = load_model_for_inference(args.draft_model_path)
         spec = dict(
             draft_params=cast_params_for_inference(d_params, d_cfg.model),
-            draft_cfg=d_cfg.model, spec_k=args.spec_k,
+            draft_cfg=d_cfg.model, spec_k=args.spec_k or 4,
         )
+    elif args.spec_k:
+        # self-drafting: the engine takes the model's own MTP module as the
+        # draft, or says by name why it cannot
+        spec = dict(spec_k=args.spec_k)
 
     quantize = args.quantize or cfg.serving.quantize
 
@@ -506,11 +514,12 @@ def _serve_http(args, cfg, make_engine, enc) -> None:
         from pretraining_llm_tpu.frontend.remote_replica import RemoteReplica
         from pretraining_llm_tpu.resilience.faults import split_serving_plan
 
-        if args.draft_model_path:
+        if args.draft_model_path or args.spec_k:
             raise SystemExit(
                 "--replica_mode process does not support speculative "
-                "serving (--draft_model_path): draft params cannot ride "
-                "a JSON worker spec"
+                "serving (--draft_model_path, --spec_k): draft params "
+                "cannot ride a JSON worker spec, and the worker spec "
+                "carries no spec_k"
             )
         engine_plan, process_plan = (
             split_serving_plan(fault_spec) if fault_spec else ("", "")

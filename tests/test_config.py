@@ -163,6 +163,8 @@ _PERF_INTENT = {
     "xing-mini":       ("naive",        "none",           "chunked"),
     # the same for every Ling-3.0 mechanism
     "ling-mini":       ("naive",        "none",           "chunked"),
+    # the same for the JoyAI mechanisms (latent attention, held experts, an MTP module)
+    "joyai-mini":      ("naive",        "none",           "chunked"),
 }
 
 

@@ -94,7 +94,7 @@ def test_decay_mask_covers_every_leaf_of_every_preset():
                if model.kv_lora_rank else {}),
             # a hybrid preset keeps one layer of each mixer and a share of its experts
             **(dict(layer_group_size=2, kda_head_dim=4, n_experts_held=2, moe_swiglu_limits=(0.0, 4.0))
-               if model.layer_group_size else {}),
+               if model.layer_group_size else dict(n_experts_held=min(model.n_experts_held, 2))),
         )
         params = transformer.init_params(tiny, jax.random.key(0))
         mask = opt.decay_mask(params)
@@ -109,7 +109,7 @@ def test_decay_mask_covers_every_leaf_of_every_preset():
                 f"{jax.tree_util.keystr(path)} — add it to _DECAY_LEAVES or "
                 f"_NO_DECAY_LEAVES in training/optimizer.py"
             )
-            is_matrix = name.startswith("w") or name in {"kernel", "embedding", "router"}
+            is_matrix = name.startswith("w") or name in {"kernel", "embedding", "router", "eh_proj"}
             assert decayed == is_matrix, (
                 f"{preset}: leaf {name!r} decayed={decayed}, expected {is_matrix}"
             )
@@ -120,6 +120,8 @@ def test_decay_mask_covers_every_leaf_of_every_preset():
     assert {"wq_a", "wkv_b", "phi", "alpha", "router_bias"} <= seen_names
     # and KDA's and the gated latent attention's (ling-mini)
     assert {"wf", "wbeta", "wg", "wgate", "conv", "A_log", "dt_bias"} <= seen_names
+    # and the multi-token-prediction module's projection (joyai-mini)
+    assert "eh_proj" in seen_names
 
 
 def test_clip_by_global_norm():

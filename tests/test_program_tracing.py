@@ -398,6 +398,116 @@ def test_a_dense_model_has_no_routing_counters(params):
     assert not [k for k in eng.stats if k.startswith("moe_")]
 
 
+# A model that drafts for itself (models/mtp.py): the module's scopes, with the block's own
+# attn.*, mla.absorb and moe.* nested inside mtp.block so that a reader can split module from
+# stack, and the round's accept/reject under spec.accept.
+MTP_CFG = dataclasses.replace(MECHANISM_CFG, hc_mult=1, rope_scaling="none", mtp_depth=1)
+MTP_SCOPES = {
+    "round": ("spec.verify", "mtp.draft", "mtp.embed_proj", "mtp.block", "mtp.head", "spec.accept", "mla.absorb", "attn.paged_gather",
+              "attn.kv_write", "attn.core", "moe.experts", "lm_head"),
+    "prefill": ("mtp.embed_proj", "mtp.block", "mtp.head", "attn.kv_write", "attn.core", "moe.experts", "sample"),
+}
+
+
+@pytest.fixture(scope="module")
+def mtp_paths():
+    p = transformer.init_params(MTP_CFG, jax.random.key(0))
+    pools = lambda: transformer.make_paged_kv_pool(MTP_CFG, 16, 8)
+    tables = jnp.asarray(np.arange(1, 9).reshape(2, 4), jnp.int32)
+    lowered = {
+        "round": paged.paged_mtp_round.lower(
+            p, pools(), jnp.asarray([3, 5], jnp.int32), jnp.asarray([7, 9], jnp.int32), tables,
+            jnp.asarray([4, 9], jnp.int32), jax.random.key(1), MTP_CFG),
+        "prefill": paged._prefill_scatter_sample.lower(
+            p, pools(), jnp.zeros((2, 16), jnp.int32), jnp.asarray([16, 11], jnp.int32),
+            jnp.asarray([[1, 2], [3, 4]], jnp.int32), jax.random.key(2), MTP_CFG, 16, 2, with_draft=True),
+    }
+    return {k: set(re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True))) for k, low in lowered.items()}
+
+
+@pytest.mark.parametrize("program,scope", [(p, s) for p, ss in MTP_SCOPES.items() for s in ss])
+def test_mtp_scope_is_in_the_lowered_program(mtp_paths, program, scope):
+    words = [re.split(r"[/()]", p) for p in mtp_paths[program]]
+    paths = [w for w in words if scope in w]
+    assert paths
+    if scope.startswith(("attn.", "mla.", "moe.")):
+        # the stack's and the module's: inside mtp.block and outside it
+        assert [w for w in paths if "mtp.block" in w] and [w for w in paths if "mtp.block" not in w]
+    if scope == "spec.accept":
+        assert not [w for w in paths if "mtp.block" in w or "spec.verify" in w or "mtp.draft" in w]
+    if scope.startswith("mtp.") and scope != "mtp.draft" and program == "round":
+        # the round's two halves: the module's scopes lie in mtp.draft, never in the verify
+        assert all("mtp.draft" in w and "spec.verify" not in w for w in paths)
+
+
+def test_a_self_drafting_round_says_so_on_its_spans(monkeypatch):
+    p = transformer.init_params(MTP_CFG, jax.random.key(0))
+    rec = spans.SpanRecorder()
+    monkeypatch.setattr(spans, "_default", rec)
+    eng = ServingEngine(p, MTP_CFG, max_batch=2, n_blocks=16, block_size=8, spec_k=1)
+    eng.submit([1, 2, 3, 4, 5], 6)
+    eng.run()
+    events, _ = rec.drain()
+    dispatches = [meta for name, *_, meta in events if name == "serving.dispatch_window"]
+    assert dispatches and all(m["kind"] == "spec" and m["steps"] == 2 for m in dispatches)
+    commits = [meta for name, *_, meta in events if name == "serving.commit"]
+    keys = {"spec_proposed", "spec_accepted", "spec_emitted", "moe_steps", "moe_layers", "moe_routed_here"}
+    assert commits and all(keys <= set(meta) for meta in commits)
+    st = eng.stats
+    assert sum(m["spec_proposed"] for m in commits) == st["spec_proposed"] > 0
+    assert sum(m["spec_accepted"] for m in commits) == st["spec_accepted"]
+    assert sum(m["spec_emitted"] for m in commits) == st["tokens"] - 1  # the first token is the prefill's
+    assert all(m["moe_layers"] == 2 for m in commits)  # the stack's expert layer and the module's block
+    # the module's block's experts touched, by themselves: at most all held, at most the round's total
+    assert all(0 < m["mtp_touched"] <= min(m["moe_experts"], m["moe_touched"]) for m in commits)
+    assert eng.pool_info()["draft"] == "mtp"
+    # every round's dispatch is one serving.spec_round span inside its serving.dispatch_window
+    rounds = [(t0, t0 + dur, depth, meta) for name, t0, dur, _, depth, meta in events if name == "serving.spec_round"]
+    windows = [(t0, t0 + dur, depth) for name, t0, dur, _, depth, _ in events if name == "serving.dispatch_window"]
+    assert len(rounds) == len(windows) == st["spec_rounds"]
+    assert all(m == {"k": 1, "draft": "mtp"} for *_, m in rounds)
+    for a, b, depth, _ in rounds:
+        assert any(wa <= a and b <= wb and wdepth < depth for wa, wb, wdepth in windows)
+
+
+def test_a_self_drafting_engine_records_nothing_and_syncs_nowhere_new(monkeypatch):
+    """No recorder: the round's span and counters leave nothing behind. And a
+    pipelined round reads the device where a decode window does (the reap's
+    ``np.asarray`` of the emitted tokens, counts, drafts and routing counters),
+    never at dispatch: with every ``np.asarray`` of a device array in the
+    engine's module counted, ensuring pages and dispatching two rounds makes none."""
+    import types
+
+    from pretraining_llm_tpu.generation import serving
+
+    monkeypatch.setattr(spans, "_default", None)
+    p = transformer.init_params(MTP_CFG, jax.random.key(0))
+    eng = ServingEngine(p, MTP_CFG, max_batch=2, n_blocks=16, block_size=8, spec_k=1)
+    eng.submit([1, 2, 3, 4, 5], 12)
+    eng.pipeline_tick()  # admit, prefill, the first round
+    reads = []
+
+    def counted(a, *args, **kw):
+        if isinstance(a, jax.Array):
+            reads.append(a.shape)
+        return np.asarray(a, *args, **kw)
+
+    numpy_seen = types.SimpleNamespace(**{k: getattr(np, k) for k in dir(np) if not k.startswith("__")})
+    numpy_seen.asarray = counted
+    monkeypatch.setattr(serving, "np", numpy_seen)
+    for _ in range(2):
+        eng._ensure_write_pages(horizon=2 * (len(eng._inflight) + 1))
+        eng._dispatch_spec_round()
+    assert len(eng._inflight) == 3 and not reads
+    eng._reap_window(eng._inflight.popleft())
+    assert (2, 2) in reads and (2,) in reads  # the reap is where the host waits: emit, n_emit and the drafts
+    monkeypatch.setattr(serving, "np", np)
+    while eng.pipeline_tick():
+        pass
+    assert [len(toks) for toks in eng.finished.values()] == [12]
+    assert spans._default is None and eng.stats["spec_rounds"] >= 3
+
+
 def test_backward_and_recompute_carry_the_scopes(scope_tokens):
     paths = scope_tokens["train"]["paths"]
     assert any("transpose(jvp(loss.ce))" in p for p in paths)
