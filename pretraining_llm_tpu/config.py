@@ -463,7 +463,7 @@ class ModelConfig:
                 raise ValueError(
                     "paged_attention_impl picks among the per-head K/V forms; a latent pool "
                     "picks its own decode form from its input (models/mla.py::decode_form: "
-                    "ops/pallas_latent.py for one query a row on a TPU, the gather form "
+                    "ops/pallas_latent.py for a few queries a row on a TPU, the gather form "
                     "otherwise), so leave it at 'gather'"
                 )
         if self.rope_scaling not in ("none", "yarn"):

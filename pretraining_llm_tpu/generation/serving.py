@@ -347,7 +347,8 @@ class ServingEngine:
         # How a decode step reads the pool, fixed for the engine's programs:
         # what its input and the backend allow (models/mla.py::decode_form for
         # a latent pool, transformer.paged_attention_form for a per-head one).
-        # (A self-drafting round verifies spec_k + 1 queries a row.)
+        # A self-drafting round's verify and draft run spec_k + 1 queries a row:
+        # a latent pool's kernel takes them as it takes one, the per-head one not yet.
         queries = self.spec_k + 1 if self.self_draft else 1
         self.decode_attention = (
             mla.decode_form(queries) if cfg.kv_lora_rank
@@ -1131,7 +1132,8 @@ class ServingEngine:
         draft); a separate draft model runs ``paged.paged_spec_round`` over
         both pools."""
         with _spans.span(
-            "serving.spec_round", k=self.spec_k, draft="mtp" if self.self_draft else "model"
+            "serving.spec_round", k=self.spec_k, draft="mtp" if self.self_draft else "model",
+            attention=self.decode_attention,
         ):
             tables = jnp.asarray(self.tables)
             if self.self_draft:

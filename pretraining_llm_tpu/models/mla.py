@@ -24,10 +24,12 @@ reshapes to (rows, slots, width) as before. Two forms of one function:
   run in the latent space against the cached latents, and the value half maps
   the result back, ``o = (softmax . c_kv) W_kvb^V``. Nothing per head is ever
   read from the cache. Over a page pool the middle of it has two forms and the
-  input picks one (``decode_form``): one query a row on a TPU reads the latent
-  pool once, in place, through ``ops/pallas_latent.py``; anything else gathers
-  ``pool[tables]`` first and reads the copy twice (about five passes over the
-  row's latents; PERF.md section 6, PR 28), which is also the tests' oracle.
+  input picks one (``decode_form``): a few queries a row on a TPU (the decode
+  step's one, the ``k + 1`` of a speculative round's verify and draft) read the
+  latent pool once for all of them, in place, through ``ops/pallas_latent.py``;
+  anything else (a prefill chunk, every other backend) gathers ``pool[tables]``
+  first and reads the copy twice (about five passes over the row's latents;
+  PERF.md section 6, PR 28), which is also the tests' oracle.
 
 The softmax scale is ``cfg.softmax_scale`` (YaRN's mscale squared included).
 Scopes: the low-rank projections in ``attn.qkv``, ``mla.absorb`` around both
@@ -127,15 +129,23 @@ def _expanded(attn: Params, q, c_kv, k_rope, cfg: ModelConfig, cdt: Any, impl: s
     return out[..., :dv]
 
 
+# The most queries a row that take the in-place kernel: their T * H query rows
+# are one left operand of its matmuls and its softmax carry lives in VMEM whole
+# (128 rows at 32 heads; compiled at that size in tests/test_pallas_latent.py).
+KERNEL_QUERIES = 4
+
+
 def decode_form(t: int, backend: Optional[str] = None) -> str:
     """The form attention over a latent page pool takes for ``t`` queries a
-    row: ``"latent_kernel"`` (``ops/pallas_latent.py``: the pool read once, in
-    place) for the single-token decode step where Mosaic compiles, ``"gather"``
-    (``pool[tables]``, then ``_absorbed``) for several queries a row (the chunk
-    lane) and for every other backend. Read from the input, never from an
-    option; the engine reports ``decode_form(1)`` in ``pool_info()``."""
+    row: ``"latent_kernel"`` (``ops/pallas_latent.py``: the pool read once for
+    all of them, in place) for up to ``KERNEL_QUERIES`` where Mosaic compiles
+    (the decode step's one, the ``spec_k + 1`` of a speculative round),
+    ``"gather"`` (``pool[tables]``, then ``_absorbed``) for more (the chunk
+    lane's hundreds) and for every other backend. Read from the input, never
+    from an option; the engine reports the form of its decode program in
+    ``pool_info()``."""
     backend = backend or jax.default_backend()
-    return "latent_kernel" if t == 1 and backend == "tpu" else "gather"
+    return "latent_kernel" if t <= KERNEL_QUERIES and backend == "tpu" else "gather"
 
 
 def _dot_dtype(cdt: Any) -> Any:
@@ -230,16 +240,16 @@ def _write_slots(pool: jax.Array, blk_ids, slots, values, fold: int) -> jax.Arra
 
 
 def _absorbed_in_place(attn: Params, q, pool, rpool, tables, seq, cfg: ModelConfig, cdt: Any):
-    """The single-token decode step over the page pool without a gathered
-    copy: q (B,1,H,nope+rope) against the pages ``tables`` names, slots
-    0..``seq`` of each row visible."""
+    """A few queries a row over the page pool without a gathered copy: q
+    (B,T,H,nope+rope) against the pages ``tables`` names, slots 0..``seq + i``
+    of a row visible to its query i."""
     from pretraining_llm_tpu.ops.pallas_latent import latent_decode_attention
 
     def core(q_lat, q_rope):
         return latent_decode_attention(
-            q_lat[:, 0].astype(pool.dtype), q_rope[:, 0].astype(pool.dtype), pool, rpool,
+            q_lat.astype(pool.dtype), q_rope.astype(pool.dtype), pool, rpool,
             tables, seq, scale=cfg.softmax_scale,
-        )[:, None]
+        )
 
     return _absorb(attn, q, core, cfg, cdt)
 

@@ -10,6 +10,13 @@ writes a gathered copy, and reads that copy for the scores and again for
 through the block table, once, and keeps scores, online softmax and the
 weighted sum in VMEM; no gathered copy exists.
 
+A row brings T queries (static; 1 for a decode step, ``k + 1`` for the verify
+and the draft of a speculative round): query i stands at slot ``seq + i`` and
+sees slots ``0 .. seq + i``. Their T * H query rows are one left operand of the
+score matmuls and of ``p . latents``, all scored against each page group as it
+is copied, so a row's latents cross HBM once for all of them; what differs
+between a row's queries is a column of last visible slots in the mask.
+
 Why not the shape of ``ops/pallas_paged.py`` (one 64-token page a grid step):
 at 32 rows x 129 pages x 6 layers that is 24.8 k grid steps a decode step of
 72 KB each, and the fixed cost of a grid step alone would eat the gain
@@ -36,10 +43,12 @@ at 32 rows x 129 pages x 6 layers that is 24.8 k grid steps a decode step of
     slots inside a class is the buffer's, the mask is computed for that order,
     and a softmax does not care.
   - Masking, the finite NEG_INF and the safe division are those of
-    ``ops/pallas_paged.py``: slot ``seq`` (the token written this step) is
-    visible, everything past it is not, and a row with no visible slot gives
-    zeros. A dead page inside the last live group is copied like a live one
-    (the table's dead tail names a real block, block 0 by convention) and
+    ``ops/pallas_paged.py``: slot ``seq + i`` (query i's own token, written
+    this step) is visible to it, everything past it is not (a later query's
+    token, or what a rejected draft left above the frontier), and a query with
+    no visible slot gives zeros. The live groups are those up to the last
+    query's slot. A dead page inside the last live group is copied like a live
+    one (the table's dead tail names a real block, block 0 by convention) and
     masked; any block of the pool holds finite values.
 
 Forward only (decode never differentiates).
@@ -59,18 +68,19 @@ NEG_INF = -1e30  # finite: exp/max edge cases (same constant as pallas_paged)
 
 # Pages a step of the in-row loop copies and computes: 16 x 64 tokens x 576 x 2 B
 # = 1.2 MB a buffer at the serving cell's widths (timed at 4, 8, 16 and 32 on
-# the chip: PERF.md section 6, PR 28).
+# the chip at one query a row, PERF.md section 6, PR 28; at 8, 16 and 32 at two
+# queries, PR 36: 16 for every query count).
 PAGES_PER_STEP = 16
 
 
 def _latent_kernel(
     tbl_ref,  # (B, nbp) int32 scalar prefetch (SMEM), nbp a multiple of P
     seq_ref,  # (B,) int32 scalar prefetch (SMEM)
-    q_ref,  # (1, H, C) queries in the latent space
-    qr_ref,  # (1, fold * H, fold * R) rope queries, block-diagonal over the classes
+    q_ref,  # (1, T * H, C) queries in the latent space, query i's heads at rows i*H..i*H+H
+    qr_ref,  # (1, fold * T * H, fold * R) rope queries, block-diagonal over the classes
     lat_ref,  # (n_blocks, rows, fold * C), left in HBM
     rope_ref,  # (n_blocks, rows, fold * R), left in HBM
-    o_ref,  # (1, H, C)
+    o_ref,  # (1, T * H, C)
     lbuf,  # VMEM (2, P, rows, fold * C): two page groups of latents
     rbuf,  # VMEM (2, P, rows, fold * R): and of rope slices
     sem,  # DMA semaphores (2,), one a buffer
@@ -78,11 +88,12 @@ def _latent_kernel(
     *,
     nb: int,
     pages: int,
+    queries: int,
     scale: float,
 ):
     b = pl.program_id(0)
     n_rows = pl.num_programs(0)
-    h, c = q_ref.shape[1], q_ref.shape[2]
+    th, c = q_ref.shape[1], q_ref.shape[2]  # a row's T * H query rows
     rows, fold = lat_ref.shape[1], lat_ref.shape[2] // c
     bs = rows * fold
     span = pages * bs  # slots a group covers
@@ -104,15 +115,19 @@ def _latent_kernel(
             cp.start()
 
     slot0 = slot_ref[0]
-    # groups holding a visible slot: slots 0..seq, at least one, at most all
-    live = jnp.clip((seq_ref[b] + span) // span, 1, n_groups_max)
-    # the last visible slot: seq, or the row's last slot for a row at capacity
-    # (its token went to the scratch block, as in the gather form)
-    last = jnp.minimum(seq_ref[b], nb * bs - 1)
+    # groups holding a slot some query sees: slots 0..seq + T - 1, at least one, at most all
+    live = jnp.clip((seq_ref[b] + (queries - 1 + span)) // span, 1, n_groups_max)
+    # the last slot query i sees: seq + i (its own token), or the row's last
+    # slot past the capacity (that token went to the scratch block, as in the
+    # gather form); a scalar for one query a row, a column over the query rows
+    own = seq_ref[b]
+    if queries > 1:
+        own = own + jax.lax.broadcasted_iota(jnp.int32, (th, 1), 0) // (th // queries)
+    last = jnp.minimum(own, nb * bs - 1)
     q = q_ref[0]
     q_rope = qr_ref[0]
     # slot of column j of class 0, within a group: page j // rows, row j % rows
-    col = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (th, cols), 1)
     slot_of_col = (col // rows) * bs + (col % rows) * fold
 
     def body(g, carry):
@@ -132,7 +147,7 @@ def _latent_kernel(
         for cp in copies(b, g, slot):
             cp.wait()
         ropes = rbuf[slot].reshape(cols, rbuf.shape[-1])
-        # (fold * H, cols): rows a*H..a*H+H are class a's rope scores
+        # (fold * T * H, cols): rows a*T*H..(a+1)*T*H are class a's rope scores
         s_rope = jax.lax.dot_general(
             q_rope, ropes, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -142,8 +157,8 @@ def _latent_kernel(
             k = lbuf[slot, :, :, pl.ds(a * c, c)].reshape(cols, c)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )  # (H, cols)
-            s = (s + s_rope[a * h : (a + 1) * h]) * scale
+            )  # (T * H, cols)
+            s = (s + s_rope[a * th : (a + 1) * th]) * scale
             valid = g * span + slot_of_col + a <= last
             s = jnp.where(valid, s, NEG_INF)
             m_new = jnp.maximum(m_new, jnp.max(s, axis=-1, keepdims=True))
@@ -160,9 +175,9 @@ def _latent_kernel(
         return m_new, l_new, acc
 
     init = (
-        jnp.full((h, 1), NEG_INF, jnp.float32),
-        jnp.zeros((h, 1), jnp.float32),
-        jnp.zeros((h, c), jnp.float32),
+        jnp.full((th, 1), NEG_INF, jnp.float32),
+        jnp.zeros((th, 1), jnp.float32),
+        jnp.zeros((th, c), jnp.float32),
     )
     _, l, acc = jax.lax.fori_loop(0, live, body, init)
     slot_ref[0] = (slot0 + live) % 2
@@ -171,28 +186,31 @@ def _latent_kernel(
 
 @functools.partial(jax.jit, static_argnames=("scale", "pages", "interpret"))
 def _latent_call(q_lat, q_rope, latent_pool, rope_pool, tables, seq_lens, scale, pages, interpret):
-    b, h, c = q_lat.shape
+    b, t, h, c = q_lat.shape
     r = q_rope.shape[-1]
     _, rows, fc = latent_pool.shape
     fold = fc // c
     nb = tables.shape[1]
+    th = t * h
+    # a row's T * H query rows are one left operand: query i's heads at rows i*H..i*H+H
+    q_lat, q_rope = q_lat.reshape(b, th, c), q_rope.reshape(b, th, r)
     # a whole number of page groups; the new tail names the scratch block 0, as a dead tail does
     tables = jnp.pad(tables.astype(jnp.int32), ((0, 0), (0, -nb % pages)))
     # class a's queries against lanes a*r..a*r+r of a folded rope row, zeros elsewhere
     q_fold = jnp.einsum("bhr,ac->bahcr", q_rope, jnp.eye(fold, dtype=q_rope.dtype))
-    q_fold = q_fold.reshape(b, fold * h, fold * r)
-    kernel = functools.partial(_latent_kernel, nb=nb, pages=pages, scale=scale)
+    q_fold = q_fold.reshape(b, fold * th, fold * r)
+    kernel = functools.partial(_latent_kernel, nb=nb, pages=pages, queries=t, scale=scale)
     row_block = lambda shape: pl.BlockSpec((1,) + shape, lambda bb, tbl, seq: (bb, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
         in_specs=[
-            row_block((h, c)),
-            row_block((fold * h, fold * r)),
+            row_block((th, c)),
+            row_block((fold * th, fold * r)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=row_block((h, c)),
+        out_specs=row_block((th, c)),
         scratch_shapes=[
             pltpu.VMEM((2, pages, rows, fc), latent_pool.dtype),
             pltpu.VMEM((2, pages, rows, fold * r), rope_pool.dtype),
@@ -200,40 +218,44 @@ def _latent_call(q_lat, q_rope, latent_pool, rope_pool, tables, seq_lens, scale,
             pltpu.SMEM((1,), jnp.int32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, c), q_lat.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, th, c), q_lat.dtype),
         # rows run in order: a row's last step starts the next row's first copy
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(tables, seq_lens.astype(jnp.int32), q_lat, q_fold, latent_pool, rope_pool)
+    return out.reshape(b, t, h, c)
 
 
 def latent_decode_attention(
-    q_lat: jax.Array,  # (B, H, C) queries moved into the latent space
-    q_rope: jax.Array,  # (B, H, R) their rotated slices
+    q_lat: jax.Array,  # (B, T, H, C) queries moved into the latent space, T a few (static)
+    q_rope: jax.Array,  # (B, T, H, R) their rotated slices
     latent_pool: jax.Array,  # (n_blocks, block / fold, fold * C)
     rope_pool: jax.Array,  # (n_blocks, block / fold, fold * R)
     block_tables: jax.Array,  # (B, max_blocks) int32, 0-padded tails
-    seq_lens: jax.Array,  # (B,) int32: slot seq_len holds this step's token
+    seq_lens: jax.Array,  # (B,) int32: slot seq_len + i holds query i's token
     *,
     scale: float,
     pages_per_step: int = PAGES_PER_STEP,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """softmax(([q_lat | q_rope] . [latents | ropes]) * scale) . latents over
-    each row's slots 0..seq_len, read from the two pools through the block
-    table: (B, H, C) in ``q_lat``'s dtype. What lies past a row's ``seq_len``
-    is never read into the result. ``interpret=None``: compiled on TPU, the
-    interpreter elsewhere (tests)."""
+    """softmax(([q_lat | q_rope] . [latents | ropes]) * scale) . latents, query
+    i of a row over the row's slots 0..seq_len + i (every slot, and not its own
+    token's, past the row's capacity), read from the two pools through the
+    block table once for all T queries: (B, T, H, C) in ``q_lat``'s dtype. What
+    lies past a query's last slot is never read into its result.
+    ``interpret=None``: compiled on TPU, the interpreter elsewhere (tests)."""
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
-    b, h, c = q_lat.shape
+    if q_lat.ndim != 4:
+        raise ValueError(f"queries {q_lat.shape} are not (rows, queries a row, heads, latent width)")
+    b, t, h, c = q_lat.shape
     r = q_rope.shape[-1]
     fold = latent_pool.shape[2] // c
     if (
-        q_rope.shape != (b, h, r)
+        q_rope.shape != (b, t, h, r)
         or latent_pool.shape[2] != fold * c
         or rope_pool.shape != latent_pool.shape[:2] + (fold * r,)
         or not q_lat.dtype == q_rope.dtype == latent_pool.dtype == rope_pool.dtype

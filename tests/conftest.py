@@ -8,6 +8,7 @@ Backends initialize lazily, so setting the platform config here (before any
 test touches a device) is sufficient whatever `JAX_PLATFORMS` says.
 """
 
+import functools
 import os
 import sys
 
@@ -60,5 +61,20 @@ def _clear_jax_caches_per_module():
     boundary keeps within-module reuse (fixtures' jitted fns stay hot
     across a module's tests) while releasing the native executables of
     every previous module."""
+    yield
+    jax.clear_caches()
+
+
+@pytest.fixture
+def kernel_forced(monkeypatch):
+    """``mla.decode_form`` answers as on a TPU, so a few queries a row through
+    the latent pool (a decode step's one, a speculative round's ``k + 1``) take
+    ``ops/pallas_latent.py`` (interpreted here) and a chunk's many still take
+    the gather form. A jitted program keeps the form it was traced with, so
+    the caches go before and after."""
+    from pretraining_llm_tpu.models import mla
+
+    monkeypatch.setattr(mla, "decode_form", functools.partial(mla.decode_form, backend="tpu"))
+    jax.clear_caches()
     yield
     jax.clear_caches()

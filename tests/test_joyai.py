@@ -13,6 +13,7 @@ the program differs from the reference by the order of its sums alone.
 """
 
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -33,7 +34,7 @@ from references import joyai as ref  # noqa: E402
 from pretraining_llm_tpu.config import get_preset  # noqa: E402
 from pretraining_llm_tpu.generation import paged  # noqa: E402
 from pretraining_llm_tpu.generation.serving import ServingEngine  # noqa: E402
-from pretraining_llm_tpu.models import moe, mtp, transformer as tr  # noqa: E402
+from pretraining_llm_tpu.models import mla, moe, mtp, transformer as tr  # noqa: E402
 from pretraining_llm_tpu.observability import spans  # noqa: E402
 
 with open(os.path.join(BENCH, "tests", "toy", "joyai.json")) as f:
@@ -225,6 +226,101 @@ def test_a_round_is_verify_then_draft_and_leaves_both_caches_at_the_frontier(par
         assert not before.any() and after[0].any()
 
 
+def _equations(jaxpr, outer=""):
+    """(primitive, scope path, first output's shape) of every equation of a
+    program, the bodies of its calls included (a kernel's own body not)."""
+    for eqn in jaxpr.eqns:
+        path = f"{outer}/{eqn.source_info.name_stack}"  # a call's body names its scopes from the call on
+        yield eqn.primitive.name, path, eqn.outvars[0].aval.shape if eqn.outvars else ()
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _equations(sub, path)
+
+
+def _rounds_through_the_pool(p, prompts, greedy, accept):
+    """A prefill with the module, then a round for every row of ``accept`` (a
+    row of it says which batch rows' drafts are the target's own next token,
+    taken from ``greedy``; the others get another token). Before each round
+    its two forwards' logits, on a copy of the pools.
+    -> (tokens emitted a row, the logits, the module's next drafts)."""
+    bs, rows = 8, len(prompts)
+    pools = tr.make_paged_kv_pool(CFG, 32, bs)
+    ids = [list(range(1 + 8 * r, 9 + 8 * r)) for r in range(rows)]
+    n_pre = [paged.required_blocks(len(pr), bs) for pr in prompts]
+    first, pools = paged.prefill_into_pool_batched(
+        p, CFG, pools, prompts, [i[:k] for i, k in zip(ids, n_pre)], jax.random.key(0), with_draft=True)
+    tables = jnp.asarray(ids, jnp.int32)
+    seq = np.asarray([len(pr) for pr in prompts], np.int32)
+    out = [[int(t)] for t in first[:, 0]]
+    logits, drafts = [], [np.asarray(first[:, 1])]
+    for wanted in accept:
+        tok = np.asarray([o[-1] for o in out], np.int32)
+        nxt = np.asarray([greedy[r][len(out[r])] for r in range(rows)], np.int32) if greedy else drafts[-1]
+        d = np.where(wanted, nxt, (nxt + 1) % CFG.vocab_size).astype(np.int32)
+        seq_tokens = jnp.stack([jnp.asarray(tok), jnp.asarray(d)], axis=1)
+        v, m, _ = paged.paged_mtp_logits(p, jax.tree.map(jnp.copy, pools), seq_tokens, seq_tokens,
+                                         tables, jnp.asarray(seq), cfg=CFG)
+        logits.append((np.asarray(v), np.asarray(m)))
+        emit, n_emit, nxt_draft, _, pools = paged.paged_mtp_round(
+            p, pools, jnp.asarray(tok), jnp.asarray(d), tables, jnp.asarray(seq), jax.random.key(1), cfg=CFG)
+        for r in range(rows):
+            out[r] += emit[r, : int(n_emit[r])].tolist()
+        seq = seq + np.asarray(n_emit)
+        drafts.append(np.asarray(nxt_draft))
+    return out, logits, drafts
+
+
+def test_rounds_through_the_kernel_are_the_gather_forms(params, request):
+    """Both queries of a row through ``ops/pallas_latent.py`` (``decode_form``
+    answering as on a TPU, the kernel interpreted): the logits of every round's
+    two forwards, the tokens emitted and the module's next drafts are the gather
+    form's, whether a draft was accepted or rejected the round before (after a
+    rejection slot s + 1 of both caches holds garbage above the frontier: the
+    next round's first query must not see it and its second overwrites it)."""
+    p = params[SEEDS[0]]
+    prompts = [tokens(40 + r, n).tolist() for r, n in enumerate((13, 22, 7))]
+    # the target's greedy continuation: a rejected draft emits the target's own token alone
+    greedy, _, _ = _rounds_through_the_pool(p, prompts, None, np.zeros((9, 3), bool))
+    assert all(len(g) == 10 for g in greedy)
+    # row 0 accepts, rejects, accepts, ..; row 1 the other way round; row 2 accepts twice running
+    accept = np.asarray([[1, 0, 1], [0, 1, 1], [1, 0, 0], [0, 1, 1]], bool)
+    round_args = (p, tr.make_paged_kv_pool(CFG, 32, 8), jnp.zeros((3,), jnp.int32), jnp.zeros((3,), jnp.int32),
+                  jnp.zeros((3, 8), jnp.int32), jnp.zeros((3,), jnp.int32), jax.random.key(1))
+    trace = lambda: jax.make_jaxpr(functools.partial(paged.paged_mtp_round, cfg=CFG))(*round_args)
+    # ``pool[tables]``, the gathered copy: a gather whose output keeps the tables' (rows, pages) in front
+    pool_gathers = lambda eqns: [path for name, path, shape in eqns if name == "gather" and shape[:2] == (3, 8)]
+    gathered = list(_equations(trace().jaxpr))
+    assert not any(name == "pallas_call" for name, *_ in gathered)
+    copies = pool_gathers(gathered)
+    assert len(copies) == 2 * (CFG.n_layers + 1)  # both fields, the module's layer too
+    assert all("attn.paged_gather" in path for path in copies)
+    want = _rounds_through_the_pool(p, prompts, greedy, accept)
+    assert [len(o) - 1 for o in want[0]] == (1 + accept).sum(0).tolist()  # an accepted draft commits two
+    assert all(o == g[: len(o)] for o, g in zip(want[0], greedy))
+
+    request.getfixturevalue("kernel_forced")
+    in_place = list(_equations(trace().jaxpr))
+    kernels = [path for name, path, _ in in_place if name == "pallas_call"]
+    assert len(kernels) == CFG.n_layers + 1 and all("attn.core" in path for path in kernels)
+    assert not pool_gathers(in_place) and not any("attn.paged_gather" in path for _, path, _ in in_place)
+    got = _rounds_through_the_pool(p, prompts, greedy, accept)
+    assert got[0] == want[0]
+    for (v, m), (v_want, m_want) in zip(got[1], want[1]):
+        assert rel_err(v, v_want) < LOGITS_TOL and rel_err(m, m_want) < LOGITS_TOL
+    assert all(np.array_equal(a, b) for a, b in zip(got[2], want[2]))
+
+
+@pytest.mark.parametrize("t,backend,form", [
+    (1, "tpu", "latent_kernel"), (2, "tpu", "latent_kernel"), (mla.KERNEL_QUERIES, "tpu", "latent_kernel"),
+    (mla.KERNEL_QUERIES + 1, "tpu", "gather"), (512, "tpu", "gather"),
+    (1, "cpu", "gather"), (2, "cpu", "gather"), (2, "gpu", "gather"), (2, None, "gather")])
+def test_the_form_is_read_from_the_query_count_and_the_backend(t, backend, form):
+    """A few queries a row (the decode step's one, a round's ``spec_k + 1``)
+    take the in-place kernel where Mosaic compiles; a chunk's many, and every
+    other backend (``None``: the one the tests run on), the gather form."""
+    assert mla.decode_form(t, backend) == form
+
+
 # -- 4. the engine: greedy speculative output is plain greedy output --------------------
 
 
@@ -355,6 +451,21 @@ def test_the_commit_span_carries_the_rounds_counts(params, plain, drafts_from, m
     assert 0 < sum(m["moe_routed_here"] for m in commits) < sum(m["moe_routed"] for m in commits)
     dispatches = [meta for name, *_, meta in events if name == "serving.dispatch_window"]
     assert dispatches and all(m["kind"] == "spec" and m["steps"] == 2 for m in dispatches)
+
+
+def test_a_self_drafting_engine_takes_the_kernel_and_says_so(params, plain, kernel_forced, monkeypatch):
+    """The engine's rounds with ``decode_form`` answering as on a TPU: the
+    plain engine's tokens, ``pool_info()`` and every ``serving.spec_round``
+    span name the form."""
+    rec = spans.SpanRecorder()
+    monkeypatch.setattr(spans, "_default", rec)
+    got, eng = _serve(params[SEEDS[0]], spec_k=1)
+    events, _ = rec.drain()
+    assert got == plain and eng.stats["spec_rounds"] > 0
+    assert eng.pool_info()["decode_attention"] == "latent_kernel"
+    rounds = [meta for name, *_, meta in events if name == "serving.spec_round"]
+    assert len(rounds) == eng.stats["spec_rounds"]
+    assert all(m == {"k": 1, "draft": "mtp", "attention": "latent_kernel"} for m in rounds)
 
 
 def test_generate_and_the_training_loss_run_the_stack_and_leave_the_module_alone(params, plain):

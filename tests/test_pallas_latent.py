@@ -26,18 +26,19 @@ SCALE = 0.23
 
 
 def gather_form(q, q_rope, pool, rpool, tables, seq):
-    """softmax(([q | q_rope] . [latents | ropes]) * SCALE) . latents over slots
-    0..seq of each row, from gathered copies of (n_blocks, BS, width) pools, in
-    float32."""
-    b = q.shape[0]
+    """softmax(([q | q_rope] . [latents | ropes]) * SCALE) . latents, query i
+    of a row over its slots 0..seq + i, from gathered copies of (n_blocks, BS,
+    width) pools, in float32."""
+    b, t = q.shape[:2]
     kv = tables.shape[1] * BS
     f32 = jnp.float32
     lat = pool[tables].reshape(b, kv, C).astype(f32)
     rope = rpool[tables].reshape(b, kv, R).astype(f32)
-    s = jnp.einsum("bhc,bkc->bhk", q.astype(f32), lat) + jnp.einsum("bhr,bkr->bhk", q_rope.astype(f32), rope)
-    mask = jnp.arange(kv)[None, None, :] <= jnp.asarray(seq)[:, None, None]
+    s = jnp.einsum("bthc,bkc->bthk", q.astype(f32), lat) + jnp.einsum("bthr,bkr->bthk", q_rope.astype(f32), rope)
+    pos = jnp.asarray(seq)[:, None] + jnp.arange(t)[None, :]
+    mask = jnp.arange(kv)[None, None, None, :] <= pos[:, :, None, None]
     p = jax.nn.softmax(jnp.where(mask, s * SCALE, -jnp.inf), axis=-1)
-    return jnp.einsum("bhk,bkc->bhc", p, lat)
+    return jnp.einsum("bthk,bkc->bthc", p, lat)
 
 
 def through_the_kernel(q, q_rope, pool, rpool, tables, seq, pages, fold=2):
@@ -48,18 +49,19 @@ def through_the_kernel(q, q_rope, pool, rpool, tables, seq, pages, fold=2):
     )
 
 
-def state(seed, seq, max_blocks, dtype=jnp.float32):
-    """Rows of the given lengths on disjoint, shuffled pages; a table's dead
-    tail is 0 (the scratch block)."""
+def state(seed, seq, max_blocks, dtype=jnp.float32, t=1):
+    """Rows of the given lengths, ``t`` queries each, on disjoint, shuffled
+    pages that reach the last query's slot; a table's dead tail is 0 (the
+    scratch block)."""
     rng = np.random.default_rng(seed)
     b = len(seq)
     perm = rng.permutation(np.arange(1, N_BLOCKS)).tolist()
     tables = np.zeros((b, max_blocks), np.int32)
     for i, n in enumerate(seq):
-        own = min(max_blocks, n // BS + 1)
+        own = min(max_blocks, (n + t - 1) // BS + 1)
         tables[i, :own] = [perm.pop() for _ in range(own)]
     normal = lambda *shape: jnp.asarray(rng.normal(size=shape), dtype)
-    return (normal(b, H, C), normal(b, H, R), normal(N_BLOCKS, BS, C), normal(N_BLOCKS, BS, R),
+    return (normal(b, t, H, C), normal(b, t, H, R), normal(N_BLOCKS, BS, C), normal(N_BLOCKS, BS, R),
             tables, np.asarray(seq, np.int32))
 
 
@@ -69,30 +71,36 @@ CASES = {
     "length-0-on-the-scratch-block": ((0, 21), 4, 2),
     "last-page-holds-one-slot": ((BS, 2 * BS), 4, 2),  # slot seq is the page's first
     "last-page-one-slot-short-of-full": ((BS - 2, 3 * BS - 2), 4, 2),  # one short of full
-    "last-page-full": ((BS - 1, 3 * BS - 1), 4, 2),
+    "last-page-full": ((BS - 1, 3 * BS - 1), 4, 2),  # a second query's slot opens a page
+    "last-group-full": ((2 * BS - 1, 4 * BS - 1, 4 * BS - 2), 6, 2),  # and a group of pages
     "pages-not-divisible-by-the-step": ((5, 37, 20), 5, 3),
     "one-page-a-step": ((5, 37, 20), 5, 1),
     "a-step-wider-than-the-table": ((5, 37, 20), 5, 8),
-    "row-at-capacity": ((4 * BS - 1, 4 * BS, 4 * BS + 5), 4, 2),  # the last two wrote to scratch
+    # the last two wrote to scratch; further queries of the first and the fourth do:
+    # a query past the capacity sees every slot and not its own token
+    "row-at-capacity": ((4 * BS - 1, 4 * BS, 4 * BS + 5, 4 * BS - 2), 4, 2),
 }
+QUERIES = pytest.mark.parametrize("t", [1, 2, 3], ids=["one-query", "two-queries", "three-queries"])
 
 
+@QUERIES
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_kernel_matches_the_gather_form(case):
+def test_kernel_matches_the_gather_form(case, t):
     seq, max_blocks, pages = CASES[case]
-    q, q_rope, pool, rpool, tables, seq = state(len(case), seq, max_blocks)
+    q, q_rope, pool, rpool, tables, seq = state(len(case), seq, max_blocks, t=t)
     if case == "length-0-on-the-scratch-block":
         tables[0] = 0  # an idle engine row: every entry the scratch block
     got = through_the_kernel(q, q_rope, pool, rpool, tables, seq, pages)
     want = gather_form(q, q_rope, pool, rpool, tables, seq)
-    assert got.shape == want.shape and got.dtype == q.dtype
+    assert got.shape == want.shape == (len(seq), t, H, C) and got.dtype == q.dtype
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
+@QUERIES
 @pytest.mark.parametrize("tail", ["live-blocks-of-another-row", "poisoned-blocks"])
-def test_a_dead_tail_is_never_read_into_the_result(tail):
+def test_a_dead_tail_is_never_read_into_the_result(tail, t):
     seq = (11, 42, 3)
-    q, q_rope, pool, rpool, tables, seq = state(5, seq, 6)
+    q, q_rope, pool, rpool, tables, seq = state(5, seq, 6, t=t)
     want = gather_form(q, q_rope, pool, rpool, tables, seq)
     if tail == "live-blocks-of-another-row":
         tables[0, 2:] = tables[1, :4]
@@ -112,8 +120,9 @@ def test_a_dead_tail_is_never_read_into_the_result(tail):
     )
 
 
-def test_kernel_bf16():
-    q, q_rope, pool, rpool, tables, seq = state(7, (13, 40, 0, 29), 6, jnp.bfloat16)
+@QUERIES
+def test_kernel_bf16(t):
+    q, q_rope, pool, rpool, tables, seq = state(7, (13, 40, 0, 29), 6, jnp.bfloat16, t=t)
     got = through_the_kernel(q, q_rope, pool, rpool, tables, seq, 2)
     assert got.dtype == jnp.bfloat16
     want = gather_form(q, q_rope, pool, rpool, tables, seq)
@@ -121,11 +130,12 @@ def test_kernel_bf16():
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=3e-2)
 
 
+@QUERIES
 @pytest.mark.parametrize("fold", [1, 4, 8])
-def test_any_fold_of_a_page_gives_the_same_numbers(fold):
+def test_any_fold_of_a_page_gives_the_same_numbers(fold, t):
     """Slots side by side in a row change the order of a class's columns, not
     what a softmax over them sums to (fold 2 is every other test's)."""
-    q, q_rope, pool, rpool, tables, seq = state(3, (19, 33, 0, 39), 5)
+    q, q_rope, pool, rpool, tables, seq = state(3, (19, 33, 0, 39), 5, t=t)
     got = through_the_kernel(q, q_rope, pool, rpool, tables, seq, 2, fold)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(gather_form(q, q_rope, pool, rpool, tables, seq)), atol=1e-5
@@ -158,20 +168,24 @@ def test_a_written_slot_is_the_slot_the_gather_form_reads(t):
     np.testing.assert_array_equal(after.reshape(N_BLOCKS, BS, C)[1:], want[1:])
 
 
-@pytest.mark.parametrize("wrong", ["width", "dtype", "batch", "rope-pool"])
+@pytest.mark.parametrize("wrong", ["width", "dtype", "batch", "rope-pool", "no-query-axis", "query-counts"])
 def test_kernel_validation(wrong):
-    q, q_rope, pool, rpool, tables, seq = state(1, (5, 9), 4)
+    q, q_rope, pool, rpool, tables, seq = state(1, (5, 9), 4, t=2)
     lat, rope = pool.reshape(N_BLOCKS, BS // 2, 2 * C), rpool.reshape(N_BLOCKS, BS // 2, 2 * R)
     args = [q, q_rope, lat, rope, jnp.asarray(tables), jnp.asarray(seq)]
-    match = "batch" if wrong == "batch" else "do not match the pools"
+    match = {"batch": "batch", "no-query-axis": "queries a row"}.get(wrong, "do not match the pools")
     if wrong == "width":
         args[0] = q[..., :24]
     elif wrong == "dtype":
         args[0] = q.astype(jnp.bfloat16)
     elif wrong == "batch":
         args[5] = jnp.zeros((3,), jnp.int32)
-    else:
+    elif wrong == "rope-pool":
         args[3] = rpool  # pages of another fold than the latents'
+    elif wrong == "no-query-axis":
+        args[0], args[1] = q[:, 0], q_rope[:, 0]  # (B, H, C): the single-query kernel's operands
+    else:
+        args[1] = q_rope[:, :1]  # two latent queries a row, one rope slice
     with pytest.raises(ValueError, match=match):
         pk.latent_decode_attention(*args, scale=SCALE, pages_per_step=3)
 
@@ -191,14 +205,19 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_kernel_compiles_for_the_chip_at_the_cells_size(one_chip):
-    """32 rows x 32 heads over 129 pages of the folded (4097, 32, 1024) and
-    (4097, 32, 128) bfloat16 pools (``serve_xing_decode_7k``): Mosaic takes it,
-    both pools enter the custom call as they lie (no copy, slice or relayout of
-    either), and nothing of the size of a gathered copy is made."""
+@pytest.mark.parametrize("b,t,n_blocks,nb", [(32, 1, 4097, 129), (64, 2, 4161, 65), (64, mla.KERNEL_QUERIES, 4161, 65)],
+                         ids=["xing-decode-step", "joyai-mtp-round", "the-most-queries-a-row"])
+def test_kernel_compiles_for_the_chip_at_the_cells_size(one_chip, b, t, n_blocks, nb):
+    """32 rows x 1 query x 32 heads over 129 pages of the folded (4097, 32,
+    1024) and (4097, 32, 128) bfloat16 pools (``serve_xing_decode_7k``), 64 rows
+    x 2 queries x 32 heads over 65 pages of (4161, 32, 1024) and (4161, 32, 128)
+    (``serve_joyai_mtp_decode_2k``'s round), and the latter at the most queries
+    ``mla.decode_form`` sends here: Mosaic takes it, both pools enter the custom
+    call as they lie (no copy, slice or relayout of either), and nothing of the
+    size of a gathered copy is made."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    b, h, c, r, bs, n_blocks, nb = 32, 32, 512, 64, 64, 4097, 129
+    h, c, r, bs = 32, 512, 64, 64
     fold = mla.page_fold(bs, r)
     page = (n_blocks, bs // fold)
     shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
@@ -208,7 +227,7 @@ def test_kernel_compiles_for_the_chip_at_the_cells_size(one_chip):
     compilation_cache.reset_cache()
     try:
         compiled = jax.jit(fn).lower(
-            shape((b, h, c)), shape((b, h, r)), shape(page + (fold * c,)), shape(page + (fold * r,)),
+            shape((b, t, h, c)), shape((b, t, h, r)), shape(page + (fold * c,)), shape(page + (fold * r,)),
             shape((b, nb), jnp.int32), shape((b,), jnp.int32),
         ).compile()
     finally:

@@ -465,7 +465,7 @@ def test_a_self_drafting_round_says_so_on_its_spans(monkeypatch):
     rounds = [(t0, t0 + dur, depth, meta) for name, t0, dur, _, depth, meta in events if name == "serving.spec_round"]
     windows = [(t0, t0 + dur, depth) for name, t0, dur, _, depth, _ in events if name == "serving.dispatch_window"]
     assert len(rounds) == len(windows) == st["spec_rounds"]
-    assert all(m == {"k": 1, "draft": "mtp"} for *_, m in rounds)
+    assert all(m == {"k": 1, "draft": "mtp", "attention": "gather"} for *_, m in rounds)  # no TPU here
     for a, b, depth, _ in rounds:
         assert any(wa <= a and b <= wb and wdepth < depth for wa, wb, wdepth in windows)
 

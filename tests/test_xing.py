@@ -94,18 +94,6 @@ def test_parameter_count_is_the_tree_and_the_familys(params):
 # -- 2. prefill into the latent pool, then decode through the page tables -------------
 
 
-@pytest.fixture
-def kernel_forced(monkeypatch):
-    """``mla.decode_form`` answers as on a TPU, so one query a row through the
-    latent pool takes ``ops/pallas_latent.py`` (interpreted here) and several
-    still take the gather form. A jitted program keeps the form it was traced
-    with, so the caches go before and after."""
-    monkeypatch.setattr(mla, "decode_form", functools.partial(mla.decode_form, backend="tpu"))
-    jax.clear_caches()
-    yield
-    jax.clear_caches()
-
-
 def _traces_the_kernel(fn, *args):
     return "pallas_call" in str(jax.make_jaxpr(fn)(*args))
 
@@ -169,29 +157,31 @@ def test_paged_decode_matches_the_reference(params, readmit, form, request):
         assert rel_err(rows, want) < LOGITS_TOL
 
 
-def test_several_queries_a_row_keep_the_gather_form(params, kernel_forced):
-    """t > 1 through the pool (the chunk lane): the gather form whatever the
-    backend, and the same numbers as t = 1 steps through the kernel."""
+@pytest.mark.parametrize("t", [3, mla.KERNEL_QUERIES + 1], ids=["a-few-queries", "a-chunk"])
+def test_several_queries_a_row_take_the_form_their_count_allows(params, kernel_forced, t):
+    """t > 1 through the pool: a few queries a row (a speculative round's)
+    through the kernel as one is, more (the chunk lane) in the gather form
+    whatever the backend, and either way the same numbers as t = 1 steps."""
     p, bs = params[SEEDS[0]], 8
-    toks = tokens(5, 19)
+    toks = tokens(5, 16 + t)
     pools = tr.make_paged_kv_pool(CFG, 16, bs)
     ids = [3, 9, 4]
     _, pools = paged.prefill_into_pool(p, CFG, pools, toks[:16].tolist(), ids[:2])
     tables = jnp.zeros((1, 4), jnp.int32).at[0, :3].set(jnp.asarray(ids))
 
-    def chunk(pools):  # three queries at slots 16..18 in one call
-        return tr.forward(p, jnp.asarray(toks[None, 16:19]), CFG, kv_cache=pools,
+    def chunk(pools):  # t queries at slots 16.. in one call
+        return tr.forward(p, jnp.asarray(toks[None, 16:]), CFG, kv_cache=pools,
                           paged=tr.PagedInfo(tables, jnp.asarray([16], jnp.int32)))[0]
 
-    assert not _traces_the_kernel(chunk, pools)
+    assert _traces_the_kernel(chunk, pools) == (t <= mla.KERNEL_QUERIES)
     together = np.asarray(chunk(pools)[0], np.float32)
     one_by_one = []
-    for i in range(3):
+    for i in range(t):
         logits, pools = paged.paged_decode_logits(
             p, pools, jnp.asarray(toks[16 + i : 17 + i]), tables, jnp.asarray([16 + i], jnp.int32), cfg=CFG)
         one_by_one.append(np.asarray(logits[0]))
     assert rel_err(together, np.stack(one_by_one)) < LOGITS_TOL
-    assert rel_err(together, reference_logits(SEEDS[0], toks)[16:19]) < LOGITS_TOL
+    assert rel_err(together, reference_logits(SEEDS[0], toks)[16:]) < LOGITS_TOL
 
 
 # -- 3. the absorbed form is the expanded form ----------------------------------------
