@@ -60,7 +60,7 @@ import numpy as np
 from pretraining_llm_tpu.config import ModelConfig
 from pretraining_llm_tpu.generation import paged, speculative
 from pretraining_llm_tpu.generation import prefix_cache as prefix_cache_mod
-from pretraining_llm_tpu.models import mla, moe, transformer
+from pretraining_llm_tpu.models import kda, mla, moe, transformer
 from pretraining_llm_tpu.observability import spans as _spans
 
 _log = logging.getLogger("pretraining_llm_tpu.serving")
@@ -367,6 +367,12 @@ class ServingEngine:
             self.decode_experts = moe.experts_form(
                 queries * int(max_batch) * cfg.experts_per_token, cfg, experts, mesh=mesh
             )
+        # And how it steps a KDA layer's state slots (models/kda.py::step_form,
+        # read from the pool's own shape and dtype); None without any.
+        self.decode_state = None
+        if cfg.layer_group_size:
+            shape, dtype = kda.state_shapes(cfg, int(max_batch) + 1)["state"]
+            self.decode_state = kda.step_form(jax.ShapeDtypeStruct(shape, dtype), mesh=mesh)
         self.max_batch = int(max_batch)
         self.block_size = int(block_size)
         # Clamp max_seq so EVERY reachable prefill bucket fits the model
@@ -668,6 +674,7 @@ class ServingEngine:
             info.update(
                 state_slots=self.max_batch, state_bytes=state,
                 bytes_per_slot=state // (self.max_batch + 1),
+                decode_state=self.decode_state,  # "kernel" | "jnp"
             )
         if self.self_draft:
             # the module's pages are one more layer of ``pools``, counted above
@@ -2444,6 +2451,8 @@ class ServingEngine:
                     self.decode_experts, st["moe_expert_tokens"].sum(),
                     st["moe_experts_touched"].sum(), st["moe_steps"],
                 )
+            if self.state_slots:
+                routing += "; state slots stepped as %s" % self.decode_state
             if self.spec_k:
                 routing += "; %d speculative rounds (draft: %s) proposed %d, accepted %d" % (
                     st.get("spec_rounds", 0), "mtp" if self.self_draft else "model",

@@ -16,7 +16,19 @@ convolution's tail, the last ``kernel - 1`` projected inputs of every channel.
 
 Two forms of the recurrence, one function of the inputs:
 
-- ``recurrent_step``: one token a row, the equations as written (``kda.step``);
+- one token a row, the equations as written (``kda.step``). ``recurrent_step``
+  is them in four ``jnp`` lines; ``ops/pallas_kda.py`` is the same four lines as
+  one kernel that brings a row's state into VMEM once and writes it back to the
+  buffer it came from (XLA's two fusions of the ``jnp`` form cross HBM three
+  and a half times). ``step_form`` reads which of the two runs from the state
+  itself, as ``mla.decode_form`` and ``moe.experts_form`` read theirs: the
+  kernel for a float32 state of whole 128-lane tiles that no mesh shards, on a
+  TPU; the ``jnp`` lines everywhere else, and as the reference the tests hold
+  the kernel to. Every ``t == 1`` call of ``mix`` (the decode step over the
+  pools as they lie, the gathered slots of ``paged.slots``, ``generate``'s
+  contiguous cache) goes through that one choice. The pool keeps its
+  (slots, H, K, V) layout for both: ``chunked``, ``paged._scatter_pages`` and
+  the benchmark's check and byte count read it so;
 - ``chunked``: many tokens a row, chunk by chunk in the WY/UT form
   (``kda.chunk``). Inside a chunk of ``CHUNK`` tokens, with ``G_i`` the
   cumulative log-decay, ``u_i = beta_i (v_i - S'^T k_i)`` solves the unit lower
@@ -45,6 +57,8 @@ import jax.numpy as jnp
 
 from pretraining_llm_tpu.config import ModelConfig
 from pretraining_llm_tpu.models import layers
+from pretraining_llm_tpu.ops import pallas_kda
+from pretraining_llm_tpu.parallel.sharding import current_mesh
 
 Params = Dict[str, Any]
 
@@ -95,6 +109,23 @@ def recurrent_step(state, q, k, v, g, beta):
     u = beta[..., None] * (v - jnp.einsum("bhkv,bhk->bhv", s, k, precision=_HI))
     s = s + k[..., None] * u[..., None, :]
     return jnp.einsum("bhkv,bhk->bhv", s, q, precision=_HI), s
+
+
+def step_form(state: Any, mesh: Any = None, backend: Optional[str] = None) -> str:
+    """The form one token of the recurrence takes over ``state`` (anything with
+    a shape and a dtype): ``"kernel"`` (``ops/pallas_kda.py``: a row's state
+    read once and written once, in place) for a float32 state (rows, H, K, V)
+    of whole 128-lane tiles that no mesh shards, where Mosaic compiles;
+    ``"jnp"`` (``recurrent_step``) for every other state and backend. Read from
+    the input, never from an option; the engine reports the form of its decode
+    program in ``pool_info()``."""
+    if (
+        pallas_kda.takes(tuple(state.shape), state.dtype)
+        and mesh is None
+        and (backend or jax.default_backend()) == "tpu"
+    ):
+        return "kernel"
+    return "jnp"
 
 
 def chunked(state, q, k, v, g, beta):
@@ -228,7 +259,8 @@ def mix(
             beta = jnp.where(valid[:, :, None], beta, 0.0)
     if t == 1:
         with jax.named_scope("kda.step"):
-            o, state = recurrent_step(state, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+            step = pallas_kda.recurrent_step if step_form(state, current_mesh()) == "kernel" else recurrent_step
+            o, state = step(state, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
             o = o[:, None]
     else:
         with jax.named_scope("kda.chunk"):

@@ -2,7 +2,7 @@
 """Compile and run every Pallas kernel on the TPU against its XLA reference,
 at the sizes serving and training use (attention: Dh 64, 12 heads, bf16; the
 paged decode step also at 32 heads of 128; the expert FFN at the two serving
-cells' expert shapes).
+cells' expert shapes; the KDA step over the Ling cell's state pool).
 
 The CPU tests run these kernels in interpret mode at toy sizes; only the
 chip hears Mosaic's refusals (tiling, unaligned slices, VMEM) and only there
@@ -257,7 +257,32 @@ def time_moe(reps: int = 20):
             del xs, w1, w2
 
 
+def kda_case(rows: int, heads: int):
+    """``ops/pallas_kda.py`` against ``kda.recurrent_step``, the state donated
+    to both as the decode programs donate the pools; the last row is dead
+    (``g = 0``, ``beta = 0``) and must come back bit for bit."""
+    from pretraining_llm_tpu.models import kda
+    from pretraining_llm_tpu.ops import pallas_kda
+
+    n = 128
+    ks = jax.random.split(jax.random.key(rows + heads), 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[1], (rows, heads, n))) * n ** -0.5
+    k = unit(jax.random.normal(ks[2], (rows, heads, n)))
+    v = jax.random.normal(ks[3], (rows, heads, n))
+    g = (-5.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (rows, heads, n)))).at[-1].set(0.0)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (rows, heads))).at[-1].set(0.0)
+    state = lambda: jax.random.normal(ks[0], (rows, heads, n, n), jnp.float32)
+    dead = np.asarray(state()[-1])
+    got_o, got_s = jax.jit(pallas_kda.recurrent_step, donate_argnums=0)(state(), q, k, v, g, beta)
+    want_o, want_s = jax.jit(kda.recurrent_step, donate_argnums=0)(state(), q, k, v, g, beta)
+    return {"o": _err(got_o, want_o), "state": _err(got_s, want_s),
+            "dead_row": 0.0 if np.array_equal(np.asarray(got_s[-1]), dead) else float("inf")}, 1e-4
+
+
 def cases():
+    for rows, heads in ((129, 32), (3, 4), (2, 12)):  # the Ling cell's pool; part groups of heads
+        yield f"kda rows{rows} heads{heads}", kda_case, (rows, heads)
     for shape in MOE_SHAPES:
         for mix in MOE_MIXES:
             for layer, clamp in ((0, False), (MOE_SHAPES[shape][0] - 1, True)):

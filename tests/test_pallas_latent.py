@@ -319,3 +319,57 @@ def test_expert_kernel_compiles_for_the_chip_at_the_cells_size(one_chip, stack, 
     assert len(stack_sized) == 2 and all(" bitcast(" in op for op in stack_sized), stack_sized
     assert 2 * 3 * d * tf * 2 <= pm.WEIGHT_TILE_BYTES
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_kda_step_compiles_for_the_chip_over_the_cells_pools_as_they_lie(one_chip, monkeypatch):
+    """``kda.mixer_block`` of a decode step at ``serve_ling_decode_4k``'s size
+    (128 rows over 129 state slots of 32 heads x 128 x 128 float32, d_model
+    2560), the pools donated as the decode programs donate them: Mosaic takes
+    ``ops/pallas_kda.py`` with a row's 2 MB of state a block each way, the
+    state pool enters the custom call as it lies and the new pool is the same
+    buffer (no copy, no second array of the pool's size), and XLA's two
+    fusions over the state are gone."""
+    import dataclasses
+    import functools
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from pretraining_llm_tpu.config import get_preset
+    from pretraining_llm_tpu.models import kda, layers, transformer
+    from pretraining_llm_tpu.ops import pallas_kda
+
+    rows, heads, n, d = 128, 32, 128, 2560
+    cfg = dataclasses.replace(get_preset("ling-mini").model, d_model=d, n_heads=heads, kda_head_dim=n,
+                              param_dtype="bfloat16", compute_dtype="bfloat16")
+    monkeypatch.setattr(kda, "step_form", functools.partial(kda.step_form, backend="tpu"))
+    monkeypatch.setattr(pallas_kda, "recurrent_step", functools.partial(pallas_kda.recurrent_step, interpret=False))
+    placed = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    blk = placed(jax.eval_shape(lambda: {
+        "ln1": layers.init_norm("rmsnorm", d, jnp.bfloat16),
+        "attn": kda.init_params(cfg, jax.random.key(0), 0.02, jnp.bfloat16),
+    }))
+    pools = placed({name + "_pool": jax.ShapeDtypeStruct(*spec) for name, spec in kda.state_shapes(cfg, rows + 1).items()})
+    assert kda.step_form(pools["state_pool"]) == "kernel"
+
+    def fn(blk, pools, x, tables, seq_lens):
+        return kda.mixer_block(blk, x, cfg, pools, paged=transformer.PagedInfo(tables, seq_lens))
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable without a chip
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(fn, donate_argnums=1).lower(
+            blk, pools, placed(jax.ShapeDtypeStruct((rows, 1, d), jnp.bfloat16)),
+            placed(jax.ShapeDtypeStruct((rows, 65), jnp.int32)), placed(jax.ShapeDtypeStruct((rows,), jnp.int32)),
+        ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "output_to_operand_aliasing={{1}: (5, {})}" in text
+    ops = [line.split(" = ", 1)[1] for line in text.splitlines() if " = " in line]
+    pool_sized = [op for op in ops if f"f32[{rows + 1},{heads},{n},{n}]" in op.split("(", 1)[0] and " parameter(" not in op]
+    made = [op for op in pool_sized if not any(f" {kind}(" in op for kind in ("custom-call", "get-tuple-element", "tuple"))]
+    assert pool_sized and not made, made
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

@@ -152,7 +152,9 @@ def test_watchdog_fires_and_reports_exit_code():
     try:
         dog.heartbeat()  # arm
         deadline = time.monotonic() + 5.0
-        while not dog.fired and time.monotonic() < deadline:
+        # exit_fn is the last thing the fire path does, after the stacks of every
+        # thread of the process are dumped: ``fired`` alone is set long before it
+        while not codes and time.monotonic() < deadline:
             time.sleep(0.05)
         assert dog.fired
         assert codes == [EXIT_WEDGED]
