@@ -221,9 +221,29 @@ class ModelConfig:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
-    # A head-wise sigmoid gate on latent attention's output, from the
-    # sublayer's input: o_h * sigmoid((x W_gate)_h).
+    # A sigmoid gate on the attention output, from the sublayer's input. Latent
+    # attention: head-wise, o_h * sigmoid((x W_gate)_h), W_gate (D, H). Per-head
+    # attention: element-wise, o * sigmoid(x W_gate), W_gate (D, H, Dh).
     attn_output_gate: bool = False
+    # Per-layer attention kind of a per-head model, "window" (the last
+    # sliding_window positions) or "full" (every earlier position), one a
+    # layer; empty = every layer the one kind sliding_window implies. A stack
+    # that mixes the two keeps two cache lifetimes when served: full layers a
+    # row's pages for its whole length, window layers a pool of their own whose
+    # pages are given back behind the window (generation/serving.py). The
+    # stack is scanned as runs of like layers (layer_runs).
+    attn_kinds: Tuple[str, ...] = ()
+    # False: full layers of a mixed stack carry no position encoding (RoPE on
+    # the window layers only).
+    rope_full_layers: bool = True
+    # RMSNorm with a learned weight over each head's queries and keys, before
+    # RoPE (per-head attention; latent attention norms its own low-rank parts).
+    qk_norm: bool = False
+    # Two more norms a layer: x + N_post_attn(attn(N_in(x))), then
+    # x + N_post_mlp(mlp(N_pre_mlp(x))).
+    sandwich_norm: bool = False
+    # Token embeddings times sqrt(d_model) (muP's input scale).
+    embed_scale: bool = False
     # Hybrid stack, layer_group_size g > 0: layer i is a latent-attention
     # layer when (i + 1) % g == 0 and a KDA linear-attention layer otherwise
     # (models/kda.py; arXiv:2510.26692). A KDA layer has n_heads heads of
@@ -419,8 +439,29 @@ class ModelConfig:
                     "a hybrid stack runs with plain residuals, no pipeline and no "
                     "ring/ulysses attention"
                 )
-        if self.attn_output_gate and not self.kv_lora_rank:
-            raise ValueError("attn_output_gate is latent attention's (kv_lora_rank)")
+        # a JSON round trip hands back a list; the config is a static (hashed) jit argument
+        object.__setattr__(self, "attn_kinds", tuple(self.attn_kinds))
+        if self.attn_kinds:
+            if len(self.attn_kinds) != self.n_layers or set(self.attn_kinds) - {"window", "full"}:
+                raise ValueError(
+                    f"attn_kinds names 'window' or 'full' for each of n_layers={self.n_layers} layers"
+                )
+            if "window" in self.attn_kinds and self.sliding_window < 1:
+                raise ValueError("a 'window' layer needs sliding_window")
+            if (self.kv_lora_rank or self.layer_group_size or self.mtp_depth or self.hc_mult > 1
+                    or self.pipeline_stages > 1 or self.kv_cache_dtype != "compute"):
+                raise ValueError(
+                    "attn_kinds is per-head attention's, over an unquantized cache: no latent "
+                    "attention, hybrid stack, multi-token-prediction module, residual streams, "
+                    "pipeline or int8 cache"
+                )
+        if not self.rope_full_layers and (self.pos_embed != "rope" or "full" not in self.attn_kinds):
+            raise ValueError("rope_full_layers=False needs pos_embed='rope' and attn_kinds with full layers")
+        if (self.qk_norm or self.sandwich_norm) and (self.kv_lora_rank or self.layer_group_size or self.hc_mult > 1):
+            raise ValueError(
+                "qk_norm and sandwich_norm are per-head attention's and the plain residual's: "
+                "no latent attention, hybrid stack or residual streams"
+            )
         if self.mtp_depth not in (0, 1):
             raise ValueError(
                 f"mtp_depth={self.mtp_depth}: one multi-token-prediction module is built "
@@ -577,11 +618,22 @@ class ModelConfig:
         )
 
     @property
+    def layer_attn_kinds(self) -> Tuple[str, ...]:
+        """"window" or "full" for every layer (a KDA layer's entry says nothing)."""
+        return self.attn_kinds or (("window" if self.sliding_window else "full",) * self.n_layers)
+
+    @property
+    def two_lifetimes(self) -> bool:
+        """Window and full attention layers in one stack: two cache lifetimes."""
+        return len(set(self.attn_kinds)) == 2
+
+    @property
     def layer_runs(self) -> Tuple[Tuple[int, int], ...]:
         """The stack as runs of like layers, (first, past the last): what
         ``forward`` scans one at a time. A homogeneous model is one run, or two
-        with leading dense layers."""
-        kinds, runs, start = self.layer_kinds, [], 0
+        with leading dense layers; a stack of window and full attention layers
+        splits where the attention kind changes too."""
+        kinds, runs, start = tuple(zip(self.layer_kinds, self.layer_attn_kinds)), [], 0
         for i in range(1, self.n_layers + 1):
             if i == self.n_layers or kinds[i] != kinds[start]:
                 runs.append((start, i))
@@ -629,7 +681,8 @@ class ModelConfig:
         n = v * d  # token embedding
         if self.pos_embed == "learned":
             n += t * d
-        shared = self._attn_params() + 2 * self._norm_params() + 2 * self._hc_params()
+        norms = 4 if self.sandwich_norm else 2
+        shared = self._attn_params() + norms * self._norm_params() + 2 * self._hc_params()
         moe_layers = self.n_layers - self.n_dense_layers if self.n_experts else 0
         n += (self.n_layers - moe_layers) * (shared + self._ffn_params(self.d_ff))
         n += moe_layers * (shared + self._moe_params(self.experts_held))
@@ -656,6 +709,10 @@ class ModelConfig:
             n += h * dh + 2 * g * dh
         if self.use_output_proj:
             n += h * dh * d + d  # wo + bias
+        if self.attn_output_gate:
+            n += d * h * dh
+        if self.qk_norm:
+            n += 2 * dh
         return n
 
     def _kda_params(self) -> int:
@@ -1752,6 +1809,34 @@ _register(
             n_experts=16, n_experts_held=8, experts_per_token=2, moe_routing="dropless",
             moe_score="sigmoid", moe_score_bias=True, moe_routed_scale=2.5, n_shared_experts=1,
             d_expert=32, n_dense_layers=1, mtp_depth=1,
+        ),
+        mesh=MeshConfig(),
+        data=DataConfig(tokenizer_name="byte"),
+        train=TrainConfig(batch_size=8, train_steps=50, eval_interval=20, eval_iters=2, lr=1e-3),
+    ),
+)
+
+# Every mechanism of the Trinity (afmoe) family at a width a CPU smoke run
+# holds: a period of three window layers (16 positions) to one full layer with
+# no position encoding, grouped-query attention with QK-norm and an
+# element-wise output gate, four norms a layer, a scaled embedding, a leading
+# dense layer, sigmoid-routed dropless experts with a shared one. Served, the
+# window layers keep a page pool of their own (two cache lifetimes). The
+# published widths are benchmark/configs/trinity-mini.json, the published
+# model's own name; this preset is for the unit tests and serve.py.
+_register(
+    "trinity-toy",
+    Config(
+        model=ModelConfig(
+            vocab_size=256, context_length=256, d_model=64, n_heads=4, n_kv_heads=2, n_layers=5,
+            d_head=16, mlp_ratio=2.5, activation="swiglu", norm="rmsnorm", pos_embed="rope",
+            tie_embeddings=False, mlp_bias=False, norm_eps=1e-5,
+            sliding_window=16, attn_kinds=("window", "window", "window", "window", "full"),
+            rope_full_layers=False, qk_norm=True, attn_output_gate=True, sandwich_norm=True,
+            embed_scale=True,
+            n_experts=8, experts_per_token=2, moe_routing="dropless", moe_score="sigmoid",
+            moe_score_bias=True, moe_routed_scale=2.826, n_shared_experts=1, d_expert=32,
+            n_dense_layers=1,
         ),
         mesh=MeshConfig(),
         data=DataConfig(tokenizer_name="byte"),

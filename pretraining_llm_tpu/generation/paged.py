@@ -78,6 +78,23 @@ def prefill_bucket(cfg: ModelConfig, n_rows: int, max_pages: int, block_size: in
     return 1 << (n_rows - 1).bit_length(), max(max_pages, pages)
 
 
+def window_first_block(seq_len: int, window: int, block_size: int) -> int:
+    """The first page a window layer still needs of a row with ``seq_len``
+    tokens cached: its next query, at position ``seq_len``, sees the positions
+    above ``seq_len - window``. Every page before it lies wholly behind the
+    window, for this query and every later one."""
+    return max(0, seq_len - window + 1) // block_size
+
+
+def window_layers(cfg: ModelConfig) -> Tuple[int, ...]:
+    """The layers that keep the second cache lifetime (a pool of their own,
+    pages given back behind the window); empty unless the stack mixes window
+    and full attention layers."""
+    if not cfg.two_lifetimes:
+        return ()
+    return tuple(i for i, kind in enumerate(cfg.attn_kinds) if kind == "window")
+
+
 def state_slots(pools: transformer.KVCache) -> int:
     """State slots a hybrid stack's pools hold, the scratch slot (the last) not
     counted; 0 for a model that keeps pages alone."""
@@ -161,6 +178,8 @@ def _scatter_staged_pages(
     flat_ids: jax.Array,  # (n_rows * n_pages,) int32 pool block ids
     n_chunks: int,  # n_rows * n_pages (static)
     slots: Optional[jax.Array] = None,  # (n_rows,) int32 state slots
+    window_ids: Optional[jax.Array] = None,  # (n_rows * n_pages,) int32, the window layers' pool
+    in_window_pool: Tuple[int, ...] = (),  # the layers that scatter at window_ids (window_layers)
 ) -> transformer.KVCache:
     """ONE definition of the staged-cache -> pool page scatter, shared by
     the single-prompt and batched admission prefills. The staged cache is
@@ -170,7 +189,10 @@ def _scatter_staged_pages(
     layer's pool at ``flat_ids`` (pad pages point at the reserved scratch
     block 0 — duplicate indices there are benign). A KDA layer's staged state
     and conv tail go whole into slot ``slots[row]`` of its state pools (pad
-    rows: the scratch slot), written with the pages."""
+    rows: the scratch slot), written with the pages. The layers
+    ``in_window_pool`` (two cache lifetimes) scatter at ``window_ids`` into
+    their own pool: a page that lies wholly behind the window already has id 0
+    there and lands on the scratch block like a pad page."""
     # per layer only where the layers keep unlike caches (_staging_cache)
     per_layer = "layers" in dense_cache and any("state_pool" in lp for lp in pools["layers"])
     field = (lambda layer, key: dense_cache["layers"][layer][key]) if per_layer else (
@@ -199,7 +221,8 @@ def _scatter_staged_pages(
             # head; (bs / fold, fold * c) of latents and (bs / fold, fold * r) of
             # rotated key slices, the same values row-major as (bs, c) and (bs, r)
             pages = field(layer, dense_key).reshape((n_chunks,) + pool.shape[1:])
-            out[pool_key] = pool.at[flat_ids].set(pages.astype(pool.dtype))
+            ids = window_ids if layer in in_window_pool else flat_ids
+            out[pool_key] = pool.at[ids].set(pages.astype(pool.dtype))
         return out
 
     with jax.named_scope("attn.kv_write"):
@@ -252,13 +275,15 @@ def _rows_at(hidden: jax.Array, idx: jax.Array) -> jax.Array:
     return jnp.take_along_axis(hidden, jnp.broadcast_to(idx[:, None, None], (n, 1, d)), axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("n_pages",), donate_argnums=(0,))
+@functools.partial(jax.jit, static_argnames=("n_pages", "in_window_pool"), donate_argnums=(0,))
 def _scatter_pages(
     pools: transformer.KVCache,
     dense_cache: transformer.KVCache,
     block_ids: jax.Array,  # (n_pages,) int32
     n_pages: int,
     slot: Optional[jax.Array] = None,  # () int32
+    window_ids: Optional[jax.Array] = None,  # (n_pages,) int32
+    in_window_pool: Tuple[int, ...] = (),
 ) -> transformer.KVCache:
     """Scatter a (L, 1, n_pages*bs, ...) dense prefill cache into the pools
     at ``block_ids``. Donated pools: the update is in-place on device. (The
@@ -268,7 +293,10 @@ def _scatter_pages(
     (mod the batch rows), the row a caller that builds its tables in prefill
     order decodes it at."""
     if "state_cursor" not in pools:
-        return _scatter_staged_pages(pools, dense_cache, block_ids, n_pages)
+        return _scatter_staged_pages(
+            pools, dense_cache, block_ids, n_pages, window_ids=window_ids,
+            in_window_pool=in_window_pool,
+        )
     cursor = pools["state_cursor"]
     if slot is None:
         slot, cursor = cursor % state_slots(pools), cursor + 1
@@ -323,8 +351,13 @@ def prefill_into_pool(
     *,
     mesh: Any = None,
     slot: Optional[int] = None,
+    window_block_ids: Optional[Sequence[int]] = None,
 ) -> Tuple[jax.Array, transformer.KVCache]:
     """Prefill one prompt and write its pages into the pool.
+
+    ``window_block_ids`` (two cache lifetimes): as many ids as ``block_ids``,
+    the window layers' pages in their own pool, 0 for every page that lies
+    wholly behind the window (``window_first_block``).
 
     ``block_ids`` must be exactly ceil(len(prompt)/block_size) pages
     (allocator output). Returns (last-token logits (V,) fp32, updated
@@ -347,9 +380,19 @@ def prefill_into_pool(
     last, dense = _prefill_dense(
         params, prompt, jnp.int32(p), cfg, p_bucket, mesh
     )
+    in_window_pool = window_layers(cfg)
+    if bool(in_window_pool) != (window_block_ids is not None) or (
+        in_window_pool and len(window_block_ids) != n_pages
+    ):
+        raise ValueError(
+            "a stack with two cache lifetimes, and no other, names window_block_ids, "
+            "one id a page of block_ids"
+        )
     pools = _scatter_pages(
         pools, dense, jnp.asarray(block_ids, jnp.int32), n_pages,
         None if slot is None else jnp.int32(slot),
+        None if window_block_ids is None else jnp.asarray(window_block_ids, jnp.int32),
+        in_window_pool,
     )
     return last, pools
 
@@ -379,6 +422,7 @@ def _prefill_scatter_sample(
     mesh: Any = None,
     slots: Optional[jax.Array] = None,  # (N,) int32 state slots (pad rows: the scratch slot)
     with_draft: bool = False,
+    window_ids: Optional[jax.Array] = None,  # (N, n_pages) int32: the window layers' pages, 0 behind the window
 ) -> Tuple[jax.Array, transformer.KVCache]:
     """Batched admission in ONE device program: causal prefill over N
     padded prompts -> scatter every row's pages into the pools -> sample
@@ -435,7 +479,8 @@ def _prefill_scatter_sample(
                 toks = jnp.stack([toks, jnp.argmax(m_last, axis=-1).astype(jnp.int32)], axis=1)
 
         pools = _scatter_staged_pages(
-            pools, cache, block_ids.reshape(-1), n_rows * n_pages, slots
+            pools, cache, block_ids.reshape(-1), n_rows * n_pages, slots,
+            None if window_ids is None else window_ids.reshape(-1), window_layers(cfg),
         )
         return toks, pools
 
@@ -455,6 +500,7 @@ def prefill_into_pool_batched(
     mesh: Any = None,
     slots: Optional[Sequence[int]] = None,
     with_draft: bool = False,
+    rows_window_ids: Optional[Sequence[Sequence[int]]] = None,
 ) -> Tuple[jax.Array, transformer.KVCache]:
     """Prefill N prompts and write all their pages into the pool in one
     device program; returns (first sampled token per prompt — a DEVICE
@@ -464,7 +510,9 @@ def prefill_into_pool_batched(
     pages. Rows and pages are bucketed (``prefill_bucket``). ``slots[i]`` is
     prompt i's state slot (state-slot models). ``with_draft``: the model's MTP
     module is prefilled too and the first value is (N, 2), first token and
-    first draft (``_prefill_scatter_sample``).
+    first draft (``_prefill_scatter_sample``). ``rows_window_ids[i]`` (two
+    cache lifetimes): as many ids as ``rows_block_ids[i]``, the window layers'
+    pages in their own pool, 0 for each page wholly behind the window.
     """
     block_size = pool_block_size(pools, cfg)
     n = len(prompts)
@@ -483,6 +531,8 @@ def prefill_into_pool_batched(
         pages.append(np_i)
     import numpy as np
 
+    if bool(window_layers(cfg)) != (rows_window_ids is not None):
+        raise ValueError("a stack with two cache lifetimes, and no other, names rows_window_ids")
     bucket_rows, bucket_pages = prefill_bucket(cfg, n, max(pages), block_size)
     p_bucket = bucket_pages * block_size
     prompt_arr = np.zeros((bucket_rows, p_bucket), np.int32)
@@ -492,11 +542,19 @@ def prefill_into_pool_batched(
         prompt_arr[i, : len(p)] = p
         lens[i] = len(p)
         ids_arr[i, : len(ids)] = ids
+    window_arr = None
+    if rows_window_ids is not None:
+        window_arr = np.zeros((bucket_rows, bucket_pages), np.int32)
+        for i, (ids, own) in enumerate(zip(rows_block_ids, rows_window_ids)):
+            if len(own) != len(ids):
+                raise ValueError(f"prompt {i}: {len(ids)} pages but {len(own)} window ids")
+            window_arr[i, : len(own)] = own
+        window_arr = jnp.asarray(window_arr)
     toks, pools = _prefill_scatter_sample(
         params, pools, jnp.asarray(prompt_arr), jnp.asarray(lens),
         jnp.asarray(ids_arr), key, cfg, p_bucket, bucket_pages,
         temperature, top_k, top_p, min_p, mesh, _slot_array(pools, slots, bucket_rows),
-        with_draft,
+        with_draft, window_arr,
     )
     return toks[:n], pools
 
@@ -658,7 +716,7 @@ def prefill_suffix_into_pool_batched(
 def _forward_sample_one(
     params, pools, tokens, block_tables, seq_lens, key, cfg,
     temperature, top_k, top_p, min_p, mesh=None, logprobs_k=0,
-    with_moe_counts=False,
+    with_moe_counts=False, window_tables=None,
 ):
     """The single decode step both jitted entry points trace: forward one
     token per row through the paged cache, sample the next. Kept as ONE
@@ -676,7 +734,8 @@ def _forward_sample_one(
         if with_moe_counts:
             logits, pools, counts = transformer.forward(
                 params, tokens[:, None], cfg, kv_cache=pools,
-                paged=PagedInfo(block_tables, seq_lens), return_moe_counts=True,
+                paged=PagedInfo(block_tables, seq_lens, window_tables=window_tables),
+                return_moe_counts=True,
             )
         else:
             logits, pools = transformer.forward(
@@ -684,7 +743,7 @@ def _forward_sample_one(
                 tokens[:, None],
                 cfg,
                 kv_cache=pools,
-                paged=PagedInfo(block_tables, seq_lens),
+                paged=PagedInfo(block_tables, seq_lens, window_tables=window_tables),
             )
         nxt, lp = sample_logits_fused(
             logits[:, 0], key, temperature=temperature, top_k=top_k,
@@ -713,6 +772,7 @@ def paged_decode_step(
     top_p: Optional[float] = None,
     min_p: Optional[float] = None,
     mesh: Any = None,
+    window_tables: Optional[jax.Array] = None,  # (B, max_blocks) int32: PagedInfo.window_tables
 ) -> Tuple[jax.Array, transformer.KVCache]:
     """One lockstep decode step for every batch row (active or idle).
 
@@ -726,7 +786,7 @@ def paged_decode_step(
     """
     nxt, _, pools = _forward_sample_one(
         params, pools, tokens, block_tables, seq_lens, key, cfg,
-        temperature, top_k, top_p, min_p, mesh,
+        temperature, top_k, top_p, min_p, mesh, window_tables=window_tables,
     )
     return nxt, pools
 
@@ -1008,6 +1068,7 @@ def paged_decode_steps(
     top_p: Optional[float] = None,
     min_p: Optional[float] = None,
     mesh: Any = None,
+    window_tables: Optional[jax.Array] = None,  # (B, max_blocks) int32: PagedInfo.window_tables
 ) -> Tuple[jax.Array, transformer.KVCache]:
     """``n_steps`` lockstep decode steps in ONE device program.
 
@@ -1038,11 +1099,12 @@ def paged_decode_steps(
             nxt, _, pools, counts = _forward_sample_one(
                 params, pools, tok, block_tables, seq, sub, cfg,
                 temperature, top_k, top_p, min_p, mesh, with_moe_counts=True,
+                window_tables=window_tables,
             )
             return (pools, nxt, seq + 1), (nxt, counts)
         nxt, _, pools = _forward_sample_one(
             params, pools, tok, block_tables, seq, sub, cfg,
-            temperature, top_k, top_p, min_p, mesh,
+            temperature, top_k, top_p, min_p, mesh, window_tables=window_tables,
         )
         return (pools, nxt, seq + 1), nxt
 
@@ -1080,6 +1142,7 @@ def paged_decode_step_lp(
     min_p: Optional[float] = None,
     mesh: Any = None,
     logprobs_k: int = 1,
+    window_tables: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, transformer.KVCache]:
     """`paged_decode_step` plus the top-k logprob payload (raw ``key``,
     preserving the sps=1 sampling stream exactly like its twin).
@@ -1087,6 +1150,7 @@ def paged_decode_step_lp(
     nxt, lp, pools = _forward_sample_one(
         params, pools, tokens, block_tables, seq_lens, key, cfg,
         temperature, top_k, top_p, min_p, mesh, logprobs_k=logprobs_k,
+        window_tables=window_tables,
     )
     return nxt, lp[0], lp[1], pools
 
@@ -1112,6 +1176,7 @@ def paged_decode_steps_lp(
     min_p: Optional[float] = None,
     mesh: Any = None,
     logprobs_k: int = 1,
+    window_tables: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, transformer.KVCache]:
     """`paged_decode_steps` with the top-k logprob payload.
 
@@ -1130,7 +1195,7 @@ def paged_decode_steps_lp(
         nxt, lp, pools = _forward_sample_one(
             params, pools, tok, block_tables, seq, sub, cfg,
             temperature, top_k, top_p, min_p, mesh,
-            logprobs_k=logprobs_k,
+            logprobs_k=logprobs_k, window_tables=window_tables,
         )
         return (pools, nxt, seq + 1), (nxt, lp[0], lp[1])
 
@@ -1159,6 +1224,7 @@ def paged_decode_logits(
     seq_lens: jax.Array,  # (B,) int32
     cfg: ModelConfig,
     mesh: Any = None,
+    window_tables: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, transformer.KVCache]:
     """UNFUSED decode forward: one step, raw (B, V) last-position logits.
 
@@ -1178,7 +1244,7 @@ def paged_decode_logits(
             tokens[:, None],
             cfg,
             kv_cache=pools,
-            paged=PagedInfo(block_tables, seq_lens),
+            paged=PagedInfo(block_tables, seq_lens, window_tables=window_tables),
         )
     return logits[:, 0].astype(jnp.float32), pools
 

@@ -91,6 +91,11 @@ class _Request:
     # Leading entries of ``blocks`` that are SHARED prefix-cache pages
     # (read-only; refcounted by the cache, never freed directly).
     n_shared: int = 0
+    # Two cache lifetimes: the row's LIVE pages in the window layers' pool,
+    # oldest first, and which page of the row the first one is; pages before
+    # it lay wholly behind the window and went back to that pool.
+    w_blocks: List[int] = dataclasses.field(default_factory=list)
+    w_first: int = 0
     row: Optional[int] = None
     admit_order: int = -1  # monotonically increasing per admission
     preemptions: int = 0
@@ -218,6 +223,24 @@ class ServingEngine:
                     "publishes into it) and a separate draft model's pool are not "
                     "built on the latent pool yet (spec_k with the model's own "
                     "multi-token-prediction module as the draft is)"
+                )
+        elif cfg.two_lifetimes:
+            # Window and full attention layers in one stack: two block lists a
+            # row, the window layers' pages given back behind the window.
+            refused = {
+                "prefix_cache": prefix_cache, "kv_checksum": kv_checksum,
+                "quantize=int8-kv": quantize == "int8-kv", "spec_k": bool(spec_k),
+                "prefill_chunk_tokens": bool(prefill_chunk_tokens),
+            }
+            if any(refused.values()):
+                raise ValueError(
+                    "a model of window and full attention layers (two cache lifetimes) is "
+                    "served without "
+                    + ", ".join(k for k, v in refused.items() if v)
+                    + ": the prefix cache (and kv_transfer, which publishes into it) and "
+                    "kv_checksum know one block list a row, int8 pages are not built on the "
+                    "window pool, and the speculative verify and the chunk lane read a row's "
+                    "pages through one table"
                 )
         if cfg.doc_mask_token >= 0:
             # Decode sessions are single documents; forward() rejects the
@@ -445,9 +468,22 @@ class ServingEngine:
         # decode activations follow via the in-forward constraints.
         self.mesh = mesh
 
+        # Two cache lifetimes: the window layers' pool is sized from what the
+        # engine knows, every row at its most: the pages that hold a window,
+        # one more where the window straddles a page boundary, and those a
+        # decode window's writes can open (n_blocks stays the full layers').
+        self.two_lifetimes = cfg.two_lifetimes
+        self.window_blocks = 0
+        if self.two_lifetimes:
+            per_row = (
+                paged.required_blocks(cfg.sliding_window, self.block_size) + 1
+                + paged.required_blocks(self.steps_per_sched, self.block_size)
+            )
+            self.window_blocks = self.max_batch * per_row + 1
+
         def _build_pool(pool_cfg: ModelConfig):
             pools = transformer.make_paged_kv_pool(
-                pool_cfg, n_blocks, block_size,
+                pool_cfg, n_blocks, block_size, window_blocks=self.window_blocks,
                 # bf16 scale pages are what carry int8-kv past the 1.9x
                 # block-capacity target; legacy int8 pools (kv_cache_dtype
                 # set directly, quantize='none') keep fp32 scales for
@@ -509,6 +545,10 @@ class ServingEngine:
         self.n_blocks = int(n_blocks)
         self.alloc = paged.BlockAllocator(n_blocks)
         self.tables = np.zeros((self.max_batch, self.max_blocks), np.int32)
+        # The window layers' allocator and table (PagedInfo.window_tables): as
+        # wide as ``tables`` and indexed the same way, naming live pages only.
+        self.w_alloc = paged.BlockAllocator(self.window_blocks) if self.two_lifetimes else None
+        self.w_tables = np.zeros_like(self.tables) if self.two_lifetimes else None
         self.seq_lens = np.zeros((self.max_batch,), np.int32)
         self.tokens = np.zeros((self.max_batch,), np.int32)
         # Self-drafting: each row's pending draft of the token after
@@ -616,9 +656,21 @@ class ServingEngine:
             # traffic fills, which is what the in-place kernel saves over
             # the gather form. Logged whenever the engine runs empty.
             "attn_pages_live": 0, "attn_pages_tabled": 0,
+            # Blocks of the pool owned by rows (or the prefix cache) after the
+            # newest tick, and the most after any.
+            "kv_blocks_in_use": 0, "kv_blocks_peak": 0,
             "ticks": 0, "slow_ticks": 0,
             "longest_tick": {"tick": 0, "seconds": 0.0, "phase_s": {}},
         }
+        if self.two_lifetimes:
+            # The second lifetime's own counters: the above then count a full
+            # layer's pages; these a window layer's (read: inside the window;
+            # tabled: the live pages its table names), its pool's blocks, and
+            # the pages given back behind the window.
+            self.stats.update(
+                window_attn_pages_live=0, window_attn_pages_tabled=0,
+                window_blocks_in_use=0, window_blocks_peak=0, window_pages_released=0,
+            )
         self._clock = _spans.PhaseClock(self.stats["phase_s"])
         self._tick_hist: deque = deque(maxlen=_spans.SLOW_HISTORY)
         # Cross-request prefix cache: content-addressed page reuse over
@@ -650,6 +702,13 @@ class ServingEngine:
         total = int(
             sum(leaf.nbytes for leaf in jax.tree.leaves(pools["layers"]))
         ) - state
+        # the window layers' own pool (two cache lifetimes); the per-block and
+        # per-token figures below are then the full layers'
+        window_layers = paged.window_layers(self.cfg)
+        window = int(sum(
+            leaf.nbytes for i in window_layers for leaf in jax.tree.leaves(pools["layers"][i])
+        ))
+        total -= window
         info = {
             "quantize": self.quantize,
             "kv_dtype": str(next(iter(layer0.values())).dtype),
@@ -663,12 +722,18 @@ class ServingEngine:
             # over all layers: 2 * kv_heads * Dh elements a layer per head,
             # latent_dim elements a layer for a latent pool
             "bytes_per_token": total // (self.n_blocks * self.block_size),
-            "pool_bytes": total,
+            "pool_bytes": total + window,
             # "gather" | "kernel" | "ragged" (per head), "gather" | "latent_kernel" (latent)
             "decode_attention": self.decode_attention,
         }
         if self.decode_experts:
             info["decode_experts"] = self.decode_experts  # "kernel" | "grouped"
+        if self.two_lifetimes:
+            info.update(
+                full_pool_bytes=total, full_layers=self.cfg.n_layers - len(window_layers),
+                window_pool_bytes=window, window_layers=len(window_layers),
+                window_n_blocks=self.window_blocks, sliding_window=self.cfg.sliding_window,
+            )
         if self.state_slots:
             # the other kind of cache: a fixed-size state a row, whatever its length
             info.update(
@@ -916,9 +981,11 @@ class ServingEngine:
         A no-op when nothing is running or waiting."""
         self._admit()
         chunked = self._dispatch_prefill_chunks(defer=False)
+        self._release_window_pages()
         decoded = self._step_decode() if self._n_decode_rows() else False
         if chunked:
             self._note_chunk_window(decoded)
+        self._note_blocks_in_use()
 
     def _count_attention_pages(self, n: int) -> None:
         """``attn_pages_live`` / ``attn_pages_tabled`` of the ``n``-step
@@ -933,9 +1000,19 @@ class ServingEngine:
         last = np.minimum(
             seq[:, None] + np.arange(n)[None, :], self.max_blocks * bs - 1
         )
+
+        def live(window: int) -> int:
+            first = np.maximum(last - window + 1, 0) // bs if window else 0
+            return int(np.sum(last // bs - first + 1))
+
         window = self.cfg.sliding_window
-        first = np.maximum(last - window + 1, 0) // bs if window else 0
-        self.stats["attn_pages_live"] += int(np.sum(last // bs - first + 1))
+        if self.two_lifetimes:
+            # a window layer's pages beside a full layer's
+            self.stats["window_attn_pages_live"] += live(window)
+            self.stats["window_attn_pages_tabled"] += n * sum(
+                len(r.w_blocks) for r in self.rows if r is not None and r.prefill_pos is None)
+            window = 0
+        self.stats["attn_pages_live"] += live(window)
         self.stats["attn_pages_tabled"] += n * self.max_batch * self.max_blocks
 
     def _decode_tables(self) -> np.ndarray:
@@ -1011,6 +1088,10 @@ class ServingEngine:
             cfg=self.cfg, temperature=self.temperature, top_k=self.top_k,
             top_p=self.top_p, min_p=self.min_p, mesh=self.mesh,
         )
+        if self.two_lifetimes:
+            # a copy: the CPU backend may alias a numpy buffer, and the next
+            # turn's release zeroes entries that this window still reads
+            common["window_tables"] = jnp.asarray(self.w_tables.copy())
         single = n == 1 and raw_key_single
         if not self.fused_sampling:
             skeys = [key] if single else list(jax.random.split(key, n))
@@ -1022,7 +1103,7 @@ class ServingEngine:
             for sub in skeys:
                 logits, self.pools = paged.paged_decode_logits(
                     self.params, self.pools, tok, tables_dev, seq,
-                    cfg=self.cfg, mesh=self.mesh,
+                    cfg=self.cfg, mesh=self.mesh, window_tables=common.get("window_tables"),
                 )
                 # THE round-trip fused sampling deletes: every step pays
                 # a (B, V) f32 device->host transfer + a second dispatch.
@@ -1214,6 +1295,7 @@ class ServingEngine:
             # this tick's window), and the token budget bounds the prefill
             # work a decode window ever waits behind — the TPOT protection.
             chunked = self._dispatch_prefill_chunks(defer=True)
+            self._release_window_pages()
             decoded = False
             if self._n_decode_rows():
                 if self.spec_k:
@@ -1257,8 +1339,59 @@ class ServingEngine:
                    or (self._inflight and not self.n_active)):
                 self._reap_window(self._inflight.popleft())
             busy = bool(self._inflight) or self.has_work()
+            self._note_blocks_in_use()
         self._account_tick(tick.t1 - tick.t0, before)
         return busy
+
+    def _note_blocks_in_use(self) -> None:
+        """Blocks out of each pool's free list after a scheduler turn, and the most so far."""
+        pools = [("kv", self.alloc)] + ([("window", self.w_alloc)] if self.two_lifetimes else [])
+        for name, alloc in pools:
+            used = alloc.n_blocks - 1 - alloc.available
+            self.stats[name + "_blocks_in_use"] = used
+            if used > self.stats[name + "_blocks_peak"]:
+                self.stats[name + "_blocks_peak"] = used
+
+    def _release_window_pages(self) -> None:
+        """Two cache lifetimes: give back every window-layer page that lies
+        wholly behind its row's window as of the next dispatch. ``seq_lens`` is
+        the dispatched frontier, so no window still to go out reads or writes
+        such a page; one already in flight runs before whatever next writes the
+        page (one device, programs in order), the discipline a finished row's
+        pages rely on."""
+        if not self.two_lifetimes:
+            return
+        released = 0
+        with self._clock.span("ensure_pages", "serving.release_pages") as span:
+            window, bs = self.cfg.sliding_window, self.block_size
+            for row, req in enumerate(self.rows):
+                if req is None or not req.w_blocks:
+                    continue
+                first = paged.window_first_block(int(self.seq_lens[row]), window, bs)
+                n = min(first - req.w_first, len(req.w_blocks))
+                if n <= 0:
+                    continue
+                self.w_alloc.free(req.w_blocks[:n])
+                del req.w_blocks[:n]
+                self.w_tables[row, req.w_first : req.w_first + n] = 0
+                req.w_first += n
+                released += n
+            span.set(released=released)
+        self.stats["window_pages_released"] += released
+
+    def _grow_window_pages(self, req: _Request, row: int, need_pages: int) -> None:
+        """Two cache lifetimes: the window layers' pages up to the row's page
+        ``need_pages - 1``, from their own pool, which holds every row at its
+        most by construction (``window_blocks``)."""
+        while req.w_first + len(req.w_blocks) < need_pages:
+            got = self.w_alloc.alloc(1)
+            if got is None:
+                raise RuntimeError(
+                    f"the window layers' pool of {self.window_blocks} blocks ran dry at row {row} "
+                    f"holding {len(req.w_blocks)}: its derived size no longer covers the scheduler"
+                )
+            self.w_tables[row, req.w_first + len(req.w_blocks)] = got[0]
+            req.w_blocks.extend(got)
 
     def _account_tick(self, seconds: float, before: Dict[str, float]) -> None:
         """Keep the longest tick's phase split; log a tick that stood still."""
@@ -1919,6 +2052,12 @@ class ServingEngine:
                     )
                 self.tables[row, :] = 0
                 self.tables[row, : len(req.blocks)] = req.blocks
+                if self.two_lifetimes:
+                    # the window layers keep the pages the first decode step can
+                    # see; a longer prompt's earlier pages are never written there
+                    req.w_first = paged.window_first_block(p, self.cfg.sliding_window, self.block_size)
+                    self.w_tables[row, :] = 0
+                    self._grow_window_pages(req, row, need)
                 if self.prefill_chunk_tokens:
                     # Chunked admission: claim the row and ALL its blocks
                     # (same watermark math — the allocation is identical),
@@ -1973,6 +2112,10 @@ class ServingEngine:
                         slots=[r.row for r in part] if self.state_slots else None,
                         # self-drafting: the module's pages and each row's first draft too
                         with_draft=self.self_draft,
+                        # two cache lifetimes: the window layers' live pages, 0 behind the window
+                        rows_window_ids=[
+                            self.w_tables[r.row, : len(ids)].tolist() for r, ids in zip(part, prefill_ids)
+                        ] if self.two_lifetimes else None,
                     )
                     drafts_dev = None
                     if self.self_draft:
@@ -2268,6 +2411,8 @@ class ServingEngine:
                     self._preempt(victim)
                     if victim is req or self.rows[row] is not req:
                         break  # this row is gone; nothing more to grow
+                if self.two_lifetimes and self.rows[row] is req:
+                    self._grow_window_pages(req, row, need_pages)
             if prealloc > 0:
                 self._prealloc_write_pages(horizon + prealloc)
 
@@ -2435,6 +2580,10 @@ class ServingEngine:
                     )
         else:
             self.alloc.free(req.blocks)
+        if self.two_lifetimes:
+            self.w_alloc.free(req.w_blocks)
+            req.w_blocks, req.w_first = [], 0
+            self.w_tables[row, :] = 0
         req.blocks = []
         req.n_shared = 0
         req.row = None

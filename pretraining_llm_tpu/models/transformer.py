@@ -26,6 +26,8 @@ trace-time loop, or one stacked array scanned with the blocks (make_kv_cache).
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -86,6 +88,13 @@ class PagedInfo(NamedTuple):
     # table names no page (the decode step); a prefill program's rows are not
     # the engine's, so it passes them (pad rows: the scratch slot, the last).
     slots: Optional[jax.Array] = None  # (B,) int32 or None
+    # Two cache lifetimes (cfg.two_lifetimes): the window layers' table into
+    # their own pool, as wide as block_tables and indexed the same way (entry
+    # j is the page of slots j * block_size ...), naming LIVE pages only: a
+    # page wholly behind the window has gone back to its pool and its entry
+    # is 0 (the scratch block), which the window mask never exposes. None =
+    # every layer reads block_tables.
+    window_tables: Optional[jax.Array] = None  # (B, max_blocks) int32 or None
 
 
 def paged_attention_form(
@@ -185,6 +194,12 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         if cfg.use_output_proj and not cfg.kv_lora_rank and mixer != "kda":
             attn["wo"] = normal(ks[1], (h, dh, d), resid_std)
             attn["bo"] = jnp.zeros((d,), dtype)
+        if not cfg.kv_lora_rank and mixer != "kda":
+            if cfg.qk_norm:
+                attn["q_norm"] = layers.init_norm("rmsnorm", dh, dtype)
+                attn["k_norm"] = layers.init_norm("rmsnorm", dh, dtype)
+            if cfg.attn_output_gate:
+                attn["wg"] = normal(jax.random.fold_in(k, 13), (d, h, dh))
         if cfg.moe_dropless and not dense_ffn:
             mlp: Params = moe.init_dropless_params(cfg, ks[2], resid_std, dtype)
         elif cfg.n_experts and not dense_ffn:
@@ -205,6 +220,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             "ln2": layers.init_norm(cfg.norm, d, dtype),
             "mlp": mlp,
         }
+        if cfg.sandwich_norm:
+            block["ln1_post"] = layers.init_norm(cfg.norm, d, dtype)
+            block["ln2_post"] = layers.init_norm(cfg.norm, d, dtype)
         if cfg.hc_mult > 1:
             k_hc = jax.random.split(jax.random.fold_in(k, 7))
             block["hc_attn"] = hyper.init_hc_params(cfg, k_hc[0], dtype)
@@ -268,8 +286,9 @@ def layer_groups(params: Params, cfg: ModelConfig):
     layers it holds, the stacked blocks of their kind, the run's first layer's
     place in that stack)]. A homogeneous model is one run of all of "blocks",
     an expert model with leading dense layers two; a hybrid stack alternates
-    between its kinds' stacks (``cfg.layer_runs``)."""
-    if not cfg.layer_group_size:
+    between its kinds' stacks, a stack of window and full attention layers
+    between its attention kinds inside "blocks" (``cfg.layer_runs``)."""
+    if not (cfg.layer_group_size or cfg.two_lifetimes):
         k = cfg.n_dense_layers if "dense_blocks" in params else 0
         groups = [(range(k, cfg.n_layers), params["blocks"], 0)]
         if k:
@@ -304,10 +323,18 @@ def _attention_block(
     segments: Optional[jax.Array] = None,
     paged: Optional[PagedInfo] = None,
     residual: bool = True,
+    kind: Optional[str] = None,
 ) -> Tuple[jax.Array, Optional[Params]]:
     """Pre-LN attention sub-block: x + attn(ln1(x)). Returns (x, new_kv).
-    ``residual=False`` returns attn(ln1(x)) alone: hyper-connections write
-    it into the streams themselves.
+    ``residual=False`` returns attn(ln1(x)) alone: hyper-connections and the
+    sandwich norms write it into the residual themselves.
+
+    ``kind`` ("window" | "full"; None = the one kind ``cfg.sliding_window``
+    implies) is the layer's attention kind in a stack that mixes them
+    (``cfg.attn_kinds``): a window layer masks to the last
+    ``cfg.sliding_window`` positions and reads ``paged.window_tables``, a full
+    layer sees every earlier position through ``paged.block_tables`` and, with
+    ``cfg.rope_full_layers`` off, rotates nothing.
 
     ``pad_offsets`` (B,) enables RAGGED cached decode: row i is left-padded
     by pad_offsets[i] slots, so its token at cache slot s has logical
@@ -322,6 +349,35 @@ def _attention_block(
         return mla.attention_block(
             blk, x, cfg, rope, positions, kv, cache_index, pad_offsets, paged, residual
         )
+    kind = kind or ("window" if cfg.sliding_window else "full")
+    window = cfg.sliding_window if kind == "window" else 0
+    if kind == "full" and not cfg.rope_full_layers:
+        rope = None
+    # A mixed stack's device trace tells its kinds apart by this outer scope.
+    with jax.named_scope(f"attn.{kind}") if cfg.attn_kinds else contextlib.nullcontext():
+        return _attention_core(
+            blk, x, cfg, rope, positions, kv, cache_index, zigzag, pad_offsets, segments,
+            paged, residual, window,
+        )
+
+
+def _attention_core(
+    blk: Params,
+    x: jax.Array,
+    cfg: ModelConfig,
+    rope: Optional[Tuple[jax.Array, jax.Array]],
+    positions: jax.Array,
+    kv: Optional[Params],
+    cache_index: Optional[jax.Array],
+    zigzag: bool,
+    pad_offsets: Optional[jax.Array],
+    segments: Optional[jax.Array],
+    paged: Optional[PagedInfo],
+    residual: bool,
+    window: int,
+) -> Tuple[jax.Array, Optional[Params]]:
+    """Per-head attention of ``_attention_block`` with the layer's ``window``
+    (0 = full) and ``rope`` (None = no position encoding) settled."""
     cdt = jnp.dtype(cfg.compute_dtype)
     with jax.named_scope("blk.norm"):
         h = layers.apply_norm(cfg.norm, blk["ln1"], x, cfg.norm_eps)
@@ -369,6 +425,11 @@ def _attention_block(
                     bkv[None, :, :, None, :] if hm else bkv[None, :, None]
                 )
             k, v = kvp[:, 0], kvp[:, 1]  # hm: (B, G, T, Dh)
+
+    if "q_norm" in blk["attn"]:
+        with jax.named_scope("attn.qk_norm"):
+            q = layers.rmsnorm(blk["attn"]["q_norm"], q, cfg.norm_eps)
+            k = layers.rmsnorm(blk["attn"]["k_norm"], k, cfg.norm_eps)
 
     if rope is not None:
         cos, sin = rope
@@ -420,6 +481,13 @@ def _attention_block(
         tq = k.shape[1]
         block_size = kv["k_pool"].shape[1]
         tables, seq = paged.block_tables, paged.seq_lens
+        if window and cfg.two_lifetimes:
+            if paged.window_tables is None:
+                raise ValueError(
+                    "a stack with two cache lifetimes reads its window layers' pool "
+                    "through PagedInfo.window_tables"
+                )
+            tables = paged.window_tables  # the window layers' own pool and live pages
         # Token i of this call writes logical slot seq + i. tq == 1 is the
         # serving decode step; tq > 1 is the speculative-decoding paged
         # VERIFY (k+1 draft tokens through the target in one program —
@@ -498,7 +566,7 @@ def _attention_block(
             with jax.named_scope("attn.core"):
                 out = ragged_paged_attention(
                     q.astype(cdt), k_in, v_in, tables, seq, q_lens,
-                    window=cfg.sliding_window,
+                    window=window,
                     kv_splits=cfg.ragged_kv_splits or None,
                     amla=cfg.ragged_amla,
                     **scales,
@@ -521,7 +589,7 @@ def _attention_block(
                     qin.astype(cdt),
                     new_kv["k_pool"].astype(cdt),
                     new_kv["v_pool"].astype(cdt),
-                    tables, seq, window=cfg.sliding_window,
+                    tables, seq, window=window,
                 )
             if tq == 1:
                 out = out[:, None]
@@ -553,9 +621,9 @@ def _attention_block(
                 # entries point at arbitrary blocks but sit at linear indices
                 # beyond the frontier — always masked.
                 kv_mask = lin[None, None, :] <= pos[:, :, None]  # (B, T, kv_len)
-                if cfg.sliding_window:
+                if window:
                     kv_mask = kv_mask & (
-                        lin[None, None, :] > pos[:, :, None] - cfg.sliding_window
+                        lin[None, None, :] > pos[:, :, None] - window
                     )
                 out = multihead_attention(
                     q, ck, cv, impl="naive", causal=False, kv_mask=kv_mask
@@ -613,7 +681,7 @@ def _attention_block(
                 out = multihead_attention(
                     q, k, v, impl="flash",
                     block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv,
-                    window=cfg.sliding_window,
+                    window=window,
                 )
         elif (
             tq > 1
@@ -646,10 +714,10 @@ def _attention_block(
                 # k_offset keeps the sliced keys' positions absolute.
                 tile = cfg.flash_block_kv or 512
                 hi = min(tmax, -(-(int(cache_index) + tq) // tile) * tile)
-                if cfg.sliding_window:
+                if window:
                     k_lo = max(
                         0,
-                        (int(cache_index) - cfg.sliding_window + 1)
+                        (int(cache_index) - window + 1)
                         // tile * tile,
                     )
                 kv_view = {
@@ -661,7 +729,7 @@ def _attention_block(
                     q, ck, cv, causal=True,
                     block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv,
                     q_offset=cache_index, k_offset=k_lo,
-                    window=cfg.sliding_window,
+                    window=window,
                 )
         else:
             with jax.named_scope("attn.core"):
@@ -680,7 +748,7 @@ def _attention_block(
                     q_positions=positions,
                     kv_positions=kv_positions,
                     kv_mask=kv_mask,
-                    window=cfg.sliding_window,
+                    window=window,
                 )
     else:
         grouped_ok = cfg.attention_impl in ("naive", "flash")
@@ -706,7 +774,7 @@ def _attention_block(
                 block_kv=cfg.flash_block_kv,
                 ring_layout="zigzag" if zigzag else "contiguous",
                 segments=segments,
-                window=cfg.sliding_window,
+                window=window,
                 heads_major=hm,
             )
 
@@ -714,6 +782,15 @@ def _attention_block(
     # expensive-to-recompute) attention output, recompute everything else.
     # (Heads-major path saves (B, H, T, Dh) — consumers below match.)
     out = checkpoint_name(out, "attn_out")
+
+    if "wg" in blk["attn"]:
+        with jax.named_scope("attn.gate"):
+            gate = jnp.einsum(
+                "btd,dhn->bhtn" if hm else "btd,dhn->bthn",
+                h.astype(cdt), _weight(blk["attn"], "wg", cdt),
+                preferred_element_type=jnp.float32,
+            )
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate)).astype(cdt)
 
     with jax.named_scope("attn.out"):
         if cfg.use_output_proj:
@@ -794,6 +871,7 @@ def _block(
     segments: Optional[jax.Array] = None,
     paged: Optional[PagedInfo] = None,
     lengths: Optional[jax.Array] = None,
+    attn_kind: Optional[str] = None,
 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
     if "wf" in blk["attn"]:  # a KDA mixer's decay projection
         if zigzag or segments is not None:
@@ -816,9 +894,22 @@ def _block(
             residual=False,
         )
         return hyper.write(coef, x, y), new_kv, aux
+    if "ln1_post" in blk:
+        # Sandwich norms: each sublayer's output is normed before it joins the residual.
+        decode = kv is not None and x.shape[1] == 1
+        y, new_kv = _attention_block(
+            blk, x, cfg, rope, positions, kv, cache_index, zigzag, pad_offsets,
+            segments=segments, paged=paged, residual=False, kind=attn_kind,
+        )
+        with jax.named_scope("blk.norm"):
+            x = x + layers.apply_norm(cfg.norm, blk["ln1_post"], y, cfg.norm_eps)
+        y, aux = _mlp_block(blk, x, cfg, decode=decode, residual=False)
+        with jax.named_scope("blk.norm"):
+            x = x + layers.apply_norm(cfg.norm, blk["ln2_post"], y, cfg.norm_eps)
+        return x, new_kv, aux
     x, new_kv = _attention_block(
         blk, x, cfg, rope, positions, kv, cache_index, zigzag, pad_offsets,
-        segments=segments, paged=paged,
+        segments=segments, paged=paged, kind=attn_kind,
     )
     x = constrain(
         x, ("data", "fsdp"), "seq" if cfg.sequence_parallel else None, None
@@ -967,6 +1058,8 @@ def forward(
     with jax.named_scope("embed"):
         emb_table = constrain(params["tok_embed"]["embedding"], None, None)
         x = emb_table[tokens].astype(cdt)
+        if cfg.embed_scale:
+            x = x * jnp.asarray(cfg.d_model ** 0.5, cdt)
         if cfg.pos_embed == "learned":
             pos_table = constrain(params["pos_embed"]["embedding"], None, None)
             if paged is not None:
@@ -1013,13 +1106,13 @@ def forward(
         ]
         return jnp.asarray(both, jnp.float32).T if any(map(any, both)) else None
 
-    def scan_body(carry, layer_inputs):
+    def scan_body(carry, layer_inputs, kind=None):
         x, aux_sum = carry
         if kv_cache is None:
             blk = layer_inputs
             x, _, aux = _block(
                 blk, x, cfg, rope, positions, None, None, zigzag,
-                segments=segments, lengths=lengths,
+                segments=segments, lengths=lengths, attn_kind=kind,
             )
             if aux.ndim:  # a dropless layer's tokens per expert ride the outputs
                 return (x, aux_sum), ((x if return_hidden else None), aux)
@@ -1027,7 +1120,7 @@ def forward(
         blk, cache_layer = layer_inputs
         x, new_kv, aux = _block(
             blk, x, cfg, rope, positions, cache_layer, cache_index,
-            pad_offsets=pad_offsets, paged=paged, lengths=lengths,
+            pad_offsets=pad_offsets, paged=paged, lengths=lengths, attn_kind=kind,
         )
         if aux.ndim:
             return (x, aux_sum), (new_kv, aux)
@@ -1064,7 +1157,10 @@ def forward(
         if n != jax.tree.leaves(blocks)[0].shape[0]:
             blocks = jax.tree.map(lambda a: a[first : first + n], blocks)
         xs = blocks if cache is None else (blocks, cache)
-        step = body
+        # a mixed stack's runs are each of one attention kind (cfg.layer_runs)
+        kind = cfg.attn_kinds[layers_of[0]] if cfg.attn_kinds else None
+        step = body if kind is None else remat.checkpoint_wrap(
+            functools.partial(scan_body, kind=kind), cfg.remat)
         if experts is not None:
             clamps = clamps_of(layers_of)
 
@@ -1073,8 +1169,8 @@ def forward(
                 inputs, layer = inputs
                 layer, limits = layer if clamps is not None else (layer, None)
                 if cache is None:
-                    return scan_body(carry, in_stack(inputs, experts, layer, limits))
-                return scan_body(carry, (in_stack(inputs[0], experts, layer, limits), inputs[1]))
+                    return scan_body(carry, in_stack(inputs, experts, layer, limits), kind)
+                return scan_body(carry, (in_stack(inputs[0], experts, layer, limits), inputs[1]), kind)
 
             idx = jnp.arange(first, first + n, dtype=jnp.int32)
             xs = (xs, idx if clamps is None else (idx, clamps))
@@ -1165,6 +1261,7 @@ def forward(
                     x, new_kv, aux = _block(
                         blk, x, cfg, rope, positions, kv_cache["layers"][layer],
                         cache_index, pad_offsets=pad_offsets, paged=paged, lengths=lengths,
+                        attn_kind=cfg.attn_kinds[layer] if cfg.attn_kinds else None,
                     )
                     if aux.ndim:
                         counts.append(aux)
@@ -1606,6 +1703,7 @@ def _is_pool_cache(kv_cache: Optional[KVCache]) -> bool:
 def _unstack_fields(
     cfg: ModelConfig, fields: Dict[str, Tuple[Tuple[int, ...], Any]],
     kda_fields: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]] = None,
+    window_fields: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]] = None,
 ) -> KVCache:
     """{'layers': per-layer dicts of fresh zero arrays} from {name:
     (stacked_shape, dtype)} specs — allocated per layer DIRECTLY (never
@@ -1613,14 +1711,19 @@ def _unstack_fields(
     capacity, and a transient 2x would OOM engines that otherwise fit).
     Each layer gets its own buffers (sharing one zeros across carry
     leaves would alias donated updates). A KDA layer of a hybrid stack keeps
-    ``kda_fields`` ({name: (shape, dtype)}, no layer dimension) instead."""
+    ``kda_fields`` ({name: (shape, dtype)}, no layer dimension) instead, a
+    window layer of a stack with two cache lifetimes ``window_fields`` (specs
+    like ``fields``, its own pool's size)."""
+    window = tuple(window_fields is not None and k == "window" for k in cfg.layer_attn_kinds)
     return {
         "layers": tuple(
             {name: jnp.zeros(shape, dt) for name, (shape, dt) in kda_fields.items()}
             if mixer == "kda" else
-            {name: jnp.zeros(shape[1:], dt) for name, (shape, dt) in fields.items()}
+            {name: jnp.zeros(shape[1:], dt)
+             for name, (shape, dt) in (window_fields if own else fields).items()}
             # the stack's layers, then the MTP module's block (an attention layer)
-            for mixer, _ in cfg.layer_kinds + (("attn", ""),) * cfg.mtp_depth
+            for (mixer, _), own in zip(
+                cfg.layer_kinds + (("attn", ""),) * cfg.mtp_depth, window + (False,) * cfg.mtp_depth)
         )
     }
 
@@ -1683,9 +1786,15 @@ def make_kv_cache(
 
 def make_paged_kv_pool(
     cfg: ModelConfig, n_blocks: int, block_size: int, dtype: Any = None,
-    *, scale_dtype: Any = None, state_slots: int = 0,
+    *, scale_dtype: Any = None, state_slots: int = 0, window_blocks: int = 0,
 ) -> KVCache:
     """Block POOL layout for paged serving decode (see PagedInfo).
+
+    A stack of window and full attention layers (``cfg.two_lifetimes``) keeps
+    two cache lifetimes: its full layers get ``n_blocks`` pages each, its
+    window layers ``window_blocks`` each, a pool of their own under a table
+    and an allocator of their own (``PagedInfo.window_tables``), block 0 the
+    scratch there too. Every other model gives every layer ``n_blocks``.
 
     One pool a layer, {'layers': (per-layer dicts,)}: {'k_pool','v_pool'}:
     (n_blocks, block_size, kv_heads, Dh), plus scale pools when
@@ -1768,7 +1877,19 @@ def make_paged_kv_pool(
         }
     # Per-layer pools update in place on the serving window's token-scan
     # carry (see make_kv_cache).
-    pools = _unstack_fields(cfg, fields, kda_fields)
+    window_fields = None
+    if cfg.two_lifetimes:
+        if window_blocks < 2:
+            raise ValueError(
+                "a stack with two cache lifetimes needs window_blocks >= 2, its window "
+                "layers' own pool (block 0 is the idle scratch)"
+            )
+        window_fields = {
+            name: ((shape[0], window_blocks) + shape[2:], dt) for name, (shape, dt) in fields.items()
+        }
+    elif window_blocks:
+        raise ValueError("window_blocks is for a stack of window and full attention layers")
+    pools = _unstack_fields(cfg, fields, kda_fields, window_fields)
     if kda_fields:
         # where generation.paged.prefill_into_pool puts a prompt it is given no slot for
         pools["state_cursor"] = jnp.zeros((), jnp.int32)

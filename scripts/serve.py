@@ -48,7 +48,14 @@ def main() -> None:
     parser.add_argument("--max_batch", type=int, default=8,
                         help="concurrent decode rows (the compiled width)")
     parser.add_argument("--n_blocks", type=int, default=256,
-                        help="KV pool size in blocks (block 0 is reserved)")
+                        help="KV pool size in blocks (block 0 is reserved). A "
+                        "model of window and full attention layers "
+                        "(model.attn_kinds) keeps two pools: this is its full "
+                        "layers'; the window layers' is sized by the engine "
+                        "(max_batch x (window / block_size + 2) + 1) and gives "
+                        "pages back behind the window; such a model is served "
+                        "without --prefix_cache, --kv_checksum, --quantize "
+                        "int8-kv, --spec_k and --prefill_chunk_tokens")
     parser.add_argument("--block_size", type=int, default=64,
                         help="tokens per pool block (multiple of 8)")
     parser.add_argument("--steps_per_sched", type=int, default=8,

@@ -165,6 +165,8 @@ _PERF_INTENT = {
     "ling-mini":       ("naive",        "none",           "chunked"),
     # the same for the JoyAI mechanisms (latent attention, held experts, an MTP module)
     "joyai-mini":      ("naive",        "none",           "chunked"),
+    # the same for the Trinity mechanisms (window and full layers, gated QK-normed GQA, four norms)
+    "trinity-toy":     ("naive",        "none",           "chunked"),
 }
 
 

@@ -95,6 +95,8 @@ def test_decay_mask_covers_every_leaf_of_every_preset():
             # a hybrid preset keeps one layer of each mixer and a share of its experts
             **(dict(layer_group_size=2, kda_head_dim=4, n_experts_held=2, moe_swiglu_limits=(0.0, 4.0))
                if model.layer_group_size else dict(n_experts_held=min(model.n_experts_held, 2))),
+            # a stack of window and full attention layers keeps one layer of each kind
+            **(dict(attn_kinds=("window", "full")) if model.attn_kinds else {}),
         )
         params = transformer.init_params(tiny, jax.random.key(0))
         mask = opt.decay_mask(params)
@@ -122,6 +124,8 @@ def test_decay_mask_covers_every_leaf_of_every_preset():
     assert {"wf", "wbeta", "wg", "wgate", "conv", "A_log", "dt_bias"} <= seen_names
     # and the multi-token-prediction module's projection (joyai-mini)
     assert "eh_proj" in seen_names
+    # and per-head attention's output gate (trinity-toy; the name is KDA's gate's too)
+    assert "wg" in seen_names
 
 
 def test_clip_by_global_norm():

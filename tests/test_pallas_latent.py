@@ -241,6 +241,40 @@ def test_kernel_compiles_for_the_chip_at_the_cells_size(one_chip, b, t, n_blocks
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20  # block-diagonal queries, a padded table
 
 
+@pytest.mark.parametrize("b,n_blocks,window", [(64, 9281, 0), (64, 2177, 2048)], ids=["full-layer", "window-layer"])
+def test_per_head_decode_kernel_compiles_for_the_chip_at_the_trinity_cells_size(one_chip, b, n_blocks, window):
+    """``ops/pallas_paged.py``'s single-query form at ``serve_trinity_decode_1k_8k``'s
+    two pools (64 rows, 32 query heads over 4 kv heads of 128: 8 a group, tables
+    of 145 pages; the full layer's 9,281 pages unclipped, a window layer's 2,177
+    under the 2,048-token clip): Mosaic takes both, the flat view of each pool is
+    a bitcast and no pool-sized copy stands beside the custom call."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from pretraining_llm_tpu.ops import pallas_paged as pp
+
+    heads, kv_heads, d, bs, max_blocks = 32, 4, 128, 64, 145
+    shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    pool = shape((n_blocks, bs, kv_heads, d))
+    pages = pp._pages_a_step(pool, pp.PAGES_PER_STEP)
+    fn = lambda q, k, v, t, n: pp._decode_call(q, k, v, t, n, window, pages, False)
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable without a chip
+    compilation_cache.reset_cache()
+    try:
+        assert pp.pages_copy_in_place(kv_heads, d)
+        compiled = jax.jit(fn).lower(shape((b, heads, d)), pool, pool, shape((b, max_blocks), jnp.int32),
+                                     shape((b,), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    ops = [line.split(" = ", 1)[1] for line in text.splitlines() if " = " in line]
+    pool_sized = [op for op in ops if op.startswith(f"bf16[{n_blocks},") and " parameter(" not in op]
+    assert len(pool_sized) == 2 and all(" bitcast(" in op for op in pool_sized), pool_sized
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 @pytest.mark.parametrize("max_blocks,heads,kv_heads,ok", [(17, 32, 8, True), (22, 32, 8, True), (22, 32, 32, True),
                                                           (17, 12, 12, False)],
                          ids=["decode-cell", "chat-cell", "ungrouped-heads", "heads-of-64"])
@@ -285,8 +319,9 @@ def test_per_head_decode_kernel_compiles_for_the_chip_at_the_cells_size(one_chip
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-@pytest.mark.parametrize("stack,held,d,f,rows,clamp", [(4, 128, 2560, 768, 1024, False), (5, 64, 3584, 1024, 128, True)],
-                         ids=["ling-decode-step", "xing-decode-step"])
+@pytest.mark.parametrize("stack,held,d,f,rows,clamp", [(4, 128, 2560, 768, 1024, False), (5, 64, 3584, 1024, 128, True),
+                                                        (4, 128, 2048, 1024, 512, False)],
+                         ids=["ling-decode-step", "xing-decode-step", "trinity-decode-step"])
 def test_expert_kernel_compiles_for_the_chip_at_the_cells_size(one_chip, stack, held, d, f, rows, clamp):
     """``ops/pallas_moe.py`` at the two serving cells' expert layers (a stack of
     128 experts of 2560 x 768 under 1,024 sorted rows, of 64 experts of 3584 x
@@ -314,7 +349,9 @@ def test_expert_kernel_compiles_for_the_chip_at_the_cells_size(one_chip, stack, 
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1 and "ragged-dot" not in text
     ops = [line.split(" = ", 1)[1] for line in text.splitlines() if " = " in line]
-    stack_sized = [op for op in ops if op.startswith((f"bf16[{stack},{held},", f"bf16[{stack * held},"))
+    # (the flat views are (L * E, d, 2f) and (L * E, f, d); Trinity's 512 sorted rows are as many as its L * E)
+    stack_sized = [op for op in ops
+                   if op.startswith((f"bf16[{stack},{held},", f"bf16[{stack * held},{d},", f"bf16[{stack * held},{f},"))
                    and " parameter(" not in op]
     assert len(stack_sized) == 2 and all(" bitcast(" in op for op in stack_sized), stack_sized
     assert 2 * 3 * d * tf * 2 <= pm.WEIGHT_TILE_BYTES

@@ -536,3 +536,86 @@ def test_outputs_are_bit_identical_without_the_scopes(params, monkeypatch):
     assert len(with_scopes) == len(without)
     for a, b in zip(with_scopes, without):
         assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+# A stack of window and full attention layers (two cache lifetimes): an outer scope a kind
+# around the attention sublayer, so that the device trace tells a window layer's kernel from
+# a full layer's; the gate and the head norms under names of their own; the page manager's
+# release as a host span that counts what it gave back.
+LIFETIMES_CFG = dataclasses.replace(get_preset("trinity-toy").model, compute_dtype="float32")
+LIFETIMES_SCOPES = {
+    "decode": ("attn.window", "attn.full", "attn.qk_norm", "attn.gate", "attn.kv_write", "attn.core", "attn.rope",
+               "moe.experts"),
+    "prefill": ("attn.window", "attn.full", "attn.qk_norm", "attn.gate", "attn.kv_write", "attn.core", "sample"),
+}
+
+
+@pytest.fixture(scope="module")
+def lifetimes_paths():
+    p = transformer.init_params(LIFETIMES_CFG, jax.random.key(0))
+    pools = lambda: transformer.make_paged_kv_pool(LIFETIMES_CFG, 16, 8, window_blocks=9)
+    tables = jnp.asarray(np.arange(1, 9).reshape(2, 4), jnp.int32)
+    lowered = {
+        "decode": paged.paged_decode_steps.lower(
+            p, pools(), jnp.asarray([3, 5], jnp.int32), tables, jnp.asarray([4, 9], jnp.int32),
+            jax.random.key(1), LIFETIMES_CFG, n_steps=2, window_tables=tables),
+        "prefill": paged._prefill_scatter_sample.lower(
+            p, pools(), jnp.zeros((2, 16), jnp.int32), jnp.asarray([16, 11], jnp.int32),
+            jnp.asarray([[1, 2], [3, 4]], jnp.int32), jax.random.key(2), LIFETIMES_CFG, 16, 2,
+            window_ids=jnp.asarray([[1, 2], [3, 4]], jnp.int32)),
+    }
+    return {k: set(re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True))) for k, low in lowered.items()}
+
+
+@pytest.mark.parametrize("program,scope", [(p, s) for p, ss in LIFETIMES_SCOPES.items() for s in ss])
+def test_lifetimes_scope_is_in_the_lowered_program(lifetimes_paths, program, scope):
+    words = [re.split(r"[/()]", p) for p in lifetimes_paths[program]]
+    paths = [w for w in words if scope in w]
+    assert paths
+    if scope in ("attn.qk_norm", "attn.gate", "attn.kv_write", "attn.core"):
+        # under each kind's outer scope, never under both, never outside one (the staged
+        # pages' scatter after a prefill is the page manager's, outside every layer)
+        inside = [w for w in paths if "attn.window" in w or "attn.full" in w]
+        assert [w for w in inside if "attn.window" in w] and [w for w in inside if "attn.full" in w]
+        assert not [w for w in inside if "attn.window" in w and "attn.full" in w]
+        assert inside == paths or (program, scope) == ("prefill", "attn.kv_write")
+    if scope == "attn.rope":  # the full layer carries no position encoding
+        assert all("attn.window" in w for w in paths)
+
+
+def test_a_one_kind_stack_has_no_outer_attention_scope(params):
+    pools = transformer.make_paged_kv_pool(CFG, 16, 8)
+    tables = jnp.asarray(np.arange(1, 9).reshape(2, 4), jnp.int32)
+    text = paged.paged_decode_steps.lower(
+        params, pools, jnp.asarray([3, 5], jnp.int32), tables, jnp.asarray([4, 9], jnp.int32),
+        jax.random.key(1), CFG, n_steps=2).as_text(debug_info=True)
+    assert "attn.core" in text and "attn.window" not in text and "attn.full" not in text
+
+
+def test_two_lifetimes_say_so_on_spans_counters_and_pool_info(monkeypatch):
+    p = transformer.init_params(LIFETIMES_CFG, jax.random.key(0))
+    rec = spans.SpanRecorder()
+    monkeypatch.setattr(spans, "_default", rec)
+    eng = ServingEngine(p, LIFETIMES_CFG, max_batch=2, n_blocks=24, block_size=8, max_seq=96)
+    eng.submit(list(range(1, 20)), 40)
+    eng.run()
+    events, _ = rec.drain()
+    releases = [(t0, t0 + dur, depth, meta) for name, t0, dur, _, depth, meta in events if name == "serving.release_pages"]
+    ticks = [(t0, t0 + dur, depth) for name, t0, dur, _, depth, _ in events if name == "serving.tick"]
+    st = eng.stats
+    assert len(releases) == st["ticks"] and all(set(m) == {"released"} for *_, m in releases)
+    assert sum(m["released"] for *_, m in releases) == st["window_pages_released"] == (19 + 40 - 16) // 8
+    for a, b, depth, _ in releases:  # inside the tick, a child of its span
+        assert any(ta <= a and b <= tb and tdepth < depth for ta, tb, tdepth in ticks)
+    assert st["window_blocks_peak"] == 16 // 8 + 1 and st["kv_blocks_peak"] == (19 + 40) // 8 + 1
+    assert st["kv_blocks_in_use"] == st["window_blocks_in_use"] == 0
+    # a full layer reads the whole row, a window layer what its table names
+    assert st["attn_pages_live"] > st["window_attn_pages_live"] > 0
+    assert st["window_attn_pages_live"] <= st["window_attn_pages_tabled"] < st["attn_pages_tabled"]
+    info = eng.pool_info()
+    per_block = 8 * 2 * LIFETIMES_CFG.kv_heads * LIFETIMES_CFG.head_dim * 4
+    assert (info["n_blocks"], info["window_n_blocks"]) == (24, 2 * (16 // 8 + 2) + 1)
+    assert (info["full_layers"], info["window_layers"], info["sliding_window"]) == (1, 4, 16)
+    assert info["full_pool_bytes"] == 24 * per_block and info["window_pool_bytes"] == 4 * 9 * per_block
+    assert info["pool_bytes"] == info["full_pool_bytes"] + info["window_pool_bytes"]
+    assert info["bytes_per_block"] == per_block

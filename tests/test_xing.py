@@ -443,7 +443,8 @@ GPT2 = dict(vocab_size=256, context_length=64, n_layers=2, activation="gelu", no
             pos_embed="learned", tie_embeddings=True, qkv_bias=True, mlp_bias=True, attention_impl="flash",
             remat="full")
 DENSE = {"mistral-7b-v0.1": ModelConfig(**MISTRAL), "gpt2-large": ModelConfig(d_model=40, n_heads=4, **GPT2),
-         "gpt2-xl": ModelConfig(d_model=50, n_heads=5, **GPT2), "xing4.0-29b-a4b": get_preset("xing-mini").model}
+         "gpt2-xl": ModelConfig(d_model=50, n_heads=5, **GPT2), "xing4.0-29b-a4b": get_preset("xing-mini").model,
+         "ling-3.0-flash": get_preset("ling-mini").model, "joyai-llm-flash": get_preset("joyai-mini").model}
 # (equations, hash) of each program as the commit 7c8a3aa (PR 28) traces it, less its `name`
 # equations for the remat tags "qkv" and "mlp_hidden", which went with the policies that read
 # them (PR 29; before that, 3f5f5eb): the benchmark's three dense configurations' flags at
@@ -460,6 +461,16 @@ PARENTS = {
     ("xing4.0-29b-a4b", "decode"): (2779, "81e950d7efcdced8"),
     ("xing4.0-29b-a4b", "prefill"): (1445, "1fd884617569206c"),
     ("xing4.0-29b-a4b", "forward"): (1354, "7c021ba348cc52d2"),
+    # the fifth and sixth, at the `ling-mini` and `joyai-mini` presets' widths (state slots beside
+    # the latent pool; the MTP module's layer and its round), as 6611a27 (PR 40) traces them:
+    # PR 41's per-layer attention kind, second page table and window pool leave them be
+    ("ling-3.0-flash", "decode"): (2512, "241427cc1ac9352d"),
+    ("ling-3.0-flash", "prefill"): (2254, "6f0de077b97d34fb"),
+    ("ling-3.0-flash", "forward"): (2023, "96aa51f3110c061b"),
+    ("joyai-llm-flash", "decode"): (1481, "e28efbc503cecb46"),
+    ("joyai-llm-flash", "prefill"): (613, "241d392a485a0b90"),
+    ("joyai-llm-flash", "forward"): (504, "9d87ef583c8c67f6"),
+    ("joyai-llm-flash", "round"): (2672, "b4e83b754e2de962"),
 }
 
 
@@ -498,12 +509,17 @@ def test_dense_programs_trace_as_the_parents(name, prog):
     elif prog == "forward":
         got = _fingerprint(lambda p, x: tr.forward(p, x, cfg)[0], p, toks)
     else:
-        pools = jax.eval_shape(lambda: tr.make_paged_kv_pool(cfg, 16, 8))
+        slots = 2 if cfg.layer_group_size else 0  # a state-slot model: a slot a row beside the pages
+        pools = jax.eval_shape(lambda: tr.make_paged_kv_pool(cfg, 16, 8, state_slots=slots))
         if prog == "decode":
             got = _fingerprint(lambda *a: paged.paged_decode_steps(*a, cfg=cfg, n_steps=1),
                                p, pools, i32(2), i32(2, 4), i32(2), key)
+        elif prog == "round":
+            got = _fingerprint(lambda *a: paged.paged_mtp_round(*a, cfg=cfg),
+                               p, pools, i32(2), i32(2), i32(2, 4), i32(2), key)
         else:
-            got = _fingerprint(lambda *a: paged._prefill_scatter_sample(*a, cfg=cfg, p_bucket=16, n_pages=2),
+            kw = dict(slots=i32(2)) if slots else {}
+            got = _fingerprint(lambda *a: paged._prefill_scatter_sample(*a, cfg=cfg, p_bucket=16, n_pages=2, **kw),
                                p, pools, toks, i32(2), i32(2, 2), key)
     assert got == PARENTS[(name, prog)]
 
