@@ -110,7 +110,11 @@ class ModelConfig:
     # (utilization ~1.0 vs (n+1)/2n contiguous); loss_fn applies the matching
     # token permutation automatically. "contiguous" keeps plain sharding.
     ring_layout: str = "zigzag"
-    # Flash-attention block sizes (tuned for TPU MXU/VMEM; 0 = auto)
+    # Flash-attention block sizes (tuned for TPU MXU/VMEM; 0 = auto:
+    # min(1024, T)). T <= 1024 is kept as ONE block a head, because every
+    # further grid step costs more than it skips; the masked half of that
+    # block is skipped inside it (ops/pallas_flash.py::causal_tiles). A size
+    # under T selects the grid's kernels, which skip whole blocks.
     flash_block_q: int = 0
     flash_block_kv: int = 0
     # Heads-major (B, H, T, Dh) q/k/v for the flash TRAINING path: produced

@@ -21,6 +21,10 @@ fully-materialized (B,H,T,T) scores, /root/reference/src/models/attention.py:51-
   - Backward = two kernels (FA2): dQ gridded over q blocks, dK/dV gridded over
     kv blocks, both re-building P from the saved logsumexp; D = rowsum(dO*O)
     is precomputed in plain XLA.
+  - A plain causal call of ONE block a head (T <= 1024 at the default sizes)
+    has nothing for the grid to skip: its forward and fused backward walk the
+    block in square sub-tiles instead and never form those above the diagonal
+    (causal_tiles; the pallas_calls `flash_fwd_tiles` / `flash_bwd_tiles`).
 
 All kernels run under interpret mode on CPU for unit testing (tests compare
 against the naive einsum path).
@@ -29,7 +33,8 @@ against the naive einsum path).
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+import logging
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +43,22 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+logger = logging.getLogger(__name__)
+
 NEG_INF = -1e30  # avoid actual -inf inside kernels (exp/max edge cases)
+
+# Side of the square sub-tiles a lone causal block is walked in (causal_tiles).
+# Timed on a v5e at the training cells' calls, T 1024, D 64, bf16, a forward /
+# a fused backward call, the kernel's own events in a trace (PR 42):
+#   (B*H) 240: block 0.816 / 1.839 ms; tile 128 0.686 / 1.323 (36 of 64
+#              sub-tiles); tile 256 0.611 / 1.251 (10 of 16); tile 512
+#              0.628 / 1.436 (3 of 4)
+#   (B*H) 300: block 1.019 / 2.297 ms; 128 0.857 / 1.652; 256 0.763 / 1.563;
+#              512 0.785 / 1.793
+# 128 computes the fewest sub-tiles and loses them again to more, smaller
+# matmuls and accumulator updates. A multiple of 128 keeps every slice on
+# lane-tile boundaries.
+CAUSAL_TILE = 256
 
 
 def _heads_first(x: jax.Array) -> jax.Array:
@@ -54,8 +74,10 @@ def _heads_last(x: jax.Array, b: int, h: int) -> jax.Array:
 
 
 def _block_sizes(t: int, block_q: int, block_kv: int) -> Tuple[int, int]:
-    # Auto default 1024: measured fastest on v5e at T=1024..8192 (s-block of
-    # (1024, 1024) f32 = 4 MB VMEM); smaller blocks pay grid/stats overhead.
+    # Auto default 1024: measured fastest on v5e at T=1024..8192; smaller
+    # blocks pay a grid step (~0.35 us) and the online softmax's rescale each.
+    # So T <= 1024 stays ONE block a head, and the causal skipping that a
+    # grid of smaller blocks would give happens inside it (causal_tiles).
     bq = min(block_q or 1024, t)
     bk = min(block_kv or 1024, t)
     while t % bq:
@@ -63,6 +85,31 @@ def _block_sizes(t: int, block_q: int, block_kv: int) -> Tuple[int, int]:
     while t % bk:
         bk //= 2
     return max(bq, 1), max(bk, 1)
+
+
+def causal_tiles(t: int, bq: int, bk: int, causal: bool, window: int, segments) -> int:
+    """How many CAUSAL_TILE-square sub-tiles a side the kernels walk a lone
+    causal block in; 0 = the block form (every other call: the grid's
+    kernels, untouched). Read from static facts of the call alone: the one
+    block of a plain causal call lies on the diagonal, where _run_ok can skip
+    nothing and half of every matmul is masked away. Walked in sub-tiles,
+    n(n+1)/2 of the n*n are computed and only the n on the diagonal masked."""
+    if not causal or window or segments is not None or bq != t or bk != t:
+        return 0
+    if t % (2 * CAUSAL_TILE):
+        return 0
+    return t // CAUSAL_TILE
+
+
+@functools.lru_cache(maxsize=None)
+def _log_form(bh: int, t: int, d: int, bq: int, bk: int, n: int) -> None:
+    """One INFO line a distinct shape, at trace time: which form it takes."""
+    if n:
+        form = (f"causal tiles of {t // n}, {n * (n + 1) // 2} of {n * n} "
+                "sub-tiles computed")
+    else:
+        form = f"block grid {t // bq} x {t // bk} of ({bq}, {bk})"
+    logger.info("flash attention (B*H, T, D) = (%d, %d, %d): %s", bh, t, d, form)
 
 
 # ---------------------------------------------------------------------------
@@ -163,6 +210,53 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, bq, bk, nk, seg, wind
         lse_ref[0] = m_scr[:] + jnp.log(safe_l)  # (bq, 1)
 
 
+def _strip_scores(q, k_ref, i: int, tile: int, scale: float) -> List[Tuple[slice, jax.Array]]:
+    """Scaled scores of causal strip i (the `tile` query rows q, at rows
+    [i*tile, (i+1)*tile)) against the keys it can see, as (key columns,
+    scores) pieces: everything left of the diagonal in one unmasked piece,
+    then the diagonal sub-tile under _mask_ok at its own offsets. Sub-tiles
+    right of the diagonal are never formed. Shared by the tiled forward and
+    backward, so the two cannot disagree on what a strip sees."""
+    r0 = i * tile
+    pieces = []
+    for cols in ([slice(0, r0)] if i else []) + [slice(r0, r0 + tile)]:
+        s = jax.lax.dot_general(
+            q, k_ref[0, cols, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
+        pieces.append((cols, s))
+    cols, s = pieces[-1]
+    ok = _mask_ok(r0, r0, tile, tile, True, 0, None, None)
+    pieces[-1] = (cols, jnp.where(ok, s, NEG_INF))
+    return pieces
+
+
+def _fwd_tiles_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, tile, n):
+    """Forward of a lone causal block (causal_tiles), one query strip at a
+    time. A strip has every key it can see in VMEM already, so its softmax is
+    an ordinary one: no m/l carry, no alpha rescale, no scratch. Arithmetic as
+    _fwd_kernel's: bf16 operands, f32 accumulation, p rounded to the value
+    dtype once before PV. Every row sees its own diagonal key, so l >= 1."""
+    for i in range(n):
+        rows = slice(i * tile, (i + 1) * tile)
+        pieces = _strip_scores(q_ref[0, rows, :], k_ref, i, tile, scale)
+        m = functools.reduce(
+            jnp.maximum, [jnp.max(s, axis=-1, keepdims=True) for _, s in pieces]
+        )  # (tile, 1)
+        l = acc = None
+        for cols, s in pieces:
+            p = jnp.exp(s - m)
+            v = v_ref[0, cols, :]
+            p_sum = jnp.sum(p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            l = p_sum if l is None else l + p_sum
+            acc = pv if acc is None else acc + pv
+        o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0, rows, :] = m + jnp.log(l)
+
+
 def _seg_views(segments: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """(b, t) int32 document ids -> q-side (b, t, 1) and k-side (b, 1, t)
     views, each blockable with the proven trailing-singleton / single-
@@ -182,6 +276,28 @@ def _fwd(
     bq, bk = _block_sizes(t, block_q, block_kv)
     nq, nk = t // bq, t // bk
     scale = 1.0 / (d**0.5)
+
+    n_tiles = causal_tiles(t, bq, bk, causal, window, segments)
+    _log_form(bh, t, d, bq, bk, n_tiles)
+    if n_tiles:
+        head = lambda bb, hh: (bb * h + hh, 0, 0)
+        kv_head = lambda bb, hh: (bb * g + hh // n_rep, 0, 0)
+        return pl.pallas_call(
+            functools.partial(_fwd_tiles_kernel, scale=scale, tile=t // n_tiles, n=n_tiles),
+            grid=(b, h),
+            in_specs=[
+                pl.BlockSpec((1, t, d), head),
+                pl.BlockSpec((1, t, d), kv_head),
+                pl.BlockSpec((1, t, d), kv_head),
+            ],
+            out_specs=[pl.BlockSpec((1, t, d), head), pl.BlockSpec((1, t, 1), head)],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+                jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
+            ],
+            interpret=interpret,
+            name="flash_fwd_tiles",
+        )(q, k, v)
 
     seg = segments is not None
     kernel = functools.partial(
@@ -412,6 +528,55 @@ def _bwd_fused_kernel(
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _bwd_tiles_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+    dk_acc, dv_acc, *, scale, n_rep, tile, n
+):
+    """_bwd_fused_kernel for a lone causal block (causal_tiles), walked in
+    the forward's strips: per strip, s and p from the saved lse, dq written
+    to its rows, dk and dv of the keys it sees accumulated in the f32
+    scratch. Grid and GQA accumulation over the group are the fused
+    kernel's."""
+    r = pl.program_id(2)  # query head within the kv group
+
+    @pl.when(r == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    for i in range(n):
+        rows = slice(i * tile, (i + 1) * tile)
+        q = q_ref[0, rows, :]
+        do = do_ref[0, rows, :]
+        lse = lse_ref[0, rows, :]  # (tile, 1)
+        delta = delta_ref[0, rows, :]
+        dq = None
+        for cols, s in _strip_scores(q, k_ref, i, tile, scale):
+            k = k_ref[0, cols, :]
+            p = jnp.exp(s - lse)
+            # bf16 matmul inputs, fp32 accumulation (see _bwd_dq_kernel).
+            dp = jax.lax.dot_general(
+                do, v_ref[0, cols, :], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            ds = p * (dp - delta) * scale
+            dq_part = jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            dq = dq_part if dq is None else dq + dq_part
+            dv_acc[cols, :] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            dk_acc[cols, :] += jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+        dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
+
+    @pl.when(r == n_rep - 1)
+    def _finalize():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
 def _bwd(
     h: int, g: int, causal: bool, block_q: int, block_kv: int, interpret: bool, residuals, grad,
     segments: Optional[jax.Array] = None, window: int = 0,
@@ -448,11 +613,18 @@ def _bwd(
                 pl.BlockSpec((1, t, 1), lambda bb, hh, r: (bb, 0, 0)),  # seg q-side
                 pl.BlockSpec((1, 1, t), lambda bb, hh, r: (bb, 0, 0)),  # seg k-side
             ]
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(
+        n_tiles = causal_tiles(t, bq, bk, causal, window, segments)
+        if n_tiles:
+            kernel = functools.partial(
+                _bwd_tiles_kernel, scale=scale, n_rep=n_rep, tile=t // n_tiles, n=n_tiles,
+            )
+        else:
+            kernel = functools.partial(
                 _bwd_fused_kernel, causal=causal, scale=scale, n_rep=n_rep,
                 seg=seg, window=window,
-            ),
+            )
+        dq, dk, dv = pl.pallas_call(
+            kernel,
             grid=(b, g, n_rep),
             in_specs=in_specs,
             out_specs=[
@@ -470,6 +642,7 @@ def _bwd(
                 pltpu.VMEM((t, d), jnp.float32),
             ],
             interpret=interpret,
+            name="flash_bwd_tiles" if n_tiles else None,
         )(q, k, v, do, lse, delta, *seg_inputs)
         return dq, dk, dv
 

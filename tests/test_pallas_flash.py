@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from pretraining_llm_tpu.ops import pallas_flash
 from pretraining_llm_tpu.ops.attention import naive_attention
 from pretraining_llm_tpu.ops.pallas_flash import pallas_flash_attention
 
@@ -157,3 +158,128 @@ def test_remat_saved_residuals_match_recompute(policy_names):
     g_ckpt = jax.jit(jax.grad(ckpt, (0, 1, 2)))(q, k, v)
     for a, b in zip(g_plain, g_ckpt):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
+
+
+# -- a lone causal block walked in causal sub-tiles (pallas_flash.causal_tiles) -------
+
+
+def _pallas_calls(jaxpr, out=None):
+    """Every pallas_call of a jaxpr, nested ones too, as (name, kernel function)."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append((eqn.params["name"], eqn.params["jaxpr"].debug_info.func_name))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_calls(sub, out)
+    return out
+
+
+def _ref_lse(q, k, causal=True):
+    """(B, T, H, D), (B, T, G, D) -> the rows' log-sum-exp of the scaled masked scores, (B*H, T)."""
+    b, t, h, d = q.shape
+    kk = jnp.repeat(k, h // k.shape[2], axis=2).astype(jnp.float32)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), kk) / d**0.5
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    return jax.nn.logsumexp(s, axis=-1).reshape(b * h, t)
+
+
+@pytest.mark.parametrize("t", [64, 128])  # 4 and 8 sub-tiles a side at tile 16
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4), (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("g", [4, 2, 1])
+def test_causal_tiles_forward_and_backward_match_naive(monkeypatch, g, dtype, tol, t):
+    monkeypatch.setattr(pallas_flash, "CAUSAL_TILE", 16)
+    q, k, v = _gqa_qkv(jax.random.key(20 + g), t=t, h=4, g=g, dtype=dtype)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2)
+
+    flash = lambda q, k, v: pallas_flash_attention(q, k, v, causal=True, interpret=True)
+    naive = lambda q, k, v: naive_attention(q, k, v, causal=True)
+    calls = _pallas_calls(jax.make_jaxpr(jax.grad(loss(flash), (0, 1, 2)))(q, k, v).jaxpr)
+    assert [name for name, _ in calls] == ["flash_fwd_tiles", "flash_bwd_tiles"]
+
+    o, lse = pallas_flash._fwd(
+        pallas_flash._heads_first(q), pallas_flash._heads_first(k), pallas_flash._heads_first(v),
+        4, g, causal=True, block_q=0, block_kv=0, interpret=True,
+    )
+    want = {"o": naive(q, k, v), "lse": _ref_lse(q, k)}
+    got = {"o": pallas_flash._heads_last(o, 2, 4), "lse": lse[..., 0]}
+    for name, grad_naive, grad_flash in zip(
+        ("dq", "dk", "dv"),
+        jax.grad(loss(naive), (0, 1, 2))(q, k, v),
+        jax.grad(loss(flash), (0, 1, 2))(q, k, v),
+    ):
+        want[name], got[name] = grad_naive, grad_flash
+    for name in want:
+        a, b = np.asarray(want[name], np.float32), np.asarray(got[name], np.float32)
+        assert a.shape == b.shape, name
+        assert np.abs(a - b).max() <= tol * np.abs(a).max(), name
+
+
+def test_causal_tiles_saved_residuals_match_recompute(monkeypatch):
+    """save_attn_res with the tiled kernels: the tags and the lse squeeze are _flash_fwd's."""
+    monkeypatch.setattr(pallas_flash, "CAUSAL_TILE", 16)
+    q, k, v = _qkv(jax.random.key(5), t=64)
+    w = jax.random.normal(jax.random.key(6), q.shape)
+
+    def loss(q, k, v):  # linear in o, so only the kernel's own backward asks for o again
+        return jnp.sum(pallas_flash_attention(q, k, v, causal=True, interpret=True) * w)
+
+    grads = {}
+    for names in (("attn_o_res", "attn_lse"), ()):
+        ckpt = jax.checkpoint(loss, policy=jax.checkpoint_policies.save_only_these_names(*names))
+        step = jax.jit(jax.grad(ckpt, (0, 1, 2)))
+        calls = [name for name, _ in _pallas_calls(jax.make_jaxpr(step)(q, k, v).jaxpr)]
+        # Saved residuals: the backward never re-runs the forward kernel.
+        assert calls.count("flash_fwd_tiles") == (1 if names else 2), calls
+        assert calls.count("flash_bwd_tiles") == 1
+        grads[names] = step(q, k, v)
+    for a, b in zip(*grads.values()):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
+
+
+# What the tiled form does not cover, each at the tile (16, or the module's own) it is traced
+# under: (t, tile, keyword arguments of the call, the static facts causal_tiles is asked).
+_BLOCK_FORM_CASES = {
+    "non_causal": (64, 16, {"causal": False}, (64, 64, 64, False, 0, None)),
+    "window": (64, 16, {"window": 24}, (64, 64, 64, True, 24, None)),
+    "segments": (64, 16, {"segments": True}, (64, 64, 64, True, 0, "ids")),
+    "t_over_1024_at_default_blocks": (2048, None, {}, (2048, 1024, 1024, True, 0, None)),
+    "explicit_block_q_under_t": (64, 16, {"block_q": 32}, (64, 32, 64, True, 0, None)),
+    "explicit_block_kv_under_t": (64, 16, {"block_kv": 32}, (64, 64, 32, True, 0, None)),
+    "t_no_multiple_of_two_tiles": (48, 16, {}, (48, 48, 48, True, 0, None)),
+    "t_of_one_tile": (16, 16, {}, (16, 16, 16, True, 0, None)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BLOCK_FORM_CASES))
+def test_calls_outside_the_tiled_form_trace_the_block_kernels(monkeypatch, case):
+    t, tile, kwargs, facts = _BLOCK_FORM_CASES[case]
+    if tile:
+        monkeypatch.setattr(pallas_flash, "CAUSAL_TILE", tile)
+    assert pallas_flash.causal_tiles(*facts) == 0
+    kwargs = dict(kwargs)
+    if kwargs.pop("segments", False):
+        kwargs["segments"] = jnp.asarray(np.arange(t)[None] // 24, jnp.int32).repeat(2, axis=0)
+    q, k, v = _gqa_qkv(jax.random.key(30), t=t, h=4, g=2, dh=8)
+
+    def grads(q, k, v):
+        loss = lambda q, k, v: jnp.sum(pallas_flash_attention(q, k, v, interpret=True, **kwargs) ** 2)
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    here = jax.make_jaxpr(grads)(q, k, v)
+    # The parent's code path: the same trace with the tiled form taken out of the module.
+    monkeypatch.setattr(pallas_flash, "causal_tiles", lambda *a: 0)
+    parent = jax.make_jaxpr(grads)(q, k, v)
+    assert str(here) == str(parent)
+    one_block = kwargs.get("block_q", t) >= t and kwargs.get("block_kv", t) >= t and t <= 1024
+    backward = ["_bwd_fused_kernel"] if one_block else ["_bwd_dq_kernel", "_bwd_dkv_kernel"]
+    assert _pallas_calls(here.jaxpr) == [(None, fn) for fn in ["_fwd_kernel"] + backward]
+
+
+def test_causal_tiles_counts_the_sub_tiles_of_a_lone_causal_block():
+    tile = pallas_flash.CAUSAL_TILE
+    assert pallas_flash.causal_tiles(1024, 1024, 1024, True, 0, None) == 1024 // tile
+    assert pallas_flash.causal_tiles(2 * tile, 2 * tile, 2 * tile, True, 0, None) == 2
+    assert pallas_flash.causal_tiles(tile, tile, tile, True, 0, None) == 0

@@ -515,6 +515,34 @@ def test_backward_and_recompute_carry_the_scopes(scope_tokens):
     assert any("rematted_computation/attn.core" in p for p in paths)
 
 
+def test_causal_tile_kernels_are_named_under_attn_core_and_say_so_once_a_shape(monkeypatch, caplog):
+    """At a context of 1,024 the flash forward (run and recomputed) and the fused backward take
+    the tiled form: their pallas_calls carry names of their own inside attn.core, and the
+    module says which form a shape took in one INFO line however often it is traced."""
+    from pretraining_llm_tpu.ops import flash_attention, pallas_flash
+
+    monkeypatch.setattr(flash_attention, "_pallas_available", lambda: True)  # interpreted off the TPU
+    cfg = dataclasses.replace(
+        TRAIN_CFG, model=dataclasses.replace(CFG, attention_impl="flash", context_length=1024)
+    )
+    pallas_flash._log_form.cache_clear()
+    with caplog.at_level(logging.INFO, logger="pretraining_llm_tpu.ops.pallas_flash"):
+        low = ts.lower_train_step(cfg)
+        ts.lower_train_step(cfg)
+    paths = set(re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True)))
+    for call in ("attn.core/flash_fwd_tiles/pallas_call", "attn.core/flash_bwd_tiles/pallas_call",
+                 "rematted_computation/attn.core/flash_fwd_tiles/pallas_call"):
+        assert any(p.endswith(call) for p in paths), call
+    assert any(p.startswith("attn.core/flash_fwd_tiles") for p in paths)  # the forward outside the remat
+    n = 1024 // pallas_flash.CAUSAL_TILE
+    b, h, d = cfg.train.batch_size // cfg.train.microbatches, CFG.n_heads, CFG.d_model // CFG.n_heads
+    lines = [r.getMessage() for r in caplog.records if r.name == "pretraining_llm_tpu.ops.pallas_flash"]
+    assert lines == [
+        f"flash attention (B*H, T, D) = ({b * h}, 1024, {d}): causal tiles of "
+        f"{pallas_flash.CAUSAL_TILE}, {n * (n + 1) // 2} of {n * n} sub-tiles computed"
+    ]
+
+
 # -- (d) the scopes are metadata: they change no output bit ---------------------------
 
 
