@@ -23,7 +23,7 @@ from typing import Any, Dict, Optional, Tuple
 
 _ACTIVATIONS = ("relu", "gelu", "swiglu")
 _NORMS = ("layernorm", "rmsnorm")
-_POS_EMBEDS = ("learned", "rope")
+_POS_EMBEDS = ("learned", "rope", "none")
 _ATTN_IMPLS = ("naive", "flash", "ring", "ulysses")
 _REMAT_POLICIES = ("none", "full", "dots_saveable", "save_attn", "save_attn_res")
 
@@ -92,7 +92,7 @@ class ModelConfig:
     mlp_ratio: float = 4.0
     activation: str = "gelu"  # relu | gelu | swiglu
     norm: str = "layernorm"  # layernorm | rmsnorm
-    pos_embed: str = "learned"  # learned | rope
+    pos_embed: str = "learned"  # learned | rope | none (no position of any kind)
     rope_theta: float = 10000.0
     use_output_proj: bool = True  # reference has none (attention.py:95)
     tie_embeddings: bool = True  # reference unties (transformer.py:37-38)
@@ -246,16 +246,44 @@ class ModelConfig:
     # Two more norms a layer: x + N_post_attn(attn(N_in(x))), then
     # x + N_post_mlp(mlp(N_pre_mlp(x))).
     sandwich_norm: bool = False
-    # Token embeddings times sqrt(d_model) (muP's input scale).
-    embed_scale: bool = False
-    # Hybrid stack, layer_group_size g > 0: layer i is a latent-attention
+    # The factor on the token embeddings (0 = none). True stands for
+    # sqrt(d_model), muP's input scale, and is stored as that number.
+    embed_scale: float = 0.0
+    # Three more multipliers of the same family of models (Granite): each
+    # sublayer's output times residual_multiplier before it joins the residual;
+    # per-head attention scores times attention_multiplier in place of
+    # 1/sqrt(head_dim) (0 = that); the logits divided by logits_scaling.
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
+    # The mixer of every layer, one name a layer: "attn" (per-head or latent
+    # attention, whichever the model has), "kda" (models/kda.py) or "mamba"
+    # (models/mamba.py). Empty = every layer "attn", or what layer_group_size,
+    # the shorthand for a period of KDA layers, fills in. A stack with
+    # recurrent layers is hybrid: stored and scanned as runs of like layers,
+    # params["blocks"] its recurrent layers and params["attn_blocks"] its
+    # attention layers (layer_runs); served, its attention layers alone keep
+    # pages and each recurrent layer a fixed-size state a row
+    # (transformer.make_paged_kv_pool). layer_kinds is the one table to ask.
+    layer_mixers: Tuple[str, ...] = ()
+    # A Mamba-2 layer (arXiv:2405.21060): mamba_heads heads of mamba_head_dim
+    # channels (the inner width, their product), a state of mamba_d_state a
+    # channel, B and C shared by the heads of one of mamba_n_groups groups, a
+    # causal depthwise convolution of mamba_conv_kernel taps on x, B and C, and
+    # the chunked form's chunk of mamba_chunk_size tokens.
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_conv_kernel: int = 4
+    mamba_chunk_size: int = 256
+    # layer_group_size g > 0: layer i is a latent-attention
     # layer when (i + 1) % g == 0 and a KDA linear-attention layer otherwise
     # (models/kda.py; arXiv:2510.26692). A KDA layer has n_heads heads of
     # kda_head_dim keys and values, a causal depthwise convolution of
     # kda_conv_kernel taps on q, k and v, and a per-channel log-decay bounded
     # below by kda_gate_lower_bound a token. Its cache is a fixed-size state a
-    # row, not pages (transformer.make_paged_kv_pool). The stack is stored and
-    # scanned as runs of like layers, params["groups"] (layer_runs).
+    # row, not pages.
     layer_group_size: int = 0
     kda_head_dim: int = 0
     kda_conv_kernel: int = 4
@@ -430,19 +458,66 @@ class ModelConfig:
         for limits in (self.moe_swiglu_limits, self.moe_shared_swiglu_limits):
             if limits and (len(limits) != self.n_layers or min(limits) < 0):
                 raise ValueError("a SwiGLU clamp list has one limit >= 0 a layer")
-        if self.layer_group_size:
-            if self.layer_group_size < 2 or not self.kv_lora_rank or self.kda_head_dim < 1:
+        # a JSON round trip hands back a list; the config is a static (hashed) jit argument
+        object.__setattr__(self, "layer_mixers", tuple(self.layer_mixers))
+        if self.embed_scale is True:
+            object.__setattr__(self, "embed_scale", float(self.d_model) ** 0.5)
+        if self.layer_mixers and (
+            self.layer_group_size or len(self.layer_mixers) != self.n_layers
+            or set(self.layer_mixers) - {"attn", "kda", "mamba"}
+        ):
+            raise ValueError(
+                f"layer_mixers names 'attn', 'kda' or 'mamba' for each of n_layers={self.n_layers} "
+                "layers, and layer_group_size (the shorthand that fills it) is then left at 0"
+            )
+        if self.layer_group_size == 1 or self.layer_group_size < 0:
+            raise ValueError("layer_group_size is a period of at least 2 layers")
+        if self.hybrid:
+            mixers = {mixer for mixer, _ in self.layer_kinds}
+            if len(mixers) != 2 or "attn" not in mixers:
                 raise ValueError(
-                    "a hybrid stack (layer_group_size >= 2) needs latent attention "
+                    "a hybrid stack has attention layers (their pages carry the block tables "
+                    "the engine schedules by) and recurrent layers of one kind"
+                )
+            if "kda" in mixers and (not self.kv_lora_rank or self.kda_head_dim < 1):
+                raise ValueError(
+                    "a hybrid stack of KDA layers needs latent attention "
                     "(kv_lora_rank) for its attention layers and kda_head_dim"
                 )
-            if self.kda_conv_kernel < 2 or self.kda_gate_lower_bound >= 0:
+            if "kda" in mixers and (self.kda_conv_kernel < 2 or self.kda_gate_lower_bound >= 0):
                 raise ValueError("kda_conv_kernel >= 2 and kda_gate_lower_bound < 0")
+            if "mamba" in mixers and (
+                min(self.mamba_heads, self.mamba_head_dim, self.mamba_d_state, self.mamba_chunk_size) < 1
+                or self.mamba_conv_kernel < 2 or self.mamba_n_groups < 1
+                or self.mamba_heads % self.mamba_n_groups or self.kv_lora_rank
+            ):
+                raise ValueError(
+                    "a hybrid stack of Mamba-2 layers needs mamba_heads (a multiple of "
+                    "mamba_n_groups), mamba_head_dim, mamba_d_state, mamba_conv_kernel >= 2 and "
+                    "mamba_chunk_size, over per-head attention layers (no kv_lora_rank)"
+                )
             if self.hc_mult > 1 or self.pipeline_stages > 1 or self.attention_impl in ("ring", "ulysses"):
                 raise ValueError(
                     "a hybrid stack runs with plain residuals, no pipeline and no "
                     "ring/ulysses attention"
                 )
+            if self.kv_cache_dtype != "compute" or self.doc_mask_token >= 0:
+                raise ValueError("a hybrid stack has no int8 cache and no document mask")
+        if self.residual_multiplier != 1.0 and (self.hc_mult > 1 or self.sandwich_norm):
+            raise ValueError(
+                "residual_multiplier scales what a sublayer adds to the plain residual: no "
+                "residual streams, no sandwich norms"
+            )
+        if self.attention_multiplier < 0 or (self.attention_multiplier and self.kv_lora_rank):
+            raise ValueError(
+                "attention_multiplier (>= 0) is per-head attention's; latent attention "
+                "scales by softmax_scale"
+            )
+        if self.logits_scaling <= 0 or (self.logits_scaling != 1.0 and (self.lm_head_bias or self.mtp_depth)):
+            raise ValueError(
+                "logits_scaling (> 0) divides the final hidden state: no head bias and no "
+                "multi-token-prediction module's head of its own"
+            )
         # a JSON round trip hands back a list; the config is a static (hashed) jit argument
         object.__setattr__(self, "attn_kinds", tuple(self.attn_kinds))
         if self.attn_kinds:
@@ -452,7 +527,7 @@ class ModelConfig:
                 )
             if "window" in self.attn_kinds and self.sliding_window < 1:
                 raise ValueError("a 'window' layer needs sliding_window")
-            if (self.kv_lora_rank or self.layer_group_size or self.mtp_depth or self.hc_mult > 1
+            if (self.kv_lora_rank or self.hybrid or self.mtp_depth or self.hc_mult > 1
                     or self.pipeline_stages > 1 or self.kv_cache_dtype != "compute"):
                 raise ValueError(
                     "attn_kinds is per-head attention's, over an unquantized cache: no latent "
@@ -461,7 +536,7 @@ class ModelConfig:
                 )
         if not self.rope_full_layers and (self.pos_embed != "rope" or "full" not in self.attn_kinds):
             raise ValueError("rope_full_layers=False needs pos_embed='rope' and attn_kinds with full layers")
-        if (self.qk_norm or self.sandwich_norm) and (self.kv_lora_rank or self.layer_group_size or self.hc_mult > 1):
+        if (self.qk_norm or self.sandwich_norm) and (self.kv_lora_rank or self.hybrid or self.hc_mult > 1):
             raise ValueError(
                 "qk_norm and sandwich_norm are per-head attention's and the plain residual's: "
                 "no latent attention, hybrid stack or residual streams"
@@ -472,7 +547,7 @@ class ModelConfig:
                 "(several chained: ROADMAP)"
             )
         if self.mtp_depth and (
-            self.layer_group_size or self.hc_mult > 1 or self.pipeline_stages > 1
+            self.hybrid or self.hc_mult > 1 or self.pipeline_stages > 1
             or self.moe_capacity or self.moe_swiglu_limits or self.moe_shared_swiglu_limits
             or self.pos_embed != "rope"
         ):
@@ -611,19 +686,34 @@ class ModelConfig:
 
     @property
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
-        """(mixer, ffn) of every layer: mixer ``"kda"`` or ``"attn"`` (per-head
-        or latent attention, whichever the model has), ffn ``"dense"`` or
-        ``"moe"``."""
+        """The layer table, (mixer, ffn) of every layer: mixer ``"attn"``
+        (per-head or latent attention, whichever the model has), ``"kda"`` or
+        ``"mamba"``, from ``layer_mixers`` (or ``layer_group_size``, the
+        shorthand for a period of KDA layers closed by an attention layer); ffn
+        ``"dense"`` or ``"moe"``."""
         g = self.layer_group_size
+        mixers = self.layer_mixers or tuple(
+            "kda" if g and (i + 1) % g else "attn" for i in range(self.n_layers)
+        )
         return tuple(
-            ("kda" if g and (i + 1) % g else "attn",
-             "moe" if self.n_experts and i >= self.n_dense_layers else "dense")
-            for i in range(self.n_layers)
+            (mixer, "moe" if self.n_experts and i >= self.n_dense_layers else "dense")
+            for i, mixer in enumerate(mixers)
         )
 
     @property
+    def state_mixer(self) -> Optional[str]:
+        """The kind of recurrent layer a hybrid stack has (``"kda"`` |
+        ``"mamba"``): each keeps a fixed-size state a row where an attention
+        layer keeps pages. None for a stack of attention layers alone."""
+        return next((mixer for mixer, _ in self.layer_kinds if mixer != "attn"), None)
+
+    @property
+    def hybrid(self) -> bool:
+        return self.state_mixer is not None
+
+    @property
     def layer_attn_kinds(self) -> Tuple[str, ...]:
-        """"window" or "full" for every layer (a KDA layer's entry says nothing)."""
+        """"window" or "full" for every layer (a recurrent layer's entry says nothing)."""
         return self.attn_kinds or (("window" if self.sliding_window else "full",) * self.n_layers)
 
     @property
@@ -653,6 +743,20 @@ class ModelConfig:
     @property
     def n_kda_layers(self) -> int:
         return sum(mixer == "kda" for mixer, _ in self.layer_kinds)
+
+    @property
+    def n_state_layers(self) -> int:
+        """Recurrent layers of either kind: the layers that keep a state a row."""
+        return sum(mixer != "attn" for mixer, _ in self.layer_kinds)
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the convolution runs over: x, then B and C of every group."""
+        return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
 
     @property
     def latent_dim(self) -> int:
@@ -690,7 +794,9 @@ class ModelConfig:
         moe_layers = self.n_layers - self.n_dense_layers if self.n_experts else 0
         n += (self.n_layers - moe_layers) * (shared + self._ffn_params(self.d_ff))
         n += moe_layers * (shared + self._moe_params(self.experts_held))
-        n += self.n_kda_layers * (self._kda_params() - self._attn_params())
+        mixer = {"kda": self._kda_params, "mamba": self._mamba_params}.get(self.state_mixer)
+        if mixer is not None:
+            n += self.n_state_layers * (mixer() - self._attn_params())
         n += self._norm_params()  # final norm
         # the module: a block of the stack's last kind, the (2D, D) projection, three norms
         ffn = self._moe_params(self.experts_held) if self.n_experts else self._ffn_params(self.d_ff)
@@ -726,6 +832,13 @@ class ModelConfig:
         return 6 * d * w + d * self.n_heads + 3 * w * self.kda_conv_kernel + (
             self.n_heads + w + self.kda_head_dim
         )
+
+    def _mamba_params(self) -> int:
+        """A Mamba-2 mixer: the input projection to [z | x B C | dt], the
+        convolution's taps and bias, dt_bias, A_log and D a head, the gated
+        norm's weight, the output projection."""
+        d, w, c, h = self.d_model, self.mamba_d_inner, self.mamba_conv_dim, self.mamba_heads
+        return d * (w + c + h) + c * (self.mamba_conv_kernel + 1) + 3 * h + w + w * d
 
     def _hc_params(self) -> int:
         """One sublayer's hyper-connection: phi, b and the three alphas."""
@@ -1841,6 +1954,35 @@ _register(
             n_experts=8, experts_per_token=2, moe_routing="dropless", moe_score="sigmoid",
             moe_score_bias=True, moe_routed_scale=2.826, n_shared_experts=1, d_expert=32,
             n_dense_layers=1,
+        ),
+        mesh=MeshConfig(),
+        data=DataConfig(tokenizer_name="byte"),
+        train=TrainConfig(batch_size=8, train_steps=50, eval_interval=20, eval_iters=2, lr=1e-3),
+    ),
+)
+
+# Every mechanism of the Granite-4.0-H (granitemoehybrid) family at a width a
+# CPU smoke run holds: Mamba-2 layers (4 heads of 16 channels, a state of 16,
+# chunks of 16 tokens) around one position-free grouped-query attention layer,
+# the four multipliers (embedding, residual, attention scores, logits), tied
+# embeddings, softmax-routed dropless experts (half of them held) with a
+# shared one of twice their width. Served, a Mamba-2 layer keeps a state slot a
+# row and the attention layer alone keeps pages. The published widths are
+# benchmark/configs/granite-4.0-h-small.json; this is for the unit tests and
+# serve.py.
+_register(
+    "granite-toy",
+    Config(
+        model=ModelConfig(
+            vocab_size=256, context_length=256, d_model=32, n_heads=4, n_kv_heads=2, n_layers=5,
+            mlp_ratio=1.0, activation="swiglu", norm="rmsnorm", pos_embed="none",
+            tie_embeddings=True, mlp_bias=False, norm_eps=1e-5,
+            layer_mixers=("mamba", "mamba", "attn", "mamba", "mamba"),
+            mamba_heads=4, mamba_head_dim=16, mamba_d_state=16, mamba_chunk_size=16,
+            embed_scale=12.0, residual_multiplier=0.22, attention_multiplier=0.125,
+            logits_scaling=4.0,
+            n_experts=8, n_experts_held=4, experts_per_token=3, moe_routing="dropless",
+            moe_score="softmax", n_shared_experts=2, d_expert=16,
         ),
         mesh=MeshConfig(),
         data=DataConfig(tokenizer_name="byte"),

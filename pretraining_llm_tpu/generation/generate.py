@@ -113,11 +113,11 @@ def _generate_jit(
             # and each pad slot's garbage K/V is overwritten by the decoded
             # token that lands there before the kv_mask ever exposes it.
             # (A recurrent layer's state has no slot to overwrite: it takes
-            # the true length and leaves the padding out, models/kda.py.)
+            # the true length and leaves the padding out, models/recurrent.py.)
             logits, cache = transformer.forward(
                 params, prompt, cfg, kv_cache=cache, cache_index=jnp.int32(0),
                 lengths=jnp.broadcast_to(prompt_len.astype(jnp.int32), (b,))
-                if cfg.layer_group_size else None,
+                if cfg.hybrid else None,
             )
             idx = jnp.broadcast_to(
                 (prompt_len - 1).astype(jnp.int32), (b, 1, logits.shape[-1])
@@ -137,7 +137,7 @@ def _generate_jit(
             pad_off = (bucket - prompt_lengths).astype(jnp.int32)
             logits, cache = transformer.forward(
                 params, prompt, cfg, kv_cache=cache, cache_index=jnp.int32(0),
-                lengths=prompt_lengths.astype(jnp.int32) if cfg.layer_group_size else None,
+                lengths=prompt_lengths.astype(jnp.int32) if cfg.hybrid else None,
             )
             idx = jnp.broadcast_to(
                 (prompt_lengths - 1).astype(jnp.int32)[:, None, None],

@@ -54,7 +54,7 @@ _POOL_OF_DENSE = {
 
 def pool_block_size(pools: transformer.KVCache, cfg: ModelConfig) -> int:
     """Tokens a page of ``pools`` holds (per-head or latent)."""
-    # the first layer that has pages (a hybrid stack's KDA layers keep state slots)
+    # the first layer that has pages (a hybrid stack's recurrent layers keep state slots)
     fields = next(f for f in pools["layers"] if "state_pool" not in f)
     if "k_pool" in fields:
         return int(fields["k_pool"].shape[1])
@@ -187,7 +187,7 @@ def _scatter_staged_pages(
     per layer for a hybrid stack, whose layers keep unlike caches; each layer
     of each field is cut into ``n_chunks`` pages and scattered into that
     layer's pool at ``flat_ids`` (pad pages point at the reserved scratch
-    block 0 — duplicate indices there are benign). A KDA layer's staged state
+    block 0 — duplicate indices there are benign). A recurrent layer's staged state
     and conv tail go whole into slot ``slots[row]`` of its state pools (pad
     rows: the scratch slot), written with the pages. The layers
     ``in_window_pool`` (two cache lifetimes) scatter at ``window_ids`` into
@@ -232,7 +232,7 @@ def _scatter_staged_pages(
 def _staging_cache(cfg: ModelConfig, n_rows: int, p_bucket: int) -> transformer.KVCache:
     """The dense cache a prefill forward fills for ``_scatter_staged_pages``:
     stacked (the rolled depth scan), per layer for a hybrid stack."""
-    return transformer.make_kv_cache(cfg, n_rows, p_bucket, stacked=not cfg.layer_group_size)
+    return transformer.make_kv_cache(cfg, n_rows, p_bucket, stacked=not cfg.hybrid)
 
 
 # A prefill computes the f32 logits of every position and keeps the last real
@@ -250,7 +250,7 @@ def _prefill_last_logits(
     (N, V) f32 at each row's ``last_idx``, the filled cache)."""
     n_rows, p_bucket = prompts.shape
     # a recurrent layer leaves its state as of each row's last real token
-    lengths = last_idx + 1 if cfg.layer_group_size else None
+    lengths = last_idx + 1 if cfg.hybrid else None
     if 4 * n_rows * p_bucket * cfg.vocab_size <= _ALL_POSITION_LOGITS_BYTES:
         logits, cache = transformer.forward(
             params, prompts, cfg, kv_cache=cache, cache_index=jnp.int32(0),
@@ -327,7 +327,7 @@ def _prefill_dense(
         # One forward fills the staging cache and _scatter_pages consumes it
         # field by field ((L, 1, pages*bs, ...) -> pool pages): stacked.
         cache = _staging_cache(cfg, 1, p_bucket)
-        if cfg.layer_group_size or 4 * p_bucket * cfg.vocab_size > _ALL_POSITION_LOGITS_BYTES:
+        if cfg.hybrid or 4 * p_bucket * cfg.vocab_size > _ALL_POSITION_LOGITS_BYTES:
             last, cache = _prefill_last_logits(
                 params, prompt, (prompt_len - 1).astype(jnp.int32)[None], cfg, cache
             )

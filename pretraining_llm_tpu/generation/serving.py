@@ -60,14 +60,40 @@ import numpy as np
 from pretraining_llm_tpu.config import ModelConfig
 from pretraining_llm_tpu.generation import paged, speculative
 from pretraining_llm_tpu.generation import prefix_cache as prefix_cache_mod
-from pretraining_llm_tpu.models import kda, mla, moe, transformer
+from pretraining_llm_tpu.models import mla, moe, recurrent, transformer
 from pretraining_llm_tpu.observability import spans as _spans
 
 _log = logging.getLogger("pretraining_llm_tpu.serving")
 
 # The most padded prompt tokens one batched admission prefill program takes
-# (ServingEngine._prefill_parts); a boundary that admits more runs several.
+# (ServingEngine._prefill_parts); a boundary that admits more runs several. An
+# engine of an expert model on a device that tells its memory starts from what
+# fits (prefill_program_tokens below), every other from this.
 PREFILL_PROGRAM_TOKENS = 32768
+
+# What an expert layer's prefill holds at once, in copies of a token's
+# experts_per_token rows of d_model: the sorted rows in, the experts' output, the
+# combine's gather and its reshape (padded to whole tiles of 16 rows). The at-size
+# compiles for a v5e read 2.6 of them in the combine alone and 232 KB a padded
+# token in all on the state-space hybrid (top-10 of 4,096: 2.8 copies beside
+# 1.8 GB that does not grow with the tokens); 3.75 puts its refused 16,384 and
+# its accepted 8,192 tokens, and the linear-attention hybrid's accepted 32,768,
+# each a factor 1.2 from the line (PERF.md section 7, "After PR 43" (5)).
+PREFILL_EXPERT_COPIES = 3.75
+
+
+def prefill_program_tokens(cfg: ModelConfig, free_bytes: Optional[int]) -> int:
+    """The most padded tokens an admission prefill program of ``cfg`` may take
+    beside what is resident: PREFILL_PROGRAM_TOKENS, or for an expert model
+    the largest power of two under it whose expert layer's temporaries
+    (PREFILL_EXPERT_COPIES) fit the ``free_bytes`` of the device. None (a
+    device that does not tell, as a CPU) keeps the constant."""
+    if not cfg.n_experts or free_bytes is None:
+        return PREFILL_PROGRAM_TOKENS
+    token_bytes = PREFILL_EXPERT_COPIES * cfg.experts_per_token * cfg.d_model * jnp.dtype(cfg.compute_dtype).itemsize
+    fit = max(1, int(free_bytes / token_bytes))
+    return min(PREFILL_PROGRAM_TOKENS, 1 << (fit.bit_length() - 1))
+
 
 # Where a scheduler turn's host time goes; stats["phase_s"] keeps one running
 # total per phase. "other" is the turn's own bookkeeping between the rest.
@@ -193,8 +219,8 @@ class ServingEngine:
                 "paged serving does not support capacity-routed MoE models "
                 "(moe_routing='dropless' is served)"
             )
-        if cfg.layer_group_size:
-            # State slots beside the pages (models/kda.py): a row's recurrent
+        if cfg.hybrid:
+            # State slots beside the pages (models/recurrent.py): a row's recurrent
             # state cannot be shared by prefix, shipped, digested or rolled back yet.
             refused = {
                 "prefix_cache": prefix_cache, "kv_checksum": kv_checksum,
@@ -202,7 +228,7 @@ class ServingEngine:
             }
             if any(refused.values()):
                 raise ValueError(
-                    "a state-slot model (linear-attention layers) is served without "
+                    f"a state-slot model ({cfg.state_mixer} layers) is served without "
                     + ", ".join(k for k, v in refused.items() if v)
                     + ": the prefix cache (and kv_transfer, which publishes into it) "
                     "and kv_checksum know pages only, int8 pages are not built on "
@@ -390,12 +416,9 @@ class ServingEngine:
             self.decode_experts = moe.experts_form(
                 queries * int(max_batch) * cfg.experts_per_token, cfg, experts, mesh=mesh
             )
-        # And how it steps a KDA layer's state slots (models/kda.py::step_form,
-        # read from the pool's own shape and dtype); None without any.
-        self.decode_state = None
-        if cfg.layer_group_size:
-            shape, dtype = kda.state_shapes(cfg, int(max_batch) + 1)["state"]
-            self.decode_state = kda.step_form(jax.ShapeDtypeStruct(shape, dtype), mesh=mesh)
+        # And how it steps a recurrent layer's state slots (the mixer's own
+        # step_form, read from the pool's shape and dtype); None without any.
+        self.decode_state = recurrent.step_form(cfg, int(max_batch) + 1, mesh=mesh)
         self.max_batch = int(max_batch)
         self.block_size = int(block_size)
         # Clamp max_seq so EVERY reachable prefill bucket fits the model
@@ -490,7 +513,7 @@ class ServingEngine:
                 # bit-compatibility with the dense int8 cache.
                 scale_dtype="bfloat16" if self.quantize == "int8-kv" else None,
                 # a hybrid stack: one state slot a batch row beside the pages
-                state_slots=self.max_batch if pool_cfg.layer_group_size else 0,
+                state_slots=self.max_batch if pool_cfg.hybrid else 0,
             )
             if mesh is None:
                 return pools
@@ -536,12 +559,21 @@ class ServingEngine:
 
         # A hybrid stack keeps a recurrent state a row beside its pages: row b
         # owns slot b of the state pools for as long as it owns the row.
-        self.state_slots = bool(cfg.layer_group_size)
+        self.state_slots = cfg.hybrid
         self.pools = _build_pool(cfg)
         # Draft pools mirror the block structure exactly: SAME table/ids,
         # draft-model dims per block (paged_spec_round's shared-frontier
         # contract).
         self.d_pools = _build_pool(self.draft_cfg) if self.draft_cfg is not None else None
+        # what _prefill_parts splits a boundary's admissions by: what fits beside the
+        # weights and the pools just built, where the device tells (halved by _admit
+        # should the compiler refuse a program all the same)
+        held = next(iter(jax.tree.leaves(self.pools)[0].devices())).memory_stats() or {}
+        free = held["bytes_limit"] - held["bytes_in_use"] if "bytes_limit" in held else None
+        self.prefill_program_tokens = prefill_program_tokens(cfg, free)
+        if self.prefill_program_tokens < PREFILL_PROGRAM_TOKENS:
+            _log.info("admission prefills hold at most %d padded tokens a program: %.2f GB free beside the "
+                      "weights and pools", self.prefill_program_tokens, free / 1e9)
         self.n_blocks = int(n_blocks)
         self.alloc = paged.BlockAllocator(n_blocks)
         self.tables = np.zeros((self.max_batch, self.max_blocks), np.int32)
@@ -693,7 +725,7 @@ class ServingEngine:
         pages included), host-side shape math only (no device sync).
         Draft pools (speculative serving) are reported separately."""
         pools = self.pools
-        # the first layer that has pages; a hybrid stack's KDA layers keep state slots
+        # the first layer that has pages; a hybrid stack's recurrent layers keep state slots
         layer0 = next(f for f in pools["layers"] if "state_pool" not in f)
         state = int(sum(
             leaf.nbytes for f in pools["layers"] if "state_pool" in f
@@ -740,6 +772,10 @@ class ServingEngine:
                 state_slots=self.max_batch, state_bytes=state,
                 bytes_per_slot=state // (self.max_batch + 1),
                 decode_state=self.decode_state,  # "kernel" | "jnp"
+                # the two kinds of layer: which mixer keeps the slots, in how
+                # many layers, and how many layers the pages above are for
+                state_mixer=self.cfg.state_mixer, state_layers=self.cfg.n_state_layers,
+                page_layers=self.cfg.n_cache_layers - self.cfg.n_state_layers,
             )
         if self.self_draft:
             # the module's pages are one more layer of ``pools``, counted above
@@ -1874,10 +1910,11 @@ class ServingEngine:
         """One boundary's admissions as the batched prefill programs they run
         in: in order, as many a program as keep its padded size (rows bucketed
         to a power of two x the longest prompt's page bucket) within
-        ``PREFILL_PROGRAM_TOKENS``. A prefill's activations grow with that
-        product (an expert layer sorts ``experts_per_token`` copies of every
-        token), and eight 5,184-token rows of a 128-row engine do not fit a
-        v5e beside 10.7 GB of weights and caches where two programs of four do."""
+        ``self.prefill_program_tokens`` (``PREFILL_PROGRAM_TOKENS`` at first). A
+        prefill's activations grow with that product (an expert layer sorts
+        ``experts_per_token`` copies of every token), and eight 5,184-token
+        rows of a 128-row engine do not fit a v5e beside 10.7 GB of weights and
+        caches where two programs of four do."""
         parts: List[List[_Request]] = []
         for req in reqs:
             trial = (parts[-1] if parts else []) + [req]
@@ -1886,7 +1923,7 @@ class ServingEngine:
                 max(paged.required_blocks(len(r.prompt), self.block_size) for r in trial),
                 self.block_size,
             )
-            if parts and rows * pages * self.block_size <= PREFILL_PROGRAM_TOKENS:
+            if parts and rows * pages * self.block_size <= self.prefill_program_tokens:
                 parts[-1].append(req)
             else:
                 parts.append([req])
@@ -2097,26 +2134,47 @@ class ServingEngine:
                 "prefill_dispatch", "serving.prefill_dispatch",
                 miss=len(miss), hits=len(hits),
             ):
-                for part in self._prefill_parts(miss):
+                parts = self._prefill_parts(miss)
+                while parts:
+                    part = parts.pop(0)
                     self._key, sub = jax.random.split(self._key)
                     prompts = [r.prompt for r in part]
                     prefill_ids = [
                         r.blocks[: paged.required_blocks(len(r.prompt), self.block_size)]
                         for r in part
                     ]
-                    toks_dev, self.pools = paged.prefill_into_pool_batched(
-                        self.params, self.cfg, self.pools, prompts, prefill_ids,
-                        sub, temperature=self.temperature, top_k=self.top_k,
-                        top_p=self.top_p, min_p=self.min_p, mesh=self.mesh,
-                        # the state is written with the pages, into the row's own slot
-                        slots=[r.row for r in part] if self.state_slots else None,
-                        # self-drafting: the module's pages and each row's first draft too
-                        with_draft=self.self_draft,
-                        # two cache lifetimes: the window layers' live pages, 0 behind the window
-                        rows_window_ids=[
-                            self.w_tables[r.row, : len(ids)].tolist() for r, ids in zip(part, prefill_ids)
-                        ] if self.two_lifetimes else None,
-                    )
+                    try:
+                        toks_dev, self.pools = paged.prefill_into_pool_batched(
+                            self.params, self.cfg, self.pools, prompts, prefill_ids,
+                            sub, temperature=self.temperature, top_k=self.top_k,
+                            top_p=self.top_p, min_p=self.min_p, mesh=self.mesh,
+                            # the state is written with the pages, into the row's own slot
+                            slots=[r.row for r in part] if self.state_slots else None,
+                            # self-drafting: the module's pages and each row's first draft too
+                            with_draft=self.self_draft,
+                            # two cache lifetimes: the window layers' live pages, 0 behind the window
+                            rows_window_ids=[
+                                self.w_tables[r.row, : len(ids)].tolist() for r, ids in zip(part, prefill_ids)
+                            ] if self.two_lifetimes else None,
+                        )
+                    except jax.errors.JaxRuntimeError as err:
+                        # The compiler knows what a program needs beside the weights and
+                        # pools that are resident, and refuses one that does not fit before
+                        # it runs: the pools it would have been given are untouched then,
+                        # and only then is there anything to run again. Several rows: halve
+                        # the split's figure and run what is left in smaller programs.
+                        given_away = any(leaf.is_deleted() for leaf in jax.tree.leaves(self.pools))
+                        if "RESOURCE_EXHAUSTED" not in str(err) or len(part) == 1 or given_away:
+                            raise
+                        rows, pages = paged.prefill_bucket(self.cfg, len(part), max(map(len, prefill_ids)), self.block_size)
+                        self.prefill_program_tokens = rows * pages * self.block_size // 2
+                        self.stats["prefill_program_tokens"] = self.prefill_program_tokens
+                        _log.warning(
+                            "no room for a prefill program of %d x %d tokens beside the weights and pools: "
+                            "admission prefills now hold at most %d padded tokens a program",
+                            rows, pages * self.block_size, self.prefill_program_tokens)
+                        parts = self._prefill_parts(part + [r for rest in parts for r in rest])
+                        continue
                     drafts_dev = None
                     if self.self_draft:
                         toks_dev, drafts_dev = toks_dev[:, 0], toks_dev[:, 1]

@@ -42,6 +42,10 @@ Two forms of the recurrence, one function of the inputs:
   them, two factors of at most 1 that a matmul multiplies (a factor that
   underflows is the zero the true product rounds to).
 
+``mix`` is the mixer on a sublayer's normed input; the cache it runs from and
+leaves behind (none, a contiguous cache, a slot of the state pools) is
+``models/recurrent.py``'s, shared with the other recurrent mixer.
+
 Both take a validity mask: an invalid position (bucket padding past a row's
 true length, a ragged row's left padding) gets ``g = 0``, ``beta = 0`` and a
 zero convolution input, so it changes neither the state nor the tail, and a
@@ -62,6 +66,7 @@ from pretraining_llm_tpu.parallel.sharding import current_mesh
 
 Params = Dict[str, Any]
 
+SCOPE = "kda"  # the mixer's device scopes: kda.proj, kda.conv, kda.gate, kda.step | kda.chunk, kda.out
 CHUNK = 64  # tokens a chunk of the chunked form
 SUB = 16  # tokens that share one reference point inside a chunk
 
@@ -273,66 +278,3 @@ def mix(
             "bthn,hnd->btd", o.astype(cdt), w(p, "wo", cdt), preferred_element_type=f32
         ).astype(cdt)
     return y, state, tail
-
-
-def mixer_block(
-    blk: Params, x: jax.Array, cfg: ModelConfig, kv: Optional[Params],
-    pad_offsets: Optional[jax.Array] = None, paged: Any = None,
-    lengths: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Optional[Params]]:
-    """The KDA counterpart of ``transformer._attention_block``: x + mix(ln1(x))
-    and the layer's new cache. ``kv`` is None (training forward: a fresh state,
-    nothing kept), ``{"state": (B,H,K,V), "conv": (B,kernel-1,C)}`` (a
-    contiguous cache: the call starts from it) or ``{"state_pool", "conv_pool"}``
-    (serving: slot ``paged.slots[b]`` of the pools, or row b's own slot; a row
-    whose table names no page is dead and leaves its slot alone).
-    ``lengths`` (B,) are the rows' true token counts in a right-padded call,
-    ``pad_offsets`` (B,) their left padding in a ragged one."""
-    b, t, _ = x.shape
-    with jax.named_scope("blk.norm"):
-        h = layers.apply_norm(cfg.norm, blk["ln1"], x, cfg.norm_eps)
-    pos = jnp.arange(t)[None, :]
-    valid = ends = None
-    if paged is not None and paged.q_lens is not None and t > 1:
-        lengths = paged.q_lens
-    if lengths is not None and t > 1:
-        valid, ends = pos < lengths[:, None], lengths.astype(jnp.int32)
-    elif pad_offsets is not None and t > 1:
-        valid = pos >= pad_offsets[:, None]  # a decode step's token is real in every row
-    if kv is None:
-        shapes = state_shapes(cfg, b)
-        state, tail = (jnp.zeros(*shapes[name]) for name in ("state", "conv"))
-        y, _, _ = mix(blk["attn"], h, cfg, state, tail, valid, ends)
-        return x + y.astype(x.dtype), None
-    if "state_pool" not in kv:
-        y, state, tail = mix(blk["attn"], h, cfg, kv["state"], kv["conv"], valid, ends)
-        return x + y.astype(x.dtype), {"state": state, "conv": tail}
-    if paged is None:
-        raise ValueError("a state pool requires forward(..., paged=PagedInfo)")
-    spool, cpool = kv["state_pool"], kv["conv_pool"]
-    if paged.slots is None:
-        # A row's slot is its index, so the recurrence runs over the pools as
-        # they lie, every slot a row (the scratch slot a row of zeros): no
-        # gather, no copy back. A dead row (its table names no page) is all
-        # padding: g = 0, beta = 0 and a tail that ends before its first token
-        # leave its slot as it was, by the arithmetic and not by a select over
-        # the states.
-        n = spool.shape[0]
-        live = jnp.pad(paged.block_tables[:, 0] != 0, (0, n - b))
-        valid = live[:, None] if valid is None else jnp.pad(valid, ((0, n - b), (0, 0))) & live[:, None]
-        ends = jnp.where(live, t if ends is None else jnp.pad(ends, (0, n - b)), 0).astype(jnp.int32)
-        y, spool, cpool = mix(
-            blk["attn"], jnp.pad(h, ((0, n - b), (0, 0), (0, 0))), cfg, spool, cpool, valid, ends)
-        y = y[:b]
-    else:
-        with jax.named_scope("kda.chunk" if t > 1 else "kda.step"):
-            # a row that holds nothing yet (the first chunk of a prompt) starts from
-            # a fresh state, whatever its slot's last owner left there
-            fresh = (paged.seq_lens == 0)[:, None, None]
-            state = jnp.where(fresh[..., None], 0.0, spool[paged.slots])
-            tail = jnp.where(fresh, 0, cpool[paged.slots])
-        y, new_state, new_tail = mix(blk["attn"], h, cfg, state, tail, valid, ends)
-        with jax.named_scope("kda.chunk" if t > 1 else "kda.step"):
-            spool = spool.at[paged.slots].set(new_state)
-            cpool = cpool.at[paged.slots].set(new_tail)
-    return x + y.astype(x.dtype), {"state_pool": spool, "conv_pool": cpool}

@@ -60,6 +60,16 @@ def init_norm(kind: str, d: int, dtype: jnp.dtype) -> Params:
     return p
 
 
+def join_residual(x: jax.Array, out: jax.Array, multiplier: float = 1.0, residual: bool = True) -> jax.Array:
+    """A sublayer's output as it joins the residual: x + out in x's dtype, the
+    output times ``multiplier`` (``ModelConfig.residual_multiplier``) first where
+    the model has one. ``residual=False`` hands back the (scaled) output alone."""
+    out = out.astype(x.dtype)
+    if multiplier != 1.0:
+        out = out * jnp.asarray(multiplier, x.dtype)
+    return x + out if residual else out
+
+
 # ---------------------------------------------------------------------------
 # Activations
 # ---------------------------------------------------------------------------

@@ -38,7 +38,7 @@ from jax.sharding import PartitionSpec as P
 
 
 from pretraining_llm_tpu.config import ModelConfig
-from pretraining_llm_tpu.models import hyper, kda, layers, mla, moe
+from pretraining_llm_tpu.models import hyper, layers, mla, moe, recurrent
 from pretraining_llm_tpu.ops import remat
 from pretraining_llm_tpu.ops.attention import multihead_attention
 from pretraining_llm_tpu.parallel.sharding import constrain, current_mesh
@@ -83,7 +83,7 @@ class PagedInfo(NamedTuple):
     # computes pad queries and lets the caller discard them, so outputs
     # for REAL queries are bit-identical whether or not q_lens is passed.
     q_lens: Optional[jax.Array] = None  # (B,) int32 or None
-    # State-slot models (models/kda.py): the slot of the state pools each row
+    # State-slot models (models/recurrent.py): the slot of the state pools each row
     # reads and writes. None = row b's own slot b, left alone while the row's
     # table names no page (the decode step); a prefill program's rows are not
     # the engine's, so it passes them (pad rows: the scratch slot, the last).
@@ -174,8 +174,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 
     def init_block(k: jax.Array, dense_ffn: bool = False, mixer: str = "attn") -> Params:
         ks = jax.random.split(k, 5)
-        if mixer == "kda":
-            attn: Params = kda.init_params(cfg, ks[0], resid_std, dtype)
+        if mixer != "attn":
+            attn: Params = recurrent.MIXERS[mixer].init_params(cfg, ks[0], resid_std, dtype)
         elif cfg.kv_lora_rank:
             attn: Params = mla.init_attn_params(cfg, ks[0], resid_std, dtype)
         elif g == h:
@@ -191,10 +191,10 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             if cfg.qkv_bias:
                 attn["bq"] = jnp.zeros((h, dh), dtype)
                 attn["bkv"] = jnp.zeros((2, g, dh), dtype)
-        if cfg.use_output_proj and not cfg.kv_lora_rank and mixer != "kda":
+        if cfg.use_output_proj and not cfg.kv_lora_rank and mixer == "attn":
             attn["wo"] = normal(ks[1], (h, dh, d), resid_std)
             attn["bo"] = jnp.zeros((d,), dtype)
-        if not cfg.kv_lora_rank and mixer != "kda":
+        if not cfg.kv_lora_rank and mixer == "attn":
             if cfg.qk_norm:
                 attn["q_norm"] = layers.init_norm("rmsnorm", dh, dtype)
                 attn["k_norm"] = layers.init_norm("rmsnorm", dh, dtype)
@@ -235,7 +235,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         "tok_embed": {"embedding": normal(k_tok, (v, d))},
         "final_norm": layers.init_norm(cfg.norm, d, dtype),
     }
-    if cfg.layer_group_size:
+    if cfg.hybrid:
         # a hybrid stack: one stack a kind of layer (stack_key), whatever the
         # order the kinds come in (layer_groups)
         kinds = cfg.layer_kinds
@@ -275,10 +275,10 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 def stack_key(cfg: ModelConfig, mixer: str, ffn: str) -> str:
     """Where the layers of one kind are stacked in the parameter tree:
     "blocks", or "dense_blocks" for an expert model's leading dense layers; a
-    hybrid stack's attention layers (its linear-attention layers are the many)
-    in "attn_blocks" and "attn_dense_blocks"."""
+    hybrid stack's attention layers (its recurrent layers are the many) in
+    "attn_blocks" and "attn_dense_blocks"."""
     key = "dense_blocks" if ffn == "dense" and cfg.n_experts else "blocks"
-    return "attn_" + key if cfg.layer_group_size and mixer == "attn" else key
+    return "attn_" + key if cfg.hybrid and mixer == "attn" else key
 
 
 def layer_groups(params: Params, cfg: ModelConfig):
@@ -288,7 +288,7 @@ def layer_groups(params: Params, cfg: ModelConfig):
     an expert model with leading dense layers two; a hybrid stack alternates
     between its kinds' stacks, a stack of window and full attention layers
     between its attention kinds inside "blocks" (``cfg.layer_runs``)."""
-    if not (cfg.layer_group_size or cfg.two_lifetimes):
+    if not (cfg.hybrid or cfg.two_lifetimes):
         k = cfg.n_dense_layers if "dense_blocks" in params else 0
         groups = [(range(k, cfg.n_layers), params["blocks"], 0)]
         if k:
@@ -425,6 +425,12 @@ def _attention_core(
                     bkv[None, :, :, None, :] if hm else bkv[None, :, None]
                 )
             k, v = kvp[:, 0], kvp[:, 1]  # hm: (B, G, T, Dh)
+
+    if cfg.attention_multiplier:
+        # Scores times attention_multiplier where every attention form below
+        # scales by 1/sqrt(head_dim): the ratio of the two goes onto the queries.
+        with jax.named_scope("attn.qkv"):
+            q = (q.astype(jnp.float32) * (cfg.attention_multiplier * cfg.head_dim ** 0.5)).astype(cdt)
 
     if "q_norm" in blk["attn"]:
         with jax.named_scope("attn.qk_norm"):
@@ -805,7 +811,7 @@ def _attention_core(
                 out = out.transpose(0, 2, 1, 3)
             b, t = out.shape[:2]
             out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)
-        return (x + out.astype(x.dtype) if residual else out.astype(x.dtype)), new_kv
+        return layers.join_residual(x, out, cfg.residual_multiplier, residual), new_kv
 
 
 def _dense_mlp(mlp: Params, h: jax.Array, cfg: ModelConfig, limit: Any = None) -> jax.Array:
@@ -855,7 +861,7 @@ def _mlp_block(
             out, aux = moe.moe_mlp(mlp, h, cfg, decode=decode)
         else:
             out, aux = _dense_mlp(mlp, h, cfg), jnp.zeros((), jnp.float32)
-        return (x + out.astype(x.dtype) if residual else out.astype(x.dtype)), aux
+        return layers.join_residual(x, out, cfg.residual_multiplier, residual), aux
 
 
 def _block(
@@ -872,11 +878,14 @@ def _block(
     paged: Optional[PagedInfo] = None,
     lengths: Optional[jax.Array] = None,
     attn_kind: Optional[str] = None,
+    mixer: str = "attn",
 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
-    if "wf" in blk["attn"]:  # a KDA mixer's decay projection
+    """One decoder layer. ``mixer`` is the layer's entry in the layer table
+    (``cfg.layer_kinds``): "attn", or a recurrent mixer of ``recurrent.MIXERS``."""
+    if mixer != "attn":
         if zigzag or segments is not None:
-            raise ValueError("a KDA layer has no ring layout and no document mask")
-        x, new_kv = kda.mixer_block(blk, x, cfg, kv, pad_offsets, paged, lengths)
+            raise ValueError("a recurrent layer has no ring layout and no document mask")
+        x, new_kv = recurrent.mixer_block(mixer, blk, x, cfg, kv, pad_offsets, paged, lengths)
         x, aux = _mlp_block(blk, x, cfg, decode=kv is not None and x.shape[1] == 1)
         return x, new_kv, aux
     if cfg.hc_mult > 1:
@@ -1000,7 +1009,7 @@ def forward(
     ``lengths`` (B,) int32: each row's true token count in a right-padded
     multi-token call (a bucketed prefill). Attention needs none (causality
     keeps the padding out of every real position); a recurrent layer
-    (models/kda.py) does, to leave its state as of the last real token.
+    (models/recurrent.py) does, to leave its state as of the last real token.
     """
     cdt = jnp.dtype(cfg.compute_dtype)
     b, t = tokens.shape
@@ -1059,7 +1068,7 @@ def forward(
         emb_table = constrain(params["tok_embed"]["embedding"], None, None)
         x = emb_table[tokens].astype(cdt)
         if cfg.embed_scale:
-            x = x * jnp.asarray(cfg.d_model ** 0.5, cdt)
+            x = x * jnp.asarray(cfg.embed_scale, cdt)
         if cfg.pos_embed == "learned":
             pos_table = constrain(params["pos_embed"]["embedding"], None, None)
             if paged is not None:
@@ -1078,7 +1087,7 @@ def forward(
             else:
                 x = x + pos_table[positions].astype(cdt)[None]
     rope = None
-    if cfg.pos_embed != "learned":
+    if cfg.pos_embed == "rope":
         rope = layers.rope_table(
             cfg.context_length, cfg.qk_rope_head_dim or cfg.head_dim, cfg.rope_theta, cfg.rope_yarn
         )
@@ -1106,13 +1115,13 @@ def forward(
         ]
         return jnp.asarray(both, jnp.float32).T if any(map(any, both)) else None
 
-    def scan_body(carry, layer_inputs, kind=None):
+    def scan_body(carry, layer_inputs, kind=None, mixer="attn"):
         x, aux_sum = carry
         if kv_cache is None:
             blk = layer_inputs
             x, _, aux = _block(
                 blk, x, cfg, rope, positions, None, None, zigzag,
-                segments=segments, lengths=lengths, attn_kind=kind,
+                segments=segments, lengths=lengths, attn_kind=kind, mixer=mixer,
             )
             if aux.ndim:  # a dropless layer's tokens per expert ride the outputs
                 return (x, aux_sum), ((x if return_hidden else None), aux)
@@ -1120,7 +1129,7 @@ def forward(
         blk, cache_layer = layer_inputs
         x, new_kv, aux = _block(
             blk, x, cfg, rope, positions, cache_layer, cache_index,
-            pad_offsets=pad_offsets, paged=paged, lengths=lengths, attn_kind=kind,
+            pad_offsets=pad_offsets, paged=paged, lengths=lengths, attn_kind=kind, mixer=mixer,
         )
         if aux.ndim:
             return (x, aux_sum), (new_kv, aux)
@@ -1157,10 +1166,11 @@ def forward(
         if n != jax.tree.leaves(blocks)[0].shape[0]:
             blocks = jax.tree.map(lambda a: a[first : first + n], blocks)
         xs = blocks if cache is None else (blocks, cache)
-        # a mixed stack's runs are each of one attention kind (cfg.layer_runs)
+        # a mixed stack's runs are each of one attention kind and one mixer (cfg.layer_runs)
         kind = cfg.attn_kinds[layers_of[0]] if cfg.attn_kinds else None
-        step = body if kind is None else remat.checkpoint_wrap(
-            functools.partial(scan_body, kind=kind), cfg.remat)
+        mixer = cfg.layer_kinds[layers_of[0]][0]
+        step = body if kind is None and mixer == "attn" else remat.checkpoint_wrap(
+            functools.partial(scan_body, kind=kind, mixer=mixer), cfg.remat)
         if experts is not None:
             clamps = clamps_of(layers_of)
 
@@ -1169,8 +1179,8 @@ def forward(
                 inputs, layer = inputs
                 layer, limits = layer if clamps is not None else (layer, None)
                 if cache is None:
-                    return scan_body(carry, in_stack(inputs, experts, layer, limits), kind)
-                return scan_body(carry, (in_stack(inputs[0], experts, layer, limits), inputs[1]), kind)
+                    return scan_body(carry, in_stack(inputs, experts, layer, limits), kind, mixer)
+                return scan_body(carry, (in_stack(inputs[0], experts, layer, limits), inputs[1]), kind, mixer)
 
             idx = jnp.arange(first, first + n, dtype=jnp.int32)
             xs = (xs, idx if clamps is None else (idx, clamps))
@@ -1262,6 +1272,7 @@ def forward(
                         blk, x, cfg, rope, positions, kv_cache["layers"][layer],
                         cache_index, pad_offsets=pad_offsets, paged=paged, lengths=lengths,
                         attn_kind=cfg.attn_kinds[layer] if cfg.attn_kinds else None,
+                        mixer=cfg.layer_kinds[layer][0],
                     )
                     if aux.ndim:
                         counts.append(aux)
@@ -1292,6 +1303,11 @@ def forward(
         x = hyper.sum_out(x)
     with jax.named_scope("final_norm"):
         x = layers.apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
+        if cfg.logits_scaling != 1.0:
+            # logits / logits_scaling, taken on the hidden state: the head is
+            # linear (no bias with it), and every head downstream (lm_head, the
+            # chunked CE, the engine's last-position head) then needs no copy
+            x = x * jnp.asarray(1.0 / cfg.logits_scaling, x.dtype)
     if return_pre_logits:
         # Loss path: the chunked-CE head computes logits itself (see
         # _chunked_ce); hand back the final-norm hidden states.
@@ -1702,7 +1718,7 @@ def _is_pool_cache(kv_cache: Optional[KVCache]) -> bool:
 
 def _unstack_fields(
     cfg: ModelConfig, fields: Dict[str, Tuple[Tuple[int, ...], Any]],
-    kda_fields: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]] = None,
+    state_fields: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]] = None,
     window_fields: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]] = None,
 ) -> KVCache:
     """{'layers': per-layer dicts of fresh zero arrays} from {name:
@@ -1710,15 +1726,15 @@ def _unstack_fields(
     materializing the stacked array first: pools are sized toward HBM
     capacity, and a transient 2x would OOM engines that otherwise fit).
     Each layer gets its own buffers (sharing one zeros across carry
-    leaves would alias donated updates). A KDA layer of a hybrid stack keeps
-    ``kda_fields`` ({name: (shape, dtype)}, no layer dimension) instead, a
+    leaves would alias donated updates). A recurrent layer of a hybrid stack keeps
+    ``state_fields`` ({name: (shape, dtype)}, no layer dimension) instead, a
     window layer of a stack with two cache lifetimes ``window_fields`` (specs
     like ``fields``, its own pool's size)."""
     window = tuple(window_fields is not None and k == "window" for k in cfg.layer_attn_kinds)
     return {
         "layers": tuple(
-            {name: jnp.zeros(shape, dt) for name, (shape, dt) in kda_fields.items()}
-            if mixer == "kda" else
+            {name: jnp.zeros(shape, dt) for name, (shape, dt) in state_fields.items()}
+            if mixer != "attn" else
             {name: jnp.zeros(shape[1:], dt)
              for name, (shape, dt) in (window_fields if own else fields).items()}
             # the stack's layers, then the MTP module's block (an attention layer)
@@ -1776,12 +1792,10 @@ def make_kv_cache(
         dtype = jnp.dtype(dtype or cfg.compute_dtype)
         fields = {"k": (shape, dtype), "v": (shape, dtype)}
     if stacked:
-        if cfg.layer_group_size:
+        if cfg.hybrid:
             raise ValueError("a hybrid stack's layers keep unlike caches: no stacked form")
         return {name: jnp.zeros(s, dt) for name, (s, dt) in fields.items()}
-    return _unstack_fields(
-        cfg, fields, kda.state_shapes(cfg, batch_size) if cfg.layer_group_size else None
-    )
+    return _unstack_fields(cfg, fields, recurrent.state_shapes(cfg, batch_size))
 
 
 def make_paged_kv_pool(
@@ -1810,9 +1824,10 @@ def make_paged_kv_pool(
     gets one more layer of the same pages, index ``n_layers``, under the same
     block tables: the module's block's own cache (models/mtp.py).
 
-    A hybrid stack (``cfg.layer_group_size``) gives pages to its attention
-    layers only; each KDA layer keeps {'state_pool': (state_slots + 1, H, K, V)
-    float32, 'conv_pool': (state_slots + 1, kernel - 1, 3 H K)}: a slot a batch
+    A hybrid stack (``cfg.hybrid``) gives pages to its attention layers only,
+    per-head or latent; each recurrent layer keeps {'state_pool': (state_slots
+    + 1, ...) float32, 'conv_pool': (state_slots + 1, kernel - 1, channels)},
+    the shapes its mixer names (``recurrent.state_shapes``): a slot a batch
     row (the engine's ``max_batch``), a row's slot its index, and the last slot
     the scratch that a prefill's pad rows write (as block 0 is for pages).
 
@@ -1868,12 +1883,12 @@ def make_paged_kv_pool(
             )
         dtype = jnp.dtype(dtype or cfg.compute_dtype)
         fields = {"k_pool": (shape, dtype), "v_pool": (shape, dtype)}
-    kda_fields = None
-    if cfg.layer_group_size:
+    state_fields = None
+    if cfg.hybrid:
         if state_slots < 1:
             raise ValueError("a hybrid stack's state pools need state_slots (the batch rows)")
-        kda_fields = {
-            name + "_pool": spec for name, spec in kda.state_shapes(cfg, state_slots + 1).items()
+        state_fields = {
+            name + "_pool": spec for name, spec in recurrent.state_shapes(cfg, state_slots + 1).items()
         }
     # Per-layer pools update in place on the serving window's token-scan
     # carry (see make_kv_cache).
@@ -1889,8 +1904,8 @@ def make_paged_kv_pool(
         }
     elif window_blocks:
         raise ValueError("window_blocks is for a stack of window and full attention layers")
-    pools = _unstack_fields(cfg, fields, kda_fields, window_fields)
-    if kda_fields:
+    pools = _unstack_fields(cfg, fields, state_fields, window_fields)
+    if state_fields:
         # where generation.paged.prefill_into_pool puts a prompt it is given no slot for
         pools["state_cursor"] = jnp.zeros((), jnp.int32)
     return pools

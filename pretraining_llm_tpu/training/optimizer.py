@@ -36,6 +36,8 @@ _DECAY_LEAVES = frozenset(
      "wq_a", "wq_b", "wkv_a", "wkv_b", "wgate",
      # KDA's decay, beta and output-gate projections (models/kda.py)
      "wf", "wbeta", "wg",
+     # Mamba-2's input and output projections (models/mamba.py)
+     "w_in", "w_out",
      # the multi-token-prediction module's (2D, D) projection (models/mtp.py)
      "eh_proj"}
 )
@@ -52,7 +54,10 @@ _NO_DECAY_LEAVES = frozenset(
      "router_bias", "phi", "b", "alpha",
      # KDA's convolution taps, decay rate and decay bias (models/kda.py): a few
      # values a channel that set time scales, not a projection
-     "conv", "A_log", "dt_bias"}
+     "conv", "A_log", "dt_bias",
+     # Mamba-2's: the same three, the convolution's bias and the skip gain D a
+     # head (its gated norm's weight is a "scale")
+     "conv_bias", "D"}
 )
 
 

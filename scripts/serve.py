@@ -55,7 +55,13 @@ def main() -> None:
                         "(max_batch x (window / block_size + 2) + 1) and gives "
                         "pages back behind the window; such a model is served "
                         "without --prefix_cache, --kv_checksum, --quantize "
-                        "int8-kv, --spec_k and --prefill_chunk_tokens")
+                        "int8-kv, --spec_k and --prefill_chunk_tokens. A model "
+                        "with recurrent layers (model.layer_mixers: Mamba-2 or "
+                        "KDA layers beside attention layers) gives these pages "
+                        "to its attention layers alone and keeps a state slot "
+                        "a row (--max_batch of them) in every recurrent layer; "
+                        "such a state-slot model is served without "
+                        "--prefix_cache, --kv_checksum, --quantize and --spec_k")
     parser.add_argument("--block_size", type=int, default=64,
                         help="tokens per pool block (multiple of 8)")
     parser.add_argument("--steps_per_sched", type=int, default=8,

@@ -167,6 +167,7 @@ _PERF_INTENT = {
     "joyai-mini":      ("naive",        "none",           "chunked"),
     # the same for the Trinity mechanisms (window and full layers, gated QK-normed GQA, four norms)
     "trinity-toy":     ("naive",        "none",           "chunked"),
+    "granite-toy":     ("naive",        "none",           "chunked"),
 }
 
 

@@ -97,6 +97,9 @@ def test_decay_mask_covers_every_leaf_of_every_preset():
                if model.layer_group_size else dict(n_experts_held=min(model.n_experts_held, 2))),
             # a stack of window and full attention layers keeps one layer of each kind
             **(dict(attn_kinds=("window", "full")) if model.attn_kinds else {}),
+            # a stack that names its mixers keeps one layer of each
+            **(dict(layer_mixers=("mamba", "attn"), mamba_heads=2, mamba_head_dim=8, mamba_d_state=4)
+               if model.layer_mixers else {}),
         )
         params = transformer.init_params(tiny, jax.random.key(0))
         mask = opt.decay_mask(params)

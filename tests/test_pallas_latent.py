@@ -359,7 +359,7 @@ def test_expert_kernel_compiles_for_the_chip_at_the_cells_size(one_chip, stack, 
 
 
 def test_kda_step_compiles_for_the_chip_over_the_cells_pools_as_they_lie(one_chip, monkeypatch):
-    """``kda.mixer_block`` of a decode step at ``serve_ling_decode_4k``'s size
+    """``recurrent.mixer_block`` of a KDA decode step at ``serve_ling_decode_4k``'s size
     (128 rows over 129 state slots of 32 heads x 128 x 128 float32, d_model
     2560), the pools donated as the decode programs donate them: Mosaic takes
     ``ops/pallas_kda.py`` with a row's 2 MB of state a block each way, the
@@ -372,7 +372,7 @@ def test_kda_step_compiles_for_the_chip_over_the_cells_pools_as_they_lie(one_chi
     from jax.experimental.compilation_cache import compilation_cache
 
     from pretraining_llm_tpu.config import get_preset
-    from pretraining_llm_tpu.models import kda, layers, transformer
+    from pretraining_llm_tpu.models import kda, layers, recurrent, transformer
     from pretraining_llm_tpu.ops import pallas_kda
 
     rows, heads, n, d = 128, 32, 128, 2560
@@ -389,7 +389,7 @@ def test_kda_step_compiles_for_the_chip_over_the_cells_pools_as_they_lie(one_chi
     assert kda.step_form(pools["state_pool"]) == "kernel"
 
     def fn(blk, pools, x, tables, seq_lens):
-        return kda.mixer_block(blk, x, cfg, pools, paged=transformer.PagedInfo(tables, seq_lens))
+        return recurrent.mixer_block("kda", blk, x, cfg, pools, paged=transformer.PagedInfo(tables, seq_lens))
 
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)  # unreadable without a chip

@@ -647,3 +647,63 @@ def test_two_lifetimes_say_so_on_spans_counters_and_pool_info(monkeypatch):
     assert info["full_pool_bytes"] == 24 * per_block and info["window_pool_bytes"] == 4 * 9 * per_block
     assert info["pool_bytes"] == info["full_pool_bytes"] + info["window_pool_bytes"]
     assert info["bytes_per_block"] == per_block
+
+
+# A state-space hybrid (Mamba-2 layers around one position-free attention layer): the mixer's
+# parts under `ssm.*` inside `blk.*` as `kda.*` are, the attention layer under the scopes
+# per-head attention has, the slots on the commit span and in pool_info by kind of layer.
+SSM_CFG = dataclasses.replace(get_preset("granite-toy").model, compute_dtype="float32")
+SSM_SCOPES = {
+    "decode": ("ssm.proj", "ssm.conv", "ssm.step", "ssm.norm", "ssm.out", "attn.qkv", "attn.kv_write", "attn.core",
+               "attn.out", "moe.router", "moe.experts", "moe.shared", "blk.norm", "final_norm", "lm_head"),
+    "prefill": ("ssm.proj", "ssm.conv", "ssm.chunk", "ssm.norm", "ssm.out", "attn.qkv", "attn.kv_write", "attn.core",
+                "moe.experts", "sample"),
+}
+
+
+@pytest.fixture(scope="module")
+def ssm_paths():
+    p = transformer.init_params(SSM_CFG, jax.random.key(0))
+    pools = lambda: transformer.make_paged_kv_pool(SSM_CFG, 16, 8, state_slots=2)
+    tables = jnp.asarray(np.arange(1, 9).reshape(2, 4), jnp.int32)
+    lowered = {
+        "decode": paged.paged_decode_steps.lower(
+            p, pools(), jnp.asarray([3, 5], jnp.int32), tables, jnp.asarray([4, 9], jnp.int32),
+            jax.random.key(1), SSM_CFG, n_steps=2),
+        "prefill": paged._prefill_scatter_sample.lower(
+            p, pools(), jnp.zeros((2, 16), jnp.int32), jnp.asarray([16, 11], jnp.int32),
+            jnp.asarray([[1, 2], [3, 4]], jnp.int32), jax.random.key(2), SSM_CFG, 16, 2,
+            slots=jnp.asarray([0, 1], jnp.int32)),
+    }
+    return {k: set(re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True))) for k, low in lowered.items()}
+
+
+@pytest.mark.parametrize("program,scope", [(p, s) for p, ss in SSM_SCOPES.items() for s in ss])
+def test_ssm_scope_is_in_the_lowered_program(ssm_paths, program, scope):
+    words = [re.split(r"[/()]", p) for p in ssm_paths[program]]
+    assert [w for w in words if scope in w]
+    # the one form a program runs: the recurrence in the decode step, the chunked form in a prefill
+    other = {"decode": "ssm.chunk", "prefill": "ssm.step"}[program]
+    assert not [w for w in words if other in w]
+    assert not [w for w in words if "attn.rope" in w or "kda.step" in w]  # no position of any kind
+
+
+def test_state_slots_say_so_on_the_commit_span_and_in_pool_info(monkeypatch):
+    p = transformer.init_params(SSM_CFG, jax.random.key(0))
+    rec = spans.SpanRecorder()
+    monkeypatch.setattr(spans, "_default", rec)
+    eng = ServingEngine(p, SSM_CFG, max_batch=2, n_blocks=24, block_size=8, max_seq=64)
+    eng.submit(list(range(1, 20)), 12)
+    eng.submit(list(range(3, 9)), 12)
+    eng.run()
+    events, _ = rec.drain()
+    commits = [meta for name, *_, meta in events if name == "serving.commit"]
+    assert commits and max(m["state_slots"] for m in commits) == 2 == eng.stats["state_slots_peak"]
+    assert all({"moe_steps", "moe_routed", "moe_routed_here", "moe_experts"} <= set(m) for m in commits)
+    assert commits[0]["moe_experts"] == 4 and commits[0]["moe_layers"] == 5
+    info = eng.pool_info()
+    assert (info["state_mixer"], info["state_layers"], info["page_layers"]) == ("mamba", 4, 1)
+    slot = 4 * (4 * 16 * 16) * 4 + 4 * 3 * (4 * 16 + 2 * 16) * 4  # float32 toy: a state and a 3-row tail a layer
+    assert info["bytes_per_slot"] == slot and info["state_bytes"] == 3 * slot
+    assert info["pool_bytes"] == 24 * 8 * 2 * SSM_CFG.kv_heads * SSM_CFG.head_dim * 4  # one layer's pages
+    assert info["decode_state"] == "jnp" and info["decode_experts"] == "grouped"

@@ -509,7 +509,7 @@ def test_dense_programs_trace_as_the_parents(name, prog):
     elif prog == "forward":
         got = _fingerprint(lambda p, x: tr.forward(p, x, cfg)[0], p, toks)
     else:
-        slots = 2 if cfg.layer_group_size else 0  # a state-slot model: a slot a row beside the pages
+        slots = 2 if cfg.hybrid else 0  # a state-slot model: a slot a row beside the pages
         pools = jax.eval_shape(lambda: tr.make_paged_kv_pool(cfg, 16, 8, state_slots=slots))
         if prog == "decode":
             got = _fingerprint(lambda *a: paged.paged_decode_steps(*a, cfg=cfg, n_steps=1),
