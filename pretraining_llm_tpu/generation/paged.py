@@ -39,6 +39,7 @@ from pretraining_llm_tpu.generation.sampling import (
 )
 from pretraining_llm_tpu.models import mtp, transformer
 from pretraining_llm_tpu.models.transformer import PagedInfo
+from pretraining_llm_tpu.ops import pallas_moe
 
 # Pool-key names <- their contiguous-cache counterparts (prefill writes a
 # dense per-request cache, then scatters its pages into the pools).
@@ -948,17 +949,28 @@ def _mtp_verify(params, pools, seq_tokens, block_tables, seq_lens, cfg):
     return out[0], out[1], out[2]["final_hidden"], out[3] if cfg.moe_dropless else None
 
 
-def _round_counters(cfg: ModelConfig, counts: Any, m_counts: Any) -> Any:
+def _routing_counters(cfg: ModelConfig, counts: jax.Array, pairs: int) -> Any:
+    """The routing counters of ``counts`` (steps, expert layers, E) tokens to
+    each held expert, every step ``pairs`` sorted (token, choice) rows a layer:
+    tokens an expert, experts touched a layer and the visits those groups take
+    in the expert kernel (``pallas_moe.group_visits`` at the width the layer
+    gives these rows: weight reads, equal to the touched where every group fits
+    one visit), each summed over the steps."""
+    visits, _, _ = pallas_moe.group_visits(counts, pallas_moe.windows(pairs, cfg.n_experts))
+    return {
+        "expert_tokens": jnp.sum(counts, axis=0),
+        "experts_touched": jnp.sum((counts > 0).astype(jnp.int32), axis=(0, 2)),
+        "expert_visits": jnp.sum(visits, axis=(0, 2)),
+    }
+
+
+def _round_counters(cfg: ModelConfig, counts: Any, m_counts: Any, pairs: int) -> Any:
     """A round's routing counters, the stack's expert layers and then the
     module's block, as ``paged_decode_steps`` gives a window's; None for a
     model without dropless experts."""
     if not cfg.moe_dropless:
         return None
-    counts = jnp.concatenate([counts, m_counts[None]], axis=0)
-    return {
-        "expert_tokens": counts,
-        "experts_touched": jnp.sum((counts > 0).astype(jnp.int32), axis=-1),
-    }
+    return _routing_counters(cfg, jnp.concatenate([counts, m_counts[None]], axis=0)[None], pairs)
 
 
 @functools.partial(
@@ -1019,7 +1031,7 @@ def paged_mtp_round(
             with jax.named_scope("mtp.head"):
                 m_logits = transformer.lm_head(params, m_hidden[jnp.arange(b), n_emit - 1][:, None], cfg)
                 nxt = jnp.argmax(m_logits[:, 0], axis=-1).astype(jnp.int32)
-        return emit, n_emit, nxt, _round_counters(cfg, counts, m_counts), pools
+        return emit, n_emit, nxt, _round_counters(cfg, counts, m_counts, 2 * b * cfg.experts_per_token), pools
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "mesh"), donate_argnums=(1,))
@@ -1088,8 +1100,9 @@ def paged_decode_steps(
     dropless expert model the first value is a pair: the tokens, and the
     window's routing counters ``{"expert_tokens": (expert layers, E) tokens
     routed to each expert over the window, "experts_touched": (expert layers,)
-    experts that got a token, summed over the steps}`` — read back with the
-    tokens, in the same transfer.
+    experts that got a token, "expert_visits": (expert layers,) visits of the
+    expert kernel those groups take, both summed over the steps}`` — read back
+    with the tokens, in the same transfer.
     """
     counted = cfg.moe_dropless
 
@@ -1114,11 +1127,7 @@ def paged_decode_steps(
     )
     if counted:
         toks, counts = toks  # counts: (n_steps, expert layers, E)
-        moe = {
-            "expert_tokens": jnp.sum(counts, axis=0),
-            "experts_touched": jnp.sum((counts > 0).astype(jnp.int32), axis=(0, 2)),
-        }
-        return (toks.T, moe), pools
+        return (toks.T, _routing_counters(cfg, counts, tokens.shape[0] * cfg.experts_per_token)), pools
     return toks.T, pools  # (B, n_steps)
 
 

@@ -1177,29 +1177,34 @@ class ServingEngine:
         """Add a reaped window's routing counters into the stats:
         ``moe_expert_tokens`` (expert layers, E) tokens routed to each expert,
         ``moe_experts_touched`` (expert layers,) experts that got a token,
-        summed over steps, and ``moe_steps``. Idle rows route too (their
+        summed over steps, ``moe_visits`` the visits their groups take in the
+        expert kernel (weight reads: equal to the touched where every group
+        fits one visit) and ``moe_steps``. Idle rows route too (their
         tokens are discarded, their experts are read all the same). Returns
         the window's own totals, the ``serving.commit`` span's metadata:
         steps, expert layers, experts a layer (those held), experts touched,
-        pairs routed (every row's choices, wherever the expert lives), pairs
+        weight reads, pairs routed (every row's choices, wherever the expert lives), pairs
         that met an expert held here, and the busiest expert's pairs summed
         over layers. A self-drafting round is one step of ``queries`` tokens a
         row, the module's block one more expert layer (its experts touched
         also by themselves, ``mtp_touched``)."""
         tokens = np.asarray(counters["expert_tokens"], np.int64)
         touched = np.asarray(counters["experts_touched"], np.int64)
+        visits = int(np.asarray(counters["expert_visits"], np.int64).sum())
         st = self.stats
         if "moe_steps" not in st:
             st["moe_expert_tokens"] = np.zeros_like(tokens)
             st["moe_experts_touched"] = np.zeros_like(touched)
+            st["moe_visits"] = 0
             st["moe_steps"] = 0
         st["moe_expert_tokens"] += tokens
         st["moe_experts_touched"] += touched
+        st["moe_visits"] += visits
         st["moe_steps"] += n_steps
         routed = n_steps * queries * self.max_batch * self.cfg.experts_per_token * tokens.shape[0]
         meta = dict(
             moe_steps=n_steps, moe_layers=tokens.shape[0], moe_experts=tokens.shape[1],
-            moe_touched=int(touched.sum()), moe_routed=routed, moe_routed_here=int(tokens.sum()),
+            moe_touched=int(touched.sum()), moe_visits=visits, moe_routed=routed, moe_routed_here=int(tokens.sum()),
             moe_busiest=int(tokens.max(axis=-1).sum()),
         )
         if self.self_draft:
@@ -2654,9 +2659,9 @@ class ServingEngine:
             st = self.stats
             routing = ""
             if "moe_steps" in st:
-                routing = "; experts (%s) took %d pairs, %d touched over %d steps" % (
+                routing = "; experts (%s) took %d pairs, %d touched, %d weight reads over %d steps" % (
                     self.decode_experts, st["moe_expert_tokens"].sum(),
-                    st["moe_experts_touched"].sum(), st["moe_steps"],
+                    st["moe_experts_touched"].sum(), st["moe_visits"], st["moe_steps"],
                 )
             if self.state_slots:
                 routing += "; state slots stepped as %s" % self.decode_state

@@ -33,6 +33,7 @@ output there never depends on what shares its batch or its padded bucket.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Tuple
 
 import jax
@@ -40,6 +41,7 @@ import jax.numpy as jnp
 
 from pretraining_llm_tpu.config import ModelConfig
 from pretraining_llm_tpu.models.layers import weight as _weight
+from pretraining_llm_tpu.ops import pallas_moe
 from pretraining_llm_tpu.parallel.sharding import constrain, current_mesh
 
 Params = Dict[str, Any]
@@ -270,11 +272,31 @@ def route_dropless(mlp: Params, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Arr
 
 
 # Sorted rows an expert (S * K / n_experts, what even routing gives each) up to
-# which the expert FFN runs as ops/pallas_moe.py's kernel. Timed on the v5e
-# against the ragged_dot pair at both serving cells' expert shapes (PERF.md
-# section 6, PR 32): the kernel wins 2.5x at 8, 1.7x at 16, the two cross
-# between 29 (three quarters of the sorted rows held elsewhere) and 52.
-KERNEL_ROWS_PER_EXPERT = 16
+# which the expert FFN runs as ops/pallas_moe.py's kernel, a visit as wide as
+# ``pallas_moe.windows`` makes it (two row tiles up to 16 rows an expert, past
+# that the span that holds a group of twice the mean in one read of its
+# weights). Timed on the v5e against the ragged_dot pair at three cells' expert
+# shapes, routing skewed so that the busiest expert gets about twice the mean
+# (``scripts/chip_kernels.py --time-moe``; PERF.md section 6, PR 44). Ms a layer,
+# kernel / pair:
+#
+#   rows an    Ling: 128 of 512      Xing: 64 of 64       Granite: 18 of 72
+#   expert     held, 2560 x 768      held, 3584 x 1024    held, 4096 x 768
+#       2        1.70 / 4.21           1.81 / 2.32          0.41 / 0.50
+#       8        2.48 / 5.12           2.14 / 4.69          0.58 / 0.68
+#      16        3.23 / 5.25           2.40 / 4.75          0.70 / 0.76
+#      24        3.74 / 5.40           2.17 / 4.82          0.59 / 0.81
+#      32        4.29 / 5.49           2.41 / 4.82          0.60 / 0.93
+#      48        4.77 / 5.67           2.40 / 5.05          0.60 / 0.91
+#      64        5.69 / 6.08           2.45 / 5.20          0.62 / 1.31
+#     128        9.37 / 6.92           3.42 / 5.79          (not run) / 1.45
+#
+# The kernel leads at all three through 64 rows an expert and has lost at Ling's
+# shape by 128 (its row gather moves every sorted row, three quarters of them
+# other chips'). The bound is the last timed figure under 64, where the cells'
+# prefills begin (Trinity's and JoyAI's stand at 64 exactly) and where the lead
+# is 16% at its thinnest; from 64 on ragged_dot stays (ROADMAP S11).
+KERNEL_ROWS_PER_EXPERT = 48
 
 
 def experts_form(
@@ -282,13 +304,17 @@ def experts_form(
 ) -> str:
     """The form a dropless layer's expert FFN takes for ``rows`` sorted (token,
     choice) pairs over ``cfg.n_experts``: ``"kernel"`` (``ops/pallas_moe.py``: each
-    touched expert's weights streamed once, for a handful of rows an expert) or
+    touched expert's weights streamed once, its rows held in one visit) or
     ``"grouped"`` (two ``jax.lax.ragged_dot``s). Read from the input as
-    ``mla.decode_form`` reads a latent pool's: a decode step over unquantized
-    bfloat16 experts of whole 128-lane tiles that no mesh shards takes the
-    kernel where Mosaic compiles; a prefill, int8 or float32 experts, a mesh
-    and every other backend keep the grouped form. The engine reports the
-    decode step's form in ``pool_info()``."""
+    ``mla.decode_form`` reads a latent pool's. The rule measures rows an expert,
+    ``rows / cfg.n_experts``, whatever program brings them: up to
+    ``KERNEL_ROWS_PER_EXPERT`` (every cell's decode step, and an admission short
+    enough to stand under it, as Ling's of one or two 1,024-token prompts) over
+    unquantized bfloat16 experts of whole 128-lane tiles that no mesh shards it
+    takes the kernel where Mosaic compiles; more rows an expert (the cells'
+    prefills, from 64 up), int8 or float32 experts, a mesh and every other
+    backend keep the grouped form. The engine reports the decode step's form in
+    ``pool_info()``."""
     w1 = experts["w1"]
     if (
         rows <= KERNEL_ROWS_PER_EXPERT * cfg.n_experts
@@ -329,22 +355,23 @@ def experts_grouped(
     return _grouped_pair(xs, w1, w2, sizes, limit)
 
 
-@jax.custom_vjp
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def experts_kernel(
-    xs: jax.Array, w1: jax.Array, w2: jax.Array, sizes: jax.Array, layer: Any = None, limit: Any = None
+    xs: jax.Array, w1: jax.Array, w2: jax.Array, sizes: jax.Array, layer: Any = None, limit: Any = None,
+    windows: int = 2,
 ) -> jax.Array:
     """``experts_grouped`` through ``ops/pallas_moe.py`` (rows past the last
-    group are promised by neither); its VJP is the grouped form's."""
-    from pretraining_llm_tpu.ops.pallas_moe import expert_ffn
-
-    return expert_ffn(xs, w1, w2, sizes, layer, limit)
-
-
-def _experts_kernel_fwd(xs, w1, w2, sizes, layer, limit):
-    return experts_kernel(xs, w1, w2, sizes, layer, limit), (xs, w1, w2, sizes, layer, limit)
+    group are promised by neither), a visit ``windows`` row tiles wide
+    (``pallas_moe.windows`` of the rows an expert the rule measured); its VJP
+    is the grouped form's."""
+    return pallas_moe.expert_ffn(xs, w1, w2, sizes, layer, limit, w=windows)
 
 
-def _experts_kernel_bwd(res, g):
+def _experts_kernel_fwd(xs, w1, w2, sizes, layer, limit, windows):
+    return experts_kernel(xs, w1, w2, sizes, layer, limit, windows), (xs, w1, w2, sizes, layer, limit)
+
+
+def _experts_kernel_bwd(windows, res, g):
     xs, w1, w2, sizes, layer, limit = res
     _, vjp = jax.vjp(lambda x, a, b, lim: experts_grouped(x, a, b, sizes, layer, lim), xs, w1, w2, limit)
     d_xs, d_w1, d_w2, d_limit = vjp(g)
@@ -364,7 +391,8 @@ def moe_mlp_dropless(
     their rows in the form the input picks (``experts_form``: one grouped
     matmul a projection, ``jax.lax.ragged_dot``, for a prefill's hundreds of
     rows an expert; one Pallas kernel that streams each touched expert's
-    weights once for a decode step's handful), the result is un-sorted and the
+    weights once for a decode step's handful to few dozen, its visit as wide as
+    ``pallas_moe.windows`` of the same figure), the result is un-sorted and the
     K weighted parts of a token summed. A token's output is a function of that
     token alone.
 
@@ -404,7 +432,7 @@ def moe_mlp_dropless(
             w1, w2, sizes = _in_stack(w1, w2, sizes, layer)
     with jax.named_scope("moe.experts"):
         if form == "kernel":
-            ys = experts_kernel(xs, w1, w2, sizes, layer, limit)
+            ys = experts_kernel(xs, w1, w2, sizes, layer, limit, pallas_moe.windows(s * k, cfg.n_experts))
         else:
             ys = _grouped_pair(xs, w1, w2, sizes, limit)
     with jax.named_scope("moe.combine"):

@@ -1,20 +1,24 @@
 """Pallas TPU expert FFN for a handful of rows an expert: weights read once.
 
-A decode step hands an expert layer two rows an expert. XLA's TPU expansion
-of ``jax.lax.ragged_dot`` is built for prefill (row tiles of hundreds) and
-pays a fixed ~25 us a touched group whatever the group's bytes (PERF.md
-section 6, PR 32). This kernel is the other end of that trade: it is bound by
-the bytes of the experts some row chose, and does the two grouped matmuls and
-the SwiGLU between them in one pass over those bytes.
+A decode step hands an expert layer two to a few dozen rows an expert. XLA's
+TPU expansion of ``jax.lax.ragged_dot`` is built for prefill (row tiles of
+hundreds) and pays a fixed ~25 us a touched group whatever the group's bytes
+(PERF.md section 6, PR 32). This kernel is the other end of that trade: it is
+bound by the bytes of the experts some row chose, and does the two grouped
+matmuls and the SwiGLU between them in one pass over those bytes.
 
   - The rows arrive sorted by expert (``models/moe.py::moe_mlp_dropless``),
     ``sizes[e]`` of them for held expert ``e``; rows past the last group
     belong to experts held elsewhere.
-  - A **visit** is one expert and ``2 * ROW_TILE`` consecutive sorted rows: the
-    two ``ROW_TILE``-aligned windows that hold the expert's first rows. A group
-    of up to ``ROW_TILE + 1`` rows is one visit wherever it starts; a larger
-    one takes a visit every ``2 * ROW_TILE`` rows, its weights read again for
-    each (the regime of a prefill, which keeps ``ragged_dot``).
+  - A **visit** is one expert and a span of ``w * ROW_TILE`` consecutive
+    sorted rows: the ``w`` ``ROW_TILE``-aligned windows from the one that holds
+    the expert's first row. A group of up to ``(w - 1) * ROW_TILE + 1`` rows is
+    one visit wherever it starts; a larger one takes a visit a span, its
+    weights read again for each. ``w`` is static and read from the call's rows
+    an expert (``windows``): two under a decode step's handful, and past
+    ``ROW_TILE`` rows an expert as many as hold a group of twice the mean, so
+    that a skewed step still reads each touched expert once (PERF.md section 6,
+    PR 44). A prefill's hundreds of rows an expert keep ``ragged_dot``.
   - Grid ``(visits, F tiles)``. The list of visits (expert, first window) is
     scalar prefetch, built from ``sizes`` outside; the weight stacks stay whole
     in HBM and a step's tiles are addressed ``(layer * E + expert, ...)`` by
@@ -28,7 +32,7 @@ the SwiGLU between them in one pass over those bytes.
     expert no row chose, another layer's expert and the rows of experts held
     elsewhere cost no grid step, no copy and no MXU pass.
   - Neighbouring groups share a sublane tile of the sorted rows, so a visit
-    cannot write its rows where they lie. It computes its two whole windows
+    cannot write its rows where they lie. It computes its whole windows
     (the neighbours' rows through its own weights are wasted MXU passes, of
     which a byte-bound kernel has plenty) into an output block of its own, and
     one row gather outside brings the sorted order back.
@@ -50,48 +54,79 @@ from jax.experimental.pallas import tpu as pltpu
 
 # Rows of a window of the sorted rows: one packed sublane tile of bfloat16.
 ROW_TILE = 16
-# The pipeline's two buffers of (gate, up, down) tiles stay under this, inside
-# Mosaic's default 16 MB of scoped VMEM with room for rows and accumulator.
+# Mosaic's default scoped VMEM is 16 MB. The pipeline's two buffers of (gate, up,
+# down) tiles stay under the first figure; with what a visit's rows take (two
+# buffers of its windows and of its output block, the row scratch, the float32
+# accumulator) they stay under the second, which leaves the compiler's own
+# temporaries their room. At two windows and the cells' widths the first binds;
+# a wide visit at D 4,096 takes narrower tiles, which time the same (PERF.md
+# section 6, PR 44).
 WEIGHT_TILE_BYTES = 12 << 20
+VMEM_BYTES = 14 << 20
 
 
-def f_tile(d: int, f: int, itemsize: int) -> int:
-    """Columns of F a grid step takes: the largest whole number of 128-lane
-    tiles that divides F and keeps two buffers of the three weight tiles under
-    ``WEIGHT_TILE_BYTES`` (at least one lane tile)."""
+def f_tile(d: int, f: int, itemsize: int, w: int = 2) -> int:
+    """Columns of F a grid step takes under a visit of ``w`` windows: the
+    largest whole number of 128-lane tiles that divides F and keeps two buffers
+    of the three weight tiles under ``WEIGHT_TILE_BYTES``, and under
+    ``VMEM_BYTES`` with the visit's rows (at least one lane tile)."""
+    # a row of a visit: two buffers in, two out, the scratch, the float32 accumulator
+    rows = w * ROW_TILE * d * (5 * itemsize + 4)
     fits = [
         tf for tf in range(128, f + 1, 128)
-        if f % tf == 0 and 2 * 3 * d * tf * itemsize <= WEIGHT_TILE_BYTES
+        if f % tf == 0 and 2 * 3 * d * tf * itemsize <= min(WEIGHT_TILE_BYTES, VMEM_BYTES - rows)
     ]
     return max(fits, default=128)
 
 
-def n_visits(n_rows: int, held: int) -> int:
-    """The most visits ``n_rows`` sorted rows (a multiple of ROW_TILE) over
-    ``held`` experts can take: one a touched expert, and one more for every
-    ROW_TILE rows a group holds (a group of n rows takes at most 1 + n //
-    ROW_TILE)."""
-    return min(held, n_rows) + n_rows // ROW_TILE
+def windows(n_rows: int, n_experts: int) -> int:
+    """ROW_TILE windows a visit holds when ``n_rows`` sorted rows are routed
+    over ``n_experts`` (all the router scores, held here or not): two up to
+    ROW_TILE rows an expert; past that the fewest that hold a group of twice
+    the mean in one visit wherever it starts (a group may start on a window's
+    last row)."""
+    if n_rows <= ROW_TILE * n_experts:
+        return 2
+    twice = -(-2 * n_rows // n_experts)
+    return -(-(twice + ROW_TILE - 1) // ROW_TILE)
 
 
-def plan(sizes: jax.Array, n_rows: int):
+def n_visits(n_rows: int, held: int, w: int = 2) -> int:
+    """The most visits of ``w`` windows ``n_rows`` sorted rows (a multiple of
+    ROW_TILE) over ``held`` experts can take: one a touched expert, and one
+    more for every ``(w - 1) * ROW_TILE`` rows a group holds (a group of n rows
+    takes at most 1 + n // ((w - 1) * ROW_TILE))."""
+    return min(held, n_rows) + n_rows // ((w - 1) * ROW_TILE)
+
+
+def group_visits(sizes: jax.Array, w: int = 2):
+    """``sizes`` (..., E) rows a held expert, in sorted order along the last
+    axis -> (visits of ``w`` windows each group takes, the window-aligned row
+    each group starts in, the row each ends before), all (..., E) int32. What
+    ``plan`` lays out and what the engine counts as weight reads."""
+    span = w * ROW_TILE
+    sizes = sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes, axis=-1)
+    base = (ends - sizes) // ROW_TILE * ROW_TILE  # the window a group starts in
+    visits = jnp.where(sizes > 0, (ends - base + span - 1) // span, 0)
+    return visits, base, ends
+
+
+def plan(sizes: jax.Array, n_rows: int, w: int = 2):
     """``sizes`` (E,) rows a held expert -> the visits and the way back.
 
     (expert (V,), first window (V,), live visits (1,), position of each sorted
     row in the visits' output (n_rows,)); V is ``n_visits``, what lies past the
     live visits is never read."""
     held = sizes.shape[0]
-    span = 2 * ROW_TILE
-    sizes = sizes.astype(jnp.int32)
-    ends = jnp.cumsum(sizes)
-    base = (ends - sizes) // ROW_TILE * ROW_TILE  # the window a group starts in
-    visits = jnp.where(sizes > 0, (ends - base + span - 1) // span, 0)
+    span = w * ROW_TILE
+    visits, base, ends = group_visits(sizes, w)
     v_ends = jnp.cumsum(visits)
     first = v_ends - visits
     live = v_ends[-1]
-    v = jnp.arange(n_visits(n_rows, held), dtype=jnp.int32)
+    v = jnp.arange(n_visits(n_rows, held, w), dtype=jnp.int32)
     expert = jnp.minimum(jnp.searchsorted(v_ends, v, side="right"), held - 1).astype(jnp.int32)
-    window = jnp.clip(base[expert] // ROW_TILE + 2 * (v - first[expert]), 0, n_rows // ROW_TILE - 1)
+    window = jnp.clip(base[expert] // ROW_TILE + w * (v - first[expert]), 0, n_rows // ROW_TILE - 1)
     rows = jnp.arange(n_rows, dtype=jnp.int32)
     of_row = jnp.minimum(jnp.searchsorted(ends, rows, side="right"), held - 1)
     rel = rows - base[of_row]
@@ -110,7 +145,7 @@ def _moe_kernel(
 ):
     if clamp:
         lim_ref, *refs = refs  # (1,) float32 in SMEM
-    xa_ref, xb_ref, wg_ref, wu_ref, wd_ref, o_ref, x_scr, acc_scr = refs
+    *x_refs, wg_ref, wu_ref, wd_ref, o_ref, x_scr, acc_scr = refs  # x_refs: the visit's windows
     v, j = pl.program_id(0), pl.program_id(1)
     cdt = x_scr.dtype
 
@@ -118,8 +153,8 @@ def _moe_kernel(
     def _visit():
         @pl.when(j == 0)
         def _rows():
-            x_scr[:ROW_TILE] = xa_ref[...]
-            x_scr[ROW_TILE:] = xb_ref[...]
+            for i, x_ref in enumerate(x_refs):
+                x_scr[i * ROW_TILE : (i + 1) * ROW_TILE] = x_ref[...]
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
         x = x_scr[...]
@@ -138,17 +173,24 @@ def _moe_kernel(
             o_ref[...] = acc_scr[...].astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("tf", "interpret"))
-def _moe_call(xs, w1, w2, sizes, layer, limit, tf, interpret):
+@functools.partial(jax.jit, static_argnames=("tf", "w", "interpret"))
+def _moe_call(xs, w1, w2, sizes, layer, limit, tf, w, interpret):
     n, d = xs.shape
     held, f = w1.shape[-3], w2.shape[-2]
     w1 = w1.reshape(-1, d, 2 * f)  # a stack's (L, E) as L * E groups: a bitcast
     w2 = w2.reshape(-1, f, d)
-    nf, span = f // tf, 2 * ROW_TILE
+    nf, span = f // tf, w * ROW_TILE
     n_rows = n + -n % ROW_TILE
     xs = jnp.pad(xs, ((0, n_rows - n), (0, 0)))
-    expert, window, live, position = plan(sizes, n_rows)
+    expert, window, live, position = plan(sizes, n_rows, w)
     n_windows, visits = n_rows // ROW_TILE, expert.shape[0]
+
+    def x_window(i):  # the visit's i-th window of the sorted rows
+        if i == 0:  # plan has clipped it: the index map two-window calls have always had
+            return pl.BlockSpec((ROW_TILE, d), lambda v, j, exp, win, live: (win[v], 0))
+        return pl.BlockSpec(
+            (ROW_TILE, d), lambda v, j, exp, win, live: (jnp.minimum(win[v] + i, n_windows - 1), 0)
+        )
 
     clamp = limit is not None
     lim = ()
@@ -157,11 +199,7 @@ def _moe_call(xs, w1, w2, sizes, layer, limit, tf, interpret):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(jnp.maximum(live[0], 1), nf),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM) for _ in lim] + [
-            pl.BlockSpec((ROW_TILE, d), lambda v, j, exp, win, live: (win[v], 0)),
-            pl.BlockSpec(
-                (ROW_TILE, d), lambda v, j, exp, win, live: (jnp.minimum(win[v] + 1, n_windows - 1), 0)
-            ),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM) for _ in lim] + [x_window(i) for i in range(w)] + [
             pl.BlockSpec((None, d, tf), lambda v, j, exp, win, live: (exp[v], 0, j)),
             pl.BlockSpec((None, d, tf), lambda v, j, exp, win, live: (exp[v], 0, nf + j)),
             pl.BlockSpec((None, tf, d), lambda v, j, exp, win, live: (exp[v], j, 0)),
@@ -175,7 +213,7 @@ def _moe_call(xs, w1, w2, sizes, layer, limit, tf, interpret):
         out_shape=jax.ShapeDtypeStruct((visits * span, d), xs.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(expert + layer * held, window, live, *lim, xs, xs, w1, w1, w2)
+    )(expert + layer * held, window, live, *lim, *[xs] * w, w1, w1, w2)
     return out[position[:n]]
 
 
@@ -187,6 +225,7 @@ def expert_ffn(
     layer: Optional[jax.Array] = None,  # the layer of a stack, a scalar
     limit: Optional[jax.Array] = None,  # SwiGLU clamp, a scalar, 0 = off
     *,
+    w: int = 2,  # ROW_TILE windows a visit holds: ``windows`` of the call's rows an expert
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """(silu(x . gate_e) * (x . up_e)) . down_e for each sorted row x of held
@@ -207,13 +246,14 @@ def expert_ffn(
         or sizes.shape != (held,)
         or stacked != (layer is not None)
         or d % 128 or f % 128
+        or w < 2
     ):
         raise ValueError(
             f"rows {xs.shape} {xs.dtype}, w1 {w1.shape} {w1.dtype}, w2 {w2.shape} {w2.dtype}, sizes "
-            f"{sizes.shape}, layer {layer}: want (N, D), ([L,] E, D, 2F), ([L,] E, F, D), (E,) in one "
-            f"dtype, D and F whole 128-lane tiles, and a layer exactly for a stack"
+            f"{sizes.shape}, layer {layer}, {w} windows a visit: want (N, D), ([L,] E, D, 2F), ([L,] E, F, D), "
+            f"(E,) in one dtype, D and F whole 128-lane tiles, a layer exactly for a stack, two windows or more"
         )
     layer = jnp.zeros((), jnp.int32) if layer is None else jnp.asarray(layer, jnp.int32)
     return _moe_call(
-        xs, w1, w2, sizes, layer, limit, f_tile(d, f, xs.dtype.itemsize), bool(interpret)
+        xs, w1, w2, sizes, layer, limit, f_tile(d, f, xs.dtype.itemsize, int(w)), int(w), bool(interpret)
     )

@@ -1,14 +1,15 @@
 #!/usr/bin/env python
 """Compile and run every Pallas kernel on the TPU against its XLA reference,
 at the sizes serving and training use (attention: Dh 64, 12 heads, bf16; the
-paged decode step also at 32 heads of 128; the expert FFN at the two serving
+paged decode step also at 32 heads of 128; the expert FFN at three serving
 cells' expert shapes; the KDA step over the Ling cell's state pool).
 
 The CPU tests run these kernels in interpret mode at toy sizes; only the
 chip hears Mosaic's refusals (tiling, unaligned slices, VMEM) and only there
 do the compiled numerics exist. One line per case, then one JSON summary
 line; exit 0 iff every case matched. A correctness run; ``--time-moe`` instead
-times the expert kernel against ``ragged_dot`` alone, 2 to 512 rows an expert.
+times the expert kernel against ``ragged_dot`` alone, 2 to 128 rows an expert
+(``--only`` then names a shape).
 
 Usage (on the chip):  python scripts/chip_kernels.py [--only SUBSTR] [--time-moe]
 """
@@ -16,6 +17,7 @@ Usage (on the chip):  python scripts/chip_kernels.py [--only SUBSTR] [--time-moe
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -165,23 +167,36 @@ def ragged_case(g: int, page: int, t: int, splits, amla: bool, int8: bool):
     return {"o": _err(got, want)}, 3e-2
 
 
-# The two serving cells' expert layers: (layers of the stack, experts held, D, F,
-# sorted rows of a decode step, of which routed to experts held here).
-MOE_SHAPES = {"ling": (3, 128, 2560, 768, 1024, 256), "xing": (3, 64, 3584, 1024, 128, 128)}
-MOE_MIXES = ("uniform", "one-takes-all", "tile-edges", "all-elsewhere")
+# The serving cells' expert layers: (layers of the stack, experts held, D, F,
+# sorted rows of a decode step, of which routed to experts held here, experts
+# the router scores). Granite holds 18 of 72: three quarters of the sorted rows
+# belong to experts held elsewhere and sort last.
+MOE_SHAPES = {
+    "ling": (3, 128, 2560, 768, 1024, 256, 512),
+    "xing": (3, 64, 3584, 1024, 128, 128, 64),
+    "granite": (3, 18, 4096, 768, 1280, 304, 72),
+}
+MOE_MIXES = ("uniform", "skewed", "one-takes-all", "tile-edges", "all-elsewhere")
 
 
 def _moe_inputs(shape: str, mix: str, rows_an_expert: int = 0, seed: int = 0):
     """(xs, w1, w2, sizes) at a cell's expert shapes. ``rows_an_expert`` > 0:
-    that many rows an expert on average, all of them routed here."""
+    that many sorted rows an expert the router scores on average, the held
+    experts' share of them routed here and the rest sorted last. ``skewed``:
+    the popularity of an expert runs from 0.4 to 1.6 of the mean, which with
+    the draw's own noise makes the busiest about twice the mean at 17 rows an
+    expert (the Granite cell's ``moe_load_max_over_mean``)."""
     from pretraining_llm_tpu.ops.pallas_moe import ROW_TILE
 
-    n_stack, held, d, f, n, here = MOE_SHAPES[shape]
+    n_stack, held, d, f, n, here, n_experts = MOE_SHAPES[shape]
     if rows_an_expert:
-        n = here = rows_an_expert * held
+        n, here = rows_an_expert * n_experts, rows_an_expert * held
     rng = np.random.default_rng(seed)
     if mix == "uniform":
         sizes = np.bincount(rng.integers(0, held, here), minlength=held)
+    elif mix == "skewed":
+        liked = rng.permutation(np.linspace(0.4, 1.6, held))
+        sizes = np.bincount(rng.choice(held, here, p=liked / liked.sum()), minlength=held)
     elif mix == "one-takes-all":
         sizes = np.zeros(held, np.int64)
         sizes[held // 3] = here
@@ -202,14 +217,17 @@ def _moe_inputs(shape: str, mix: str, rows_an_expert: int = 0, seed: int = 0):
     return xs, w1, w2, jnp.asarray(sizes, jnp.int32)
 
 
-def moe_case(shape: str, mix: str, layer: int, clamp: bool):
+def moe_case(shape: str, mix: str, layer: int, clamp: bool, rows_an_expert: int = 0):
     """``ops/pallas_moe.py`` against the ``ragged_dot`` pair it replaces, over
-    the rows routed here (what lies past them is promised by neither)."""
+    the rows routed here (what lies past them is promised by neither), a visit
+    as wide as ``moe_mlp_dropless`` makes it at these rows an expert."""
     from pretraining_llm_tpu.models import moe
+    from pretraining_llm_tpu.ops import pallas_moe
 
-    xs, w1, w2, sizes = _moe_inputs(shape, mix)
+    xs, w1, w2, sizes = _moe_inputs(shape, mix, rows_an_expert)
     limit = jnp.float32(1.5) if clamp else None
-    got = jax.jit(moe.experts_kernel)(xs, w1, w2, sizes, jnp.int32(layer), limit)
+    w = pallas_moe.windows(xs.shape[0], MOE_SHAPES[shape][-1])
+    got = jax.jit(moe.experts_kernel, static_argnums=6)(xs, w1, w2, sizes, jnp.int32(layer), limit, w)
     want = jax.jit(moe.experts_grouped)(xs, w1, w2, sizes, jnp.int32(layer), limit)
     here = int(sizes.sum())
     if not here:
@@ -218,14 +236,23 @@ def moe_case(shape: str, mix: str, layer: int, clamp: bool):
     return {"o": _err(got[:here], want[:here]) / scale}, 3e-2
 
 
-def time_moe(reps: int = 20):
+# Rows an expert at which ``time_moe`` times the two forms: the decode step's own
+# mix (0), the decode cells' 2 to 8, and the band the rule stands in.
+MOE_TIMED_ROWS = (0, 2, 8, 16, 24, 32, 48, 64, 128)
+
+
+def time_moe(reps: int = 20, only: str = ""):
     """Milliseconds a layer of the kernel and of the ``ragged_dot`` pair alone,
-    at both cells' expert shapes: the decode step's own mix, then 2 to 512 rows
-    an expert (PERF.md section 6, PR 32: where ``moe.KERNEL_ROWS_PER_EXPERT``
-    comes from). Each call runs every layer of the stack in turn."""
+    at the three expert shapes, under even and skewed routing: the decode
+    step's own mix, then 2 to 128 rows an expert (PERF.md section 6, PR 44:
+    where ``moe.KERNEL_ROWS_PER_EXPERT`` comes from). The kernel at a visit of
+    two windows (``kernel_w2_ms``) and at the width ``pallas_moe.windows``
+    gives these rows an expert (``kernel_ms``, ``w``), with the weight reads a
+    touched expert of each. Each call runs every layer of the stack in turn."""
     import time
 
     from pretraining_llm_tpu.models import moe
+    from pretraining_llm_tpu.ops import pallas_moe
 
     def ms_a_layer(form, xs, w1, w2, sizes):
         def every_layer(xs, w1, w2, sizes):
@@ -240,21 +267,32 @@ def time_moe(reps: int = 20):
         out.block_until_ready()
         return (time.perf_counter() - t0) / (reps * w1.shape[0]) * 1e3
 
-    for shape, (_, held, d, f, _, _) in MOE_SHAPES.items():
-        for rows in (0, 2, 8, 32, 64, 128, 512):
-            xs, w1, w2, sizes = _moe_inputs(shape, "uniform", rows)
-            touched = int((sizes > 0).sum())
-            line = {
-                "shape": shape, "rows_an_expert": rows or "decode step", "rows": xs.shape[0],
-                "touched": touched, "touched_mb": round(touched * 3 * d * f * 2 / 1e6, 1),
-            }
-            for name, form in (("kernel_ms", moe.experts_kernel), ("grouped_ms", moe.experts_grouped)):
-                try:
-                    line[name] = round(ms_a_layer(form, xs, w1, w2, sizes), 4)
-                except Exception as e:
-                    line[name] = f"{type(e).__name__}: {str(e).splitlines()[0][:200]}"
-            print(json.dumps(line), flush=True)
-            del xs, w1, w2
+    for shape, (_, held, d, f, _, _, n_experts) in MOE_SHAPES.items():
+        if only not in shape:
+            continue
+        for mix in ("uniform", "skewed"):
+            for rows in MOE_TIMED_ROWS:
+                xs, w1, w2, sizes = _moe_inputs(shape, mix, rows)
+                touched = int((sizes > 0).sum())
+                w = pallas_moe.windows(xs.shape[0], n_experts)
+                reads = lambda w: round(float(pallas_moe.group_visits(sizes, w)[0].sum()) / max(touched, 1), 3)
+                line = {
+                    "shape": shape, "mix": mix, "rows_an_expert": rows or "decode step", "rows": xs.shape[0],
+                    "here": int(sizes.sum()), "touched": touched, "touched_mb": round(touched * 3 * d * f * 2 / 1e6, 1),
+                    "max_over_mean": round(float(sizes.max()) * held / max(int(sizes.sum()), 1), 2),
+                    "w": w, "reads_a_touched": reads(w), "reads_a_touched_w2": reads(2),
+                }
+                forms = [("kernel_ms", functools.partial(moe.experts_kernel, windows=w)),
+                         ("grouped_ms", moe.experts_grouped)]
+                if w != 2:
+                    forms.insert(1, ("kernel_w2_ms", moe.experts_kernel))
+                for name, form in forms:
+                    try:
+                        line[name] = round(ms_a_layer(form, xs, w1, w2, sizes), 4)
+                    except Exception as e:
+                        line[name] = f"{type(e).__name__}: {str(e).splitlines()[0][:200]}"
+                print(json.dumps(line), flush=True)
+                del xs, w1, w2
 
 
 def kda_case(rows: int, heads: int):
@@ -288,6 +326,8 @@ def cases():
             for layer, clamp in ((0, False), (MOE_SHAPES[shape][0] - 1, True)):
                 name = f"moe {shape} {mix} layer{layer}" + (" clamp" if clamp else "")
                 yield name, moe_case, (shape, mix, layer, clamp)
+        for rows in (24, 48):  # past a row tile an expert: the visit widens
+            yield f"moe {shape} skewed rows{rows}", moe_case, (shape, "skewed", 1, False, rows)
     for t in (1024, 2048):  # one block (fused backward) / 2x2 blocks
         for g in (12, 4):
             for seg in (False, True):
@@ -330,7 +370,7 @@ def main() -> int:
               file=sys.stderr)
         return 1
     if args.time_moe:
-        time_moe()
+        time_moe(only=args.only)
         return 0
     results = {}
     for name, fn, fn_args in cases():

@@ -18,6 +18,7 @@ dropped term shows.
 import dataclasses
 import json
 import os
+import re
 import sys
 import types
 
@@ -524,6 +525,63 @@ def test_routing_counters_count_the_pairs_that_met_an_expert_held(params):
     assert st["moe_expert_tokens"].shape == (5, 4)  # five expert layers, the 4 experts held of 8
     here = st["moe_expert_tokens"].sum() / (st["moe_steps"] * 2 * 3 * 5)  # rows x choices x layers
     assert 0.2 < here < 0.8  # half the experts are here; random weights route about half the pairs to them
+
+
+def test_weight_reads_are_the_plans_live_visits_on_the_commit_span_and_in_the_closing_line(params, monkeypatch, caplog):
+    """``moe_visits``: the visits the expert kernel's ``plan`` lays out for each
+    step's groups, summed over layers and steps (one function counts both); a
+    toy step's groups each fit one visit, so it reads what ``moe_touched`` reads."""
+    import logging
+
+    from pretraining_llm_tpu.ops import pallas_moe
+    from pretraining_llm_tpu.observability import spans
+
+    rec = spans.SpanRecorder()
+    monkeypatch.setattr(spans, "_default", rec)
+    eng = ServingEngine(params[SEEDS[0]], CFG, max_batch=2, n_blocks=32, block_size=8)
+    windows, count = [], eng._count_moe
+    monkeypatch.setattr(eng, "_count_moe", lambda c, n, **kw: windows.append((np.asarray(c["expert_tokens"]), n)) or count(c, n, **kw))
+    eng.submit(tokens(1, 9).tolist(), 6)
+    with caplog.at_level(logging.INFO, logger="pretraining_llm_tpu.serving"):
+        eng.run()
+    st = eng.stats
+    pairs = 2 * CFG.experts_per_token  # a step's sorted rows a layer: two rows, idle or not
+    w = pallas_moe.windows(pairs, CFG.n_experts)
+    assert w == 2 and windows and all(n == 1 for _, n in windows)  # one step a window: the counts are a step's
+    live = sum(int(pallas_moe.plan(jnp.asarray(layer), pairs + -pairs % pallas_moe.ROW_TILE, w)[2][0])
+               for step, _ in windows for layer in step)
+    assert st["moe_visits"] == live == st["moe_experts_touched"].sum() > 0
+    events, _ = rec.drain()
+    commits = [meta for name, *_, meta in events if name == "serving.commit" and "moe_steps" in meta]
+    assert commits and sum(m["moe_visits"] for m in commits) == live
+    assert all(m["moe_visits"] == m["moe_touched"] for m in commits)
+    line, = [r.getMessage() for r in caplog.records if "engine empty" in r.getMessage()]
+    # (written when the last row leaves, ahead of the windows still in flight)
+    said = re.search(r"experts \(grouped\) took \d+ pairs, (\d+) touched, (\d+) weight reads over \d+ steps", line)
+    assert said and 0 < int(said[1]) == int(said[2]) <= live
+
+
+@pytest.mark.parametrize("counts,pairs,visits", [
+    ([[3, 0, 2, 1]], 6, [3]),  # a toy step: every group one visit, the touched
+    ([[17, 0, 15, 0]], 32, [2]),  # a group of a row tile and one more is one visit wherever it starts
+    ([[40, 0, 3, 0]], 6, [3]),  # a group past the span of two windows reads its weights again
+    ([[15, 18, 0, 0]], 6, [3]),  # 18 rows from a window's last row cross the span
+    ([[15, 18, 30, 49]], 8 * 18, [4]),  # past a row tile an expert the visit widens: each group once
+    ([[15, 18, 30, 50]], 8 * 18, [5]),  # one row more than twice the mean's span holds from a last row
+], ids=["toy", "tile-and-one", "past-the-span", "late-start", "wide-visit", "wide-visit-crossed"])
+def test_weight_reads_are_the_touched_exactly_when_every_group_fits_a_visit(counts, pairs, visits):
+    from pretraining_llm_tpu.ops import pallas_moe
+
+    counts = jnp.asarray(counts, jnp.int32)[None]  # (one step, one layer, the 4 experts held of 8)
+    got = paged._routing_counters(CFG, counts, pairs)
+    touched = np.asarray(got["experts_touched"])
+    np.testing.assert_array_equal(got["expert_visits"], visits)
+    n_rows = int(counts.sum()) + -int(counts.sum()) % pallas_moe.ROW_TILE
+    w = pallas_moe.windows(pairs, CFG.n_experts)
+    assert int(pallas_moe.plan(counts[0, 0], n_rows, w)[2][0]) == visits[0]
+    fits = all(start % pallas_moe.ROW_TILE + n <= w * pallas_moe.ROW_TILE
+               for start, n in zip(np.cumsum(counts[0, 0]) - np.asarray(counts[0, 0]), np.asarray(counts[0, 0])))
+    assert (visits[0] == touched[0]) == fits
 
 
 @pytest.mark.parametrize("ragged", [False, True])

@@ -364,8 +364,10 @@ def test_routing_counters_count_the_pairs_that_met_an_expert_held(params):
     eng.run()
     st = eng.stats
     assert st["moe_expert_tokens"].shape == (5, 8)  # five expert layers, the 8 experts held of 16
-    meta = eng._count_moe({"expert_tokens": np.ones((5, 8), np.int64), "experts_touched": np.full((5,), 8)}, 3)
+    meta = eng._count_moe({"expert_tokens": np.ones((5, 8), np.int64), "experts_touched": np.full((5,), 8),
+                           "expert_visits": np.full((5,), 9)}, 3)
     assert meta["moe_routed"] == 3 * 2 * 2 * 5 and meta["moe_routed_here"] == 40 and meta["moe_experts"] == 8
+    assert meta["moe_touched"] == 40 and meta["moe_visits"] == 45
     here = st["moe_expert_tokens"].sum() / (st["moe_steps"] * 2 * 2 * 5)
     assert 0.2 < here < 0.8  # half the experts are here; random weights route about half the pairs to them
 

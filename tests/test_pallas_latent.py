@@ -319,23 +319,31 @@ def test_per_head_decode_kernel_compiles_for_the_chip_at_the_cells_size(one_chip
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-@pytest.mark.parametrize("stack,held,d,f,rows,clamp", [(4, 128, 2560, 768, 1024, False), (5, 64, 3584, 1024, 128, True),
-                                                        (4, 128, 2048, 1024, 512, False)],
-                         ids=["ling-decode-step", "xing-decode-step", "trinity-decode-step"])
-def test_expert_kernel_compiles_for_the_chip_at_the_cells_size(one_chip, stack, held, d, f, rows, clamp):
-    """``ops/pallas_moe.py`` at the two serving cells' expert layers (a stack of
+@pytest.mark.parametrize("stack,held,n_experts,d,f,rows,clamp,windows", [
+    (4, 128, 512, 2560, 768, 1024, False, 2), (5, 64, 64, 3584, 1024, 128, True, 2), (4, 128, 128, 2048, 1024, 512, False, 2),
+    (4, 128, 512, 2560, 768, 8192, False, 2), (10, 18, 72, 4096, 768, 1280, False, 4), (10, 18, 72, 4096, 768, 48 * 72, False, 7),
+    (4, 128, 512, 2560, 768, 2 * 8192, False, 5),
+], ids=["ling-decode-step", "xing-decode-step", "trinity-decode-step", "ling-one-prompt-admission",
+        "granite-decode-step", "granite-at-the-rules-bound", "ling-two-prompt-admission"])
+def test_expert_kernel_compiles_for_the_chip_at_the_cells_size(one_chip, stack, held, n_experts, d, f, rows, clamp, windows):
+    """``ops/pallas_moe.py`` at the serving cells' expert layers (a stack of
     128 experts of 2560 x 768 under 1,024 sorted rows, of 64 experts of 3584 x
-    1024 under 128): Mosaic takes it with its tiles inside the default scoped
-    VMEM, both stacks enter the custom call as they lie (the flat (L * E, ...)
-    views are bitcasts; no copy, slice or relayout of either), and the
-    temporaries are the visits' output, not a stack's size."""
+    1024 under 128, of 18 of 72 experts of 4096 x 768 under 1,280 with a visit
+    of four windows, whose rows leave the weight tiles half the columns): Mosaic
+    takes it with its tiles inside the default scoped VMEM, both stacks enter the custom call as
+    they lie (the flat (L * E, ...) views are bitcasts; no copy, slice or
+    relayout of either), and the temporaries are the visits' output, not a
+    stack's size. Up to a row tile an expert the visit is two windows and the
+    tiles are what they were before PR 44."""
     from jax.experimental.compilation_cache import compilation_cache
 
     from pretraining_llm_tpu.ops import pallas_moe as pm
 
     shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
-    tf = pm.f_tile(d, f, 2)
-    fn = lambda xs, w1, w2, sizes, layer, *limit: pm._moe_call(xs, w1, w2, sizes, layer, *(limit or (None,)), tf, False)
+    w = pm.windows(rows, n_experts)
+    tf = pm.f_tile(d, f, 2, w)
+    assert w == windows and tf <= pm.f_tile(d, f, 2) and (w > 2 or tf == pm.f_tile(d, f, 2))
+    fn = lambda xs, w1, w2, sizes, layer, *limit: pm._moe_call(xs, w1, w2, sizes, layer, *(limit or (None,)), tf, w, False)
     args = [shape((rows, d)), shape((stack, held, d, 2 * f)), shape((stack, held, f, d)),
             shape((held,), jnp.int32), shape((), jnp.int32)] + [shape((), jnp.float32)] * clamp
     cached = jax.config.jax_enable_compilation_cache
@@ -355,7 +363,8 @@ def test_expert_kernel_compiles_for_the_chip_at_the_cells_size(one_chip, stack, 
                    and " parameter(" not in op]
     assert len(stack_sized) == 2 and all(" bitcast(" in op for op in stack_sized), stack_sized
     assert 2 * 3 * d * tf * 2 <= pm.WEIGHT_TILE_BYTES
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    out_bytes = pm.n_visits(rows, held, w) * w * pm.ROW_TILE * d * 2  # what the most visits these rows can take write
+    assert compiled.memory_analysis().temp_size_in_bytes < max(64 << 20, out_bytes + (16 << 20))
 
 
 def test_kda_step_compiles_for_the_chip_over_the_cells_pools_as_they_lie(one_chip, monkeypatch):

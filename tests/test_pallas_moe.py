@@ -54,6 +54,8 @@ def _tol(dtype):
     return 2e-5 if dtype == jnp.float32 else 6e-2
 
 
+WIDE = 4  # the windows a visit holds at the Granite cell's 17.8 rows an expert: a span of 64 rows
+
 GROUPS = {
     "empty-one-two-seven": [0, 1, 2, 7, 0, 3],
     "tile-and-one-more": [TILE, 0, TILE + 1, 1, 0, 2],
@@ -61,35 +63,43 @@ GROUPS = {
     "one-takes-every-row": [0, 0, 3 * TILE + 5, 0, 0, 0],
     "all-alone": [1, 1, 1, 1, 1, 1],
     "last-only": [0, 0, 0, 0, 0, 9],
+    # from a window's last row: as many rows as a wide span still holds, then one more (span - 15 and past it)
+    "wide-span-from-a-last-row": [TILE - 1, 3 * TILE + 1, 0, 5, 0, 1],
+    "past-a-wide-span-from-a-last-row": [TILE - 1, 3 * TILE + 2, 0, 5, 0, 1],
+    "crosses-two-wide-spans": [3, 0, 2 * WIDE * TILE + 3, 0, TILE + 2, 0],
+    "the-cells-mix": [21, 9, 34, 17, 25, 12],  # about 18 rows an expert, the busiest twice the mean
 }
 
 
+@pytest.mark.parametrize("w", [2, WIDE], ids=["two-windows", "wide"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("sizes", GROUPS.values(), ids=GROUPS)
-def test_kernel_is_the_grouped_form_and_the_dense_oracle(sizes, dtype):
+def test_kernel_is_the_grouped_form_and_the_dense_oracle(sizes, dtype, w):
     sizes = jnp.asarray(sizes, jnp.int32)
     n = int(sizes.sum())
     xs, (w1, w2) = _rows(n, dtype), _weights(dtype)
-    got = pk.expert_ffn(xs, w1, w2, sizes)
+    got = pk.expert_ffn(xs, w1, w2, sizes, w=w)
     assert got.shape == xs.shape and got.dtype == dtype
     want = moe.experts_grouped(xs, w1, w2, sizes)
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), atol=_tol(dtype))
     np.testing.assert_allclose(np.asarray(got, np.float32), _oracle(xs, w1, w2, sizes), atol=_tol(dtype))
 
 
-@pytest.mark.parametrize("sizes,elsewhere", [([2, 0, 5, 1], 9), ([0, 0, 0, 0], 13), ([TILE + 1, 0, 0, 3], TILE)],
-                         ids=["some-here", "all-elsewhere", "a-window-of-strangers"])
-def test_rows_of_experts_held_elsewhere_cost_nothing_and_change_nothing(sizes, elsewhere):
+@pytest.mark.parametrize("w", [2, WIDE], ids=["two-windows", "wide"])
+@pytest.mark.parametrize("sizes,elsewhere", [([2, 0, 5, 1], 9), ([0, 0, 0, 0], 13), ([TILE + 1, 0, 0, 3], TILE),
+                                             ([20, 13, 0, 36], 3 * 69)],
+                         ids=["some-here", "all-elsewhere", "a-window-of-strangers", "three-quarters-elsewhere"])
+def test_rows_of_experts_held_elsewhere_cost_nothing_and_change_nothing(sizes, elsewhere, w):
     """``held < n_experts``: the rows past the last group belong to no expert
     here. The rows that do come out as without them."""
     sizes = jnp.asarray(sizes, jnp.int32)
     here = int(sizes.sum())
     xs, (w1, w2) = _rows(here + elsewhere, jnp.float32), _weights(jnp.float32, held=4)
-    got = pk.expert_ffn(xs, w1, w2, sizes)
+    got = pk.expert_ffn(xs, w1, w2, sizes, w=w)
     assert got.shape == xs.shape
     np.testing.assert_allclose(got[:here], _oracle(xs, w1, w2, sizes)[:here], atol=2e-5)
     if here:
-        np.testing.assert_array_equal(got[:here], pk.expert_ffn(xs[:here], w1, w2, sizes))
+        np.testing.assert_array_equal(got[:here], pk.expert_ffn(xs[:here], w1, w2, sizes, w=w))
 
 
 @pytest.mark.parametrize("clamp", [None, 0.0, 0.4], ids=["no-clamp", "clamp-off", "clamp-on"])
@@ -122,27 +132,93 @@ def test_what_no_row_chose_is_never_read():
     np.testing.assert_array_equal(got[:here], clean[:here])
 
 
-@pytest.mark.parametrize("rows,held", [(16, 4), (256, 64), (1024, 128), (48, 300)])
-def test_the_grid_holds_every_plan(rows, held):
+@pytest.mark.parametrize("w", [2, 3, WIDE], ids=["two-windows", "three", "wide"])
+@pytest.mark.parametrize("rows,held", [(16, 4), (256, 64), (1024, 128), (48, 300), (1280, 18), (8192, 128)])
+def test_the_grid_holds_every_plan(rows, held, w):
     """``n_visits`` bounds the visits of the mixes that take most: every
-    touched expert alone in a visit, and groups that start on a window's last
-    row."""
-    span = 2 * TILE
+    touched expert alone in a visit, groups that start on a window's last
+    row, and groups one row longer than a visit holds from there (1,280 rows
+    over 18 held: the Granite cell's decode step; 8,192 over 128: Ling's
+    one-prompt admission)."""
+    span = w * TILE
     first_on_a_last_row = np.bincount(np.arange(rows - TILE + 1) % min(held, 3), minlength=held)
     first_on_a_last_row[0] += TILE - 1
+    crossing = np.zeros(held, int)  # TILE - 1 rows, then groups that each cross a span by one row
+    crossing[0] = min(TILE - 1, rows)
+    n_crossing = min(held - 1, (rows - crossing[0]) // (span - TILE))
+    crossing[1 : 1 + n_crossing] = span - TILE
+    crossing[-1] += rows - crossing.sum()
     for sizes in (
         np.bincount(np.arange(rows) % held, minlength=held),
         first_on_a_last_row,
+        crossing,
         np.r_[rows, np.zeros(held - 1, int)],
     ):
+        assert sizes.sum() == rows
         ends = np.cumsum(sizes)
         base = (ends - sizes) // TILE * TILE
         visits = np.where(sizes > 0, -(-(ends - base) // span), 0).sum()
-        assert visits <= pk.n_visits(rows, held)
-        expert, window, live, position = pk.plan(jnp.asarray(sizes, jnp.int32), rows)
-        assert int(live[0]) == visits and expert.shape == window.shape == (pk.n_visits(rows, held),)
+        assert visits <= pk.n_visits(rows, held, w)
+        np.testing.assert_array_equal(pk.group_visits(jnp.asarray(sizes, jnp.int32), w)[0].sum(), visits)
+        expert, window, live, position = pk.plan(jnp.asarray(sizes, jnp.int32), rows, w)
+        assert int(live[0]) == visits and expert.shape == window.shape == (pk.n_visits(rows, held, w),)
         here = np.asarray(position)[: sizes.sum()]
         assert len(set(here.tolist())) == len(here) and here.max(initial=0) < visits * span + span
+        # a row lies in its visit's span at the place it has in the sorted rows
+        first_window = np.asarray(window)[here // span]
+        np.testing.assert_array_equal((first_window * TILE + here % span)[: sizes.sum()], np.arange(sizes.sum()))
+    assert pk.n_visits(rows, held) == min(held, rows) + rows // TILE  # two windows: the bound the cells have had
+
+
+@pytest.mark.parametrize("rows,n_experts,w", [
+    (2 * 512, 512, 2), (1024 * 8, 512, 2),  # Ling: a decode step, a one-prompt admission at exactly a row tile an expert
+    (32 * 4, 64, 2), (64 * 2 * 8, 256, 2), (64 * 8, 128, 2),  # Xing, JoyAI's two-query round, Trinity
+    (128 * 10, 72, WIDE),  # Granite: 17.8 rows an expert, a span of 64 holds every group up to 49 rows
+    (16 * 8 + 1, 8, 3), (24 * 8, 8, 4), (32 * 8, 8, 5), (48 * 8, 8, 7),
+], ids=["ling-decode", "ling-admission", "xing-decode", "joyai-round", "trinity-decode", "granite-decode",
+        "just-past-a-tile", "24", "32", "48"])
+def test_a_visit_is_two_windows_up_to_a_row_tile_an_expert_and_holds_twice_the_mean_past_it(rows, n_experts, w):
+    assert pk.windows(rows, n_experts) == w
+    if w > 2:
+        twice = -(-2 * rows // n_experts)
+        assert (w - 1) * TILE + 1 >= twice > (w - 2) * TILE + 1  # the fewest windows that do
+
+
+@pytest.mark.parametrize("rows,held,d,f,stack", [(1024, 128, 256, 384, 2), (128, 8, 128, 256, None), (8192, 16, 128, 128, 3)],
+                         ids=["ling-like", "xing-like", "an-admission"])
+def test_at_a_row_tile_an_expert_and_under_the_traced_call_is_what_it_was(rows, held, d, f, stack):
+    """Up to ROW_TILE rows an expert the call is PR 32's, figure for figure:
+    two 16-row windows of the sorted rows, a 32-row scratch, accumulator and
+    output block, one output block a visit of ``min(held, rows) + rows // 16``,
+    a dynamic first grid bound, and no VMEM limit of its own."""
+    shape = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt)
+    lead = () if stack is None else (stack,)
+    w = pk.windows(rows, rows // 16)  # exactly a row tile an expert
+    assert w == pk.windows(rows, 4 * rows) == 2
+    args = [shape(rows, d), shape(*lead, held, d, 2 * f), shape(*lead, held, f, d), shape(held, dt=jnp.int32)]
+    args += [shape(dt=jnp.int32)] * (stack is not None)
+    jaxpr = jax.make_jaxpr(lambda *a: pk.expert_ffn(*a, w=w, interpret=False))(*args)
+
+    def find(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                if (found := find(sub)) is not None:
+                    return found
+
+    call = find(jaxpr.jaxpr)
+    grid = call.params["grid_mapping"]
+    tf = pk.f_tile(d, f, 2)
+    visits = min(held, rows) + rows // 16
+    block = lambda bm: tuple(getattr(dim, "block_size", None) for dim in bm.block_shape)
+    assert [block(bm) for bm in grid.block_mappings] == [(16, d), (16, d), (None, d, tf), (None, d, tf), (None, tf, d), (32, d)]
+    assert grid.grid[1] == f // tf and not isinstance(grid.grid[0], int) and grid.num_index_operands == 3
+    assert [(a.shape, a.dtype) for a in (s.inner_aval for s in grid.scratch_avals)] == [((32, d), jnp.bfloat16), ((32, d), jnp.float32)]
+    assert [(a.shape, a.dtype) for a in call.params["out_avals"]] == [((visits * 32, d), jnp.bfloat16)]
+    assert [v.aval.shape for v in call.invars[1:4]] == [(visits,), (visits,), (1,)]
+    params = call.params["compiler_params"]["mosaic_tpu"]
+    assert params.vmem_limit_bytes is None and params.dimension_semantics == ("arbitrary", "arbitrary")
 
 
 def test_shapes_the_kernel_cannot_take_are_refused_by_name():
@@ -170,26 +246,46 @@ def _experts(dtype=jnp.bfloat16, d=128, f=128):
     return {"w1": jax.ShapeDtypeStruct((8, d, 2 * f), dtype), "w2": jax.ShapeDtypeStruct((8, f, d), dtype)}
 
 
-@pytest.mark.parametrize("rows,backend,experts,mesh,compute,form", [
-    (2 * 8, "tpu", _experts(), None, "bfloat16", "kernel"),
-    (moe.KERNEL_ROWS_PER_EXPERT * 8, "tpu", _experts(), None, "bfloat16", "kernel"),
-    (moe.KERNEL_ROWS_PER_EXPERT * 8 + 1, "tpu", _experts(), None, "bfloat16", "grouped"),  # a prefill
-    (512 * 8, "tpu", _experts(), None, "bfloat16", "grouped"),
-    (2 * 8, "cpu", _experts(), None, "bfloat16", "grouped"),
-    (2 * 8, "gpu", _experts(), None, "bfloat16", "grouped"),
-    (2 * 8, "tpu", _experts(jnp.int8), None, "bfloat16", "grouped"),  # quantized for serving
-    (2 * 8, "tpu", _experts(jnp.float32), None, "float32", "grouped"),
-    (2 * 8, "tpu", _experts(), None, "float32", "grouped"),  # a cast copy of the stack is no place to stream from
-    (2 * 8, "tpu", _experts(), "a mesh", "bfloat16", "grouped"),
-    (2 * 8, "tpu", _experts(d=192), None, "bfloat16", "grouped"),
-    (2 * 8, "tpu", _experts(f=64), None, "bfloat16", "grouped"),
-], ids=["decode", "at-the-constant", "past-it", "prefill", "cpu", "gpu", "int8", "float32", "float32-compute",
-        "mesh", "d-not-lanes", "f-not-lanes"])
-def test_form_is_read_from_rows_backend_dtype_mesh_and_lanes(rows, backend, experts, mesh, compute, form):
-    cfg = dataclasses.replace(CFG, compute_dtype=compute)
+ROWS = moe.KERNEL_ROWS_PER_EXPERT  # the rule's bound, in sorted rows an expert
+
+# what the cells' programs hand the rule: (sorted rows, experts the router scores)
+CELLS = {
+    "ling-decode": (128 * 8, 512, "kernel"), "ling-one-prompt-prefill": (1024 * 8, 512, "kernel"),  # 2 and exactly 16
+    "ling-first-wave-prefill": (8 * 1024 * 8, 512, "grouped"),  # 128
+    "xing-decode": (32 * 4, 64, "kernel"), "xing-prefill": (8192 * 4, 64, "grouped"),  # 2, 512
+    "joyai-round": (64 * 2 * 8, 256, "kernel"), "joyai-prefill": (2048 * 8, 256, "grouped"),  # 4, 64
+    "trinity-decode": (64 * 8, 128, "kernel"), "trinity-prefill": (1024 * 8, 128, "grouped"),  # 4, 64
+    "granite-decode": (128 * 10, 72, "kernel"), "granite-prefill": (1024 * 10, 72, "grouped"),  # 17.8, 142
+}
+
+
+@pytest.mark.parametrize("rows,n_experts,backend,experts,mesh,compute,form", [
+    (2 * 8, 8, "tpu", _experts(), None, "bfloat16", "kernel"),
+    (ROWS * 8, 8, "tpu", _experts(), None, "bfloat16", "kernel"),
+    (ROWS * 8 + 1, 8, "tpu", _experts(), None, "bfloat16", "grouped"),  # a prefill
+    (512 * 8, 8, "tpu", _experts(), None, "bfloat16", "grouped"),
+    (2 * 8, 8, "cpu", _experts(), None, "bfloat16", "grouped"),
+    (2 * 8, 8, "gpu", _experts(), None, "bfloat16", "grouped"),
+    (2 * 8, 8, "tpu", _experts(jnp.int8), None, "bfloat16", "grouped"),  # quantized for serving
+    (2 * 8, 8, "tpu", _experts(jnp.float32), None, "float32", "grouped"),
+    (2 * 8, 8, "tpu", _experts(), None, "float32", "grouped"),  # a cast copy of the stack is no place to stream from
+    (2 * 8, 8, "tpu", _experts(), "a mesh", "bfloat16", "grouped"),
+    (2 * 8, 8, "tpu", _experts(d=192), None, "bfloat16", "grouped"),
+    (2 * 8, 8, "tpu", _experts(f=64), None, "bfloat16", "grouped"),
+] + [(rows, n_experts, "tpu", _experts(), None, "bfloat16", form) for rows, n_experts, form in CELLS.values()],
+    ids=["decode", "at-the-constant", "past-it", "prefill", "cpu", "gpu", "int8", "float32", "float32-compute",
+         "mesh", "d-not-lanes", "f-not-lanes"] + list(CELLS))
+def test_form_is_read_from_rows_backend_dtype_mesh_and_lanes(rows, n_experts, backend, experts, mesh, compute, form):
+    cfg = dataclasses.replace(CFG, compute_dtype=compute, n_experts=n_experts)
     assert moe.experts_form(rows, cfg, experts, mesh=mesh, backend=backend) == form
     if backend == "cpu":  # what this process runs on, unasked
         assert moe.experts_form(rows, cfg, experts, mesh=mesh) == form
+
+
+def test_the_rule_stands_between_the_decode_steps_and_the_prefills():
+    """One bound, in rows an expert: past a row tile (the Granite cell's decode
+    step stands at 17.8) and under the 64 at which the cells' prefills begin."""
+    assert 17.8 < ROWS < 64
 
 
 @pytest.fixture
@@ -217,12 +313,12 @@ def _decode(p, n_steps=2):
     )
 
 
-def _prefill(p, tokens=64):
-    pools = transformer.make_paged_kv_pool(CFG, 32, 8)
-    pages = jnp.arange(1, 1 + 2 * tokens // 8, dtype=jnp.int32).reshape(2, -1)
+def _prefill(p, tokens=64, rows=8):
+    pools = transformer.make_paged_kv_pool(CFG, 1 + rows * tokens // 8, 8)
+    pages = jnp.arange(1, 1 + rows * tokens // 8, dtype=jnp.int32).reshape(rows, -1)
     return functools.partial(
-        paged._prefill_scatter_sample, p, pools, jnp.zeros((2, tokens), jnp.int32),
-        jnp.asarray([tokens, 11], jnp.int32), pages, jax.random.key(2), CFG, tokens, tokens // 8,
+        paged._prefill_scatter_sample, p, pools, jnp.zeros((rows, tokens), jnp.int32),
+        jnp.asarray([tokens, 11] * (rows // 2), jnp.int32), pages, jax.random.key(2), CFG, tokens, tokens // 8,
     )
 
 
@@ -247,7 +343,7 @@ def test_decode_traces_the_kernel_under_moe_experts_and_prefill_keeps_ragged_dot
     decode = _primitives(_decode(params))
     assert "ragged_dot" not in decode and "ragged_dot_general" not in decode
     assert decode["pallas_call"] and all("moe.experts" in path for path in decode["pallas_call"])
-    prefill = _primitives(_prefill(params))  # 2 x 64 tokens x 2 choices over 8 experts: 32 rows an expert
+    prefill = _primitives(_prefill(params))  # 8 x 64 tokens x 2 choices over 8 experts: 128 rows an expert
     assert "pallas_call" not in prefill
     assert any(name.startswith("ragged_dot") for name in prefill)
 
@@ -276,6 +372,8 @@ def test_engine_reports_the_decode_steps_form(params, on_a_tpu, caplog):
     # a batch whose decode step brings a prefill's rows an expert keeps the grouped form
     wide = ServingEngine(params, CFG, max_batch=8 * moe.KERNEL_ROWS_PER_EXPERT, n_blocks=16, block_size=8)
     assert wide.pool_info()["decode_experts"] == "grouped"
+    # and one past a row tile an expert, under the rule's bound, the kernel with a wider visit
+    assert ServingEngine(params, CFG, max_batch=4 * 18, n_blocks=16, block_size=8).pool_info()["decode_experts"] == "kernel"
 
 
 def test_engine_off_the_tpu_and_without_experts(params):

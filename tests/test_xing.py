@@ -458,19 +458,23 @@ PARENTS = {
     # the fourth configuration, at the `xing-mini` preset's widths (latent pool, dropless experts
     # with no group limit, no clamp and every expert held, four streams), as f8a0c12 (PR 30)
     # traces it: PR 31's per-layer type table, state slots, group limit and clamps leave it be
-    ("xing4.0-29b-a4b", "decode"): (2779, "81e950d7efcdced8"),
+    # (the expert models' decode and round programs since PR 44: the parent's - 2,779 / 81e950d7efcdced8,
+    # 2,512 / 241427cc1ac9352d, 1,481 / e28efbc503cecb46 and 2,672 / b4e83b754e2de962 at a5eb60d - and 37 to 39
+    # int32 equations over the (steps, expert layers, E) routing counts, the weight reads `expert_visits`;
+    # diffed equation by equation against that commit, nothing else differs)
+    ("xing4.0-29b-a4b", "decode"): (2816, "a917014dbbbf7e7e"),
     ("xing4.0-29b-a4b", "prefill"): (1445, "1fd884617569206c"),
     ("xing4.0-29b-a4b", "forward"): (1354, "7c021ba348cc52d2"),
     # the fifth and sixth, at the `ling-mini` and `joyai-mini` presets' widths (state slots beside
     # the latent pool; the MTP module's layer and its round), as 6611a27 (PR 40) traces them:
     # PR 41's per-layer attention kind, second page table and window pool leave them be
-    ("ling-3.0-flash", "decode"): (2512, "241427cc1ac9352d"),
+    ("ling-3.0-flash", "decode"): (2549, "4525c94499540e5b"),
     ("ling-3.0-flash", "prefill"): (2254, "6f0de077b97d34fb"),
     ("ling-3.0-flash", "forward"): (2023, "96aa51f3110c061b"),
-    ("joyai-llm-flash", "decode"): (1481, "e28efbc503cecb46"),
+    ("joyai-llm-flash", "decode"): (1518, "2c64035367baaf79"),
     ("joyai-llm-flash", "prefill"): (613, "241d392a485a0b90"),
     ("joyai-llm-flash", "forward"): (504, "9d87ef583c8c67f6"),
-    ("joyai-llm-flash", "round"): (2672, "b4e83b754e2de962"),
+    ("joyai-llm-flash", "round"): (2711, "463725f3440a882c"),
 }
 
 
