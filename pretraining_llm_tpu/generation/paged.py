@@ -53,6 +53,13 @@ _POOL_OF_DENSE = {
 }
 
 
+def _laid_out(params: Any, cfg: ModelConfig, mesh: Any) -> Any:
+    """A tree handed in from outside the engine, in the serving layout the
+    engine's own tree has (``transformer.serving_layout``: the same stored
+    tree gives the very same arrays); under a serving mesh as it came."""
+    return transformer.serving_layout(params, cfg) if mesh is None else params
+
+
 def pool_block_size(pools: transformer.KVCache, cfg: ModelConfig) -> int:
     """Tokens a page of ``pools`` holds (per-head or latent)."""
     # the first layer that has pages (a hybrid stack's recurrent layers keep state slots)
@@ -379,7 +386,7 @@ def prefill_into_pool(
     prompt = jnp.zeros((1, p_bucket), jnp.int32)
     prompt = prompt.at[0, :p].set(jnp.asarray(prompt_ids, jnp.int32))
     last, dense = _prefill_dense(
-        params, prompt, jnp.int32(p), cfg, p_bucket, mesh
+        _laid_out(params, cfg, mesh), prompt, jnp.int32(p), cfg, p_bucket, mesh
     )
     in_window_pool = window_layers(cfg)
     if bool(in_window_pool) != (window_block_ids is not None) or (
@@ -552,7 +559,7 @@ def prefill_into_pool_batched(
             window_arr[i, : len(own)] = own
         window_arr = jnp.asarray(window_arr)
     toks, pools = _prefill_scatter_sample(
-        params, pools, jnp.asarray(prompt_arr), jnp.asarray(lens),
+        _laid_out(params, cfg, mesh), pools, jnp.asarray(prompt_arr), jnp.asarray(lens),
         jnp.asarray(ids_arr), key, cfg, p_bucket, bucket_pages,
         temperature, top_k, top_p, min_p, mesh, _slot_array(pools, slots, bucket_rows),
         with_draft, window_arr,
@@ -1034,7 +1041,6 @@ def paged_mtp_round(
         return emit, n_emit, nxt, _round_counters(cfg, counts, m_counts, 2 * b * cfg.experts_per_token), pools
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "mesh"), donate_argnums=(1,))
 def paged_mtp_logits(
     params: Any,
     pools: transformer.KVCache,
@@ -1049,7 +1055,14 @@ def paged_mtp_logits(
     forces the tokens: the stack's logits (B, 2, V) over ``seq_tokens`` and
     the module's (B, 2, V) over the stack's own hidden states and
     ``following``, both through the pool (what ``paged_decode_logits`` is to
-    the decode step)."""
+    the decode step). ``pools`` is donated."""
+    return _paged_mtp_logits(
+        _laid_out(params, cfg, mesh), pools, seq_tokens, following, block_tables, seq_lens, cfg, mesh
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "mesh"), donate_argnums=(1,))
+def _paged_mtp_logits(params, pools, seq_tokens, following, block_tables, seq_lens, cfg, mesh=None):
     from pretraining_llm_tpu.parallel.sharding import activation_mesh
 
     with activation_mesh(mesh):
@@ -1220,11 +1233,6 @@ def paged_decode_steps_lp(
     )
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("cfg", "mesh"),
-    donate_argnums=(1,),
-)
 def paged_decode_logits(
     params: Any,
     pools: transformer.KVCache,
@@ -1243,8 +1251,15 @@ def paged_decode_logits(
     path (`paged_decode_step[s]` / `_lp`) eliminates. The serving engine
     keeps this lane wired (``fused_sampling=False``) so fused-vs-unfused
     greedy bit-identity stays testable and the transfer win stays
-    benchable.
+    benchable. ``pools`` is donated.
     """
+    return _paged_decode_logits(
+        _laid_out(params, cfg, mesh), pools, tokens, block_tables, seq_lens, cfg, mesh, window_tables
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "mesh"), donate_argnums=(1,))
+def _paged_decode_logits(params, pools, tokens, block_tables, seq_lens, cfg, mesh=None, window_tables=None):
     from pretraining_llm_tpu.parallel.sharding import activation_mesh
 
     with activation_mesh(mesh):

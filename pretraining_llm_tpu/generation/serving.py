@@ -67,8 +67,8 @@ _log = logging.getLogger("pretraining_llm_tpu.serving")
 
 # The most padded prompt tokens one batched admission prefill program takes
 # (ServingEngine._prefill_parts); a boundary that admits more runs several. An
-# engine of an expert model on a device that tells its memory starts from what
-# fits (prefill_program_tokens below), every other from this.
+# engine on a device that tells its memory starts from what fits
+# (prefill_program_tokens below), every other from this.
 PREFILL_PROGRAM_TOKENS = 32768
 
 # What an expert layer's prefill holds at once, in copies of a token's
@@ -81,17 +81,32 @@ PREFILL_PROGRAM_TOKENS = 32768
 # each a factor 1.2 from the line (PERF.md section 7, "After PR 43" (5)).
 PREFILL_EXPERT_COPIES = 3.75
 
+# What a dense model's prefill holds at once for a padded token: its keys and
+# values in every layer's staged pages, and the FFN's gate, up and hidden rows
+# (160 KB on an 18-layer Mistral, whose at-size compiles read 151 KB: 0.62 GB
+# for 4 x 1,024 tokens, 1.24 GB for 8 x 1,024), times this for what else is
+# live and for a heap in pieces: with the serving layout's copy beside a stored
+# tree its caller keeps, 1.28 GB are free, a program of 8,192 tokens found no
+# room on the chip and 4 x 1,024 ran (PERF.md section 6, PR 45).
+PREFILL_DENSE_MARGIN = 1.25
+
 
 def prefill_program_tokens(cfg: ModelConfig, free_bytes: Optional[int]) -> int:
     """The most padded tokens an admission prefill program of ``cfg`` may take
-    beside what is resident: PREFILL_PROGRAM_TOKENS, or for an expert model
-    the largest power of two under it whose expert layer's temporaries
-    (PREFILL_EXPERT_COPIES) fit the ``free_bytes`` of the device. None (a
-    device that does not tell, as a CPU) keeps the constant."""
-    if not cfg.n_experts or free_bytes is None:
+    beside what is resident: the largest power of two up to
+    PREFILL_PROGRAM_TOKENS whose temporaries fit the ``free_bytes`` of the
+    device, an expert model's by its expert layer (PREFILL_EXPERT_COPIES), a
+    dense model's by its staged pages and FFN rows (PREFILL_DENSE_MARGIN).
+    None (a device that does not tell, as a CPU) keeps the constant."""
+    if free_bytes is None:
         return PREFILL_PROGRAM_TOKENS
-    token_bytes = PREFILL_EXPERT_COPIES * cfg.experts_per_token * cfg.d_model * jnp.dtype(cfg.compute_dtype).itemsize
-    fit = max(1, int(free_bytes / token_bytes))
+    if cfg.n_experts:
+        token_values = PREFILL_EXPERT_COPIES * cfg.experts_per_token * cfg.d_model
+    else:
+        token_values = PREFILL_DENSE_MARGIN * (
+            cfg.n_layers * 2 * cfg.kv_heads * cfg.head_dim + 3 * cfg.d_ff
+        )
+    fit = max(1, int(free_bytes / (token_values * jnp.dtype(cfg.compute_dtype).itemsize)))
     return min(PREFILL_PROGRAM_TOKENS, 1 << (fit.bit_length() - 1))
 
 
@@ -391,7 +406,22 @@ class ServingEngine:
                 self.draft_params = quantize_mod.quantize_params_for_serving(
                     self.draft_params, self.draft_cfg
                 )
-        self.params = params
+        # A dense SwiGLU's w1 laid out for the serving programs, once
+        # (transformer.serving_layout; a serving mesh keeps the tree it sharded).
+        # Every other leaf is the caller's own; layout_bytes is what the copies hold.
+        self.params = params if mesh is not None else transformer.serving_layout(params, cfg)
+        stored = {id(leaf) for leaf in jax.tree.leaves(params)}
+        laid = {
+            ".".join(str(k.key) for k in path): leaf.nbytes
+            for path, leaf in jax.tree_util.tree_leaves_with_path(self.params)
+            if id(leaf) not in stored
+        }
+        if laid:
+            _log.info(
+                "serving layout: %.2f GB laid out for the matmuls beside the stored tree: %s",
+                sum(laid.values()) / 1e9,
+                ", ".join(f"{name} {n / 1e9:.2f} GB" for name, n in laid.items()),
+            )
         self.cfg = cfg
         # How a decode step reads the pool, fixed for the engine's programs:
         # what its input and the backend allow (models/mla.py::decode_form for
@@ -693,6 +723,8 @@ class ServingEngine:
             "kv_blocks_in_use": 0, "kv_blocks_peak": 0,
             "ticks": 0, "slow_ticks": 0,
             "longest_tick": {"tick": 0, "seconds": 0.0, "phase_s": {}},
+            # Bytes of the copies in the serving layout (0: none made).
+            "layout_bytes": sum(laid.values()),
         }
         if self.two_lifetimes:
             # The second lifetime's own counters: the above then count a full

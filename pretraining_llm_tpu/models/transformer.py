@@ -29,6 +29,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import math
+import weakref
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -152,6 +153,11 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 
     GPT-2 style init: N(0, 0.02) everywhere, residual-output projections
     (wo, w2) scaled by 1/sqrt(2*n_layers), zeros for biases.
+
+    This is the STORED layout: what checkpoints hold, the importers write and
+    training, ``generate.py`` and a serving mesh read. A serving engine on one
+    device reads a dense SwiGLU's ``w1`` from a copy it lays out once when it
+    is built (``serving_layout``); nothing stored changes for it.
     """
     dtype = jnp.dtype(cfg.param_dtype)
     d, h, dh, f, v, t, nl = (
@@ -300,6 +306,81 @@ def layer_groups(params: Params, cfg: ModelConfig):
         groups.append((range(a, b), params[key], seen.get(key, 0)))
         seen[key] = seen.get(key, 0) + b - a
     return groups
+
+
+# ---------------------------------------------------------------------------
+# The serving layout of a dense SwiGLU's w1
+# ---------------------------------------------------------------------------
+
+# id of a stored w1 -> (weak reference to it, its gate and up halves): the same
+# stored leaf gives the same halves, and they go when the leaf does.
+_SERVING_COPIES: Dict[int, Tuple[Any, Tuple[jax.Array, jax.Array]]] = {}
+
+
+@jax.jit
+def _gate_and_up(w1: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """(L, d, 2, f) -> two (L, d, f)."""
+    return w1[:, :, 0], w1[:, :, 1]
+
+
+def _serving_halves(w1: Any) -> Optional[Tuple[jax.Array, jax.Array]]:
+    """The halves of a stored, stacked SwiGLU ``w1`` (L, d, 2, f), made once a
+    leaf; None where the rule leaves it as it is: a GELU's (L, d, f), int8
+    codes, an array a mesh shards, anything that is not an array on a device."""
+    if (
+        not isinstance(w1, jax.Array)
+        or isinstance(w1, jax.core.Tracer)
+        or w1.ndim != 4
+        or not jnp.issubdtype(w1.dtype, jnp.floating)
+        or not isinstance(w1.sharding, jax.sharding.SingleDeviceSharding)
+    ):
+        return None
+    key = id(w1)
+    held = _SERVING_COPIES.get(key)
+    if held is None or held[0]() is not w1:
+        # (the table bound now: a leaf may die after the module's names have gone)
+        forget = lambda _, copies=_SERVING_COPIES: copies.pop(key, None)
+        held = (weakref.ref(w1, forget), _gate_and_up(w1))
+        _SERVING_COPIES[key] = held
+    return held[1]
+
+
+def serving_layout(params: Params, cfg: ModelConfig) -> Params:
+    """``params`` with the dense SwiGLU FFNs of its per-head attention stacks
+    laid out for the serving programs: ``mlp.w1`` (L, d, 2, f) becomes
+    ``mlp.w1_gate`` and ``mlp.w1_up``, (L, d, f) each. The TPU tiles an array's
+    last two axes; stored, those are (2, f), the matmul wants the contracted
+    ``d`` there, and XLA copies the whole stack into another tiling in every
+    program that reads it (12 ms of a 30 ms decode step on an 18-layer
+    Mistral, PERF.md section 6, PR 45). A weight whose last two axes are
+    (contracted, output), as w2 is stored, is read in place, and ``_dense_mlp``
+    runs two such matmuls where it finds the halves.
+
+    The stored layout (``init_params``, checkpoints, the importers) is what it
+    was: this is a copy made once, one jitted program a leaf, for the engine
+    and for the paged entry points that take a tree from outside it. Every
+    other leaf is shared with ``params``. The halves are remembered weakly by
+    the identity of the stored leaf, so the same stored tree gives the same
+    arrays again (the engine and a caller that still holds the stored tree
+    meet one copy) and they are freed with it. Latent attention and recurrent
+    stacks, a GELU's w1, expert stacks, int8 leaves, a tree a mesh shards and a
+    tree already in this form pass through untouched; with nothing to lay out
+    the result is ``params`` itself."""
+    if cfg.kv_lora_rank:
+        return params
+    kinds, out = cfg.layer_kinds, params
+    per_head = {
+        next(k for k, v in params.items() if v is stack)
+        for layers_of, stack, _ in layer_groups(params, cfg) if kinds[layers_of[0]][0] == "attn"
+    }
+    for key in sorted(per_head):
+        mlp = params[key]["mlp"]
+        halves = _serving_halves(mlp.get("w1"))
+        if halves is not None:
+            mlp = {k: v for k, v in mlp.items() if k != "w1"}
+            mlp["w1_gate"], mlp["w1_up"] = halves
+            out = {**out, key: {**params[key], "mlp": mlp}}
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -818,7 +899,18 @@ def _dense_mlp(mlp: Params, h: jax.Array, cfg: ModelConfig, limit: Any = None) -
     """The dense FFN on normed input: w2 . act(w1 . h), in compute dtype.
     ``limit`` clamps a SwiGLU (``moe.swiglu``)."""
     cdt = jnp.dtype(cfg.compute_dtype)
-    if cfg.activation == "swiglu":
+    if "w1_gate" in mlp:
+        # the serving layout (serving_layout): w1's halves, a matmul each
+        gate, up = (
+            jnp.einsum(
+                "btd,df->btf", h, _weight(mlp, name, cdt), preferred_element_type=jnp.float32
+            ).astype(cdt)
+            for name in ("w1_gate", "w1_up")
+        )
+        if "b1" in mlp:
+            gate, up = gate + mlp["b1"][0].astype(cdt), up + mlp["b1"][1].astype(cdt)
+        hidden = moe.swiglu(gate, up, limit)
+    elif cfg.activation == "swiglu":
         gates = jnp.einsum(
             "btd,dcf->bctf", h, _weight(mlp, "w1", cdt), preferred_element_type=jnp.float32
         ).astype(cdt)

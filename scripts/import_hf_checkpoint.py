@@ -31,6 +31,11 @@ Mapping (HF state_dict key -> params leaf):
   *.attn.bias / *.attn.masked_bias       -> dropped (causal-mask buffers; this
                                             framework masks by index arithmetic)
 
+The leaves above are the framework's stored layout, unchanged by serving: a
+serving engine makes its own layout of a dense SwiGLU's w1 when it is built
+(`models/transformer.py::serving_layout`); a GELU/ReLU w1 (D, F) as written
+here is read in place.
+
 Usage:
   python scripts/import_hf_checkpoint.py /path/to/hf_gpt2_dir --out_dir imported
   python scripts/generate_text.py --model_path imported --input_text "..."

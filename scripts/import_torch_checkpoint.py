@@ -25,6 +25,11 @@ Mapping (reference state_dict key -> params leaf):
   lm_head.{weight,bias}        (V, D) / (V,)   -> lm_head.{kernel (D,V), bias}
   *.tril / pos_idxs buffers                    -> dropped (mask buffers, B10)
 
+The leaves above are the framework's stored layout, unchanged by serving: a
+serving engine makes its own layout of a dense SwiGLU's w1 when it is built
+(`models/transformer.py::serving_layout`); a GELU/ReLU w1 (D, F) as written
+here is read in place.
+
 Usage:
   python scripts/import_torch_checkpoint.py ckpt.pt --out_dir imported_ckpt
   python scripts/generate_text.py --model_path imported_ckpt --input_text "..."
