@@ -40,6 +40,9 @@ _RETIRED_KEYS: Dict[str, Tuple[Optional[Tuple[Any, ...]], Any, str]] = {
     "model.scan_unroll": (None, 1, "PR 29"),
     "model.ce_impl": (("fused",), "chunked", "PR 29"),
     "model.remat": (("save_qkv_attn", "save_big"), "save_attn_res", "PR 29"),
+    "model.paged_attention_impl": (None, "gather", "PR 47"),
+    "model.ragged_kv_splits": (None, 1, "PR 47"),
+    "model.ragged_amla": (None, False, "PR 47"),
 }
 
 
@@ -319,56 +322,12 @@ class ModelConfig:
     # scales with L*B*T). Prefill attention always runs on the unquantized
     # local block; only decode-step reads dequantize.
     kv_cache_dtype: str = "compute"  # compute | int8
-    # Paged (serving) attention over a per-head page pool. "gather", the
-    # default, means "what the input and the backend allow"
-    # (models/transformer.py::paged_attention_form): the single-token decode
-    # step over an unquantized, unsharded pool of lane-wide heads on a TPU
-    # runs the Pallas kernel that copies each row's LIVE pages from the pool
-    # in place (ops/pallas_paged.py); everything else (several queries a
-    # row, int8 pools, a serving mesh, every other backend) attends over
-    # pool[tables], every slot the table names, with a masked einsum. On
-    # a v5e XLA keeps that gathered copy in VMEM while it fits (one HBM
-    # pass over every tabled slot, not three), so the kernel's gain is
-    # first the slots it never reads (PERF.md section 6, PR 30).
-    # "kernel" forces the Pallas forms for every input and backend
-    # (interpreted off the TPU; what the tests select): int8 pools then go
-    # through the ragged kernel, which fuses the scale-page dequant into
-    # its page loop (only int8 bytes + scales cross HBM), where "gather"
-    # dequantizes after the pool gather.
-    paged_attention_impl: str = "gather"  # gather | kernel
-    # Ragged-kernel speed knobs (paged_attention_impl="kernel" only; the
-    # gather form and the decode step's kernel ignore both). `ragged_kv_splits` partitions each row's
-    # page range across that many parallel grid lanes (FA2 work
-    # partitioning with a log-sum-exp combine): 1 = single-pass kernel
-    # (the pre-split default, bit-compatible), 0 = auto-tune from
-    # (max_pages, B), >1 = forced count. `ragged_amla` switches the
-    # online softmax to AMLA's exp2 MUL-by-ADD rescale (per-page
-    # correction as an exponent-field add; int8 dequant scales absorbed
-    # into the same restructure). Defaults keep the single-pass numerics;
-    # no cell selects either, they wait for the paged-attention race
-    # (ROADMAP S4).
-    ragged_kv_splits: int = 1  # 0 = auto | 1 = off | >1 = forced
-    ragged_amla: bool = False
 
     def __post_init__(self) -> None:
         if self.kv_cache_dtype not in ("compute", "int8"):
             raise ValueError(
                 f"kv_cache_dtype must be 'compute' or 'int8', got "
                 f"{self.kv_cache_dtype!r}"
-            )
-        if self.paged_attention_impl not in ("gather", "kernel"):
-            raise ValueError(
-                f"paged_attention_impl must be 'gather' or 'kernel', got "
-                f"{self.paged_attention_impl!r}"
-            )
-        # int8 pools work with BOTH paged impls: "gather" dequantizes
-        # after the pool gather, "kernel" routes every query shape through
-        # the ragged kernel, which fuses the scale-page dequant into its
-        # page loop (ops/pallas_ragged.py).
-        if self.ragged_kv_splits < 0:
-            raise ValueError(
-                f"ragged_kv_splits must be >= 0 (0 = auto), got "
-                f"{self.ragged_kv_splits}"
             )
         if self.activation not in _ACTIVATIONS:
             raise ValueError(f"activation must be one of {_ACTIVATIONS}, got {self.activation!r}")
@@ -579,13 +538,6 @@ class ModelConfig:
                 )
             if self.n_kv_heads not in (None, self.n_heads) or self.qkv_bias:
                 raise ValueError("latent attention has no grouped KV heads and no QKV bias")
-            if self.paged_attention_impl != "gather":
-                raise ValueError(
-                    "paged_attention_impl picks among the per-head K/V forms; a latent pool "
-                    "picks its own decode form from its input (models/mla.py::decode_form: "
-                    "ops/pallas_latent.py for a few queries a row on a TPU, the gather form "
-                    "otherwise), so leave it at 'gather'"
-                )
         if self.rope_scaling not in ("none", "yarn"):
             raise ValueError(f"rope_scaling must be 'none' or 'yarn', got {self.rope_scaling!r}")
         if self.rope_scaling == "yarn" and (self.rope_factor < 1 or self.rope_original_context < 1):

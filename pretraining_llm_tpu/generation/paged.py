@@ -626,9 +626,9 @@ def _suffix_prefill_sample(
 
     n_rows = suffix.shape[0]
     with activation_mesh(mesh):
-        # q_lens rides along for the kernel attention path only (ragged
-        # per-row DMA elision on TPU); the gather path ignores it, so CPU
-        # outputs are bit-identical with or without it.
+        # q_lens rides along for the state-slot layers (models/recurrent.py:
+        # a pad query leaves the row's state alone); attention ignores it, so
+        # its outputs are bit-identical with or without it.
         logits, pools = transformer.forward(
             params, suffix, cfg, kv_cache=pools,
             paged=PagedInfo(block_tables, cached_lens, q_lens=suffix_lens, slots=slots),

@@ -787,7 +787,7 @@ class ServingEngine:
             # latent_dim elements a layer for a latent pool
             "bytes_per_token": total // (self.n_blocks * self.block_size),
             "pool_bytes": total + window,
-            # "gather" | "kernel" | "ragged" (per head), "gather" | "latent_kernel" (latent)
+            # "gather" | "kernel" (per head), "gather" | "latent_kernel" (latent)
             "decode_attention": self.decode_attention,
         }
         if self.decode_experts:

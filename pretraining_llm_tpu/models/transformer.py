@@ -79,10 +79,10 @@ class PagedInfo(NamedTuple):
     # Ragged multi-token calls (chunked prefill): row b's TRUE query count
     # (<= T); queries past it are padding whose outputs the caller
     # discards. None = uniform (every row carries all T queries — decode
-    # steps and the speculative verify). Only the kernel attention path
-    # reads it (per-row DMA elision + pad-query masking); the gather path
-    # computes pad queries and lets the caller discard them, so outputs
-    # for REAL queries are bit-identical whether or not q_lens is passed.
+    # steps and the speculative verify). A state-slot layer reads it
+    # (models/recurrent.py: a pad query leaves the row's state alone);
+    # attention computes pad queries and lets the caller discard them, so
+    # outputs for REAL queries are bit-identical whether or not it is passed.
     q_lens: Optional[jax.Array] = None  # (B,) int32 or None
     # State-slot models (models/recurrent.py): the slot of the state pools each row
     # reads and writes. None = row b's own slot b, left alone while the row's
@@ -102,25 +102,19 @@ def paged_attention_form(
     cfg: ModelConfig,
     tq: int,
     quantized: bool,
-    ragged: bool = False,
     backend: Optional[str] = None,
     mesh: Any = None,
 ) -> str:
     """The form attention over a per-head page pool takes for ``tq`` queries
     a row: ``"kernel"`` (``ops/pallas_paged.py``: the row's live pages read in
-    place), ``"ragged"`` (``ops/pallas_ragged.py``: int8 pools, per-row query
-    counts) or ``"gather"`` (``pool[tables]`` and a masked einsum over every
-    slot the table names). Under ``paged_attention_impl="gather"``, the
-    default, it is read from the input as ``mla.decode_form`` reads a latent
-    pool's: the single-token decode step over an unquantized pool that no
-    serving mesh shards and whose pages are copies of their own takes the
-    kernel where Mosaic compiles, everything else (several queries a row,
-    int8 pools, a sharded pool, narrow or odd heads, every other backend) the
-    gather form. ``"kernel"`` forces the Pallas forms whatever the input and
-    the backend (interpreted off the TPU). The engine reports the decode
-    step's form in ``pool_info()``."""
-    if cfg.paged_attention_impl == "kernel":
-        return "ragged" if quantized or (tq > 1 and ragged) else "kernel"
+    place) or ``"gather"`` (``pool[tables]`` and a masked einsum over every
+    slot the table names). It is read from the input as ``mla.decode_form``
+    reads a latent pool's: the single-token decode step over an unquantized
+    pool that no serving mesh shards and whose pages are copies of their own
+    takes the kernel where Mosaic compiles, everything else (several queries a
+    row, int8 pools, a sharded pool, narrow or odd heads, every other backend)
+    the gather form. The engine reports the decode step's form in
+    ``pool_info()``."""
     from pretraining_llm_tpu.ops.pallas_paged import pages_copy_in_place
 
     if (
@@ -619,67 +613,24 @@ def _attention_core(
                     "v_pool": scatter(kv["v_pool"], v),
                 }
 
-        form = paged_attention_form(
-            cfg, tq, quantized, ragged=paged.q_lens is not None, mesh=current_mesh()
-        )
-        if form == "ragged":
-            # The ragged kernel owns what the other forms cannot take. int8
-            # pools: it fuses the per-(slot, head) dequant into its page
-            # loop — int8 bytes + scale pages are what crosses HBM, never a
-            # dequantized (B, kv_len) copy — for EVERY query shape (decode
-            # steps pass q_lens=1 per row, uniform multi-token verifies pass
-            # q_lens=tq), so the quantized graph has a single attention
-            # numerics path. Unquantized: the chunk lane, whose rows carry
-            # heterogeneous true query counts; it elides DMA past each
-            # row's OWN chunk end instead of scanning every row to the
-            # longest row's frontier.
-            from pretraining_llm_tpu.ops.pallas_ragged import (
-                ragged_paged_attention,
-            )
-
-            if tq > 1 and paged.q_lens is not None:
-                q_lens = paged.q_lens
-            else:
-                q_lens = jnp.full((bsz,), tq, dtype=seq.dtype)
-            if quantized:
-                k_in, v_in = new_kv["k_pool"], new_kv["v_pool"]
-                scales = {
-                    "k_scale": new_kv["k_scale_pool"],
-                    "v_scale": new_kv["v_scale_pool"],
-                }
-            else:
-                k_in, v_in = new_kv["k_pool"].astype(cdt), new_kv["v_pool"].astype(cdt)
-                scales = {}
-            with jax.named_scope("attn.core"):
-                out = ragged_paged_attention(
-                    q.astype(cdt), k_in, v_in, tables, seq, q_lens,
-                    window=window,
-                    kv_splits=cfg.ragged_kv_splits or None,
-                    amla=cfg.ragged_amla,
-                    **scales,
-                )
-        elif form == "kernel":
+        form = paged_attention_form(cfg, tq, quantized, mesh=current_mesh())
+        if form == "kernel":
             # Gather-free: the Pallas kernel copies each row's LIVE pages
             # straight from the pool through the block table
-            # (ops/pallas_paged.py), several a step of an in-row loop for
-            # the decode step; the slots of the table that hold nothing are
-            # never read. tq > 1 routes the multi-token form (the
-            # speculative verify's per-query frontiers live inside the
-            # kernel mask).
+            # (ops/pallas_paged.py), several a step of an in-row loop; the
+            # slots of the table that hold nothing are never read.
             from pretraining_llm_tpu.ops.pallas_paged import (
                 paged_decode_attention,
             )
 
-            qin = q[:, 0] if tq == 1 else q
             with jax.named_scope("attn.core"):
                 out = paged_decode_attention(
-                    qin.astype(cdt),
+                    q[:, 0].astype(cdt),
                     new_kv["k_pool"].astype(cdt),
                     new_kv["v_pool"].astype(cdt),
                     tables, seq, window=window,
                 )
-            if tq == 1:
-                out = out[:, None]
+            out = out[:, None]
         else:
             max_blocks = tables.shape[1]
             kv_len = max_blocks * block_size

@@ -1,4 +1,4 @@
-"""Pallas TPU paged-attention decode kernels (gather-free block tables).
+"""Pallas TPU paged-attention decode kernel (gather-free block tables).
 
 The XLA paged-decode path (models/transformer.py, the gather form) attends
 over ``pool[tables]``: every slot the block table names, ``max_blocks x
@@ -6,14 +6,13 @@ block_size`` of them a row, whatever the row holds and whether the row is in
 use, and masks. On a v5e XLA keeps the gathered copy in VMEM while it fits
 (one HBM pass over every tabled slot) and writes it to HBM and reads it back
 when it does not (three): what costs is the slots, and past some table width
-the copies too (PERF.md section 6, PR 30). The kernels here read pool pages
+the copies too (PERF.md section 6, PR 30). The kernel here reads pool pages
 through the block table instead, and only pages that hold a visible slot --
 vLLM's PagedAttention memory model (SURVEY section 2.2).
 
-Two forms behind one entry point, ``paged_decode_attention``:
-
-**One query a row** (the serving decode step), ``_decode_kernel``, after
-``ops/pallas_latent.py``:
+One form, ``paged_decode_attention``: one query a row (the serving decode
+step) over a pool whose pages are copies of their own
+(``pages_copy_in_place``), ``_decode_kernel``, after ``ops/pallas_latent.py``:
 
   - Grid ``(rows,)``; both pools stay in HBM (``memory_space=ANY``), block
     table and ``seq_lens`` are scalar prefetch. Inside a row a ``fori_loop``
@@ -35,26 +34,16 @@ Two forms behind one entry point, ``paged_decode_attention``:
     written once a row. ``window=`` keeps its meaning: pages wholly below the
     window are not copied.
 
-**Several queries a row** (the speculative verify; ``q`` of 4 dims; also one
-query a row over a pool whose pages are no copy of their own, see
-``pages_copy_in_place``), ``_paged_kernel``: grid (batch, max_blocks), one
-page a grid step through BlockSpec index maps, accumulator and softmax
-statistics in VMEM scratch across the block steps (the revisiting schedule of
-ops/pallas_flash.py). Dead
-table entries are 0 = the scratch block: consecutive identical block indices
-elide their DMA and ``pl.when`` skips their compute. One page a grid step is
-12-16 k grid steps a decode step at serving sizes, which is why the
-single-query form does not take this shape.
-
-Both: mask, finite NEG_INF and safe division are the same (slot ``seq + t``
-holds query t's own token and is visible; a row with no visible slot gives
+Mask, finite NEG_INF and safe division are the gather form's (slot ``seq``
+holds the query's own token and is visible; a row with no visible slot gives
 zeros); any block of the pool holds finite values. Forward only (decode never
 differentiates).
 
-The model takes the single-query form by itself on a TPU
-(``models/transformer.py::paged_attention_form``); ``cfg.paged_attention_impl
-== "kernel"`` forces the Pallas forms everywhere (interpreted off the TPU).
-int8 pools go through ``ops/pallas_ragged.py``, which fuses the dequant.
+The model takes the kernel by itself where the input allows it
+(``models/transformer.py::paged_attention_form``); several queries a row, int8
+pools, narrow or odd heads and a serving mesh keep the gather form.
+``gather_attention`` below is that form as a plain function: the reference
+the kernel's tests and ``scripts/chip_kernels.py`` compare against.
 """
 
 from __future__ import annotations
@@ -259,141 +248,12 @@ def _decode_call(q, k_pool, v_pool, block_tables, seq_lens, window, pages, inter
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), q, flat(k_pool), flat(v_pool))
 
 
-def _paged_kernel(
-    tbl_ref,  # (B, nb) int32 scalar-prefetch (SMEM)
-    seq_ref,  # (B,) int32 scalar-prefetch (SMEM)
-    q_ref,  # (1, H*T, Dh) — heads-major fold, query t at row h*T + t
-    k_ref,  # (1, bs, G, Dh) — the page tbl[b, j]
-    v_ref,  # (1, bs, G, Dh)
-    o_ref,  # (1, H*T, Dh)
-    acc,  # VMEM (H*T, Dh) f32
-    m_scr,  # VMEM (H*T, 1) f32
-    l_scr,  # VMEM (H*T, 1) f32
-    *,
-    bs: int,
-    nb: int,
-    g: int,
-    n_rep: int,
-    t: int,
-    scale: float,
-    window: int,
-):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-
-    seq = seq_ref[b]
-    # Block liveness: any linear slot in [j*bs, j*bs+bs) visible to any
-    # of the T queries — query t's frontier is seq + t (slot seq + t
-    # holds its just-written token: inclusive, exactly the gather path's
-    # per-query mask). Sliding window kills blocks entirely below the
-    # OLDEST query's window.
-    run = j * bs <= seq + (t - 1)
-    if window:
-        run = jnp.logical_and(run, j * bs + bs - 1 > seq - window)
-
-    @pl.when(run)
-    def _compute():
-        rows = n_rep * t
-        # Per-row frontier: row r within a group is query (r % t) of head
-        # (r // t) — the heads-major fold keeps each GQA group's rows
-        # contiguous so the static slice below works, at the price of
-        # this tiny modulo iota.
-        t_of_row = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) % t
-        lin = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
-        valid = lin <= seq + t_of_row  # (n_rep*T, bs)
-        if window:
-            valid = jnp.logical_and(valid, lin > seq + t_of_row - window)
-        q = q_ref[0]  # (H*T, Dh)
-        k = k_ref[0]  # (bs, G, Dh)
-        v = v_ref[0]
-        for grp in range(g):
-            sl = slice(grp * rows, (grp + 1) * rows)
-            qg = q[sl]  # (n_rep*T, Dh)
-            kg = k[:, grp]  # (bs, Dh)
-            vg = v[:, grp]
-            s = jax.lax.dot_general(
-                qg, kg, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # (n_rep*T, bs)
-            s = jnp.where(valid, s, NEG_INF)
-            m_prev = m_scr[sl]  # (n_rep*T, 1)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            # A fully-masked row keeps m == NEG_INF -> exp(s-m)=1 for
-            # masked entries; zero by the mask itself (flash kernel
-            # discipline).
-            p = jnp.where(valid, p, 0.0)
-            l_scr[sl] = l_scr[sl] * alpha + jnp.sum(
-                p, axis=-1, keepdims=True
-            )
-            m_scr[sl] = m_new
-            pv = jax.lax.dot_general(
-                p.astype(vg.dtype), vg, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            acc[sl] = acc[sl] * alpha + pv
-
-    @pl.when(j == nb - 1)
-    def _finalize():
-        l = l_scr[:]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc[:] / safe_l).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("t", "window", "interpret"))
-def _paged_call(q, k_pool, v_pool, block_tables, seq_lens, t, window,
-                interpret):
-    b, ht, d = q.shape  # ht == H * T, heads-major fold
-    n_blocks, bs, g, _ = k_pool.shape
-    nb = block_tables.shape[1]
-    n_rep = ht // (g * t)
-    kernel = functools.partial(
-        _paged_kernel, bs=bs, nb=nb, g=g, n_rep=n_rep, t=t,
-        scale=1.0 / (d**0.5), window=window,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nb),
-        in_specs=[
-            pl.BlockSpec((1, ht, d), lambda bb, j, tbl, seq: (bb, 0, 0)),
-            pl.BlockSpec(
-                (1, bs, g, d),
-                lambda bb, j, tbl, seq: (tbl[bb, j], 0, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, bs, g, d),
-                lambda bb, j, tbl, seq: (tbl[bb, j], 0, 0, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec((1, ht, d), lambda bb, j, tbl, seq: (bb, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((ht, d), jnp.float32),
-            pltpu.VMEM((ht, 1), jnp.float32),
-            pltpu.VMEM((ht, 1), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, ht, d), q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q, k_pool, v_pool)
-
-
 def paged_decode_attention(
-    q: jax.Array,  # (B, H, Dh) or (B, T, H, Dh) — T queries per row
+    q: jax.Array,  # (B, H, Dh): one query a row
     k_pool: jax.Array,  # (n_blocks, block_size, G, Dh)
     v_pool: jax.Array,
     block_tables: jax.Array,  # (B, max_blocks) int32, 0-padded tails
-    seq_lens: jax.Array,  # (B,) int32 — slot seq_len + t holds query t's K/V
+    seq_lens: jax.Array,  # (B,) int32 — slot seq_len holds the query's own K/V
     *,
     window: int = 0,
     pages_per_step: int = PAGES_PER_STEP,
@@ -401,31 +261,24 @@ def paged_decode_attention(
 ) -> jax.Array:
     """Paged decode attention straight off the block pool.
 
-    (B, H, Dh) is the serving decode step (one query per row): each row's
-    live pages are copied from the pools in place, ``pages_per_step`` a step
-    of an in-row loop (where ``pages_copy_in_place``; the form below at one
-    query otherwise). A 4-dim (B, T, H, Dh) q is the multi-token form (the
-    speculative verify), one page a grid step: query t sits at logical slot
-    seq + t and sees slots <= seq + t — exactly the gather path's per-query
-    frontier masks. Returns q's shape. Numerics match the gather path to
-    accumulation-order tolerance; what is saved is every slot of the table
-    that holds nothing visible. `interpret=None` auto-selects: compiled on
-    TPU, interpreter elsewhere (tests).
+    The serving decode step, one query a row: each row's live pages are
+    copied from the pools in place, ``pages_per_step`` a step of an in-row
+    loop. Returns q's shape. Numerics match the gather form
+    (``gather_attention``) to accumulation-order tolerance; what is saved is
+    every slot of the table that holds nothing visible. Several queries a
+    row and a pool whose pages are no copy of their own
+    (``pages_copy_in_place``) are the gather form's and raise here.
+    `interpret=None` auto-selects: compiled on TPU, interpreter elsewhere
+    (tests).
     """
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
-    multi = q.ndim == 4
-    if multi:
-        b, t, h, d = q.shape
-        # Heads-major fold (H*T rows, query t of head h at row h*T + t):
-        # keeps each GQA group's rows CONTIGUOUS so the kernel's static
-        # group slices work; the transpose is B*T*H*D elements (tiny at
-        # decode shapes).
-        qf = q.transpose(0, 2, 1, 3).reshape(b, h * t, d)
-    else:
-        b, h, d = q.shape
-        t = 1
-        qf = q
+    if q.ndim != 3:
+        raise ValueError(
+            f"the kernel takes one query a row, q of (B, H, Dh), got {q.shape}: "
+            "several queries a row take the gather form"
+        )
+    b, h, d = q.shape
     g = k_pool.shape[2]
     if h % g != 0:
         raise ValueError(f"kv heads ({g}) must divide query heads ({h})")
@@ -436,15 +289,74 @@ def paged_decode_attention(
             f"tables {block_tables.shape} / seq_lens {seq_lens.shape} do not "
             f"match batch {b}"
         )
-    if not multi and pages_copy_in_place(g, d):
-        return _decode_call(
-            q, k_pool, v_pool, block_tables, seq_lens, int(window),
-            _pages_a_step(k_pool, int(pages_per_step)), bool(interpret),
+    if not pages_copy_in_place(g, d):
+        raise ValueError(
+            f"a page of {g} kv heads of {d} is no copy of its own (pages_copy_in_place: "
+            "heads of whole 128-lane tiles, kv heads that fill or divide 8): "
+            "such a pool takes the gather form"
         )
-    out = _paged_call(
-        qf, k_pool, v_pool, block_tables, seq_lens, t, int(window),
-        bool(interpret),
+    return _decode_call(
+        q, k_pool, v_pool, block_tables, seq_lens, int(window),
+        _pages_a_step(k_pool, int(pages_per_step)), bool(interpret),
     )
-    if multi:
-        return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
-    return out
+
+
+def gather_attention(
+    q: jax.Array,  # (B, T, H, Dh)
+    k_pool: jax.Array,
+    v_pool: jax.Array,
+    block_tables: jax.Array,
+    seq_lens: jax.Array,
+    q_lens: jax.Array,
+    *,
+    window: int = 0,
+    k_scale: Optional[jax.Array] = None,
+    v_scale: Optional[jax.Array] = None,
+) -> jax.Array:
+    """The reference of paged attention in plain XLA: materialize
+    ``pool[tables]`` and run the per-query masked softmax in float32 -- the
+    model's gather branch math with a validity term for per-row query counts.
+    Query t of row b sits at slot ``seq_lens[b] + t`` and sees slots up to its
+    own (inside ``window``, if any); pad queries (``t >= q_lens[b]``) return
+    zeros, as a row with no visible slot does in the kernel.
+    ``k_scale``/``v_scale`` are an int8 pool's scale pages: dequantized after
+    the gather."""
+    b, t, h, d = q.shape
+    g = k_pool.shape[2]
+    n_rep = h // g
+    bs = k_pool.shape[1]
+    kv_len = block_tables.shape[1] * bs
+    ck = k_pool[block_tables].reshape(b, kv_len, g, d)
+    cv = v_pool[block_tables].reshape(b, kv_len, g, d)
+    if k_scale is not None:
+        cks = k_scale[block_tables].reshape(b, kv_len, g, 1)
+        cvs = v_scale[block_tables].reshape(b, kv_len, g, 1)
+        ck = ck.astype(jnp.float32) * (
+            cks.astype(jnp.float32) * (1.0 / 127.0)
+        )
+        cv = cv.astype(jnp.float32) * (
+            cvs.astype(jnp.float32) * (1.0 / 127.0)
+        )
+    if n_rep > 1:
+        ck = jnp.repeat(ck, n_rep, axis=2)
+        cv = jnp.repeat(cv, n_rep, axis=2)
+    lin = jnp.arange(kv_len)
+    pos = seq_lens[:, None] + jnp.arange(t)[None, :]  # (B, T)
+    mask = lin[None, None, :] <= pos[:, :, None]  # (B, T, kv_len)
+    if window:
+        mask = mask & (lin[None, None, :] > pos[:, :, None] - window)
+    qvalid = jnp.arange(t)[None, :] < q_lens[:, None]  # (B, T)
+    mask = mask & qvalid[:, :, None]
+    s = jnp.einsum(
+        "bthd,bkhd->bthk", q.astype(jnp.float32), ck.astype(jnp.float32)
+    ) / (d**0.5)
+    s = jnp.where(mask[:, :, None, :], s, NEG_INF)
+    # Pad queries are fully masked: a plain softmax would spread 1/kv_len
+    # everywhere; zero them like the kernel's safe-l division does.
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    p = jnp.where(mask[:, :, None, :], p, 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    p = p / jnp.where(l == 0.0, 1.0, l)
+    out = jnp.einsum("bthk,bkhd->bthd", p, cv.astype(jnp.float32))
+    return out.astype(q.dtype)

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Compile and run every Pallas kernel on the TPU against its XLA reference,
-at the sizes serving and training use (attention: Dh 64, 12 heads, bf16; the
-paged decode step also at 32 heads of 128; the expert FFN at three serving
+at the sizes serving and training use (flash attention: Dh 64, 12 heads, bf16;
+the paged decode step at 32 heads of 128; the expert FFN at three serving
 cells' expert shapes; the KDA step over the Ling cell's state pool).
 
 The CPU tests run these kernels in interpret mode at toy sizes; only the
@@ -84,85 +84,43 @@ def flash_case(t: int, g: int, seg: bool):
     return errs, 3e-2
 
 
-def _pool(key, g: int, page: int, int8: bool, dh: int = DH):
-    ks = jax.random.split(key, 4)
+def _pool(key, g: int, page: int, dh: int):
+    ks = jax.random.split(key, 2)
     shape = (N_BLOCKS, page, g, dh)
-    if not int8:
-        return (
-            jax.random.normal(ks[0], shape, jnp.bfloat16),
-            jax.random.normal(ks[1], shape, jnp.bfloat16),
-            None, None,
-        )
-    codes = [
-        jax.random.randint(k, shape, -127, 128, jnp.int32).astype(jnp.int8)
-        for k in ks[:2]
-    ]
-    scales = [
-        jax.random.uniform(k, shape[:-1] + (1,), jnp.float32, 0.5, 3.0).astype(jnp.bfloat16)
-        for k in ks[2:]
-    ]
-    return codes[0], codes[1], scales[0], scales[1]
+    return tuple(jax.random.normal(k, shape, jnp.bfloat16) for k in ks)
 
 
-def _tables(b: int, page: int, t: int, rng: np.random.Generator):
-    """Disjoint page lists per row, mixed committed lengths, 0-padded tails."""
+def _tables(b: int, page: int, rng: np.random.Generator):
+    """Disjoint page lists per row, mixed committed lengths (each with room
+    for the step's own token), 0-padded tails."""
     nb = CTX // page
-    seq = rng.integers(0, CTX - t, size=b).astype(np.int32)
+    seq = rng.integers(0, CTX - 1, size=b).astype(np.int32)
     seq[0] = 0  # a fresh row
-    seq[-1] = CTX - t  # a full row
+    seq[-1] = CTX - 1  # a full row
     tables = np.zeros((b, nb), np.int32)
     free = list(range(1, N_BLOCKS))
     rng.shuffle(free)
     for i in range(b):
-        need = -(-(int(seq[i]) + t) // page)
+        need = -(-(int(seq[i]) + 1) // page)
         need = min(need, len(free) // (b - i))
         tables[i, :need] = [free.pop() for _ in range(need)]
-        seq[i] = min(int(seq[i]), need * page - t)
+        seq[i] = min(int(seq[i]), need * page - 1)
     return jnp.asarray(tables), jnp.asarray(seq)
 
 
-def paged_case(g: int, page: int, t: int, h: int = H, dh: int = DH):
-    """Heads of 64 run the one-page-a-grid-step form whatever ``t``; one query
-    a row over heads of 128 (the Mistral cells' layout) the form that copies a
-    row's live pages in place (``pallas_paged.pages_copy_in_place``)."""
-    from pretraining_llm_tpu.ops.pallas_paged import paged_decode_attention
-    from pretraining_llm_tpu.ops.pallas_ragged import ragged_gather_attention
+def paged_case(g: int, page: int, h: int, dh: int):
+    """One query a row over heads of 128 (the Mistral cells' layout): the
+    kernel that copies a row's live pages in place against the gather form."""
+    from pretraining_llm_tpu.ops.pallas_paged import gather_attention, paged_decode_attention
 
     b = 8
-    rng = np.random.default_rng(page + t)
-    k_pool, v_pool, _, _ = _pool(jax.random.key(1), g, page, False, dh)
-    tables, seq = _tables(b, page, t, rng)
-    q = jax.random.normal(jax.random.key(2), (b, t, h, dh), jnp.bfloat16)
-    got = paged_decode_attention(
-        q[:, 0] if t == 1 else q, k_pool, v_pool, tables, seq
-    )
-    if t == 1:
-        got = got[:, None]
-    want = jax.jit(ragged_gather_attention)(
-        q, k_pool, v_pool, tables, seq, jnp.full((b,), t, jnp.int32)
-    )
-    return {"o": _err(got, want)}, 3e-2
-
-
-def ragged_case(g: int, page: int, t: int, splits, amla: bool, int8: bool):
-    from pretraining_llm_tpu.ops.pallas_ragged import (
-        ragged_gather_attention, ragged_paged_attention,
-    )
-
-    b = 8
-    rng = np.random.default_rng(page + t)
-    k_pool, v_pool, k_scale, v_scale = _pool(jax.random.key(3), g, page, int8)
-    tables, seq = _tables(b, page, t, rng)
-    # Decode rows (1 query) ride with chunk rows of every length up to T.
-    q_lens = jnp.asarray(np.minimum(t, rng.integers(1, t + 1, size=b)).astype(np.int32))
-    q_lens = q_lens.at[0].set(t).at[1].set(1)
-    q = jax.random.normal(jax.random.key(4), (b, t, H, DH), jnp.bfloat16)
-    kw = dict(k_scale=k_scale, v_scale=v_scale)
-    got = ragged_paged_attention(
-        q, k_pool, v_pool, tables, seq, q_lens, kv_splits=splits, amla=amla, **kw
-    )
-    want = jax.jit(ragged_gather_attention)(
-        q, k_pool, v_pool, tables, seq, q_lens, **kw
+    rng = np.random.default_rng(page + 1)
+    k_pool, v_pool = _pool(jax.random.key(1), g, page, dh)
+    tables, seq = _tables(b, page, rng)
+    q = jax.random.normal(jax.random.key(2), (b, 1, h, dh), jnp.bfloat16)
+    got = paged_decode_attention(q[:, 0], k_pool, v_pool, tables, seq)[:, None]
+    want = jax.jit(gather_attention)(
+        q, k_pool, v_pool, tables, seq, jnp.ones((b,), jnp.int32)
     )
     return {"o": _err(got, want)}, 3e-2
 
@@ -333,27 +291,8 @@ def cases():
             for seg in (False, True):
                 yield f"flash t{t} g{g}" + (" seg" if seg else ""), flash_case, (t, g, seg)
     for page in (64, 16):
-        for g in (12, 4):
-            for t in (1, 4):  # decode / speculative verify
-                yield f"paged page{page} g{g} t{t}", paged_case, (g, page, t)
         for g in (8, 32):  # grouped and ungrouped heads of 128, in place
-            yield f"paged page{page} g{g} t1 h32 dh128", paged_case, (g, page, 1, 32, 128)
-    for page in (64, 16):
-        for g in (12, 4):
-            for t in (1, 128):  # decode-only launch / chunked-prefill launch
-                for splits, amla, int8 in (
-                    (1, False, False),
-                    (None, False, False),  # model.ragged_kv_splits=0: auto
-                    (4, False, False),
-                    (1, True, False),
-                    (1, False, True),
-                    (4, True, True),
-                ):
-                    name = (
-                        f"ragged page{page} g{g} t{t} splits{splits or 'auto'}"
-                        + (" amla" if amla else "") + (" int8" if int8 else "")
-                    )
-                    yield name, ragged_case, (g, page, t, splits, amla, int8)
+            yield f"paged page{page} g{g} t1 h32 dh128", paged_case, (g, page, 32, 128)
 
 
 def main() -> int:

@@ -1531,42 +1531,20 @@ print(f"live SLO smoke ok: {len(prompts)} requests, 0 alerts, "
       f"ttft_p99={fleet['ttft_s']['p99']:.3f}s, live reconciled")
 EOF
 
-# Ragged-kernel speed push gate: (a) the KV-split / AMLA kernel variants
-# must reproduce the gather reference on a small identity grid
-# (interpret mode — numerics, not speed), and (b) fused-vs-unfused
-# greedy decode must be bit-identical through the serving engine. Fast
-# versions of the exhaustive tier-1 grids, run on every smoke.
+# Fused-sampling gate: fused-vs-unfused greedy decode must be bit-identical
+# through the serving engine. A fast version of the tier-1 test, run on every
+# smoke.
 JAX_PLATFORMS=cpu python - <<'EOF'
 import dataclasses
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-
-from pretraining_llm_tpu.ops.pallas_ragged import (
-    ragged_gather_attention, ragged_paged_attention)
-
-rng = np.random.default_rng(0)
-b, t, h, g, d, bs, nb = 2, 4, 4, 2, 32, 8, 16
-q = jnp.asarray(rng.normal(size=(b, t, h, d)), jnp.float32)
-kp = jnp.asarray(rng.normal(size=(nb, bs, g, d)), jnp.float32)
-vp = jnp.asarray(rng.normal(size=(nb, bs, g, d)), jnp.float32)
-tbl = jnp.asarray(rng.integers(1, nb, size=(b, 4)), jnp.int32)
-seq = jnp.asarray([15, 17], jnp.int32)  # straddle the splits=2 edge (16)
-ql = jnp.asarray([1, t], jnp.int32)
-ref = ragged_gather_attention(q, kp, vp, tbl, seq, ql)
-for kv_splits, amla in [(1, False), (2, False), (2, True), (None, True)]:
-    out = ragged_paged_attention(
-        q, kp, vp, tbl, seq, ql, kv_splits=kv_splits, amla=amla)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), atol=2e-4 if amla else 2e-5,
-        err_msg=f"kv_splits={kv_splits} amla={amla}")
-print("ragged kernel identity ok: splits x amla match gather")
 
 from pretraining_llm_tpu.config import get_preset
 from pretraining_llm_tpu.generation.serving import ServingEngine
 from pretraining_llm_tpu.models import transformer
 
+rng = np.random.default_rng(0)
 cfg = dataclasses.replace(get_preset("tiny").model, compute_dtype="float32")
 params = transformer.init_params(cfg, jax.random.key(0))
 prompts = [rng.integers(0, cfg.vocab_size, size=n).tolist()

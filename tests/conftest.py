@@ -78,3 +78,20 @@ def kernel_forced(monkeypatch):
     jax.clear_caches()
     yield
     jax.clear_caches()
+
+
+@pytest.fixture
+def paged_kernel_forced(monkeypatch):
+    """``transformer.paged_attention_form`` answers as on a TPU, so the decode
+    step over a per-head pool whose pages are copies of their own (heads of
+    128) takes ``ops/pallas_paged.py`` (interpreted here); several queries a
+    row, int8 pools and narrow heads still take the gather form. Caches as
+    above."""
+    from pretraining_llm_tpu.models import transformer
+
+    monkeypatch.setattr(
+        transformer, "paged_attention_form", functools.partial(transformer.paged_attention_form, backend="tpu")
+    )
+    jax.clear_caches()
+    yield
+    jax.clear_caches()

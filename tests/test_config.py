@@ -5,7 +5,7 @@ import json
 
 import pytest
 
-from pretraining_llm_tpu.config import Config, MeshConfig, ModelConfig, get_preset, list_presets
+from pretraining_llm_tpu.config import _RETIRED_KEYS, Config, MeshConfig, ModelConfig, get_preset, list_presets
 
 
 def test_presets_exist():
@@ -81,9 +81,9 @@ def test_json_roundtrip():
     assert restored == cfg
 
 
-# What the parent of PR 29 wrote into every checkpoint's saved config, and
-# what a command line could still ask for: (key, value, the value named in
-# the error, or None where the key is dropped and the config loads).
+# What the parents of PR 29 and PR 47 wrote into every checkpoint's saved
+# config, and what a command line could still ask for: (key, value, the value
+# named in the error, or None where the key is dropped and the config loads).
 _RETIRED = [
     ("decode_cache_layout", "unstacked", None),
     ("decode_unroll_layers", False, None),
@@ -94,6 +94,13 @@ _RETIRED = [
     ("ce_impl", "fused", "'chunked'"),
     ("remat", "save_big", "'save_attn_res'"),
     ("remat", "save_qkv_attn", "'save_attn_res'"),
+    ("paged_attention_impl", "gather", None),
+    ("ragged_kv_splits", 1, None),
+    ("ragged_amla", False, None),
+    ("paged_attention_impl", "kernel", "'gather'"),
+    ("ragged_kv_splits", 0, "=1"),
+    ("ragged_kv_splits", 4, "=1"),
+    ("ragged_amla", True, "False"),
 ]
 
 
@@ -104,7 +111,8 @@ def test_retired_model_keys(key, value, use):
     refused by name with the value to use."""
     saved = json.loads(Config().to_json())
     assert key not in saved["model"] or use is not None  # the field is gone
-    saved["model"].update(decode_cache_layout="unstacked", decode_unroll_layers=False, scan_unroll=1)
+    saved["model"].update(decode_cache_layout="unstacked", decode_unroll_layers=False, scan_unroll=1,
+                          paged_attention_impl="gather", ragged_kv_splits=1, ragged_amla=False)
     saved["model"][key] = value
     if use is None:
         assert Config.from_json(json.dumps(saved)) == Config()
@@ -114,7 +122,8 @@ def test_retired_model_keys(key, value, use):
         lambda: Config.from_json(json.dumps(saved)),
         lambda: Config().with_overrides({f"model.{key}": value}),
     ):
-        with pytest.raises(ValueError, match=rf"model\.{key}={value!r}.*removed in PR 29") as e:
+        removed_in = _RETIRED_KEYS[f"model.{key}"][2]
+        with pytest.raises(ValueError, match=rf"model\.{key}={value!r}.*removed in {removed_in}") as e:
             load()
         assert use in str(e.value)
 

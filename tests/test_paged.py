@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from pretraining_llm_tpu.config import get_preset
+from pretraining_llm_tpu.config import ModelConfig, get_preset
 from pretraining_llm_tpu.generation import paged
 from pretraining_llm_tpu.generation.generate import generate
 from pretraining_llm_tpu.generation.serving import ServingEngine
@@ -459,56 +459,198 @@ def test_engine_pipelined_max_new_one(params):
         assert out[rid] == _reference_greedy(params, CFG, p, 1)
 
 
-def test_paged_kernel_engine_matches_generate(params):
-    """paged_attention_impl='kernel' (Pallas block-table kernel, interpret
-    mode on CPU) must emit the same greedy tokens as the gather path's
+# Heads of 128: a page is a copy of its own, so the decode step takes the
+# in-place kernel (ops/pallas_paged.py, interpreted here) wherever
+# ``paged_attention_form`` sees a TPU (conftest's ``paged_kernel_forced``).
+WIDE_CFG = dataclasses.replace(CFG, d_head=128)
+
+
+@pytest.fixture(scope="module")
+def wide_params():
+    return transformer.init_params(WIDE_CFG, jax.random.key(0))
+
+
+def test_decode_kernel_engine_matches_generate(wide_params, paged_kernel_forced):
+    """The decode step through the Pallas block-table kernel (interpret
+    mode on CPU) must emit the same greedy tokens as the dense-cache
     ground truth — through fragmentation, mid-window finishes, and block
     reuse."""
-    cfgk = dataclasses.replace(CFG, paged_attention_impl="kernel")
     prompts = _prompts(4)
     n_new = 8
     eng = ServingEngine(
-        params, cfgk, max_batch=2, n_blocks=24, block_size=8,
+        wide_params, WIDE_CFG, max_batch=2, n_blocks=24, block_size=8,
         temperature=0.0, steps_per_sched=4,
     )
+    assert eng.decode_attention == "kernel"
     rids = [eng.submit(p, n_new) for p in prompts]
     out = eng.run()
     for rid, p in zip(rids, prompts):
-        assert out[rid] == _reference_greedy(params, CFG, p, n_new)
+        assert out[rid] == _reference_greedy(wide_params, WIDE_CFG, p, n_new)
 
 
-def test_paged_kernel_gqa_and_window(params):
+def test_decode_kernel_gqa_and_window(request):
     """Kernel path with GQA heads + sliding window == gather path, token
     for token."""
-    from pretraining_llm_tpu.models.transformer import init_params
-
-    cfg_g = dataclasses.replace(
-        CFG, n_heads=4, n_kv_heads=2, sliding_window=16
-    )
-    params_g = init_params(cfg_g, jax.random.key(1))
-    cfg_k = dataclasses.replace(cfg_g, paged_attention_impl="kernel")
+    cfg = dataclasses.replace(WIDE_CFG, n_heads=4, n_kv_heads=2, sliding_window=16)
+    params_g = transformer.init_params(cfg, jax.random.key(1))
     p = _prompts(1, lengths=(20,))[0]
     n_new = 10
     out = {}
-    for name, cfg in (("gather", cfg_g), ("kernel", cfg_k)):
+    for form in ("gather", "kernel"):
+        if form == "kernel":
+            request.getfixturevalue("paged_kernel_forced")
         eng = ServingEngine(
             params_g, cfg, max_batch=1, n_blocks=16, block_size=8,
             temperature=0.0,
         )
+        assert eng.decode_attention == form
         rid = eng.submit(p, n_new)
-        out[name] = eng.run()[rid]
+        out[form] = eng.run()[rid]
     assert out["kernel"] == out["gather"]
 
 
-def test_paged_kernel_config_validation():
-    from pretraining_llm_tpu.config import ModelConfig
+# -- the gather form against the reference of paged attention -------------------------------
 
-    with pytest.raises(ValueError, match="gather' or 'kernel"):
-        ModelConfig(paged_attention_impl="magic")
-    # kernel + int8 pools is a supported combination (the ragged kernel
-    # fuses the scale-page dequant into its page loop) — must construct.
-    cfg = ModelConfig(paged_attention_impl="kernel", kv_cache_dtype="int8")
-    assert cfg.kv_cache_dtype == "int8"
+
+@dataclasses.dataclass(frozen=True)
+class Gathered:
+    """One call of ``_attention_core`` over a per-head page pool: ``t``
+    queries a row, of which ``q_lens`` are real ("ragged": one to ``t`` a
+    row, "mixed": decode rows beside chunk rows, "uniform": all ``t``, or
+    the counts), at ``seq`` committed tokens a row (None: random, with room
+    for the row's queries) over fragmented tables."""
+
+    g: int = 2
+    t: int = 6
+    window: int = 0
+    b: int = 3
+    h: int = 8
+    bs: int = 8
+    max_blocks: int = 5
+    q_lens: object = "ragged"
+    seq: object = None
+    pool: str = "float32"  # the pages' dtype; "int8": codes under float32 scales, "int8-bf16": bfloat16 ones
+
+
+_EDGES = {
+    # the last live slot one below, on and one above the first slot of the second page
+    f"page-16-last-slot-{edge}-window-{window}-{pool}-{kind}": Gathered(
+        b=2, t=4, bs=16, max_blocks=3, window=window, pool=pool,
+        q_lens=(n, n), seq=(edge - n + 1,) * 2,
+    )
+    for edge in (15, 16, 17) for window in (0, 12) for pool in ("float32", "int8")
+    for kind, n in (("decode", 1), ("chunk", 4))
+}
+GATHERED = {
+    **{f"ragged-g{g}-window-{w}": Gathered(g=g, window=w) for g, w in ((8, 0), (2, 0), (4, 12), (1, 0))},
+    **{f"decode-beside-chunk-rows-g{g}": Gathered(g=g, b=4, t=8, max_blocks=6, q_lens="mixed") for g in (4, 2)},
+    "pad-queries-and-a-row-of-none": Gathered(h=4, t=4, max_blocks=2, q_lens=(2, 4, 0), seq=(0, 8, 3)),
+    "bf16": Gathered(b=2, t=5, h=4, max_blocks=3, q_lens="mixed", pool="bfloat16"),
+    **{
+        f"{pool}-g{g}-window-{w}": Gathered(g=g, window=w, pool=pool)
+        for pool in ("int8", "int8-bf16") for g, w in ((8, 0), (2, 0), (4, 12), (1, 0))
+    },
+    "uniform-batch": Gathered(g=4, b=2, t=4, q_lens="uniform"),
+    **{
+        f"several-queries-a-row-g{g}-t{t}-window-{w}": Gathered(g=g, b=2, t=t, window=w, q_lens="uniform")
+        for g, t, w in ((4, 5, 0), (2, 3, 0), (4, 4, 12))
+    },
+    **_EDGES,
+}
+
+
+def _quantize_pool(x):
+    """The engine's page convention (``transformer._kv_quantize``): int8
+    codes under a per-(slot, head) amax scale over the channel dim."""
+    scale = np.maximum(np.abs(x).max(axis=-1, keepdims=True), 1e-8)
+    return np.round(x / scale * 127.0).astype(np.int8), scale.astype(np.float32)
+
+
+@pytest.mark.parametrize("name", GATHERED)
+def test_gather_form_matches_the_reference(name):
+    """What the model computes for several queries a row, per-row query
+    counts and int8 pages is ``ops.pallas_paged.gather_attention`` over the
+    pool it hands back, and that pool holds every real token's K/V at the
+    slot its row's table names."""
+    from pretraining_llm_tpu.models import layers
+    from pretraining_llm_tpu.ops.pallas_paged import gather_attention
+
+    case = GATHERED[name]
+    b, t, h, g, bs, d, d_model = case.b, case.t, case.h, case.g, case.bs, 16, 16 * case.h
+    quantized = case.pool.startswith("int8")
+    cdt = "float32" if quantized else case.pool
+    cfg = ModelConfig(
+        vocab_size=64, context_length=64, d_model=d_model, n_heads=h, n_kv_heads=g, d_head=d, n_layers=1,
+        pos_embed="none", use_output_proj=False, compute_dtype=cdt, kv_cache_dtype="int8" if quantized else "compute",
+    )
+    rng = np.random.default_rng(list(GATHERED).index(name))
+    normal = lambda *shape, std=1.0: (std * rng.normal(size=shape)).astype(np.float32)
+
+    # fragmented tables, a count of real queries a row, and room for them behind ``seq``
+    n_blocks = 1 + b * case.max_blocks
+    free = rng.permutation(np.arange(1, n_blocks)).tolist()
+    tables = np.zeros((b, case.max_blocks), np.int32)
+    if isinstance(case.q_lens, tuple):
+        q_lens = np.asarray(case.q_lens, np.int32)
+    elif case.q_lens == "mixed":
+        q_lens = np.asarray([1 if i % 2 == 0 else rng.integers(2, t + 1) for i in range(b)], np.int32)
+    else:
+        q_lens = np.full((b,), t, np.int32) if case.q_lens == "uniform" else rng.integers(1, t + 1, b).astype(np.int32)
+    seq = np.zeros((b,), np.int32)
+    for i in range(b):
+        least = 1 if case.seq is None else (case.seq[i] + max(int(q_lens[i]), 1) - 1) // bs + 1
+        own = int(rng.integers(least, case.max_blocks + 1))
+        tables[i, :own] = [free.pop() for _ in range(own)]
+        seq[i] = rng.integers(0, own * bs - t + 1) if case.seq is None else case.seq[i]
+
+    blk = jax.tree.map(lambda a: a[0], transformer.init_params(cfg, jax.random.key(0))["blocks"])
+    blk["attn"] = {name: jnp.asarray(normal(*w.shape, std=2 / d_model ** 0.5)) for name, w in blk["attn"].items()}
+    kv = {}
+    for name in ("k_pool", "v_pool"):
+        pages = normal(n_blocks, bs, g, d, std=2.0 if name == "k_pool" else 1.0)
+        if quantized:
+            pages, scale = _quantize_pool(pages)
+            kv[name.replace("_pool", "_scale_pool")] = jnp.asarray(
+                scale, jnp.bfloat16 if case.pool == "int8-bf16" else jnp.float32
+            )
+        kv[name] = jnp.asarray(pages, jnp.int8 if quantized else cdt)
+    x = jnp.asarray(normal(b, t, d_model), cdt)
+    info = transformer.PagedInfo(jnp.asarray(tables), jnp.asarray(seq), q_lens=jnp.asarray(q_lens))
+
+    out, new_kv = jax.jit(
+        lambda x, kv, info: transformer._attention_core(
+            blk, x, cfg, None, jnp.arange(t), kv, None, False, None, None, info, False, case.window
+        )
+    )(x, kv, info)
+
+    # the layer's own projections, its three lines
+    hidden = layers.apply_norm(cfg.norm, blk["ln1"], x, cfg.norm_eps).astype(cdt)
+    project = lambda spec, w: jnp.einsum(spec, hidden, w.astype(cdt), preferred_element_type=jnp.float32).astype(cdt)
+    if "wqkv" in blk["attn"]:
+        q, k, _ = project("btd,dchn->cbthn", blk["attn"]["wqkv"])
+    else:
+        q, k = project("btd,dhn->bthn", blk["attn"]["wq"]), project("btd,dcgn->cbtgn", blk["attn"]["wkv"])[0]
+
+    scales = {"k_scale": new_kv["k_scale_pool"], "v_scale": new_kv["v_scale_pool"]} if quantized else {}
+    want = gather_attention(q, new_kv["k_pool"], new_kv["v_pool"], info.block_tables, info.seq_lens, info.q_lens,
+                            window=case.window, **scales)
+    real = (np.arange(t)[None, :] < q_lens[:, None])[:, :, None, None]
+    got = np.where(real, np.asarray(out, np.float32).reshape(b, t, h, d), 0.0)
+    # bfloat16 results differ by an ulp of the output: 2 ** -8 of its size
+    atol, rtol = (3e-2, 1e-2) if case.pool == "bfloat16" else (2e-5, 1e-7)
+    np.testing.assert_allclose(got, np.asarray(want, np.float32), atol=atol, rtol=rtol)
+    assert np.all(np.isfinite(np.asarray(out, np.float32)))
+
+    # every real token's key lies where its row's table says
+    k_pages = np.asarray(new_kv["k_pool"], np.float32)
+    if quantized:
+        k_pages = k_pages * np.asarray(new_kv["k_scale_pool"], np.float32) / 127.0
+    for i in range(b):
+        for j in range(int(q_lens[i])):
+            slot = int(seq[i]) + j
+            page = k_pages[tables[i, slot // bs], slot % bs]
+            err = np.abs(page - np.asarray(k[i, j], np.float32)).max()
+            assert err <= (0.05 if quantized else 1e-6) * max(1.0, np.abs(page).max()), (i, j, err)
 
 
 DRAFT_CFG = dataclasses.replace(CFG, n_layers=1, d_model=16, n_heads=2)
@@ -575,19 +717,20 @@ def test_spec_serving_preemption_and_stop(params, draft_params):
         assert out[rid] == want, f"request {rid}"
 
 
-def test_spec_serving_kernel_path_matches_generate(params, draft_params):
-    """Speculative serving with paged_attention_impl='kernel': the draft
-    steps run the single-token kernel, the verify runs the multi-token
-    kernel — greedy output must still equal dense-cache target-only
-    decoding."""
-    cfgk = dataclasses.replace(CFG, paged_attention_impl="kernel")
-    draft_k = dataclasses.replace(DRAFT_CFG, paged_attention_impl="kernel")
+def test_spec_serving_kernel_path_matches_generate(params, paged_kernel_forced):
+    """Speculative serving where the form says kernel: the draft's steps
+    (one query a row, heads of 128) run the in-place kernel, the verify's
+    k + 1 queries a row the gather form — greedy output must still equal
+    dense-cache target-only decoding."""
+    draft_k = dataclasses.replace(DRAFT_CFG, d_head=128)
+    assert transformer.paged_attention_form(draft_k, 1, False) == "kernel"
+    assert transformer.paged_attention_form(CFG, 4, False) == "gather"
     prompts = _prompts(2)
     n_new = 8
     eng = ServingEngine(
-        params, cfgk, max_batch=2, n_blocks=32, block_size=8,
-        temperature=0.0, draft_params=draft_params, draft_cfg=draft_k,
-        spec_k=3,
+        params, CFG, max_batch=2, n_blocks=32, block_size=8,
+        temperature=0.0, draft_params=transformer.init_params(draft_k, jax.random.key(99)),
+        draft_cfg=draft_k, spec_k=3,
     )
     rids = [eng.submit(p, n_new) for p in prompts]
     out = eng.run()

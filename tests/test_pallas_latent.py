@@ -126,7 +126,7 @@ def test_kernel_bf16(t):
     got = through_the_kernel(q, q_rope, pool, rpool, tables, seq, 2)
     assert got.dtype == jnp.bfloat16
     want = gather_form(q, q_rope, pool, rpool, tables, seq)
-    # the tolerance of tests/test_pallas_paged.py::test_kernel_bf16
+    # the tolerance of the bfloat16 cases of tests/test_pallas_paged.py::test_decode_kernel_matches_gather
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=3e-2)
 
 

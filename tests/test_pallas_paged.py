@@ -1,14 +1,12 @@
-"""Pallas paged-attention kernels vs the gather+masked-softmax reference.
+"""The Pallas paged-attention kernel vs the gather+masked-softmax reference.
 
-The kernels (ops/pallas_paged.py) read pool pages directly through the
-scalar-prefetched block table; the reference materializes pool[tables]
-and runs a masked softmax — the two must agree to accumulation-order
-tolerance for every (GQA, window, dtype, fragmentation) combination.
-Interpret mode on CPU (same convention as test_pallas_flash). Heads of 64
-go through the one-page-a-grid-step form, heads of 128 with one query a
-row through the form that copies a row's live pages several a step; the
-at-size compile for a described v5e sits with the latent kernel's, in
-tests/test_pallas_latent.py.
+The kernel (ops/pallas_paged.py) reads pool pages directly through the
+scalar-prefetched block table; the reference (``gather_attention`` beside it)
+materializes pool[tables] and runs a masked softmax -- the two must agree to
+accumulation-order tolerance for every (GQA, window, dtype, fragmentation)
+combination. Interpret mode on CPU (same convention as test_pallas_flash),
+heads of 128 (a page is a copy of its own); the at-size compile for a
+described v5e sits with the latent kernel's, in tests/test_pallas_latent.py.
 """
 
 import dataclasses
@@ -22,130 +20,40 @@ from pretraining_llm_tpu.config import ModelConfig
 from pretraining_llm_tpu.generation.serving import ServingEngine
 from pretraining_llm_tpu.models import transformer
 from pretraining_llm_tpu.ops import pallas_paged
-from pretraining_llm_tpu.ops.pallas_paged import paged_decode_attention
-
-
-def _random_state(rng, b, n_blocks, max_blocks, bs):
-    """Fragmented tables: each row owns a random disjoint set of pages."""
-    perm = rng.permutation(np.arange(1, n_blocks)).tolist()
-    tables = np.zeros((b, max_blocks), np.int32)
-    seq = np.zeros((b,), np.int32)
-    for i in range(b):
-        n_pages = int(rng.integers(1, max_blocks + 1))
-        own = [perm.pop() for _ in range(n_pages)]
-        tables[i, : len(own)] = own
-        seq[i] = int(rng.integers(0, n_pages * bs))
-    return tables, seq
-
-
-def _gather_ref_multi(q, kp, vp, tables, seq, window):
-    """(B, T, H, D) reference: query t's frontier is seq + t. The single-
-    token reference below is the T=1 slice of this — ONE source of truth
-    for the mask/softmax numerics."""
-    b, t, h, d = q.shape
-    g = kp.shape[2]
-    n_rep = h // g
-    kv_len = tables.shape[1] * kp.shape[1]
-    ck = jnp.repeat(kp[tables].reshape(b, kv_len, g, d), n_rep, axis=2)
-    cv = jnp.repeat(vp[tables].reshape(b, kv_len, g, d), n_rep, axis=2)
-    lin = jnp.arange(kv_len)
-    pos = seq[:, None] + jnp.arange(t)[None, :]  # (B, T)
-    mask = lin[None, None, :] <= pos[:, :, None]  # (B, T, kv_len)
-    if window:
-        mask = mask & (lin[None, None, :] > pos[:, :, None] - window)
-    s = jnp.einsum(
-        "bthd,bkhd->bthk", q.astype(jnp.float32), ck.astype(jnp.float32)
-    ) / np.sqrt(d)
-    s = jnp.where(mask[:, :, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bthk,bkhd->bthd", p, cv.astype(jnp.float32))
+from pretraining_llm_tpu.ops.pallas_paged import gather_attention, paged_decode_attention
 
 
 def _gather_ref(q, kp, vp, tables, seq, window):
-    return _gather_ref_multi(q[:, None], kp, vp, tables, seq, window)[:, 0]
-
-
-@pytest.mark.parametrize("g,window", [(8, 0), (2, 0), (4, 12), (1, 0)])
-def test_kernel_matches_gather(g, window):
-    rng = np.random.default_rng(g * 100 + window)
-    b, h, d, bs, n_blocks, max_blocks = 3, 8, 64, 8, 24, 5
-    q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_blocks, bs, g, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_blocks, bs, g, d)), jnp.float32)
-    tables, seq = _random_state(rng, b, n_blocks, max_blocks, bs)
-    out = paged_decode_attention(
-        q, kp, vp, jnp.asarray(tables), jnp.asarray(seq), window=window
-    )
-    ref = _gather_ref(q, kp, vp, tables, seq, window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-
-def test_kernel_bf16():
-    rng = np.random.default_rng(7)
-    b, h, g, d, bs, n_blocks, max_blocks = 2, 4, 2, 64, 8, 12, 3
-    q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.bfloat16)
-    kp = jnp.asarray(rng.normal(size=(n_blocks, bs, g, d)), jnp.bfloat16)
-    vp = jnp.asarray(rng.normal(size=(n_blocks, bs, g, d)), jnp.bfloat16)
-    tables, seq = _random_state(rng, b, n_blocks, max_blocks, bs)
-    out = paged_decode_attention(
-        q, kp, vp, jnp.asarray(tables), jnp.asarray(seq)
-    )
-    assert out.dtype == jnp.bfloat16
-    ref = _gather_ref(q, kp, vp, tables, seq, 0)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref), atol=3e-2
-    )
-
-
-def test_kernel_seq_zero_and_full():
-    """Edge rows: seq 0 (only the just-written slot visible) and a row at
-    its last slot."""
-    rng = np.random.default_rng(11)
-    b, h, g, d, bs, max_blocks = 2, 4, 4, 64, 8, 2
-    q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(8, bs, g, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(8, bs, g, d)), jnp.float32)
-    tables = np.asarray([[3, 0], [5, 6]], np.int32)
-    seq = np.asarray([0, 2 * bs - 1], np.int32)
-    out = paged_decode_attention(
-        q, kp, vp, jnp.asarray(tables), jnp.asarray(seq)
-    )
-    ref = _gather_ref(q, kp, vp, tables, seq, 0)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-
-@pytest.mark.parametrize("g,t,window", [(4, 5, 0), (2, 3, 0), (4, 4, 12)])
-def test_kernel_multitoken_matches_gather(g, t, window):
-    """The (B, T, H, D) form (speculative verify): per-query frontiers
-    seq+t inside the kernel mask == the gather path's 3D mask."""
-    rng = np.random.default_rng(g * 31 + t)
-    b, h, d, bs, n_blocks, max_blocks = 2, 8, 64, 8, 24, 5
-    q = jnp.asarray(rng.normal(size=(b, t, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_blocks, bs, g, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_blocks, bs, g, d)), jnp.float32)
-    tables, seq = _random_state(rng, b, n_blocks, max_blocks, bs)
-    # Keep every query's write slot within capacity (the engine's page
-    # horizon guarantees this in real use).
-    seq = np.minimum(seq, max_blocks * bs - t)
-    out = paged_decode_attention(
-        q, kp, vp, jnp.asarray(tables), jnp.asarray(seq), window=window
-    )
-    assert out.shape == (b, t, h, d)
-    ref = _gather_ref_multi(q, kp, vp, tables, seq, window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    ones = jnp.ones((q.shape[0],), jnp.int32)
+    return gather_attention(
+        q[:, None], kp, vp, jnp.asarray(tables), jnp.asarray(seq), ones, window=window
+    )[:, 0]
 
 
 def test_kernel_validation():
-    q = jnp.zeros((2, 4, 64))
-    kp = jnp.zeros((8, 8, 3, 64))
+    q = jnp.zeros((2, 4, 128))
+    kp = jnp.zeros((8, 8, 3, 128))
     with pytest.raises(ValueError, match="divide"):
         paged_decode_attention(
             q, kp, kp, jnp.zeros((2, 2), jnp.int32), jnp.zeros((2,), jnp.int32)
         )
-    kp = jnp.zeros((8, 8, 2, 64))
+    kp = jnp.zeros((8, 8, 2, 128))
     with pytest.raises(ValueError, match="batch"):
         paged_decode_attention(
             q, kp, kp, jnp.zeros((3, 2), jnp.int32), jnp.zeros((3,), jnp.int32)
+        )
+
+
+@pytest.mark.parametrize("q_shape,pool_shape", [
+    ((2, 3, 4, 128), (8, 8, 2, 128)),  # several queries a row
+    ((2, 4, 64), (8, 8, 2, 64)),  # heads half a lane tile wide
+    ((2, 6, 128), (8, 8, 3, 128)),  # kv heads that neither fill nor divide 8
+], ids=["several-queries-a-row", "heads-of-64", "three-kv-heads"])
+def test_kernel_refuses_what_the_gather_form_serves(q_shape, pool_shape):
+    kp = jnp.zeros(pool_shape)
+    with pytest.raises(ValueError, match="gather form"):
+        paged_decode_attention(
+            jnp.zeros(q_shape), kp, kp, jnp.zeros((2, 2), jnp.int32), jnp.zeros((2,), jnp.int32)
         )
 
 
@@ -187,6 +95,20 @@ DECODE_CASES = {
     "block-64": Rows(seq=(0, 63, 64, 200, None), bs=64, max_blocks=4, h=8, g=8),
     "block-64-the-cells-heads": Rows(seq=(130, 1), bs=64, max_blocks=3, h=32, g=8, window=100, pages=8),
     "bf16": Rows(seq=(45, 3, 20, None), dtype="bfloat16"),
+    # what the one-page-a-grid-step form's tests held, at a width this form takes
+    "heads-8-over-8": Rows(seq=(37, 8, 22), max_blocks=5, g=8),
+    "heads-8-over-2": Rows(seq=(37, 8, 22), max_blocks=5, g=2),
+    "heads-8-over-4-window-12": Rows(seq=(37, 8, 22), max_blocks=5, g=4, window=12),
+    "heads-8-over-1": Rows(seq=(37, 8, 22), max_blocks=5, g=1),
+    "heads-8-over-8-window-12": Rows(seq=(37, 8, 22), max_blocks=5, g=8, window=12),
+    "heads-8-over-2-window-12": Rows(seq=(37, 8, 22), max_blocks=5, g=2, window=12),
+    "heads-8-over-1-window-12": Rows(seq=(37, 8, 22), max_blocks=5, g=1, window=12),
+    "bf16-heads-4-over-2": Rows(seq=(13, 20), max_blocks=3, h=4, g=2, dtype="bfloat16"),
+    "seq-0-beside-a-full-table": Rows(seq=(0, 15), max_blocks=2, h=4, g=4),
+    **{
+        f"page-16-seq-{n}-window-{w}": Rows(seq=(n, 40), bs=16, max_blocks=3, window=w)
+        for n in (15, 16, 17) for w in (0, 12)
+    },
 }
 
 
@@ -271,22 +193,17 @@ MESH = object()  # any serving mesh: the pool may be sharded over it
     (1, False, "tpu", None, dataclasses.replace(WIDE, d_head=64), "gather"),  # a page is no copy of its own
     (1, False, "tpu", None, dataclasses.replace(WIDE, n_heads=6, n_kv_heads=3), "gather"),  # nor here
     (1, False, "tpu", None, dataclasses.replace(WIDE, n_heads=16, n_kv_heads=8), "kernel"),
-    # forced: the Pallas forms whatever the input and the backend
-    (1, False, "cpu", None, dataclasses.replace(WIDE, paged_attention_impl="kernel"), "kernel"),
-    (4, False, "cpu", MESH, dataclasses.replace(WIDE, paged_attention_impl="kernel"), "kernel"),
-    (1, True, "cpu", None, dataclasses.replace(WIDE, paged_attention_impl="kernel"), "ragged"),
+    (4, True, "tpu", None, WIDE, "gather"),  # int8 pages under several queries
+    (1, True, "cpu", None, WIDE, "gather"),
+    (2, False, "tpu", None, dataclasses.replace(WIDE, n_heads=16, n_kv_heads=8), "gather"),  # a round of k = 1
+    (1, False, "tpu", None, dataclasses.replace(WIDE, n_heads=12, n_kv_heads=12), "gather"),  # 12 kv heads: XLA
+    # would copy the pool into another layout
 ])
 def test_the_form_follows_the_input(tq, quantized, backend, mesh, cfg, form, monkeypatch):
     assert transformer.paged_attention_form(cfg, tq, quantized, backend=backend, mesh=mesh) == form
     # and without the argument, the backend is the process's own
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     assert transformer.paged_attention_form(cfg, tq, quantized, mesh=mesh) == form
-
-
-def test_ragged_query_counts_take_the_ragged_kernel_only_where_forced():
-    forced = dataclasses.replace(WIDE, paged_attention_impl="kernel")
-    assert transformer.paged_attention_form(forced, 4, False, ragged=True, backend="tpu") == "ragged"
-    assert transformer.paged_attention_form(WIDE, 4, False, ragged=True, backend="tpu") == "gather"
 
 
 @pytest.mark.parametrize("backend", ["cpu", "tpu"])
@@ -299,9 +216,8 @@ def test_the_engine_reports_the_form_of_its_decode_step(backend, monkeypatch):
     assert eng.decode_attention == eng.pool_info()["decode_attention"] == form
 
 
-def test_the_decode_program_takes_the_kernel_where_the_form_says_so(monkeypatch):
-    """The forced form and the form read from the input are one branch: the
-    decode step through the kernel gives the gather form's logits."""
+def test_the_decode_program_takes_the_kernel_where_the_form_says_so(request):
+    """The decode step through the kernel gives the gather form's logits."""
     from pretraining_llm_tpu.generation import paged
 
     cfg = dataclasses.replace(WIDE, compute_dtype="float32", n_heads=4, n_kv_heads=2, sliding_window=12)
@@ -312,10 +228,12 @@ def test_the_decode_program_takes_the_kernel_where_the_form_says_so(monkeypatch)
     tables = jnp.asarray([[3, 5, 7], [2, 0, 0], [0, 0, 0]], jnp.int32)
     seq, tok = jnp.asarray([20, 4, 0], jnp.int32), jnp.asarray([1, 2, 0], jnp.int32)
 
-    def logits(c):
+    def logits():
         copy = jax.tree.map(jnp.copy, pools)  # the program donates its pools
-        return np.asarray(paged.paged_decode_logits(params, copy, tok, tables, seq, cfg=c)[0])
+        return np.asarray(paged.paged_decode_logits(params, copy, tok, tables, seq, cfg=cfg)[0])
 
-    want = logits(cfg)
-    got = logits(dataclasses.replace(cfg, paged_attention_impl="kernel"))
-    np.testing.assert_allclose(got, want, atol=2e-4)
+    want = logits()
+    request.getfixturevalue("paged_kernel_forced")
+    program = jax.make_jaxpr(lambda p: paged.paged_decode_logits(params, p, tok, tables, seq, cfg=cfg))(pools)
+    assert "pallas_call" in str(program)
+    np.testing.assert_allclose(logits(), want, atol=2e-4)

@@ -63,8 +63,8 @@ def tokens(seed, n):
     return np.random.default_rng([seed % 2 ** 31, 9]).integers(0, CFG.vocab_size, n, dtype=np.int32)
 
 
-def reference(seed, toks, **control):
-    return np.asarray(serving_check.reference_forward(dict(ARCH, **control), seed)(np.asarray(toks)))
+def reference(seed, toks, arch=ARCH, **control):
+    return np.asarray(serving_check.reference_forward(dict(arch, **control), seed)(np.asarray(toks)))
 
 
 # -- 1. the full forward pass and the loss -------------------------------------------------
@@ -192,19 +192,26 @@ def test_two_lifetimes_get_two_pools():
 # -- 3. prefill and decode through both pools ---------------------------------------------------
 
 
-@pytest.mark.parametrize("impl", ["gather", "kernel"])
-def test_prefill_then_decode_through_both_pools_matches_the_reference(params, impl):
+@pytest.mark.parametrize("form", ["gather", "kernel"])
+def test_prefill_then_decode_through_both_pools_matches_the_reference(params, form, request):
     """Three rows at a batch of four: one inside the window, one that crosses
     it while decoding, one prefilled past it (into its last window pages
     only); 40 teacher-forced steps, so that every row gives pages back at
-    least twice and the freed pages are taken by the other rows."""
+    least twice and the freed pages are taken by the other rows. The decode
+    step's kernel reads the same two pools where the form says so: heads of
+    128 under a backend that answers "tpu" (conftest's ``paged_kernel_forced``)."""
     seed = SEEDS[0]
-    cfg = dataclasses.replace(CFG, paged_attention_impl=impl)
-    eng = ServingEngine(params[seed], cfg, max_batch=4, n_blocks=40, block_size=BS, max_seq=96)
+    arch, cfg, weights_ = ARCH, CFG, params[seed]
+    if form == "kernel":
+        arch = dict(ARCH, head_dim=128)
+        cfg, weights_ = program.model_config(arch, 128), weights.serving_params(arch, seed)
+        request.getfixturevalue("paged_kernel_forced")
+    eng = ServingEngine(weights_, cfg, max_batch=4, n_blocks=40, block_size=BS, max_seq=96)
+    assert eng.decode_attention == form
     sample = [(5, 40), (14, 40), (44, 40)]
     seqs = [tokens(seed + r, p + k) for r, (p, k) in enumerate(sample)]
-    prog, did = trinity_check.program_logits(params[seed], cfg, eng, sample, seqs)
-    ref = [reference(seed, toks)[p - 1 : p + k] for (p, k), toks in zip(sample, seqs)]
+    prog, did = trinity_check.program_logits(weights_, cfg, eng, sample, seqs)
+    ref = [reference(seed, toks, arch)[p - 1 : p + k] for (p, k), toks in zip(sample, seqs)]
     assert serving_check.rel_err(prog, ref) < LOGITS_TOL
     assert float(trinity_check.row_errors(prog, ref).max()) < LOGITS_TOL
     # rows 0 and 1 give back the pages of positions < 45 + 1 - 16 and < 54 + 1 - 16, row 2
@@ -212,7 +219,7 @@ def test_prefill_then_decode_through_both_pools_matches_the_reference(params, im
     assert did["window_pages_released"] == 12
     assert did["window_pages_held_most"] == WINDOW // BS + 1  # never the pool's + 2: one step a window here
     # the all-full control fails on every row past the window
-    far = reference(seed, seqs[2], control="all_full")[43:84]
+    far = reference(seed, seqs[2], arch, control="all_full")[43:84]
     assert rel_err(prog[2], far) > 0.05
 
 

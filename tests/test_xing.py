@@ -372,16 +372,10 @@ def test_engine_refuses_what_the_latent_pool_lacks(params, kw):
         ServingEngine(params[SEEDS[0]], CFG, max_batch=2, n_blocks=8, block_size=8, max_seq=32, **kw)
 
 
-@pytest.mark.parametrize("impl", ["gather", "kernel"])
-def test_a_per_head_engine_reports_the_form_it_was_configured_with(impl):
-    cfg = ModelConfig(vocab_size=64, context_length=32, d_model=16, n_heads=2, n_layers=1, paged_attention_impl=impl)
+def test_a_per_head_engine_reports_the_form_its_input_allows():
+    cfg = ModelConfig(vocab_size=64, context_length=32, d_model=16, n_heads=2, n_layers=1)
     eng = ServingEngine(tr.init_params(cfg, jax.random.key(0)), cfg, max_batch=2, n_blocks=8, block_size=8)
-    assert eng.pool_info()["decode_attention"] == impl
-
-
-def test_a_latent_model_leaves_the_per_head_option_alone():
-    with pytest.raises(ValueError, match="picks its own decode form"):
-        dataclasses.replace(CFG, paged_attention_impl="kernel")
+    assert eng.pool_info()["decode_attention"] == "gather"
 
 
 def test_capacity_routed_experts_are_still_refused():
