@@ -43,6 +43,7 @@ _RETIRED_KEYS: Dict[str, Tuple[Optional[Tuple[Any, ...]], Any, str]] = {
     "model.paged_attention_impl": (None, "gather", "PR 47"),
     "model.ragged_kv_splits": (None, 1, "PR 47"),
     "model.ragged_amla": (None, False, "PR 47"),
+    "model.flash_heads_major": (None, False, "PR 50"),
 }
 
 
@@ -116,15 +117,14 @@ class ModelConfig:
     # Flash-attention block sizes (tuned for TPU MXU/VMEM; 0 = auto:
     # min(1024, T)). T <= 1024 is kept as ONE block a head, because every
     # further grid step costs more than it skips; the masked half of that
-    # block is skipped inside it (ops/pallas_flash.py::causal_tiles). A size
-    # under T selects the grid's kernels, which skip whole blocks.
+    # block is skipped inside it (ops/pallas_flash.py::causal_tiles), and at
+    # a head size of 64 or a multiple of 128 those kernels read q, k, v and
+    # write o where the projections leave them, (B, T, H*Dh), with no
+    # transposing copy around the call (heads_in_place: read from the shapes,
+    # no field selects it). A size under T selects the grid's kernels, which
+    # skip whole blocks and are handed the heads folded first.
     flash_block_q: int = 0
     flash_block_kv: int = 0
-    # Heads-major (B, H, T, Dh) q/k/v for the flash TRAINING path: produced
-    # straight from the projection einsum so the kernel fold is a reshape,
-    # not a transpose. No cell selects it; it waits for the race of the
-    # folds around the flash calls (ROADMAP S5(b)).
-    flash_heads_major: bool = False
     # Rematerialization policy applied to each scanned block — see
     # ops/remat.py for what each saves.
     remat: str = "none"  # none | full | dots_saveable | save_attn | save_attn_res

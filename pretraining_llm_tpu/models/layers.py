@@ -128,34 +128,18 @@ def rope_table(
 
 def apply_rope(
     x: jax.Array, cos: jax.Array, sin: jax.Array, positions: jax.Array,
-    seq_axis: int = 1,
 ) -> jax.Array:
-    """Rotate (B, T, H, Dh) — or (B, H, T, Dh) with ``seq_axis=2`` — by
-    position.
+    """Rotate (B, T, H, Dh) by position.
 
     positions: (T,) int32 into the table — shared across the batch — or
     (B, T) for per-row positions (ragged left-padded decode, where row i's
     token at slot s has logical position s - pad_offset_i).
-
-    ``seq_axis=2`` serves the HEADS-MAJOR training layout the flash path
-    uses (q/k/v produced (B, H, T, Dh) straight from the projection
-    einsum so the Pallas kernel's fold is a free reshape — no transpose
-    copies in the step; see models.transformer._attention_block).
     """
-    if seq_axis not in (1, 2):
-        raise ValueError(f"seq_axis must be 1 or 2, got {seq_axis}")
+    cos_t, sin_t = cos[positions], sin[positions]  # (B, T, Dh/2) or (T, Dh/2)
     if positions.ndim == 2:
-        cos_t = cos[positions]  # (B, T, Dh/2)
-        sin_t = sin[positions]
-        expand = (slice(None), slice(None), None) if seq_axis == 1 else (
-            slice(None), None, slice(None))
-        cos_t, sin_t = cos_t[expand], sin_t[expand]  # head dim broadcast
+        cos_t, sin_t = cos_t[:, :, None], sin_t[:, :, None]  # head dim broadcast
     else:
-        cos_t = cos[positions]  # (T, Dh/2)
-        sin_t = sin[positions]
-        expand = (None, slice(None), None) if seq_axis == 1 else (
-            None, None, slice(None))
-        cos_t, sin_t = cos_t[expand], sin_t[expand]
+        cos_t, sin_t = cos_t[None, :, None], sin_t[None, :, None]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     rotated = jnp.concatenate([x1 * cos_t - x2 * sin_t, x2 * cos_t + x1 * sin_t], axis=-1)
     return rotated.astype(x.dtype)

@@ -456,50 +456,50 @@ def _attention_core(
     cdt = jnp.dtype(cfg.compute_dtype)
     with jax.named_scope("blk.norm"):
         h = layers.apply_norm(cfg.norm, blk["ln1"], x, cfg.norm_eps)
-    # HEADS-MAJOR training layout for the flash kernel (opt-in probe knob,
-    # measured ~1% slower on v5e despite removing the per-call relayout
-    # copies — see ModelConfig.flash_heads_major for the numbers): q/k/v
-    # produced (B, H, T, Dh) straight from the projection einsum, kernel
-    # fold becomes a free reshape. Cached decode and the other impls keep
-    # the (B, T, H, Dh) convention.
-    hm = (
-        kv is None
-        and cfg.attention_impl == "flash"
-        and cfg.flash_heads_major
-    )
+    # With no cache to write (training, evaluation) the projections see ONE
+    # head of H*Dh lanes: the same dots, whose results end in (1, H*Dh) and
+    # not (H, Dh), split into heads again after. The TPU compiler lays a dot's
+    # result out by the shape the dot gives it. One that ends in (H, 64) it
+    # keeps with T minor-most (64 is half a lane tile) and then copies,
+    # transposing, into and out of every flash call; one that ends in H*64
+    # lanes stays as the flash kernels read it (ops/pallas_flash.py::
+    # heads_in_place) and no copy stands beside the call (PR 50, the
+    # optimised HLO of both training cells). The programs that write a cache
+    # are what they were.
+    as_lanes = kv is None
+
+    def lanes(a: jax.Array) -> jax.Array:
+        """(..., H, Dh) -> (..., 1, H*Dh) where the heads stay merged."""
+        return a.reshape(a.shape[:-2] + (1, -1)) if as_lanes else a
+
     with jax.named_scope("attn.qkv"):
         if "wqkv" in blk["attn"]:
             qkv = jnp.einsum(
-                "btd,dchn->bchtn" if hm else "btd,dchn->bcthn",
-                h.astype(cdt), _weight(blk["attn"], "wqkv", cdt),
+                "btd,dchn->bcthn", h.astype(cdt), lanes(_weight(blk["attn"], "wqkv", cdt)),
                 preferred_element_type=jnp.float32,
             ).astype(cdt)
             if "bqkv" in blk["attn"]:
-                bqkv = blk["attn"]["bqkv"].astype(cdt)  # (3, H, Dh)
-                qkv = qkv + (
-                    bqkv[None, :, :, None, :] if hm else bqkv[None, :, None, :, :]
-                )
-            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # hm: (B, H, T, Dh)
+                bqkv = lanes(blk["attn"]["bqkv"].astype(cdt))  # (3, H, Dh)
+                qkv = qkv + bqkv[None, :, None, :, :]
+            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
         else:
             # GQA: H query heads, kv_heads <= H key/value heads.
             q = jnp.einsum(
-                "btd,dhn->bhtn" if hm else "btd,dhn->bthn",
-                h.astype(cdt), _weight(blk["attn"], "wq", cdt),
+                "btd,dhn->bthn", h.astype(cdt), lanes(_weight(blk["attn"], "wq", cdt)),
                 preferred_element_type=jnp.float32,
             ).astype(cdt)
             kvp = jnp.einsum(
-                "btd,dcgn->bcgtn" if hm else "btd,dcgn->bctgn",
-                h.astype(cdt), _weight(blk["attn"], "wkv", cdt),
+                "btd,dcgn->bctgn", h.astype(cdt), lanes(_weight(blk["attn"], "wkv", cdt)),
                 preferred_element_type=jnp.float32,
             ).astype(cdt)
             if "bq" in blk["attn"]:
-                bq = blk["attn"]["bq"].astype(cdt)  # (H, Dh)
-                bkv = blk["attn"]["bkv"].astype(cdt)  # (2, G, Dh)
-                q = q + (bq[None, :, None, :] if hm else bq[None, None])
-                kvp = kvp + (
-                    bkv[None, :, :, None, :] if hm else bkv[None, :, None]
-                )
-            k, v = kvp[:, 0], kvp[:, 1]  # hm: (B, G, T, Dh)
+                bq = lanes(blk["attn"]["bq"].astype(cdt))  # (H, Dh)
+                bkv = lanes(blk["attn"]["bkv"].astype(cdt))  # (2, G, Dh)
+                q = q + bq[None, None]
+                kvp = kvp + bkv[None, :, None]
+            k, v = kvp[:, 0], kvp[:, 1]
+        if as_lanes:
+            q, k, v = (a.reshape(a.shape[:2] + (-1, cfg.head_dim)) for a in (q, k, v))
 
     if cfg.attention_multiplier:
         # Scores times attention_multiplier where every attention form below
@@ -528,8 +528,8 @@ def _attention_core(
         else:
             rope_pos = positions
         with jax.named_scope("attn.rope"):
-            q = layers.apply_rope(q, cos, sin, rope_pos, seq_axis=2 if hm else 1)
-            k = layers.apply_rope(k, cos, sin, rope_pos, seq_axis=2 if hm else 1)
+            q = layers.apply_rope(q, cos, sin, rope_pos)
+            k = layers.apply_rope(k, cos, sin, rope_pos)
 
     # GQA: every attention path attends H query heads against G KV heads
     # directly when the layout allows it (no K/V expansion — the cache/HBM
@@ -813,34 +813,30 @@ def _attention_core(
                 ring_layout="zigzag" if zigzag else "contiguous",
                 segments=segments,
                 window=window,
-                heads_major=hm,
             )
 
     # Tag for the 'save_attn' remat policy: keep the (cheap-to-store,
     # expensive-to-recompute) attention output, recompute everything else.
-    # (Heads-major path saves (B, H, T, Dh) — consumers below match.)
     out = checkpoint_name(out, "attn_out")
 
     if "wg" in blk["attn"]:
         with jax.named_scope("attn.gate"):
             gate = jnp.einsum(
-                "btd,dhn->bhtn" if hm else "btd,dhn->bthn",
-                h.astype(cdt), _weight(blk["attn"], "wg", cdt),
+                "btd,dhn->bthn", h.astype(cdt), _weight(blk["attn"], "wg", cdt),
                 preferred_element_type=jnp.float32,
             )
             out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate)).astype(cdt)
 
     with jax.named_scope("attn.out"):
         if cfg.use_output_proj:
+            wo = _weight(blk["attn"], "wo", cdt)  # (H, Dh, d)
+            if as_lanes:
+                wo = wo.reshape((1, -1) + wo.shape[2:])
             out = jnp.einsum(
-                "bhtn,hnd->btd" if hm else "bthn,hnd->btd",
-                out, _weight(blk["attn"], "wo", cdt),
-                preferred_element_type=jnp.float32,
+                "bthn,hnd->btd", lanes(out), wo, preferred_element_type=jnp.float32,
             ).astype(cdt) + blk["attn"]["bo"].astype(cdt)
         else:
             # Reference shape (attention.py:95): concat heads is the output.
-            if hm:
-                out = out.transpose(0, 2, 1, 3)
             b, t = out.shape[:2]
             out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)
         return layers.join_residual(x, out, cfg.residual_multiplier, residual), new_kv

@@ -115,12 +115,8 @@ def multihead_attention(
     ring_layout: str = "contiguous",
     segments: Optional[jax.Array] = None,
     window: int = 0,
-    heads_major: bool = False,
 ) -> jax.Array:
     """Dispatch over attention implementations.
-
-    ``heads_major=True`` is flash-only: operands (B, H|G, T, D), result
-    (B, H, T, D) — the kernel-native layout (see ops.flash_attention).
 
     'ring' routes to `parallel.ring_attention` (shard_map over the active
     mesh's 'seq' axis, read from `parallel.sharding.current_mesh()` at trace
@@ -129,14 +125,6 @@ def multihead_attention(
     ``ring_layout="zigzag"`` asserts the caller already zigzag-permuted the
     sequence dim (models.transformer.loss_fn does this).
     """
-    if heads_major and (
-        impl != "flash" or q_positions is not None or kv_positions is not None
-        or kv_mask is not None
-    ):
-        raise ValueError(
-            "heads_major is the flash TRAINING layout only (no cached-"
-            "decode positions/masks, no other impls)"
-        )
     if impl in ("ring", "ulysses"):
         if window:
             raise ValueError(
@@ -207,6 +195,6 @@ def multihead_attention(
 
         return flash_attention(
             q, k, v, causal=causal, block_q=block_q, block_kv=block_kv,
-            segments=segments, window=window, heads_major=heads_major,
+            segments=segments, window=window,
         )
     raise ValueError(f"unknown attention impl {impl!r}")

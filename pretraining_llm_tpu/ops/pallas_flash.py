@@ -20,11 +20,19 @@ fully-materialized (B,H,T,T) scores, /root/reference/src/models/attention.py:51-
     and accumulates across the group's n_rep query heads in VMEM scratch.
   - Backward = two kernels (FA2): dQ gridded over q blocks, dK/dV gridded over
     kv blocks, both re-building P from the saved logsumexp; D = rowsum(dO*O)
-    is precomputed in plain XLA.
+    is precomputed in plain XLA (the tiled backward below takes it itself).
   - A plain causal call of ONE block a head (T <= 1024 at the default sizes)
     has nothing for the grid to skip: its forward and fused backward walk the
     block in square sub-tiles instead and never form those above the diagonal
     (causal_tiles; the pallas_calls `flash_fwd_tiles` / `flash_bwd_tiles`).
+  - Those tiled kernels read q, k, v and write o, dq, dk, dv IN PLACE where
+    the head size allows it (heads_in_place): the arrays stay (B, T, H*D) as
+    the projections leave them (a free reshape of (B, T, H, D)), a block is a
+    128-lane column block of that, and two heads of 64 sit side by side in
+    its lanes, told apart by lane masks. No transpose exists on either side
+    of such a call, forward, recompute or backward, and the backward takes
+    D = rowsum(dO*O) from the blocks it already holds. Every other call folds
+    the heads first ((B*H, T, D): _heads_first / _heads_last).
 
 All kernels run under interpret mode on CPU for unit testing (tests compare
 against the naive einsum path).
@@ -101,15 +109,49 @@ def causal_tiles(t: int, bq: int, bk: int, causal: bool, window: int, segments) 
     return t // CAUSAL_TILE
 
 
+LANES = 128  # a vreg's and an HBM tile's minor dimension
+
+
+def heads_in_place(d: int, h: int, g: int, n_tiles: int) -> int:
+    """How many heads share one column block of the lanes when the tiled
+    kernels read q, k, v and write o, dq, dk, dv in place, as (B, T, H*D)
+    arrays (what a projection einsum leaves, reshaped for free); 0 = the
+    heads are folded first, (B*H, T, D), with a transposing copy of every
+    operand and result around the call. Read from the shapes alone, for the
+    calls causal_tiles takes (n_tiles > 0; every other call folds):
+
+      - d a multiple of 128: 1. A head is a whole-tile column block of its
+        own; grouped heads (g < h) index down to their KV head's block.
+      - d = 64, h = g: 2. A block of 128 lanes holds heads 2p and 2p + 1,
+        which the kernels tell apart with lane masks (_head_lanes). An odd
+        h (GPT-2 XL's 25) leaves the last block's upper half outside the
+        array: what is read there is arbitrary (not zero, possibly NaN) and
+        is masked off every operand (_kv_inside), what is written there
+        falls away.
+      - anything else (d = 64 under grouped heads, whose pair would need
+        half of a KV block; head sizes that tile no 128 lanes): 0.
+    """
+    if not n_tiles:
+        return 0
+    if d % LANES == 0:
+        return 1
+    if 2 * d == LANES and h == g:
+        return 2
+    return 0
+
+
 @functools.lru_cache(maxsize=None)
-def _log_form(bh: int, t: int, d: int, bq: int, bk: int, n: int) -> None:
-    """One INFO line a distinct shape, at trace time: which form it takes."""
+def _log_form(bh: int, t: int, d: int, bq: int, bk: int, n: int, heads: int) -> None:
+    """One INFO line a distinct shape, at trace time: which form it takes, and
+    where the kernels find the heads (heads_in_place)."""
     if n:
         form = (f"causal tiles of {t // n}, {n * (n + 1) // 2} of {n * n} "
                 "sub-tiles computed")
     else:
         form = f"block grid {t // bq} x {t // bk} of ({bq}, {bk})"
-    logger.info("flash attention (B*H, T, D) = (%d, %d, %d): %s", bh, t, d, form)
+    layout = (f"heads in place, {heads} a block of {heads * d} lanes" if heads
+              else "heads first")
+    logger.info("flash attention (B*H, T, D) = (%d, %d, %d): %s; %s", bh, t, d, form, layout)
 
 
 # ---------------------------------------------------------------------------
@@ -210,18 +252,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, bq, bk, nk, seg, wind
         lse_ref[0] = m_scr[:] + jnp.log(safe_l)  # (bq, 1)
 
 
-def _strip_scores(q, k_ref, i: int, tile: int, scale: float) -> List[Tuple[slice, jax.Array]]:
+def _strip_scores(q, keys, i: int, tile: int, scale: float) -> List[Tuple[slice, jax.Array]]:
     """Scaled scores of causal strip i (the `tile` query rows q, at rows
-    [i*tile, (i+1)*tile)) against the keys it can see, as (key columns,
-    scores) pieces: everything left of the diagonal in one unmasked piece,
-    then the diagonal sub-tile under _mask_ok at its own offsets. Sub-tiles
-    right of the diagonal are never formed. Shared by the tiled forward and
-    backward, so the two cannot disagree on what a strip sees."""
+    [i*tile, (i+1)*tile)) against the keys it can see (`keys(columns)` loads
+    them: _kv_inside), as (key columns, scores) pieces: everything left of
+    the diagonal in one unmasked piece, then the diagonal sub-tile under
+    _mask_ok at its own offsets. Sub-tiles right of the diagonal are never
+    formed. Shared by the tiled forward and backward, so the two cannot
+    disagree on what a strip sees."""
     r0 = i * tile
     pieces = []
     for cols in ([slice(0, r0)] if i else []) + [slice(r0, r0 + tile)]:
         s = jax.lax.dot_general(
-            q, k_ref[0, cols, :], (((1,), (1,)), ((), ())),
+            q, keys(cols), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
         pieces.append((cols, s))
@@ -231,30 +274,113 @@ def _strip_scores(q, k_ref, i: int, tile: int, scale: float) -> List[Tuple[slice
     return pieces
 
 
-def _fwd_tiles_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, tile, n):
+def _head_lanes(shape: Tuple[int, int], j: int, heads: int, inside) -> Optional[jax.Array]:
+    """Mask over a (rows, width) value of the lanes that are head j's of the
+    `heads` side by side in a block, less those outside the array (`inside`:
+    the first lane past it, or None); None where the block is one whole head.
+    Zeroing the other head's lanes of q (of dO) makes a contraction over all
+    the lanes that head's own: the neighbour's products are exact zeros, and
+    the MXU pass is the one a head of 64 half-filled anyway."""
+    if heads == 1:
+        return None
+    d = shape[1] // heads
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    mine = jnp.logical_and(lane >= j * d, lane < (j + 1) * d)
+    return mine if inside is None else jnp.logical_and(mine, lane < inside)
+
+
+def _own(mine: Optional[jax.Array], x: jax.Array) -> jax.Array:
+    return x if mine is None else jnp.where(mine, x, jnp.zeros_like(x))
+
+
+def _kv_inside(k_ref, v_ref, scratch, edge: int):
+    """Loaders of rows of the block's k and of its v, `keys(cols)` and
+    `values(cols)`, and the first lane past the array in this block (None:
+    all inside). `edge` (static) is how many lanes of the LAST column block
+    lie inside the array where an odd head count leaves it half outside (0:
+    none does). What is read past it is arbitrary, and a zeroed lane of q
+    times an arbitrary one of k is no zero if that one is a NaN: there k and
+    v are copied to scratch with the outside lanes zeroed, once a block, and
+    _head_lanes keeps them out of q, dO and O. The head that would sit there
+    is computed all the same, from zeros, and its results fall away with the
+    lanes they are written to: skipping it under pl.when cost both kernels a
+    tenth of their time at 25 heads (PR 50; 3.8% of their work is nobody's)."""
+    if not edge:
+        return (lambda cols: k_ref[0, cols, :]), (lambda cols: v_ref[0, cols, :]), None
+    last = pl.program_id(1) == pl.num_programs(1) - 1
+    inside = jnp.where(last, edge, k_ref.shape[2])
+    ok = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape[1:], 1) < inside
+    ks, vs = scratch
+    ks[...] = _own(ok, k_ref[0])
+    vs[...] = _own(ok, v_ref[0])
+    return (lambda cols: ks[cols, :]), (lambda cols: vs[cols, :]), inside
+
+
+def _fwd_tiles_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, tile, n, heads, edge):
     """Forward of a lone causal block (causal_tiles), one query strip at a
     time. A strip has every key it can see in VMEM already, so its softmax is
     an ordinary one: no m/l carry, no alpha rescale, no scratch. Arithmetic as
     _fwd_kernel's: bf16 operands, f32 accumulation, p rounded to the value
-    dtype once before PV. Every row sees its own diagonal key, so l >= 1."""
+    dtype once before PV. Every row sees its own diagonal key, so l >= 1.
+    A block holds `heads` heads side by side in its lanes (heads_in_place; 1
+    when the heads were folded first): each takes its own scores from its own
+    lanes of q, p @ v is taken over all the lanes and each head's lanes of it
+    selected into o."""
+    keys, values, inside = _kv_inside(k_ref, v_ref, scratch, edge)
     for i in range(n):
         rows = slice(i * tile, (i + 1) * tile)
-        pieces = _strip_scores(q_ref[0, rows, :], k_ref, i, tile, scale)
-        m = functools.reduce(
-            jnp.maximum, [jnp.max(s, axis=-1, keepdims=True) for _, s in pieces]
-        )  # (tile, 1)
-        l = acc = None
-        for cols, s in pieces:
-            p = jnp.exp(s - m)
-            v = v_ref[0, cols, :]
-            p_sum = jnp.sum(p, axis=-1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            l = p_sum if l is None else l + p_sum
-            acc = pv if acc is None else acc + pv
-        o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
-        lse_ref[0, rows, :] = m + jnp.log(l)
+        q = q_ref[0, rows, :]
+        o = None
+        for j in range(heads):
+            mine = _head_lanes(q.shape, j, heads, inside)
+            pieces = _strip_scores(_own(mine, q), keys, i, tile, scale)
+            m = functools.reduce(
+                jnp.maximum, [jnp.max(s, axis=-1, keepdims=True) for _, s in pieces]
+            )  # (tile, 1)
+            l = acc = None
+            for cols, s in pieces:
+                p = jnp.exp(s - m)
+                v = values(cols)
+                p_sum = jnp.sum(p, axis=-1, keepdims=True)
+                pv = jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                l = p_sum if l is None else l + p_sum
+                acc = pv if acc is None else acc + pv
+            o = acc / l if o is None else jnp.where(mine, acc / l, o)
+            lse_ref[j, rows, :] = m + jnp.log(l)
+        o_ref[0, rows, :] = o.astype(o_ref.dtype)
+
+
+def _tile_spec(in_place: bool, t: int, width: int, n: int, at) -> pl.BlockSpec:
+    """One (T, width) block of a tiled call's operand or result: block
+    `at(*grid ids)` of the n that batch row ids[0] holds. In place the array
+    is (B, T, n*width) (less what an odd head count leaves off the last
+    block) and the block a column block of its lanes; with the heads folded
+    first it is (B*n, T, width) and the block a row of it."""
+    if in_place:
+        return pl.BlockSpec((1, t, width), lambda *ids: (ids[0], 0, at(*ids)))
+    return pl.BlockSpec((1, t, width), lambda *ids: (ids[0] * n + at(*ids), 0, 0))
+
+
+def _dims(q: jax.Array, h: int, heads: int) -> Tuple[int, int, int]:
+    """(B, T, D) of a q handed over in place, (B, T, H*D), where `heads`
+    (heads_in_place) says so, and with its heads folded first, (B*H, T, D)."""
+    if heads:
+        return q.shape[0], q.shape[1], q.shape[2] // h
+    return q.shape[0] // h, q.shape[1], q.shape[2]
+
+
+def _tiles_layout(t: int, d: int, h: int, g: int, heads: int):
+    """What the forward and the backward of a tiled call share: the heads and
+    the lanes of a block, the blocks a batch row's q and its k hold, the
+    static `edge` of _kv_inside, and _tile_spec left to take (blocks, at)."""
+    in_block = max(heads, 1)
+    width = d * in_block
+    nbq, nbk = pl.cdiv(h, in_block), pl.cdiv(g, in_block)
+    edge = (h % in_block) * d
+    spec = functools.partial(_tile_spec, bool(heads), t, width)
+    return in_block, width, nbq, nbk, edge, spec
 
 
 def _seg_views(segments: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -268,33 +394,39 @@ def _seg_views(segments: jax.Array) -> Tuple[jax.Array, jax.Array]:
 def _fwd(
     q: jax.Array, k: jax.Array, v: jax.Array, h: int, g: int, *,
     causal: bool, block_q: int, block_kv: int, interpret: bool,
-    segments: Optional[jax.Array] = None, window: int = 0,
+    segments: Optional[jax.Array] = None, window: int = 0, heads: int = 0,
 ) -> Tuple[jax.Array, jax.Array]:
-    bh, t, d = q.shape
-    b = bh // h
+    """q (B*H, T, D) and k, v (B*G, T, D) -> o like q and lse (B*H, T, 1);
+    with `heads` (heads_in_place) q (B, T, H*D), k, v (B, T, G*D) and lse
+    (B*blocks*heads, T, 1), a row a head of each block (an odd head count's
+    last row of a batch row is nobody's)."""
+    b, t, d = _dims(q, h, heads)
+    bh = b * h
     n_rep = h // g
     bq, bk = _block_sizes(t, block_q, block_kv)
     nq, nk = t // bq, t // bk
     scale = 1.0 / (d**0.5)
 
     n_tiles = causal_tiles(t, bq, bk, causal, window, segments)
-    _log_form(bh, t, d, bq, bk, n_tiles)
+    _log_form(bh, t, d, bq, bk, n_tiles, heads)
     if n_tiles:
-        head = lambda bb, hh: (bb * h + hh, 0, 0)
-        kv_head = lambda bb, hh: (bb * g + hh // n_rep, 0, 0)
+        in_block, width, nbq, nbk, edge, spec = _tiles_layout(t, d, h, g, heads)
+        head = lambda bb, hh: hh
+        kv_head = lambda bb, hh: hh // n_rep
         return pl.pallas_call(
-            functools.partial(_fwd_tiles_kernel, scale=scale, tile=t // n_tiles, n=n_tiles),
-            grid=(b, h),
-            in_specs=[
-                pl.BlockSpec((1, t, d), head),
-                pl.BlockSpec((1, t, d), kv_head),
-                pl.BlockSpec((1, t, d), kv_head),
+            functools.partial(_fwd_tiles_kernel, scale=scale, tile=t // n_tiles, n=n_tiles,
+                              heads=in_block, edge=edge),
+            grid=(b, nbq),
+            in_specs=[spec(nbq, head), spec(nbk, kv_head), spec(nbk, kv_head)],
+            out_specs=[
+                spec(nbq, head),
+                pl.BlockSpec((in_block, t, 1), lambda bb, hh: (bb * nbq + hh, 0, 0)),
             ],
-            out_specs=[pl.BlockSpec((1, t, d), head), pl.BlockSpec((1, t, 1), head)],
             out_shape=[
-                jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-                jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
+                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct((b * nbq * in_block, t, 1), jnp.float32),
             ],
+            scratch_shapes=[pltpu.VMEM((t, width), k.dtype)] * (2 if edge else 0),
             interpret=interpret,
             name="flash_fwd_tiles",
         )(q, k, v)
@@ -529,15 +661,20 @@ def _bwd_fused_kernel(
 
 
 def _bwd_tiles_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc, *, scale, n_rep, tile, n
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, dk_ref, dv_ref,
+    dk_acc, dv_acc, *scratch, scale, n_rep, tile, n, heads, edge
 ):
     """_bwd_fused_kernel for a lone causal block (causal_tiles), walked in
     the forward's strips: per strip, s and p from the saved lse, dq written
     to its rows, dk and dv of the keys it sees accumulated in the f32
     scratch. Grid and GQA accumulation over the group are the fused
-    kernel's."""
+    kernel's. D = rowsum(dO*O) is taken here, from blocks that are in VMEM
+    anyway. With `heads` side by side in a block (see _fwd_tiles_kernel) each
+    head's own lanes of q and dO give its s, dp and D; ds @ k is taken over
+    all the lanes and the head's selected into dq; p^T @ dO and ds^T @ q of
+    a head land in its own lanes of dv and dk by themselves."""
     r = pl.program_id(2)  # query head within the kv group
+    keys, values, inside = _kv_inside(k_ref, v_ref, scratch, edge)
 
     @pl.when(r == 0)
     def _init():
@@ -546,30 +683,36 @@ def _bwd_tiles_kernel(
 
     for i in range(n):
         rows = slice(i * tile, (i + 1) * tile)
-        q = q_ref[0, rows, :]
-        do = do_ref[0, rows, :]
-        lse = lse_ref[0, rows, :]  # (tile, 1)
-        delta = delta_ref[0, rows, :]
-        dq = None
-        for cols, s in _strip_scores(q, k_ref, i, tile, scale):
-            k = k_ref[0, cols, :]
-            p = jnp.exp(s - lse)
-            # bf16 matmul inputs, fp32 accumulation (see _bwd_dq_kernel).
-            dp = jax.lax.dot_general(
-                do, v_ref[0, cols, :], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            ds = p * (dp - delta) * scale
-            dq_part = jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            dq = dq_part if dq is None else dq + dq_part
-            dv_acc[cols, :] += jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            dk_acc[cols, :] += jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-        dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
+        q_all = q_ref[0, rows, :]
+        do_all = do_ref[0, rows, :]
+        do_o = do_all.astype(jnp.float32) * o_ref[0, rows, :].astype(jnp.float32)
+        dq_all = None
+        for j in range(heads):
+            mine = _head_lanes(q_all.shape, j, heads, inside)
+            q, do = _own(mine, q_all), _own(mine, do_all)
+            lse = lse_ref[j, rows, :]  # (tile, 1)
+            delta = jnp.sum(_own(mine, do_o), axis=-1, keepdims=True)
+            dq = None
+            for cols, s in _strip_scores(q, keys, i, tile, scale):
+                k = keys(cols)
+                p = jnp.exp(s - lse)
+                # bf16 matmul inputs, fp32 accumulation (see _bwd_dq_kernel).
+                dp = jax.lax.dot_general(
+                    do, values(cols), (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                ds = p * (dp - delta) * scale
+                dq_part = jax.lax.dot_general(
+                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                dq = dq_part if dq is None else dq + dq_part
+                dv_acc[cols, :] += jax.lax.dot_general(
+                    p.astype(do.dtype), do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                dk_acc[cols, :] += jax.lax.dot_general(
+                    ds.astype(q.dtype), q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                )
+            dq_all = dq if dq_all is None else jnp.where(mine, dq, dq_all)
+        dq_ref[0, rows, :] = dq_all.astype(dq_ref.dtype)
 
     @pl.when(r == n_rep - 1)
     def _finalize():
@@ -577,17 +720,50 @@ def _bwd_tiles_kernel(
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _bwd_tiles(
+    h: int, g: int, heads: int, n_tiles: int, interpret: bool, q, k, v, o, lse, do,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The fused backward of a call causal_tiles takes, in _fwd's layouts."""
+    b, t, d = _dims(q, h, heads)
+    n_rep = h // g
+    in_block, width, nbq, nbk, edge, spec = _tiles_layout(t, d, h, g, heads)
+    head = lambda bb, hh, r: hh * n_rep + r
+    kv_head = lambda bb, hh, r: hh
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_tiles_kernel, scale=1.0 / (d**0.5), n_rep=n_rep, tile=t // n_tiles,
+            n=n_tiles, heads=in_block, edge=edge,
+        ),
+        grid=(b, nbk, n_rep),
+        in_specs=[
+            spec(nbq, head), spec(nbk, kv_head), spec(nbk, kv_head),  # q, k, v
+            spec(nbq, head), spec(nbq, head),  # do, o
+            pl.BlockSpec((in_block, t, 1), lambda bb, hh, r: (bb * nbq + head(bb, hh, r), 0, 0)),
+        ],
+        out_specs=[spec(nbq, head), spec(nbk, kv_head), spec(nbk, kv_head)],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)],
+        scratch_shapes=[pltpu.VMEM((t, width), jnp.float32)] * 2
+        + [pltpu.VMEM((t, width), k.dtype)] * (2 if edge else 0),
+        interpret=interpret,
+        name="flash_bwd_tiles",
+    )(q, k, v, do, o, lse)
+
+
 def _bwd(
     h: int, g: int, causal: bool, block_q: int, block_kv: int, interpret: bool, residuals, grad,
-    segments: Optional[jax.Array] = None, window: int = 0,
+    segments: Optional[jax.Array] = None, window: int = 0, heads: int = 0,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     q, k, v, o, lse2 = residuals
     lse = lse2[..., None]
     do = grad
+    t = q.shape[1]
+    bq, bk = _block_sizes(t, block_q, block_kv)
+    n_tiles = causal_tiles(t, bq, bk, causal, window, segments)
+    if n_tiles:
+        return _bwd_tiles(h, g, heads, n_tiles, interpret, q, k, v, o, lse, do)
     bh, t, d = q.shape
     b = bh // h
     n_rep = h // g
-    bq, bk = _block_sizes(t, block_q, block_kv)
     nq, nk = t // bq, t // bk
     scale = 1.0 / (d**0.5)
 
@@ -613,18 +789,11 @@ def _bwd(
                 pl.BlockSpec((1, t, 1), lambda bb, hh, r: (bb, 0, 0)),  # seg q-side
                 pl.BlockSpec((1, 1, t), lambda bb, hh, r: (bb, 0, 0)),  # seg k-side
             ]
-        n_tiles = causal_tiles(t, bq, bk, causal, window, segments)
-        if n_tiles:
-            kernel = functools.partial(
-                _bwd_tiles_kernel, scale=scale, n_rep=n_rep, tile=t // n_tiles, n=n_tiles,
-            )
-        else:
-            kernel = functools.partial(
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(
                 _bwd_fused_kernel, causal=causal, scale=scale, n_rep=n_rep,
                 seg=seg, window=window,
-            )
-        dq, dk, dv = pl.pallas_call(
-            kernel,
+            ),
             grid=(b, g, n_rep),
             in_specs=in_specs,
             out_specs=[
@@ -642,7 +811,6 @@ def _bwd(
                 pltpu.VMEM((t, d), jnp.float32),
             ],
             interpret=interpret,
-            name="flash_bwd_tiles" if n_tiles else None,
         )(q, k, v, do, lse, delta, *seg_inputs)
         return dq, dk, dv
 
@@ -717,20 +885,20 @@ def _bwd(
 
 
 # ---------------------------------------------------------------------------
-# custom_vjp wrapper (heads-first layout), public (B, T, H, D) entry
+# custom_vjp wrapper (operands as _fwd takes them), public (B, T, H, D) entry
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, h, g, causal, block_q, block_kv, interpret, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, h, g, causal, block_q, block_kv, interpret, window, heads):
     o, _ = _fwd(q, k, v, h, g, causal=causal, block_q=block_q, block_kv=block_kv,
-                interpret=interpret, window=window)
+                interpret=interpret, window=window, heads=heads)
     return o
 
 
-def _flash_fwd(q, k, v, h, g, causal, block_q, block_kv, interpret, window):
+def _flash_fwd(q, k, v, h, g, causal, block_q, block_kv, interpret, window, heads):
     o, lse = _fwd(q, k, v, h, g, causal=causal, block_q=block_q, block_kv=block_kv,
-                  interpret=interpret, window=window)
+                  interpret=interpret, window=window, heads=heads)
     # Remat tags: under the 'save_attn_res' policy the VJP
     # residuals themselves are saved, so the backward never re-runs this
     # kernel (plain 'save_attn' only tags the merged output downstream,
@@ -738,14 +906,15 @@ def _flash_fwd(q, k, v, h, g, causal, block_q, block_kv, interpret, window):
     # lse is squeezed to 2-D for the residual: a trailing-singleton (bh, t, 1)
     # buffer saved across the layer scan provokes pathological XLA layout
     # handling (observed as a compile hang with these residuals saved).
+    # The residuals are the arrays in the layout the kernels were handed.
     o_res = checkpoint_name(o, "attn_o_res")
     lse2 = checkpoint_name(lse[..., 0], "attn_lse")
     return o, (q, k, v, o_res, lse2)
 
 
-def _flash_bwd(h, g, causal, block_q, block_kv, interpret, window, residuals, grad):
+def _flash_bwd(h, g, causal, block_q, block_kv, interpret, window, heads, residuals, grad):
     return _bwd(h, g, causal, block_q, block_kv, interpret, residuals, grad,
-                window=window)
+                window=window, heads=heads)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -794,20 +963,16 @@ def pallas_flash_attention(
     interpret: Optional[bool] = None,
     segments: Optional[jax.Array] = None,
     window: int = 0,
-    heads_major: bool = False,
 ) -> jax.Array:
     """Flash attention. q: (B, T, H, Dh); k, v: (B, T, G, Dh) with G | H
     (grouped-query attention — G < H never materializes repeated K/V).
     Returns (B, T, H, Dh).
 
-    ``heads_major=True``: q is (B, H, T, Dh) and k/v (B, G, T, Dh), and the
-    output comes back (B, H, T, Dh). The kernel's internal layout IS
-    heads-major ((B*H, T, D) folds), so this entry makes the fold a free
-    reshape instead of a transpose — callers that produce q/k/v heads-major
-    straight from their projection einsum (the training flash path) shed
-    the per-layer relayout copies the op-level profile showed around every
-    custom call (~6% of the gpt2-124m step, 2026-08-01 capture). Same
-    pallas_call either way — no new kernel-config class.
+    Where heads_in_place says so (a lone causal block a head at a head size
+    of 64 or a multiple of 128: every training step at T <= 1024) the
+    kernels are handed the arrays as they are, reshaped (B, T, H*Dh) for
+    free, and return o the same way; every other call folds the heads first
+    and unfolds o, a transposing copy each.
 
     ``segments`` (B, T) int32 document ids restricts attention to keys of
     the query's own document (packed-sequence training; composed with the
@@ -823,30 +988,26 @@ def pallas_flash_attention(
     """
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
-    if heads_major:
-        b, h, t, d = q.shape
-        g = k.shape[1]
-    else:
-        b, t, h, d = q.shape
-        g = k.shape[2]
+    b, t, h, d = q.shape
+    g = k.shape[2]
     if h % g != 0:
         raise ValueError(f"kv heads ({g}) must divide query heads ({h})")
-    if heads_major:
-        qf = q.reshape(b * h, t, d)
-        kf = k.reshape(b * g, t, d)
-        vf = v.reshape(b * g, t, d)
-    else:
-        qf, kf, vf = _heads_first(q), _heads_first(k), _heads_first(v)
+    if segments is not None and segments.shape != (b, t):
+        raise ValueError(
+            f"segments must be (batch, seq) = ({b}, {t}), got {segments.shape}"
+        )
+    bq, bk = _block_sizes(t, block_q, block_kv)
+    heads = heads_in_place(d, h, g, causal_tiles(t, bq, bk, causal, window, segments))
+    if heads:
+        in_place = lambda x: x.reshape(b, t, -1)
+        of = _flash(in_place(q), in_place(k), in_place(v), h, g, causal, block_q,
+                    block_kv, interpret, int(window), heads)
+        return of.reshape(b, t, h, d)
+    qf, kf, vf = _heads_first(q), _heads_first(k), _heads_first(v)
     if segments is not None:
-        if segments.shape != (b, t):
-            raise ValueError(
-                f"segments must be (batch, seq) = ({b}, {t}), got {segments.shape}"
-            )
         of = _flash_seg(qf, kf, vf, segments.astype(jnp.int32), h, g, causal,
                         block_q, block_kv, interpret, int(window))
     else:
         of = _flash(qf, kf, vf, h, g, causal, block_q, block_kv, interpret,
-                    int(window))
-    if heads_major:
-        return of.reshape(b, h, t, d)
+                    int(window), 0)
     return _heads_last(of, b, h)

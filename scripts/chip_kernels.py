@@ -1,7 +1,9 @@
 #!/usr/bin/env python
 """Compile and run every Pallas kernel on the TPU against its XLA reference,
-at the sizes serving and training use (flash attention: Dh 64, 12 heads, bf16;
-the paged decode step at 32 heads of 128; the expert FFN at three serving
+at the sizes serving and training use (flash attention: Dh 64, 12 heads, bf16,
+and the head layouts the tiled kernels read in place: 25 heads of 64, whose
+last 128-lane block is half outside the array, and grouped heads of 128 and
+256; the paged decode step at 32 heads of 128; the expert FFN at three serving
 cells' expert shapes; the KDA step over the Ling cell's state pool).
 
 The CPU tests run these kernels in interpret mode at toy sizes; only the
@@ -42,16 +44,16 @@ def _err(got, want) -> float:
     return float(jnp.max(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))))
 
 
-def flash_case(t: int, g: int, seg: bool):
+def flash_case(t: int, g: int, seg: bool, h: int = H, dh: int = DH):
     from pretraining_llm_tpu.ops.attention import naive_attention
     from pretraining_llm_tpu.ops.pallas_flash import pallas_flash_attention
 
     b = 2
     ks = jax.random.split(jax.random.key(t + g), 4)
-    q = jax.random.normal(ks[0], (b, t, H, DH), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (b, t, g, DH), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (b, t, g, DH), jnp.bfloat16)
-    w = jax.random.normal(ks[3], (b, t, H, DH), jnp.float32)
+    q = jax.random.normal(ks[0], (b, t, h, dh), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (b, t, g, dh), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, t, g, dh), jnp.bfloat16)
+    w = jax.random.normal(ks[3], (b, t, h, dh), jnp.float32)
     segments = None
     if seg:
         # Three documents per row, boundaries off the block grid.
@@ -290,6 +292,9 @@ def cases():
         for g in (12, 4):
             for seg in (False, True):
                 yield f"flash t{t} g{g}" + (" seg" if seg else ""), flash_case, (t, g, seg)
+    # heads in place (pallas_flash.heads_in_place): an odd count of 64, grouped heads of 128, 256
+    for h, g, dh in ((25, 25, 64), (8, 2, 128), (2, 1, 256)):
+        yield f"flash t1024 h{h} g{g} dh{dh}", flash_case, (1024, g, False, h, dh)
     for page in (64, 16):
         for g in (8, 32):  # grouped and ungrouped heads of 128, in place
             yield f"paged page{page} g{g} t1 h32 dh128", paged_case, (g, page, 32, 128)

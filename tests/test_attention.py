@@ -58,53 +58,6 @@ def test_flash_dispatch_via_multihead():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
-def test_flash_heads_major_matches_default():
-    """The heads-major flash entry (operands (B, H|G, T, D)) == the
-    default layout, values and grads — through the multihead dispatch and
-    end-to-end through a model forward with flash_heads_major=True."""
-    q, k, v = _qkv(jax.random.key(6))
-    want = multihead_attention(q, k, v, impl="flash", block_q=16, block_kv=16)
-    got = multihead_attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), impl="flash", block_q=16, block_kv=16,
-        heads_major=True,
-    )
-    np.testing.assert_allclose(
-        np.asarray(got.transpose(0, 2, 1, 3)), np.asarray(want),
-        rtol=1e-5, atol=1e-5,
-    )
-    with pytest.raises(ValueError, match="flash TRAINING"):
-        multihead_attention(q, k, v, impl="naive", heads_major=True)
-
-    import dataclasses
-
-    from pretraining_llm_tpu.config import get_preset
-    from pretraining_llm_tpu.models import transformer
-
-    base = dataclasses.replace(
-        get_preset("tiny").model, compute_dtype="float32",
-        attention_impl="flash",
-    )
-    hm_cfg = dataclasses.replace(base, flash_heads_major=True)
-    params = transformer.init_params(base, jax.random.key(0))
-    tok = jax.random.randint(jax.random.key(1), (2, 32), 0, base.vocab_size)
-    tgt = jnp.roll(tok, -1, axis=1)
-
-    def loss(cfg_):
-        return transformer.loss_fn(params, tok, tgt, cfg_)
-
-    l0, l1 = float(loss(base)), float(loss(hm_cfg))
-    np.testing.assert_allclose(l1, l0, rtol=1e-6)
-    g0 = jax.grad(lambda p: transformer.loss_fn(p, tok, tgt, base))(params)
-    g1 = jax.grad(lambda p: transformer.loss_fn(p, tok, tgt, hm_cfg))(params)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5
-        ),
-        g0, g1,
-    )
-
-
 def test_kv_cache_masking_matches_full_context():
     """Decode semantics: attending over a padded cache == attending the prefix."""
     b, t, h, dh = 1, 16, 2, 8

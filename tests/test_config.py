@@ -81,7 +81,7 @@ def test_json_roundtrip():
     assert restored == cfg
 
 
-# What the parents of PR 29 and PR 47 wrote into every checkpoint's saved
+# What the parents of PR 29, PR 47 and PR 50 wrote into every checkpoint's saved
 # config, and what a command line could still ask for: (key, value, the value
 # named in the error, or None where the key is dropped and the config loads).
 _RETIRED = [
@@ -101,6 +101,8 @@ _RETIRED = [
     ("ragged_kv_splits", 0, "=1"),
     ("ragged_kv_splits", 4, "=1"),
     ("ragged_amla", True, "False"),
+    ("flash_heads_major", False, None),
+    ("flash_heads_major", True, "False"),
 ]
 
 
@@ -112,7 +114,8 @@ def test_retired_model_keys(key, value, use):
     saved = json.loads(Config().to_json())
     assert key not in saved["model"] or use is not None  # the field is gone
     saved["model"].update(decode_cache_layout="unstacked", decode_unroll_layers=False, scan_unroll=1,
-                          paged_attention_impl="gather", ragged_kv_splits=1, ragged_amla=False)
+                          paged_attention_impl="gather", ragged_kv_splits=1, ragged_amla=False,
+                          flash_heads_major=False)
     saved["model"][key] = value
     if use is None:
         assert Config.from_json(json.dumps(saved)) == Config()

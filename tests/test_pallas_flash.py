@@ -283,3 +283,152 @@ def test_causal_tiles_counts_the_sub_tiles_of_a_lone_causal_block():
     assert pallas_flash.causal_tiles(1024, 1024, 1024, True, 0, None) == 1024 // tile
     assert pallas_flash.causal_tiles(2 * tile, 2 * tile, 2 * tile, True, 0, None) == 2
     assert pallas_flash.causal_tiles(tile, tile, tile, True, 0, None) == 0
+
+
+# -- the tiled kernels read q, k, v and write o in place (pallas_flash.heads_in_place) --------
+
+# (h, g, d): heads a 128-lane block that the rule gives (0: the heads are folded first)
+_IN_PLACE_CASES = {
+    "d64_even_heads": ((4, 4, 64), 2),
+    "d64_odd_heads_the_last_block_half_outside": ((3, 3, 64), 2),
+    "d64_one_head_alone": ((1, 1, 64), 2),
+    "d128_grouped_heads": ((4, 2, 128), 1),
+    "d256": ((2, 2, 256), 1),
+    "d64_grouped_heads_folded_first": ((4, 2, 64), 0),
+    "d32_folded_first": ((4, 4, 32), 0),
+}
+
+
+def _grad_jaxpr(q, k, v, **kwargs):
+    def grads(q, k, v):
+        loss = lambda q, k, v: jnp.sum(
+            pallas_flash_attention(q, k, v, interpret=True, **kwargs).astype(jnp.float32) ** 2)
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    return jax.make_jaxpr(grads)(q, k, v)
+
+
+def _equations(jaxpr, out=None):
+    """Every equation of a jaxpr outside the kernels' own bodies, nested programs included."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        out.append(eqn)
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                _equations(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4), (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("case", sorted(_IN_PLACE_CASES))
+def test_heads_in_place_forward_lse_and_backward_match_naive(monkeypatch, case, dtype, tol):
+    monkeypatch.setattr(pallas_flash, "CAUSAL_TILE", 16)
+    (h, g, d), heads = _IN_PLACE_CASES[case]
+    b, t = 2, 64
+    assert pallas_flash.heads_in_place(d, h, g, pallas_flash.causal_tiles(t, t, t, True, 0, None)) == heads
+    q, k, v = _gqa_qkv(jax.random.key(40 + h + d), b=b, t=t, h=h, g=g, dh=d, dtype=dtype)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2)
+
+    flash = lambda q, k, v: pallas_flash_attention(q, k, v, causal=True, interpret=True)
+    naive = lambda q, k, v: naive_attention(q, k, v, causal=True)
+    if heads:
+        fold = lambda x: x.reshape(b, t, -1)
+        o, lse = pallas_flash._fwd(fold(q), fold(k), fold(v), h, g, causal=True, block_q=0,
+                                   block_kv=0, interpret=True, heads=heads)
+        # a row a head of every block; an odd head count's last row is nobody's
+        got = {"o": o.reshape(b, t, h, d), "lse": lse.reshape(b, -1, t)[:, :h].reshape(b * h, t)}
+    else:
+        first = pallas_flash._heads_first
+        o, lse = pallas_flash._fwd(first(q), first(k), first(v), h, g, causal=True, block_q=0,
+                                   block_kv=0, interpret=True)
+        got = {"o": pallas_flash._heads_last(o, b, h), "lse": lse[..., 0]}
+    want = {"o": naive(q, k, v), "lse": _ref_lse(q, k)}
+    for name, grad_naive, grad_flash in zip(
+        ("dq", "dk", "dv"),
+        jax.grad(loss(naive), (0, 1, 2))(q, k, v),
+        jax.grad(loss(flash), (0, 1, 2))(q, k, v),
+    ):
+        want[name], got[name] = grad_naive, grad_flash
+    for name in want:
+        x, y = np.asarray(want[name], np.float32), np.asarray(got[name], np.float32)
+        assert x.shape == y.shape, name
+        assert np.abs(x - y).max() <= tol * np.abs(x).max(), name
+
+
+def test_an_odd_head_counts_last_block_reads_poison_that_the_masks_keep_out(monkeypatch):
+    """25 heads of 64 are twelve and a half blocks of 128 lanes. The interpreter fills what a
+    block reads outside its array with NaN, as the chip may: with the lane masks taken out the
+    lone head's results hold it, with them nothing does."""
+    monkeypatch.setattr(pallas_flash, "CAUSAL_TILE", 16)
+    q, k, v = _qkv(jax.random.key(50), b=1, t=32, h=3, dh=64)
+    loss = lambda q, k, v: jnp.sum(pallas_flash_attention(q, k, v, interpret=True) ** 2)
+    sound = jax.grad(loss, (0, 1, 2))(q, k, v)
+    assert all(np.isfinite(np.asarray(x)).all() for x in sound)
+    monkeypatch.setattr(pallas_flash, "_own", lambda mine, x: x)
+    poisoned = jax.grad(loss, (0, 1, 2))(q, k, v)
+    assert all(np.isnan(np.asarray(x)[:, :, 2]).any() for x in poisoned)
+
+
+@pytest.mark.parametrize("h,d", [(4, 64), (3, 64), (2, 128)])
+def test_a_call_in_place_transposes_nothing(monkeypatch, h, d):
+    """No transpose on either side of the calls, forward or backward, and the kernels'
+    operands are the (B, T, H*D) arrays themselves."""
+    monkeypatch.setattr(pallas_flash, "CAUSAL_TILE", 16)
+    b, t = 2, 64
+    q, k, v = _qkv(jax.random.key(0), b=b, t=t, h=h, dh=d, dtype=jnp.bfloat16)
+    eqns = _equations(_grad_jaxpr(q, k, v).jaxpr)
+    assert "transpose" not in {e.primitive.name for e in eqns}
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in calls] == ["flash_fwd_tiles", "flash_bwd_tiles"]
+    for e in calls:
+        wide = [x.aval.shape for x in list(e.invars) + list(e.outvars) if x.aval.shape[-1] != 1]
+        assert wide and set(wide) == {(b, t, h * d)}, wide
+    # the same shape with its heads grouped folds them first, as every call did
+    folded = _equations(_grad_jaxpr(q, k[:, :, :1], v[:, :, :1]).jaxpr)
+    if d == 64 and h > 1:
+        assert "transpose" in {e.primitive.name for e in folded}
+
+
+# Calls the in-place rule leaves alone though their head size and count are ones it takes,
+# because causal_tiles does not take them: the text of the traced gradient program, hashed at
+# 8c127b4 (PR 47, the parent of the PR that brought the rule), bfloat16 (B, T, H, D) operands.
+_PARENT_PROGRAMS = {
+    "multi_block_grid": ((1, 2048, 2, 64), {}, "eb58c441a458ba1d"),
+    "window": ((2, 512, 2, 64), {"window": 200}, "b46053fbafe51803"),
+    "segments": ((2, 512, 2, 64), {"segments": True}, "dbe1f088c46c417e"),
+    "non_causal": ((2, 512, 2, 64), {"causal": False}, "60a20b649559c460"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PARENT_PROGRAMS))
+def test_calls_outside_the_rule_trace_the_parents_program(case):
+    import hashlib
+
+    shape, kwargs, parent = _PARENT_PROGRAMS[case]
+    kwargs = dict(kwargs)
+    if kwargs.pop("segments", False):
+        kwargs["segments"] = jnp.zeros(shape[:2], jnp.int32)
+    q = jnp.zeros(shape, jnp.bfloat16)
+    assert pallas_flash.heads_in_place(64, 2, 2, 0) == 0
+    text = str(_grad_jaxpr(q, q, q, **kwargs))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == parent
+
+
+def test_the_log_names_the_layout_once_a_shape(monkeypatch, caplog):
+    monkeypatch.setattr(pallas_flash, "CAUSAL_TILE", 16)
+    pallas_flash._log_form.cache_clear()
+    with caplog.at_level("INFO", logger=pallas_flash.logger.name):
+        for h, g, d in [(4, 4, 64), (4, 4, 64), (3, 3, 64), (2, 1, 128), (4, 2, 64), (4, 2, 64)]:
+            q, k, v = _gqa_qkv(jax.random.key(0), t=64, h=h, g=g, dh=d)
+            jax.eval_shape(lambda q, k, v: pallas_flash_attention(q, k, v, interpret=True), q, k, v)
+    lines = [r.getMessage() for r in caplog.records]
+    tiles = "causal tiles of 16, 10 of 16 sub-tiles computed"
+    assert lines == [
+        f"flash attention (B*H, T, D) = (8, 64, 64): {tiles}; heads in place, 2 a block of 128 lanes",
+        f"flash attention (B*H, T, D) = (6, 64, 64): {tiles}; heads in place, 2 a block of 128 lanes",
+        f"flash attention (B*H, T, D) = (4, 64, 128): {tiles}; heads in place, 1 a block of 128 lanes",
+        f"flash attention (B*H, T, D) = (8, 64, 64): {tiles}; heads first",
+    ]
+    pallas_flash._log_form.cache_clear()

@@ -9,8 +9,10 @@ count by the step's pages, the fold of a page's slots, and whatever a table's
 dead tail points at. Interpret mode on CPU (same convention as
 test_pallas_paged); one at-size compile for a described v5e says what Mosaic
 would refuse. The other kernels of the serving path compile at size here too
-(``ops/pallas_paged.py``'s decode form, ``ops/pallas_moe.py``): one file, so
-that one test worker loads the TPU compiler.
+(``ops/pallas_paged.py``'s decode form, ``ops/pallas_moe.py``), and so do the
+tiled flash kernels of the training cells (``ops/pallas_flash.py``, alone and
+in a GPT-2 large layer): one file, so that one test worker loads the TPU
+compiler.
 """
 
 import jax
@@ -419,3 +421,84 @@ def test_kda_step_compiles_for_the_chip_over_the_cells_pools_as_they_lie(one_chi
     made = [op for op in pool_sized if not any(f" {kind}(" in op for kind in ("custom-call", "get-tuple-element", "tuple"))]
     assert pool_sized and not made, made
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def _compiled_for_the_chip(fn, *shapes):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable without a chip
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(fn).lower(*shapes).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+
+
+# (B, T, H, G, Dh), the lanes of the arrays the custom calls are handed (0: the heads folded first)
+_FLASH_TILED = {
+    "gpt2-large-step": ((12, 1024, 20, 20, 64), 1280),
+    "gpt2-xl-step-an-odd-head-count": ((12, 1024, 25, 25, 64), 1600),
+    "ling-latent-admission": ((1, 1024, 32, 32, 256), 8192),
+    "grouped-heads-of-128": ((4, 1024, 32, 8, 128), 4096),
+    "grouped-heads-of-64-folded-first": ((2, 1024, 12, 4, 64), 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FLASH_TILED))
+def test_tiled_flash_kernels_compile_for_the_chip_at_the_training_cells_size(one_chip, case):
+    """Forward and fused backward of a lone causal block a head at T 1,024, as both training
+    cells call them three times a layer a step (and Ling's one-row latent admission the
+    forward): Mosaic takes the 128-lane column blocks, the masked selects of bfloat16
+    operands, the half-outside last block of 25 heads and, with the heads folded first,
+    blocks 64 lanes wide; the calls are handed (B, T, H*Dh) arrays where the rule says so."""
+    import functools
+
+    from pretraining_llm_tpu.ops import pallas_flash
+
+    (b, t, h, g, d), lanes = _FLASH_TILED[case]
+    assert bool(pallas_flash.heads_in_place(d, h, g, t // pallas_flash.CAUSAL_TILE)) == bool(lanes)
+    attn = functools.partial(pallas_flash.pallas_flash_attention, interpret=False)
+    loss = lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) ** 2)
+    shape = lambda heads: jax.ShapeDtypeStruct((b, t, heads, d), jnp.bfloat16, sharding=one_chip)
+    text = _compiled_for_the_chip(jax.grad(loss, (0, 1, 2)), shape(h), shape(g), shape(g)).as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    in_place = f"bf16[{b},{t},{lanes}]"
+    assert all((in_place in line) == bool(lanes) for line in calls), calls
+
+
+def test_no_copy_stands_beside_the_flash_calls_of_a_gpt2_large_layer(one_chip, monkeypatch):
+    """One layer of ``train_gpt2large_1chip``'s step (12 x 1,024 tokens, 20 heads of 64, remat
+    ``full``), forward and backward, compiled for the chip: the projections hand q, k, v and
+    the cotangent of o to the custom calls where their dots leave them and take o, dq, dk, dv
+    from them the same way - no copy of an activation (what a change of layout compiles to) is
+    left in the program (the parent had twelve of bf16[12,20,1024,64] a layer and one of the
+    padded lse)."""
+    import functools
+
+    from pretraining_llm_tpu.config import ModelConfig
+    from pretraining_llm_tpu.models import transformer
+    from pretraining_llm_tpu.ops import flash_attention, pallas_flash
+
+    b, t = 12, 1024
+    cfg = ModelConfig(
+        vocab_size=256, context_length=t, d_model=1280, n_heads=20, n_layers=1, activation="gelu",
+        norm="layernorm", pos_embed="learned", tie_embeddings=True, qkv_bias=True, mlp_bias=True,
+        attention_impl="flash", remat="full",
+    )
+    monkeypatch.setattr(flash_attention, "_pallas_available", lambda: True)
+    monkeypatch.setattr(pallas_flash, "pallas_flash_attention",
+                        functools.partial(pallas_flash.pallas_flash_attention, interpret=False))
+    placed = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = placed(jax.eval_shape(lambda k: transformer.init_params(cfg, k), jax.random.key(0)))
+    tokens = placed(jax.ShapeDtypeStruct((b, t), jnp.int32))
+    grad = jax.grad(lambda p, x, y: transformer.loss_fn(p, x, y, cfg))
+    text = _compiled_for_the_chip(grad, params, tokens, tokens).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3  # forward, its recompute, the fused backward
+    moved = [line.strip()[:120] for line in text.splitlines()
+             if " copy(" in line
+             and any(f"[{shape}]" in line.split(" = ", 1)[-1].split("(", 1)[0]
+                     for shape in ("12,1024,1280", "12,1024,20,64", "12,20,1024,64", "12,1,1024,20,64", "240,1024,1"))]
+    assert not moved, moved

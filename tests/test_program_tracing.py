@@ -518,7 +518,8 @@ def test_backward_and_recompute_carry_the_scopes(scope_tokens):
 def test_causal_tile_kernels_are_named_under_attn_core_and_say_so_once_a_shape(monkeypatch, caplog):
     """At a context of 1,024 the flash forward (run and recomputed) and the fused backward take
     the tiled form: their pallas_calls carry names of their own inside attn.core, and the
-    module says which form a shape took in one INFO line however often it is traced."""
+    module says which form a shape took, and where the kernels find the heads (these heads of
+    8 are folded first), in one INFO line however often it is traced."""
     from pretraining_llm_tpu.ops import flash_attention, pallas_flash
 
     monkeypatch.setattr(flash_attention, "_pallas_available", lambda: True)  # interpreted off the TPU
@@ -539,7 +540,7 @@ def test_causal_tile_kernels_are_named_under_attn_core_and_say_so_once_a_shape(m
     lines = [r.getMessage() for r in caplog.records if r.name == "pretraining_llm_tpu.ops.pallas_flash"]
     assert lines == [
         f"flash attention (B*H, T, D) = ({b * h}, 1024, {d}): causal tiles of "
-        f"{pallas_flash.CAUSAL_TILE}, {n * (n + 1) // 2} of {n * n} sub-tiles computed"
+        f"{pallas_flash.CAUSAL_TILE}, {n * (n + 1) // 2} of {n * n} sub-tiles computed; heads first"
     ]
 
 

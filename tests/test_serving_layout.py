@@ -133,14 +133,20 @@ def test_int8_leaves_and_a_sharded_tree_are_left_as_they_are(what, mesh8):
     assert tr.serving_layout(tree, cfg) is tree
 
 
-# (equations, order-free hash) of the stored tree's programs as the parent commit c350700 (PR 44) traces them
+# (equations, order-free hash) of the stored tree's programs as the parent commit c350700 (PR 44) traces
+# them - 343 / 77d25608d9a0a70c, 143 / 1af97aa27b1aee22, 353 / c65d5acae457bc98, 147 / d30f29fd98cda2ca,
+# 993 / 8a617d22a4085f06, 437 / 8acc577d133cbb57 in this order, still so at 8c127b4 (PR 47) - with what PR 50
+# did to every program that writes no cache: the projections' heads kept as one axis of H*Dh lanes in their
+# dots (the same equations at (1, H*Dh) where they stood at (H, Dh)) and the reshapes around them, 7 to 9 a
+# layer forward; diffed equation by equation against 8c127b4, by primitive only reshapes were added (and two
+# broadcast_in_dim where a bias's cotangent is reshaped back)
 PARENTS = {
-    ("swiglu_gqa", "train"): (343, "77d25608d9a0a70c"),
-    ("swiglu_gqa", "forward"): (143, "1af97aa27b1aee22"),
-    ("swiglu_gqa_biased", "train"): (353, "c65d5acae457bc98"),
-    ("swiglu_gqa_biased", "forward"): (147, "d30f29fd98cda2ca"),
-    ("two_lifetimes", "train"): (993, "8a617d22a4085f06"),
-    ("two_lifetimes", "forward"): (437, "8acc577d133cbb57"),
+    ("swiglu_gqa", "train"): (357, "43c0f1b877d45a11"),
+    ("swiglu_gqa", "forward"): (150, "a5c26c0ec51f0794"),
+    ("swiglu_gqa_biased", "train"): (373, "ce28029dcc240383"),
+    ("swiglu_gqa_biased", "forward"): (156, "5bfe9115dcf4c4a0"),
+    ("two_lifetimes", "train"): (1035, "5c3372aa914079d6"),
+    ("two_lifetimes", "forward"): (458, "a37db01f0f7e961b"),
 }
 
 

@@ -446,9 +446,14 @@ DENSE = {"mistral-7b-v0.1": ModelConfig(**MISTRAL), "gpt2-large": ModelConfig(d_
 PARENTS = {
     ("mistral-7b-v0.1", "decode"): (523, "16f3f04aad6681aa"),
     ("mistral-7b-v0.1", "prefill"): (271, "1e842ff3fbeb6ac0"),
-    ("mistral-7b-v0.1", "forward"): (210, "86a2e3bb3520ba25"),
-    ("gpt2-large", "train"): (808, "98fa975426632a7d"),
-    ("gpt2-xl", "train"): (808, "092d28b6a34b7b9b"),
+    # the three programs that write no cache, since PR 50: the parent's (8c127b4: 210 /
+    # 86a2e3bb3520ba25, 808 / 98fa975426632a7d, 808 / 092d28b6a34b7b9b) with the projections' heads
+    # kept as one axis of H*Dh lanes in their dots - every equation between wqkv (wq, wkv) and the
+    # split into q, k, v, and around wo, at (1, H*Dh) where it stood at (H, Dh), and 7 reshapes a
+    # layer forward, 22 forward and backward; diffed equation by equation, nothing else differs
+    ("mistral-7b-v0.1", "forward"): (217, "3b4f3d5f569b4936"),
+    ("gpt2-large", "train"): (830, "e2d18ac32ab2c6e0"),
+    ("gpt2-xl", "train"): (830, "60733136754c69d4"),
     # the fourth configuration, at the `xing-mini` preset's widths (latent pool, dropless experts
     # with no group limit, no clamp and every expert held, four streams), as f8a0c12 (PR 30)
     # traces it: PR 31's per-layer type table, state slots, group limit and clamps leave it be
