@@ -183,7 +183,6 @@ def run(ctx: Ctx) -> RunResult:
     ms = 1e3
     return RunResult(
         end_to_end={
-            "ttft_p95_ms": ms * loadgen.percentile(st["ttft"], 0.95),
             "tpot_p50_ms": ms * loadgen.percentile(st["tpot"], 0.50),
             "setup_s": opened["setup_s"],
         },
@@ -196,7 +195,11 @@ def run(ctx: Ctx) -> RunResult:
             "rows": eng.max_batch, "mean_rows_active": tokens / max(1, steps),
             "queue_wait_p50_ms": ms * loadgen.percentile(st["queue_wait"], 0.5),
             "generator_lag_p95_ms": ms * loadgen.percentile(st["lags"], 0.95),
+            # which kind of host a run had (PERF.md section 2): a request sent tens of ms late, a tick of seconds
+            "generator_lag_max_ms": ms * max(st["lags"]), "longest_tick_ms": ms * st["longest_tick"][0],
             "ttft_p50_ms": ms * loadgen.percentile(st["ttft"], 0.5),
+            "ttft_p90_ms": ms * loadgen.percentile(st["ttft"], 0.9),
+            # the tail: per layer since PR 53's refusal round (`ttft_p95_ms.chat`), spread too wide to judge
             "ttft_p95_ms": ms * loadgen.percentile(st["ttft"], 0.95),
             "ttft_mean_ms": ms * float(np.mean(st["ttft"])) if st["ttft"] else None,
             "bytes_in_use": resident, "emitted": st["emitted"],

@@ -29,7 +29,8 @@ def spread(values: List[float]) -> float:
     if len(values) < 2:
         return float("nan")
     q = statistics.quantiles(values, n=4)
-    return (q[2] - q[0]) / statistics.median(values)
+    median = statistics.median(values)
+    return (q[2] - q[0]) / median if median else float("nan")  # a count that reads 0 in every run
 
 
 def counts_agree(directory: str) -> int:

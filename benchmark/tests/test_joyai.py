@@ -185,11 +185,15 @@ def test_the_cell_names_only_what_the_files_say():
     assert (traffic["rows"], traffic["prompt_tokens"], traffic["output_tokens"]) == (64, 2048, 2048)
     assert closed_decode.first_wave(64, 2048, 2048)[-1] == (4064, 32)  # the longest prompt of the first wave
     assert CELL in next(m for m in man["end_to_end"] if m["name"] == "output_tokens_per_s")["workloads"]
-    mine = [m for m in man["per_layer"] if m["name"].endswith(".jdecode")]
-    assert len(mine) == 23 and all(m["workloads"] == [CELL] and m["moves"] == "output_tokens_per_s" for m in mine)
+    # 23 readings: what the round changes under the cell's own suffix (PR 53 gave the rest one name a metric)
+    mine = registry.metrics_for(CELL, trace=True)
+    assert len(mine) == 23 and all(m["moves"] == "output_tokens_per_s" for m in mine)
+    own = [m for m in mine if m["name"].endswith(".jdecode")]
+    assert own == [m for m in man["per_layer"] if m["name"].endswith(".jdecode")]
+    assert len(own) == 9 and all(m["workloads"] == [CELL] for m in own)
+    assert all(m["name"].endswith(".decode") and CELL in m["workloads"] for m in mine if m not in own)
     assert {m["layer"] for m in mine if m["name"].split(".")[0] in (
         "mtp_time_share", "mtp_draft_hbm_roofline", "spec_accept_rate", "spec_tokens_per_round")} == {"draft module"}
-    assert man["per_layer"][-23:] == mine  # appended, nothing in the middle
     # every one has its reader's file, and ISSUE 35's list is all there
     assert all(os.path.exists(os.path.join(registry.BENCH_DIR, "layer_metrics", m["name"] + ".json")) for m in mine)
     assert {"decode_step_ms", "decode_hbm_roofline", "latent_attn_hbm_roofline", "moe_experts_hbm_roofline",

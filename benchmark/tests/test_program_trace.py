@@ -177,7 +177,7 @@ def test_new_metric_files_use_the_new_readers():
         "paged_attn_time_share.decode", "paged_attn_time_share.chat", "mlp_time_share.decode",
         "optimizer_time_share.train", "remat_recompute_time_share.train", "ce_head_time_share.train",
         "scope_coverage.train", "scope_coverage.decode", "scope_coverage.chat",
-        "tick_host_ms.decode", "tick_host_ms.chat", "admit_ms_p95.chat", "loop_overhead_ms_per_turn.chat"}
+        "tick_host_ms.decode", "tick_host_nowait_ms.chat", "admit_ms_p95.chat", "loop_overhead_ms_per_turn.chat"}
     listed = registry.list_all()
     assert {"scope_time_share", "span_stat"} <= set(listed["readers"])
     assert new <= set(listed["layer_metrics"])

@@ -208,16 +208,18 @@ def test_the_cell_names_only_what_the_files_say():
     assert wave[1] == (1152, 8064) and wave[-1] == (9088, 128) and sum(p > 2048 for p, _ in wave) == 55
     assert traffic["check_sample"] == [[1024, 128], [2000, 128], [9000, 128]]
     assert CELL in next(m for m in man["end_to_end"] if m["name"] == "output_tokens_per_s")["workloads"]
-    mine = [m for m in man["per_layer"] if m["name"].endswith(".tdecode")]
-    assert len(mine) == 23 and all(m["workloads"] == [CELL] and m["moves"] == "output_tokens_per_s" for m in mine)
-    # appended in one piece behind everything PR 40 had (a later PR appends behind these)
-    at = man["per_layer"].index(mine[0])
-    assert man["per_layer"][at : at + 23] == mine and at >= 104 and man["workloads"].index(cell) >= 7
+    # 23 readings: the two lifetimes' own under the cell's suffix (PR 53 gave the rest one name a metric)
+    mine = registry.metrics_for(CELL, trace=True)
+    assert len(mine) == 23 and all(m["moves"] == "output_tokens_per_s" for m in mine)
+    own = [m for m in mine if m["name"].endswith(".tdecode")]
+    assert own == [m for m in man["per_layer"] if m["name"].endswith(".tdecode")]
+    assert len(own) == 8 and all(m["workloads"] == [CELL] for m in own) and man["workloads"].index(cell) >= 7
+    assert all(m["name"].endswith(".decode") and CELL in m["workloads"] for m in mine if m not in own)
     assert all(os.path.exists(os.path.join(registry.BENCH_DIR, "layer_metrics", m["name"] + ".json")) for m in mine)
     names = {m["name"].split(".")[0] for m in mine}
     assert {"decode_step_ms", "prefill_device_share", "device_idle_share", "compiles_in_window", "batch_occupancy",
             "host_blocked_share", "tick_host_ms", "kv_blocks_peak", "hbm_resident_gb", "preemptions", "scope_coverage",
-            "decode_hbm_roofline", "moe_time_share", "moe_experts_hbm_roofline", "moe_experts_touched_share",
+            "decode_touched_hbm_roofline", "moe_time_share", "moe_experts_hbm_roofline", "moe_experts_touched_share",
             "moe_load_max_over_mean", "window_attn_time_share", "full_attn_time_share", "window_attn_hbm_roofline",
             "full_attn_hbm_roofline", "window_blocks_peak", "window_pages_released", "release_host_ms"} == names
     layers = {m["name"].split(".")[0]: m["layer"] for m in mine}
