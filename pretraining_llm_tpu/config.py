@@ -246,6 +246,15 @@ class ModelConfig:
     # RMSNorm with a learned weight over each head's queries and keys, before
     # RoPE (per-head attention; latent attention norms its own low-rank parts).
     qk_norm: bool = False
+    # The same two norms over the WHOLE projected width instead, all heads'
+    # queries (n_heads * d_head channels, one weight as wide) and all KV heads'
+    # keys, before the heads are cut (OLMo 2, arXiv:2501.00656).
+    qk_norm_whole: bool = False
+    # Where a sublayer's one norm stands: "input", x + f(N(x)) (pre-norm), or
+    # "output", x + N(f(x)) (OLMo 2: the sublayer reads the residual as it is
+    # and its output is normed before it joins). The same two weights a layer,
+    # ln1 and ln2, either way; the final norm is unchanged.
+    norm_placement: str = "input"  # input | output
     # Two more norms a layer: x + N_post_attn(attn(N_in(x))), then
     # x + N_post_mlp(mlp(N_pre_mlp(x))).
     sandwich_norm: bool = False
@@ -260,9 +269,10 @@ class ModelConfig:
     attention_multiplier: float = 0.0
     logits_scaling: float = 1.0
     # The mixer of every layer, one name a layer: "attn" (per-head or latent
-    # attention, whichever the model has), "kda" (models/kda.py) or "mamba"
-    # (models/mamba.py). Empty = every layer "attn", or what layer_group_size,
-    # the shorthand for a period of KDA layers, fills in. A stack with
+    # attention, whichever the model has), "kda" (models/kda.py), "mamba"
+    # (models/mamba.py) or "gdn" (models/gdn.py). Empty = every layer "attn",
+    # or what layer_group_size, the shorthand for a period of KDA layers,
+    # fills in. A stack with
     # recurrent layers is hybrid: stored and scanned as runs of like layers,
     # params["blocks"] its recurrent layers and params["attn_blocks"] its
     # attention layers (layer_runs); served, its attention layers alone keep
@@ -280,6 +290,16 @@ class ModelConfig:
     mamba_n_groups: int = 1
     mamba_conv_kernel: int = 4
     mamba_chunk_size: int = 256
+    # A Gated DeltaNet layer (arXiv:2412.06464): gdn_heads heads of gdn_key_dim
+    # keys and gdn_value_dim values (a gdn_key_dim x gdn_value_dim float32
+    # state a head), one scalar log-decay a head and token, a causal depthwise
+    # convolution of gdn_conv_kernel taps on q, k and v, and beta in (0, 2)
+    # instead of (0, 1) with gdn_allow_neg_eigval (arXiv:2411.12537).
+    gdn_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv_kernel: int = 4
+    gdn_allow_neg_eigval: bool = False
     # layer_group_size g > 0: layer i is a latent-attention
     # layer when (i + 1) % g == 0 and a KDA linear-attention layer otherwise
     # (models/kda.py; arXiv:2510.26692). A KDA layer has n_heads heads of
@@ -423,10 +443,10 @@ class ModelConfig:
             object.__setattr__(self, "embed_scale", float(self.d_model) ** 0.5)
         if self.layer_mixers and (
             self.layer_group_size or len(self.layer_mixers) != self.n_layers
-            or set(self.layer_mixers) - {"attn", "kda", "mamba"}
+            or set(self.layer_mixers) - {"attn", "kda", "mamba", "gdn"}
         ):
             raise ValueError(
-                f"layer_mixers names 'attn', 'kda' or 'mamba' for each of n_layers={self.n_layers} "
+                f"layer_mixers names 'attn', 'kda', 'mamba' or 'gdn' for each of n_layers={self.n_layers} "
                 "layers, and layer_group_size (the shorthand that fills it) is then left at 0"
             )
         if self.layer_group_size == 1 or self.layer_group_size < 0:
@@ -454,6 +474,15 @@ class ModelConfig:
                     "a hybrid stack of Mamba-2 layers needs mamba_heads (a multiple of "
                     "mamba_n_groups), mamba_head_dim, mamba_d_state, mamba_conv_kernel >= 2 and "
                     "mamba_chunk_size, over per-head attention layers (no kv_lora_rank)"
+                )
+            if "gdn" in mixers and (
+                min(self.gdn_heads, self.gdn_key_dim, self.gdn_value_dim) < 1
+                or self.gdn_conv_kernel < 2 or self.kv_lora_rank
+            ):
+                raise ValueError(
+                    "a hybrid stack of Gated DeltaNet layers needs gdn_heads, gdn_key_dim, "
+                    "gdn_value_dim and gdn_conv_kernel >= 2, over per-head attention layers "
+                    "(no kv_lora_rank)"
                 )
             if self.hc_mult > 1 or self.pipeline_stages > 1 or self.attention_impl in ("ring", "ulysses"):
                 raise ValueError(
@@ -499,6 +528,22 @@ class ModelConfig:
             raise ValueError(
                 "qk_norm and sandwich_norm are per-head attention's and the plain residual's: "
                 "no latent attention, hybrid stack or residual streams"
+            )
+        if self.qk_norm_whole and (self.kv_lora_rank or self.qk_norm):
+            raise ValueError(
+                "qk_norm_whole norms per-head attention's whole projected width in place of "
+                "qk_norm's heads: no latent attention, and one of the two"
+            )
+        if self.norm_placement not in ("input", "output"):
+            raise ValueError(f"norm_placement must be 'input' or 'output', got {self.norm_placement!r}")
+        if self.norm_placement == "output" and (
+            self.kv_lora_rank or self.sandwich_norm or self.hc_mult > 1 or self.mtp_depth
+            or self.state_mixer == "kda"
+        ):
+            raise ValueError(
+                "norm_placement='output' is built for per-head attention, Mamba-2 and Gated "
+                "DeltaNet layers on the plain residual: no latent attention (or KDA layers over "
+                "it), sandwich norms, residual streams or multi-token-prediction module"
             )
         if self.mtp_depth not in (0, 1):
             raise ValueError(
@@ -639,8 +684,8 @@ class ModelConfig:
     @property
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
         """The layer table, (mixer, ffn) of every layer: mixer ``"attn"``
-        (per-head or latent attention, whichever the model has), ``"kda"`` or
-        ``"mamba"``, from ``layer_mixers`` (or ``layer_group_size``, the
+        (per-head or latent attention, whichever the model has), ``"kda"``,
+        ``"mamba"`` or ``"gdn"``, from ``layer_mixers`` (or ``layer_group_size``, the
         shorthand for a period of KDA layers closed by an attention layer); ffn
         ``"dense"`` or ``"moe"``."""
         g = self.layer_group_size
@@ -655,7 +700,7 @@ class ModelConfig:
     @property
     def state_mixer(self) -> Optional[str]:
         """The kind of recurrent layer a hybrid stack has (``"kda"`` |
-        ``"mamba"``): each keeps a fixed-size state a row where an attention
+        ``"mamba"`` | ``"gdn"``): each keeps a fixed-size state a row where an attention
         layer keeps pages. None for a stack of attention layers alone."""
         return next((mixer for mixer, _ in self.layer_kinds if mixer != "attn"), None)
 
@@ -711,6 +756,11 @@ class ModelConfig:
         return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
 
     @property
+    def gdn_conv_dim(self) -> int:
+        """Channels a Gated DeltaNet layer's convolution runs over: q, k, then v."""
+        return self.gdn_heads * (2 * self.gdn_key_dim + self.gdn_value_dim)
+
+    @property
     def latent_dim(self) -> int:
         """Values cached a token a layer by latent attention (0 = per-head K/V)."""
         return self.kv_lora_rank + self.qk_rope_head_dim if self.kv_lora_rank else 0
@@ -746,7 +796,9 @@ class ModelConfig:
         moe_layers = self.n_layers - self.n_dense_layers if self.n_experts else 0
         n += (self.n_layers - moe_layers) * (shared + self._ffn_params(self.d_ff))
         n += moe_layers * (shared + self._moe_params(self.experts_held))
-        mixer = {"kda": self._kda_params, "mamba": self._mamba_params}.get(self.state_mixer)
+        mixer = {
+            "kda": self._kda_params, "mamba": self._mamba_params, "gdn": self._gdn_params,
+        }.get(self.state_mixer)
         if mixer is not None:
             n += self.n_state_layers * (mixer() - self._attn_params())
         n += self._norm_params()  # final norm
@@ -775,6 +827,8 @@ class ModelConfig:
             n += d * h * dh
         if self.qk_norm:
             n += 2 * dh
+        if self.qk_norm_whole:
+            n += (h + g) * dh
         return n
 
     def _kda_params(self) -> int:
@@ -791,6 +845,14 @@ class ModelConfig:
         norm's weight, the output projection."""
         d, w, c, h = self.d_model, self.mamba_d_inner, self.mamba_conv_dim, self.mamba_heads
         return d * (w + c + h) + c * (self.mamba_conv_kernel + 1) + 3 * h + w + w * d
+
+    def _gdn_params(self) -> int:
+        """A Gated DeltaNet mixer: the q, k and v projections and the taps of
+        their convolution, the decay and beta projections, the output gate and
+        the output projection, A_log and dt_bias a head, the head norm's weight."""
+        d, h, v = self.d_model, self.gdn_heads, self.gdn_value_dim
+        c = self.gdn_conv_dim
+        return d * c + c * self.gdn_conv_kernel + 2 * d * h + 2 * d * h * v + 2 * h + v
 
     def _hc_params(self) -> int:
         """One sublayer's hyper-connection: phi, b and the three alphas."""
@@ -1935,6 +1997,31 @@ _register(
             logits_scaling=4.0,
             n_experts=8, n_experts_held=4, experts_per_token=3, moe_routing="dropless",
             moe_score="softmax", n_shared_experts=2, d_expert=16,
+        ),
+        mesh=MeshConfig(),
+        data=DataConfig(tokenizer_name="byte"),
+        train=TrainConfig(batch_size=8, train_steps=50, eval_interval=20, eval_iters=2, lr=1e-3),
+    ),
+)
+
+# Every mechanism of the Olmo-Hybrid (olmo_hybrid) family at a width a CPU
+# smoke run holds: two periods of three Gated DeltaNet layers (3 heads of 8 keys
+# and 16 values: a rectangular state, beta in (0, 2)) to one position-free
+# attention layer of 3 heads and 3 KV heads, RMSNorm over the whole width of q
+# and of k, every sublayer normed on its output, a dense SwiGLU, an untied head.
+# Served, a Gated DeltaNet layer keeps a state slot a row and the attention
+# layers alone keep pages. The published widths are
+# benchmark/configs/olmo-hybrid-7b.json; this is for the unit tests and serve.py.
+_register(
+    "olmo-hybrid-toy",
+    Config(
+        model=ModelConfig(
+            vocab_size=256, context_length=256, d_model=48, n_heads=3, n_kv_heads=3, n_layers=8,
+            mlp_ratio=2.0, activation="swiglu", norm="rmsnorm", pos_embed="none",
+            tie_embeddings=False, mlp_bias=False, norm_eps=1e-6,
+            layer_mixers=("gdn", "gdn", "gdn", "attn") * 2,
+            gdn_heads=3, gdn_key_dim=8, gdn_value_dim=16, gdn_allow_neg_eigval=True,
+            qk_norm_whole=True, norm_placement="output",
         ),
         mesh=MeshConfig(),
         data=DataConfig(tokenizer_name="byte"),

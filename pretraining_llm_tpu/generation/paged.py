@@ -40,6 +40,7 @@ from pretraining_llm_tpu.generation.sampling import (
 from pretraining_llm_tpu.models import mtp, transformer
 from pretraining_llm_tpu.models.transformer import PagedInfo
 from pretraining_llm_tpu.ops import pallas_moe
+from pretraining_llm_tpu.ops.pallas_paged import pad_kv_heads
 
 # Pool-key names <- their contiguous-cache counterparts (prefill writes a
 # dense per-request cache, then scatters its pages into the pools).
@@ -228,7 +229,10 @@ def _scatter_staged_pages(
             # a page is whatever one block of this pool holds: (bs, G, Dh) per
             # head; (bs / fold, fold * c) of latents and (bs / fold, fold * r) of
             # rotated key slices, the same values row-major as (bs, c) and (bs, r)
-            pages = field(layer, dense_key).reshape((n_chunks,) + pool.shape[1:])
+            staged_field = field(layer, dense_key)
+            if pool.ndim == 4:  # per head: zeros in the pool's padding heads, where it has any
+                staged_field = pad_kv_heads(staged_field, pool.shape[-2])
+            pages = staged_field.reshape((n_chunks,) + pool.shape[1:])
             ids = window_ids if layer in in_window_pool else flat_ids
             out[pool_key] = pool.at[ids].set(pages.astype(pool.dtype))
         return out
@@ -259,7 +263,9 @@ def _prefill_last_logits(
     n_rows, p_bucket = prompts.shape
     # a recurrent layer leaves its state as of each row's last real token
     lengths = last_idx + 1 if cfg.hybrid else None
-    if 4 * n_rows * p_bucket * cfg.vocab_size <= _ALL_POSITION_LOGITS_BYTES:
+    # (a state-slot model's pools leave the least room beside the weights: its
+    # head always runs on the last positions alone)
+    if not cfg.hybrid and 4 * n_rows * p_bucket * cfg.vocab_size <= _ALL_POSITION_LOGITS_BYTES:
         logits, cache = transformer.forward(
             params, prompts, cfg, kv_cache=cache, cache_index=jnp.int32(0),
             lengths=lengths,

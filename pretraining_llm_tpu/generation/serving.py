@@ -790,6 +790,10 @@ class ServingEngine:
             # "gather" | "kernel" (per head), "gather" | "latent_kernel" (latent)
             "decode_attention": self.decode_attention,
         }
+        if "k_pool" in layer0:
+            # the head axis the pages are stored with: the model's kv heads, or
+            # more where the pool pads them (ops/pallas_paged.py::pool_kv_heads)
+            info["pool_kv_heads"] = int(layer0["k_pool"].shape[-2])
         if self.decode_experts:
             info["decode_experts"] = self.decode_experts  # "kernel" | "grouped"
         if self.two_lifetimes:

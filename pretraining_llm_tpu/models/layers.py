@@ -53,6 +53,25 @@ def apply_norm(kind: str, p: Params, x: jax.Array, eps: float) -> jax.Array:
     return layernorm(p, x, eps) if kind == "layernorm" else rmsnorm(p, x, eps)
 
 
+def norm_in(cfg, p: Params, x: jax.Array) -> jax.Array:
+    """What a sublayer reads of the residual ``x``: N(x) under its norm ``p``,
+    or ``x`` as it is where the norm stands on the output
+    (``cfg.norm_placement``)."""
+    if cfg.norm_placement == "output":
+        return x
+    with jax.named_scope("blk.norm"):
+        return apply_norm(cfg.norm, p, x, cfg.norm_eps)
+
+
+def norm_out(cfg, p: Params, y: jax.Array) -> jax.Array:
+    """What a sublayer hands the residual: its output ``y``, normed by ``p``
+    where the norm stands there (x + N(f(x)))."""
+    if cfg.norm_placement != "output":
+        return y
+    with jax.named_scope("blk.norm"):
+        return apply_norm(cfg.norm, p, y, cfg.norm_eps)
+
+
 def init_norm(kind: str, d: int, dtype: jnp.dtype) -> Params:
     p = {"scale": jnp.ones((d,), dtype)}
     if kind == "layernorm":

@@ -1,14 +1,15 @@
 """The cache discipline of a recurrent mixer: a layer whose cache is a
-fixed-size state a row (``models/kda.py``, ``models/mamba.py``), where an
+fixed-size state a row (``models/kda.py``, ``models/mamba.py``,
+``models/gdn.py``), where an
 attention layer's is pages.
 
 A mixer is a module with ``init_params``, ``state_shapes`` ({"state": float32,
-"conv": compute dtype} for so many rows), ``step_form`` and ``mix`` (normed
-input, state and conv tail in; output, new state and new tail out, under a
-validity mask), and ``SCOPE``, the prefix of its device scopes. ``MIXERS``
-names them as the layer table does (``ModelConfig.layer_kinds``); whoever asks
-"does this model keep state slots, and of what shape" asks here and the table,
-never a mixer by name.
+"conv": compute dtype} for so many rows), ``step_form`` and ``mix`` (the
+sublayer's input, state and conv tail in; output, new state and new tail out,
+under a validity mask), and ``SCOPE``, the prefix of its device scopes.
+``MIXERS`` names them as the layer table does (``ModelConfig.layer_kinds``);
+whoever asks "does this model keep state slots, and of what shape" asks here
+and the table, never a mixer by name.
 """
 
 from __future__ import annotations
@@ -19,11 +20,11 @@ import jax
 import jax.numpy as jnp
 
 from pretraining_llm_tpu.config import ModelConfig
-from pretraining_llm_tpu.models import kda, layers, mamba
+from pretraining_llm_tpu.models import gdn, kda, layers, mamba
 
 Params = Dict[str, Any]
 
-MIXERS = {"kda": kda, "mamba": mamba}
+MIXERS = {"kda": kda, "mamba": mamba, "gdn": gdn}
 
 
 def state_shapes(cfg: ModelConfig, rows: int) -> Optional[Dict[str, Tuple[Tuple[int, ...], Any]]]:
@@ -48,7 +49,8 @@ def mixer_block(
     lengths: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Params]]:
     """The recurrent counterpart of ``transformer._attention_block``:
-    x + mix(ln1(x)) and the layer's new cache. ``kv`` is None (training
+    x + mix(ln1(x)), or x + ln1(mix(x)) where the norm stands on the output
+    (``cfg.norm_placement``), and the layer's new cache. ``kv`` is None (training
     forward: a fresh state, nothing kept), ``{"state": (B,...), "conv":
     (B,kernel-1,C)}`` (a contiguous cache: the call starts from it) or
     ``{"state_pool", "conv_pool"}`` (serving: slot ``paged.slots[b]`` of the
@@ -58,9 +60,9 @@ def mixer_block(
     one."""
     mod = MIXERS[mixer]
     b, t, _ = x.shape
-    with jax.named_scope("blk.norm"):
-        h = layers.apply_norm(cfg.norm, blk["ln1"], x, cfg.norm_eps)
-    joined = lambda y: layers.join_residual(x, y, cfg.residual_multiplier)
+    h = layers.norm_in(cfg, blk["ln1"], x)
+    joined = lambda y: layers.join_residual(
+        x, layers.norm_out(cfg, blk["ln1"], y), cfg.residual_multiplier)
 
     pos = jnp.arange(t)[None, :]
     valid = ends = None

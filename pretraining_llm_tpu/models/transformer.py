@@ -42,6 +42,9 @@ from pretraining_llm_tpu.config import ModelConfig
 from pretraining_llm_tpu.models import hyper, layers, mla, moe, recurrent
 from pretraining_llm_tpu.ops import remat
 from pretraining_llm_tpu.ops.attention import multihead_attention
+from pretraining_llm_tpu.ops.pallas_paged import (
+    pad_kv_heads, pages_copy_in_place, paged_decode_attention, pool_kv_heads,
+)
 from pretraining_llm_tpu.parallel.sharding import constrain, current_mesh
 
 Params = Dict[str, Any]
@@ -115,14 +118,13 @@ def paged_attention_form(
     row, int8 pools, a sharded pool, narrow or odd heads, every other backend)
     the gather form. The engine reports the decode step's form in
     ``pool_info()``."""
-    from pretraining_llm_tpu.ops.pallas_paged import pages_copy_in_place
-
     if (
         tq == 1
         and not quantized
         and mesh is None
         and (backend or jax.default_backend()) == "tpu"
-        and pages_copy_in_place(cfg.kv_heads, cfg.head_dim)
+        # the head axis the pool was built with (make_paged_kv_pool)
+        and pages_copy_in_place(pool_kv_heads(cfg.kv_heads, cfg.head_dim), cfg.head_dim)
     ):
         return "kernel"
     return "gather"
@@ -195,9 +197,10 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             attn["wo"] = normal(ks[1], (h, dh, d), resid_std)
             attn["bo"] = jnp.zeros((d,), dtype)
         if not cfg.kv_lora_rank and mixer == "attn":
-            if cfg.qk_norm:
-                attn["q_norm"] = layers.init_norm("rmsnorm", dh, dtype)
-                attn["k_norm"] = layers.init_norm("rmsnorm", dh, dtype)
+            if cfg.qk_norm or cfg.qk_norm_whole:
+                # a head's width, or all heads' (qk_norm_whole)
+                attn["q_norm"] = layers.init_norm("rmsnorm", dh * (h if cfg.qk_norm_whole else 1), dtype)
+                attn["k_norm"] = layers.init_norm("rmsnorm", dh * (g if cfg.qk_norm_whole else 1), dtype)
             if cfg.attn_output_gate:
                 attn["wg"] = normal(jax.random.fold_in(k, 13), (d, h, dh))
         if cfg.moe_dropless and not dense_ffn:
@@ -340,7 +343,8 @@ def _serving_halves(w1: Any) -> Optional[Tuple[jax.Array, jax.Array]]:
 
 
 def serving_layout(params: Params, cfg: ModelConfig) -> Params:
-    """``params`` with the dense SwiGLU FFNs of its per-head attention stacks
+    """``params`` with the dense SwiGLU FFNs of a per-head model's stacks (its
+    attention layers' and, in a hybrid stack, its recurrent layers' alike)
     laid out for the serving programs: ``mlp.w1`` (L, d, 2, f) becomes
     ``mlp.w1_gate`` and ``mlp.w1_up``, (L, d, f) each. The TPU tiles an array's
     last two axes; stored, those are (2, f), the matmul wants the contracted
@@ -356,18 +360,17 @@ def serving_layout(params: Params, cfg: ModelConfig) -> Params:
     other leaf is shared with ``params``. The halves are remembered weakly by
     the identity of the stored leaf, so the same stored tree gives the same
     arrays again (the engine and a caller that still holds the stored tree
-    meet one copy) and they are freed with it. Latent attention and recurrent
+    meet one copy) and they are freed with it. A latent-attention model's
     stacks, a GELU's w1, expert stacks, int8 leaves, a tree a mesh shards and a
     tree already in this form pass through untouched; with nothing to lay out
     the result is ``params`` itself."""
     if cfg.kv_lora_rank:
         return params
-    kinds, out = cfg.layer_kinds, params
-    per_head = {
-        next(k for k, v in params.items() if v is stack)
-        for layers_of, stack, _ in layer_groups(params, cfg) if kinds[layers_of[0]][0] == "attn"
+    out = params
+    stacks = {
+        next(k for k, v in params.items() if v is stack) for _, stack, _ in layer_groups(params, cfg)
     }
-    for key in sorted(per_head):
+    for key in sorted(stacks):
         mlp = params[key]["mlp"]
         halves = _serving_halves(mlp.get("w1"))
         if halves is not None:
@@ -428,8 +431,9 @@ def _attention_block(
     window = cfg.sliding_window if kind == "window" else 0
     if kind == "full" and not cfg.rope_full_layers:
         rope = None
-    # A mixed stack's device trace tells its kinds apart by this outer scope.
-    with jax.named_scope(f"attn.{kind}") if cfg.attn_kinds else contextlib.nullcontext():
+    # A mixed stack's device trace tells its kinds of layer apart by this outer
+    # scope: window from full layers, attention from recurrent ones.
+    with jax.named_scope(f"attn.{kind}") if cfg.attn_kinds or cfg.hybrid else contextlib.nullcontext():
         return _attention_core(
             blk, x, cfg, rope, positions, kv, cache_index, zigzag, pad_offsets, segments,
             paged, residual, window,
@@ -454,8 +458,7 @@ def _attention_core(
     """Per-head attention of ``_attention_block`` with the layer's ``window``
     (0 = full) and ``rope`` (None = no position encoding) settled."""
     cdt = jnp.dtype(cfg.compute_dtype)
-    with jax.named_scope("blk.norm"):
-        h = layers.apply_norm(cfg.norm, blk["ln1"], x, cfg.norm_eps)
+    h = layers.norm_in(cfg, blk["ln1"], x)
     # With no cache to write (training, evaluation) the projections see ONE
     # head of H*Dh lanes: the same dots, whose results end in (1, H*Dh) and
     # not (H, Dh), split into heads again after. The TPU compiler lays a dot's
@@ -507,7 +510,13 @@ def _attention_core(
         with jax.named_scope("attn.qkv"):
             q = (q.astype(jnp.float32) * (cfg.attention_multiplier * cfg.head_dim ** 0.5)).astype(cdt)
 
-    if "q_norm" in blk["attn"]:
+    if cfg.qk_norm_whole:
+        with jax.named_scope("attn.qk_norm"):
+            # over all heads' channels at once, before the heads are cut
+            whole = lambda p, a: layers.rmsnorm(
+                p, a.reshape(a.shape[:2] + (-1,)), cfg.norm_eps).reshape(a.shape)
+            q, k = whole(blk["attn"]["q_norm"], q), whole(blk["attn"]["k_norm"], k)
+    elif "q_norm" in blk["attn"]:
         with jax.named_scope("attn.qk_norm"):
             q = layers.rmsnorm(blk["attn"]["q_norm"], q, cfg.norm_eps)
             k = layers.rmsnorm(blk["attn"]["k_norm"], k, cfg.norm_eps)
@@ -594,8 +603,9 @@ def _attention_core(
             # One (B, T)-indexed scatter per pool: rows own disjoint
             # blocks and a row's T slots are distinct, so indices collide
             # only on the reserved scratch block (idle rows, overshoot
-            # redirects) — whose content is never unmasked.
-            return pool.at[blk_ids, slots].set(val.astype(pool.dtype))
+            # redirects) — whose content is never unmasked. A pool with
+            # padding heads (pool_kv_heads) gets whole rows: zeros there.
+            return pool.at[blk_ids, slots].set(pad_kv_heads(val, pool.shape[-2]).astype(pool.dtype))
 
         with jax.named_scope("attn.kv_write"):
             if quantized:
@@ -619,16 +629,12 @@ def _attention_core(
             # straight from the pool through the block table
             # (ops/pallas_paged.py), several a step of an in-row loop; the
             # slots of the table that hold nothing are never read.
-            from pretraining_llm_tpu.ops.pallas_paged import (
-                paged_decode_attention,
-            )
-
             with jax.named_scope("attn.core"):
                 out = paged_decode_attention(
                     q[:, 0].astype(cdt),
                     new_kv["k_pool"].astype(cdt),
                     new_kv["v_pool"].astype(cdt),
-                    tables, seq, window=window,
+                    tables, seq, window=window, kv_heads=cfg.kv_heads,
                 )
             out = out[:, None]
         else:
@@ -637,8 +643,10 @@ def _attention_core(
 
             def gather(pool):
                 # (B, max_blocks, block_size, ...) -> (B, kv_len, ...): each
-                # row's logical KV sequence, assembled from its pool blocks.
-                return pool[tables].reshape((bsz, kv_len) + pool.shape[2:])
+                # row's logical KV sequence, assembled from its pool blocks
+                # (without the pool's padding heads, where it has any).
+                rows = pool[tables].reshape((bsz, kv_len) + pool.shape[2:])
+                return rows if pool.shape[2] == cfg.kv_heads else rows[:, :, : cfg.kv_heads]
 
             with jax.named_scope("attn.paged_gather"):
                 if quantized:
@@ -839,7 +847,8 @@ def _attention_core(
             # Reference shape (attention.py:95): concat heads is the output.
             b, t = out.shape[:2]
             out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)
-        return layers.join_residual(x, out, cfg.residual_multiplier, residual), new_kv
+    out = layers.norm_out(cfg, blk["ln1"], out)
+    return layers.join_residual(x, out, cfg.residual_multiplier, residual), new_kv
 
 
 def _dense_mlp(mlp: Params, h: jax.Array, cfg: ModelConfig, limit: Any = None) -> jax.Array:
@@ -887,8 +896,7 @@ def _mlp_block(
     for a dropless expert layer the second value is the tokens routed to each
     expert, (E,) int32, instead."""
     cdt = jnp.dtype(cfg.compute_dtype)
-    with jax.named_scope("blk.norm"):
-        h = layers.apply_norm(cfg.norm, blk["ln2"], x, cfg.norm_eps).astype(cdt)
+    h = layers.norm_in(cfg, blk["ln2"], x).astype(cdt)
     mlp = blk["mlp"]
     with jax.named_scope("mlp"):
         if "router" in mlp and cfg.moe_dropless:
@@ -900,7 +908,8 @@ def _mlp_block(
             out, aux = moe.moe_mlp(mlp, h, cfg, decode=decode)
         else:
             out, aux = _dense_mlp(mlp, h, cfg), jnp.zeros((), jnp.float32)
-        return layers.join_residual(x, out, cfg.residual_multiplier, residual), aux
+    out = layers.norm_out(cfg, blk["ln2"], out)
+    return layers.join_residual(x, out, cfg.residual_multiplier, residual), aux
 
 
 def _block(
@@ -1202,14 +1211,30 @@ def forward(
         holds every layer of the group's kind, the group's from ``first`` on."""
         blocks, experts = without_experts(stack)
         n = len(layers_of)
-        if n != jax.tree.leaves(blocks)[0].shape[0]:
-            blocks = jax.tree.map(lambda a: a[first : first + n], blocks)
-        xs = blocks if cache is None else (blocks, cache)
         # a mixed stack's runs are each of one attention kind and one mixer (cfg.layer_runs)
         kind = cfg.attn_kinds[layers_of[0]] if cfg.attn_kinds else None
         mixer = cfg.layer_kinds[layers_of[0]][0]
         step = body if kind is None and mixer == "attn" else remat.checkpoint_wrap(
             functools.partial(scan_body, kind=kind, mixer=mixer), cfg.remat)
+        part = n != jax.tree.leaves(blocks)[0].shape[0]
+        if part and cache is not None and experts is None:
+            # A run that is part of its stack, in a serving program: the scan
+            # walks the run's places in the stack and takes each layer out of
+            # it there, as a scan over the whole stack takes its own. A slice
+            # of the stack handed to the scan is a copy of the run's weights
+            # (1.3 GB for three 7B-wide layers, beside pools that leave 2 GB).
+            # Training keeps the slice: a gradient through an index would add
+            # into a whole stack's worth of zeros a layer.
+            def at_place(carry, inputs):
+                place, cache_layer = inputs
+                blk = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, place, 0, keepdims=False), blocks)
+                return scan_body(carry, (blk, cache_layer), kind, mixer)
+
+            (x, aux), out = jax.lax.scan(at_place, (x, aux), (jnp.arange(first, first + n, dtype=jnp.int32), cache))
+            return x, aux, out
+        if part:
+            blocks = jax.tree.map(lambda a: a[first : first + n], blocks)
+        xs = blocks if cache is None else (blocks, cache)
         if experts is not None:
             clamps = clamps_of(layers_of)
 
@@ -1850,7 +1875,9 @@ def make_paged_kv_pool(
     scratch there too. Every other model gives every layer ``n_blocks``.
 
     One pool a layer, {'layers': (per-layer dicts,)}: {'k_pool','v_pool'}:
-    (n_blocks, block_size, kv_heads, Dh), plus scale pools when
+    (n_blocks, block_size, kv_heads, Dh) (the head axis rounded up where
+    ``ops/pallas_paged.py::pool_kv_heads`` says so: 30 heads of 128 are stored
+    as 32, so that the decode kernel reads the pages in place), plus scale pools when
     ``kv_cache_dtype='int8'``. A latent (MLA) model pools ``latent_dim``
     values a token, the same for every head:
     {'latent_pool': (n_blocks, block_size / fold, fold * kv_lora_rank),
@@ -1885,7 +1912,9 @@ def make_paged_kv_pool(
     if block_size % 8:
         # TPU sublane granularity; also keeps page gathers tile-aligned.
         raise ValueError(f"block_size must be a multiple of 8, got {block_size}")
-    shape = (cfg.n_cache_layers, n_blocks, block_size, cfg.kv_heads, cfg.head_dim)
+    # an unquantized per-head pool's head axis may carry padding (pool_kv_heads)
+    heads = cfg.kv_heads if cfg.kv_cache_dtype == "int8" else pool_kv_heads(cfg.kv_heads, cfg.head_dim)
+    shape = (cfg.n_cache_layers, n_blocks, block_size, heads, cfg.head_dim)
     if cfg.kv_lora_rank:
         if scale_dtype is not None:
             raise ValueError("a latent pool has no int8 pages yet (ROADMAP)")

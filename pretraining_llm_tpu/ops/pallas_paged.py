@@ -12,7 +12,8 @@ vLLM's PagedAttention memory model (SURVEY section 2.2).
 
 One form, ``paged_decode_attention``: one query a row (the serving decode
 step) over a pool whose pages are copies of their own
-(``pages_copy_in_place``), ``_decode_kernel``, after ``ops/pallas_latent.py``:
+(``pages_copy_in_place``; a pool of 30 heads is built with 32,
+``pool_kv_heads``), ``_decode_kernel``, after ``ops/pallas_latent.py``:
 
   - Grid ``(rows,)``; both pools stay in HBM (``memory_space=ANY``), block
     table and ``seq_lens`` are scalar prefetch. Inside a row a ``fori_loop``
@@ -79,6 +80,28 @@ def pages_copy_in_place(kv_heads: int, head_dim: int) -> bool:
     return head_dim % 128 == 0 and (kv_heads % 8 == 0 or 8 % kv_heads == 0)
 
 
+def pool_kv_heads(kv_heads: int, head_dim: int) -> int:
+    """The head axis an unquantized per-head pool is built with: ``kv_heads``,
+    or the next multiple of 8 where more than 8 heads of whole 128-lane tiles
+    neither fill nor divide the 8-sublane tile (30 -> 32: plain multi-head
+    attention at an odd head count), so that its pages are copies of their own
+    all the same (``pages_copy_in_place``). The heads past ``kv_heads`` are
+    padding: written as zeros with every token, masked in the kernel with the
+    foreign kv heads, cut off after the gather, never read as data. Up to 8
+    heads and narrow heads keep their count (a pad would multiply the pool, or
+    buy nothing: the kernel does not take them either way)."""
+    if head_dim % 128 == 0 and kv_heads > 8 and kv_heads % 8:
+        return -(-kv_heads // 8) * 8
+    return kv_heads
+
+
+def pad_kv_heads(x: jax.Array, heads: int) -> jax.Array:
+    """(..., G, Dh) -> (..., heads, Dh): zeros in the pool's padding heads
+    (``pool_kv_heads``); ``x`` itself where the pool has none."""
+    g = x.shape[-2]
+    return x if g == heads else jnp.pad(x, ((0, 0),) * (x.ndim - 2) + ((0, heads - g), (0, 0)))
+
+
 def _pages_a_step(pool: jax.Array, wanted: int) -> int:
     """``wanted`` pages a step, or as many as keep two groups of both pools
     inside ``_GROUP_BYTES`` of VMEM."""
@@ -99,7 +122,8 @@ def _decode_kernel(
     slot_ref,  # SMEM (1,) int32: the buffer this row's first group is in
     *,
     bs: int,
-    g: int,
+    g: int,  # the pool's head axis: the real kv heads first, then its padding heads
+    n_rep: int,  # query heads a real kv head
     pages: int,
     scale: float,
     window: int,
@@ -108,7 +132,6 @@ def _decode_kernel(
     n_rows = pl.num_programs(0)
     nb = tbl_ref.shape[1]
     h, d = q_ref.shape[1], q_ref.shape[2]
-    n_rep = h // g
     keys = bs * g  # rows of the flat key axis a page
 
     def live_pages(row):
@@ -150,7 +173,8 @@ def _decode_kernel(
 
     def slots_of_cols(width):
         """Column j of ``width`` pages side by side: slot (j // G) of kv head
-        j % G, pages ``bs`` slots apart; a query head sees its own kv head."""
+        j % G, pages ``bs`` slots apart; a query head sees its own kv head (a
+        padding head, past the last query head's, is no one's)."""
         row = jax.lax.broadcasted_iota(jnp.int32, (h, width * keys), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (h, width * keys), 1)
         return jnp.where(col % g == row // n_rep, col // g, _FOREIGN)
@@ -216,12 +240,12 @@ def _decode_kernel(
     o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "pages", "interpret"))
-def _decode_call(q, k_pool, v_pool, block_tables, seq_lens, window, pages, interpret):
+@functools.partial(jax.jit, static_argnames=("window", "pages", "interpret", "n_rep"))
+def _decode_call(q, k_pool, v_pool, block_tables, seq_lens, window, pages, interpret, n_rep=None):
     b, h, d = q.shape
     n_blocks, bs, g, _ = k_pool.shape
     kernel = functools.partial(
-        _decode_kernel, bs=bs, g=g, pages=pages, scale=1.0 / (d**0.5), window=window
+        _decode_kernel, bs=bs, g=g, n_rep=n_rep or h // g, pages=pages, scale=1.0 / (d**0.5), window=window
     )
     row_block = pl.BlockSpec((1, h, d), lambda bb, tbl, seq: (bb, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -258,8 +282,12 @@ def paged_decode_attention(
     window: int = 0,
     pages_per_step: int = PAGES_PER_STEP,
     interpret: Optional[bool] = None,
+    kv_heads: Optional[int] = None,
 ) -> jax.Array:
     """Paged decode attention straight off the block pool.
+
+    ``kv_heads``: the pool's real kv heads, the first of its head axis, where
+    the rest is padding (``pool_kv_heads``); None = the whole axis.
 
     The serving decode step, one query a row: each row's live pages are
     copied from the pools in place, ``pages_per_step`` a step of an in-row
@@ -280,8 +308,9 @@ def paged_decode_attention(
         )
     b, h, d = q.shape
     g = k_pool.shape[2]
-    if h % g != 0:
-        raise ValueError(f"kv heads ({g}) must divide query heads ({h})")
+    real = g if kv_heads is None else int(kv_heads)
+    if h % real != 0 or real > g:
+        raise ValueError(f"kv heads ({real} of the pool's {g}) must divide query heads ({h})")
     if k_pool.shape != v_pool.shape:
         raise ValueError(f"k/v pool mismatch: {k_pool.shape} vs {v_pool.shape}")
     if block_tables.shape[0] != b or seq_lens.shape != (b,):
@@ -297,7 +326,7 @@ def paged_decode_attention(
         )
     return _decode_call(
         q, k_pool, v_pool, block_tables, seq_lens, int(window),
-        _pages_a_step(k_pool, int(pages_per_step)), bool(interpret),
+        _pages_a_step(k_pool, int(pages_per_step)), bool(interpret), h // real,
     )
 
 

@@ -38,6 +38,9 @@ _DECAY_LEAVES = frozenset(
      "wf", "wbeta", "wg",
      # Mamba-2's input and output projections (models/mamba.py)
      "w_in", "w_out",
+     # Gated DeltaNet's decay projection (models/gdn.py; its other projections
+     # carry KDA's and Mamba-2's names)
+     "wa",
      # the multi-token-prediction module's (2D, D) projection (models/mtp.py)
      "eh_proj"}
 )

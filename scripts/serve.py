@@ -56,8 +56,8 @@ def main() -> None:
                         "pages back behind the window; such a model is served "
                         "without --prefix_cache, --kv_checksum, --quantize "
                         "int8-kv, --spec_k and --prefill_chunk_tokens. A model "
-                        "with recurrent layers (model.layer_mixers: Mamba-2 or "
-                        "KDA layers beside attention layers) gives these pages "
+                        "with recurrent layers (model.layer_mixers: Mamba-2, KDA "
+                        "or Gated DeltaNet layers beside attention layers) gives these pages "
                         "to its attention layers alone and keeps a state slot "
                         "a row (--max_batch of them) in every recurrent layer; "
                         "such a state-slot model is served without "

@@ -180,6 +180,7 @@ _PERF_INTENT = {
     # the same for the Trinity mechanisms (window and full layers, gated QK-normed GQA, four norms)
     "trinity-toy":     ("naive",        "none",           "chunked"),
     "granite-toy":     ("naive",        "none",           "chunked"),
+    "olmo-hybrid-toy": ("naive",        "none",           "chunked"),
 }
 
 

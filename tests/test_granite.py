@@ -266,13 +266,15 @@ def test_the_decode_step_is_one_more_token_of_the_prefill(params):
 # -- 3. prefill then decode through slots and pool ------------------------------------
 
 
-def _teacher_forced(p, seqs, prompt_lens, steps, readmit_row=None, readmit_at=None):
+def _teacher_forced(p, seqs, prompt_lens, steps, readmit_row=None, readmit_at=None, cfg=None):
     """Logits after each forced token, through the engine's prefill and decode
     lanes on hand-built tables, a row's state slot its index. ``readmit_row`` is
     preempted before step ``readmit_at``: its pages are freed and what it held
-    is prefilled anew into other pages and, as the engine does, its own slot."""
+    is prefilled anew into other pages and, as the engine does, its own slot.
+    ``cfg``: another state-slot model's (tests/test_olmo_hybrid.py)."""
+    cfg = cfg or CFG
     bs, max_blocks, rows = 8, 16, len(seqs)
-    pools = tr.make_paged_kv_pool(CFG, 64, bs, state_slots=rows)
+    pools = tr.make_paged_kv_pool(cfg, 64, bs, state_slots=rows)
     alloc = paged.BlockAllocator(64)
     tables = np.zeros((rows, max_blocks), np.int32)
     seq_lens = np.zeros((rows,), np.int32)
@@ -287,7 +289,7 @@ def _teacher_forced(p, seqs, prompt_lens, steps, readmit_row=None, readmit_at=No
     # batched prefill: rows of different lengths in one padded bucket, slots out of order
     order = list(range(rows))[::-1]
     _, pools = paged.prefill_into_pool_batched(
-        p, CFG, pools, [prompts[r] for r in order], [ids[r][: n_pre[r]] for r in order],
+        p, cfg, pools, [prompts[r] for r in order], [ids[r][: n_pre[r]] for r in order],
         jax.random.key(0), slots=order)
     for j in range(steps):
         if j == readmit_at:
@@ -299,10 +301,10 @@ def _teacher_forced(p, seqs, prompt_lens, steps, readmit_row=None, readmit_at=No
             tables[r] = 0
             tables[r, : len(ids[r])] = ids[r]
             _, pools = paged.prefill_into_pool(
-                p, CFG, pools, held, ids[r][: paged.required_blocks(len(held), bs)], slot=r)
+                p, cfg, pools, held, ids[r][: paged.required_blocks(len(held), bs)], slot=r)
         tok = np.asarray([s[n + j] for s, n in zip(seqs, prompt_lens)], np.int32)
         logits, pools = paged.paged_decode_logits(
-            p, pools, jnp.asarray(tok), jnp.asarray(tables), jnp.asarray(seq_lens), cfg=CFG)
+            p, pools, jnp.asarray(tok), jnp.asarray(tables), jnp.asarray(seq_lens), cfg=cfg)
         for r in range(rows):
             out[r].append(np.asarray(logits[r], np.float32))
         seq_lens += 1
@@ -476,9 +478,9 @@ def test_a_mid_prefill_row_rides_no_decode_window_and_its_slot_waits(params):
         assert not np.array_equal(after[name][0], before[name][0])
 
 
-@pytest.mark.parametrize("preset", ["granite-toy", "ling-mini"])
+@pytest.mark.parametrize("preset", ["granite-toy", "ling-mini", "olmo-hybrid-toy"])
 def test_the_engine_refuses_by_name_what_is_not_built_on_state_slots(preset):
-    """Either recurrent mixer: the refusals read the table, not a mixer's name."""
+    """Any recurrent mixer: the refusals read the table, not a mixer's name."""
     cfg = get_preset(preset).model
     p = tr.init_params(cfg, jax.random.key(0))
     for kw, name in ((dict(prefix_cache=True), "prefix_cache"), (dict(kv_checksum=True), "kv_checksum"),

@@ -196,8 +196,10 @@ MESH = object()  # any serving mesh: the pool may be sharded over it
     (4, True, "tpu", None, WIDE, "gather"),  # int8 pages under several queries
     (1, True, "cpu", None, WIDE, "gather"),
     (2, False, "tpu", None, dataclasses.replace(WIDE, n_heads=16, n_kv_heads=8), "gather"),  # a round of k = 1
-    (1, False, "tpu", None, dataclasses.replace(WIDE, n_heads=12, n_kv_heads=12), "gather"),  # 12 kv heads: XLA
-    # would copy the pool into another layout
+    (1, False, "tpu", None, dataclasses.replace(WIDE, n_heads=12, n_kv_heads=12), "kernel"),  # 12 kv heads: the
+    # pool stores 16 (pool_kv_heads), where XLA would copy a pool of 12 into another layout
+    (1, False, "tpu", None, dataclasses.replace(WIDE, n_heads=30, n_kv_heads=30), "kernel"),  # 30 as 32
+    (1, False, "tpu", None, dataclasses.replace(WIDE, n_heads=12, n_kv_heads=6), "gather"),  # up to 8: no padding
 ])
 def test_the_form_follows_the_input(tq, quantized, backend, mesh, cfg, form, monkeypatch):
     assert transformer.paged_attention_form(cfg, tq, quantized, backend=backend, mesh=mesh) == form

@@ -708,3 +708,65 @@ def test_state_slots_say_so_on_the_commit_span_and_in_pool_info(monkeypatch):
     assert info["bytes_per_slot"] == slot and info["state_bytes"] == 3 * slot
     assert info["pool_bytes"] == 24 * 8 * 2 * SSM_CFG.kv_heads * SSM_CFG.head_dim * 4  # one layer's pages
     assert info["decode_state"] == "jnp" and info["decode_experts"] == "grouped"
+
+
+# A Gated DeltaNet hybrid under output-side norms: the mixer's parts under `gdn.*`, the attention
+# layers under the scopes per-head attention has inside `attn.full` (what tells them from the
+# recurrent layers in a trace), `blk.norm` on each sublayer's output, the forms in pool_info.
+GDN_CFG = dataclasses.replace(get_preset("olmo-hybrid-toy").model, compute_dtype="float32")
+GDN_SCOPES = {
+    "decode": ("gdn.proj", "gdn.conv", "gdn.gate", "gdn.step", "gdn.out", "attn.full", "attn.qkv", "attn.qk_norm",
+               "attn.kv_write", "attn.core", "attn.out", "mlp", "blk.norm", "final_norm", "lm_head"),
+    "prefill": ("gdn.proj", "gdn.conv", "gdn.gate", "gdn.chunk", "gdn.out", "attn.full", "attn.qkv", "attn.kv_write",
+                "attn.core", "mlp", "blk.norm", "sample"),
+}
+
+
+@pytest.fixture(scope="module")
+def gdn_paths():
+    p = transformer.init_params(GDN_CFG, jax.random.key(0))
+    pools = lambda: transformer.make_paged_kv_pool(GDN_CFG, 16, 8, state_slots=2)
+    tables = jnp.asarray(np.arange(1, 9).reshape(2, 4), jnp.int32)
+    lowered = {
+        "decode": paged.paged_decode_steps.lower(
+            p, pools(), jnp.asarray([3, 5], jnp.int32), tables, jnp.asarray([4, 9], jnp.int32),
+            jax.random.key(1), GDN_CFG, n_steps=2),
+        "prefill": paged._prefill_scatter_sample.lower(
+            p, pools(), jnp.zeros((2, 16), jnp.int32), jnp.asarray([16, 11], jnp.int32),
+            jnp.asarray([[1, 2], [3, 4]], jnp.int32), jax.random.key(2), GDN_CFG, 16, 2,
+            slots=jnp.asarray([0, 1], jnp.int32)),
+    }
+    return {k: set(re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True))) for k, low in lowered.items()}
+
+
+@pytest.mark.parametrize("program,scope", [(p, s) for p, ss in GDN_SCOPES.items() for s in ss])
+def test_gdn_scope_is_in_the_lowered_program(gdn_paths, program, scope):
+    words = [re.split(r"[/()]", p) for p in gdn_paths[program]]
+    assert [w for w in words if scope in w]
+    # the one form a program runs: the recurrence in the decode step, the chunked form in a prefill
+    other = {"decode": "gdn.chunk", "prefill": "gdn.step"}[program]
+    assert not [w for w in words if other in w]
+    assert not [w for w in words if "attn.rope" in w or "kda.step" in w or "ssm.step" in w]
+    # the attention layers' parts stand inside attn.full, the recurrent layers' parts outside it
+    assert [w for w in words if "attn.core" in w and "attn.full" in w]
+    assert not [w for w in words if "attn.full" in w and [x for x in w if x.startswith("gdn.")]]
+
+
+def test_gdn_slots_and_forms_say_so_on_the_commit_span_and_in_pool_info(monkeypatch):
+    p = transformer.init_params(GDN_CFG, jax.random.key(0))
+    rec = spans.SpanRecorder()
+    monkeypatch.setattr(spans, "_default", rec)
+    eng = ServingEngine(p, GDN_CFG, max_batch=2, n_blocks=24, block_size=8, max_seq=64)
+    eng.submit(list(range(1, 20)), 12)
+    eng.submit(list(range(3, 9)), 12)
+    eng.run()
+    events, _ = rec.drain()
+    commits = [meta for name, *_, meta in events if name == "serving.commit"]
+    assert commits and max(m["state_slots"] for m in commits) == 2 == eng.stats["state_slots_peak"]
+    info = eng.pool_info()
+    assert (info["state_mixer"], info["state_layers"], info["page_layers"]) == ("gdn", 6, 2)
+    assert info["decode_state"] == "jnp" and info["decode_attention"] == "gather" and "decode_experts" not in info
+    assert info["pool_kv_heads"] == GDN_CFG.kv_heads == 3  # three heads of 16: stored as they are
+    wide = dataclasses.replace(GDN_CFG, n_heads=30, n_kv_heads=30, d_head=128)
+    pools = jax.eval_shape(lambda: transformer.make_paged_kv_pool(wide, 4, 8, state_slots=2))
+    assert pools["layers"][3]["k_pool"].shape == (4, 8, 32, 128)  # thirty heads of 128: stored as 32

@@ -468,7 +468,10 @@ PARENTS = {
     # the latent pool; the MTP module's layer and its round), as 6611a27 (PR 40) traces them:
     # PR 41's per-layer attention kind, second page table and window pool leave them be
     ("ling-3.0-flash", "decode"): (2549, "4525c94499540e5b"),
-    ("ling-3.0-flash", "prefill"): (2254, "6f0de077b97d34fb"),
+    # (the prefill since PR 54: a state-slot model's admission runs the head on each row's last
+    # position alone, `paged._prefill_last_logits`; the parent's 2,254 / 6f0de077b97d34fb with the
+    # head's dot over (N, 1, D) where it stood over (N, P, D) and the take of the last row ahead of it)
+    ("ling-3.0-flash", "prefill"): (2254, "14eb943ac170ae38"),
     ("ling-3.0-flash", "forward"): (2023, "96aa51f3110c061b"),
     ("joyai-llm-flash", "decode"): (1518, "2c64035367baaf79"),
     ("joyai-llm-flash", "prefill"): (613, "241d392a485a0b90"),
