@@ -121,8 +121,11 @@ class ModelConfig:
     # a head size of 64 or a multiple of 128 those kernels read q, k, v and
     # write o where the projections leave them, (B, T, H*Dh), with no
     # transposing copy around the call (heads_in_place: read from the shapes,
-    # no field selects it). A size under T selects the grid's kernels, which
-    # skip whole blocks and are handed the heads folded first.
+    # no field selects it); a fused QKV projection's result, (B, 3, T, H*Dh),
+    # goes to them whole where nothing stands between the two (no cache, no
+    # rotation or norm of q and k: models/transformer.py::_qkv_stays_whole),
+    # and d(qkv) comes back as one array. A size under T selects the grid's
+    # kernels, which skip whole blocks and are handed the heads folded first.
     flash_block_q: int = 0
     flash_block_kv: int = 0
     # Rematerialization policy applied to each scanned block — see

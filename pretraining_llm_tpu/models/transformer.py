@@ -42,6 +42,7 @@ from pretraining_llm_tpu.config import ModelConfig
 from pretraining_llm_tpu.models import hyper, layers, mla, moe, recurrent
 from pretraining_llm_tpu.ops import remat
 from pretraining_llm_tpu.ops.attention import multihead_attention
+from pretraining_llm_tpu.ops.flash_attention import flash_attention_qkv, flash_takes_qkv
 from pretraining_llm_tpu.ops.pallas_paged import (
     pad_kv_heads, pages_copy_in_place, paged_decode_attention, pool_kv_heads,
 )
@@ -440,6 +441,28 @@ def _attention_block(
         )
 
 
+def _qkv_stays_whole(
+    cfg: ModelConfig, attn: Params, kv: Any, rope: Any, segments: Any, window: int,
+    shape: Tuple[int, ...],
+) -> bool:
+    """Whether the fused projection's result, `shape` (B, 3, T, 1, H*Dh), goes
+    to the flash kernels as one array. Read from the call, never set: no cache
+    is written (training, evaluation), nothing stands between the projection
+    and the attention (no rotation for this layer, no q/k norm, no multiplier
+    on q), the call is plain causal attention by the flash implementation (no
+    window, no document mask), and flash_takes_qkv finds tiled kernels that
+    read these heads in place (T, head size, block sizes) and a mesh case
+    that reaches them, on a TPU."""
+    if kv is not None or rope is not None or segments is not None or window:
+        return False
+    if cfg.attention_multiplier or cfg.qk_norm_whole or "q_norm" in attn:
+        return False
+    return cfg.attention_impl == "flash" and flash_takes_qkv(
+        shape[:3] + shape[4:], shape[4] // cfg.head_dim,
+        block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv,
+    )
+
+
 def _attention_core(
     blk: Params,
     x: jax.Array,
@@ -469,7 +492,14 @@ def _attention_core(
     # heads_in_place) and no copy stands beside the call (PR 50, the
     # optimised HLO of both training cells). The programs that write a cache
     # are what they were.
+    # Where the fused projection's result can go to those kernels as it is,
+    # (B, 3, T, H*Dh), it does (qkv_whole; _qkv_stays_whole has the rule): they
+    # take q, k and v out of its planes themselves and hand back one d(qkv),
+    # and neither three slice copies a forward pass nor the gradient put
+    # together again from three stand beside the calls (PR 55). Every other
+    # call slices, as ever.
     as_lanes = kv is None
+    qkv_whole: Optional[jax.Array] = None
 
     def lanes(a: jax.Array) -> jax.Array:
         """(..., H, Dh) -> (..., 1, H*Dh) where the heads stay merged."""
@@ -484,7 +514,11 @@ def _attention_core(
             if "bqkv" in blk["attn"]:
                 bqkv = lanes(blk["attn"]["bqkv"].astype(cdt))  # (3, H, Dh)
                 qkv = qkv + bqkv[None, :, None, :, :]
-            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
+            if _qkv_stays_whole(cfg, blk["attn"], kv, rope, segments, window, qkv.shape):
+                qkv_whole = qkv.reshape(qkv.shape[:3] + (-1,))
+                q = k = v = None  # the kernels' to take out of qkv_whole
+            else:
+                q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
         else:
             # GQA: H query heads, kv_heads <= H key/value heads.
             q = jnp.einsum(
@@ -501,7 +535,7 @@ def _attention_core(
                 q = q + bq[None, None]
                 kvp = kvp + bkv[None, :, None]
             k, v = kvp[:, 0], kvp[:, 1]
-        if as_lanes:
+        if as_lanes and qkv_whole is None:
             q, k, v = (a.reshape(a.shape[:2] + (-1, cfg.head_dim)) for a in (q, k, v))
 
     if cfg.attention_multiplier:
@@ -811,17 +845,23 @@ def _attention_core(
                 current_mesh(), cfg.n_heads, cfg.kv_heads
             )
         with jax.named_scope("attn.core"):
-            out = multihead_attention(
-                q,
-                k if grouped_ok else rep(k),
-                v if grouped_ok else rep(v),
-                impl=cfg.attention_impl,
-                block_q=cfg.flash_block_q,
-                block_kv=cfg.flash_block_kv,
-                ring_layout="zigzag" if zigzag else "contiguous",
-                segments=segments,
-                window=window,
-            )
+            if qkv_whole is not None:
+                out = flash_attention_qkv(
+                    qkv_whole, qkv_whole.shape[-1] // cfg.head_dim,
+                    block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv,
+                )
+            else:
+                out = multihead_attention(
+                    q,
+                    k if grouped_ok else rep(k),
+                    v if grouped_ok else rep(v),
+                    impl=cfg.attention_impl,
+                    block_q=cfg.flash_block_q,
+                    block_kv=cfg.flash_block_kv,
+                    ring_layout="zigzag" if zigzag else "contiguous",
+                    segments=segments,
+                    window=window,
+                )
 
     # Tag for the 'save_attn' remat policy: keep the (cheap-to-store,
     # expensive-to-recompute) attention output, recompute everything else.

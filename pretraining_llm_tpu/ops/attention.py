@@ -13,6 +13,9 @@ materializing (B, T, T) scores per head with a pre-registered tril mask buffer
     accuracy), matmuls accumulate fp32 via preferred_element_type.
   - `impl='flash'` routes to the Pallas blockwise kernel (ops.flash_attention);
     `impl='ring'` to sequence-parallel ring attention (parallel.ring_attention).
+    A caller that holds q, k and v as one fused projection's result asks
+    ops.flash_attention.flash_takes_qkv and, on a yes, hands that array to
+    flash_attention_qkv instead of three slices of it to this dispatch.
 """
 
 from __future__ import annotations

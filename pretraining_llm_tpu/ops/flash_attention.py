@@ -155,7 +155,29 @@ def _pallas_available() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def shard_mapped_kernel(kernel, q, k, v, mesh, *, batch_axes=("data", "fsdp"),
+_BATCH_AXES = ("data", "fsdp")
+
+
+def _tensor_shards(mesh, batch: int, h: int, g: int, batch_axes) -> int:
+    """Over how many 'tensor' shards an attention call's heads split when the
+    call (`batch` rows, h query and g KV heads) is expressible per shard of
+    `mesh`; 0 when it is not (head counts not divisible by the tensor axis,
+    an indivisible batch; seq/pipe-sharded activations belong to the
+    ring/ulysses/pipeline paths)."""
+    if any(mesh.shape.get(ax, 1) > 1 for ax in ("seq", "pipe")):
+        return 0
+    batch_shards = 1
+    for ax in batch_axes:
+        batch_shards *= mesh.shape.get(ax, 1)
+    if batch % batch_shards != 0:
+        return 0  # small/partial batch: let the caller's fallback handle it
+    tp = mesh.shape.get("tensor", 1)
+    if tp > 1 and (h % tp != 0 or g % tp != 0):
+        return 0
+    return tp
+
+
+def shard_mapped_kernel(kernel, q, k, v, mesh, *, batch_axes=_BATCH_AXES,
                         segments=None):
     """Run an attention kernel per-shard under a batch/head-sharded mesh.
 
@@ -164,25 +186,15 @@ def shard_mapped_kernel(kernel, q, k, v, mesh, *, batch_axes=("data", "fsdp"),
     onto every device. This wraps it in a shard_map over the batch axes
     (+ 'tensor' on the head dim when the head counts divide).
 
-    Returns None when the layout isn't expressible per-shard (head counts
-    not divisible by the tensor axis; seq/pipe-sharded activations belong to
-    the ring/ulysses/pipeline paths) — caller falls back.
+    Returns None when the layout isn't expressible per-shard
+    (_tensor_shards) — caller falls back.
     """
     from jax.sharding import PartitionSpec as P
 
-    if any(mesh.shape.get(ax, 1) > 1 for ax in ("seq", "pipe")):
+    tp = _tensor_shards(mesh, q.shape[0], q.shape[2], k.shape[2], batch_axes)
+    if not tp:
         return None
-    batch_shards = 1
-    for ax in batch_axes:
-        batch_shards *= mesh.shape.get(ax, 1)
-    if q.shape[0] % batch_shards != 0:
-        return None  # small/partial batch: let the caller's fallback handle it
-    h, g = q.shape[2], k.shape[2]
-    tp = mesh.shape.get("tensor", 1)
-    if tp > 1 and (h % tp != 0 or g % tp != 0):
-        return None
-    head_ax = "tensor" if tp > 1 else None
-    spec = P(batch_axes, None, head_ax, None)
+    spec = P(batch_axes, None, "tensor" if tp > 1 else None, None)
     if segments is not None:
         seg_spec = P(batch_axes, None)
         return jax.shard_map(
@@ -194,6 +206,100 @@ def shard_mapped_kernel(kernel, q, k, v, mesh, *, batch_axes=("data", "fsdp"),
         kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)
+
+
+def _kernel_reach(mesh) -> str:
+    """How a Pallas attention kernel is reached under the active mesh:
+    'direct' (no mesh, or every nontrivial axis manual), 'shard_map' (no
+    manual axis) or 'partial' (a partial-manual region: not at all).
+
+    Manual-region classification (ADVICE r2): the direct kernel
+    call is only correct when EVERY nontrivial mesh axis is manual
+    (ulysses' all-to-all body — operands are per-device local
+    arrays). In a PARTIAL-manual region (the pipeline: manual over
+    'pipe' only) activations are still auto-sharded over
+    data/fsdp, so a direct pallas_call would be replicated by
+    GSPMD, all-gathering the global batch — and a nested shard_map
+    over the auto axes is not expressible either; the blockwise
+    fallback serves there (GSPMD partitions plain JAX ops)."""
+    if mesh is None or all(s == 1 for s in mesh.shape.values()):
+        return "direct"
+    abstract_mesh = jax.sharding.get_abstract_mesh()
+    manual_axes = {
+        name
+        for name, kind in zip(
+            abstract_mesh.axis_names, abstract_mesh.axis_types
+        )
+        if kind == jax.sharding.AxisType.Manual
+    }
+    nontrivial = {name for name, size in mesh.shape.items() if size > 1}
+    if nontrivial <= manual_axes:
+        return "direct"  # fully manual region
+    return "partial" if manual_axes else "shard_map"
+
+
+def _qkv_shards(shape, n_heads: int, block_q: int, block_kv: int):
+    """How flash_attention_qkv runs the tiled Pallas kernels on a fused
+    projection's result of `shape` (B, 3, T, H*Dh): (None, 1) for a direct
+    call, (mesh, tensor shards) for one a shard_map wraps; None where it has
+    no kernel for the call and the caller slices q, k and v out for
+    flash_attention."""
+    from pretraining_llm_tpu.ops.pallas_flash import qkv_heads_in_place
+    from pretraining_llm_tpu.parallel.sharding import current_mesh
+
+    if not _pallas_available() or len(shape) != 4 or shape[1] != 3 or shape[3] % n_heads:
+        return None
+    if not qkv_heads_in_place(shape[2], shape[3] // n_heads, n_heads, block_q, block_kv):
+        return None
+    mesh = current_mesh()
+    reach = _kernel_reach(mesh)
+    if reach == "direct":
+        return None, 1
+    tp = _tensor_shards(mesh, shape[0], n_heads, n_heads, _BATCH_AXES) if reach == "shard_map" else 0
+    return (mesh, tp) if tp else None
+
+
+def flash_takes_qkv(shape, n_heads: int, *, block_q: int = 0, block_kv: int = 0) -> bool:
+    """Whether flash_attention_qkv takes a fused QKV projection's result of
+    `shape` (B, 3, T, H*Dh) as it is: on a TPU, a plain causal call the tiled
+    kernels read in place (pallas_flash.qkv_heads_in_place), under a mesh
+    case in which flash_attention reaches the Pallas kernel. Read from the
+    call and the mesh; nothing selects it."""
+    return _qkv_shards(shape, n_heads, block_q, block_kv) is not None
+
+
+def flash_attention_qkv(
+    qkv: jax.Array, n_heads: int, *, block_q: int = 0, block_kv: int = 0
+) -> jax.Array:
+    """Plain causal flash attention over the q, k and v that are the planes of
+    one array, (B, 3, T, H*Dh) -> (B, T, H, Dh): flash_attention of the three
+    slices, without the slices and with one d(qkv) on the way back. For calls
+    flash_takes_qkv says yes to; the mesh cases are flash_attention's."""
+    from jax.sharding import PartitionSpec as P
+
+    from pretraining_llm_tpu.ops.pallas_flash import pallas_flash_attention_qkv
+
+    shards = _qkv_shards(qkv.shape, n_heads, block_q, block_kv)
+    if shards is None:
+        raise ValueError(f"no Pallas kernel takes q, k and v from one array of {qkv.shape} here")
+    mesh, tp = shards
+    kernel = functools.partial(
+        pallas_flash_attention_qkv, n_heads=n_heads // tp, block_q=block_q, block_kv=block_kv
+    )
+    if mesh is None:
+        return kernel(qkv)
+    # o crosses the shard_map's edge with its heads merged, (B, T, H*Dh), and
+    # is split into heads outside: with (B, T, 25, 64) at the edge the compiler
+    # relaid the recomputed o T minor-most for attn.out, a copy a layer a step
+    # and a slower dot (1.7% of the four-chip cell's step on the chip, PR 55).
+    b, _, t, lanes = qkv.shape
+    head_ax = "tensor" if tp > 1 else None
+    merged = jax.shard_map(
+        lambda x: kernel(x).reshape(x.shape[0], t, -1), mesh=mesh,
+        in_specs=(P(_BATCH_AXES, None, None, head_ax),),
+        out_specs=P(_BATCH_AXES, None, head_ax), check_vma=False,
+    )(qkv)
+    return merged.reshape(b, t, n_heads, lanes // n_heads)
 
 
 def flash_attention(
@@ -233,29 +339,10 @@ def flash_attention(
             block_kv=block_kv, window=window,
         )
         mesh = current_mesh()
-        if mesh is None or all(s == 1 for s in mesh.shape.values()):
+        reach = _kernel_reach(mesh)
+        if reach == "direct":
             return kernel(q, k, v, segments=segments)
-        # Manual-region classification (ADVICE r2): the direct kernel
-        # call is only correct when EVERY nontrivial mesh axis is manual
-        # (ulysses' all-to-all body — operands are per-device local
-        # arrays). In a PARTIAL-manual region (the pipeline: manual over
-        # 'pipe' only) activations are still auto-sharded over
-        # data/fsdp, so a direct pallas_call would be replicated by
-        # GSPMD, all-gathering the global batch — and a nested shard_map
-        # over the auto axes is not expressible either; use the
-        # blockwise fallback there (GSPMD partitions plain JAX ops).
-        abstract_mesh = jax.sharding.get_abstract_mesh()
-        manual_axes = {
-            name
-            for name, kind in zip(
-                abstract_mesh.axis_names, abstract_mesh.axis_types
-            )
-            if kind == jax.sharding.AxisType.Manual
-        }
-        nontrivial = {name for name, size in mesh.shape.items() if size > 1}
-        if nontrivial <= manual_axes:
-            return kernel(q, k, v, segments=segments)  # fully manual region
-        if not manual_axes:
+        if reach == "shard_map":
             out = shard_mapped_kernel(kernel, q, k, v, mesh, segments=segments)
             if out is not None:
                 return out
@@ -269,7 +356,7 @@ def flash_attention(
         why = (
             "inside a partial-manual shard_map region (e.g. the "
             "pipeline's pipe-only region)"
-            if manual_axes
+            if reach == "partial"
             else "the mesh/shape layout is not expressible per-shard "
             "(seq/pipe-sharded activations, or batch/head counts not "
             "divisible by the mesh axes)"

@@ -33,6 +33,13 @@ fully-materialized (B,H,T,T) scores, /root/reference/src/models/attention.py:51-
     of such a call, forward, recompute or backward, and the backward takes
     D = rowsum(dO*O) from the blocks it already holds. Every other call folds
     the heads first ((B*H, T, D): _heads_first / _heads_last).
+  - Where q, k and v are the three planes of ONE array, a fused projection's
+    (B, 3, T, H*D), the same tiled kernels take them out of it themselves
+    (pallas_flash_attention_qkv): an operand's block is a column block of
+    plane c, the index map choosing c, and the backward writes dq, dk and dv
+    into one block of one d(qkv) of that shape. Neither the three slices of
+    the projection's result nor the gradient put together again from three
+    exist beside such a call.
 
 All kernels run under interpret mode on CPU for unit testing (tests compare
 against the naive einsum path).
@@ -141,9 +148,11 @@ def heads_in_place(d: int, h: int, g: int, n_tiles: int) -> int:
 
 
 @functools.lru_cache(maxsize=None)
-def _log_form(bh: int, t: int, d: int, bq: int, bk: int, n: int, heads: int) -> None:
-    """One INFO line a distinct shape, at trace time: which form it takes, and
-    where the kernels find the heads (heads_in_place)."""
+def _log_form(bh: int, t: int, d: int, bq: int, bk: int, n: int, heads: int,
+              one: bool = False) -> None:
+    """One INFO line a distinct shape, at trace time: which form it takes,
+    where the kernels find the heads (heads_in_place), and whether they find
+    q, k and v in one array (pallas_flash_attention_qkv)."""
     if n:
         form = (f"causal tiles of {t // n}, {n * (n + 1) // 2} of {n * n} "
                 "sub-tiles computed")
@@ -151,6 +160,8 @@ def _log_form(bh: int, t: int, d: int, bq: int, bk: int, n: int, heads: int) -> 
         form = f"block grid {t // bq} x {t // bk} of ({bq}, {bk})"
     layout = (f"heads in place, {heads} a block of {heads * d} lanes" if heads
               else "heads first")
+    if one:
+        layout += ", q, k and v from one array"
     logger.info("flash attention (B*H, T, D) = (%d, %d, %d): %s; %s", bh, t, d, form, layout)
 
 
@@ -352,35 +363,43 @@ def _fwd_tiles_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, scale, tile
         o_ref[0, rows, :] = o.astype(o_ref.dtype)
 
 
-def _tile_spec(in_place: bool, t: int, width: int, n: int, at) -> pl.BlockSpec:
+def _tile_spec(in_place: bool, t: int, width: int, n: int, at,
+               plane: Optional[int] = None) -> pl.BlockSpec:
     """One (T, width) block of a tiled call's operand or result: block
     `at(*grid ids)` of the n that batch row ids[0] holds. In place the array
     is (B, T, n*width) (less what an odd head count leaves off the last
     block) and the block a column block of its lanes; with the heads folded
-    first it is (B*n, T, width) and the block a row of it."""
+    first it is (B*n, T, width) and the block a row of it. `plane` c makes
+    the array (B, 3, T, n*width), q, k and v in one, and the block that
+    column block of its plane c: the kernel sees the same (1, T, width)."""
+    if plane is not None:
+        return pl.BlockSpec((1, None, t, width), lambda *ids: (ids[0], plane, 0, at(*ids)))
     if in_place:
         return pl.BlockSpec((1, t, width), lambda *ids: (ids[0], 0, at(*ids)))
     return pl.BlockSpec((1, t, width), lambda *ids: (ids[0] * n + at(*ids), 0, 0))
 
 
 def _dims(q: jax.Array, h: int, heads: int) -> Tuple[int, int, int]:
-    """(B, T, D) of a q handed over in place, (B, T, H*D), where `heads`
-    (heads_in_place) says so, and with its heads folded first, (B*H, T, D)."""
+    """(B, T, D) of a q handed over in place, (B, T, H*D) or the (B, 3, T, H*D)
+    it is a plane of, where `heads` (heads_in_place) says so, and with its
+    heads folded first, (B*H, T, D)."""
     if heads:
-        return q.shape[0], q.shape[1], q.shape[2] // h
+        return q.shape[0], q.shape[-2], q.shape[-1] // h
     return q.shape[0] // h, q.shape[1], q.shape[2]
 
 
-def _tiles_layout(t: int, d: int, h: int, g: int, heads: int):
+def _tiles_layout(t: int, d: int, h: int, g: int, heads: int, one: bool = False):
     """What the forward and the backward of a tiled call share: the heads and
     the lanes of a block, the blocks a batch row's q and its k hold, the
-    static `edge` of _kv_inside, and _tile_spec left to take (blocks, at)."""
+    static `edge` of _kv_inside, _tile_spec left to take (blocks, at, plane),
+    and the planes q, k and v are of the array they are handed as (`one`: of
+    a fused projection's (B, 3, T, H*D); else each an array of its own)."""
     in_block = max(heads, 1)
     width = d * in_block
     nbq, nbk = pl.cdiv(h, in_block), pl.cdiv(g, in_block)
     edge = (h % in_block) * d
     spec = functools.partial(_tile_spec, bool(heads), t, width)
-    return in_block, width, nbq, nbk, edge, spec
+    return in_block, width, nbq, nbk, edge, spec, (0, 1, 2) if one else (None, None, None)
 
 
 def _seg_views(segments: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -395,11 +414,14 @@ def _fwd(
     q: jax.Array, k: jax.Array, v: jax.Array, h: int, g: int, *,
     causal: bool, block_q: int, block_kv: int, interpret: bool,
     segments: Optional[jax.Array] = None, window: int = 0, heads: int = 0,
+    one: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """q (B*H, T, D) and k, v (B*G, T, D) -> o like q and lse (B*H, T, 1);
     with `heads` (heads_in_place) q (B, T, H*D), k, v (B, T, G*D) and lse
     (B*blocks*heads, T, 1), a row a head of each block (an odd head count's
-    last row of a batch row is nobody's)."""
+    last row of a batch row is nobody's). With `one` (a tiled call in place,
+    h = g) q, k and v are all three the one (B, 3, T, H*D) array whose planes
+    they are, and o is (B, T, H*D) as ever."""
     b, t, d = _dims(q, h, heads)
     bh = b * h
     n_rep = h // g
@@ -408,22 +430,22 @@ def _fwd(
     scale = 1.0 / (d**0.5)
 
     n_tiles = causal_tiles(t, bq, bk, causal, window, segments)
-    _log_form(bh, t, d, bq, bk, n_tiles, heads)
+    _log_form(bh, t, d, bq, bk, n_tiles, heads, one)
     if n_tiles:
-        in_block, width, nbq, nbk, edge, spec = _tiles_layout(t, d, h, g, heads)
+        in_block, width, nbq, nbk, edge, spec, (qp, kp, vp) = _tiles_layout(t, d, h, g, heads, one)
         head = lambda bb, hh: hh
         kv_head = lambda bb, hh: hh // n_rep
         return pl.pallas_call(
             functools.partial(_fwd_tiles_kernel, scale=scale, tile=t // n_tiles, n=n_tiles,
                               heads=in_block, edge=edge),
             grid=(b, nbq),
-            in_specs=[spec(nbq, head), spec(nbk, kv_head), spec(nbk, kv_head)],
+            in_specs=[spec(nbq, head, qp), spec(nbk, kv_head, kp), spec(nbk, kv_head, vp)],
             out_specs=[
                 spec(nbq, head),
                 pl.BlockSpec((in_block, t, 1), lambda bb, hh: (bb * nbq + hh, 0, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct((b,) + q.shape[-2:] if one else q.shape, q.dtype),
                 jax.ShapeDtypeStruct((b * nbq * in_block, t, 1), jnp.float32),
             ],
             scratch_shapes=[pltpu.VMEM((t, width), k.dtype)] * (2 if edge else 0),
@@ -720,28 +742,47 @@ def _bwd_tiles_kernel(
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _bwd_tiles_one_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dqkv_ref, *scratch, **static):
+    """_bwd_tiles_kernel writing dq, dk and dv into the three planes of one
+    (1, 3, T, width) block of d(qkv): the same body over three views of it."""
+    dq_ref, dk_ref, dv_ref = (dqkv_ref.at[:, c] for c in range(3))
+    _bwd_tiles_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, dk_ref, dv_ref,
+                      *scratch, **static)
+
+
 def _bwd_tiles(
     h: int, g: int, heads: int, n_tiles: int, interpret: bool, q, k, v, o, lse, do,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """The fused backward of a call causal_tiles takes, in _fwd's layouts."""
+    one: bool = False,
+):
+    """The fused backward of a call causal_tiles takes, in _fwd's layouts:
+    (dq, dk, dv), or with `one` the one d(qkv) of the one array's shape. There
+    h = g, so a grid step's dq, dk and dv blocks share their index and one
+    output block takes all three: three outputs that XLA stacked after the
+    call would be the copy the one array is there to spare."""
     b, t, d = _dims(q, h, heads)
     n_rep = h // g
-    in_block, width, nbq, nbk, edge, spec = _tiles_layout(t, d, h, g, heads)
+    in_block, width, nbq, nbk, edge, spec, (qp, kp, vp) = _tiles_layout(t, d, h, g, heads, one)
     head = lambda bb, hh, r: hh * n_rep + r
     kv_head = lambda bb, hh, r: hh
+    if one:
+        out_specs = pl.BlockSpec((1, 3, t, width), lambda bb, hh, r: (bb, 0, 0, hh))
+        out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    else:
+        out_specs = [spec(nbq, head), spec(nbk, kv_head), spec(nbk, kv_head)]
+        out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)]
     return pl.pallas_call(
         functools.partial(
-            _bwd_tiles_kernel, scale=1.0 / (d**0.5), n_rep=n_rep, tile=t // n_tiles,
-            n=n_tiles, heads=in_block, edge=edge,
+            _bwd_tiles_one_kernel if one else _bwd_tiles_kernel, scale=1.0 / (d**0.5),
+            n_rep=n_rep, tile=t // n_tiles, n=n_tiles, heads=in_block, edge=edge,
         ),
         grid=(b, nbk, n_rep),
         in_specs=[
-            spec(nbq, head), spec(nbk, kv_head), spec(nbk, kv_head),  # q, k, v
+            spec(nbq, head, qp), spec(nbk, kv_head, kp), spec(nbk, kv_head, vp),  # q, k, v
             spec(nbq, head), spec(nbq, head),  # do, o
             pl.BlockSpec((in_block, t, 1), lambda bb, hh, r: (bb * nbq + head(bb, hh, r), 0, 0)),
         ],
-        out_specs=[spec(nbq, head), spec(nbk, kv_head), spec(nbk, kv_head)],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((t, width), jnp.float32)] * 2
         + [pltpu.VMEM((t, width), k.dtype)] * (2 if edge else 0),
         interpret=interpret,
@@ -952,6 +993,35 @@ def _flash_seg_bwd(h, g, causal, block_q, block_kv, interpret, window, residuals
 _flash_seg.defvjp(_flash_seg_fwd, _flash_seg_bwd)
 
 
+# One-array variant (pallas_flash_attention_qkv): the VJP is over the fused
+# projection's (B, 3, T, H*D) itself, so the residuals hold that one array and
+# the cotangent is one d(qkv). Always a plain causal call causal_tiles takes,
+# in place (`heads` > 0), with h = g.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
+def _flash_one(qkv, h, block_q, block_kv, interpret, heads):
+    return _flash_one_fwd(qkv, h, block_q, block_kv, interpret, heads)[0]
+
+
+def _flash_one_fwd(qkv, h, block_q, block_kv, interpret, heads):
+    o, lse = _fwd(qkv, qkv, qkv, h, h, causal=True, block_q=block_q, block_kv=block_kv,
+                  interpret=interpret, heads=heads, one=True)
+    # the tags and the squeeze of lse: see _flash_fwd
+    o_res = checkpoint_name(o, "attn_o_res")
+    lse2 = checkpoint_name(lse[..., 0], "attn_lse")
+    return o, (qkv, o_res, lse2)
+
+
+def _flash_one_bwd(h, block_q, block_kv, interpret, heads, residuals, grad):
+    qkv, o, lse2 = residuals
+    t = qkv.shape[2]
+    n_tiles = causal_tiles(t, *_block_sizes(t, block_q, block_kv), True, 0, None)
+    return (_bwd_tiles(h, h, heads, n_tiles, interpret, qkv, qkv, qkv, o, lse2[..., None],
+                       grad, one=True),)
+
+
+_flash_one.defvjp(_flash_one_fwd, _flash_one_bwd)
+
+
 def pallas_flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -972,7 +1042,9 @@ def pallas_flash_attention(
     of 64 or a multiple of 128: every training step at T <= 1024) the
     kernels are handed the arrays as they are, reshaped (B, T, H*Dh) for
     free, and return o the same way; every other call folds the heads first
-    and unfolds o, a transposing copy each.
+    and unfolds o, a transposing copy each. A caller that holds q, k and v as
+    one fused projection's result hands that to pallas_flash_attention_qkv
+    instead of three slices of it to this.
 
     ``segments`` (B, T) int32 document ids restricts attention to keys of
     the query's own document (packed-sequence training; composed with the
@@ -1011,3 +1083,47 @@ def pallas_flash_attention(
         of = _flash(qf, kf, vf, h, g, causal, block_q, block_kv, interpret,
                     int(window), 0)
     return _heads_last(of, b, h)
+
+
+def qkv_heads_in_place(t: int, d: int, h: int, block_q: int = 0, block_kv: int = 0) -> int:
+    """heads_in_place of plain causal self-attention over h = g heads of d at
+    length t: what pallas_flash_attention_qkv needs above 0 to take a call."""
+    bq, bk = _block_sizes(t, block_q, block_kv)
+    return heads_in_place(d, h, h, causal_tiles(t, bq, bk, True, 0, None))
+
+
+def pallas_flash_attention_qkv(
+    qkv: jax.Array,
+    n_heads: int,
+    *,
+    block_q: int = 0,
+    block_kv: int = 0,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Plain causal flash attention with q, k and v taken out of one array.
+    qkv: (B, 3, T, H*Dh), a fused QKV projection's result with the heads
+    merged in the lanes (plane 0 q, 1 k, 2 v; H = n_heads of each). Returns
+    (B, T, H, Dh), what pallas_flash_attention(qkv[:, 0], qkv[:, 1],
+    qkv[:, 2]) returns bit for bit, and its VJP one d(qkv) of qkv's shape.
+
+    Only for calls the tiled kernels read in place (qkv_heads_in_place > 0: a
+    lone causal block a head, T a multiple of 512, heads of 64 or of a
+    multiple of 128); a caller asks that first and slices otherwise. The
+    kernels are pallas_flash_attention's own: an operand's block is a column
+    block of plane c of the one array, and the backward's one output block
+    holds dq, dk and dv, so no slice of qkv and no stacking of three
+    gradients stands beside the calls.
+    """
+    if interpret is None:
+        interpret = jax.devices()[0].platform != "tpu"
+    b, planes, t, lanes = qkv.shape
+    if planes != 3 or lanes % n_heads:
+        raise ValueError(f"qkv must be (B, 3, T, H*Dh) with H = {n_heads}, got {qkv.shape}")
+    heads = qkv_heads_in_place(t, lanes // n_heads, n_heads, block_q, block_kv)
+    if not heads:
+        raise ValueError(
+            f"no tiled kernel reads {n_heads} heads of {lanes // n_heads} at T = {t} in place: "
+            "slice qkv and call pallas_flash_attention"
+        )
+    of = _flash_one(qkv, n_heads, block_q, block_kv, interpret, heads)
+    return of.reshape(b, t, n_heads, lanes // n_heads)

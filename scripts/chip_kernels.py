@@ -255,6 +255,26 @@ def time_moe(reps: int = 20, only: str = ""):
                 del xs, w1, w2
 
 
+def flash_qkv_case(t: int, h: int, dh: int):
+    """``pallas_flash_attention_qkv`` (q, k and v out of one (B, 3, T, H*Dh)
+    array, one d(qkv) back) against the three-array entry over slices of the
+    same array: the same kernels on the same values, so equal bit for bit."""
+    from pretraining_llm_tpu.ops.pallas_flash import pallas_flash_attention, pallas_flash_attention_qkv
+
+    b = 2
+    ks = jax.random.split(jax.random.key(t + h), 2)
+    qkv = jax.random.normal(ks[0], (b, 3, t, h * dh), jnp.bfloat16)
+    w = jax.random.normal(ks[1], (b, t, h, dh), jnp.float32)
+    one = lambda x: pallas_flash_attention_qkv(x, h)
+    three = lambda x: pallas_flash_attention(*(x[:, c].reshape(b, t, h, dh) for c in range(3)))
+    both = lambda fn: jax.jit(
+        lambda x: (fn(x), jax.grad(lambda x: jnp.sum(fn(x).astype(jnp.float32) * w))(x)))(qkv)
+    (o1, g1), (o3, g3) = both(one), both(three)
+    if not float(jnp.max(jnp.abs(g3.astype(jnp.float32)))) > 0:
+        return {"dqkv": float("inf")}, 0.0
+    return {"o": _err(o1, o3), "dqkv": _err(g1, g3)}, 0.0
+
+
 def kda_case(rows: int, heads: int):
     """``ops/pallas_kda.py`` against ``kda.recurrent_step``, the state donated
     to both as the decode programs donate the pools; the last row is dead
@@ -295,6 +315,9 @@ def cases():
     # heads in place (pallas_flash.heads_in_place): an odd count of 64, grouped heads of 128, 256
     for h, g, dh in ((25, 25, 64), (8, 2, 128), (2, 1, 256)):
         yield f"flash t1024 h{h} g{g} dh{dh}", flash_case, (1024, g, False, h, dh)
+    # q, k and v out of a fused projection's one array: the training cells' heads, heads of 128
+    for t, h, dh in ((1024, 20, 64), (1024, 25, 64), (512, 8, 128)):
+        yield f"flash qkv t{t} h{h} dh{dh}", flash_qkv_case, (t, h, dh)
     for page in (64, 16):
         for g in (8, 32):  # grouped and ungrouped heads of 128, in place
             yield f"paged page{page} g{g} t1 h32 dh128", paged_case, (g, page, 32, 128)

@@ -211,3 +211,128 @@ def test_shard_mapped_kernel_rejects_indivisible_batch(mesh8):
     q, k, v = (jax.random.normal(kk, (2, 32, 4, 8), jnp.float32) for kk in ks)
     kernel = functools.partial(pallas_flash_attention, causal=True, interpret=True)
     assert shard_mapped_kernel(kernel, q, k, v, mesh8) is None  # 2 % 4 != 0
+
+
+# -- the fused QKV projection's one array goes to the tiled flash kernels as it is -----------
+# (models/transformer.py::_qkv_stays_whole; ops/flash_attention.py::flash_attention_qkv)
+
+
+def _toy(**kw):
+    from pretraining_llm_tpu.config import ModelConfig
+
+    base = dict(vocab_size=128, context_length=512, d_model=128, n_heads=2, n_layers=2,
+                pos_embed="learned", attention_impl="flash", compute_dtype="bfloat16", remat="full")
+    return ModelConfig(**{**base, **kw})
+
+
+def _program(cfg, t, cached=False):
+    """The traced loss gradient (or a prefill that writes a cache) of a toy and its inputs."""
+    from pretraining_llm_tpu.models import transformer
+
+    params = transformer.init_params(cfg, jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (2, t), 1, cfg.vocab_size)
+    if cfg.doc_mask_token >= 0:
+        tokens = tokens.at[:, t // 3].set(cfg.doc_mask_token)
+    if cached:
+        cache = transformer.make_kv_cache(cfg, 2, t)
+        fn = lambda p: transformer.forward(p, tokens, cfg, kv_cache=cache, cache_index=0)[0]
+    else:
+        fn = jax.value_and_grad(lambda p: transformer.loss_fn(p, tokens, jnp.roll(tokens, -1, 1), cfg))
+    return fn, params
+
+
+def _flash_operands(fn, params):
+    """Shapes of the first operand of every flash pallas_call of a traced program, and the
+    shapes every slice in it cuts from."""
+    from tests.test_pallas_flash import _equations
+
+    eqns = _equations(jax.make_jaxpr(fn)(params).jaxpr)
+    calls = [e.invars[0].aval.shape for e in eqns if e.primitive.name == "pallas_call"]
+    sliced = [e.invars[0].aval.shape for e in eqns if e.primitive.name == "slice"]
+    return calls, sliced
+
+
+_RULE_CASES = {
+    # (config overrides, T, cached): does the projection's result stay whole?
+    "gpt2_toy_no_cache": (dict(), 512, False, True),
+    "gpt2_toy_heads_of_128": (dict(n_heads=1), 1024, False, True),
+    "rotary_toy": (dict(pos_embed="rope"), 512, False, False),
+    "segments_batch": (dict(doc_mask_token=0), 512, False, False),
+    "cached_prefill": (dict(), 512, True, False),
+    "t_of_2048": (dict(context_length=2048), 2048, False, False),
+    "grouped_heads_hold_no_wqkv": (dict(n_heads=4, n_kv_heads=2, d_model=256), 512, False, False),
+    "qk_norm_between": (dict(qk_norm=True), 512, False, False),
+    "explicit_block_under_t": (dict(flash_block_q=256), 512, False, False),
+    "naive_implementation": (dict(attention_impl="naive"), 512, False, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RULE_CASES))
+def test_who_hands_the_flash_kernels_the_projections_one_array(monkeypatch, case):
+    import pretraining_llm_tpu.ops.flash_attention as fa
+
+    monkeypatch.setattr(fa, "_pallas_available", lambda: True)  # interpreted off the TPU
+    overrides, t, cached, whole = _RULE_CASES[case]
+    cfg = _toy(**overrides)
+    calls, sliced = _flash_operands(*_program(cfg, t, cached))
+    lanes = cfg.n_heads * cfg.head_dim
+    one_array, projected = (2, 3, t, lanes), (2, 3, t, 1, lanes)
+    if whole:
+        # forward, recomputed forward and backward, all on the one array; nothing slices it
+        assert calls == [one_array] * 3, calls
+        assert one_array not in sliced and projected not in sliced
+    else:
+        assert one_array not in calls
+        if cfg.attention_impl == "flash":
+            assert calls, "the case must still reach the Pallas kernels"
+        if "wqkv" in _program(cfg, t, cached)[1]["blocks"]["attn"]:
+            assert any(s[:3] == (2, 3, t) for s in sliced), sliced  # today's three slices
+
+
+def test_off_the_tpu_nothing_takes_the_one_array_path():
+    calls, sliced = _flash_operands(*_program(_toy(), 512))
+    assert calls == [] and (2, 3, 512, 1, 128) in sliced
+
+
+@pytest.mark.parametrize("bias", [True, False])
+def test_one_array_path_gives_the_sliced_paths_loss_and_gradients_bit_for_bit(monkeypatch, bias):
+    import pretraining_llm_tpu.ops.flash_attention as fa
+    from pretraining_llm_tpu.models import transformer
+
+    monkeypatch.setattr(fa, "_pallas_available", lambda: True)
+    cfg = _toy(qkv_bias=bias, mlp_bias=bias)
+    fn, params = _program(cfg, 512)
+    loss_one, grads_one = jax.jit(fn)(params)
+    monkeypatch.setattr(transformer, "flash_takes_qkv", lambda *a, **k: False)  # the parent's lines
+    fn, _ = _program(cfg, 512)
+    assert _flash_operands(fn, params)[0] == [(2, 512, 128)] * 3
+    loss_three, grads_three = jax.jit(fn)(params)
+    assert np.isfinite(float(loss_one)) and float(loss_one) == float(loss_three)
+    same = jax.tree.map(
+        lambda a, b: np.array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32)),
+        grads_one, grads_three)
+    assert all(jax.tree.leaves(same)), same
+    assert float(jnp.abs(grads_one["blocks"]["attn"]["wqkv"].astype(jnp.float32)).max()) > 0
+
+
+def test_one_array_entry_under_a_batch_sharded_mesh_matches_the_three_array_entry(monkeypatch, mesh8):
+    """The shard_map case the four-chip cell takes: batch over data x fsdp, heads over tensor."""
+    import pretraining_llm_tpu.ops.flash_attention as fa
+    from pretraining_llm_tpu.parallel.sharding import activation_mesh
+
+    monkeypatch.setattr(fa, "_pallas_available", lambda: True)
+    b, t, h, d = 4, 512, 4, 64
+    qkv = jax.random.normal(jax.random.key(21), (b, 3, t, h * d), jnp.bfloat16)
+    w = jax.random.normal(jax.random.key(22), (b, t, h, d), jnp.float32)
+    three = lambda x: fa.flash_attention(*(x[:, c].reshape(b, t, h, d) for c in range(3)), causal=True)
+    one = lambda x: fa.flash_attention_qkv(x, h)
+    grad = lambda fn: jax.jit(jax.value_and_grad(lambda x: jnp.sum(fn(x).astype(jnp.float32) * w)))
+    with activation_mesh(mesh8):
+        assert fa.flash_takes_qkv(qkv.shape, h)
+        assert not fa.flash_takes_qkv((2,) + qkv.shape[1:], h)  # 2 rows over 4 batch shards
+        assert not fa.flash_takes_qkv((b, 3, t, 3 * d), 3)  # 3 heads over 2 tensor shards
+        (l1, g1), (l3, g3) = grad(one)(qkv), grad(three)(qkv)
+    assert float(l1) == float(l3)
+    assert np.array_equal(np.asarray(g1, np.float32), np.asarray(g3, np.float32))
+    with pytest.raises(ValueError, match="no Pallas kernel takes"):
+        fa.flash_attention_qkv(qkv[:, :, :256], h)

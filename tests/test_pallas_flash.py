@@ -7,7 +7,7 @@ import pytest
 
 from pretraining_llm_tpu.ops import pallas_flash
 from pretraining_llm_tpu.ops.attention import naive_attention
-from pretraining_llm_tpu.ops.pallas_flash import pallas_flash_attention
+from pretraining_llm_tpu.ops.pallas_flash import pallas_flash_attention, pallas_flash_attention_qkv
 
 
 def _qkv(key, b=2, t=64, h=2, dh=16, dtype=jnp.float32):
@@ -416,6 +416,68 @@ def test_calls_outside_the_rule_trace_the_parents_program(case):
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == parent
 
 
+# -- q, k and v taken out of a fused projection's one array (pallas_flash_attention_qkv) ------
+
+
+def _one_and_three(b, t, h, d):
+    """The one-array entry and the three-array entry over slices of the same array, as
+    functions of a bfloat16 (B, 3, T, H*D)."""
+    one = lambda qkv: pallas_flash_attention_qkv(qkv, h, interpret=True)
+    three = lambda qkv: pallas_flash_attention(
+        *(qkv[:, c].reshape(b, t, h, d) for c in range(3)), causal=True, interpret=True)
+    return one, three
+
+
+@pytest.mark.parametrize("t", [512, 1024])  # 2 and 4 sub-tiles a side at the module's own tile
+@pytest.mark.parametrize("h,d", [(4, 64), (5, 64), (2, 128)])  # 5: the edge block
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_one_array_entry_equals_the_three_array_entry_bit_for_bit(what, h, d, t):
+    b = 2
+    qkv = jax.random.normal(jax.random.key(60 + h), (b, 3, t, h * d), jnp.bfloat16)
+    fns = _one_and_three(b, t, h, d)
+    if what == "gradient":
+        w = jax.random.normal(jax.random.key(61), (b, t, h, d), jnp.float32)
+        fns = [jax.grad(lambda qkv, fn=fn: jnp.sum(fn(qkv).astype(jnp.float32) * w)) for fn in fns]
+    got, want = (np.asarray(fn(qkv), np.float32) for fn in fns)
+    assert got.shape == ((b, t, h, d) if what == "forward" else qkv.shape)
+    assert np.isfinite(want).all() and np.abs(want).max() > 0
+    assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("h,d", [(4, 64), (3, 64), (2, 128)])
+def test_the_one_array_entry_slices_and_stacks_nothing(monkeypatch, h, d):
+    """Forward and backward are the tiled calls alone: their q, k, v operands are the one
+    array three times over, the residual is that array, and d(qkv) leaves the backward call as
+    one result of its shape; no slice, concatenate, pad or transpose beside them."""
+    monkeypatch.setattr(pallas_flash, "CAUSAL_TILE", 16)
+    b, t = 2, 64
+    qkv = jnp.zeros((b, 3, t, h * d), jnp.bfloat16)
+    one, three = _one_and_three(b, t, h, d)
+    loss = lambda fn: (lambda x: jnp.sum(fn(x).astype(jnp.float32) ** 2))
+    eqns = _equations(jax.make_jaxpr(jax.grad(loss(one)))(qkv).jaxpr)
+    wide = lambda e: any(x.aval.shape[-1:] == (h * d,) for x in list(e.invars) + list(e.outvars))
+    copies = {"slice", "dynamic_slice", "concatenate", "pad", "transpose", "dynamic_update_slice", "gather"}
+    assert not copies & {e.primitive.name for e in eqns if wide(e)}  # (lse's squeeze is a slice)
+    fwd, bwd = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in (fwd, bwd)] == ["flash_fwd_tiles", "flash_bwd_tiles"]
+    assert fwd.invars[0] is fwd.invars[1] is fwd.invars[2] is bwd.invars[0] is bwd.invars[1] is bwd.invars[2]
+    assert [x.aval.shape for x in bwd.outvars] == [qkv.shape]
+    # the three-array entry over the same array slices it, and puts d(qkv) together from three
+    apart = {e.primitive.name for e in _equations(jax.make_jaxpr(jax.grad(loss(three)))(qkv).jaxpr) if wide(e)}
+    assert {"slice", "pad"} <= apart or {"slice", "concatenate"} <= apart, apart
+
+
+@pytest.mark.parametrize("case", ["t_over_1024", "t_no_multiple_of_512", "d32", "block_q_under_t"])
+def test_the_one_array_entry_refuses_what_no_tiled_kernel_reads_in_place(case):
+    t, d, kwargs = {"t_over_1024": (2048, 64, {}), "t_no_multiple_of_512": (768, 64, {}),
+                    "d32": (512, 32, {}), "block_q_under_t": (512, 64, {"block_q": 256})}[case]
+    assert pallas_flash.qkv_heads_in_place(t, d, 2, **kwargs) == 0
+    assert pallas_flash.qkv_heads_in_place(512, 64, 2) == 2 and pallas_flash.qkv_heads_in_place(1024, 128, 3) == 1
+    with pytest.raises(ValueError, match="no tiled kernel reads"):
+        jax.eval_shape(lambda x: pallas_flash_attention_qkv(x, 2, interpret=True, **kwargs),
+                       jax.ShapeDtypeStruct((1, 3, t, 2 * d), jnp.bfloat16))
+
+
 def test_the_log_names_the_layout_once_a_shape(monkeypatch, caplog):
     monkeypatch.setattr(pallas_flash, "CAUSAL_TILE", 16)
     pallas_flash._log_form.cache_clear()
@@ -430,5 +492,17 @@ def test_the_log_names_the_layout_once_a_shape(monkeypatch, caplog):
         f"flash attention (B*H, T, D) = (6, 64, 64): {tiles}; heads in place, 2 a block of 128 lanes",
         f"flash attention (B*H, T, D) = (4, 64, 128): {tiles}; heads in place, 1 a block of 128 lanes",
         f"flash attention (B*H, T, D) = (8, 64, 64): {tiles}; heads first",
+    ]
+    # the one-array entry says where it found q, k and v, once a shape too
+    caplog.clear()
+    with caplog.at_level("INFO", logger=pallas_flash.logger.name):
+        for h, d in [(4, 64), (4, 64), (2, 128)]:
+            qkv = jax.ShapeDtypeStruct((2, 3, 64, h * d), jnp.bfloat16)
+            jax.eval_shape(lambda x: pallas_flash_attention_qkv(x, h, interpret=True), qkv)
+    assert [r.getMessage() for r in caplog.records] == [
+        f"flash attention (B*H, T, D) = (8, 64, 64): {tiles}; heads in place, 2 a block of 128 lanes, "
+        "q, k and v from one array",
+        f"flash attention (B*H, T, D) = (4, 64, 128): {tiles}; heads in place, 1 a block of 128 lanes, "
+        "q, k and v from one array",
     ]
     pallas_flash._log_form.cache_clear()

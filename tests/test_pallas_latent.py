@@ -469,13 +469,45 @@ def test_tiled_flash_kernels_compile_for_the_chip_at_the_training_cells_size(one
     assert all((in_place in line) == bool(lanes) for line in calls), calls
 
 
+# (B, T, H, Dh) of a fused projection's (B, 3, T, H*Dh) handed to the kernels whole
+_FLASH_ONE_ARRAY = {
+    "gpt2-large-step": (12, 1024, 20, 64),
+    "gpt2-xl-step-an-odd-head-count": (12, 1024, 25, 64),
+    "heads-of-128": (4, 1024, 8, 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FLASH_ONE_ARRAY))
+def test_tiled_flash_kernels_compile_for_the_chip_on_the_projections_one_array(one_chip, case):
+    """The same two kernels with q, k and v taken out of one (B, 3, T, H*Dh) array and d(qkv)
+    written as one: Mosaic takes the squeezed plane of the operands' blocks, the three views of
+    the backward's one (1, 3, T, 128) output block and, at 25 heads, that block half outside
+    the array; the calls are handed the one array and the backward returns one of its shape."""
+    import functools
+
+    from pretraining_llm_tpu.ops import pallas_flash
+
+    b, t, h, d = _FLASH_ONE_ARRAY[case]
+    attn = functools.partial(pallas_flash.pallas_flash_attention_qkv, n_heads=h, interpret=False)
+    loss = lambda qkv: jnp.sum(attn(qkv).astype(jnp.float32) ** 2)
+    qkv = jax.ShapeDtypeStruct((b, 3, t, h * d), jnp.bfloat16, sharding=one_chip)
+    text = _compiled_for_the_chip(jax.grad(loss), qkv).as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    one = f"bf16[{b},3,{t},{h * d}]"
+    assert all(line.split(" custom-call(")[1].count(one) >= 3 or one in line.split(" = ")[1].split(" custom-call(")[0]
+               for line in calls), calls
+    assert sum(line.split(" = ")[1].split(" custom-call(")[0].startswith(one) for line in calls) == 1  # d(qkv)
+
+
 def test_no_copy_stands_beside_the_flash_calls_of_a_gpt2_large_layer(one_chip, monkeypatch):
     """One layer of ``train_gpt2large_1chip``'s step (12 x 1,024 tokens, 20 heads of 64, remat
     ``full``), forward and backward, compiled for the chip: the projections hand q, k, v and
     the cotangent of o to the custom calls where their dots leave them and take o, dq, dk, dv
     from them the same way - no copy of an activation (what a change of layout compiles to) is
     left in the program (the parent had twelve of bf16[12,20,1024,64] a layer and one of the
-    padded lse)."""
+    padded lse). Since PR 55 q, k and v reach the calls as the fused projection's one array,
+    and no fusion slices that array or puts d(qkv) together beside them."""
     import functools
 
     from pretraining_llm_tpu.config import ModelConfig
@@ -489,8 +521,8 @@ def test_no_copy_stands_beside_the_flash_calls_of_a_gpt2_large_layer(one_chip, m
         attention_impl="flash", remat="full",
     )
     monkeypatch.setattr(flash_attention, "_pallas_available", lambda: True)
-    monkeypatch.setattr(pallas_flash, "pallas_flash_attention",
-                        functools.partial(pallas_flash.pallas_flash_attention, interpret=False))
+    monkeypatch.setattr(pallas_flash, "pallas_flash_attention_qkv",
+                        functools.partial(pallas_flash.pallas_flash_attention_qkv, interpret=False))
     placed = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
     params = placed(jax.eval_shape(lambda k: transformer.init_params(cfg, k), jax.random.key(0)))
     tokens = placed(jax.ShapeDtypeStruct((b, t), jnp.int32))
@@ -500,5 +532,14 @@ def test_no_copy_stands_beside_the_flash_calls_of_a_gpt2_large_layer(one_chip, m
     moved = [line.strip()[:120] for line in text.splitlines()
              if " copy(" in line
              and any(f"[{shape}]" in line.split(" = ", 1)[-1].split("(", 1)[0]
-                     for shape in ("12,1024,1280", "12,1024,20,64", "12,20,1024,64", "12,1,1024,20,64", "240,1024,1"))]
+                     for shape in ("12,1024,1280", "12,1024,20,64", "12,20,1024,64", "12,1,1024,20,64", "240,1024,1",
+                                   "12,3,1024,1280", "12,3,1024,1,1280"))]
     assert not moved, moved
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    whole = "bf16[12,3,1024,1280]"
+    assert all(line.split(" custom-call(")[1].count(whole) >= 3 for line in calls), calls
+    assert sum(line.split(" = ")[1].startswith(whole) for line in calls) == 1  # the backward's one d(qkv)
+    # the parent's three slice copies a forward pass were fusions named for what they did
+    assert "slice_bitcast_fusion" not in text
+    # and its d(qkv) was three pads of the kernels' gradients, added up
+    assert not [line.strip()[:100] for line in text.splitlines() if " pad(" in line and "bf16[12,3,1024" in line]
