@@ -45,6 +45,7 @@ from pretraining_llm_tpu.observability.capacity import (
     DecisionLog,
 )
 from pretraining_llm_tpu.observability import spans as _spans
+from pretraining_llm_tpu.observability import witness as _witness
 
 _log = logging.getLogger("pretraining_llm_tpu.serving")
 
@@ -766,11 +767,14 @@ class EngineLoop:
                 if slow:
                     with self._lock:
                         self.counters["slow_turns"] += 1
-                    _log.warning(
+                    t1 = time.monotonic()  # the turn ended just now, on the witness's clock
+                    turn_s = sum(clock.acc.values()) - sum(before.values())
+                    args = (turn, overhead, len(overheads), slow, _spans.format_split(split))
+                    # The witness thread writes the line, a period or two from now, once it knows the cause.
+                    _witness.when_settled(t1 - turn_s, t1, lambda cause, args=args: _log.warning(
                         "slow turn %d of the engine loop: %.3f s outside the engine's tick "
-                        "and the idle wait, the last %d turns' median times %.0f: %s",
-                        turn, overhead, len(overheads), slow, _spans.format_split(split),
-                    )
+                        "and the idle wait, the last %d turns' median times %.0f: %s; %s", *args, cause,
+                    ))
                 overheads.append(overhead)
         except BaseException as e:
             failure = e

@@ -62,6 +62,7 @@ from pretraining_llm_tpu.generation import paged, speculative
 from pretraining_llm_tpu.generation import prefix_cache as prefix_cache_mod
 from pretraining_llm_tpu.models import mla, moe, recurrent, transformer
 from pretraining_llm_tpu.observability import spans as _spans
+from pretraining_llm_tpu.observability import witness as _witness
 
 _log = logging.getLogger("pretraining_llm_tpu.serving")
 
@@ -747,6 +748,7 @@ class ServingEngine:
                 self.alloc, self.block_size,
                 min_blocks=prefix_cache_min_blocks, stats=self.stats,
             )
+        _witness.ensure()  # the process's late-wake witness: what a slow tick's line asks
 
     # -- public API --------------------------------------------------------
 
@@ -1484,11 +1486,12 @@ class ServingEngine:
                 }
             if slow:
                 st["slow_ticks"] += 1
-                _log.warning(
-                    "slow tick %d: %.3f s, the last %d ticks' median times %.0f: %s",
-                    st["ticks"], seconds, len(self._tick_hist), slow,
-                    _spans.format_split(split),
-                )
+                t1 = time.monotonic()  # the tick ended a few microseconds ago, on the witness's clock
+                args = (st["ticks"], seconds, len(self._tick_hist), slow, _spans.format_split(split))
+                # The witness thread writes the line, a period or two from now, once it knows the cause.
+                _witness.when_settled(t1 - seconds, t1, lambda cause: _log.warning(
+                    "slow tick %d: %.3f s, the last %d ticks' median times %.0f: %s; %s", *args, cause,
+                ))
         self._tick_hist.append(seconds)
 
     def _dispatch_window(self, n: int) -> None:
@@ -2708,7 +2711,8 @@ class ServingEngine:
                 )
             _log.info(
                 "engine empty after %d ticks, %d decode steps: attention read %d "
-                "live pages of %d tabled (%.4f)%s",
+                "live pages of %d tabled (%.4f)%s; %s",
                 st["ticks"], st["steps"], st["attn_pages_live"], st["attn_pages_tabled"],
                 st["attn_pages_live"] / max(1, st["attn_pages_tabled"]), routing,
+                _witness.summary(),
             )

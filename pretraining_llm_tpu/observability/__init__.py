@@ -15,6 +15,11 @@ spent 20% of wall-clock replaying a poison window look identical. The pieces:
                  memory too and exports Chrome trace-event JSON, once
                  somebody asked for one (``get_recorder``/``set_recorder``):
                  recording is opt-in, and costs an append to a list.
+  - witness.py — the process's late-wake witness: a sleeper thread and a
+                 sleeper child process whose late wakes say whether a slow
+                 tick's thread could run, and if not whether the machine or
+                 this interpreter held it; always on, started by the engine
+                 and the train step.
   - goodput.py — folds the event stream into a wall-clock decomposition
                  (productive / replay / eval / checkpoint / restore / idle /
                  other) and a single ``goodput`` fraction. Replay detection

@@ -27,6 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pretraining_llm_tpu.config import Config
 from pretraining_llm_tpu.models import transformer
+from pretraining_llm_tpu.observability import witness
 from pretraining_llm_tpu.parallel.sharding import (
     activation_mesh,
     batch_pspec,
@@ -272,6 +273,7 @@ def build_train_step(
     """Compile the train step. batch: (x, y) each (B, T) int32, B = global batch."""
     model_cfg = cfg.model
     step_fn = _make_step_fn(cfg, mesh)
+    witness.ensure()  # whoever drives the step (the trainer, the benchmark) runs under the witness
 
     if mesh is None:
         return jax.jit(step_fn, donate_argnums=0)

@@ -31,7 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pretraining_llm_tpu.config import Config
 from pretraining_llm_tpu.data import loader as data_loader
-from pretraining_llm_tpu.observability import ObservabilityHub
+from pretraining_llm_tpu.observability import ObservabilityHub, witness
 from pretraining_llm_tpu.parallel.mesh import build_mesh
 from pretraining_llm_tpu.parallel.sharding import batch_pspec
 from pretraining_llm_tpu.training import checkpoint as ckpt
@@ -648,7 +648,7 @@ class Trainer:
                     # the cumulative goodput fraction into the log record.
                     last.update(self.obs.on_log_boundary(step, last, last))
                     if is_host0:
-                        self.logger.log({"step": step, **last})
+                        self.logger.log({"step": step, **last, **witness.record()})
                     if detector is not None:
                         anomaly = detector.observe(step, last)
                         if anomaly is not None:

@@ -11,6 +11,7 @@ test touches a device) is sufficient whatever `JAX_PLATFORMS` says.
 import functools
 import os
 import sys
+import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -63,6 +64,19 @@ def _clear_jax_caches_per_module():
     every previous module."""
     yield
     jax.clear_caches()
+
+
+@pytest.fixture(autouse=True)
+def _slow_lines_land_in_their_own_test():
+    """A slow tick's or a slow turn's WARNING is written by the late-wake
+    witness's thread a period or two after the tick (``witness.when_settled``):
+    inside the test that made it slow, not in the next one's ``caplog``."""
+    yield
+    module = sys.modules.get("pretraining_llm_tpu.observability.witness")
+    running = getattr(module, "_witness", None)
+    deadline = time.monotonic() + 2.0
+    while running is not None and running.asked and time.monotonic() < deadline:
+        time.sleep(0.005)
 
 
 @pytest.fixture

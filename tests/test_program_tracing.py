@@ -22,7 +22,7 @@ from pretraining_llm_tpu.frontend.engine_loop import EngineLoop
 from pretraining_llm_tpu.generation import paged, serving
 from pretraining_llm_tpu.generation.serving import PHASES, ServingEngine
 from pretraining_llm_tpu.models import transformer
-from pretraining_llm_tpu.observability import spans
+from pretraining_llm_tpu.observability import spans, witness
 from pretraining_llm_tpu.training import train_step as ts
 
 TINY = get_preset("tiny")
@@ -138,6 +138,40 @@ def test_engine_loop_spans(params, monkeypatch):
     assert loop.counters["slow_turns"] == 0
 
 
+WITNESS_SPANS = {
+    "loop.late_wake": {"late_ms", "ended_ms_ago", "outside_ms", "gc_ms", "outside"},
+    "loop.witness_beat": set(),  # read for its presence alone
+}
+
+
+@pytest.mark.parametrize("name", sorted(WITNESS_SPANS))
+def test_witness_span_names_and_meta(name, monkeypatch):
+    """The late-wake witness's two spans (observability/witness.py), as
+    ``benchmark/readers/late_wake.py`` finds them: profiler annotations with
+    numbers in their meta, and nothing in the in-memory recorder."""
+    rec = spans.SpanRecorder()
+    monkeypatch.setattr(spans, "_default", rec)
+    made = []
+    real = spans.Span
+
+    def recording(span_name, recorder, meta):
+        made.append((span_name, recorder, dict(meta)))
+        return real(span_name, recorder, meta)
+
+    monkeypatch.setattr(witness, "Span", recording)
+    now = [50.0]
+    w = witness.Witness(clock=lambda: now[0], sleep=lambda s: now.__setitem__(0, now[0] + s))
+    w.step()
+    now[0] += 0.1  # the next wake comes 100 ms late
+    for _ in range(int(witness.BEAT_S / witness.PERIOD_S)):
+        w.step()
+    (meta,) = [m for n, r, m in made if n == name]
+    assert set(meta) == WITNESS_SPANS[name]
+    assert all(isinstance(v, (int, float)) for v in meta.values())  # what `program_trace.load` brings back
+    assert all(r is None for _, r, _ in made) and rec.summary() == {}
+    assert meta.get("outside", 0) == 0  # this one has no child
+
+
 # -- (b) the always-on phase account --------------------------------------------------
 
 
@@ -226,6 +260,11 @@ def test_stalled_readback_logs_one_slow_tick_naming_the_phase(params, monkeypatc
         eng.pipeline_tick()
         for _ in range(5):
             eng.pipeline_tick()
+        # the witness thread writes the line, a period or two after the tick, once it knows the cause
+        deadline = time.monotonic() + 5.0
+        while not caplog.records and time.monotonic() < deadline:
+            time.sleep(witness.PERIOD_S)
+        time.sleep(5 * witness.PERIOD_S)  # as long again as a second line would take
     assert eng.stats["slow_ticks"] == 1
     (record,) = [r for r in caplog.records if "slow tick" in r.getMessage()]
     message = record.getMessage()
@@ -234,6 +273,10 @@ def test_stalled_readback_logs_one_slow_tick_naming_the_phase(params, monkeypatc
     assert message.split(": ", 2)[2].startswith("host_blocked=")
     longest = eng.stats["longest_tick"]
     assert longest["tick"] == 21 and longest["phase_s"]["host_blocked"] >= 0.4
+    # the line ends with the witness's verdict: the readback slept, so the interpreter was free
+    # and the sleepers were on time, unless this machine held the test itself for 20 ms
+    assert re.search(r"; (every sleeper was on time: the device or the transfer"
+                     r"|the process could not run for [0-9.]+ of it \((machine|process|unknown): [^)]*\))$", message)
     assert max(longest["phase_s"], key=longest["phase_s"].get) == "host_blocked"
 
 
