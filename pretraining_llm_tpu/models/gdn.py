@@ -25,8 +25,10 @@ last ``kernel - 1`` projected inputs of every channel.
 Two forms of the recurrence, one function of the inputs:
 
 - one token a row, ``gdn.step``: whichever form ``kda.step_form`` reads from
-  the state (the Pallas kernel takes a float32 state of whole 128-lane tiles;
-  every other state, 96 x 192 among them, the four ``jnp`` lines);
+  the state (on a TPU, with no mesh, the Pallas kernel takes a float32 state
+  whose K is whole 8-sublane tiles, 96 x 192 as it lies in the pool among
+  them, the head's one decay broadcast over its K channels; everywhere else
+  the four ``jnp`` lines);
 - ``chunked``, ``gdn.chunk``: many tokens a row, chunk by chunk in the WY/UT
   form. A scalar gate makes it plain: with ``G_i`` the cumulative log-decay
   inside a chunk of ``kda.CHUNK`` tokens, ``A_ij = beta_i (k_i . k_j) exp(G_i -

@@ -22,8 +22,8 @@ Two forms of the recurrence, one function of the inputs:
   buffer it came from (XLA's two fusions of the ``jnp`` form cross HBM three
   and a half times). ``step_form`` reads which of the two runs from the state
   itself, as ``mla.decode_form`` and ``moe.experts_form`` read theirs: the
-  kernel for a float32 state of whole 128-lane tiles that no mesh shards, on a
-  TPU; the ``jnp`` lines everywhere else, and as the reference the tests hold
+  kernel for a float32 state whose K is whole 8-sublane tiles, that no mesh
+  shards, on a TPU; the ``jnp`` lines everywhere else, and as the reference the tests hold
   the kernel to. Every ``t == 1`` call of ``mix`` (the decode step over the
   pools as they lie, the gathered slots of ``paged.slots``, ``generate``'s
   contiguous cache) goes through that one choice. The pool keeps its
@@ -120,7 +120,7 @@ def step_form(state: Any, mesh: Any = None, backend: Optional[str] = None) -> st
     """The form one token of the recurrence takes over ``state`` (anything with
     a shape and a dtype): ``"kernel"`` (``ops/pallas_kda.py``: a row's state
     read once and written once, in place) for a float32 state (rows, H, K, V)
-    of whole 128-lane tiles that no mesh shards, where Mosaic compiles;
+    that ``pallas_kda.takes`` (K whole 8-sublane tiles) and no mesh shards, where Mosaic compiles;
     ``"jnp"`` (``recurrent_step``) for every other state and backend. Read from
     the input, never from an option; the engine reports the form of its decode
     program in ``pool_info()``."""

@@ -13,6 +13,11 @@ state in VMEM between them. Per (row, head), everything float32::
     S'  = D + k[:, None] * u[None, :]      # stored as it is made
     o   = sum_K  q[:, None] * S'           # (V,)
 
+Two mixers step through it: KDA (a square state of whole lane tiles, 128 x 128,
+a decay a channel) and Gated DeltaNet (``models/gdn.py``: 96 x 192, the head's
+one decay broadcast over its K channels by the caller). One recurrence; what
+differs is read from the state's shape.
+
   - Grid ``(rows,)``. A grid step brings all heads of one row: the state block
     (1, H, K, V) — 2 MB at 32 heads of 128 x 128 — comes in through the
     pipeline, the new state leaves through the output block of the same index,
@@ -27,20 +32,35 @@ state in VMEM between them. Per (row, head), everything float32::
   - **The layout stays (rows, H, K, V)**: ``kda.chunked`` (prefill),
     ``paged._scatter_pages``, the benchmark's check and its byte count all read
     the pool so, and a decode program that donates its pools hands this call
-    the pool itself. V lies on lanes, K on sublanes: a head is 16 vregs of 8
-    values of K each.
+    the pool itself. V lies on lanes, K on sublanes: a head of 128 x 128 is 16
+    vregs of 8 values of K each. The block's last two dimensions are the
+    array's own, so K need only be whole 8-sublane tiles and V may be any
+    width: the chip stores V padded to whole 128-lane tiles, and **a block is
+    reckoned at its padded lanes** (``_block_bytes``), against
+    ``STATE_BLOCK_BYTES`` and in ``vmem_limit_bytes``. A 96 x 192 head is 12
+    sublane tiles by two lane tiles, the second half empty: 30 heads are
+    2.95 MB a row as they lie (2.21 MB of numbers), and the pass moves the
+    padding with them. Six pools of 129 rows take 7.06 ms where the bare copy
+    takes 7.00 (647 and 652 GB/s of padded bytes, 485 GB/s of the numbers
+    alone; XLA's two fusions 10.03 ms: PERF.md section 6, PR 57). Whole tiles
+    (all heads' values side by side) would need the pool in another layout.
   - q, k and exp(g) index K, so the state's tiles want them down the sublanes,
     and they arrive as rows (K on lanes). Eight heads' three vectors are
-    stacked to one (128, K) tile (24 rows of it used) and transposed on the XLU,
-    once a group of eight heads; head ``hh``'s vectors are then columns
+    stacked to one (128, Kp) tile (24 rows of it used) and transposed on the
+    XLU, once a group of eight heads; head ``hh``'s vectors are then columns
     ``hh``, ``8 + hh``, ``16 + hh`` of the transposed tile, each broadcast over
-    the lanes as it meets the state. Nothing is pre-broadcast in HBM.
+    the lanes as it meets the state. Nothing is pre-broadcast in HBM. The
+    transpose wants whole tiles, so the wrapper pads the three operands' K to
+    whole lane tiles (Kp; 1.5 MB a layer at 96 beside 760 MB of state) and the
+    kernel takes the first K sublanes of the transposed tile.
   - Inside a step a ``fori_loop`` walks the groups of eight heads; the eight
     heads of a group are unrolled, which is what lets the scheduler overlap one
     head's loads with another's sums (a loop of one head a turn was timed at
-    twice the time, bound by its own chain and no longer by the bytes). The
-    sums over K add the 16 vregs and then the 8 sublanes; on the chip they
-    came out bit-equal to XLA's at the cell's shape.
+    twice the time, bound by its own chain and no longer by the bytes). Heads
+    that fill no whole group (30 = 3 groups and 6) follow the loop as one
+    shorter group. The sums over K add the K / 8 vregs and then the 8
+    sublanes; on the chip they came out bit-equal to XLA's at both cells'
+    shapes.
   - A dead row or a padded one (``g = 0``, ``beta = 0``) keeps its arithmetic:
     ``S * 1 + k * 0`` is written back over ``S``. No select over states, no
     skipped row: the bytes are the same for every row, as the benchmark counts.
@@ -58,26 +78,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
 GROUP = 8  # heads a transposed tile serves: one float32 sublane tile of each vector
 # The most bytes of state a row may bring: in, out and their second buffers are
 # four such blocks of VMEM.
 STATE_BLOCK_BYTES = 4 << 20
 
 
+def _block_bytes(h: int, k: int, v: int) -> int:
+    """A row's heads as they lie on the chip: V padded to whole 128-lane tiles."""
+    return h * k * (v + -v % LANES) * 4
+
+
 def takes(state_shape: Tuple[int, ...], dtype) -> bool:
     """Whether the kernel takes a state of this shape and dtype: float32,
-    (rows, H, K, V) with K and V whole 128-lane tiles, a row's heads inside
-    ``STATE_BLOCK_BYTES``."""
+    (rows, H, K, V) with K whole 8-sublane tiles and V any width, a row's heads
+    at their padded lanes inside ``STATE_BLOCK_BYTES``."""
     if len(state_shape) != 4 or jnp.dtype(dtype) != jnp.float32:
         return False
     _, h, k, v = state_shape
-    return k % 128 == 0 and v % 128 == 0 and h * k * v * 4 <= STATE_BLOCK_BYTES
+    return k % 8 == 0 and 0 < _block_bytes(h, k, v) <= STATE_BLOCK_BYTES
 
 
 def _kda_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, s_ref, o_ref, so_ref, *, heads: int):
-    # q/k/g (1, Hp, K), v (1, Hp, V), beta (1, Hp, 1): heads padded to whole groups;
-    # s/so (1, H, K, V); o (1, Hp, V)
-    spare = jnp.zeros((128 - 3 * GROUP, q_ref.shape[-1]), jnp.float32)
+    # q/k/g (1, Hp, Kp), v (1, Hp, V), beta (1, Hp, 1): heads padded to whole groups,
+    # K to whole lane tiles; s/so (1, H, K, V); o (1, Hp, V)
+    kdim = s_ref.shape[2]
+    spare = jnp.zeros((LANES - 3 * GROUP, q_ref.shape[-1]), jnp.float32)
 
     def group(gi, n: int):
         """Heads gi * GROUP .. + n, n static."""
@@ -85,11 +112,11 @@ def _kda_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, s_ref, o_ref, so_ref, *, h
         rows = pl.ds(h0, GROUP)
         decay = jnp.exp(g_ref[0, rows, :])
         stacked = jnp.concatenate([decay, k_ref[0, rows, :], q_ref[0, rows, :], spare], axis=0)
-        cols = stacked.T  # (K, 128): vector j of head hh down column j * GROUP + hh
+        cols = stacked.T  # (Kp, 128): vector j of head hh down column j * GROUP + hh
         v, beta = v_ref[0, rows, :], beta_ref[0, rows, :]
         outs = []
         for hh in range(n):
-            dc, kc, qc = (cols[:, j * GROUP + hh : j * GROUP + hh + 1] for j in range(3))
+            dc, kc, qc = (cols[:kdim, j * GROUP + hh : j * GROUP + hh + 1] for j in range(3))
             d = s_ref[0, h0 + hh] * dc
             kts = jnp.sum(d * kc, axis=0, keepdims=True)
             u = beta[hh : hh + 1, :] * (v[hh : hh + 1, :] - kts)
@@ -109,15 +136,15 @@ def _kda_kernel(q_ref, k_ref, g_ref, v_ref, beta_ref, s_ref, o_ref, so_ref, *, h
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _kda_call(state, q, k, v, g, beta, interpret):
     n, h, kdim, vdim = state.shape
-    hp = h + -h % GROUP
-    pad = lambda a: jnp.pad(a, ((0, 0), (0, hp - h)) + ((0, 0),) * (a.ndim - 2))
+    hp, kp = h + -h % GROUP, kdim + -kdim % LANES
+    pad = lambda a, lanes=0: jnp.pad(a, ((0, 0), (0, hp - h), (0, lanes)))  # heads to whole groups, K to whole tiles
     small = lambda width: pl.BlockSpec((1, hp, width), lambda i: (i, 0, 0))
     big = pl.BlockSpec((1, h, kdim, vdim), lambda i: (i, 0, 0, 0))
-    block = h * kdim * vdim * 4
+    block = _block_bytes(h, kdim, vdim)
     o, new_state = pl.pallas_call(
         functools.partial(_kda_kernel, heads=h),
         grid=(n,),
-        in_specs=[small(kdim), small(kdim), small(kdim), small(vdim), small(1), big],
+        in_specs=[small(kp), small(kp), small(kp), small(vdim), small(1), big],
         out_specs=[small(vdim), big],
         out_shape=[jax.ShapeDtypeStruct((n, hp, vdim), jnp.float32), jax.ShapeDtypeStruct(state.shape, jnp.float32)],
         input_output_aliases={5: 1},
@@ -127,7 +154,7 @@ def _kda_call(state, q, k, v, g, beta, interpret):
             vmem_limit_bytes=4 * block + (8 << 20),
         ),
         interpret=interpret,
-    )(pad(q), pad(k), pad(g), pad(v), pad(beta[..., None]), state)
+    )(pad(q, kp - kdim), pad(k, kp - kdim), pad(g, kp - kdim), pad(v), pad(beta[..., None]), state)
     return o[:, :h], new_state
 
 
@@ -154,8 +181,8 @@ def recurrent_step(
     ):
         raise ValueError(
             f"state {state.shape} {state.dtype}, q {q.shape}, k {k.shape}, v {v.shape}, g {g.shape}, beta "
-            f"{beta.shape}: want a float32 state (N, H, K, V) with K and V whole 128-lane tiles and a row's "
-            f"heads within {STATE_BLOCK_BYTES} bytes, q/k/g (N, H, K), v (N, H, V), beta (N, H)"
+            f"{beta.shape}: want a float32 state (N, H, K, V) with K whole 8-sublane tiles and a row's heads, V "
+            f"at its padded lanes, within {STATE_BLOCK_BYTES} bytes, q/k/g (N, H, K), v (N, H, V), beta (N, H)"
         )
     f32 = lambda a: a.astype(jnp.float32)
     return _kda_call(state, f32(q), f32(k), f32(v), f32(g), f32(beta), bool(interpret))

@@ -4,7 +4,8 @@ at the sizes serving and training use (flash attention: Dh 64, 12 heads, bf16,
 and the head layouts the tiled kernels read in place: 25 heads of 64, whose
 last 128-lane block is half outside the array, and grouped heads of 128 and
 256; the paged decode step at 32 heads of 128; the expert FFN at three serving
-cells' expert shapes; the KDA step over the Ling cell's state pool).
+cells' expert shapes; the KDA step over the Ling cell's state pool and over
+the Olmo-Hybrid cell's, 96 x 192 a head under one decay a head).
 
 The CPU tests run these kernels in interpret mode at toy sizes; only the
 chip hears Mosaic's refusals (tiling, unaligned slices, VMEM) and only there
@@ -275,22 +276,23 @@ def flash_qkv_case(t: int, h: int, dh: int):
     return {"o": _err(o1, o3), "dqkv": _err(g1, g3)}, 0.0
 
 
-def kda_case(rows: int, heads: int):
+def kda_case(rows: int, heads: int, kdim: int = 128, vdim: int = 128, gate_channels: bool = True):
     """``ops/pallas_kda.py`` against ``kda.recurrent_step``, the state donated
     to both as the decode programs donate the pools; the last row is dead
-    (``g = 0``, ``beta = 0``) and must come back bit for bit."""
+    (``g = 0``, ``beta = 0``) and must come back bit for bit. ``gate_channels``
+    False: one decay a head, broadcast over K as ``models/gdn.py`` hands it."""
     from pretraining_llm_tpu.models import kda
     from pretraining_llm_tpu.ops import pallas_kda
 
-    n = 128
     ks = jax.random.split(jax.random.key(rows + heads), 6)
     unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
-    q = unit(jax.random.normal(ks[1], (rows, heads, n))) * n ** -0.5
-    k = unit(jax.random.normal(ks[2], (rows, heads, n)))
-    v = jax.random.normal(ks[3], (rows, heads, n))
-    g = (-5.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (rows, heads, n)))).at[-1].set(0.0)
+    q = unit(jax.random.normal(ks[1], (rows, heads, kdim))) * kdim ** -0.5
+    k = unit(jax.random.normal(ks[2], (rows, heads, kdim)))
+    v = jax.random.normal(ks[3], (rows, heads, vdim))
+    g = -5.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (rows, heads, kdim if gate_channels else 1)))
+    g = jnp.broadcast_to(g, (rows, heads, kdim)).at[-1].set(0.0)
     beta = jax.nn.sigmoid(jax.random.normal(ks[5], (rows, heads))).at[-1].set(0.0)
-    state = lambda: jax.random.normal(ks[0], (rows, heads, n, n), jnp.float32)
+    state = lambda: jax.random.normal(ks[0], (rows, heads, kdim, vdim), jnp.float32)
     dead = np.asarray(state()[-1])
     got_o, got_s = jax.jit(pallas_kda.recurrent_step, donate_argnums=0)(state(), q, k, v, g, beta)
     want_o, want_s = jax.jit(kda.recurrent_step, donate_argnums=0)(state(), q, k, v, g, beta)
@@ -301,6 +303,9 @@ def kda_case(rows: int, heads: int):
 def cases():
     for rows, heads in ((129, 32), (3, 4), (2, 12)):  # the Ling cell's pool; part groups of heads
         yield f"kda rows{rows} heads{heads}", kda_case, (rows, heads)
+    # Gated DeltaNet: the Olmo-Hybrid cell's pool (V a lane tile and a half, 3 groups of heads and 6) and a toy's
+    for rows, heads, kdim, vdim in ((129, 30, 96, 192), (3, 3, 8, 16)):
+        yield f"kda rows{rows} heads{heads} state{kdim}x{vdim} scalar gate", kda_case, (rows, heads, kdim, vdim, False)
     for shape in MOE_SHAPES:
         for mix in MOE_MIXES:
             for layer, clamp in ((0, False), (MOE_SHAPES[shape][0] - 1, True)):

@@ -42,6 +42,7 @@ from pretraining_llm_tpu.models import gdn, kda, recurrent, transformer as tr  #
 from pretraining_llm_tpu.ops import pallas_paged  # noqa: E402
 from pretraining_llm_tpu.training.optimizer import decay_mask  # noqa: E402
 
+from tests import test_pallas_kda as kernel_paths  # noqa: E402  the decode paths and the jaxpr walk of the kernel's own tests
 from tests.test_granite import _teacher_forced  # noqa: E402  one helper for both state-slot families
 
 with open(os.path.join(BENCH, "tests", "toy", "olmo_hybrid.json")) as f:
@@ -253,8 +254,58 @@ def test_the_decode_step_is_one_more_token_of_the_prefill(params):
     y_last, s, c = gdn.mix(p, h[:, 20:], CFG, s, c)
     assert rel_err(y_last[:, 0], np.asarray(y_all[:, 20])) < 1e-5
     assert rel_err(s, np.asarray(s_all)) < 1e-5 and rel_err(c, np.asarray(c_all)) < 1e-6
-    assert gdn.step_form(jax.ShapeDtypeStruct((129, 30, 96, 192), jnp.float32), backend="tpu") == "jnp"
-    assert gdn.step_form(jax.ShapeDtypeStruct((129, 32, 128, 128), jnp.float32), backend="tpu") == "kernel"
+    for pool in ((129, 30, 96, 192), (129, 32, 128, 128)):  # the cell's pool as it lies, and Ling's
+        assert gdn.step_form(jax.ShapeDtypeStruct(pool, jnp.float32), backend="tpu") == "kernel"
+
+
+# -- 2b. the one-token step as ``ops/pallas_kda.py``'s kernel (interpreted here) --------
+
+# the toy with the published state a head: 96 keys (12 sublane tiles) by 192 values (a lane tile and a half)
+WIDE_ARCH = dict(ARCH, linear_key_head_dim=96, linear_value_head_dim=192)
+WIDE = program.model_config(WIDE_ARCH, 128)
+
+
+@pytest.fixture(scope="module")
+def wide_params():
+    return weights.serving_params(WIDE_ARCH, SEEDS[0])
+
+
+@pytest.fixture
+def on_a_tpu(monkeypatch):
+    """``gdn.step_form`` answers as on a TPU. A jitted program keeps the form it
+    was traced with, so the caches go before and after."""
+    monkeypatch.setattr(gdn, "step_form", functools.partial(kda.step_form, backend="tpu"))
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("path", kernel_paths.PATHS.values(), ids=kernel_paths.PATHS)
+def test_decode_through_the_kernel_is_decode_through_the_jnp_form(wide_params, on_a_tpu, monkeypatch, path):
+    """Three rows and a dead one prefilled and stepped three times through the
+    state slots, over the pools as they lie and through gathered slots: logits
+    and slots of the kernel are those of ``kda.recurrent_step``'s four lines."""
+    got, got_pools = path(wide_params, cfg=WIDE)
+    monkeypatch.setattr(gdn, "step_form", lambda *a, **k: "jnp")
+    jax.clear_caches()
+    want, want_pools = path(wide_params, cfg=WIDE)
+    assert rel_err(got, want) < 1e-5
+    states = [(g["state_pool"], w["state_pool"]) for g, w in zip(got_pools["layers"], want_pools["layers"])
+              if "state_pool" in g]
+    assert len(states) == 6 and states[0][0].shape == (5, 3, 96, 192)
+    for held, wanted in states:
+        assert rel_err(held[:3], np.asarray(wanted[:3])) < 1e-5
+        np.testing.assert_array_equal(np.asarray(held[3:]), 0.0)  # the dead row's slot and the scratch slot: nothing written
+
+
+def test_the_decode_step_traces_the_kernel_under_gdn_step(wide_params, on_a_tpu):
+    pools, tables, lens = kernel_paths._prefilled(wide_params, cfg=WIDE)
+    step = lambda: paged.paged_decode_logits(
+        wide_params, pools, jnp.zeros((4,), jnp.int32), jnp.asarray(tables), jnp.asarray(lens), cfg=WIDE)
+    calls = kernel_paths._pallas_calls(step)
+    assert len(calls) == 6 and all("gdn.step" in path for path, _ in calls)  # one a Gated DeltaNet layer
+    assert all(call.invars[5].aval.shape == (5, 3, 96, 192) and dict(call.params["input_output_aliases"]) == {5: 1}
+               for _, call in calls)
 
 
 # -- 3. prefill then decode through slots and pool ------------------------------------

@@ -1,10 +1,11 @@
 """ops/pallas_kda.py (interpreted here): one token of the KDA recurrence as a
 kernel against ``kda.recurrent_step``, the four ``jnp`` lines it replaces on
-the TPU; the rule that picks the form (``kda.step_form``); and a toy Ling stack
-decoded through its state slots with the kernel forced, on both paths that
-reach ``kda.mix`` with one token a row. The compiled kernel is heard on the
-chip and, at the cell's size, by the at-size compile in
-``tests/test_pallas_latent.py``."""
+the TPU, over KDA's square state of whole lane tiles and over Gated DeltaNet's
+96 x 192 under one decay a head; the rule that picks the form
+(``kda.step_form``); and a toy Ling stack decoded through its state slots with
+the kernel forced, on both paths that reach ``kda.mix`` with one token a row.
+The compiled kernel is heard on the chip and, at the cells' sizes, by the
+at-size compiles in ``tests/test_pallas_latent.py``."""
 
 import dataclasses
 import functools
@@ -31,15 +32,23 @@ LOWER = -5.0  # kda_gate_lower_bound of the family: the strongest decay a token
 TOL = 1e-5
 
 
-def _inputs(rows, heads, seed=0, state="random", g="random"):
+GDN = (96, 192)  # a Gated DeltaNet head of the Olmo-Hybrid cell: 12 sublane tiles by a lane tile and a half
+
+
+def _inputs(rows, heads, seed=0, state="random", g="random", shape=(K, V)):
+    """``shape`` other than KDA's square: Gated DeltaNet's operands, one decay a
+    head broadcast over K as ``models/gdn.py`` hands it, beta in (0, 2)."""
+    kdim, vdim = shape
+    gdn = shape != (K, V)
     ks = jax.random.split(jax.random.key(seed), 6)
-    s = jax.random.normal(ks[0], (rows, heads, K, V), jnp.float32)
+    s = jax.random.normal(ks[0], (rows, heads, kdim, vdim), jnp.float32)
     unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
-    q = unit(jax.random.normal(ks[1], (rows, heads, K))) * K ** -0.5
-    k = unit(jax.random.normal(ks[2], (rows, heads, K)))
-    v = jax.random.normal(ks[3], (rows, heads, V))
-    gate = LOWER * jax.nn.sigmoid(jax.random.normal(ks[4], (rows, heads, K)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (rows, heads)))
+    q = unit(jax.random.normal(ks[1], (rows, heads, kdim))) * kdim ** -0.5
+    k = unit(jax.random.normal(ks[2], (rows, heads, kdim)))
+    v = jax.random.normal(ks[3], (rows, heads, vdim))
+    gate = LOWER * jax.nn.sigmoid(jax.random.normal(ks[4], (rows, heads, 1 if gdn else kdim)))
+    gate = jnp.broadcast_to(gate, (rows, heads, kdim))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (rows, heads))) * (2.0 if gdn else 1.0)
     if state == "zero":
         s = jnp.zeros_like(s)
     if g != "random":
@@ -77,11 +86,15 @@ CASES = {
 }
 
 
+# heads and the state a head: KDA's, one head and the Ling cell's 32; Gated DeltaNet's at the Olmo-Hybrid cell's 30
+HEADS = {"1": (1, (K, V)), "32": (32, (K, V)), "30-of-96x192-under-a-scalar-gate": (30, GDN)}
+
+
 @pytest.mark.parametrize("case", CASES.values(), ids=CASES)
-@pytest.mark.parametrize("heads", [1, 32])
+@pytest.mark.parametrize("heads,shape", HEADS.values(), ids=HEADS)
 @pytest.mark.parametrize("rows", [1, 3, 129])
-def test_kernel_is_the_recurrent_step(rows, heads, case):
-    args = _inputs(rows, heads, seed=rows + heads, **case)
+def test_kernel_is_the_recurrent_step(rows, heads, shape, case):
+    args = _inputs(rows, heads, seed=rows + heads, shape=shape, **case)
     o, s = pk.recurrent_step(*args)
     want_o, want_s = kda.recurrent_step(*args)
     assert o.shape == want_o.shape and s.shape == want_s.shape and o.dtype == s.dtype == jnp.float32
@@ -89,16 +102,22 @@ def test_kernel_is_the_recurrent_step(rows, heads, case):
     _close(s, want_s)
 
 
-@pytest.mark.parametrize("heads", [4, 12], ids=["half-a-group", "a-group-and-a-half"])
-def test_heads_that_fill_no_whole_group(heads):
-    args = _inputs(2, heads, seed=5)
+SHAPES = {"kda-128x128": (K, V), "gdn-96x192": GDN}
+PART_GROUPS = {"half-a-group": (4, (K, V)), "a-group-and-a-half": (12, (K, V)), "gdn-a-group-and-six": (14, GDN),
+               "a-toys-16x16": (4, (16, 16)), "one-sublane-tile-of-K": (3, (8, 16)), "K-past-a-lane-tile": (2, (136, 64))}
+
+
+@pytest.mark.parametrize("heads,shape", PART_GROUPS.values(), ids=PART_GROUPS)
+def test_heads_that_fill_no_whole_group(heads, shape):
+    args = _inputs(2, heads, seed=5, shape=shape)
     for got, want in zip(pk.recurrent_step(*args), kda.recurrent_step(*args)):
         _close(got, want)
 
 
-def test_a_dropped_term_fails_the_tolerance():
+@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES)
+def test_a_dropped_term_fails_the_tolerance(shape):
     """The control of ``TOL``: the step with the rank-one update left out."""
-    s, q, k, v, g, beta = _inputs(3, 4, seed=9)
+    s, q, k, v, g, beta = _inputs(3, 4, seed=9, shape=shape)
     o, new = pk.recurrent_step(s, q, k, v, g, beta)
     decayed = s * jnp.exp(g)[..., None]
     with pytest.raises(AssertionError):
@@ -107,19 +126,21 @@ def test_a_dropped_term_fails_the_tolerance():
         _close(o, jnp.einsum("bhkv,bhk->bhv", decayed, q))
 
 
-def test_a_dead_row_leaves_its_state_bit_for_bit():
-    s, q, k, v, g, beta = _inputs(3, 8, seed=2)
+@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES)
+def test_a_dead_row_leaves_its_state_bit_for_bit(shape):
+    s, q, k, v, g, beta = _inputs(3, 8, seed=2, shape=shape)
     g, beta = g.at[1].set(0.0), beta.at[1].set(0.0)
     _, new = pk.recurrent_step(s, q, k, v, g, beta)
     np.testing.assert_array_equal(np.asarray(new[1]), np.asarray(s[1]))
     assert float(jnp.abs(new[0] - s[0]).max()) > 0 and float(jnp.abs(new[2] - s[2]).max()) > 0
 
 
-def test_the_new_state_takes_the_states_buffer():
+@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES)
+def test_the_new_state_takes_the_states_buffer(shape):
     """The call aliases the state onto its second result, so a caller that
     donates the state (the decode programs donate the pools) gets the new state
     where the old one lay and nothing of the state's size beside it."""
-    args = _inputs(3, 8, seed=4)
+    args = _inputs(3, 8, seed=4, shape=shape)
     want_o, want_s = kda.recurrent_step(*args)
     ((_, call),) = _pallas_calls(lambda: pk.recurrent_step(*args))
     assert dict(call.params["input_output_aliases"]) == {5: 1}
@@ -133,12 +154,15 @@ def test_the_new_state_takes_the_states_buffer():
 
 
 REFUSED = {
-    "K-half-a-lane-tile": lambda s, q, k, v, g, b: (s[:, :, :64], q[..., :64], k[..., :64], v, g[..., :64], b),
-    "V-not-whole-lane-tiles": lambda s, q, k, v, g, b: (
-        jnp.pad(s, ((0, 0),) * 3 + ((0, 64),)), q, k, jnp.pad(v, ((0, 0),) * 2 + ((0, 64),)), g, b),
+    "K-half-a-sublane-tile": lambda s, q, k, v, g, b: (s[:, :, :4], q[..., :4], k[..., :4], v, g[..., :4], b),
+    "K-not-whole-sublane-tiles": lambda s, q, k, v, g, b: (s[:, :, :100], q[..., :100], k[..., :100], v, g[..., :100], b),
     "bfloat16-state": lambda s, q, k, v, g, b: (s.astype(jnp.bfloat16), q, k, v, g, b),
     "a-rows-heads-past-the-block": lambda s, q, k, v, g, b: tuple(
         jnp.concatenate([a] * 9, axis=1) for a in (s, q, k, v, g, b)),
+    # 48 heads of 128 x 129 are 3.2 MB as numbers and 6.3 MB as they lie, V padded to two lane tiles
+    "a-padded-block-past-the-block": lambda s, q, k, v, g, b: tuple(
+        jnp.concatenate([a] * 6, axis=1) for a in (jnp.pad(s, ((0, 0),) * 3 + ((0, 1),)), q, k,
+                                                   jnp.pad(v, ((0, 0),) * 2 + ((0, 1),)), g, b)),
     "no-row-axis": lambda s, q, k, v, g, b: (s[0], q, k, v, g, b),
     "q-of-another-shape": lambda s, q, k, v, g, b: (s, q[:, :4], k, v, g, b),
     "beta-a-channel": lambda s, q, k, v, g, b: (s, q, k, v, g, g),
@@ -147,7 +171,7 @@ REFUSED = {
 
 @pytest.mark.parametrize("wrong", REFUSED.values(), ids=REFUSED)
 def test_what_the_kernel_cannot_take_is_refused_by_name(wrong):
-    with pytest.raises(ValueError, match="whole 128-lane tiles"):
+    with pytest.raises(ValueError, match="K whole 8-sublane tiles"):
         pk.recurrent_step(*wrong(*_inputs(2, 8)))
 
 
@@ -167,10 +191,20 @@ FORMS = {
     "the-backend-it-runs-on": (_state(), None, None, "jnp"),  # the tests run on the CPU
     "under-a-mesh": (_state(), "mesh8", "tpu", "jnp"),
     "bfloat16-state": (_state(dtype=jnp.bfloat16), None, "tpu", "jnp"),
-    "the-toys-heads-of-16": (_state((3, 4, 16, 16)), None, "tpu", "jnp"),
-    "K-half-a-tile": (_state((3, 4, 64, 128)), None, "tpu", "jnp"),
-    "V-a-tile-and-a-half": (_state((3, 4, 128, 192)), None, "tpu", "jnp"),
+    "the-toys-heads-of-16": (_state((3, 4, 16, 16)), None, "tpu", "kernel"),
+    "K-half-a-tile": (_state((3, 4, 64, 128)), None, "tpu", "kernel"),
+    "V-a-tile-and-a-half": (_state((3, 4, 128, 192)), None, "tpu", "kernel"),
+    "the-olmo-hybrid-cells-pool": (_state((129, 30, 96, 192)), None, "tpu", "kernel"),
+    "the-olmo-hybrid-cells-pool-under-a-mesh": (_state((129, 30, 96, 192)), "mesh8", "tpu", "jnp"),
+    "the-olmo-hybrid-cells-pool-on-a-cpu": (_state((129, 30, 96, 192)), None, "cpu", "jnp"),
+    "the-olmo-hybrid-cells-pool-in-bfloat16": (_state((129, 30, 96, 192), jnp.bfloat16), None, "tpu", "jnp"),
+    "K-not-whole-sublane-tiles": (_state((3, 4, 100, 128)), None, "tpu", "jnp"),
+    "K-half-a-sublane-tile": (_state((3, 4, 4, 128)), None, "tpu", "jnp"),
     "a-rows-heads-past-the-block": (_state((3, 72, 128, 128)), None, "tpu", "jnp"),
+    # 3,932,160 B of numbers, 5,242,880 B as they lie: V = 192 takes two lane tiles a row of K
+    "a-padded-block-past-the-block": (_state((3, 40, 128, 192)), None, "tpu", "jnp"),
+    "the-same-heads-in-whole-tiles": (_state((3, 40, 128, 128)), None, "tpu", "kernel"),
+    "no-heads": (_state((3, 0, 128, 128)), None, "tpu", "jnp"),
 }
 
 
@@ -207,47 +241,47 @@ def on_a_tpu(monkeypatch):
     jax.clear_caches()
 
 
-def _prompts(rows):
-    return [np.asarray(jax.random.randint(jax.random.key(r), (5 + 3 * r,), 0, CFG.vocab_size)).tolist()
+def _prompts(rows, cfg=CFG):
+    return [np.asarray(jax.random.randint(jax.random.key(r), (5 + 3 * r,), 0, cfg.vocab_size)).tolist()
             for r in range(rows)]
 
 
-def _prefilled(p, rows=3):
+def _prefilled(p, rows=3, cfg=CFG):
     """Pools with ``rows`` prompts prefilled, row r's state in slot r; the last
     row stays dead (its table names no page)."""
-    pools = transformer.make_paged_kv_pool(CFG, 32, BLOCK, state_slots=rows + 1)
+    pools = transformer.make_paged_kv_pool(cfg, 32, BLOCK, state_slots=rows + 1)
     tables = np.zeros((rows + 1, 4), np.int32)
     lens = np.zeros((rows + 1,), np.int32)
-    for r, toks in enumerate(_prompts(rows)):
+    for r, toks in enumerate(_prompts(rows, cfg)):
         tables[r, :3] = [1 + 3 * r, 2 + 3 * r, 3 + 3 * r]
         lens[r] = len(toks)
         _, pools = paged.prefill_into_pool(
-            p, CFG, pools, toks, tables[r, : paged.required_blocks(len(toks), BLOCK)].tolist(), slot=r)
+            p, cfg, pools, toks, tables[r, : paged.required_blocks(len(toks), BLOCK)].tolist(), slot=r)
     return pools, tables, lens
 
 
-def _slot_as_row(p, steps=3):
+def _slot_as_row(p, steps=3, cfg=CFG):
     """Decode steps over the pools as they lie (``paged.slots`` None)."""
-    pools, tables, lens = _prefilled(p)
+    pools, tables, lens = _prefilled(p, cfg=cfg)
     out = []
     for j in range(steps):
         tok = jnp.asarray([7 + j, 11 + j, 13 + j, 0], jnp.int32)
-        logits, pools = paged.paged_decode_logits(p, pools, tok, jnp.asarray(tables), jnp.asarray(lens), cfg=CFG)
+        logits, pools = paged.paged_decode_logits(p, pools, tok, jnp.asarray(tables), jnp.asarray(lens), cfg=cfg)
         out.append(np.asarray(logits[:3]))
         lens[:3] += 1
     return np.stack(out), pools
 
 
-def _gathered_slots(p, steps=3):
+def _gathered_slots(p, steps=3, cfg=CFG):
     """The same steps with the rows' slots handed in, out of order: each row's
     state gathered, stepped and scattered back (``paged.slots`` given)."""
-    pools, tables, lens = _prefilled(p)
+    pools, tables, lens = _prefilled(p, cfg=cfg)
     order = np.asarray([2, 0, 1])
     out = []
     for j in range(steps):
         tok = jnp.asarray([7 + j, 11 + j, 13 + j], jnp.int32)[order]
         logits, pools = transformer.forward(
-            p, tok[:, None], CFG, kv_cache=pools,
+            p, tok[:, None], cfg, kv_cache=pools,
             paged=transformer.PagedInfo(jnp.asarray(tables[order]), jnp.asarray(lens[order]), slots=jnp.asarray(order)),
         )
         out.append(np.asarray(logits[:, 0], np.float32)[np.argsort(order)])
@@ -314,7 +348,7 @@ def test_engine_reports_the_state_steps_form_on_a_tpu(params, on_a_tpu, caplog):
 
 def test_engine_off_the_tpu_at_the_toys_width_and_without_state_slots(params):
     assert ServingEngine(params, CFG, max_batch=2, n_blocks=16, block_size=BLOCK).pool_info()["decode_state"] == "jnp"
-    toy = get_preset("ling-mini").model  # KDA heads of 16: no lane tile
+    toy = get_preset("ling-mini").model  # KDA heads of 16: the kernel's on a TPU, not here
     eng = ServingEngine(transformer.init_params(toy, jax.random.key(0)), toy, max_batch=2, n_blocks=16, block_size=BLOCK)
     assert eng.pool_info()["decode_state"] == "jnp"
     dense = get_preset("tiny").model

@@ -369,13 +369,25 @@ def test_expert_kernel_compiles_for_the_chip_at_the_cells_size(one_chip, stack, 
     assert compiled.memory_analysis().temp_size_in_bytes < max(64 << 20, out_bytes + (16 << 20))
 
 
-def test_kda_step_compiles_for_the_chip_over_the_cells_pools_as_they_lie(one_chip, monkeypatch):
-    """``recurrent.mixer_block`` of a KDA decode step at ``serve_ling_decode_4k``'s size
-    (128 rows over 129 state slots of 32 heads x 128 x 128 float32, d_model
-    2560), the pools donated as the decode programs donate them: Mosaic takes
-    ``ops/pallas_kda.py`` with a row's 2 MB of state a block each way, the
-    state pool enters the custom call as it lies and the new pool is the same
-    buffer (no copy, no second array of the pool's size), and XLA's two
+# mixer, preset, what the cell's size replaces in it, (rows, heads, K, V) of the pool
+_STATE_STEPS = {
+    "ling-kda-128x128": ("kda", "ling-mini", dict(d_model=2560, n_heads=32, kda_head_dim=128), (128, 32, 128, 128)),
+    "olmo-hybrid-gdn-96x192": ("gdn", "olmo-hybrid-toy", dict(d_model=3840, n_heads=30, n_kv_heads=30, gdn_heads=30,
+                                                              gdn_key_dim=96, gdn_value_dim=192), (128, 30, 96, 192)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STATE_STEPS))
+def test_kda_step_compiles_for_the_chip_over_the_cells_pools_as_they_lie(one_chip, monkeypatch, case):
+    """``recurrent.mixer_block`` of a decode step at ``serve_ling_decode_4k``'s size
+    (KDA: 128 rows over 129 state slots of 32 heads x 128 x 128 float32, d_model
+    2560) and at ``serve_olmo_hybrid_decode_512_2k``'s (Gated DeltaNet: 30 heads x
+    96 x 192, d_model 3840, heads that fill no whole group of eight and V a lane
+    tile and a half), the pools donated as the decode programs donate them:
+    Mosaic takes ``ops/pallas_kda.py`` with a row's 2 MB (2.95 MB as the
+    96 x 192 state lies, V padded to two lane tiles) of state a block each way,
+    the state pool enters the custom call as it lies and the new pool is the
+    same buffer (no copy, no second array of the pool's size), and XLA's two
     fusions over the state are gone."""
     import dataclasses
     import functools
@@ -386,21 +398,22 @@ def test_kda_step_compiles_for_the_chip_over_the_cells_pools_as_they_lie(one_chi
     from pretraining_llm_tpu.models import kda, layers, recurrent, transformer
     from pretraining_llm_tpu.ops import pallas_kda
 
-    rows, heads, n, d = 128, 32, 128, 2560
-    cfg = dataclasses.replace(get_preset("ling-mini").model, d_model=d, n_heads=heads, kda_head_dim=n,
-                              param_dtype="bfloat16", compute_dtype="bfloat16")
-    monkeypatch.setattr(kda, "step_form", functools.partial(kda.step_form, backend="tpu"))
+    name, preset, sized, (rows, heads, n, nv) = _STATE_STEPS[case]
+    mixer = recurrent.MIXERS[name]
+    d = sized["d_model"]
+    cfg = dataclasses.replace(get_preset(preset).model, param_dtype="bfloat16", compute_dtype="bfloat16", **sized)
+    monkeypatch.setattr(mixer, "step_form", functools.partial(kda.step_form, backend="tpu"))
     monkeypatch.setattr(pallas_kda, "recurrent_step", functools.partial(pallas_kda.recurrent_step, interpret=False))
     placed = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
     blk = placed(jax.eval_shape(lambda: {
         "ln1": layers.init_norm("rmsnorm", d, jnp.bfloat16),
-        "attn": kda.init_params(cfg, jax.random.key(0), 0.02, jnp.bfloat16),
+        "attn": mixer.init_params(cfg, jax.random.key(0), 0.02, jnp.bfloat16),
     }))
-    pools = placed({name + "_pool": jax.ShapeDtypeStruct(*spec) for name, spec in kda.state_shapes(cfg, rows + 1).items()})
-    assert kda.step_form(pools["state_pool"]) == "kernel"
+    pools = placed({key + "_pool": jax.ShapeDtypeStruct(*spec) for key, spec in mixer.state_shapes(cfg, rows + 1).items()})
+    assert pools["state_pool"].shape == (rows + 1, heads, n, nv) and mixer.step_form(pools["state_pool"]) == "kernel"
 
     def fn(blk, pools, x, tables, seq_lens):
-        return recurrent.mixer_block("kda", blk, x, cfg, pools, paged=transformer.PagedInfo(tables, seq_lens))
+        return recurrent.mixer_block(name, blk, x, cfg, pools, paged=transformer.PagedInfo(tables, seq_lens))
 
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)  # unreadable without a chip
@@ -417,7 +430,7 @@ def test_kda_step_compiles_for_the_chip_over_the_cells_pools_as_they_lie(one_chi
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "output_to_operand_aliasing={{1}: (5, {})}" in text
     ops = [line.split(" = ", 1)[1] for line in text.splitlines() if " = " in line]
-    pool_sized = [op for op in ops if f"f32[{rows + 1},{heads},{n},{n}]" in op.split("(", 1)[0] and " parameter(" not in op]
+    pool_sized = [op for op in ops if f"f32[{rows + 1},{heads},{n},{nv}]" in op.split("(", 1)[0] and " parameter(" not in op]
     made = [op for op in pool_sized if not any(f" {kind}(" in op for kind in ("custom-call", "get-tuple-element", "tuple"))]
     assert pool_sized and not made, made
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
