@@ -21,7 +21,7 @@ from typing import Any, Dict, Optional, Tuple
 # Model
 # ---------------------------------------------------------------------------
 
-_ACTIVATIONS = ("relu", "gelu", "swiglu")
+_ACTIVATIONS = ("relu", "gelu", "swiglu", "relu2")
 _NORMS = ("layernorm", "rmsnorm")
 _POS_EMBEDS = ("learned", "rope", "none")
 _ATTN_IMPLS = ("naive", "flash", "ring", "ulysses")
@@ -65,6 +65,18 @@ def _without_retired_keys(section: str, kw: Dict[str, Any]) -> Dict[str, Any]:
     return out
 
 
+def layers_from_pattern(pattern: str) -> Dict[str, Tuple[str, ...]]:
+    """``layer_mixers`` and ``layer_ffns`` of a table of single sublayers from
+    Nemotron-H's ``hybrid_override_pattern``: ``M`` a Mamba-2 mixer alone, ``*``
+    an attention layer alone, ``E`` an expert FFN alone. A loader of the family's
+    configurations calls this (the benchmark's family file, the toy preset)."""
+    kinds = {"M": ("mamba", "none"), "*": ("attn", "none"), "E": ("none", "moe")}
+    if not pattern or set(pattern) - set(kinds):
+        raise ValueError(f"a layer pattern is made of {''.join(kinds)}, got {pattern!r}")
+    mixers, ffns = zip(*(kinds[c] for c in pattern))
+    return {"layer_mixers": mixers, "layer_ffns": ffns}
+
+
 def yarn_mscale(factor: float, mscale: float) -> float:
     """YaRN's attention-magnitude correction, 0.1 * mscale * ln(factor) + 1."""
     import math
@@ -94,7 +106,7 @@ class ModelConfig:
     # n_heads/n_kv_heads.
     n_kv_heads: Optional[int] = None
     mlp_ratio: float = 4.0
-    activation: str = "gelu"  # relu | gelu | swiglu
+    activation: str = "gelu"  # relu | gelu | swiglu | relu2 (ungated, relu(x)^2)
     norm: str = "layernorm"  # layernorm | rmsnorm
     pos_embed: str = "learned"  # learned | rope | none (no position of any kind)
     rope_theta: float = 10000.0
@@ -281,7 +293,15 @@ class ModelConfig:
     # attention layers (layer_runs); served, its attention layers alone keep
     # pages and each recurrent layer a fixed-size state a row
     # (transformer.make_paged_kv_pool). layer_kinds is the one table to ask.
+    # "none": the layer has no mixer, it is its FFN alone under one norm.
     layer_mixers: Tuple[str, ...] = ()
+    # The table's other column, the FFN of every layer: "dense", "moe" or
+    # "none" (the layer is its mixer alone under one norm, x + f(N(x))). Empty
+    # = every layer's FFN is what n_experts and n_dense_layers say. A table
+    # is of whole layers (a mixer and an FFN each) or of single sublayers (a
+    # mixer or an FFN each: Nemotron-H's pattern string), never both; a layer
+    # of one sublayer keeps one norm, and only a mixer layer keeps a cache.
+    layer_ffns: Tuple[str, ...] = ()
     # A Mamba-2 layer (arXiv:2405.21060): mamba_heads heads of mamba_head_dim
     # channels (the inner width, their product), a state of mamba_d_state a
     # channel, B and C shared by the heads of one of mamba_n_groups groups, a
@@ -410,8 +430,11 @@ class ModelConfig:
                 raise ValueError(
                     f"moe_score must be 'softmax' or 'sigmoid', got {self.moe_score!r}"
                 )
-            if self.moe_routing == "dropless" and self.activation != "swiglu":
-                raise ValueError("dropless experts are SwiGLU (activation='swiglu')")
+            if self.moe_routing == "dropless" and self.activation not in ("swiglu", "relu2"):
+                raise ValueError(
+                    "dropless experts are SwiGLU (activation='swiglu') or ungated relu^2 "
+                    "(activation='relu2')"
+                )
             if self.moe_routing == "capacity" and (
                 self.moe_score != "softmax" or self.moe_score_bias
                 or self.n_shared_experts or self.d_expert or self.moe_routed_scale != 1.0
@@ -434,6 +457,8 @@ class ModelConfig:
             raise ValueError(
                 "moe_n_group, n_experts_held and the SwiGLU clamps need moe_routing='dropless'"
             )
+        if (self.moe_swiglu_limits or self.moe_shared_swiglu_limits) and self.activation != "swiglu":
+            raise ValueError("the SwiGLU clamps clamp a SwiGLU (activation='swiglu')")
         for name in ("moe_swiglu_limits", "moe_shared_swiglu_limits"):
             # a JSON round trip hands back a list; the config is a static (hashed) jit argument
             object.__setattr__(self, name, tuple(float(v) for v in getattr(self, name)))
@@ -442,20 +467,48 @@ class ModelConfig:
                 raise ValueError("a SwiGLU clamp list has one limit >= 0 a layer")
         # a JSON round trip hands back a list; the config is a static (hashed) jit argument
         object.__setattr__(self, "layer_mixers", tuple(self.layer_mixers))
+        object.__setattr__(self, "layer_ffns", tuple(self.layer_ffns))
         if self.embed_scale is True:
             object.__setattr__(self, "embed_scale", float(self.d_model) ** 0.5)
         if self.layer_mixers and (
             self.layer_group_size or len(self.layer_mixers) != self.n_layers
-            or set(self.layer_mixers) - {"attn", "kda", "mamba", "gdn"}
+            or set(self.layer_mixers) - {"attn", "kda", "mamba", "gdn", "none"}
         ):
             raise ValueError(
-                f"layer_mixers names 'attn', 'kda', 'mamba' or 'gdn' for each of n_layers={self.n_layers} "
-                "layers, and layer_group_size (the shorthand that fills it) is then left at 0"
+                f"layer_mixers names 'attn', 'kda', 'mamba', 'gdn' or 'none' for each of "
+                f"n_layers={self.n_layers} layers, and layer_group_size (the shorthand that fills "
+                "it) is then left at 0"
             )
+        if self.layer_ffns or "none" in self.layer_mixers:
+            single = [(m == "none") != (f == "none") for m, f in self.layer_kinds]
+            if (
+                len(self.layer_ffns) not in (0, self.n_layers)
+                or set(self.layer_ffns) - {"dense", "moe", "none"}
+                or ("moe" in self.layer_ffns and not self.n_experts)
+                or any(m == f == "none" for m, f in self.layer_kinds)
+                or (any(single) and not all(single))
+            ):
+                raise ValueError(
+                    f"layer_ffns names 'dense', 'moe' (an expert model's) or 'none' for each of "
+                    f"n_layers={self.n_layers} layers; a layer has a mixer, an FFN or both, and a "
+                    "table is of whole layers or of single sublayers, not a mix of the two"
+                )
+            if self.single_sublayers and (
+                not self.hybrid or self.n_dense_layers or self.hc_mult > 1 or self.sandwich_norm
+                or self.mtp_depth or self.pipeline_stages > 1 or self.moe_capacity
+                or self.moe_swiglu_limits or self.moe_shared_swiglu_limits
+            ):
+                raise ValueError(
+                    "a table of single sublayers is built beside recurrent layers (a hybrid "
+                    "stack's per-layer caches; attention and FFN layers alone: ROADMAP) and runs on "
+                    "the plain residual under one norm a layer: no n_dense_layers (the table says "
+                    "it), residual streams, sandwich norms, multi-token-prediction module, pipeline, "
+                    "capacity-routed experts or per-layer SwiGLU clamps"
+                )
         if self.layer_group_size == 1 or self.layer_group_size < 0:
             raise ValueError("layer_group_size is a period of at least 2 layers")
         if self.hybrid:
-            mixers = {mixer for mixer, _ in self.layer_kinds}
+            mixers = {mixer for mixer, _ in self.layer_kinds} - {"none"}
             if len(mixers) != 2 or "attn" not in mixers:
                 raise ValueError(
                     "a hybrid stack has attention layers (their pages carry the block tables "
@@ -690,22 +743,30 @@ class ModelConfig:
         (per-head or latent attention, whichever the model has), ``"kda"``,
         ``"mamba"`` or ``"gdn"``, from ``layer_mixers`` (or ``layer_group_size``, the
         shorthand for a period of KDA layers closed by an attention layer); ffn
-        ``"dense"`` or ``"moe"``."""
+        ``"dense"`` or ``"moe"``, from ``layer_ffns`` or ``n_experts`` and
+        ``n_dense_layers``. Either may be ``"none"``: the layer is then its other
+        sublayer alone under one norm (``single_sublayers``)."""
         g = self.layer_group_size
         mixers = self.layer_mixers or tuple(
             "kda" if g and (i + 1) % g else "attn" for i in range(self.n_layers)
         )
-        return tuple(
-            (mixer, "moe" if self.n_experts and i >= self.n_dense_layers else "dense")
-            for i, mixer in enumerate(mixers)
+        ffns = self.layer_ffns or tuple(
+            "moe" if self.n_experts and i >= self.n_dense_layers else "dense"
+            for i in range(self.n_layers)
         )
+        return tuple(zip(mixers, ffns))
+
+    @property
+    def single_sublayers(self) -> bool:
+        """A table whose every layer is one sublayer, a mixer or an FFN."""
+        return any("none" in kind for kind in self.layer_kinds)
 
     @property
     def state_mixer(self) -> Optional[str]:
         """The kind of recurrent layer a hybrid stack has (``"kda"`` |
         ``"mamba"`` | ``"gdn"``): each keeps a fixed-size state a row where an attention
         layer keeps pages. None for a stack of attention layers alone."""
-        return next((mixer for mixer, _ in self.layer_kinds if mixer != "attn"), None)
+        return next((mixer for mixer, _ in self.layer_kinds if mixer not in ("attn", "none")), None)
 
     @property
     def hybrid(self) -> bool:
@@ -736,8 +797,9 @@ class ModelConfig:
 
     @property
     def n_cache_layers(self) -> int:
-        """Layers of a decode cache or page pool: the stack's, then the
-        multi-token-prediction module's block."""
+        """Entries of a decode cache or page pool: the stack's layers (a layer
+        with no mixer holds an empty one), then the multi-token-prediction
+        module's block."""
         return self.n_layers + self.mtp_depth
 
     @property
@@ -746,8 +808,20 @@ class ModelConfig:
 
     @property
     def n_state_layers(self) -> int:
-        """Recurrent layers of either kind: the layers that keep a state a row."""
-        return sum(mixer != "attn" for mixer, _ in self.layer_kinds)
+        """Recurrent layers of any kind: the layers that keep a state a row."""
+        return sum(mixer not in ("attn", "none") for mixer, _ in self.layer_kinds)
+
+    @property
+    def n_page_layers(self) -> int:
+        """Layers that keep pages in a served pool: the stack's attention layers
+        and the multi-token-prediction module's block."""
+        return sum(mixer == "attn" for mixer, _ in self.layer_kinds) + self.mtp_depth
+
+    @property
+    def n_cacheless_layers(self) -> int:
+        """Layers with no mixer: an FFN alone keeps nothing between calls, so
+        its entry in a cache or a pool is empty."""
+        return sum(mixer == "none" for mixer, _ in self.layer_kinds)
 
     @property
     def mamba_d_inner(self) -> int:
@@ -794,16 +868,19 @@ class ModelConfig:
         n = v * d  # token embedding
         if self.pos_embed == "learned":
             n += t * d
+        # a sublayer's own: its norm (two under sandwich norms) and its hyper-connection
         norms = 4 if self.sandwich_norm else 2
-        shared = self._attn_params() + norms * self._norm_params() + 2 * self._hc_params()
-        moe_layers = self.n_layers - self.n_dense_layers if self.n_experts else 0
-        n += (self.n_layers - moe_layers) * (shared + self._ffn_params(self.d_ff))
-        n += moe_layers * (shared + self._moe_params(self.experts_held))
-        mixer = {
-            "kda": self._kda_params, "mamba": self._mamba_params, "gdn": self._gdn_params,
-        }.get(self.state_mixer)
-        if mixer is not None:
-            n += self.n_state_layers * (mixer() - self._attn_params())
+        sub = norms // 2 * self._norm_params() + self._hc_params()
+        shared = self._attn_params() + 2 * sub
+        mixers = {
+            "attn": self._attn_params, "kda": self._kda_params, "mamba": self._mamba_params,
+            "gdn": self._gdn_params,
+        }
+        for mixer, ffn in self.layer_kinds:
+            if mixer != "none":
+                n += mixers[mixer]() + sub
+            if ffn != "none":
+                n += sub + (self._moe_params(self.experts_held) if ffn == "moe" else self._ffn_params(self.d_ff))
         n += self._norm_params()  # final norm
         # the module: a block of the stack's last kind, the (2D, D) projection, three norms
         ffn = self._moe_params(self.experts_held) if self.n_experts else self._ffn_params(self.d_ff)
@@ -870,6 +947,7 @@ class ModelConfig:
         d = self.d_model
         if self.activation == "swiglu":
             return d * 2 * f + f * d + ((2 * f + d) if self.mlp_bias else 0)
+        # ungated (relu, gelu, relu2): two matrices
         return d * f + f * d + ((f + d) if self.mlp_bias else 0)
 
     def _per_expert_params(self) -> int:
@@ -895,7 +973,7 @@ class ModelConfig:
         n = self.num_params()
         if self.n_experts:
             inactive = self.experts_held - self.experts_per_token
-            n -= (self.n_layers - self.n_dense_layers) * inactive * self._per_expert_params()
+            n -= sum(ffn == "moe" for _, ffn in self.layer_kinds) * inactive * self._per_expert_params()
         if self.mtp_depth:
             # the training forward does not run the module
             n -= self.num_params() - dataclasses.replace(self, mtp_depth=0).num_params()
@@ -924,7 +1002,7 @@ class ModelConfig:
             d_attn = self.n_heads * (self.head_dim + self.v_head_dim) // 2
         return (
             6 * self.num_active_params()
-            + 12 * self.n_layers * d_attn * self.context_length // 2
+            + 12 * (self.n_layers - self.n_cacheless_layers) * d_attn * self.context_length // 2
         )
 
 
@@ -2025,6 +2103,37 @@ _register(
             layer_mixers=("gdn", "gdn", "gdn", "attn") * 2,
             gdn_heads=3, gdn_key_dim=8, gdn_value_dim=16, gdn_allow_neg_eigval=True,
             qk_norm_whole=True, norm_placement="output",
+        ),
+        mesh=MeshConfig(),
+        data=DataConfig(tokenizer_name="byte"),
+        train=TrainConfig(batch_size=8, train_steps=50, eval_interval=20, eval_iters=2, lr=1e-3),
+    ),
+)
+
+# Every mechanism of the Nemotron-H (nemotron_h) family at a width a CPU smoke
+# run holds: a table of single sublayers from the family's pattern string (M a
+# Mamba-2 mixer alone, * an attention layer alone, E an expert FFN alone, each
+# x + f(N(x)) under its one norm), Mamba-2 at 2 groups of 2 heads whose inner
+# width (4 x 12) is not twice the hidden size, position-free attention whose
+# query width (4 x 16) is not the hidden size either, ungated relu^2 experts of
+# two matrices under a sigmoid router with a selection bias and a scale (half
+# of them held) beside a shared one of twice their width, an untied head.
+# Served, the Mamba-2 layers keep a state slot a row, the attention layer pages
+# and the expert layers nothing. The published widths are
+# benchmark/configs/nemotron-3-nano-30b-a3b.json; this is for the unit tests and
+# serve.py.
+_register(
+    "nemotron-h-toy",
+    Config(
+        model=ModelConfig(
+            vocab_size=256, context_length=256, d_model=32, n_heads=4, n_kv_heads=2, d_head=16,
+            n_layers=7, mlp_ratio=0.75, activation="relu2", norm="rmsnorm", pos_embed="none",
+            tie_embeddings=False, mlp_bias=False, norm_eps=1e-5,
+            **layers_from_pattern("MEM*EME"),
+            mamba_heads=4, mamba_head_dim=12, mamba_d_state=16, mamba_n_groups=2, mamba_chunk_size=16,
+            n_experts=8, n_experts_held=4, experts_per_token=2, moe_routing="dropless",
+            moe_score="sigmoid", moe_score_bias=True, moe_routed_scale=2.5,
+            n_shared_experts=2, d_expert=24,
         ),
         mesh=MeshConfig(),
         data=DataConfig(tokenizer_name="byte"),

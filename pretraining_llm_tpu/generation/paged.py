@@ -64,7 +64,7 @@ def _laid_out(params: Any, cfg: ModelConfig, mesh: Any) -> Any:
 def pool_block_size(pools: transformer.KVCache, cfg: ModelConfig) -> int:
     """Tokens a page of ``pools`` holds (per-head or latent)."""
     # the first layer that has pages (a hybrid stack's recurrent layers keep state slots)
-    fields = next(f for f in pools["layers"] if "state_pool" not in f)
+    fields = next(f for f in pools["layers"] if f and "state_pool" not in f)
     if "k_pool" in fields:
         return int(fields["k_pool"].shape[1])
     # a latent page is folded (models/mla.py::page_fold): rows x (slots a row x width)
@@ -217,6 +217,8 @@ def _scatter_staged_pages(
 
     def _layer(layer, layer_pool):
         out = dict(layer_pool)
+        if not layer_pool:  # a layer with no mixer staged nothing and keeps nothing
+            return out
         if "state_pool" in layer_pool:
             if slots is None:
                 raise ValueError("a state-slot model's prefill names each row's slot")

@@ -80,7 +80,16 @@ PREFILL_PROGRAM_TOKENS = 32768
 # 1.8 GB that does not grow with the tokens); 3.75 puts its refused 16,384 and
 # its accepted 8,192 tokens, and the linear-attention hybrid's accepted 32,768,
 # each a factor 1.2 from the line (PERF.md section 7, "After PR 43" (5)).
-PREFILL_EXPERT_COPIES = 3.75
+# The same where the expert layer's prefill runs ops/pallas_moe.py's kernel and
+# not the grouped matmuls (moe.prefill_form says which: ungated experts whose
+# width is no whole number of lane tiles): beside the above it holds the sorted
+# rows padded to whole row tiles, the visits' output blocks (7/6 of the rows)
+# and the gather that brings the sorted order back. The at-size compiles for a
+# v5e read 214 KB a padded token (6.6 copies of top-6 of 2,688) beside 0.53 GB
+# that does not grow: 8 x 2,048 tokens refused by 0.52 GB, 8 x 1,024 taken with
+# 1.47 GB to spare; 8.5 puts both a factor 1.2 and more from the line (PERF.md
+# section 6, PR 58).
+PREFILL_EXPERT_COPIES = {"grouped": 3.75, "kernel": 8.5}
 
 # What a dense model's prefill holds at once for a padded token: its keys and
 # values in every layer's staged pages, and the FFN's gate, up and hidden rows
@@ -92,17 +101,18 @@ PREFILL_EXPERT_COPIES = 3.75
 PREFILL_DENSE_MARGIN = 1.25
 
 
-def prefill_program_tokens(cfg: ModelConfig, free_bytes: Optional[int]) -> int:
+def prefill_program_tokens(cfg: ModelConfig, free_bytes: Optional[int], prefill_experts: str = "grouped") -> int:
     """The most padded tokens an admission prefill program of ``cfg`` may take
     beside what is resident: the largest power of two up to
     PREFILL_PROGRAM_TOKENS whose temporaries fit the ``free_bytes`` of the
-    device, an expert model's by its expert layer (PREFILL_EXPERT_COPIES), a
+    device, an expert model's by its expert layer (PREFILL_EXPERT_COPIES of the
+    form a prefill's experts take, ``prefill_experts``: moe.prefill_form), a
     dense model's by its staged pages and FFN rows (PREFILL_DENSE_MARGIN).
     None (a device that does not tell, as a CPU) keeps the constant."""
     if free_bytes is None:
         return PREFILL_PROGRAM_TOKENS
     if cfg.n_experts:
-        token_values = PREFILL_EXPERT_COPIES * cfg.experts_per_token * cfg.d_model
+        token_values = PREFILL_EXPERT_COPIES[prefill_experts] * cfg.experts_per_token * cfg.d_model
     else:
         token_values = PREFILL_DENSE_MARGIN * (
             cfg.n_layers * 2 * cfg.kv_heads * cfg.head_dim + 3 * cfg.d_ff
@@ -439,14 +449,17 @@ class ServingEngine:
         # And how a decode step (max_batch rows, K choices each) runs a dropless
         # layer's experts (models/moe.py::experts_form); None without any.
         self.decode_experts = None
+        prefill_experts = "grouped"  # and a prefill's, which sizes an admission program (prefill_program_tokens)
         if cfg.moe_dropless:
             experts = next(
                 stack["mlp"]["experts"] for _, stack, _ in transformer.layer_groups(params, cfg)
-                if "experts" in stack["mlp"]
+                if "experts" in stack.get("mlp", {})  # a stack of mixers alone has no FFN
             )
-            self.decode_experts = moe.experts_form(
-                queries * int(max_batch) * cfg.experts_per_token, cfg, experts, mesh=mesh
-            )
+            pairs = queries * int(max_batch) * cfg.experts_per_token
+            self.decode_experts = moe.experts_form(pairs, cfg, experts, mesh=mesh)
+            # the kernel's activation and tiles at that step, whether or not it runs
+            self.decode_experts_plan = moe.experts_plan(pairs, cfg, experts)
+            prefill_experts = moe.prefill_form(cfg, experts, mesh=mesh)
         # And how it steps a recurrent layer's state slots (the mixer's own
         # step_form, read from the pool's shape and dtype); None without any.
         self.decode_state = recurrent.step_form(cfg, int(max_batch) + 1, mesh=mesh)
@@ -601,7 +614,7 @@ class ServingEngine:
         # should the compiler refuse a program all the same)
         held = next(iter(jax.tree.leaves(self.pools)[0].devices())).memory_stats() or {}
         free = held["bytes_limit"] - held["bytes_in_use"] if "bytes_limit" in held else None
-        self.prefill_program_tokens = prefill_program_tokens(cfg, free)
+        self.prefill_program_tokens = prefill_program_tokens(cfg, free, prefill_experts)
         if self.prefill_program_tokens < PREFILL_PROGRAM_TOKENS:
             _log.info("admission prefills hold at most %d padded tokens a program: %.2f GB free beside the "
                       "weights and pools", self.prefill_program_tokens, free / 1e9)
@@ -759,8 +772,9 @@ class ServingEngine:
         pages included), host-side shape math only (no device sync).
         Draft pools (speculative serving) are reported separately."""
         pools = self.pools
-        # the first layer that has pages; a hybrid stack's recurrent layers keep state slots
-        layer0 = next(f for f in pools["layers"] if "state_pool" not in f)
+        # the first layer that has pages; a hybrid stack's recurrent layers keep
+        # state slots, a layer with no mixer keeps nothing
+        layer0 = next(f for f in pools["layers"] if f and "state_pool" not in f)
         state = int(sum(
             leaf.nbytes for f in pools["layers"] if "state_pool" in f
             for leaf in jax.tree.leaves(f)
@@ -798,6 +812,7 @@ class ServingEngine:
             info["pool_kv_heads"] = int(layer0["k_pool"].shape[-2])
         if self.decode_experts:
             info["decode_experts"] = self.decode_experts  # "kernel" | "grouped"
+            info["decode_experts_plan"] = self.decode_experts_plan
         if self.two_lifetimes:
             info.update(
                 full_pool_bytes=total, full_layers=self.cfg.n_layers - len(window_layers),
@@ -812,8 +827,9 @@ class ServingEngine:
                 decode_state=self.decode_state,  # "kernel" | "jnp"
                 # the two kinds of layer: which mixer keeps the slots, in how
                 # many layers, and how many layers the pages above are for
+                # and how many keep no cache at all (an FFN alone: the layer table)
                 state_mixer=self.cfg.state_mixer, state_layers=self.cfg.n_state_layers,
-                page_layers=self.cfg.n_cache_layers - self.cfg.n_state_layers,
+                page_layers=self.cfg.n_page_layers, cacheless_layers=self.cfg.n_cacheless_layers,
             )
         if self.self_draft:
             # the module's pages are one more layer of ``pools``, counted above
@@ -2698,12 +2714,15 @@ class ServingEngine:
             st = self.stats
             routing = ""
             if "moe_steps" in st:
-                routing = "; experts (%s) took %d pairs, %d touched, %d weight reads over %d steps" % (
+                routing = "; experts (%s) took %d pairs, %d touched, %d weight reads over %d steps (%s)" % (
                     self.decode_experts, st["moe_expert_tokens"].sum(),
-                    st["moe_experts_touched"].sum(), st["moe_visits"], st["moe_steps"],
+                    st["moe_experts_touched"].sum(), st["moe_visits"], st["moe_steps"], self.decode_experts_plan,
                 )
             if self.state_slots:
-                routing += "; state slots stepped as %s" % self.decode_state
+                routing += "; state slots stepped as %s (%d state, %d page, %d cacheless layers)" % (
+                    self.decode_state, self.cfg.n_state_layers, self.cfg.n_page_layers,
+                    self.cfg.n_cacheless_layers,
+                )
             if self.spec_k:
                 routing += "; %d speculative rounds (draft: %s) proposed %d, accepted %d" % (
                     st.get("spec_rounds", 0), "mtp" if self.self_draft else "model",

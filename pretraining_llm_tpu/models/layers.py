@@ -99,6 +99,8 @@ def activation_fn(kind: str, x: jax.Array) -> jax.Array:
         return jax.nn.relu(x)
     if kind == "gelu":
         return jax.nn.gelu(x, approximate=True)
+    if kind == "relu2":
+        return jnp.square(jax.nn.relu(x))
     raise ValueError(f"activation_fn does not handle {kind!r} (swiglu is fused in mlp)")
 
 

@@ -3,7 +3,11 @@ state a row, not pages. The second recurrent mixer beside ``models/kda.py``,
 under the same cache discipline (``models/recurrent.py::mixer_block``).
 
 Per token, with ``u`` the sublayer's normed input, H heads of P channels
-(``d_in`` = H P), a state of N a channel, G groups of heads sharing B and C::
+(``d_in`` = H P, ``cfg.mamba_heads * cfg.mamba_head_dim``: the product, never
+an expansion factor times the hidden size — Granite's 8,192 happens to be
+2 x 4,096, Nemotron-H's 4,096 is not 2 x 2,688), a state of N a channel, G
+groups of H / G consecutive heads sharing B and C (1 in Granite, 8 in
+Nemotron-H)::
 
     [z | xBC | dt] = u W_in                     widths d_in | d_in + 2 G N | H
     xBC = SiLU(conv(xBC) + b)                   causal depthwise, over time

@@ -215,9 +215,14 @@ def init_dropless_params(
     cfg: ModelConfig, key: jax.Array, resid_std: float, dtype: jnp.dtype
 ) -> Params:
     """Router (D, E) [+ selection bias (E,)], experts stored as the grouped
-    matmul reads them — w1 (E, D, 2F) with the gate columns before the up
-    columns, w2 (E, F, D) — and the shared expert as a dense SwiGLU."""
+    matmul and ``ops/pallas_moe.py`` both read them, and the shared expert as the
+    model's dense FFN. A SwiGLU expert (``cfg.activation == "swiglu"``) is w1
+    (E, D, 2F) with the gate columns before the up columns and w2 (E, F, D); an
+    ungated relu^2 expert (``"relu2"``) is two matrices, w1 (E, D, F) and w2
+    (E, F, D), at its own width (no padding to lane tiles: the kernel cuts F in
+    sublane tiles of both)."""
     d, f, e, held = cfg.d_model, cfg.expert_width, cfg.n_experts, cfg.experts_held
+    gated = cfg.activation == "swiglu"
     ks = jax.random.split(key, 5)
 
     def normal(k: jax.Array, shape: Tuple[int, ...], s: float = 0.02) -> jax.Array:
@@ -226,14 +231,15 @@ def init_dropless_params(
     out: Params = {
         "router": normal(ks[0], (d, e)),
         "experts": {
-            "w1": normal(ks[1], (held, d, 2 * f)), "w2": normal(ks[2], (held, f, d), resid_std)
+            "w1": normal(ks[1], (held, d, (1 + gated) * f)), "w2": normal(ks[2], (held, f, d), resid_std)
         },
     }
     if cfg.moe_score_bias:
         out["router_bias"] = jnp.zeros((e,), dtype)
     if cfg.n_shared_experts:
         fs = cfg.n_shared_experts * f
-        out["shared"] = {"w1": normal(ks[3], (d, 2, fs)), "w2": normal(ks[4], (fs, d), resid_std)}
+        w1_shape = (d, 2, fs) if gated else (d, fs)
+        out["shared"] = {"w1": normal(ks[3], w1_shape), "w2": normal(ks[4], (fs, d), resid_std)}
     return out
 
 
@@ -313,19 +319,50 @@ def experts_form(
     unquantized bfloat16 experts of whole 128-lane tiles that no mesh shards it
     takes the kernel where Mosaic compiles; more rows an expert (the cells'
     prefills, from 64 up), int8 or float32 experts, a mesh and every other
-    backend keep the grouped form. The engine reports the decode step's form in
-    ``pool_info()``."""
+    backend keep the grouped form. An ungated expert (w1 as wide as w2 is tall:
+    ``pallas_moe.ungated``) needs whole lane tiles of D only and a width of
+    whole 16-row sublane tiles: the kernel reads both its matrices with F on the
+    sublanes (1,856 is 14.5 lane tiles and 116 sublane tiles), and at such a
+    width it takes every call whatever its rows (the comment below). The engine reports the decode step's form in
+    ``pool_info()``, and ``experts_plan`` the kernel's activation and tiles."""
     w1 = experts["w1"]
-    if (
-        rows <= KERNEL_ROWS_PER_EXPERT * cfg.n_experts
-        and w1.dtype == jnp.dtype(cfg.compute_dtype) == jnp.bfloat16
+    ungated = pallas_moe.ungated(w1, experts["w2"])
+    if not (
+        w1.dtype == jnp.dtype(cfg.compute_dtype) == jnp.bfloat16
         and mesh is None
         and (backend or jax.default_backend()) == "tpu"
         and w1.shape[-2] % 128 == 0
-        and w1.shape[-1] % 256 == 0
+        and w1.shape[-1] % (pallas_moe.ROW_TILE if ungated else 256) == 0
     ):
+        return "grouped"
+    if ungated and w1.shape[-1] % 128:
+        # The TPU lays (.., D, F) out with D minor-most where F is no whole number
+        # of lane tiles (no padding), ``ragged_dot`` wants F there, and XLA
+        # re-lays the whole stack for every call of it: 3.4 GB a call at 11 x 32
+        # experts of 2,688 x 1,856, which does not fit beside the pools. The
+        # kernel reads the stack as it lies, so it takes every call, a prefill's
+        # too, at more visits an expert (``pallas_moe.MAX_WINDOWS``).
         return "kernel"
-    return "grouped"
+    return "kernel" if rows <= KERNEL_ROWS_PER_EXPERT * cfg.n_experts else "grouped"
+
+
+def prefill_form(cfg: ModelConfig, experts: Params, mesh: Any = None) -> str:
+    """The form these experts take in a prefill: at the first count of rows past
+    the rule's bound, so ``"kernel"`` only where the shape takes the kernel at
+    every size. What the engine sizes its admission programs by."""
+    return experts_form((KERNEL_ROWS_PER_EXPERT + 1) * cfg.n_experts, cfg, experts, mesh)
+
+
+def experts_plan(rows: int, cfg: ModelConfig, experts: Params) -> str:
+    """What ``ops/pallas_moe.py`` would run for ``rows`` sorted pairs over these
+    experts, in words for ``pool_info()``: the activation, the weight tiles a
+    grid step takes and their F tile, the row windows a visit holds."""
+    w1 = experts["w1"]
+    d, itemsize, w = w1.shape[-2], w1.dtype.itemsize, pallas_moe.windows(rows, cfg.n_experts)
+    gated = not pallas_moe.ungated(w1, experts["w2"])
+    f = w1.shape[-1] // (1 + gated)
+    tf = pallas_moe.f_tile(d, f, itemsize, w, gated)
+    return f"{'swiglu, 3' if gated else 'relu2, 2'} tiles a step of {tf} of F {f}, {w} windows a visit"
 
 
 def _in_stack(w1: jax.Array, w2: jax.Array, sizes: jax.Array, layer: jax.Array):
@@ -339,9 +376,15 @@ def _in_stack(w1: jax.Array, w2: jax.Array, sizes: jax.Array, layer: jax.Array):
 
 
 def _grouped_pair(xs: jax.Array, w1: jax.Array, w2: jax.Array, sizes: jax.Array, limit: Any):
+    """The two grouped matmuls and the activation between them, which the
+    shapes name: w1 twice as wide as w2 is tall is a SwiGLU's gate and up
+    columns, w1 as wide an ungated expert's, relu(.)^2."""
     f, cdt = w2.shape[-2], xs.dtype
     up = jax.lax.ragged_dot(xs, w1, sizes, preferred_element_type=cdt)
-    hidden = swiglu(up[:, :f], up[:, f:], limit)
+    if pallas_moe.ungated(w1, w2):
+        hidden = jnp.square(jax.nn.relu(up))
+    else:
+        hidden = swiglu(up[:, :f], up[:, f:], limit)
     return jax.lax.ragged_dot(hidden, w2, sizes, preferred_element_type=cdt)
 
 
@@ -384,8 +427,8 @@ experts_kernel.defvjp(_experts_kernel_fwd, _experts_kernel_bwd)
 def moe_mlp_dropless(
     mlp: Params, h: jax.Array, cfg: ModelConfig, dense_mlp: Any
 ) -> Tuple[jax.Array, jax.Array]:
-    """Dropless expert FFN on normed input h (B, T, D) -> (output, tokens
-    routed to each expert (E,) int32).
+    """Dropless expert FFN (SwiGLU or ungated relu^2 experts) on normed input
+    h (B, T, D) -> (output, tokens routed to each expert (E,) int32).
 
     The (token, choice) pairs are sorted by expert, the experts held run over
     their rows in the form the input picks (``experts_form``: one grouped
@@ -400,7 +443,9 @@ def moe_mlp_dropless(
     ``cfg.n_experts`` the router scores, from ``cfg``'s first expert on: a
     pair routed to an expert that lives elsewhere adds nothing here (expert
     parallelism's share of the result, without the exchange).
-    ``dense_mlp(params, h)`` is the model's dense SwiGLU, for the shared expert.
+    ``dense_mlp(params, h)`` is the model's dense FFN (a SwiGLU, or the ungated
+    relu^2 of a model whose experts are), for the shared expert. The experts'
+    own activation is read from their shapes (``_grouped_pair``).
 
     Inside a layer stack ``mlp["experts"]`` is the stack's weights, (L, E, ...),
     and ``mlp["expert_layer"]`` says which layer this is: the grouped matmul

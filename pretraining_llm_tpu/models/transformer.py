@@ -175,9 +175,15 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 
     g = cfg.kv_heads
 
-    def init_block(k: jax.Array, dense_ffn: bool = False, mixer: str = "attn") -> Params:
+    def init_block(k: jax.Array, dense_ffn: bool = False, mixer: str = "attn", ffn: bool = True) -> Params:
+        """One layer: its mixer under ``ln1`` and its FFN under ``ln2``. A layer
+        of one sublayer (``mixer="none"`` or ``ffn=False``: the table's
+        ``"none"``) has that sublayer and its one norm alone."""
         ks = jax.random.split(k, 5)
-        if mixer != "attn":
+        attn: Params = {}
+        if mixer == "none":
+            pass
+        elif mixer != "attn":
             attn: Params = recurrent.MIXERS[mixer].init_params(cfg, ks[0], resid_std, dtype)
         elif cfg.kv_lora_rank:
             attn: Params = mla.init_attn_params(cfg, ks[0], resid_std, dtype)
@@ -204,7 +210,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
                 attn["k_norm"] = layers.init_norm("rmsnorm", dh * (g if cfg.qk_norm_whole else 1), dtype)
             if cfg.attn_output_gate:
                 attn["wg"] = normal(jax.random.fold_in(k, 13), (d, h, dh))
-        if cfg.moe_dropless and not dense_ffn:
+        if not ffn:
+            mlp: Params = {}
+        elif cfg.moe_dropless and not dense_ffn:
             mlp: Params = moe.init_dropless_params(cfg, ks[2], resid_std, dtype)
         elif cfg.n_experts and not dense_ffn:
             mlp: Params = moe.init_moe_params(cfg, ks[2], resid_std, dtype)
@@ -218,12 +226,11 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             if cfg.mlp_bias:
                 mlp["b1"] = jnp.zeros((f,), dtype)
                 mlp["b2"] = jnp.zeros((d,), dtype)
-        block = {
-            "ln1": layers.init_norm(cfg.norm, d, dtype),
-            "attn": attn,
-            "ln2": layers.init_norm(cfg.norm, d, dtype),
-            "mlp": mlp,
-        }
+        block = {}
+        if mixer != "none":
+            block.update(ln1=layers.init_norm(cfg.norm, d, dtype), attn=attn)
+        if ffn:
+            block.update(ln2=layers.init_norm(cfg.norm, d, dtype), mlp=mlp)
         if cfg.sandwich_norm:
             block["ln1_post"] = layers.init_norm(cfg.norm, d, dtype)
             block["ln2_post"] = layers.init_norm(cfg.norm, d, dtype)
@@ -246,7 +253,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         for kind in sorted(set(kinds)):
             of_kind = jnp.asarray([i for i, k in enumerate(kinds) if k == kind])
             params[stack_key(cfg, *kind)] = jax.vmap(lambda k, _kind=kind: init_block(
-                k, dense_ffn=_kind[1] == "dense", mixer=_kind[0]
+                k, dense_ffn=_kind[1] == "dense", mixer=_kind[0], ffn=_kind[1] != "none"
             ))(layer_keys[of_kind])
     else:
         params["blocks"] = jax.vmap(init_block)(layer_keys[cfg.n_dense_layers:])
@@ -279,9 +286,14 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 def stack_key(cfg: ModelConfig, mixer: str, ffn: str) -> str:
     """Where the layers of one kind are stacked in the parameter tree:
     "blocks", or "dense_blocks" for an expert model's leading dense layers; a
-    hybrid stack's attention layers (its recurrent layers are the many) in
-    "attn_blocks" and "attn_dense_blocks"."""
+    hybrid stack's attention layers in "attn_blocks" and "attn_dense_blocks"
+    (its recurrent layers keep the plain names), and the layers with no mixer
+    of a table of single sublayers in "ffn_blocks" and "ffn_dense_blocks"
+    (there "blocks" are the recurrent mixers alone, "attn_blocks" the
+    attention layers alone)."""
     key = "dense_blocks" if ffn == "dense" and cfg.n_experts else "blocks"
+    if mixer == "none":
+        return "ffn_" + key
     return "attn_" + key if cfg.hybrid and mixer == "attn" else key
 
 
@@ -372,7 +384,7 @@ def serving_layout(params: Params, cfg: ModelConfig) -> Params:
         next(k for k, v in params.items() if v is stack) for _, stack, _ in layer_groups(params, cfg)
     }
     for key in sorted(stacks):
-        mlp = params[key]["mlp"]
+        mlp = params[key].get("mlp", {})  # a stack of mixers alone has none
         halves = _serving_halves(mlp.get("w1"))
         if halves is not None:
             mlp = {k: v for k, v in mlp.items() if k != "w1"}
@@ -969,11 +981,20 @@ def _block(
     mixer: str = "attn",
 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
     """One decoder layer. ``mixer`` is the layer's entry in the layer table
-    (``cfg.layer_kinds``): "attn", or a recurrent mixer of ``recurrent.MIXERS``."""
+    (``cfg.layer_kinds``): "attn", a recurrent mixer of ``recurrent.MIXERS``, or
+    "none". A layer of one sublayer is what ``blk`` holds: an FFN alone (no
+    "attn"; its cache entry, empty, goes through as it came) or a mixer alone
+    (no "mlp"), each under its one norm, x + f(N(x))."""
+    if mixer == "none":
+        x, aux = _mlp_block(blk, x, cfg, decode=kv is not None and x.shape[1] == 1)
+        return x, kv, aux
+    no_ffn = "mlp" not in blk  # then the layer's other sublayer is all of it: no router loss, no counts
     if mixer != "attn":
         if zigzag or segments is not None:
             raise ValueError("a recurrent layer has no ring layout and no document mask")
         x, new_kv = recurrent.mixer_block(mixer, blk, x, cfg, kv, pad_offsets, paged, lengths)
+        if no_ffn:
+            return x, new_kv, jnp.zeros((), jnp.float32)
         x, aux = _mlp_block(blk, x, cfg, decode=kv is not None and x.shape[1] == 1)
         return x, new_kv, aux
     if cfg.hc_mult > 1:
@@ -1011,6 +1032,8 @@ def _block(
     x = constrain(
         x, ("data", "fsdp"), "seq" if cfg.sequence_parallel else None, None
     )
+    if no_ffn:
+        return x, new_kv, jnp.zeros((), jnp.float32)
     # Uncapacitated MoE routing only for single-token decode steps: prefill
     # processes whole prompts, where capacity = token count would rebuild the
     # O(S^2) dispatch the grouped path exists to avoid.
@@ -1227,7 +1250,7 @@ def forward(
 
     def without_experts(blocks):
         """(blocks less a dropless group's expert stack, that stack or None)."""
-        if not (cfg.moe_dropless and "experts" in blocks["mlp"]):
+        if not (cfg.moe_dropless and "experts" in blocks.get("mlp", {})):
             return blocks, None
         mlp = {k: v for k, v in blocks["mlp"].items() if k != "experts"}
         return {**blocks, "mlp": mlp}, blocks["mlp"]["experts"]
@@ -1290,7 +1313,7 @@ def forward(
             xs = (xs, idx if clamps is None else (idx, clamps))
             step = remat.checkpoint_wrap(with_experts, cfg.remat)
         (x, aux), out = jax.lax.scan(step, (x, aux), xs)
-        if cfg.moe_dropless and "router" in blocks["mlp"]:
+        if cfg.moe_dropless and "router" in blocks.get("mlp", {}):
             out, counts = out  # an expert group yields (outputs, tokens per expert)
             counts_of.append(counts)
         return x, aux, out
@@ -1815,9 +1838,9 @@ def loss_fn(
 def _is_pool_cache(kv_cache: Optional[KVCache]) -> bool:
     """True for a page pool (make_paged_kv_pool), false for a dense cache."""
     layers_ = (kv_cache or {}).get("layers")
-    return bool(layers_) and any(
-        name in layers_[0] for name in ("k_pool", "latent_pool", "state_pool")
-    )
+    # the first layer that keeps a cache at all (a layer with no mixer keeps none)
+    first = next((lyr for lyr in layers_ or () if lyr), {})
+    return any(name in first for name in ("k_pool", "latent_pool", "state_pool"))
 
 
 def _unstack_fields(
@@ -1837,6 +1860,7 @@ def _unstack_fields(
     window = tuple(window_fields is not None and k == "window" for k in cfg.layer_attn_kinds)
     return {
         "layers": tuple(
+            {} if mixer == "none" else  # an FFN alone keeps nothing between calls
             {name: jnp.zeros(shape, dt) for name, (shape, dt) in state_fields.items()}
             if mixer != "attn" else
             {name: jnp.zeros(shape[1:], dt)

@@ -40,6 +40,18 @@ matmuls and the SwiGLU between them in one pass over those bytes.
 Same arithmetic as the grouped form: operands in the compute dtype, float32
 accumulation, gate / up and the hidden each rounded to the compute dtype once.
 Forward only here; ``models/moe.py`` gives the call the grouped form's VJP.
+
+**Two expert forms, read from the shapes.** ``w1 (.., D, 2F)`` is the SwiGLU
+above, three weight tiles a grid step. ``w1 (.., D, F)`` is an **ungated**
+expert of two matrices, ``w2 . relu(x . w1)^2`` (Nemotron-H), two tiles a step,
+and its F need not be whole lane tiles (1,856 = 14.5 of them). The TPU lays an
+array of (.., 2688, 1856) out with the 2,688 minor-most, the axis that fills
+whole lane tiles (every stored byte a real one, no padding to 1,920), so the
+kernel takes ``w1`` as that layout's own view, ``(.., F, D)`` (the ``swapaxes``
+in ``_moe_call`` is a bitcast there, and an at-size compile shows no copy of
+the stack), cuts F tiles of whole 16-row sublane tiles from it (464 = 16 x 29)
+exactly as it cuts them from ``w2 (.., F, D)``, and the up-projection contracts
+the lane axes of both operands. Same grid, same visits, same accumulator.
 """
 
 from __future__ import annotations
@@ -63,20 +75,36 @@ ROW_TILE = 16
 # section 6, PR 44).
 WEIGHT_TILE_BYTES = 12 << 20
 VMEM_BYTES = 14 << 20
+# The widest visit: what the rule's bound asks for (48 rows an expert: twice the
+# mean from a window's last row). A call of more rows an expert (only an ungated
+# expert whose F is no whole number of lane tiles makes one: ``moe.experts_form``)
+# keeps it and takes more visits an expert.
+MAX_WINDOWS = 7
 
 
-def f_tile(d: int, f: int, itemsize: int, w: int = 2) -> int:
+def ungated(w1: jax.Array, w2: jax.Array) -> bool:
+    """Whether these experts are the two-matrix form: ``w1`` ([L,] E, D, F) as
+    wide as ``w2`` ([L,] E, F, D) is tall, not a SwiGLU's (.., D, 2F). The one
+    place that reads it from the shapes; ``models/moe.py`` asks here too."""
+    return w1.shape[-1] == w2.shape[-2]
+
+
+def f_tile(d: int, f: int, itemsize: int, w: int = 2, gated: bool = True) -> int:
     """Columns of F a grid step takes under a visit of ``w`` windows: the
     largest whole number of 128-lane tiles that divides F and keeps two buffers
     of the three weight tiles under ``WEIGHT_TILE_BYTES``, and under
-    ``VMEM_BYTES`` with the visit's rows (at least one lane tile)."""
+    ``VMEM_BYTES`` with the visit's rows (at least one lane tile). An ungated
+    expert (``gated=False``) has two weight tiles a step and F on the sublanes
+    of both: whole 16-row tiles of it (at least one; 1,856 = 64 x 29 gives 464
+    at every width of visit)."""
     # a row of a visit: two buffers in, two out, the scratch, the float32 accumulator
     rows = w * ROW_TILE * d * (5 * itemsize + 4)
+    unit, tiles = (128, 3) if gated else (ROW_TILE, 2)
     fits = [
-        tf for tf in range(128, f + 1, 128)
-        if f % tf == 0 and 2 * 3 * d * tf * itemsize <= min(WEIGHT_TILE_BYTES, VMEM_BYTES - rows)
+        tf for tf in range(unit, f + 1, unit)
+        if f % tf == 0 and 2 * tiles * d * tf * itemsize <= min(WEIGHT_TILE_BYTES, VMEM_BYTES - rows)
     ]
-    return max(fits, default=128)
+    return max(fits, default=unit)
 
 
 def windows(n_rows: int, n_experts: int) -> int:
@@ -84,11 +112,11 @@ def windows(n_rows: int, n_experts: int) -> int:
     over ``n_experts`` (all the router scores, held here or not): two up to
     ROW_TILE rows an expert; past that the fewest that hold a group of twice
     the mean in one visit wherever it starts (a group may start on a window's
-    last row)."""
+    last row), and never more than ``MAX_WINDOWS``."""
     if n_rows <= ROW_TILE * n_experts:
         return 2
     twice = -(-2 * n_rows // n_experts)
-    return -(-(twice + ROW_TILE - 1) // ROW_TILE)
+    return min(-(-(twice + ROW_TILE - 1) // ROW_TILE), MAX_WINDOWS)
 
 
 def n_visits(n_rows: int, held: int, w: int = 2) -> int:
@@ -142,10 +170,14 @@ def _moe_kernel(
     live_ref,  # (1,) int32: visits that do anything
     *refs,
     clamp: bool,
+    gated: bool = True,
 ):
     if clamp:
         lim_ref, *refs = refs  # (1,) float32 in SMEM
-    *x_refs, wg_ref, wu_ref, wd_ref, o_ref, x_scr, acc_scr = refs  # x_refs: the visit's windows
+    *refs, wu_ref, wd_ref, o_ref, x_scr, acc_scr = refs
+    if gated:
+        *refs, wg_ref = refs
+    x_refs = refs  # the visit's windows
     v, j = pl.program_id(0), pl.program_id(1)
     cdt = x_scr.dtype
 
@@ -160,12 +192,19 @@ def _moe_kernel(
         x = x_scr[...]
         dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
         # rounded to the compute dtype as ragged_dot's preferred_element_type does
-        gate = dot(x, wg_ref[...]).astype(cdt).astype(jnp.float32)
-        up = dot(x, wu_ref[...]).astype(cdt).astype(jnp.float32)
-        if clamp:
-            lim = lim_ref[0]
-            gate, up = jnp.minimum(gate, lim), jnp.clip(up, -lim, lim)
-        hidden = (gate * jax.nn.sigmoid(gate)).astype(cdt).astype(jnp.float32) * up
+        if gated:
+            gate = dot(x, wg_ref[...]).astype(cdt).astype(jnp.float32)
+            up = dot(x, wu_ref[...]).astype(cdt).astype(jnp.float32)
+            if clamp:
+                lim = lim_ref[0]
+                gate, up = jnp.minimum(gate, lim), jnp.clip(up, -lim, lim)
+            hidden = (gate * jax.nn.sigmoid(gate)).astype(cdt).astype(jnp.float32) * up
+        else:
+            # the (tf, D) tile of w1 as it lies: the lane axes of both operands contract
+            up = jax.lax.dot_general(
+                x, wu_ref[...], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            up = jnp.maximum(up.astype(cdt).astype(jnp.float32), 0.0)
+            hidden = up * up
         acc_scr[...] += dot(hidden.astype(cdt), wd_ref[...])
 
         @pl.when(j == pl.num_programs(1) - 1)
@@ -177,7 +216,8 @@ def _moe_kernel(
 def _moe_call(xs, w1, w2, sizes, layer, limit, tf, w, interpret):
     n, d = xs.shape
     held, f = w1.shape[-3], w2.shape[-2]
-    w1 = w1.reshape(-1, d, 2 * f)  # a stack's (L, E) as L * E groups: a bitcast
+    gated = not ungated(w1, w2)
+    w1 = w1.reshape(-1, d, w1.shape[-1])  # a stack's (L, E) as L * E groups: a bitcast
     w2 = w2.reshape(-1, f, d)
     nf, span = f // tf, w * ROW_TILE
     n_rows = n + -n % ROW_TILE
@@ -199,21 +239,24 @@ def _moe_call(xs, w1, w2, sizes, layer, limit, tf, w, interpret):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(jnp.maximum(live[0], 1), nf),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM) for _ in lim] + [x_window(i) for i in range(w)] + [
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM) for _ in lim] + [x_window(i) for i in range(w)] + ([
             pl.BlockSpec((None, d, tf), lambda v, j, exp, win, live: (exp[v], 0, j)),
             pl.BlockSpec((None, d, tf), lambda v, j, exp, win, live: (exp[v], 0, nf + j)),
+        ] if gated else [
+            pl.BlockSpec((None, tf, d), lambda v, j, exp, win, live: (exp[v], j, 0)),  # of w1 as (.., F, D)
+        ]) + [
             pl.BlockSpec((None, tf, d), lambda v, j, exp, win, live: (exp[v], j, 0)),
         ],
         out_specs=pl.BlockSpec((span, d), lambda v, j, exp, win, live: (v, 0)),
         scratch_shapes=[pltpu.VMEM((span, d), xs.dtype), pltpu.VMEM((span, d), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_moe_kernel, clamp=clamp),
+        functools.partial(_moe_kernel, clamp=clamp, gated=gated),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((visits * span, d), xs.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(expert + layer * held, window, live, *lim, *[xs] * w, w1, w1, w2)
+    )(expert + layer * held, window, live, *lim, *[xs] * w, *((w1, w1) if gated else (jnp.swapaxes(w1, 1, 2),)), w2)
     return out[position[:n]]
 
 
@@ -229,7 +272,9 @@ def expert_ffn(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """(silu(x . gate_e) * (x . up_e)) . down_e for each sorted row x of held
-    expert e: (N, D) in ``xs``'s dtype. Rows past ``sum(sizes)`` (experts held
+    expert e: (N, D) in ``xs``'s dtype; with ``w1`` ([L,] E, D, F), as wide as
+    ``w2`` is tall, the ungated relu(x . w1_e)^2 . w2_e (F whole 16-row tiles,
+    no clamp). Rows past ``sum(sizes)`` (experts held
     elsewhere) come back as something finite or not: the caller selects them
     away, as after ``ragged_dot``. ``interpret=None``: compiled on TPU, the
     interpreter elsewhere (tests)."""
@@ -238,22 +283,25 @@ def expert_ffn(
     n, d = xs.shape
     held, f = w1.shape[-3], w2.shape[-2]
     stacked = w1.ndim == 4
+    two = ungated(w1, w2)
     if (
-        w1.shape[-3:] != (held, d, 2 * f)
+        w1.shape[-3:] not in ((held, d, 2 * f), (held, d, f))
         or w2.shape != w1.shape[:-2] + (f, d)
         or w1.ndim != 3 + stacked
         or not xs.dtype == w1.dtype == w2.dtype
         or sizes.shape != (held,)
         or stacked != (layer is not None)
-        or d % 128 or f % 128
+        or d % 128 or f % (ROW_TILE if two else 128)
+        or (two and limit is not None)
         or w < 2
     ):
         raise ValueError(
             f"rows {xs.shape} {xs.dtype}, w1 {w1.shape} {w1.dtype}, w2 {w2.shape} {w2.dtype}, sizes "
-            f"{sizes.shape}, layer {layer}, {w} windows a visit: want (N, D), ([L,] E, D, 2F), ([L,] E, F, D), "
-            f"(E,) in one dtype, D and F whole 128-lane tiles, a layer exactly for a stack, two windows or more"
+            f"{sizes.shape}, layer {layer}, {w} windows a visit: want (N, D), ([L,] E, D, 2F) or an ungated "
+            f"([L,] E, D, F) without a clamp, ([L,] E, F, D), (E,) in one dtype, D whole 128-lane tiles and F "
+            f"too (ungated: whole 16-row tiles), a layer exactly for a stack, two windows or more"
         )
     layer = jnp.zeros((), jnp.int32) if layer is None else jnp.asarray(layer, jnp.int32)
     return _moe_call(
-        xs, w1, w2, sizes, layer, limit, f_tile(d, f, xs.dtype.itemsize, int(w)), int(w), bool(interpret)
+        xs, w1, w2, sizes, layer, limit, f_tile(d, f, xs.dtype.itemsize, int(w), not two), int(w), bool(interpret)
     )

@@ -136,7 +136,10 @@ MOE_SHAPES = {
     "ling": (3, 128, 2560, 768, 1024, 256, 512),
     "xing": (3, 64, 3584, 1024, 128, 128, 64),
     "granite": (3, 18, 4096, 768, 1280, 304, 72),
+    # ungated relu^2 experts of two matrices, a width of 14.5 lane tiles (MOE_UNGATED)
+    "nemotron": (3, 32, 2688, 1856, 768, 192, 128),
 }
+MOE_UNGATED = ("nemotron",)
 MOE_MIXES = ("uniform", "skewed", "one-takes-all", "tile-edges", "all-elsewhere")
 
 
@@ -174,7 +177,7 @@ def _moe_inputs(shape: str, mix: str, rows_an_expert: int = 0, seed: int = 0):
         one = jax.jit(lambda k: (jax.random.normal(k, shape, jnp.float32) * fan_in ** -0.5).astype(jnp.bfloat16))
         return jnp.stack([one(k) for k in jax.random.split(key, n_stack)])
 
-    w1, w2 = stack(ks[1], (held, d, 2 * f), d), stack(ks[2], (held, f, d), f)
+    w1, w2 = stack(ks[1], (held, d, (1 if shape in MOE_UNGATED else 2) * f), d), stack(ks[2], (held, f, d), f)
     return xs, w1, w2, jnp.asarray(sizes, jnp.int32)
 
 
@@ -186,7 +189,7 @@ def moe_case(shape: str, mix: str, layer: int, clamp: bool, rows_an_expert: int 
     from pretraining_llm_tpu.ops import pallas_moe
 
     xs, w1, w2, sizes = _moe_inputs(shape, mix, rows_an_expert)
-    limit = jnp.float32(1.5) if clamp else None
+    limit = jnp.float32(1.5) if clamp and shape not in MOE_UNGATED else None  # an ungated expert has no clamp
     w = pallas_moe.windows(xs.shape[0], MOE_SHAPES[shape][-1])
     got = jax.jit(moe.experts_kernel, static_argnums=6)(xs, w1, w2, sizes, jnp.int32(layer), limit, w)
     want = jax.jit(moe.experts_grouped)(xs, w1, w2, sizes, jnp.int32(layer), limit)
@@ -239,7 +242,7 @@ def time_moe(reps: int = 20, only: str = ""):
                 reads = lambda w: round(float(pallas_moe.group_visits(sizes, w)[0].sum()) / max(touched, 1), 3)
                 line = {
                     "shape": shape, "mix": mix, "rows_an_expert": rows or "decode step", "rows": xs.shape[0],
-                    "here": int(sizes.sum()), "touched": touched, "touched_mb": round(touched * 3 * d * f * 2 / 1e6, 1),
+                    "here": int(sizes.sum()), "touched": touched, "touched_mb": round(touched * (2 if shape in MOE_UNGATED else 3) * d * f * 2 / 1e6, 1),
                     "max_over_mean": round(float(sizes.max()) * held / max(int(sizes.sum()), 1), 2),
                     "w": w, "reads_a_touched": reads(w), "reads_a_touched_w2": reads(2),
                 }
@@ -311,7 +314,8 @@ def cases():
             for layer, clamp in ((0, False), (MOE_SHAPES[shape][0] - 1, True)):
                 name = f"moe {shape} {mix} layer{layer}" + (" clamp" if clamp else "")
                 yield name, moe_case, (shape, mix, layer, clamp)
-        for rows in (24, 48):  # past a row tile an expert: the visit widens
+        for rows in (24, 48) + ((384,) if shape in MOE_UNGATED else ()):  # past a row tile an expert: the visit widens
+            # (384: a prefill's rows, which a width of no whole lane tiles hands the kernel too)
             yield f"moe {shape} skewed rows{rows}", moe_case, (shape, "skewed", 1, False, rows)
     for t in (1024, 2048):  # one block (fused backward) / 2x2 blocks
         for g in (12, 4):

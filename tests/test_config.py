@@ -181,6 +181,8 @@ _PERF_INTENT = {
     "trinity-toy":     ("naive",        "none",           "chunked"),
     "granite-toy":     ("naive",        "none",           "chunked"),
     "olmo-hybrid-toy": ("naive",        "none",           "chunked"),
+    # the same for the Nemotron-H mechanisms (a table of single sublayers, ungated relu^2 experts)
+    "nemotron-h-toy":  ("naive",        "none",           "chunked"),
 }
 
 
@@ -203,3 +205,24 @@ def test_preset_perf_knobs_match_intent(name):
     )
     assert m.remat == remat, f"{name}: remat={m.remat!r}, intent {remat!r}"
     assert m.ce_impl == ce, f"{name}: ce_impl={m.ce_impl!r}, intent {ce!r}"
+
+
+@pytest.mark.parametrize("name", ["tiny", "xing-mini", "ling-mini", "joyai-mini", "trinity-toy", "granite-toy",
+                                  "olmo-hybrid-toy", "nemotron-h-toy", "moe-8x350m"])
+def test_every_count_of_layers_asks_the_table(name):
+    """The table's counts add up for every kind of stack, and the parameter
+    count walks the same table: an FFN alone is no recurrent layer, keeps no
+    cache and has no attention term; a whole layer counts once in each column."""
+    m = get_preset(name).model
+    kinds = m.layer_kinds
+    assert len(kinds) == m.n_layers and m.n_cache_layers == m.n_layers + m.mtp_depth
+    assert m.n_state_layers + (m.n_page_layers - m.mtp_depth) + m.n_cacheless_layers == m.n_layers
+    assert m.single_sublayers == (name == "nemotron-h-toy") == bool(m.n_cacheless_layers)
+    assert (m.state_mixer is not None) == m.hybrid == bool(m.n_state_layers)
+    assert sum(b - a for a, b in m.layer_runs) == m.n_layers
+    ffns = [f for _, f in kinds]
+    assert ffns.count("moe") == (0 if not m.n_experts else m.n_layers - m.n_dense_layers - ffns.count("none"))
+    inactive = (m.experts_held - m.experts_per_token) * m._per_expert_params() if m.n_experts else 0
+    mtp = m.num_params() - dataclasses.replace(m, mtp_depth=0).num_params()
+    assert m.num_params() - m.num_active_params() == ffns.count("moe") * inactive + mtp
+

@@ -85,7 +85,7 @@ def test_decay_mask_covers_every_leaf_of_every_preset():
             context_length=32,
             d_model=16,
             n_heads=4,
-            n_layers=2,
+            n_layers=3 if model.layer_ffns else 2,
             d_head=4,
             n_kv_heads=(2 if (model.n_kv_heads or model.n_heads) != model.n_heads else None),
             n_experts=min(model.n_experts, 4),
@@ -97,9 +97,12 @@ def test_decay_mask_covers_every_leaf_of_every_preset():
                if model.layer_group_size else dict(n_experts_held=min(model.n_experts_held, 2))),
             # a stack of window and full attention layers keeps one layer of each kind
             **(dict(attn_kinds=("window", "full")) if model.attn_kinds else {}),
-            # a stack that names its mixers keeps one layer of each
-            **(dict(layer_mixers=("mamba", "attn"), mamba_heads=2, mamba_head_dim=8, mamba_d_state=4)
+            # a stack that names its mixers keeps one layer of each; a table of single
+            # sublayers one layer of each of its three kinds
+            **(dict(layer_mixers=("mamba", "attn") + (("none",) if model.layer_ffns else ()),
+                    mamba_heads=2, mamba_head_dim=8, mamba_d_state=4)
                if model.layer_mixers else {}),
+            **(dict(layer_ffns=("none", "none", "moe")) if model.layer_ffns else {}),
         )
         params = transformer.init_params(tiny, jax.random.key(0))
         mask = opt.decay_mask(params)

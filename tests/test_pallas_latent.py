@@ -369,6 +369,47 @@ def test_expert_kernel_compiles_for_the_chip_at_the_cells_size(one_chip, stack, 
     assert compiled.memory_analysis().temp_size_in_bytes < max(64 << 20, out_bytes + (16 << 20))
 
 
+@pytest.mark.parametrize("rows,windows", [(128 * 6, 2), (1024 * 6, 7), (8192 * 6, 7)],
+                         ids=["nemotron-decode-step", "nemotron-one-prompt-admission", "nemotron-8192-token-admission"])
+def test_ungated_expert_kernel_compiles_for_the_chip_at_the_cells_size(one_chip, rows, windows):
+    """``ops/pallas_moe.py``'s two-matrix form at the Nemotron-H cell's expert
+    layers (a stack of 11 x 32 experts of 2,688 x 1,856, a width of 14.5 lane
+    tiles): Mosaic takes F tiles of 464 rows of both matrices, the up-projection
+    contracting the lane axes of both operands; the device keeps ``w1`` with D
+    minor-most, so its (.., F, D) view is a bitcast and neither stack is copied,
+    sliced or re-laid (the grouped form re-lays all 3.4 GB of ``w1`` a call). A
+    prefill's rows take the same kernel at the widest visit, whose rows and the
+    two tiles' buffers (14.2 MB) still stand inside ``VMEM_BYTES``."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from pretraining_llm_tpu.ops import pallas_moe as pm
+
+    stack, held, n_experts, d, f = 11, 32, 128, 2688, 1856
+    shape = lambda s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    w = pm.windows(rows, n_experts)
+    tf = pm.f_tile(d, f, 2, w, gated=False)
+    assert (w, tf) == (windows, 464)
+    fn = lambda xs, w1, w2, sizes, layer: pm._moe_call(xs, w1, w2, sizes, layer, None, tf, w, False)
+    args = [shape((rows, d)), shape((stack, held, d, f)), shape((stack, held, f, d)), shape((held,), jnp.int32),
+            shape((), jnp.int32)]
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # unreadable without a chip
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(fn).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "ragged-dot" not in text
+    ops = [line.split(" = ", 1)[1] for line in text.splitlines() if " = " in line]
+    stack_sized = [op for op in ops if op.startswith((f"bf16[{stack},{held},", f"bf16[{stack * held},"))
+                   and " parameter(" not in op]
+    assert len(stack_sized) == 2 and all(" bitcast(" in op for op in stack_sized), stack_sized
+    out_bytes = pm.n_visits(rows, held, w) * w * pm.ROW_TILE * d * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < out_bytes + rows * d * 2 + (16 << 20)
+
+
 # mixer, preset, what the cell's size replaces in it, (rows, heads, K, V) of the pool
 _STATE_STEPS = {
     "ling-kda-128x128": ("kda", "ling-mini", dict(d_model=2560, n_heads=32, kda_head_dim=128), (128, 32, 128, 128)),

@@ -401,3 +401,47 @@ def test_gradient_through_the_layer_is_the_grouped_forms(on_a_tpu, monkeypatch):
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert np.isfinite(np.asarray(g, np.float32)).all()
         np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(w, np.float32), atol=2e-2, rtol=5e-2)
+
+
+# What Mosaic is handed for a SwiGLU expert at the five expert cells' decode
+# shapes (stack, held, experts scored, D, F, sorted rows, clamp): the hash of
+# the kernel's assembly without source locations. PR 58 put a second expert
+# form (two matrices, relu^2) into the same kernel body and pinned these from
+# the parent's tree: an edit for one form that leaves the other's cells as
+# they were leaves these as they are; one that moves them says so here, and
+# owes those cells' rates.
+SWIGLU_CELLS = {
+    "ling": ((4, 128, 512, 2560, 768, 1024, False), "eb9a28296186"),
+    "xing": ((5, 64, 64, 3584, 1024, 128, True), "85267eae5d17"),
+    "trinity": ((4, 128, 128, 2048, 1024, 512, False), "87d03b0c40a2"),
+    "granite": ((10, 18, 72, 4096, 768, 1280, False), "b31b3df94d85"),
+    "joyai": ((4, 128, 256, 2048, 768, 1024, False), "a28ac67faaa7"),
+}
+
+
+@pytest.mark.parametrize("cell", list(SWIGLU_CELLS))
+def test_the_swiglu_kernel_handed_to_mosaic_is_the_pinned_one(cell):
+    import base64
+    import hashlib
+    import re
+
+    from jax._src.interpreters import mlir as jmlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    (stack, held, n_experts, d, f, rows, clamp), pinned = SWIGLU_CELLS[cell]
+    w = pk.windows(rows, n_experts)
+    tf = pk.f_tile(d, f, 2, w)
+    s = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype)
+    args = [s((rows, d)), s((stack, held, d, 2 * f)), s((stack, held, f, d)), s((held,), jnp.int32), s((), jnp.int32)]
+    if clamp:
+        args.append(s((), jnp.float32))
+    call = lambda xs, w1, w2, sizes, layer, *lim: pk._moe_call(xs, w1, w2, sizes, layer, *(lim or (None,)), tf, w, False)
+    text = jax.jit(call).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    (body,) = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)
+    ctx = jmlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    with ctx:
+        ctx.allow_unregistered_dialects = True
+        asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(enable_debug_info=False)
+    assert hashlib.sha256(asm.encode()).hexdigest()[:12] == pinned

@@ -813,3 +813,46 @@ def test_gdn_slots_and_forms_say_so_on_the_commit_span_and_in_pool_info(monkeypa
     wide = dataclasses.replace(GDN_CFG, n_heads=30, n_kv_heads=30, d_head=128)
     pools = jax.eval_shape(lambda: transformer.make_paged_kv_pool(wide, 4, 8, state_slots=2))
     assert pools["layers"][3]["k_pool"].shape == (4, 8, 32, 128)  # thirty heads of 128: stored as 32
+
+
+# A table of single sublayers (Nemotron-H): every layer one sublayer under one norm, and each kind of
+# layer keeps its own scopes: the mixer's parts under `ssm.*`, the attention's under `attn.full`, the
+# expert FFN's under `mlp` / `moe.*`, the layer's one norm under `blk.norm`.
+NEMO_CFG = dataclasses.replace(get_preset("nemotron-h-toy").model, compute_dtype="float32")
+NEMO_SCOPES = {
+    "decode": ("ssm.proj", "ssm.conv", "ssm.step", "ssm.norm", "ssm.out", "attn.full", "attn.qkv", "attn.kv_write",
+               "attn.core", "attn.out", "mlp", "moe.router", "moe.dispatch", "moe.experts", "moe.shared",
+               "moe.combine", "blk.norm", "final_norm", "lm_head"),
+    "prefill": ("ssm.proj", "ssm.conv", "ssm.chunk", "ssm.norm", "ssm.out", "attn.full", "attn.kv_write",
+                "attn.core", "moe.experts", "moe.shared", "blk.norm", "sample"),
+}
+
+
+@pytest.fixture(scope="module")
+def nemo_paths():
+    p = transformer.init_params(NEMO_CFG, jax.random.key(0))
+    pools = lambda: transformer.make_paged_kv_pool(NEMO_CFG, 16, 8, state_slots=2)
+    tables = jnp.asarray(np.arange(1, 9).reshape(2, 4), jnp.int32)
+    lowered = {
+        "decode": paged.paged_decode_steps.lower(
+            p, pools(), jnp.asarray([3, 5], jnp.int32), tables, jnp.asarray([4, 9], jnp.int32),
+            jax.random.key(1), NEMO_CFG, n_steps=2),
+        "prefill": paged._prefill_scatter_sample.lower(
+            p, pools(), jnp.zeros((2, 16), jnp.int32), jnp.asarray([16, 11], jnp.int32),
+            jnp.asarray([[1, 2], [3, 4]], jnp.int32), jax.random.key(2), NEMO_CFG, 16, 2,
+            slots=jnp.asarray([0, 1], jnp.int32)),
+    }
+    return {k: set(re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True))) for k, low in lowered.items()}
+
+
+@pytest.mark.parametrize("program,scope", [(p, s) for p, ss in NEMO_SCOPES.items() for s in ss])
+def test_single_sublayer_scope_is_in_the_lowered_program(nemo_paths, program, scope):
+    words = [re.split(r"[/()]", p) for p in nemo_paths[program]]
+    assert [w for w in words if scope in w]
+    other = {"decode": "ssm.chunk", "prefill": "ssm.step"}[program]
+    assert not [w for w in words if other in w]
+    assert not [w for w in words if "attn.rope" in w]  # no position of any kind
+    # one sublayer a layer: nothing of the experts stands inside a mixer's scope, nor the other way round
+    assert not [w for w in words if "attn.full" in w and [x for x in w if x.startswith(("ssm.", "moe."))]]
+    assert not [w for w in words if "mlp" in w and [x for x in w if x.startswith(("ssm.", "attn."))]]
+
