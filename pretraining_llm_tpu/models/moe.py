@@ -424,6 +424,72 @@ def _experts_kernel_bwd(windows, res, g):
 experts_kernel.defvjp(_experts_kernel_fwd, _experts_kernel_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def experts_visits(
+    xs: jax.Array, w1: jax.Array, w2: jax.Array, sizes: jax.Array, visits: Any, layer: Any = None,
+    limit: Any = None, windows: int = 2,
+) -> jax.Array:
+    """``experts_kernel`` without the way back to sorted order: ``xs`` (whole
+    row tiles of sorted rows) under ``visits``, the first three of
+    ``pallas_moe.plan(sizes, xs.shape[0], windows)`` -> the visits' output, in
+    which row i of group e lies at the plan's ``offset[e] + i``. What the layer
+    calls: it gathers each pair's row from there once. Its VJP brings the
+    cotangent to sorted order and is then the grouped form's."""
+    return pallas_moe.expert_visits(xs, w1, w2, sizes, visits, layer, limit, w=windows)
+
+
+def _experts_visits_fwd(xs, w1, w2, sizes, visits, layer, limit, windows):
+    return experts_visits(xs, w1, w2, sizes, visits, layer, limit, windows), (xs, w1, w2, sizes, layer, limit)
+
+
+def _experts_visits_bwd(windows, res, g):
+    xs, sizes = res[0], res[3]
+    offset = pallas_moe.plan(sizes, xs.shape[0], windows)[3]
+    g = g[pallas_moe.sorted_positions(sizes, offset, xs.shape[0], g.shape[0])]
+    g = jnp.where(jnp.arange(xs.shape[0])[:, None] < jnp.sum(sizes), g, 0)  # past the last group: nobody's
+    d_xs, d_w1, d_w2, _, _, d_limit = _experts_kernel_bwd(windows, res, g)
+    return d_xs, d_w1, d_w2, None, (None, None, None), None, d_limit
+
+
+experts_visits.defvjp(_experts_visits_fwd, _experts_visits_bwd)
+
+
+# Pairs a block of ``_places``: a pair is compared with the 256 of its block.
+COUNT_BLOCK = 256
+
+
+def _of_expert(flat: jax.Array, table: jax.Array) -> jax.Array:
+    """``table[..., flat]`` for ``flat`` (...,) in [0, E] and ``table`` (E,), or
+    one that broadcasts against ``flat``'s leading axes, (..., 1, E): a compare
+    against all E summed, 0 for E itself (a pair held elsewhere). A gather of N
+    scalars costs the TPU 9 us at 1,280 (PERF.md section 6, PR 59); this fuses
+    into what reads it."""
+    chose = flat[..., None] == jnp.arange(table.shape[-1], dtype=jnp.int32)
+    return jnp.sum(jnp.where(chose, table, 0), axis=-1)
+
+
+def _places(flat: jax.Array, held: int) -> Tuple[jax.Array, jax.Array]:
+    """A pair's place, counted once and in token order. ``flat`` (N,) is each
+    (token, choice) pair's held expert, ``held`` for one that lives elsewhere ->
+    (sizes (held,) pairs of each held expert, rank (N,) the pairs before a pair
+    that chose its expert: its place in its group under a stable sort).
+
+    The pairs before a pair are those of its own block of ``COUNT_BLOCK`` (one
+    compare of the block against itself under a triangle, summed) and those of
+    the blocks before (the cumulative sum of the blocks' totals, N / 256 rows of
+    ``held``). Nothing of N x held is stored; no sort, no search, no scatter. A
+    cumulative sum down all N pairs is a reduce-window of 28 us at 1,280 pairs
+    on the TPU, five times a sort of them (PERF.md section 6, PR 59)."""
+    n = flat.shape[0]
+    blocks = -(-n // COUNT_BLOCK)
+    fb = jnp.pad(flat, (0, blocks * COUNT_BLOCK - n), constant_values=held).reshape(blocks, COUNT_BLOCK)
+    earlier = jnp.tril(jnp.ones((COUNT_BLOCK, COUNT_BLOCK), bool), -1)
+    within = jnp.sum((fb[:, :, None] == fb[:, None, :]) & earlier, axis=2, dtype=jnp.int32)
+    totals = jnp.sum(fb[:, :, None] == jnp.arange(held, dtype=jnp.int32), axis=1, dtype=jnp.int32)  # (blocks, held)
+    rank = within + _of_expert(fb, (jnp.cumsum(totals, axis=0) - totals)[:, None, :])
+    return jnp.sum(totals, axis=0), rank.reshape(blocks * COUNT_BLOCK)[:n]
+
+
 def moe_mlp_dropless(
     mlp: Params, h: jax.Array, cfg: ModelConfig, dense_mlp: Any
 ) -> Tuple[jax.Array, jax.Array]:
@@ -435,9 +501,18 @@ def moe_mlp_dropless(
     matmul a projection, ``jax.lax.ragged_dot``, for a prefill's hundreds of
     rows an expert; one Pallas kernel that streams each touched expert's
     weights once for a decode step's handful to few dozen, its visit as wide as
-    ``pallas_moe.windows`` of the same figure), the result is un-sorted and the
-    K weighted parts of a token summed. A token's output is a function of that
-    token alone.
+    ``pallas_moe.windows`` of the same figure), and the K weighted parts of a
+    token are summed. A token's output is a function of that token alone.
+
+    A pair's place is planned once, in token order: one stable sort gives the
+    order of the way in, one count (``_places``) the sizes of the groups and
+    each pair's rank in its group, and arithmetic on those the row of the
+    experts' output that holds the pair's (the kernel's output lies a span a
+    visit, ``pallas_moe.plan``; the grouped form's in sorted order). Each row is
+    gathered once on the way in (the kernel's windows read sorted rows) and once
+    on the way out, straight from that output into token order, where it is
+    weighted: nothing is brought back to sorted order only to be un-sorted, no
+    second sort inverts the first, no search finds an expert again.
 
     The layer holds the experts its weights carry, ``w1.shape[0]`` of the
     ``cfg.n_experts`` the router scores, from ``cfg``'s first expert on: a
@@ -467,32 +542,47 @@ def moe_mlp_dropless(
     with jax.named_scope("moe.router"):
         idx, gates = route_dropless(mlp, x, cfg)
     with jax.named_scope("moe.dispatch"):
-        flat = idx.reshape(s * k)
+        n = s * k
+        flat = idx.reshape(n)
         flat = jnp.where(flat < held, flat, held)  # experts held elsewhere sort last
         order = jnp.argsort(flat, stable=True)
-        counts = jnp.bincount(flat, length=held + 1).astype(jnp.int32)
-        xs = x[order // k].astype(cdt)  # (S*K, D), rows grouped by expert
-        sizes = counts[:held]
-        if form == "grouped" and layer is not None:
-            w1, w2, sizes = _in_stack(w1, w2, sizes, layer)
+        sizes, rank = _places(flat, held)
+        if form == "kernel":
+            windows = pallas_moe.windows(n, cfg.n_experts)
+            order = jnp.pad(order, (0, -n % pallas_moe.ROW_TILE))  # whole row tiles: the pad rows are nobody's
+            # the kernel writes a span a visit: where each group's first row lies in that
+            *visits, first_row = pallas_moe.plan(sizes, order.shape[0], windows)
+        else:
+            # the grouped form writes a row where it read it: a group begins after those before it
+            first_row, group_sizes = jnp.cumsum(sizes) - sizes, sizes
+            if layer is not None:
+                w1, w2, group_sizes = _in_stack(w1, w2, sizes, layer)
+        # the row of the experts' output that holds the pair's (some row of the first group's for one held elsewhere)
+        dest = rank + _of_expert(flat, first_row)
+        xs = x[order // k].astype(cdt)  # rows grouped by expert
     with jax.named_scope("moe.experts"):
         if form == "kernel":
-            ys = experts_kernel(xs, w1, w2, sizes, layer, limit, pallas_moe.windows(s * k, cfg.n_experts))
+            out = experts_visits(xs, w1, w2, sizes, tuple(visits), layer, limit, windows)
         else:
-            ys = _grouped_pair(xs, w1, w2, sizes, limit)
+            out = _grouped_pair(xs, w1, w2, group_sizes, limit)
     with jax.named_scope("moe.combine"):
-        here = flat[order] < held
-        g_sorted = jnp.where(here, gates.reshape(s * k)[order], 0.0)
-        ys = ys.astype(jnp.float32) * g_sorted[:, None]
-        if held < cfg.n_experts:
-            # rows past the last group belong to no expert held: the grouped
-            # matmul promises nothing for them, so select, do not multiply by 0
-            ys = jnp.where(here[:, None], ys, 0.0)
-        ys = ys.astype(cdt)
-        y = jnp.sum(
-            ys[jnp.argsort(order)].reshape(s, k, d).astype(jnp.float32), axis=1
-        ).astype(cdt).reshape(b, t, d)
+        # each pair's row straight from the experts' output, choice-major: K
+        # slabs of S whole rows whatever K is, summed slab by slab in choice order
+        here, weight = idx < held, jnp.where(idx < held, gates, 0.0)
+        rows = out[dest.reshape(s, k).T.reshape(n)]
+        y = None
+        for j in range(k):
+            part = rows[j * s : (j + 1) * s].astype(jnp.float32) * weight[:, j, None]
+            if held < cfg.n_experts:
+                # rows past the last group belong to no expert held: the grouped
+                # matmul promises nothing for them, so select, do not multiply by 0
+                part = jnp.where(here[:, j, None], part, 0.0)
+            # rounded to the compute dtype as an array of it would be (the TPU's
+            # compiler drops a cast there and back and would sum the unrounded products)
+            part = jax.lax.reduce_precision(part, jnp.finfo(cdt).nexp, jnp.finfo(cdt).nmant)
+            y = part if y is None else y + part
+        y = y.astype(cdt).reshape(b, t, d)
     if "shared" in mlp:
         with jax.named_scope("moe.shared"):
             y = y + dense_mlp(mlp["shared"], h)
-    return y.astype(h.dtype), counts[:held]
+    return y.astype(h.dtype), sizes

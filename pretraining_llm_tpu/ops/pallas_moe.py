@@ -34,8 +34,11 @@ matmuls and the SwiGLU between them in one pass over those bytes.
   - Neighbouring groups share a sublane tile of the sorted rows, so a visit
     cannot write its rows where they lie. It computes its whole windows
     (the neighbours' rows through its own weights are wasted MXU passes, of
-    which a byte-bound kernel has plenty) into an output block of its own, and
-    one row gather outside brings the sorted order back.
+    which a byte-bound kernel has plenty) into an output block of its own. The
+    layer gathers each pair's row from there straight into token order
+    (``expert_visits``: the visits' output and ``plan``'s ``offset``); the sorted
+    form (``expert_ffn``: timing, tests, the VJP) brings the sorted order back
+    with one row gather.
 
 Same arithmetic as the grouped form: operands in the compute dtype, float32
 accumulation, gate / up and the hidden each rounded to the compute dtype once.
@@ -48,7 +51,7 @@ and its F need not be whole lane tiles (1,856 = 14.5 of them). The TPU lays an
 array of (.., 2688, 1856) out with the 2,688 minor-most, the axis that fills
 whole lane tiles (every stored byte a real one, no padding to 1,920), so the
 kernel takes ``w1`` as that layout's own view, ``(.., F, D)`` (the ``swapaxes``
-in ``_moe_call`` is a bitcast there, and an at-size compile shows no copy of
+in ``_visits_call`` is a bitcast there, and an at-size compile shows no copy of
 the stack), cuts F tiles of whole 16-row sublane tiles from it (464 = 16 x 29)
 exactly as it cuts them from ``w2 (.., F, D)``, and the up-projection contracts
 the lane axes of both operands. Same grid, same visits, same accumulator.
@@ -141,27 +144,41 @@ def group_visits(sizes: jax.Array, w: int = 2):
 
 
 def plan(sizes: jax.Array, n_rows: int, w: int = 2):
-    """``sizes`` (E,) rows a held expert -> the visits and the way back.
+    """``sizes`` (E,) rows a held expert -> the visits and where each group lies
+    in their output.
 
-    (expert (V,), first window (V,), live visits (1,), position of each sorted
-    row in the visits' output (n_rows,)); V is ``n_visits``, what lies past the
-    live visits is never read."""
+    (expert (V,), first window (V,), live visits (1,), offset (E,)); V is
+    ``n_visits``, what lies past the live visits is never read. The i-th row of
+    group ``e`` (in sorted order) lies at row ``offset[e] + i`` of the visits'
+    output: a visit writes its whole span, so a row keeps the place it has in
+    its group's first window, ``(first + rel // span) * span + rel % span`` for
+    the row ``rel`` rows past that window's start, which is ``first * span +
+    rel``. Every lookup is a compare against all E groups summed (V x E: a few
+    thousand), no search and no gather."""
     held = sizes.shape[0]
     span = w * ROW_TILE
     visits, base, ends = group_visits(sizes, w)
     v_ends = jnp.cumsum(visits)
     first = v_ends - visits
-    live = v_ends[-1]
     v = jnp.arange(n_visits(n_rows, held, w), dtype=jnp.int32)
-    expert = jnp.minimum(jnp.searchsorted(v_ends, v, side="right"), held - 1).astype(jnp.int32)
-    window = jnp.clip(base[expert] // ROW_TILE + w * (v - first[expert]), 0, n_rows // ROW_TILE - 1)
-    rows = jnp.arange(n_rows, dtype=jnp.int32)
-    of_row = jnp.minimum(jnp.searchsorted(ends, rows, side="right"), held - 1)
-    rel = rows - base[of_row]
-    position = (first[of_row] + rel // span) * span + rel % span
-    # a row past the last group gets some position inside the output: never used
-    position = jnp.clip(position, 0, v.shape[0] * span - 1)
-    return expert, window.astype(jnp.int32), live.reshape(1), position
+    # a visit's group: as many groups as end at or before it (the last past the live visits)
+    expert = jnp.minimum(jnp.sum(v_ends[None, :] <= v[:, None], axis=1, dtype=jnp.int32), held - 1)
+    of_visit = expert[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :]
+    window = w * v + jnp.sum(jnp.where(of_visit, (base // ROW_TILE - w * first)[None, :], 0), axis=1)
+    window = jnp.clip(window, 0, n_rows // ROW_TILE - 1).astype(jnp.int32)
+    offset = first * span + ends - sizes.astype(jnp.int32) - base
+    return expert, window, v_ends[-1].reshape(1), offset
+
+
+def sorted_positions(sizes: jax.Array, offset: jax.Array, n: int, out_rows: int) -> jax.Array:
+    """(n,) the row of the visits' output (``out_rows`` long) that holds each of
+    the first ``n`` sorted rows; a row past the last group gets some row inside
+    it, which nobody reads. The sorted form's way back, and the VJP's."""
+    ends = jnp.cumsum(sizes.astype(jnp.int32))
+    rows = jnp.arange(n, dtype=jnp.int32)[:, None]
+    of_row = (ends - sizes <= rows) & (rows < ends)
+    position = rows[:, 0] + jnp.sum(jnp.where(of_row, (offset - ends + sizes)[None, :], 0), axis=1)
+    return jnp.clip(position, 0, out_rows - 1)
 
 
 def _moe_kernel(
@@ -213,17 +230,17 @@ def _moe_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("tf", "w", "interpret"))
-def _moe_call(xs, w1, w2, sizes, layer, limit, tf, w, interpret):
-    n, d = xs.shape
+def _visits_call(xs, w1, w2, visits, layer, limit, tf, w, interpret):
+    """The kernel over ``plan``'s visits of the sorted rows ``xs`` (whole row
+    tiles) -> the visits' output, a span a visit: (V * w * ROW_TILE, D)."""
+    n_rows, d = xs.shape
     held, f = w1.shape[-3], w2.shape[-2]
     gated = not ungated(w1, w2)
     w1 = w1.reshape(-1, d, w1.shape[-1])  # a stack's (L, E) as L * E groups: a bitcast
     w2 = w2.reshape(-1, f, d)
     nf, span = f // tf, w * ROW_TILE
-    n_rows = n + -n % ROW_TILE
-    xs = jnp.pad(xs, ((0, n_rows - n), (0, 0)))
-    expert, window, live, position = plan(sizes, n_rows, w)
-    n_windows, visits = n_rows // ROW_TILE, expert.shape[0]
+    expert, window, live = visits
+    n_windows = n_rows // ROW_TILE
 
     def x_window(i):  # the visit's i-th window of the sorted rows
         if i == 0:  # plan has clipped it: the index map two-window calls have always had
@@ -250,34 +267,27 @@ def _moe_call(xs, w1, w2, sizes, layer, limit, tf, w, interpret):
         out_specs=pl.BlockSpec((span, d), lambda v, j, exp, win, live: (v, 0)),
         scratch_shapes=[pltpu.VMEM((span, d), xs.dtype), pltpu.VMEM((span, d), jnp.float32)],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_moe_kernel, clamp=clamp, gated=gated),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((visits * span, d), xs.dtype),
+        out_shape=jax.ShapeDtypeStruct((expert.shape[0] * span, d), xs.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(expert + layer * held, window, live, *lim, *[xs] * w, *((w1, w1) if gated else (jnp.swapaxes(w1, 1, 2),)), w2)
-    return out[position[:n]]
 
 
-def expert_ffn(
-    xs: jax.Array,  # (N, D) rows sorted by expert, the compute dtype
-    w1: jax.Array,  # (E, D, 2F) gate columns then up columns, or a stack (L, E, D, 2F)
-    w2: jax.Array,  # (E, F, D) or (L, E, F, D)
-    sizes: jax.Array,  # (E,) int32 rows of each held expert, in order
-    layer: Optional[jax.Array] = None,  # the layer of a stack, a scalar
-    limit: Optional[jax.Array] = None,  # SwiGLU clamp, a scalar, 0 = off
-    *,
-    w: int = 2,  # ROW_TILE windows a visit holds: ``windows`` of the call's rows an expert
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    """(silu(x . gate_e) * (x . up_e)) . down_e for each sorted row x of held
-    expert e: (N, D) in ``xs``'s dtype; with ``w1`` ([L,] E, D, F), as wide as
-    ``w2`` is tall, the ungated relu(x . w1_e)^2 . w2_e (F whole 16-row tiles,
-    no clamp). Rows past ``sum(sizes)`` (experts held
-    elsewhere) come back as something finite or not: the caller selects them
-    away, as after ``ragged_dot``. ``interpret=None``: compiled on TPU, the
-    interpreter elsewhere (tests)."""
+@functools.partial(jax.jit, static_argnames=("tf", "w", "interpret"))
+def _moe_call(xs, w1, w2, sizes, layer, limit, tf, w, interpret):
+    n = xs.shape[0]
+    xs = jnp.pad(xs, ((0, -n % ROW_TILE), (0, 0)))
+    *visits, offset = plan(sizes, xs.shape[0], w)
+    out = _visits_call(xs, w1, w2, visits, layer, limit, tf, w, interpret)
+    return out[sorted_positions(sizes, offset, n, out.shape[0])]
+
+
+def _checked(xs, w1, w2, sizes, layer, limit, w, interpret):
+    """The static arguments of a call, (layer, F tile, windows, interpret), or
+    a ValueError that names what the kernel cannot take."""
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
     n, d = xs.shape
@@ -302,6 +312,42 @@ def expert_ffn(
             f"too (ungated: whole 16-row tiles), a layer exactly for a stack, two windows or more"
         )
     layer = jnp.zeros((), jnp.int32) if layer is None else jnp.asarray(layer, jnp.int32)
-    return _moe_call(
-        xs, w1, w2, sizes, layer, limit, f_tile(d, f, xs.dtype.itemsize, int(w), not two), int(w), bool(interpret)
-    )
+    return layer, f_tile(d, f, xs.dtype.itemsize, int(w), not two), int(w), bool(interpret)
+
+
+def expert_ffn(
+    xs: jax.Array,  # (N, D) rows sorted by expert, the compute dtype
+    w1: jax.Array,  # (E, D, 2F) gate columns then up columns, or a stack (L, E, D, 2F)
+    w2: jax.Array,  # (E, F, D) or (L, E, F, D)
+    sizes: jax.Array,  # (E,) int32 rows of each held expert, in order
+    layer: Optional[jax.Array] = None,  # the layer of a stack, a scalar
+    limit: Optional[jax.Array] = None,  # SwiGLU clamp, a scalar, 0 = off
+    *,
+    w: int = 2,  # ROW_TILE windows a visit holds: ``windows`` of the call's rows an expert
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """(silu(x . gate_e) * (x . up_e)) . down_e for each sorted row x of held
+    expert e: (N, D) in ``xs``'s dtype; with ``w1`` ([L,] E, D, F), as wide as
+    ``w2`` is tall, the ungated relu(x . w1_e)^2 . w2_e (F whole 16-row tiles,
+    no clamp). Rows past ``sum(sizes)`` (experts held
+    elsewhere) come back as something finite or not: the caller selects them
+    away, as after ``ragged_dot``. ``interpret=None``: compiled on TPU, the
+    interpreter elsewhere (tests)."""
+    layer, *static = _checked(xs, w1, w2, sizes, layer, limit, w, interpret)
+    return _moe_call(xs, w1, w2, sizes, layer, limit, *static)
+
+
+def expert_visits(
+    xs: jax.Array, w1: jax.Array, w2: jax.Array, sizes: jax.Array, visits, layer: Optional[jax.Array] = None,
+    limit: Optional[jax.Array] = None, *, w: int = 2, interpret: Optional[bool] = None,
+) -> jax.Array:
+    """``expert_ffn`` without the way back: ``xs`` (whole row tiles of sorted
+    rows) under ``visits``, the first three of ``plan(sizes, xs.shape[0], w)``
+    -> the visits' output (V * w * ROW_TILE, D), where row i of group e lies at
+    ``offset[e] + i`` (the plan's fourth). What a caller that gathers from the
+    output anyway (``models/moe.py``) takes, so that no pass restores a sorted
+    order only to undo it."""
+    if xs.shape[0] % ROW_TILE:
+        raise ValueError(f"rows {xs.shape}: the visits read whole tiles of {ROW_TILE} rows")
+    layer, *static = _checked(xs, w1, w2, sizes, layer, limit, w, interpret)
+    return _visits_call(xs, w1, w2, visits, layer, limit, *static)

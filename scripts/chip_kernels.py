@@ -12,9 +12,10 @@ chip hears Mosaic's refusals (tiling, unaligned slices, VMEM) and only there
 do the compiled numerics exist. One line per case, then one JSON summary
 line; exit 0 iff every case matched. A correctness run; ``--time-moe`` instead
 times the expert kernel against ``ragged_dot`` alone, 2 to 128 rows an expert
-(``--only`` then names a shape).
+(``--only`` then names a shape), and ``--time-moe-layer`` the whole expert
+layer around it at the six expert cells' decode shapes (``--only`` names a cell).
 
-Usage (on the chip):  python scripts/chip_kernels.py [--only SUBSTR] [--time-moe]
+Usage (on the chip):  python scripts/chip_kernels.py [--only SUBSTR] [--time-moe | --time-moe-layer]
 """
 
 from __future__ import annotations
@@ -259,6 +260,106 @@ def time_moe(reps: int = 20, only: str = ""):
                 del xs, w1, w2
 
 
+# The six expert cells' decode steps: tokens a step (JoyAI's 64 rows bring two
+# queries each) and what names the layer in a ModelConfig.
+MOE_LAYERS = {
+    "granite": (128, dict(d_model=4096, n_experts=72, n_experts_held=18, experts_per_token=10, d_expert=768,
+                          n_shared_experts=2, moe_score="softmax")),
+    "nemotron": (128, dict(d_model=2688, n_experts=128, n_experts_held=32, experts_per_token=6, d_expert=1856,
+                           n_shared_experts=2, activation="relu2", moe_score_bias=True, moe_routed_scale=2.5)),
+    "ling": (128, dict(d_model=2560, n_experts=512, n_experts_held=128, experts_per_token=8, d_expert=768,
+                       moe_score_bias=True, moe_n_group=8, moe_topk_group=4, moe_routed_scale=2.5)),
+    "joyai": (128, dict(d_model=2048, n_experts=256, n_experts_held=128, experts_per_token=8, d_expert=768,
+                        moe_score_bias=True, moe_routed_scale=2.5)),
+    "trinity": (64, dict(d_model=2048, n_experts=128, experts_per_token=8, d_expert=1024, moe_score_bias=True,
+                         moe_routed_scale=2.826)),
+    "xing": (32, dict(d_model=3584, n_experts=64, experts_per_token=4, d_expert=1024, moe_score_bias=True,
+                      moe_routed_scale=2.0)),
+}
+
+
+def time_moe_layer(reps: int = 20, only: str = "", n_stack: int = 2, chain: int = 8):
+    """Milliseconds a layer of the whole dropless expert layer (router to the
+    sum with the shared expert: the five ``moe.*`` scopes) at the six expert
+    cells' decode shapes, as it stood before PR 59 (``tests/test_moe.py`` keeps
+    that layer as its oracle; read from there) and as it stands, beside the
+    kernel alone over the same step's sorted rows (its visits planned outside
+    the timed call): what is left between the two is the glue. Each call chains
+    ``chain`` layers, a layer's output added to the next one's input, over a
+    stack of ``n_stack`` layers' experts read where they lie."""
+    import importlib.util
+    import time
+
+    from pretraining_llm_tpu.config import ModelConfig
+    from pretraining_llm_tpu.models import moe, transformer
+    from pretraining_llm_tpu.ops import pallas_moe
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location("oracle", os.path.join(root, "tests", "test_moe.py"))
+    oracle = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(oracle)
+
+    def ms(fn, *args):
+        fn(*args).block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out = fn(*args)
+        out.block_until_ready()
+        return round((time.perf_counter() - t0) / (reps * chain) * 1e3, 4)
+
+    layers = jnp.arange(chain, dtype=jnp.int32) % n_stack
+    for cell, (tokens, fields) in MOE_LAYERS.items():
+        if only not in cell:
+            continue
+        cfg = ModelConfig(**{**dict(
+            vocab_size=64, context_length=64, n_heads=2, n_layers=1, activation="swiglu", norm="rmsnorm",
+            mlp_bias=False, compute_dtype="bfloat16", param_dtype="bfloat16", moe_routing="dropless",
+            moe_score="sigmoid", n_shared_experts=1), **fields})
+        init = jax.jit(lambda k: moe.init_dropless_params(cfg, k, 0.02, jnp.bfloat16))
+        made = [init(k) for k in jax.random.split(jax.random.key(0), n_stack)]
+        mlp = dict(made[0], experts=jax.tree.map(lambda *a: jnp.stack(a), *[m["experts"] for m in made]))
+        del made
+        h = jax.random.normal(jax.random.key(1), (tokens, 1, cfg.d_model), jnp.bfloat16)
+        dense = lambda shared, hh: transformer._dense_mlp(shared, hh, cfg)
+        pairs = tokens * cfg.experts_per_token
+        form = moe.experts_form(pairs, cfg, mlp["experts"])
+
+        def chained(layer_fn):
+            def run(mlp, h):
+                def body(h, layer):
+                    return h + layer_fn(dict(mlp, expert_layer=layer), h, cfg, dense)[0] * 0.125, None
+                return jax.lax.scan(body, h, layers)[0]
+            return jax.jit(run)
+
+        before = chained(lambda m, x, c, dn: oracle._layer_before_pr59(m, x, c, dn, form))
+        now = chained(moe.moe_mlp_dropless)
+        line = {"cell": cell, "tokens": tokens, "pairs": pairs, "held": cfg.experts_held, "form": form,
+                "same": bool(jnp.array_equal(before(mlp, h), now(mlp, h))),
+                "layer_before_ms": ms(before, mlp, h), "layer_ms": ms(now, mlp, h)}
+        if form == "kernel":
+            # the kernel alone over this step's own sorted rows
+            flat = jnp.minimum(moe.route_dropless(mlp, h[:, 0], cfg)[0].reshape(pairs), cfg.experts_held)
+            sizes = jnp.bincount(flat, length=cfg.experts_held + 1)[: cfg.experts_held].astype(jnp.int32)
+            xs = h[jnp.argsort(flat, stable=True) // cfg.experts_per_token, 0]
+            xs = jnp.pad(xs, ((0, -pairs % pallas_moe.ROW_TILE), (0, 0)))
+            w = pallas_moe.windows(pairs, cfg.n_experts)
+            visits = tuple(pallas_moe.plan(sizes, xs.shape[0], w)[:3])
+
+            @jax.jit
+            def kernel(xs, w1, w2, sizes, visits):
+                def body(acc, layer):
+                    out = moe.experts_visits(xs, w1, w2, sizes, visits, layer, None, w)
+                    return acc + out[0, 0].astype(jnp.float32), None
+                return jax.lax.scan(body, jnp.float32(0), layers)[0]
+
+            line.update(w=w, touched=int((sizes > 0).sum()), visits=int(visits[2][0]),
+                        kernel_ms=ms(kernel, xs, mlp["experts"]["w1"], mlp["experts"]["w2"], sizes, visits))
+            line.update(glue_before_ms=round(line["layer_before_ms"] - line["kernel_ms"], 4),
+                        glue_ms=round(line["layer_ms"] - line["kernel_ms"], 4))
+        print(json.dumps(line), flush=True)
+        del mlp
+
+
 def flash_qkv_case(t: int, h: int, dh: int):
     """``pallas_flash_attention_qkv`` (q, k and v out of one (B, 3, T, H*Dh)
     array, one d(qkv) back) against the three-array entry over slices of the
@@ -337,6 +438,9 @@ def main() -> int:
     ap.add_argument("--only", default="", help="run cases whose name contains this")
     ap.add_argument("--time-moe", action="store_true",
                     help="time the expert kernel against ragged_dot instead (one JSON line a size)")
+    ap.add_argument("--time-moe-layer", action="store_true",
+                    help="time the whole expert layer, as before PR 59 and as it is, beside the kernel alone "
+                         "at the six expert cells' decode shapes (one JSON line a cell)")
     args = ap.parse_args()
     dev = jax.devices()[0]
     print(f"jax {jax.__version__} backend {jax.default_backend()} "
@@ -347,6 +451,9 @@ def main() -> int:
         return 1
     if args.time_moe:
         time_moe(only=args.only)
+        return 0
+    if args.time_moe_layer:
+        time_moe_layer(only=args.only)
         return 0
     results = {}
     for name, fn, fn_args in cases():

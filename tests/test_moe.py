@@ -11,9 +11,11 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from pretraining_llm_tpu.config import ModelConfig, get_preset
 from pretraining_llm_tpu.models import moe, transformer
+from pretraining_llm_tpu.ops import pallas_moe
 from pretraining_llm_tpu.training import train_step as ts
 
 
@@ -233,3 +235,112 @@ def test_expert_parallel_train_step_matches_single_device(mesh_exp4):
     # bf16 compute + mesh-dependent reduction order => small numeric slack
     np.testing.assert_allclose(sharded_loss, float(metrics1["loss"]), rtol=1e-3)
     assert int(jax.device_get(sharded["step"])) == 1
+
+
+# -- the dropless layer plans its rows once (PR 59) -----------------------------------
+
+
+def _layer_before_pr59(mlp, h, cfg, dense_mlp, form):
+    """``moe.moe_mlp_dropless`` as it stood before PR 59, kept here as the oracle:
+    two sorts, a ``bincount``, the experts' output brought back to sorted order
+    (inside ``moe.experts_kernel``) and then to token order by a second row
+    gather, gates and masks gathered into sorted order for the product."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    b, t, d = h.shape
+    s, k = b * t, cfg.experts_per_token
+    x = h.reshape(s, d)
+    w1, w2 = mlp["experts"]["w1"].astype(cdt), mlp["experts"]["w2"].astype(cdt)
+    held = w1.shape[-3]
+    layer, limit = mlp.get("expert_layer"), mlp.get("expert_limit")
+    idx, gates = moe.route_dropless(mlp, x, cfg)
+    flat = idx.reshape(s * k)
+    flat = jnp.where(flat < held, flat, held)
+    order = jnp.argsort(flat, stable=True)
+    counts = jnp.bincount(flat, length=held + 1).astype(jnp.int32)
+    xs = x[order // k].astype(cdt)
+    sizes = counts[:held]
+    if form == "kernel":
+        ys = moe.experts_kernel(xs, w1, w2, sizes, layer, limit, pallas_moe.windows(s * k, cfg.n_experts))
+    else:
+        ys = moe.experts_grouped(xs, w1, w2, sizes, layer, limit)
+    here = flat[order] < held
+    g_sorted = jnp.where(here, gates.reshape(s * k)[order], 0.0)
+    ys = ys.astype(jnp.float32) * g_sorted[:, None]
+    if held < cfg.n_experts:
+        ys = jnp.where(here[:, None], ys, 0.0)
+    ys = ys.astype(cdt)
+    y = jnp.sum(ys[jnp.argsort(order)].reshape(s, k, d).astype(jnp.float32), axis=1).astype(cdt).reshape(b, t, d)
+    if "shared" in mlp:
+        y = y + dense_mlp(mlp["shared"], h)
+    return y.astype(h.dtype), counts[:held]
+
+
+# (experts held of 8, a stack, activation, clamp, form, tokens, routing): every
+# value of each beside every value of the others at least once. 7 tokens x 2
+# choices is no whole row tile of sorted rows; "one-takes-all" has one choice a
+# token and a bias that sends every token to expert 1; "one-takes-none" a bias
+# that keeps expert 0 out of every selection.
+_PLANNED_ONCE = [
+    (8, False, "swiglu", None, "kernel", 16, "scored"),
+    (8, True, "swiglu", 0.5, "grouped", 7, "scored"),
+    (8, True, "relu2", None, "kernel", 7, "one-takes-all"),
+    (8, False, "relu2", None, "grouped", 16, "one-takes-none"),
+    (2, True, "swiglu", None, "kernel", 7, "one-takes-none"),
+    (2, False, "swiglu", 0.5, "kernel", 16, "one-takes-all"),
+    (2, False, "relu2", None, "grouped", 7, "scored"),
+    (2, True, "relu2", None, "kernel", 16, "scored"),
+    (2, True, "swiglu", 0.5, "grouped", 16, "one-takes-all"),
+    (8, False, "swiglu", 0.5, "kernel", 7, "one-takes-none"),
+    (8, True, "swiglu", None, "grouped", 16, "one-takes-all"),
+    (2, False, "swiglu", None, "grouped", 7, "one-takes-none"),
+    (8, False, "swiglu", None, "kernel", 40, "scored"),  # 10 rows an expert: three row tiles' span and more visits
+    (2, True, "swiglu", 0.0, "kernel", 200, "scored"),  # 50 rows an expert, a clamp that is off
+]
+
+
+@pytest.mark.parametrize(
+    "held,stacked,activation,clamp,form,tokens,routing", _PLANNED_ONCE,
+    ids=["-".join(str(v) for v in case) for case in _PLANNED_ONCE],
+)
+def test_the_layer_that_plans_its_rows_once_is_the_layer_before_to_the_bit(
+    monkeypatch, held, stacked, activation, clamp, form, tokens, routing
+):
+    """One count and one gather each way give what two sorts, two searches and
+    three gathers gave, bit for bit: a gather commutes with an elementwise
+    product, so the float32 product with the gate, the rounding to bfloat16, the
+    float32 sum over the choices in choice order and the last rounding stand
+    where they stood. The kernel is interpreted here."""
+    n_experts, d, f, n_stack = 8, 128, 128, 3
+    cfg = ModelConfig(
+        vocab_size=64, context_length=256, d_model=d, n_heads=2, n_layers=1, activation=activation, norm="rmsnorm",
+        compute_dtype="bfloat16", param_dtype="bfloat16", n_experts=n_experts, n_experts_held=held % n_experts,
+        experts_per_token=1 if routing == "one-takes-all" else 2, moe_routing="dropless", moe_score="sigmoid",
+        moe_score_bias=True, n_shared_experts=1, d_expert=f, mlp_bias=False,
+    )
+    mlp = moe.init_dropless_params(cfg, jax.random.key(held + tokens), 0.02, jnp.bfloat16)
+    mlp["experts"] = {k: v * 8 for k, v in mlp["experts"].items()}  # outputs of a size that rounding shows
+    bias = {"scored": [0.0] * 8, "one-takes-all": [0, 9, 0, 0, 0, 0, 0, 0], "one-takes-none": [-9] + [0.0] * 7}
+    mlp["router_bias"] = jnp.asarray(bias[routing], jnp.bfloat16)
+    if stacked:
+        keys = jax.random.split(jax.random.key(7), n_stack)
+        others = [moe.init_dropless_params(cfg, k_, 0.02, jnp.bfloat16)["experts"] for k_ in keys]
+        others[1] = mlp["experts"]
+        mlp["experts"] = jax.tree.map(lambda *a: jnp.stack(a), *others)
+        mlp["expert_layer"] = jnp.int32(1)
+    if clamp is not None:
+        mlp["expert_limit"] = jnp.float32(clamp)
+    h = jax.random.normal(jax.random.key(tokens), (1, tokens, d), jnp.bfloat16)
+    dense = lambda shared, hh: transformer._dense_mlp(shared, hh, cfg)
+    monkeypatch.setattr(moe, "experts_form", lambda *a, **kw: form)
+
+    got, want = jax.jit(lambda m, x: (
+        moe.moe_mlp_dropless(m, x, cfg, dense), _layer_before_pr59(m, x, cfg, dense, form)))(mlp, h)
+    np.testing.assert_array_equal(got[1], want[1])
+    counts = np.asarray(got[1])
+    assert counts.shape == (held,) and (held < 8 or counts.sum() == tokens * cfg.experts_per_token)
+    if routing == "one-takes-all":
+        assert counts[1] == tokens and counts.sum() == tokens
+    if routing == "one-takes-none":
+        assert counts[0] == 0 and counts[1:].all()
+    assert float(jnp.max(jnp.abs(want[0].astype(jnp.float32)))) > 0.05
+    np.testing.assert_array_equal(np.asarray(got[0], np.float32), np.asarray(want[0], np.float32))

@@ -160,13 +160,23 @@ def test_the_grid_holds_every_plan(rows, held, w):
         visits = np.where(sizes > 0, -(-(ends - base) // span), 0).sum()
         assert visits <= pk.n_visits(rows, held, w)
         np.testing.assert_array_equal(pk.group_visits(jnp.asarray(sizes, jnp.int32), w)[0].sum(), visits)
-        expert, window, live, position = pk.plan(jnp.asarray(sizes, jnp.int32), rows, w)
+        expert, window, live, offset = pk.plan(jnp.asarray(sizes, jnp.int32), rows, w)
         assert int(live[0]) == visits and expert.shape == window.shape == (pk.n_visits(rows, held, w),)
-        here = np.asarray(position)[: sizes.sum()]
-        assert len(set(here.tolist())) == len(here) and here.max(initial=0) < visits * span + span
+        assert offset.shape == (held,)
+        # the visits' (expert, first window) lists are what the two searches gave
+        v_ends = np.cumsum(np.where(sizes > 0, -(-(ends - base) // span), 0))
+        want_expert = np.minimum(np.searchsorted(v_ends, np.arange(len(expert)), side="right"), held - 1)
+        np.testing.assert_array_equal(expert, want_expert)
+        want_window = base[want_expert] // TILE + w * (np.arange(len(expert)) - (v_ends - np.diff(v_ends, prepend=0))[want_expert])
+        np.testing.assert_array_equal(window, np.clip(want_window, 0, rows // TILE - 1))
+        # row i of group e lies at offset[e] + i: every sorted row a place of its own inside the live visits
+        of_row = np.repeat(np.arange(held), sizes)
+        here = np.asarray(offset)[of_row] + np.arange(rows) - (ends - sizes)[of_row]
+        assert len(set(here.tolist())) == len(here) and here.max(initial=0) < visits * span
         # a row lies in its visit's span at the place it has in the sorted rows
         first_window = np.asarray(window)[here // span]
-        np.testing.assert_array_equal((first_window * TILE + here % span)[: sizes.sum()], np.arange(sizes.sum()))
+        np.testing.assert_array_equal(first_window * TILE + here % span, np.arange(rows))
+        np.testing.assert_array_equal(pk.sorted_positions(jnp.asarray(sizes, jnp.int32), offset, rows, visits * span), here)
     assert pk.n_visits(rows, held) == min(held, rows) + rows // TILE  # two windows: the bound the cells have had
 
 
@@ -401,6 +411,42 @@ def test_gradient_through_the_layer_is_the_grouped_forms(on_a_tpu, monkeypatch):
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert np.isfinite(np.asarray(g, np.float32)).all()
         np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(w, np.float32), atol=2e-2, rtol=5e-2)
+
+
+@pytest.mark.parametrize("form", ["kernel", "grouped"])
+def test_the_layer_plans_its_rows_once(monkeypatch, form):
+    """The mechanism of PR 59, read off the jaxpr of one decode-shaped layer
+    (128 rows x 2 choices over 8 experts of which 4 are held): a pair's place
+    is counted once, in token order, and each row is gathered once on the way
+    in (``x[order // k]``, the sorted rows the kernel's windows read) and once
+    on the way out (each pair's row straight from the experts' output, visit
+    order or sorted, weighted in token order). One sort is left (the order of
+    the way in); no second sort inverts it, no ``searchsorted`` finds again the
+    expert a pair chose (a ``while`` of log2 steps, each a gather), and nothing
+    restores a sorted order only to undo it. The layer before did two sorts,
+    two searches and three (N, D) gathers in the kernel's form."""
+    cfg = dataclasses.replace(CFG, n_experts_held=4)
+    mlp = jax.eval_shape(lambda: moe.init_dropless_params(cfg, jax.random.key(0), 0.02, jnp.bfloat16))
+    h = jax.ShapeDtypeStruct((128, 1, cfg.d_model), jnp.bfloat16)
+    monkeypatch.setattr(moe, "experts_form", lambda *a, **kw: form)
+    n, d = 128 * cfg.experts_per_token, cfg.d_model
+    found = {"sort": 0, "while": 0, "row gathers": 0, "pallas_call": 0}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            if name in found:
+                found[name] += 1
+            if name == "pallas_call":
+                continue  # the kernel's own body
+            if name == "gather":
+                shapes = [eqn.invars[0].aval.shape, eqn.outvars[0].aval.shape]
+                found["row gathers"] += any(len(sh) == 2 and sh[0] >= n and sh[1] == d for sh in shapes)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(lambda m, x: moe.moe_mlp_dropless(m, x, cfg, lambda shared, hh: transformer._dense_mlp(shared, hh, cfg)))(mlp, h).jaxpr)
+    assert found == {"sort": 1, "while": 0, "row gathers": 2, "pallas_call": form == "kernel"}, found
 
 
 # What Mosaic is handed for a SwiGLU expert at the five expert cells' decode

@@ -410,7 +410,7 @@ def test_the_expert_kernel_sits_under_moe_experts(monkeypatch):
     finally:
         jax.clear_caches()
     paths = set(re.findall(r'loc\("([^"]+)"', text))
-    kernel = [path for path in paths if "jit(_moe_call)" in path]
+    kernel = [path for path in paths if "jit(_visits_call)" in path]
     assert kernel and all({"mlp", "moe.experts"} <= set(re.split(r"[/()]", path)) for path in kernel)
     assert not [path for path in paths if "ragged_dot" in path]
 

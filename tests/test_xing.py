@@ -461,22 +461,30 @@ PARENTS = {
     # 2,512 / 241427cc1ac9352d, 1,481 / e28efbc503cecb46 and 2,672 / b4e83b754e2de962 at a5eb60d - and 37 to 39
     # int32 equations over the (steps, expert layers, E) routing counts, the weight reads `expert_visits`;
     # diffed equation by equation against that commit, nothing else differs)
-    ("xing4.0-29b-a4b", "decode"): (2816, "a917014dbbbf7e7e"),
-    ("xing4.0-29b-a4b", "prefill"): (1445, "1fd884617569206c"),
-    ("xing4.0-29b-a4b", "forward"): (1354, "7c021ba348cc52d2"),
+    # (all three expert models' programs since PR 59: the parent's at a346854 - 2,816 / a917014dbbbf7e7e,
+    # 1,445 / 1fd884617569206c, 1,354 / 7c021ba348cc52d2; Ling's 2,549 / 4525c94499540e5b, 2,254 /
+    # 14eb943ac170ae38, 2,023 / 96aa51f3110c061b; JoyAI's 1,518 / 2c64035367baaf79, 613 / 241d392a485a0b90,
+    # 504 / 9d87ef583c8c67f6, 2,711 / 463725f3440a882c - with the dropless expert layer planning its rows
+    # once: 36 to 41 equations a traced expert layer leave (the second sort, the `bincount`'s scatter-add, the
+    # two N-vector gathers, the un-sort's row gather and the reduce over K) and 78 to 93 come (the count in
+    # blocks, the place of each pair, the K slabs weighted and summed one by one); the equations outside the expert layer
+    # are the parent's, diffed as multisets on the decode programs of Xing and Ling)
+    ("xing4.0-29b-a4b", "decode"): (2900, "e4d88fa2215dddac"),
+    ("xing4.0-29b-a4b", "prefill"): (1487, "6c596de135eff457"),
+    ("xing4.0-29b-a4b", "forward"): (1396, "a7cecb89b7655ca8"),
     # the fifth and sixth, at the `ling-mini` and `joyai-mini` presets' widths (state slots beside
     # the latent pool; the MTP module's layer and its round), as 6611a27 (PR 40) traces them:
     # PR 41's per-layer attention kind, second page table and window pool leave them be
-    ("ling-3.0-flash", "decode"): (2549, "4525c94499540e5b"),
+    ("ling-3.0-flash", "decode"): (2809, "bd5a44c64df2ad29"),
     # (the prefill since PR 54: a state-slot model's admission runs the head on each row's last
     # position alone, `paged._prefill_last_logits`; the parent's 2,254 / 6f0de077b97d34fb with the
     # head's dot over (N, 1, D) where it stood over (N, P, D) and the take of the last row ahead of it)
-    ("ling-3.0-flash", "prefill"): (2254, "14eb943ac170ae38"),
-    ("ling-3.0-flash", "forward"): (2023, "96aa51f3110c061b"),
-    ("joyai-llm-flash", "decode"): (1518, "2c64035367baaf79"),
-    ("joyai-llm-flash", "prefill"): (613, "241d392a485a0b90"),
-    ("joyai-llm-flash", "forward"): (504, "9d87ef583c8c67f6"),
-    ("joyai-llm-flash", "round"): (2711, "463725f3440a882c"),
+    ("ling-3.0-flash", "prefill"): (2462, "1ab631339827f697"),
+    ("ling-3.0-flash", "forward"): (2231, "29f10cb478aad124"),
+    ("joyai-llm-flash", "decode"): (1622, "3b612b6419896fa2"),
+    ("joyai-llm-flash", "prefill"): (665, "a3a49066c6daf8df"),
+    ("joyai-llm-flash", "forward"): (556, "d50d4ffe09b175b7"),
+    ("joyai-llm-flash", "round"): (2867, "9790c0e38988ca1c"),
 }
 
 
